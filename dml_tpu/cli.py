@@ -880,6 +880,12 @@ def main(argv: Optional[List[str]] = None) -> None:
         logfile=getattr(args, "log_file", None),
     )
     if args.command == "node":
+        # a node may compile (engine, LM backend): place the persistent
+        # cache by the one rule before anything does. Imports jax,
+        # touches no device.
+        from .compile_cache import configure_compile_cache
+
+        configure_compile_cache()
         asyncio.run(_run_node(args))
     elif args.command == "introducer":
         asyncio.run(_run_introducer(args))

@@ -32,12 +32,14 @@ prefill so the dense-dispatch intermediate stays bounded).
 
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.transformer import rope
 from .quantize import kernel_of
@@ -266,40 +268,47 @@ def decode_step(
     )
 
 
-def batched_decode_step(
-    params: Dict[str, Any],
-    cfg: LMConfig,
-    cache: Dict[str, Any],
-    tokens: jax.Array,  # [B] int32 — each slot's current input token
-    pos: jax.Array,  # [B] int32 — each slot's own write position
-) -> Tuple[jax.Array, Dict[str, Any]]:
-    """decode_step with PER-SLOT positions — the continuous-batching
-    primitive (inference/lm_server.py): every slot advances through
-    its own sequence independently, so requests of different lengths
-    decode together in one program. Identical math to decode_step
-    (which is the pos-broadcast special case)."""
-    hd = cfg.head_dim
-    b = tokens.shape[0]
-    grp = cfg.n_heads // cfg.kv_heads
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)[:, None, :]
-    positions = pos[:, None]  # [B, 1] — rope's per-example form
-    # layout-generic (bf16 {k, v} or kv_quant {k_q, ...}): every leaf
-    # carries [B, KV, max_len, ...]
-    max_len = next(iter(next(iter(cache.values())).values())).shape[2]
-    # per-slot validity: slot b sees cache positions <= pos[b]
-    valid = jnp.arange(max_len)[None, :] <= pos[:, None]  # [B, T]
-    # the Pallas cache-attention kernel replaces the einsum on TPU
-    # where it measured faster (v5e, r4 dispersion A/B, median of 5
-    # paired slopes): int8 caches (6662 vs 4482 tok/s b8/4k — the
-    # einsum path materializes the dequantized cache in HBM first),
-    # MHA (1057 vs 790 b1/4k — the full-width cache is the most
-    # bandwidth-bound) and MQA (1950 vs 1792). Grouped bf16 caches
-    # (1 < KV < H) stay on the einsum: XLA's batched-matmul schedule
-    # held 5676 vs 4912 at b8/4k. DML_TPU_DECODE_KERNEL=0/1 forces
-    # the path — the A/B lever the bench uses to re-verify the policy
-    # every round.
+def heads_axis(mesh: Optional[Mesh], *head_counts: int) -> Optional[str]:
+    """The mesh axis attention heads shard over: "tp" when every given
+    head count divides over it, else None (replicated). The one rule
+    for where a head lives — the KV cache's placement (LMServer) and
+    the per-device kernel wrappers below must agree on it."""
+    tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+    return "tp" if tp > 1 and all(n % tp == 0 for n in head_counts) else None
+
+
+def _kernel_on_mesh(kernel, mesh: Optional[Mesh], in_specs, out_specs):
+    """A Pallas kernel placed per device under `mesh`. GSPMD cannot
+    partition a Mosaic custom call — lowering for a real multi-chip
+    mesh raises "Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map" (the CPU mesh, where kernels
+    interpret to plain XLA ops, never showed it) — so each device runs
+    the kernel on its own heads, or whole where heads do not divide.
+    check_vma off: a pallas_call's out_shape carries no vma metadata."""
+    if mesh is None:
+        return kernel
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
+
+
+def uses_decode_kernel(cfg: LMConfig) -> bool:
+    """Whether `batched_decode_step` hands cache attention to the
+    Pallas kernel (ops/decode_attention.py) — the one place that
+    decides, so a caller can ask which path a config compiles to.
+
+    The kernel replaces the einsum on TPU where it measured faster
+    (v5e, 2026-07 dispersion A/B, median of 5 paired slopes; not
+    re-measured on the current installation): int8 caches (6662 vs
+    4482 tok/s b8/4k — the einsum path materializes the dequantized
+    cache in HBM first), MHA (1057 vs 790 b1/4k — the full-width cache
+    is the most bandwidth-bound) and MQA (1950 vs 1792). Grouped bf16
+    caches (1 < KV < H) stay on the einsum: XLA's batched-matmul
+    schedule held 5676 vs 4912 at b8/4k. DML_TPU_DECODE_KERNEL=0/1
+    forces the path — the A/B lever that re-verifies the policy."""
     force = os.environ.get("DML_TPU_DECODE_KERNEL")
-    use_kernel = jax.default_backend() == "tpu" and (
+    return jax.default_backend() == "tpu" and (
         force == "1"
         or (
             force != "0"
@@ -310,6 +319,48 @@ def batched_decode_step(
             )
         )
     )
+
+
+def batched_decode_step(
+    params: Dict[str, Any],
+    cfg: LMConfig,
+    cache: Dict[str, Any],
+    tokens: jax.Array,  # [B] int32 — each slot's current input token
+    pos: jax.Array,  # [B] int32 — each slot's own write position
+    mesh: Optional[Mesh] = None,
+) -> Tuple[jax.Array, Dict[str, Any]]:
+    """decode_step with PER-SLOT positions — the continuous-batching
+    primitive (inference/lm_server.py): every slot advances through
+    its own sequence independently, so requests of different lengths
+    decode together in one program. Identical math to decode_step
+    (which is the pos-broadcast special case). `mesh` is the mesh the
+    params are sharded over, if any: the Pallas cache-attention
+    kernel is then placed per device (`_kernel_on_mesh`)."""
+    hd = cfg.head_dim
+    b = tokens.shape[0]
+    grp = cfg.n_heads // cfg.kv_heads
+    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)[:, None, :]
+    positions = pos[:, None]  # [B, 1] — rope's per-example form
+    # layout-generic (bf16 {k, v} or kv_quant {k_q, ...}): every leaf
+    # carries [B, KV, max_len, ...]
+    max_len = next(iter(next(iter(cache.values())).values())).shape[2]
+    # per-slot validity: slot b sees cache positions <= pos[b]
+    valid = jnp.arange(max_len)[None, :] <= pos[:, None]  # [B, T]
+    use_kernel = uses_decode_kernel(cfg)
+    if use_kernel:
+        from ..ops.decode_attention import decode_attention
+
+        ax = heads_axis(mesh, cfg.n_heads, cfg.kv_heads)
+        q_spec = P(None, None, ax, None)  # [B, 1, H, D]
+        c_spec = P(None, ax, None, None)  # [B, KV, T, D] / [B, KV, 1, T]
+        kernel = _kernel_on_mesh(
+            lambda q, k, v, p, ks=None, vs=None: decode_attention(
+                q, k, v, p, k_scale=ks, v_scale=vs),
+            mesh,
+            in_specs=(q_spec, c_spec, c_spec, P())
+            + ((c_spec, c_spec) if cfg.kv_quant else ()),
+            out_specs=q_spec,
+        )
 
     new_cache: Dict[str, Any] = {}
     for i in range(cfg.n_layers):
@@ -346,11 +397,9 @@ def batched_decode_step(
                 }
                 new_cache[name] = lay
                 if use_kernel:
-                    from ..ops.decode_attention import decode_attention
-
-                    return decode_attention(
+                    return kernel(
                         q, lay["k_q"], lay["v_q"], pos,
-                        k_scale=lay["k_s"], v_scale=lay["v_s"],
+                        lay["k_s"], lay["v_s"],
                     )
                 ck = _kv_dequant(
                     lay["k_q"], jnp.swapaxes(lay["k_s"], 2, 3)
@@ -363,9 +412,7 @@ def batched_decode_step(
                 cv = upd(cache[name]["v"], vh.astype(cfg.dtype), axis=2)
                 new_cache[name] = {"k": ck, "v": cv}
                 if use_kernel:
-                    from ..ops.decode_attention import decode_attention
-
-                    return decode_attention(q, ck, cv, pos)
+                    return kernel(q, ck, cv, pos)
             qg = q.astype(jnp.float32).reshape(b, 1, cfg.kv_heads, grp, hd)
             s = jnp.einsum(
                 "bqkgd,bktd->bkgqt", qg, ck.astype(jnp.float32)
@@ -492,9 +539,13 @@ def prefill(
     prompt: jax.Array,  # [B, Tp] int32
     max_len: int,
     logits_index: Optional[jax.Array] = None,
+    mesh: Optional[Mesh] = None,
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Process the WHOLE prompt in one forward: returns (logits at the
     last prompt position [B, V], cache filled for positions < Tp).
+
+    `mesh` is the mesh the params are sharded over, if any: the flash
+    kernel is then placed per device (`_kernel_on_mesh`).
 
     `logits_index` (scalar) selects which position's logits to return
     instead of the last — the continuous-batching server prefills
@@ -517,6 +568,12 @@ def prefill(
     pad = max_len - tp
     grp = cfg.n_heads // cfg.kv_heads
 
+    h_spec = P(None, None, heads_axis(mesh, cfg.n_heads), None)  # [B,T,H,D]
+    flash = _kernel_on_mesh(
+        functools.partial(flash_attention, causal=True), mesh,
+        in_specs=(h_spec, h_spec, h_spec), out_specs=h_spec,
+    )
+
     def attn_fn(q, k, v):
         # flash kernel is head-symmetric: broadcast GQA kv heads to
         # full heads for the prefill pass (the cache below keeps the
@@ -524,7 +581,7 @@ def prefill(
         if grp > 1:
             k = jnp.repeat(k, grp, axis=2)
             v = jnp.repeat(v, grp, axis=2)
-        return flash_attention(q, k, v, causal=True)
+        return flash(q, k, v)
 
     cache: Dict[str, Any] = {}
     pad4 = ((0, 0), (0, 0), (0, pad), (0, 0))  # head-major: pad T axis 2

@@ -231,7 +231,7 @@ class LMBackend:
         # - overlap=True (default): all callers feed ONE LMDriver —
         #   their prompts merge into the same slot grid, so batch N+1
         #   prefills into freed slots while batch N is still decoding
-        #   and per-chunk link round-trips amortize over everything in
+        #   and per-chunk readbacks amortize over everything in
         #   flight (see LMDriver's docstring for why this beats
         #   per-worker servers on one chip).
         # - overlap=False: the round-3/4 lock-serialized path, kept as
@@ -635,8 +635,8 @@ class LMBackend:
         params, cfg = lm_spec_parts(spec)
         max_new = int(spec.get("max_new_tokens", 32))
         # default chunk ≈ the per-request budget (capped): every step's
-        # packed readback costs a link round-trip, so a 32-token budget
-        # at chunk 16 pays twice the round-trips for the same tokens.
+        # packed readback is a host synchronization, so a 32-token budget
+        # at chunk 16 pays twice as many for the same tokens.
         # Operators with mixed budgets set chunk explicitly (smaller =
         # finer continuous-batching join granularity).
         chunk_default = max(1, min(max_new, 32))

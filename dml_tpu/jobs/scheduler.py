@@ -96,9 +96,9 @@ class DepthController:
 
     Round 5's artifact of record measured static depth-2 pipelining as
     a pessimization (0.91×/0.85× vs the depth-1 serial loop) while r4's
-    congested-link captures had it winning 1.47–1.57× — like the
-    sync-vs-pipelined dispatch choice, the winner is decided by link
-    weather, not by the code. This applies the same cure the engine's
+    captures had it winning 1.47–1.57× — like the sync-vs-pipelined
+    dispatch choice, the winner is decided by the conditions of the
+    run, not by the code. This applies the same cure the engine's
     ``choose_dispatch_mode`` proved on the C4 path: measure both modes
     on real work, commit to the winner, and re-measure when conditions
     drift (Orca/vLLM's measured-not-assumed scheduling discipline).
@@ -123,10 +123,11 @@ class DepthController:
     (fetch / infer / put — the same ACK-carried timings
     ``breakdown_stats`` aggregates) against the probe-time signature;
     a stage mean drifting past ``drift_ratio`` in either direction
-    re-arms the probe, so congested links regain overlap and healed
-    links fall back to the cheap path automatically. ``reprobe_ttl_s``
-    re-arms on age alone (link weather drifts without a stage-wall
-    signature move when it shifts all stages together).
+    re-arms the probe, so a run whose stages slow down regains
+    overlap and one that recovers falls back to the cheap path
+    automatically. ``reprobe_ttl_s`` re-arms on age alone (conditions
+    can drift without a stage-wall signature move when they shift all
+    stages together).
     """
 
     PHASES = (1, 2)
@@ -187,7 +188,7 @@ class DepthController:
         # pool size the committed depth was measured against (None
         # until first observed): elastic membership can grow or shrink
         # the slot count mid-job, which changes the overlap economics
-        # as surely as link weather does — a size change re-arms the
+        # as surely as a stage-wall drift does — a size change re-arms the
         # probe (trigger "pool") so the committed depth is re-validated
         # against the pool that actually exists now
         self._pool_size: Optional[int] = None

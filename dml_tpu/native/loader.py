@@ -1,12 +1,17 @@
 """ctypes wrapper for the native batch image loader (native/dataloader.cpp).
 
-Builds `libdmlloader.so` with g++ on first use (cached beside the
-source; rebuilt when the source is newer). The loader is the fast path
-of `models.preprocess.load_images`: libjpeg DCT-scaled decode + C++
-bilinear resize + thread pool, producing the contiguous NHWC uint8
-batch the engine ships to HBM. Falls back cleanly when a compiler or
-libjpeg is unavailable (`native_available()` -> False) — the PIL path
-stays fully supported.
+Builds the shared library with g++ on first use, beside the source,
+under a name keyed by a hash of the source and the build command:
+`libdmlloader-<key>.so`. A library whose key does not match — built
+from other source, with other flags, or copied in from another
+checkout — is never loaded; it is rebuilt here. The flags name no
+CPU (`-march=native` is gone), so a library that travels with a copy
+of the tree still runs on the machine it lands on. The loader is the
+fast path of `models.preprocess.load_images`: libjpeg DCT-scaled
+decode + C++ bilinear resize + thread pool, producing the contiguous
+NHWC uint8 batch the engine ships to HBM. Where a compiler or libjpeg
+is unavailable the PIL path serves (`native_available()` -> False),
+after ONE warning that carries the compiler's stderr.
 
 Set DML_NATIVE_LOADER=0 to force the PIL path.
 """
@@ -14,55 +19,72 @@ Set DML_NATIVE_LOADER=0 to force the PIL path.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
 import threading
-from typing import Optional, Sequence
+from typing import List, Optional, Sequence
 
 import numpy as np
 
 log = logging.getLogger(__name__)
 
-_SRC_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
-_SRC = os.path.abspath(os.path.join(_SRC_DIR, "dataloader.cpp"))
-_LIB = os.path.abspath(os.path.join(_SRC_DIR, "libdmlloader.so"))
+_SRC_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native")
+)
+_SRC = os.path.join(_SRC_DIR, "dataloader.cpp")
 
 _lock = threading.Lock()
 _loader: Optional["NativeLoader"] = None
 _failed = False
 
 
-def _build() -> bool:
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return True
+def _build_cmd(out: str) -> List[str]:
+    return [
+        os.environ.get("CXX", "g++"),
+        "-O3", "-fPIC", "-std=c++17", "-shared",
+        "-o", out, _SRC, "-ljpeg", "-lpthread",
+    ]
+
+
+def lib_path() -> str:
+    """The library this source and build command produce: the key is a
+    hash of both, so nothing else on disk can stand in for it."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(_build_cmd("")).encode())
+    return os.path.join(_SRC_DIR, f"libdmlloader-{h.hexdigest()[:16]}.so")
+
+
+def _build(lib: str) -> None:
+    """Compile `lib` unless it is already there. Raises on failure
+    (CalledProcessError carries the compiler's stderr)."""
+    if os.path.exists(lib):
+        return
     # compile to a private temp path and rename into place: concurrent
     # processes (several nodes on one host) must never observe a
     # half-written .so
-    tmp = f"{_LIB}.tmp.{os.getpid()}"
-    cmd = [
-        os.environ.get("CXX", "g++"),
-        "-O3", "-march=native", "-fPIC", "-std=c++17", "-shared",
-        "-o", tmp, _SRC, "-ljpeg", "-lpthread",
-    ]
+    tmp = f"{lib}.tmp.{os.getpid()}"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB)
-        return True
-    except Exception as e:
-        stderr = getattr(e, "stderr", b"")
-        log.info("native loader build failed (%s); using PIL path. %s",
-                 e, stderr.decode(errors="replace") if stderr else "")
-        try:
+        subprocess.run(
+            _build_cmd(tmp), check=True, capture_output=True, timeout=120
+        )
+        os.replace(tmp, lib)
+    finally:
+        if os.path.exists(tmp):
             os.unlink(tmp)
-        except OSError:
-            pass
-        return False
+    # libraries under any other key are stale by construction
+    for old in glob.glob(os.path.join(_SRC_DIR, "libdmlloader*.so")):
+        if old != lib:
+            os.unlink(old)
 
 
 class NativeLoader:
-    def __init__(self, lib_path: str = _LIB):
-        self._lib = ctypes.CDLL(lib_path)
+    def __init__(self, path: str):
+        self._lib = ctypes.CDLL(path)
         self._lib.dml_decode_batch.restype = ctypes.c_int
         self._lib.dml_decode_batch.argtypes = [
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_int, ctypes.c_int,
@@ -107,12 +129,18 @@ def get_loader() -> Optional[NativeLoader]:
         if _loader is not None or _failed:
             return _loader
         try:
-            if not os.path.exists(_SRC) or not _build():
-                _failed = True
-                return None
-            _loader = NativeLoader()
-        except Exception:
-            log.exception("native loader unavailable; using PIL path")
+            lib = lib_path()
+            _build(lib)
+            _loader = NativeLoader(lib)
+        except Exception as e:
+            # once per process (_failed latches): the serving path
+            # silently becoming PIL is a finding, not a detail
+            stderr = getattr(e, "stderr", None)
+            log.warning(
+                "native JPEG loader unavailable (%r); image decode "
+                "falls back to PIL. %s", e,
+                stderr.decode(errors="replace") if stderr else "",
+            )
             _failed = True
     return _loader
 

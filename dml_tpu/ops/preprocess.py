@@ -7,9 +7,14 @@ single VMEM pass, per preprocessing mode ("caffe"/"tf"/"unit"), as
 the Pallas counterpart of `normalize_on_device` — one HBM read, one
 HBM write, no intermediate f32 image in HBM.
 
-The image is viewed as [N*H, W*3] so the lane dimension is a
+The image is viewed as [N, H, W*3] so the lane dimension is a
 multiple of 3 channels; per-channel constants are applied via a
 modulo-3 lane mask instead of a gather (TPU-friendly: iota + where).
+Only W and C merge: folding N into H as well makes XLA's TPU compiler
+spend minutes on the uint8 [N, H, W*3] -> [N*H, W*3] relayout whenever
+H is not a multiple of the 32-row uint8 tile (299, 380 — 223 s at
+[128, 299, 299, 3], measured ahead of time for a v5e), while the
+kernel itself compiles in under a second either way.
 """
 
 from __future__ import annotations
@@ -91,30 +96,15 @@ def normalize_sharded(
         return normalize_on_device(x, mode, dtype)
     if mesh is None or getattr(mesh, "empty", False):
         return fused_normalize(x, mode, dtype)
-    from functools import partial
-
-    try:  # jax >= 0.8 promotes shard_map out of experimental
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P("dp", *(None,) * (x.ndim - 1))
-    body = partial(fused_normalize, mode=mode, dtype=dtype)
-    # the pallas_call inside can't express varying-mesh-axes metadata
-    # on its out_shape, which jax>=0.8's shard_map rejects under its
-    # default check_vma=True; disable the check (the body is trivially
-    # per-shard). Older jax spells the flag check_rep.
-    try:
-        wrapped = shard_map(
-            body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-            check_vma=False,
-        )
-    except TypeError:  # pragma: no cover - older jax
-        wrapped = shard_map(
-            body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-            check_rep=False,
-        )
+    # check_vma off: the pallas_call inside cannot state varying-mesh-
+    # axes metadata on its out_shape (the body is trivially per-shard)
+    wrapped = jax.shard_map(
+        functools.partial(fused_normalize, mode=mode, dtype=dtype),
+        mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False,
+    )
     return wrapped(x)
 
 
@@ -139,19 +129,16 @@ def fused_normalize(
         raise ValueError(f"expected [N,H,W,3], got {x.shape}")
     interpret = _interpret_default() if interpret is None else interpret
     n, h, w, _ = x.shape
-    rows = n * h
     width3 = w * 3
-    x2 = x.reshape(rows, width3)
-    br = min(block_rows, rows)
-    pad = (-rows) % br
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
+    bh = min(block_rows, h)
+    # a ragged last row block (299 = 256 + 43) is Pallas's to mask: the
+    # op is elementwise, so out-of-range rows are read and dropped
     out = pl.pallas_call(
         functools.partial(_normalize_kernel, mode=mode, width3=width3),
-        grid=((rows + pad) // br,),
-        in_specs=[pl.BlockSpec((br, width3), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, width3), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(((rows + pad), width3), dtype),
+        grid=(n, pl.cdiv(h, bh)),
+        in_specs=[pl.BlockSpec((None, bh, width3), lambda i, j: (i, j, 0))],
+        out_specs=pl.BlockSpec((None, bh, width3), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, h, width3), dtype),
         interpret=interpret,
-    )(x2)
-    return out[:rows].reshape(n, h, w, 3)
+    )(x.reshape(n, h, width3))
+    return out.reshape(n, h, w, 3)

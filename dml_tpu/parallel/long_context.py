@@ -57,7 +57,6 @@ def make_lm(mesh: Mesh, seq_parallel: str = "ring", **config) -> TransformerLM:
             return attn(q, k, v, causal=causal)
     else:
         from ..ops import flash_attention
-        from .pipeline import shard_map_nocheck
 
         # GSPMD can't partition an opaque pallas_call, so place the
         # kernel per-device explicitly: batch over dp, heads over tp
@@ -86,9 +85,9 @@ def make_lm(mesh: Mesh, seq_parallel: str = "ring", **config) -> TransformerLM:
                 return flash_attention(q, k, v, causal=causal)
             # checking stays off: pallas_call out_shapes carry no vma
             # info, and the kernel is per-device pure anyway
-            return shard_map_nocheck(
+            return jax.shard_map(
                 local, mesh=mesh, in_specs=(spec, spec, spec),
-                out_specs=spec,
+                out_specs=spec, check_vma=False,
             )(q, k, v)
 
     return TransformerLM(attention=attention, mesh=mesh, **config)

@@ -37,46 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.7 exports it at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
 # stage_fn(stage_params, x_microbatch) -> y_microbatch (same shape family)
 StageFn = Callable[[Any, jax.Array], jax.Array]
-
-
-def shard_map_nocheck(f, *, mesh, in_specs, out_specs, check=False):
-    """shard_map across the jax replication-checker API rename
-    (>= 0.7 calls the kwarg ``check_vma``; 0.4.x calls it
-    ``check_rep``) — the single seam every sharded kernel in this
-    package goes through instead of spelling the try/except locally.
-    Checking defaults off: the checker rejects the masked psum-collect
-    pattern both this module and the pipelined LM serving form
-    (inference/lm_sharded.py) use. Callers whose bodies are checkable
-    (ring/ulysses reference paths) pass ``check=True`` to keep it."""
-    try:
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    except TypeError:
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check,
-        )
-
-
-def pcast_varying(x, axes):
-    """``pcast(..., to="varying")`` across the same API generations as
-    `shard_map_nocheck`: >= 0.9 spells it ``pcast``, 0.7/0.8
-    ``pvary``, and 0.4.x has no vma type system at all (``check_rep``
-    instead of ``check_vma``) — there the cast is an identity."""
-    if hasattr(jax.lax, "pcast"):  # pragma: no cover - jax >= 0.9
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):  # pragma: no cover - jax 0.7/0.8
-        return jax.lax.pvary(x, axes)
-    return x
 
 
 def stack_stage_params(per_stage: Sequence[Any]) -> Any:
@@ -158,7 +120,8 @@ def pipeline_apply(
     # compose); otherwise replicate (identical redundant compute)
     dp = mesh.shape.get("dp", 1)
     x_spec = P(None, "dp") if dp > 1 and mb % dp == 0 else P()
-    ym = shard_map_nocheck(
+    # check_vma off: the checker rejects the masked psum-collect above
+    ym = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(
@@ -166,5 +129,6 @@ def pipeline_apply(
             x_spec,  # stage 0 injects its dp-row's microbatch slice
         ),
         out_specs=x_spec,
+        check_vma=False,
     )(stacked_params, xm)
     return ym.reshape(b, *x.shape[1:])

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .pipeline import pcast_varying, shard_map_nocheck
 
 NEG_INF = -1e30
 
@@ -64,9 +63,8 @@ def _ring_attention_local(
 
     # cast-to-varying: the scan carry must be device-varying like
     # q/k/v are, or shard_map's vma type checker rejects the loop
-    # (identity on jax generations without the vma system)
     def varying(x):
-        return pcast_varying(x, (batch_axis, axis_name))
+        return jax.lax.pcast(x, (batch_axis, axis_name), to="varying")
 
     o = varying(jnp.zeros((b, h, t_local, d), jnp.float32))
     m = varying(jnp.full((b, h, t_local), NEG_INF, jnp.float32))
@@ -128,7 +126,7 @@ def _ring_attention_local_flash(
     b, t_local, h, d = q.shape
 
     def varying(x):
-        return pcast_varying(x, (batch_axis, axis_name))
+        return jax.lax.pcast(x, (batch_axis, axis_name), to="varying")
 
     out0 = varying(jnp.zeros((b, t_local, h, d), jnp.float32))
     lse0 = varying(jnp.full((b, h, t_local), NEG_INF, jnp.float32))
@@ -213,14 +211,14 @@ def ring_attention(
             _ring_attention_local, axis_name=axis_name, batch_axis="dp",
             causal=causal, scale=scale,
         )
-    fn = shard_map_nocheck(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
         # pallas_call outputs carry no vma info; the body is
         # per-device pure either way
-        check=not use_flash,
+        check_vma=not use_flash,
     )
     return fn(q, k, v)
 

@@ -48,7 +48,6 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .pipeline import shard_map_nocheck
 from .ring_attention import reference_attention
 
 
@@ -149,10 +148,10 @@ def ulysses_attention(
         _ulysses_local, axis_name=axis_name, causal=causal,
         scale=scale, use_flash=use_flash,
     )
-    return shard_map_nocheck(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check=not use_flash,
+        check_vma=not use_flash,
     )(q, k, v)

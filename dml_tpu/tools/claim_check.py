@@ -86,20 +86,30 @@ GEN_BEGIN = "<!-- BENCH-TABLE:BEGIN"
 GEN_END = "<!-- BENCH-TABLE:END -->"
 
 
-def canonical_artifact_path(parity_path: Optional[str] = None) -> str:
+def canonical_artifact_path(
+    parity_path: Optional[str] = None,
+) -> Optional[str]:
     """The artifact of record = the file PARITY's generated table is
-    stamped with (``source=...`` in the BENCH-TABLE marker)."""
+    stamped with (``source=...`` in the BENCH-TABLE marker). None when
+    the marker says ``source=none``: nothing has been measured on the
+    current installation, so there is no artifact and every unlabeled
+    perf claim in the docs is a violation."""
+    from .parity_table import NO_SOURCE
+
     parity_path = parity_path or os.path.join(REPO, "PARITY.md")
     with open(parity_path) as f:
         for line in f:
             m = re.search(r"BENCH-TABLE:BEGIN source=(\S+)", line)
             if m:
+                if m.group(1) == NO_SOURCE:
+                    return None
                 return os.path.join(REPO, m.group(1))
     raise ValueError(f"no BENCH-TABLE source marker in {parity_path}")
 
 
-def artifact_numbers(path: str) -> Dict[str, List[float]]:
-    """Kind-bucketed numeric leaves of the artifact:
+def artifact_numbers(path: Optional[str]) -> Dict[str, List[float]]:
+    """Kind-bucketed numeric leaves of the artifact (every bucket empty
+    for ``path=None``, no artifact of record):
 
     - ``ratio``: values under ratio-like keys (speedup/gain/ratio/vs)
     - ``mfu``: values under mfu/util keys, plus their ×100 percents
@@ -120,7 +130,7 @@ def artifact_numbers(path: str) -> Dict[str, List[float]]:
     capture itself."""
     from .parity_table import load_bench
 
-    data = load_bench(path)
+    data = load_bench(path) if path is not None else {}
     buckets: Dict[str, List[float]] = {
         "ratio": [], "mfu": [], "rate": [], "time": [], "size": [],
         "flops": [],
@@ -333,7 +343,8 @@ def check_metrics_block(path: str) -> List[str]:
 
 
 def run_metrics_check(artifact_path: Optional[str] = None) -> List[str]:
-    return check_metrics_block(artifact_path or canonical_artifact_path())
+    path = artifact_path or canonical_artifact_path()
+    return check_metrics_block(path) if path is not None else []
 
 
 # ----------------------------------------------------------------------
@@ -494,13 +505,11 @@ def run_chaos_check(artifact_path: Optional[str] = None) -> List[str]:
 
 
 # ----------------------------------------------------------------------
-# round-6 serving fields: adaptive pipeline depth, per-section link
-# weather, steady-state LM (bench _bench_cluster_serving /
-# _bench_cluster_lm; ISSUE 4 tentpole)
+# round-6 serving fields: adaptive pipeline depth, steady-state LM
+# (bench _bench_cluster_serving / _bench_cluster_lm; ISSUE 4 tentpole)
 # ----------------------------------------------------------------------
 
-#: first round whose bench carries the adaptive-depth verdict, the
-#: in-section link-weather probes on BOTH cluster sections, and the
+#: first round whose bench carries the adaptive-depth verdict and the
 #: steady-state LM phase; earlier artifacts predate them
 SERVING_FIELDS_REQUIRED_FROM_ROUND = 6
 
@@ -514,22 +523,9 @@ ADAPTIVE_RATIO_FLOOR = 0.9
 STEADY_MIN_S = 15.0
 
 
-def _link_weather_ok(section: Dict[str, Any]) -> bool:
-    lw = section.get("link_weather_at_section")
-    return (
-        isinstance(lw, dict)
-        and isinstance(lw.get("readback_128kb_ms"), (int, float))
-        and isinstance(lw.get("upload_mb_per_s"), (int, float))
-    )
-
-
 def check_serving_block(path: str) -> List[str]:
     """Validate the round-6 serving fields WHEN their sections ran:
 
-    - ``cluster_serving`` and ``cluster_lm_serving`` each carry an
-      in-section ``link_weather_at_section`` probe (readback latency +
-      upload bandwidth) — a 74.6-vs-220 q/s cross-capture gap must be
-      attributable, not asserted;
     - ``cluster_serving.adaptive`` records the depth controller's
       verdict, and ``pipelining_speedup`` (adaptive vs the BETTER
       forced static on the same capture) is not below the probe-noise
@@ -578,12 +574,6 @@ def check_serving_block(path: str) -> List[str]:
     not_run = set(matrix.get("_skipped", {})) | set(matrix.get("_errors", {}))
     cs = matrix.get("cluster_serving")
     if cs is not None and "cluster_serving" not in not_run:
-        if not _link_weather_ok(cs):
-            problems.append(
-                f"{name}: cluster_serving.link_weather_at_section "
-                "missing readback/upload (the q/s numbers carry no "
-                "attribution for cross-round gaps)"
-            )
         ad = cs.get("adaptive")
         if not isinstance(ad, dict) or not isinstance(
             ad.get("depth"), (int, float)
@@ -607,11 +597,6 @@ def check_serving_block(path: str) -> List[str]:
             )
     clm = matrix.get("cluster_lm_serving")
     if clm is not None and "cluster_lm_serving" not in not_run:
-        if not _link_weather_ok(clm):
-            problems.append(
-                f"{name}: cluster_lm_serving.link_weather_at_section "
-                "missing readback/upload"
-            )
         ss = clm.get("steady_state")
         if not isinstance(ss, dict):
             problems.append(
@@ -2271,58 +2256,30 @@ def check_parity_source(parity_path: Optional[str] = None) -> List[str]:
 
 def main() -> None:
     art_path = canonical_artifact_path()
-    print(f"artifact of record: {os.path.basename(art_path)}")
+    print("artifact of record: " + (
+        os.path.basename(art_path) if art_path is not None
+        else "none (not measured on the current installation)"
+    ))
     total = 0
     for name, bad in run_check().items():
         for i, line, v, unit in bad:
             total += 1
             print(f"{name}:{i}: unlabeled {v:g} {unit} not in artifact")
             print(f"    {line[:120]}")
-    for problem in run_metrics_check(art_path):
-        total += 1
-        print(f"metrics block: {problem}")
-    for problem in run_chaos_check(art_path):
-        total += 1
-        print(f"chaos block: {problem}")
-    for problem in run_serving_check(art_path):
-        total += 1
-        print(f"serving block: {problem}")
-    for problem in run_sharded_check(art_path):
-        total += 1
-        print(f"sharded block: {problem}")
-    for problem in run_lm_sharded_check(art_path):
-        total += 1
-        print(f"lm-sharded block: {problem}")
-    for problem in run_request_check(art_path):
-        total += 1
-        print(f"request block: {problem}")
-    for problem in run_tracing_check(art_path):
-        total += 1
-        print(f"tracing block: {problem}")
-    for problem in run_kv_cache_check(art_path):
-        total += 1
-        print(f"kv-cache block: {problem}")
-    for problem in run_lint_check(art_path):
-        total += 1
-        print(f"lint block: {problem}")
-    for problem in run_scale_check(art_path):
-        total += 1
-        print(f"scale block: {problem}")
-    for problem in run_elastic_check(art_path):
-        total += 1
-        print(f"elastic block: {problem}")
-    for problem in run_signal_check(art_path):
-        total += 1
-        print(f"signal block: {problem}")
-    for problem in run_autoscale_check(art_path):
-        total += 1
-        print(f"autoscale block: {problem}")
-    for problem in run_specdec_check(art_path):
-        total += 1
-        print(f"specdec block: {problem}")
-    for problem in run_train_check(art_path):
-        total += 1
-        print(f"train block: {problem}")
+    block_checks = (
+        ("metrics", run_metrics_check), ("chaos", run_chaos_check),
+        ("serving", run_serving_check), ("sharded", run_sharded_check),
+        ("lm-sharded", run_lm_sharded_check),
+        ("request", run_request_check), ("tracing", run_tracing_check),
+        ("kv-cache", run_kv_cache_check), ("lint", run_lint_check),
+        ("scale", run_scale_check), ("elastic", run_elastic_check),
+        ("signal", run_signal_check), ("autoscale", run_autoscale_check),
+        ("specdec", run_specdec_check), ("train", run_train_check),
+    )
+    for label, check in block_checks if art_path is not None else ():
+        for problem in check(art_path):
+            total += 1
+            print(f"{label} block: {problem}")
     for problem in check_parity_source():
         total += 1
         print(f"parity source: {problem}")

@@ -21,11 +21,13 @@ one. Measured MFU should land between the implied bounds — if it
 sits below the pessimistic bound, something is actually wrong (a
 layout/algorithm problem), not "the architecture".
 
-**Round-4 revision (VERDICT r3 item 8):** the single 750 GB/s stream
-constant is wrong for EfficientNet's access patterns. Microbenched on
-the v5e (``--microbench``, slope-timed isolated convs at B4's own
-shapes): depthwise convs achieve 120–360 GB/s, dense 1x1s 250–570,
-scaling with working-set size — no B4 conv class comes near 750.
+**Measured-bandwidth revision:** one stream constant (the published
+819 GB/s of `benchmarks.CHIP_PEAKS`) is wrong for EfficientNet's
+access patterns. Microbenched on a v5e in 2026-07 (``--microbench``,
+slope-timed isolated convs at B4's own shapes; not re-measured on the
+current installation): depthwise convs achieved 120–360 GB/s, dense
+1x1s 250–570, scaling with working-set size — no B4 conv class comes
+near the peak.
 ``mfu_bound_serial_measured_bw`` recomputes the serial bound with the
 measured per-class bandwidths; for B4 b128 that bound is ~0.062 and
 the isolated sum-of-parts measurement (55 unique conv shapes, counted)
@@ -50,10 +52,13 @@ import math
 import sys
 from typing import Any, Dict
 
-# measured stream bandwidth on this chip (~650-750 GB/s effective on
-# the bench lm decode path, latest BENCH_r* artifact; spec 819)
-HBM_BW = 750e9
-PEAK = 197e12  # v5e dense bf16
+from ..benchmarks import CHIP_PEAKS
+
+# the chip this analytical model describes, from the repo's one table
+# of published peaks (its measured per-class bandwidths are `eff_bw`)
+_V5E = CHIP_PEAKS["TPU v5 lite"]
+HBM_BW = _V5E["hbm_bytes_per_s"]
+PEAK = _V5E["bf16_flops"]
 
 
 def eff_bw(feature_group_count: int, spatial: int) -> float:

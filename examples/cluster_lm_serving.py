@@ -125,6 +125,9 @@ async def _leader_c1(stack):
 
 
 def main() -> None:
+    from dml_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--nodes", type=int, default=4)
     ap.add_argument("--prompts", type=int, default=8)

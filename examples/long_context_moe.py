@@ -18,6 +18,9 @@ import numpy as np
 
 
 def main() -> None:
+    from dml_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--dp", type=int, default=-1)
     p.add_argument("--sp", type=int, default=2)

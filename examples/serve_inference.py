@@ -16,6 +16,9 @@ import json
 
 
 def main() -> None:
+    from dml_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--model", default="ResNet50")
     p.add_argument("--batch-size", type=int, default=32)

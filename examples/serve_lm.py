@@ -33,6 +33,9 @@ CFG = LMConfig(vocab_size=512, d_model=128, n_heads=8, n_layers=4,
 
 
 def main() -> None:
+    from dml_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     model = TransformerLM(
         vocab_size=CFG.vocab_size, d_model=CFG.d_model,
         n_heads=CFG.n_heads, n_layers=CFG.n_layers, d_ff=CFG.d_ff,

@@ -17,6 +17,9 @@ import json
 
 
 def main() -> None:
+    from dml_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--data-dir", required=True)
     p.add_argument("--labels", required=True, help="json: {file: class_idx}")

@@ -30,11 +30,6 @@ def test_cluster_serving_bench_with_failure_injection():
     cs = out["cluster_serving"]
     assert cs["queries"] == 48
     assert cs["qps_end_to_end"] > 0
-    # VERDICT r5: the section's numbers carry their OWN link
-    # conditions, probed at section time (not the stale bring-up probe)
-    weather = cs["link_weather_at_section"]
-    assert weather["upload_mb_per_s"] > 0
-    assert weather["readback_128kb_ms"] >= 0
     bd = cs["breakdown"]
     assert bd["batches"] > 0
     assert bd["fetch_ms"] >= 0 and bd["infer_ms"] > 0
@@ -212,9 +207,6 @@ def test_cluster_lm_serving_bench():
     assert cs["prompts"] == 6
     assert cs["prompts_per_s"] > 0
     assert cs["gen_tok_per_s_end_to_end"] > 0
-    # the section carries its own link conditions (VERDICT r5)
-    lw = cs["link_weather_at_section"]
-    assert lw["upload_mb_per_s"] > 0 and lw["readback_128kb_ms"] >= 0
     # steady-state refill phase: post-ramp window covered, sustained
     # rate measured, tok/s-vs-wall curve recorded
     ss = cs["steady_state"]

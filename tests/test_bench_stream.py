@@ -119,3 +119,21 @@ def test_interrupt_unwinds_past_fail_soft_with_prior_lines_streamed():
                      stream=stream)
     assert [ln["section"] for ln in lines] == ["ok"]
     assert out["done"] == 1 and "_errors" not in out
+
+
+def test_bench_refuses_a_non_tpu_device():
+    """A bench number is a TPU number or it is nothing: on the CPU the
+    bench must fail at start, before any section runs or prints."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "bench.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=root,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert "needs a TPU" in proc.stderr
+    assert proc.stdout == ""

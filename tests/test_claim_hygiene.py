@@ -71,7 +71,21 @@ def test_checker_skips_generated_block(tmp_path):
     assert cc.check_file(str(md), buckets) == []
 
 
-def test_canonical_artifact_path_parses_parity_marker():
-    path = cc.canonical_artifact_path()
-    with open(path) as f:
-        json.load(f)  # exists and is valid JSON
+def test_canonical_artifact_path_parses_parity_marker(tmp_path):
+    parity = tmp_path / "PARITY.md"
+    parity.write_text(
+        "intro\n<!-- BENCH-TABLE:BEGIN source=BENCH_x.json sha1=abc -->\n"
+        "<!-- BENCH-TABLE:END -->\n"
+    )
+    path = cc.canonical_artifact_path(str(parity))
+    assert path.endswith("BENCH_x.json")
+    # the repo itself: nothing measured on the current installation,
+    # so no artifact of record — and every bucket a claim could match
+    # against is empty (only labeled claims pass run_check)
+    assert cc.canonical_artifact_path() is None
+    assert not any(cc.artifact_numbers(None).values())
+    parity.write_text("no marker here\n")
+    import pytest
+
+    with pytest.raises(ValueError):
+        cc.canonical_artifact_path(str(parity))

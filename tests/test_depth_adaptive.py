@@ -428,8 +428,8 @@ def test_group_ack_duplicates_freshness_gated(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# claim_check: the round-6 bench fields (link weather, adaptive
-# verdict, steady-state LM) + compact-summary / provenance plumbing
+# claim_check: the round-6 bench fields (adaptive verdict,
+# steady-state LM) + compact-summary / provenance plumbing
 # ----------------------------------------------------------------------
 
 
@@ -441,16 +441,10 @@ GOOD_CS = {
     "pipelining_speedup_static": 1.13,
     "adaptive": {"state": "settled", "depth": 2,
                  "last_probe": {"winner": 2}},
-    "link_weather_at_section": {
-        "upload_mb_per_s": 900.0, "readback_128kb_ms": 12.0,
-    },
 }
 
 GOOD_CLM = {
     "gen_tok_per_s_end_to_end": 1800.0,
-    "link_weather_at_section": {
-        "upload_mb_per_s": 900.0, "readback_128kb_ms": 12.0,
-    },
     "steady_state": {
         "measured_steady_s": 16.2,
         "gen_tok_per_s_steady": 2400.0,
@@ -482,13 +476,6 @@ def test_claim_check_serving_fields(tmp_path):
     assert cc.check_serving_block(_artifact(
         tmp_path, "BENCH_r05x", {"cluster_serving": {}}
     )) == []
-    # missing link weather on either cluster section fails
-    cs = dict(GOOD_CS)
-    cs.pop("link_weather_at_section")
-    bad = cc.check_serving_block(
-        _artifact(tmp_path, "nolw", {"cluster_serving": cs})
-    )
-    assert any("link_weather_at_section" in p for p in bad)
     # a committed depth that LOSES to a forced static beyond probe
     # noise fails the artifact (the r5 0.91x failure mode)
     bad = cc.check_serving_block(_artifact(tmp_path, "lost", {

@@ -171,7 +171,7 @@ def test_choose_dispatch_mode_picks_faster_both_ways(engine):
         n_sync = calls["sync"]
         assert engine.choose_dispatch_mode(round_spec) == "pipelined"
         assert calls["sync"] == n_sync
-        # ... but the entry EXPIRES: link weather drifts, so a
+        # ... but the entry EXPIRES: host conditions drift, so a
         # long-lived server must re-measure (ttl_s=0 forces it)
         engine.infer_arrays = orig_sync
         engine.infer_arrays_nowait = slow_nowait

@@ -324,7 +324,7 @@ def test_driver_thread_death_fails_tickets_not_hangs(params):
     srv = LMServer(params, CFG, max_slots=1, max_len=32, chunk=2)
 
     def exploding_step():
-        raise RuntimeError("tunnel fell over")
+        raise RuntimeError("device fell over")
 
     srv.step = exploding_step
     drv = LMDriver(srv)

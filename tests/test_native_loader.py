@@ -92,3 +92,60 @@ def test_load_images_uses_native_and_falls_back(tmp_path):
     # ...except PIL sniffs content, so the PNG decodes fine:
     out = load_images([str(png)], (32, 32))
     assert out.shape == (1, 32, 32, 3)
+
+
+def test_library_is_keyed_by_source_and_flags(tmp_path, monkeypatch, loader):
+    """A `.so` whose key does not match this source and build command is
+    never loaded — not a stale `libdmlloader.so` trusted by mtime, not a
+    binary that rode in with a copy of the tree from another machine."""
+    import shutil
+
+    from dml_tpu.native import loader as nl
+
+    built = nl.lib_path()
+    assert os.path.exists(built)  # the `loader` fixture built it
+    assert "-march=native" not in nl._build_cmd("x.so")
+
+    # a private source dir holding an edited source, the old name and a
+    # library under ANOTHER key, each newer than the source
+    src_dir = tmp_path / "native"
+    src_dir.mkdir()
+    with open(nl._SRC) as f:
+        (src_dir / "dataloader.cpp").write_text(f.read() + "\n// edited\n")
+    decoys = [src_dir / "libdmlloader.so",
+              src_dir / "libdmlloader-0123456789abcdef.so"]
+    for d in decoys:
+        d.write_bytes(b"not a shared object")
+    monkeypatch.setattr(nl, "_SRC_DIR", str(src_dir))
+    monkeypatch.setattr(nl, "_SRC", str(src_dir / "dataloader.cpp"))
+    monkeypatch.setattr(nl, "_loader", None)
+    monkeypatch.setattr(nl, "_failed", False)
+
+    want = nl.lib_path()
+    assert os.path.basename(want) != os.path.basename(built)  # source changed
+    assert want not in [str(d) for d in decoys]
+    got = nl.get_loader()
+    assert got is not None and os.path.exists(want)
+    # built fresh from the source at hand; the decoys were swept, not loaded
+    assert not any(d.exists() for d in decoys)
+    shutil.rmtree(src_dir)
+
+
+def test_failed_build_warns_once_with_compiler_stderr(
+        tmp_path, monkeypatch, caplog):
+    from dml_tpu.native import loader as nl
+
+    src_dir = tmp_path / "native"
+    src_dir.mkdir()
+    (src_dir / "dataloader.cpp").write_text("this is not C++\n")
+    monkeypatch.setattr(nl, "_SRC_DIR", str(src_dir))
+    monkeypatch.setattr(nl, "_SRC", str(src_dir / "dataloader.cpp"))
+    monkeypatch.setattr(nl, "_loader", None)
+    monkeypatch.setattr(nl, "_failed", False)
+    with caplog.at_level("WARNING", logger=nl.__name__):
+        assert nl.get_loader() is None
+        assert nl.get_loader() is None  # latched: no second build, no second warning
+    warnings = [r for r in caplog.records if r.levelname == "WARNING"]
+    assert len(warnings) == 1
+    assert "falls back to PIL" in warnings[0].getMessage()
+    assert "error" in warnings[0].getMessage()  # the compiler's own words
