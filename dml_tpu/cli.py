@@ -104,13 +104,17 @@ observability:
   profile metrics cluster           leader-aggregated cluster view via
                                     METRICS_PULL: per-model C1-C5 rates,
                                     counts, latency mean + p50/p95/p99
-  profile spans                     wall-clock span stats (store/job hot paths)
+  profile spans                     serve-loop span stats per name (count,
+                                    total, mean, max) over the recorder's
+                                    loop ring: LM dispatch phases, worker
+                                    stages, store ops (TRACER.summary())
   profile trace start [dir]         capture a jax.profiler (XLA) trace
   profile trace stop                stop + write the trace
   trace [dump]                      this node's flight recorder: finished
                                     request spans (bounded ring) + slowest-K
                                     + deadline-miss/shed/requeue/fallback
-                                    exemplars (dml_tpu/tracing.py)
+                                    exemplars + the serve-loop spans (a ring
+                                    of their own) (dml_tpu/tracing.py)
   trace pull [relays]               leader-aggregated cluster traces via
                                     TRACE_PULL (optionally relay-fanned)
   trace chrome [path]               export cluster traces as Chrome
@@ -327,7 +331,7 @@ class NodeApp:
             r = await j.restore_jobs(ver, force="force" in a)
             print(f"ok jobs={r['jobs']} queued_batches={r['queued_batches']}")
         elif cmd == "profile" and a:
-            from .observability import METRICS, SPANS, summarize_snapshot
+            from .observability import METRICS, summarize_snapshot
 
             if a[0] == "metrics":
                 sub = a[1] if len(a) > 1 else "summary"
@@ -351,7 +355,9 @@ class NodeApp:
                         summarize_snapshot(METRICS.snapshot()), indent=2
                     ))
             elif a[0] == "spans":
-                print(json.dumps(SPANS.summary(), indent=2))
+                from .tracing import TRACER
+
+                print(json.dumps(TRACER.summary(), indent=2))
             elif a[0] == "trace" and len(a) >= 2 and a[1] == "start":
                 import jax
 
@@ -373,7 +379,7 @@ class NodeApp:
             sub = a[0] if a else "dump"
             if sub == "dump":
                 # this node's flight recorder: ring + slowest-K +
-                # pinned exemplars, newest-last
+                # pinned exemplars + loop ring, newest-last
                 spans = trc.TRACER.dump()
                 print(json.dumps({
                     "recorder": trc.TRACER.stats(),

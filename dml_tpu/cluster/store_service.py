@@ -379,7 +379,7 @@ class StoreService:
         replication (§3.3). Retried with an idempotency token: a
         duplicate PUT_REQUEST joins the in-flight request (or re-fetches
         the completed reply) instead of minting a second version."""
-        from ..observability import span
+        from ..tracing import TRACER
 
         local_path = os.path.abspath(os.path.expanduser(local_path))
         if not os.path.isfile(local_path):
@@ -388,7 +388,10 @@ class StoreService:
         t0 = time.monotonic()
         t0_wall = time.time()
         try:
-            with span("store.put"):
+            with TRACER.loop_span(
+                "store_op_put", node=self.node.me.unique_name,
+                file=sdfs_name,
+            ):
                 reply = await self._leader_retry(
                     MsgType.PUT_REQUEST,
                     {
@@ -417,12 +420,15 @@ class StoreService:
         """`get <sdfs> <local>` — download one version (latest default)
         from any live replica (reference get_file_locally,
         worker.py:1323-1354). Returns the version fetched."""
-        from ..observability import span
+        from ..tracing import TRACER
 
         t0 = time.monotonic()
         t0_wall = time.time()
         try:
-            with span("store.get"):
+            with TRACER.loop_span(
+                "store_op_get", node=self.node.me.unique_name,
+                file=sdfs_name,
+            ):
                 got = await self._get_impl(
                     sdfs_name, local_path, version, timeout
                 )

@@ -33,6 +33,7 @@ import numpy as np
 log = logging.getLogger(__name__)
 
 from ..jobs.cost_model import ModelCost
+from ..tracing import current_all_ctxs
 from .generate import LMConfig
 from .lm_server import LMDriver, LMServer
 
@@ -299,11 +300,16 @@ class LMBackend:
                     f"max_len {self.server.max_len}"
                 )
         cbs = self._token_cbs(paths, on_token)
+        # a traced ingress request's `lm_request` span hangs under the
+        # worker's `infer` span: the job service keys the batch's trace
+        # contexts by local path (contextvars ride asyncio.to_thread)
+        by_path = {c.key: c for c in current_all_ctxs() if c.sampled}
+        trace = [by_path.get(p) for p in paths] if by_path else None
         if self.overlap:
             t0 = time.monotonic()
             toks = self.driver.serve(
                 prompts, budgets, on_dispatch=on_dispatch,
-                on_token=cbs,
+                on_token=cbs, trace=trace,
             )
             infer_time = time.monotonic() - t0
             results = {
@@ -317,7 +323,7 @@ class LMBackend:
                 # it must not inflate the scheduler's per_query model
                 t0 = time.monotonic()
                 rids = self.server.submit_many(
-                    prompts, budgets, on_token=cbs
+                    prompts, budgets, on_token=cbs, trace=trace
                 )
                 # run(rids): drain only OUR requests — a bare run()
                 # would also consume (and discard) results of any

@@ -83,6 +83,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..observability import METRICS
+from ..tracing import TRACER, TraceContext
 from .generate import (
     LMConfig,
     _sample,
@@ -100,7 +101,10 @@ log = logging.getLogger(__name__)
 # prefill programs, at per-DISPATCH granularity (a step covers
 # chunk × slots tokens), so the decode path's device rate is
 # unaffected. Handles are bound once at import: no name lookups on
-# the hot path.
+# the hot path. Beside them the serve loop records loop spans
+# (tracing.Tracer.loop_span: one per dispatch PHASE, never per token
+# or per slot), which say where inside a dispatch the host's time went
+# and which the JAX profiler shows as `dml.lm_*` annotations.
 _M_REQS = METRICS.counter(
     "lm_server_requests_total", "requests submitted to the slot grid")
 _M_REQS_DONE = METRICS.counter(
@@ -110,22 +114,40 @@ _M_TOKENS = METRICS.counter(
     "generated tokens delivered to request outputs")
 _M_STEPS = METRICS.counter(
     "lm_server_steps_total", "chunked decode dispatches")
-_M_COMPILES = METRICS.counter(
-    "lm_server_compile_events_total",
-    "first-seen dispatch shapes per server (upper bound on XLA "
-    "compilations; jit caches may dedupe across servers)")
 _M_QUEUE_WAIT = METRICS.histogram(
     "lm_server_queue_wait_seconds", "submit -> slot placement wait")
 _M_PREFILL = METRICS.histogram(
     "lm_server_prefill_dispatch_seconds",
-    "host wall of one placement group's prefill + insert + merge "
-    "dispatch chain (async dispatch; device time shows up in step)")
+    "host wall of one placement group's ENQUEUE chain (build, prefill, "
+    "inserts, first-token sample, merges: the `lm_prefill_group` span). "
+    "Dispatch is async: this is not the prefill's device time, which "
+    "the next step's readback waits out")
+_M_PREFILL_TOKENS = METRICS.counter(
+    "lm_server_prefill_tokens_total",
+    "tokens sent through group prefills by kind=: prompt (the "
+    "requests' own tokens) and padded (group rows x bucket, what the "
+    "device computed)")
+_M_PREFILL_PROMPT = _M_PREFILL_TOKENS.labels(kind="prompt")
+_M_PREFILL_PADDED = _M_PREFILL_TOKENS.labels(kind="padded")
+_M_FIRST_TOKEN = METRICS.histogram(
+    "lm_server_first_token_seconds",
+    "slot placement -> the request's first token VALUE on the host "
+    "(it rides the next dispatch's packed readback)")
 _M_STEP = METRICS.histogram(
     "lm_server_step_seconds",
     "one chunked decode step incl. its packed readback")
+_M_PACK = METRICS.histogram(
+    "lm_server_pack_seconds",
+    "issuing a dispatch's packed readback: one eager concatenate whose "
+    "arity varies, so it may load or compile a program")
 _M_READBACK = METRICS.histogram(
     "lm_server_readback_seconds",
-    "blocking device->host readbacks (the serve loop's only stalls)")
+    "blocking device->host readbacks (the serve loop's only stalls); "
+    "a decode dispatch's excludes issuing the pack")
+_M_DELIVER = METRICS.histogram(
+    "lm_server_deliver_seconds",
+    "a dispatch's token delivery: first tokens, every request's "
+    "on_token callbacks, retirements")
 _M_SLOTS = METRICS.gauge(
     "lm_server_slots_active", "occupied decode slots")
 _M_SLOTS_TOTAL = METRICS.gauge(
@@ -178,6 +200,13 @@ class _Request:
     emitted: int = 0
     slot: Optional[int] = None
     t_submit: float = 0.0  # monotonic submit time (queue-wait metric)
+    # monotonic placement time and the time the first token's VALUE
+    # reached the host (0.0 = not yet): the `lm_request` span's events
+    t_placed: float = 0.0
+    t_first: float = 0.0
+    # where the request's `lm_request` span hangs: the worker's `infer`
+    # span of a traced ingress request, else None (a trace of its own)
+    ctx: Optional[TraceContext] = None
     # per-request delivery callback (ingress token streaming): fired
     # with each token VALUE the moment it is read back to the host —
     # the decode grid's per-token stream source. Never on the device
@@ -213,6 +242,20 @@ class _Request:
                     # level: this fires per token and a broken stream
                     # callback would flood anything louder
                     log.debug("on_token callback failed: %r", e)
+        # once per call, never per token: the first call stamps the
+        # first token, the one that fills the budget closes the span
+        if self.t_first == 0.0:
+            self.t_first = time.monotonic()
+            _M_FIRST_TOKEN.observe(self.t_first - self.t_placed)
+        if len(self.out) >= self.max_new_tokens:
+            labels = {} if self.slot is None else {"slot": self.slot}
+            TRACER.loop_record(
+                "lm_request", self.t_submit, time.monotonic(), self.ctx,
+                events=(("placed", self.t_placed),
+                        ("first_token", self.t_first)),
+                prompt_tokens=int(self.prompt.size),
+                new_tokens=len(self.out), **labels,
+            )
 
     @property
     def done(self) -> bool:
@@ -363,10 +406,6 @@ class LMServer:
         # (rid, position) streams the chunk sampler continues)
         # prefill's logits are already [rows, vocab] (_head squeezes)
         self._sample_first = jax.jit(self._sample_slots)
-        # compile-event accounting: first-seen dispatch shapes on THIS
-        # server (each distinct shape costs one XLA compilation unless
-        # a jit/persistent cache already holds it)
-        self._seen_shapes: set = set()
         # worker-resident KV prefix cache (inference/kv_cache.py),
         # enable_kv_cache wires both; None (the default) keeps the
         # serve path bit-identical to a cache-less build
@@ -741,6 +780,8 @@ class LMServer:
         prompts: Sequence[np.ndarray],
         max_new_tokens,
         on_token: Optional[Sequence[Optional[Callable[[int], None]]]] = None,
+        trace: Optional[Sequence[Optional[TraceContext]]] = None,
+        parent: Any = None,
     ) -> List[int]:
         """Queue a burst of requests and place them in ONE batched
         round. `max_new_tokens` is an int shared by the burst or a
@@ -752,7 +793,11 @@ class LMServer:
 
         `on_token` is an optional per-prompt sequence of callbacks;
         request i's callback fires with each of its token values as
-        they are read back (the ingress per-token stream source)."""
+        they are read back (the ingress per-token stream source).
+        `trace` is an optional per-prompt sequence of trace contexts
+        (request i's `lm_request` span hangs under its context) and
+        `parent` the loop span that caused the burst (the driver's
+        `lm_submit`), under which its placement is recorded."""
         if isinstance(max_new_tokens, (int, np.integer)):
             budgets = [int(max_new_tokens)] * len(prompts)
         else:
@@ -766,6 +811,10 @@ class LMServer:
                 f"{len(on_token)} on_token callbacks for "
                 f"{len(prompts)} prompts"
             )
+        if trace is not None and len(trace) != len(prompts):
+            raise ValueError(
+                f"{len(trace)} trace contexts for {len(prompts)} prompts"
+            )
         validated = [
             self._validate(p, b) for p, b in zip(prompts, budgets)
         ]
@@ -776,10 +825,11 @@ class LMServer:
             reqs.append(_Request(
                 self._rid, prompt, b, t_submit=now,
                 on_token=on_token[i] if on_token is not None else None,
+                ctx=trace[i] if trace is not None else None,
             ))
         _M_REQS.inc(len(reqs))
         self._queue.extend(reqs)
-        self._place_waiting()
+        self._place_waiting(parent)
         return [r.rid for r in reqs]
 
     def free_slot_count(self) -> int:
@@ -871,6 +921,7 @@ class LMServer:
         ``first_token`` is host-side, so it lands in the output
         directly with no pending readback."""
         tp = req.prompt.size
+        req.t_placed = time.monotonic()
         # rebuild the [1, KV, max_len, ...] insert-shaped tree: values
         # pad the T axis (2), kv_quant scales carry T on lanes (3)
         pcache = {}
@@ -936,12 +987,17 @@ class LMServer:
             self._spec.draft_cache, pcache, jnp.int32(slot),
             jnp.int32(0),
         )
-        shape = ("draft_prefill", bucket, 1)
-        if shape not in self._seen_shapes:
-            self._seen_shapes.add(shape)
-            _M_COMPILES.inc()
 
-    def _place_waiting(self) -> None:
+    def _place_waiting(self, parent: Any = None) -> None:
+        """Free slots take queued requests, recorded as one `lm_place`
+        span under `parent` (the dispatch's `lm_step`, the driver's
+        `lm_submit`, or none) that holds one `lm_prefill_group` span
+        per bucket group."""
+        with TRACER.loop_span("lm_place", parent) as span:
+            span.label(requests=self._place_waiting_in(span))
+
+    def _place_waiting_in(self, span: Any) -> int:
+        """`_place_waiting`'s body; returns how many requests it placed."""
         # Placement is FULLY ASYNC and GROUP-BATCHED: free slots take
         # queued requests bucket-by-bucket, each bucket group running
         # ONE batched prefill (rows padded to a power-of-two group
@@ -958,7 +1014,8 @@ class LMServer:
             if self._slot_req[slot] is None and self._queue:
                 pairs.append((slot, self._queue.pop(0)))
         if not pairs:
-            return
+            return 0
+        placed = len(pairs)
         if self._warm is not None and self.temperature == 0.0:
             # KV-prefix warm starts intercept placement REQUEST BY
             # REQUEST: a prompt extending a cached prefix adopts the
@@ -984,14 +1041,29 @@ class LMServer:
                 _M_SLOTS.set(
                     sum(1 for r in self._slot_req if r is not None)
                 )
-                return
+                return placed
         groups: Dict[int, List[Tuple[int, _Request]]] = {}
         for slot, req in pairs:
             b = min(_bucket(req.prompt.size), self.max_len)
             groups.setdefault(b, []).append((slot, req))
         for bucket, grp in groups.items():
-            t_grp0 = time.monotonic()
-            k = len(grp)
+            self._place_group(bucket, grp, span)
+        _M_SLOTS.set(sum(1 for r in self._slot_req if r is not None))
+        return placed
+
+    def _place_group(
+        self, bucket: int, grp: List[Tuple[int, _Request]], parent: Any
+    ) -> None:
+        """One bucket group's placement: ONE batched prefill, one
+        row-indexed insert per request, one batched first-token sample
+        and the masked merges, all enqueued without waiting for the
+        device (the `lm_prefill_group` span and the
+        `lm_server_prefill_dispatch_seconds` observation are that
+        enqueue chain's host wall)."""
+        k = len(grp)
+        with TRACER.loop_span(
+            "lm_prefill_group", parent, bucket=bucket, rows=k
+        ) as span:
             # group-row padding policy: short buckets pad straight to
             # max_slots — ONE prefill compilation per bucket, which a
             # 1-prompt warmup already covers (distinct (bucket, rows)
@@ -1049,10 +1121,6 @@ class LMServer:
                         self._spec.draft_cache, dpcache,
                         jnp.int32(slot), jnp.int32(row),
                     )
-                dshape = ("draft_prefill", bucket, kp)
-                if dshape not in self._seen_shapes:
-                    self._seen_shapes.add(dshape)
-                    _M_COMPILES.inc()
             # first generated tokens occupy position tp — the same
             # (rid, position) streams the chunk sampler continues
             firsts = self._sample_first(
@@ -1066,21 +1134,22 @@ class LMServer:
             self._pending_first.append(
                 ([req for _, req in grp], firsts)
             )
-            now = time.monotonic()
-            shape = ("prefill", bucket, kp)
-            if shape not in self._seen_shapes:
-                self._seen_shapes.add(shape)
-                _M_COMPILES.inc()
-            _M_PREFILL.observe(now - t_grp0)
-            for slot, req in grp:
-                _M_QUEUE_WAIT.observe(now - req.t_submit)
-                req.emitted = 1
-                req.slot = slot
-                self._slot_req[slot] = req
-                self.rid_vec[slot] = req.rid
-                if req.done:  # max_new_tokens == 1
-                    self._retire(slot)
-        _M_SLOTS.set(sum(1 for r in self._slot_req if r is not None))
+            prompt_tokens = int(tps[:k].sum())
+            span.label(padded_rows=kp, prompt_tokens=prompt_tokens,
+                       padded_tokens=kp * bucket)
+        now = span.m1
+        _M_PREFILL.observe(now - span.m0)
+        _M_PREFILL_PROMPT.inc(prompt_tokens)
+        _M_PREFILL_PADDED.inc(kp * bucket)
+        for slot, req in grp:
+            _M_QUEUE_WAIT.observe(now - req.t_submit)
+            req.t_placed = now
+            req.emitted = 1
+            req.slot = slot
+            self._slot_req[slot] = req
+            self.rid_vec[slot] = req.rid
+            if req.done:  # max_new_tokens == 1
+                self._retire(slot)
 
     def _retire(self, slot: int) -> None:
         req = self._slot_req[slot]
@@ -1149,9 +1218,9 @@ class LMServer:
             return
         entries = self._pending_first
         self._pending_first = []
-        t0 = time.monotonic()
-        vals = np.asarray(jnp.concatenate([v for _, v in entries]))
-        _M_READBACK.observe(time.monotonic() - t0)
+        with TRACER.loop_span("lm_readback", arrays=len(entries)) as rb:
+            vals = np.asarray(jnp.concatenate([v for _, v in entries]))
+        _M_READBACK.observe(rb.m1 - rb.m0)
         self._distribute_firsts(entries, vals, 0)
         flushed = sum(len(reqs) for reqs, _ in entries)
         self.tokens_delivered += flushed
@@ -1169,13 +1238,12 @@ class LMServer:
             self._place_waiting()
             if not any(r is not None for r in self._slot_req):
                 return
-        _M_OCCUPANCY.observe(
-            sum(1 for r in self._slot_req if r is not None)
-        )
-        if self._use_spec():
-            self._spec_step()
-        else:
-            self._chunk_step()
+        occupancy = sum(1 for r in self._slot_req if r is not None)
+        _M_OCCUPANCY.observe(occupancy)
+        dispatch = self._spec_step if self._use_spec() else self._chunk_step
+        with TRACER.loop_span("lm_step", occupancy=occupancy) as span:
+            dispatch(span)
+        _M_STEP.observe(span.m1 - span.m0)
 
     def _use_spec(self) -> bool:
         """Per-DISPATCH host gate for the speculative round. False
@@ -1205,20 +1273,21 @@ class LMServer:
                 return False
         return True
 
-    def _spec_step(self) -> None:
+    def _spec_step(self, step: Any) -> None:
         """One speculative round: propose k tokens per slot, verify
         all of them in ONE multi-token target forward, commit 1..k
         target-greedy tokens per slot. Same packed-readback
         discipline as `_chunk_step` — committed tokens + accept
         lengths + any deferred placement firsts ride ONE blocking
-        readback."""
-        t_step0 = time.monotonic()
+        readback — and the same phase spans under `step`, with one
+        `lm_dispatch` for the proposals and one for the verify."""
         sp = self._spec
         k = sp.k
         b = self.max_slots
         firsts = self._pending_first
         self._pending_first = []
         real = [False] * b  # slots whose proposals count toward rate
+        propose = TRACER.loop_span("lm_dispatch", step, phase="propose")
         if sp.draft_params is not None:
             # device draft: proposals never leave the chip. A shipped
             # draft is redundant here (the local draft re-proposes) —
@@ -1227,9 +1296,6 @@ class LMServer:
                 if r is not None:
                     r.shipped_draft = None
                     real[r.slot] = True
-            if "spec_propose" not in self._seen_shapes:
-                self._seen_shapes.add("spec_propose")
-                _M_COMPILES.inc()
             sp.draft_cache, d_toks = self._propose_fn(
                 sp.draft_params, sp.draft_cache,
                 self._cur_dev, self._pos_dev,
@@ -1260,49 +1326,52 @@ class LMServer:
                     d[r.slot] = row
                     real[r.slot] = True
             d_toks = jnp.asarray(d)
-        if "spec_verify" not in self._seen_shapes:
-            self._seen_shapes.add("spec_verify")
-            _M_COMPILES.inc()
-        (
-            self.cache, self._cur_dev, self._pos_dev, toks, acc
-        ) = self._verify_fn(
-            self.params, self.cache, self._cur_dev, self._pos_dev,
-            d_toks,
+        propose.end()
+        with TRACER.loop_span("lm_dispatch", step, phase="verify"):
+            (
+                self.cache, self._cur_dev, self._pos_dev, toks, acc
+            ) = self._verify_fn(
+                self.params, self.cache, self._cur_dev, self._pos_dev,
+                d_toks,
+            )
+        packed = self._read_packed(
+            step, [jnp.ravel(toks), acc] + [v for _, v in firsts]
         )
-        t_rb0 = time.monotonic()
-        packed = np.asarray(jnp.concatenate(
-            [jnp.ravel(toks), acc] + [v for _, v in firsts]
-        ))
-        _M_READBACK.observe(time.monotonic() - t_rb0)
         n = b * k
-        tokm = packed[:n].reshape(b, k)
-        accs = packed[n : n + b]
-        # same pre-callback occupancy snapshot as _chunk_step: an
-        # on_token adoption mid-delivery must wait for the next
-        # dispatch, not consume this round's stale verify column
-        live = list(enumerate(self._slot_req))
-        self._distribute_firsts(firsts, packed, n + b)
-        delivered = sum(len(reqs) for reqs, _ in firsts)
-        prop_n = acc_n = 0
-        for slot, req in live:
-            if req is None:
-                continue
-            a = int(accs[slot])
-            c = min(a + 1, k)
-            take = min(c, req.max_new_tokens - req.emitted)
-            req.deliver(tokm[slot, :take])
-            req.emitted += take
-            delivered += take
-            if real[slot]:
-                prop_n += k
-                acc_n += a
-                req.spec_rounds += 1
-                req.spec_accepted += a
-            # take < c ⇒ retire; device cur/pos overran the budget,
-            # erased by the next insert (the _insert_impl invariant —
-            # same discipline as the chunk path)
-            if req.done:
-                self._retire(slot)
+        first_n = sum(len(reqs) for reqs, _ in firsts)
+        with TRACER.loop_span("lm_deliver", step) as deliver:
+            tokm = packed[:n].reshape(b, k)
+            accs = packed[n : n + b]
+            # same pre-callback occupancy snapshot as _chunk_step: an
+            # on_token adoption mid-delivery must wait for the next
+            # dispatch, not consume this round's stale verify column
+            live = list(enumerate(self._slot_req))
+            self._distribute_firsts(firsts, packed, n + b)
+            delivered = first_n
+            retired = 0
+            prop_n = acc_n = 0
+            for slot, req in live:
+                if req is None:
+                    continue
+                a = int(accs[slot])
+                c = min(a + 1, k)
+                take = min(c, req.max_new_tokens - req.emitted)
+                req.deliver(tokm[slot, :take])
+                req.emitted += take
+                delivered += take
+                if real[slot]:
+                    prop_n += k
+                    acc_n += a
+                    req.spec_rounds += 1
+                    req.spec_accepted += a
+                # take < c ⇒ retire; device cur/pos overran the budget,
+                # erased by the next insert (the _insert_impl invariant
+                # — same discipline as the chunk path)
+                if req.done:
+                    self._retire(slot)
+                    retired += 1
+            deliver.label(tokens=delivered, retired=retired)
+        _M_DELIVER.observe(deliver.m1 - deliver.m0)
         sp.rounds += 1
         if prop_n:
             _M_SPEC_PROPOSED.inc(prop_n)
@@ -1329,70 +1398,86 @@ class LMServer:
                     # workload, not the lifetime average
                     sp.win_proposed //= 2
                     sp.win_accepted //= 2
-        self._place_waiting()
+        self._finish_step(step, delivered, first_n)
+
+    def _read_packed(self, step: Any, arrays: List[jax.Array]) -> np.ndarray:
+        """ONE packed readback per step: the dispatch's tokens plus any
+        placement first tokens deferred since the last one. cur/pos
+        never come back to the host (device-authoritative). Two phases
+        under `step`: `lm_pack` issues the eager concatenate, whose
+        arity varies with the placement groups pending, so it may load
+        or compile a program; `lm_readback` is the blocking np.asarray,
+        which stalls the host until the device drains and is the ONLY
+        such stall in the serve loop."""
+        with TRACER.loop_span("lm_pack", step, arrays=len(arrays)) as pack:
+            packed = jnp.concatenate(arrays)
+        with TRACER.loop_span("lm_readback", step) as readback:
+            out = np.asarray(packed)
+        _M_PACK.observe(pack.m1 - pack.m0)
+        _M_READBACK.observe(readback.m1 - readback.m0)
+        return out
+
+    def _finish_step(self, step: Any, delivered: int, first_n: int) -> None:
+        """A dispatch's tail: freed slots take waiting requests (the
+        continuous-batching join point), then the per-dispatch counts."""
+        self._place_waiting(step)
         self.tokens_delivered += delivered
         _M_TOKENS.inc(delivered)
         _M_STEPS.inc()
         _M_SLOTS.set(sum(1 for r in self._slot_req if r is not None))
-        _M_STEP.observe(time.monotonic() - t_step0)
+        step.label(tokens=delivered, firsts=first_n)
 
-    def _chunk_step(self) -> None:
-        """The plain chunked-scan dispatch (step()'s pre-spec body)."""
-        t_step0 = time.monotonic()
+    def _chunk_step(self, step: Any) -> None:
+        """The plain chunked-scan dispatch (step()'s pre-spec body),
+        as five phase spans under `step`: `lm_dispatch`, `lm_pack`,
+        `lm_readback`, `lm_deliver`, `lm_place`."""
         firsts = self._pending_first
         self._pending_first = []
-        if "chunk" not in self._seen_shapes:
-            self._seen_shapes.add("chunk")
-            _M_COMPILES.inc()
-        self.cache, self._cur_dev, self._pos_dev, toks = self._chunk_fn(
-            self.params, self.cache, self._cur_dev, self._pos_dev,
-            jnp.asarray(self.rid_vec),
+        with TRACER.loop_span("lm_dispatch", step):
+            self.cache, self._cur_dev, self._pos_dev, toks = self._chunk_fn(
+                self.params, self.cache, self._cur_dev, self._pos_dev,
+                jnp.asarray(self.rid_vec),
+            )
+        packed = self._read_packed(
+            step, [jnp.ravel(toks)] + [v for _, v in firsts]
         )
-        # ONE packed readback per step — chunk tokens plus any
-        # placement first tokens deferred since the last one. cur/pos
-        # never come back to the host (device-authoritative); each
-        # blocking np.asarray stalls the host until the device drains,
-        # and this is now the ONLY one in the serve loop
-        t_rb0 = time.monotonic()
-        packed = np.asarray(jnp.concatenate(
-            [jnp.ravel(toks)] + [v for _, v in firsts]
-        ))
-        _M_READBACK.observe(time.monotonic() - t_rb0)
         n = self.chunk * self.max_slots
-        toks = packed[:n].reshape(self.chunk, self.max_slots)
-        # snapshot occupancy BEFORE any deliver() fires user callbacks:
-        # a callback may adopt a prefilled request (submit_prefilled)
-        # into a slot this step freed — or never occupied — and a live
-        # iteration would then hand the adoptee THIS dispatch's stale
-        # column. The adoptee decodes from the NEXT dispatch; its
-        # placement already delivered the slab's first token exactly
-        # once (tests/test_specdec.py pins the race).
-        live = list(enumerate(self._slot_req))
-        self._distribute_firsts(firsts, packed, n)
         # deferred first tokens ride this readback: they are delivered
         # tokens of this step (the chunk takes below cover budget - 1
         # of each request, the placement-time first covers the rest)
-        delivered = sum(len(reqs) for reqs, _ in firsts)
-        for slot, req in live:
-            if req is None:
-                continue
-            take = min(self.chunk, req.max_new_tokens - req.emitted)
-            req.deliver(toks[:take, slot])
-            req.emitted += take
-            delivered += take
-            # take < chunk ⇒ the request retires here; the slot's
-            # device cur/pos ran past its budget, which the next
-            # insert's full overwrite erases (the _insert_impl
-            # invariant) — an ACTIVE continuation always has
-            # take == chunk, so device and host never disagree
-            if req.done:
-                self._retire(slot)
-        self._place_waiting()
-        self.tokens_delivered += delivered
-        _M_TOKENS.inc(delivered)
-        _M_STEPS.inc()
-        _M_SLOTS.set(sum(1 for r in self._slot_req if r is not None))
-        _M_STEP.observe(time.monotonic() - t_step0)
+        first_n = sum(len(reqs) for reqs, _ in firsts)
+        with TRACER.loop_span("lm_deliver", step) as deliver:
+            toks = packed[:n].reshape(self.chunk, self.max_slots)
+            # snapshot occupancy BEFORE any deliver() fires user
+            # callbacks: a callback may adopt a prefilled request
+            # (submit_prefilled) into a slot this step freed — or never
+            # occupied — and a live iteration would then hand the
+            # adoptee THIS dispatch's stale column. The adoptee decodes
+            # from the NEXT dispatch; its placement already delivered
+            # the slab's first token exactly once
+            # (tests/test_specdec.py pins the race).
+            live = list(enumerate(self._slot_req))
+            self._distribute_firsts(firsts, packed, n)
+            delivered = first_n
+            retired = 0
+            for slot, req in live:
+                if req is None:
+                    continue
+                take = min(self.chunk, req.max_new_tokens - req.emitted)
+                req.deliver(toks[:take, slot])
+                req.emitted += take
+                delivered += take
+                # take < chunk ⇒ the request retires here; the slot's
+                # device cur/pos ran past its budget, which the next
+                # insert's full overwrite erases (the _insert_impl
+                # invariant) — an ACTIVE continuation always has
+                # take == chunk, so device and host never disagree
+                if req.done:
+                    self._retire(slot)
+                    retired += 1
+            deliver.label(tokens=delivered, retired=retired)
+        _M_DELIVER.observe(deliver.m1 - deliver.m0)
+        self._finish_step(step, delivered, first_n)
 
     def has_work(self) -> bool:
         """True while any request is queued or occupying a slot."""
@@ -1463,6 +1548,12 @@ class _Ticket:
     # per-prompt token-delivery callbacks (ingress streaming), passed
     # through to LMServer.submit_many
     on_token: Optional[Sequence[Optional[Callable[[int], None]]]] = None
+    # per-prompt trace contexts, passed through likewise
+    trace: Optional[Sequence[Optional[TraceContext]]] = None
+    # monotonic time the caller queued the ticket: a ticket waits while
+    # the driver thread is inside a decode dispatch, which no other
+    # clock sees (`lm_submit`'s label `ticket_wait_s`)
+    t_queued: float = dataclasses.field(default_factory=time.monotonic)
     rids: Optional[List[int]] = None
     remaining: int = 0
     results: Optional[Dict[int, np.ndarray]] = None
@@ -1532,6 +1623,7 @@ class LMDriver:
         on_token: Optional[
             Sequence[Optional[Callable[[int], None]]]
         ] = None,
+        trace: Optional[Sequence[Optional[TraceContext]]] = None,
     ) -> List[np.ndarray]:
         """Blocking: decode `prompts`, return their completions in
         order. `max_new_tokens` is an int or a per-prompt sequence
@@ -1541,13 +1633,16 @@ class LMDriver:
         pipeline can start preparing its next batch from that point,
         not from completion. `on_token` (per-prompt callbacks, fired
         on the driver thread per delivered token) streams each
-        request's tokens as they read back."""
+        request's tokens as they read back. `trace` (per-prompt trace
+        contexts) hangs each request's `lm_request` span under the
+        span that caused it."""
         t = _Ticket(
             prompts=[np.asarray(p, np.int32).reshape(-1) for p in prompts],
             max_new_tokens=max_new_tokens,
             event=threading.Event(),
             on_dispatch=on_dispatch,
             on_token=on_token,
+            trace=trace,
         )
         with self._cv:
             if self._stop:
@@ -1620,12 +1715,12 @@ class LMDriver:
         srv = self.server
         while True:
             with self._cv:
-                while (
-                    not self._incoming
-                    and not srv.has_work()
-                    and not self._stop
-                ):
-                    self._cv.wait()
+                if not (self._incoming or srv.has_work() or self._stop):
+                    with TRACER.loop_span("lm_idle"):
+                        while not (
+                            self._incoming or srv.has_work() or self._stop
+                        ):
+                            self._cv.wait()
                 if self._stop and not self._incoming and not srv.has_work():
                     return
                 new = self._incoming
@@ -1635,32 +1730,14 @@ class LMDriver:
             # preemption must fully drain before the driver touches
             # the grid
             with self._server_lock:
-                for t in new:
-                    try:
-                        # validation failures reject the WHOLE ticket
-                        # before any of its prompts queue (submit_many
-                        # is atomic), so a bad prompt file can't leave
-                        # siblings decoding into a discarded result
-                        t.rids = srv.submit_many(
-                            t.prompts, t.max_new_tokens,
-                            on_token=t.on_token,
-                        )
-                        t.remaining = len(t.rids)
-                        t.results = {}
-                        for rid in t.rids:
-                            self._owner[rid] = t
-                        if t.remaining == 0:
-                            t.event.set()
-                    except Exception as e:
-                        t.error = e
-                        t.event.set()
-                        continue
-                    if t.on_dispatch is not None:
-                        try:
-                            t.on_dispatch()
-                        except Exception as e:
-                            # a pipeline hint, never a decode error
-                            log.warning("on_dispatch hook failed: %r", e)
+                if new:
+                    with TRACER.loop_span(
+                        "lm_submit", tickets=len(new),
+                        requests=sum(len(t.prompts) for t in new),
+                    ) as span:
+                        span.label(ticket_wait_s=round(sum(
+                            span.m0 - t.t_queued for t in new), 6))
+                        self._submit_tickets(new, span)
                 if srv.has_work():
                     srv.step()
                     with self._cv:
@@ -1675,3 +1752,34 @@ class LMDriver:
                 if t.remaining == 0:
                     self.tickets_served += 1
                     t.event.set()
+
+    def _submit_tickets(self, new: List[_Ticket], span: Any) -> None:
+        """Hand the tickets taken this round to the server (under the
+        server lock, inside the round's `lm_submit` span)."""
+        srv = self.server
+        for t in new:
+            try:
+                # validation failures reject the WHOLE ticket
+                # before any of its prompts queue (submit_many
+                # is atomic), so a bad prompt file can't leave
+                # siblings decoding into a discarded result
+                t.rids = srv.submit_many(
+                    t.prompts, t.max_new_tokens,
+                    on_token=t.on_token, trace=t.trace, parent=span,
+                )
+                t.remaining = len(t.rids)
+                t.results = {}
+                for rid in t.rids:
+                    self._owner[rid] = t
+                if t.remaining == 0:
+                    t.event.set()
+            except Exception as e:
+                t.error = e
+                t.event.set()
+                continue
+            if t.on_dispatch is not None:
+                try:
+                    t.on_dispatch()
+                except Exception as e:
+                    # a pipeline hint, never a decode error
+                    log.warning("on_dispatch hook failed: %r", e)

@@ -83,6 +83,10 @@ _M_DEADLINE_MISS = METRICS.counter(
 _M_QWAIT = METRICS.histogram(
     "request_queue_wait_seconds",
     "admission -> batch dispatch wait, per class")
+_M_WORKER_WAIT = METRICS.histogram(
+    "request_worker_wait_seconds",
+    "of a request's formation: the wait in a batch whose linger had run "
+    "out and that was still held because no worker was free, per class")
 _M_E2E = METRICS.histogram(
     "request_e2e_latency_seconds",
     "admission -> completion end-to-end latency, per class")
@@ -159,6 +163,10 @@ class PendingRequest:
     #: requests whose relay predates tracing
     arrival_wall: float = 0.0
     ctx: Optional[TraceContext] = None
+    #: of the formation wait, the seconds this request sat in a batch
+    #: that only the want of a free worker still held (set when
+    #: ``BatchFormer.due`` pops its batch)
+    worker_wait: float = 0.0
 
 
 @dataclass
@@ -170,6 +178,20 @@ class FormingBatch:
     affinity: Optional[str]
     opened_at: float
     reqs: List[PendingRequest] = field(default_factory=list)
+    #: the first ``due()`` that found the linger run out and still held
+    #: the batch because its model's pipeline was not hungry (no free
+    #: worker, or batches of the model queued ahead); None until then
+    held_since: Optional[float] = None
+
+    def release(self, t: float) -> "FormingBatch":
+        """Stamp each request's ``worker_wait`` as the batch leaves at
+        ``t``: from when the batch was first held for want of a worker
+        (or from the request's own arrival, if later) to now."""
+        if self.held_since is not None:
+            for r in self.reqs:
+                r.worker_wait = max(
+                    0.0, t - max(self.held_since, r.arrival))
+        return self
 
 
 class BatchFormer:
@@ -260,20 +282,22 @@ class BatchFormer:
                 out.append(FormingBatch(
                     model=fb.model, slo=fb.slo, affinity=fb.affinity,
                     opened_at=fb.opened_at, reqs=fb.reqs[:size],
-                ))
+                    held_since=fb.held_since,
+                ).release(t))
                 fb.reqs = fb.reqs[size:]
             if not fb.reqs:
                 del self.forming[key]
                 continue
             slack_out = t >= self._dispatch_by(fb)
-            feed = (
+            lingered = (
                 self.mode == "continuous"
-                and fb.model in hungry
                 and t - fb.opened_at >= fb.slo.linger_s * self.linger_scale
             )
-            if slack_out or feed:
+            if slack_out or (lingered and fb.model in hungry):
                 del self.forming[key]
-                out.append(fb)
+                out.append(fb.release(t))
+            elif lingered and fb.held_since is None:
+                fb.held_since = t
         return out
 
 
@@ -794,13 +818,15 @@ class RequestRouter:
                 st.dispatched_wall = now_wall
             ids.append(r.id)
             _M_QWAIT.observe(now - r.arrival, slo=r.slo.name)
+            _M_WORKER_WAIT.observe(r.worker_wait, slo=r.slo.name)
             if r.ctx is not None:
                 # formation span: admission -> this dispatch (the
                 # front-door queue wait, wall-clocked)
                 TRACER.start_span(
                     "formation", ctx=r.ctx, node=self._me,
                     t0=r.arrival_wall,
-                    labels={"job": job_id, "slo": r.slo.name},
+                    labels={"job": job_id, "slo": r.slo.name,
+                            "worker_wait": round(r.worker_wait, 6)},
                 ).end(now_wall)
         self._by_job[job_id] = ids
         _M_FILL.observe(len(reqs) / self._batch_size_of(fb.model))
@@ -1089,7 +1115,11 @@ class RequestRouter:
         dispatch walls plus the batch ACK's carried stage timings
         (``JobState.stage_timing``) — available on a real multi-
         process cluster too, where the worker's spans live on the
-        worker. ``dispatch`` is the residual between dispatch and
+        worker. ``worker_wait`` is the part of ``formation`` spent in
+        a batch whose linger had run out and that only the want of a
+        free worker still held (``FormingBatch.release``); the rest of
+        ``formation`` is the linger and the tick. ``dispatch`` is the
+        residual between dispatch and
         completion not explained by the worker's measured exec
         (scheduler queue + wire + ACK latency), floored at zero."""
         r = state.req
@@ -1097,6 +1127,10 @@ class RequestRouter:
         if state.dispatched_wall and r.arrival_wall:
             stages["formation"] = max(
                 0.0, state.dispatched_wall - r.arrival_wall
+            )
+            # monotonic, where formation is wall-clocked: never more
+            stages["worker_wait"] = min(
+                r.worker_wait, stages["formation"]
             )
         timing = getattr(st, "stage_timing", None) or {}
         fetch = float(timing.get("fetch", 0.0))

@@ -288,8 +288,15 @@ class JobService:
         # restarts at 1, so workers compare seqs only within one
         # incarnation (keyed per sender as (inc, last_seq))
         self._incarnation = int(time.time() * 1000)
-        self._assigned_at: Dict[str, Tuple[Tuple[int, int], float]] = {}
-        self._staged_at: Dict[str, Tuple[Tuple[int, int], float]] = {}
+        # worker -> (batch key, LAST send, FIRST send), monotonic: the
+        # resend loop runs off the last send, the dispatch->ACK wall
+        # off the first (a batch that outlasts `task_resend_after` is
+        # re-sent every such interval, and a wall taken from the last
+        # re-send would never exceed it)
+        self._assigned_at: Dict[
+            str, Tuple[Tuple[int, int], float, float]] = {}
+        self._staged_at: Dict[
+            str, Tuple[Tuple[int, int], float, float]] = {}
         # coordinator-side per-batch wall-time breakdown from ACKs
         # (fetch / backend / infer) — where cluster-serving time goes
         self.batch_timing: Deque[Dict[str, float]] = deque(maxlen=512)
@@ -982,10 +989,14 @@ class JobService:
                 if reps:
                     b.replicas[f] = reps
                 versions[f] = self.store.metadata.latest_version(f)
-        if staged:
-            self._staged_at[worker] = (b.key, time.monotonic())
-        else:
-            self._assigned_at[worker] = (b.key, time.monotonic())
+        now = time.monotonic()
+        sent = self._staged_at if staged else self._assigned_at
+        prev = sent.get(worker)
+        sent[worker] = (
+            b.key, now,
+            prev[2] if prev is not None and prev[0] == b.key else now,
+        )
+        if not staged:
             if b.traces:
                 # close the scheduler-side `dispatch` span on the
                 # FIRST real send: `q` (stamped by the router at
@@ -1186,13 +1197,13 @@ class JobService:
         if cost:
             self._fold_cost(d.get("model", ""), cost)
         at = self._assigned_at.get(msg.sender)
+        dispatch_to_ack: Optional[float] = None
         if at is not None and at[0] == (job_id, batch_id):
             # the cross-check's unforgeable side: OUR wall between
-            # dispatch and this ACK, paired with the worker's self-
-            # reported exec wall inside the payload
-            self.signal.observe_ack(
-                msg.sender, time.monotonic() - at[1], d
-            )
+            # the batch's first dispatch and this ACK, paired with the
+            # worker's self-reported exec wall inside the payload
+            dispatch_to_ack = time.monotonic() - at[2]
+            self.signal.observe_ack(msg.sender, dispatch_to_ack, d)
             del self._assigned_at[msg.sender]
         sat = self._staged_at.get(msg.sender)
         if sat is not None and sat[0] == (job_id, batch_id):
@@ -1268,6 +1279,12 @@ class JobService:
                 "stage_wait": float(d.get("stage_wait_time", 0.0)),
                 "put": float(d.get("put_time", 0.0)),
                 "n": int(d.get("n_images", 0)),
+                # the leader's own wall from the first send of
+                # WORKER_TASK_REQUEST (a staged batch's: of its stage)
+                # to this ACK, where this ACK answers the assignment
+                # on record
+                **({"dispatch_to_ack": dispatch_to_ack}
+                   if dispatch_to_ack is not None else {}),
             })
         sb = self.store.standby_node()
         if sb is not None and sb.unique_name != self._me:
@@ -2125,13 +2142,20 @@ class JobService:
     ) -> None:
         import dataclasses as _dc
 
-        from ..observability import span
-
         fanout: Optional[_StreamFanout] = None
-        ctx_token = None
+        ctx_token = infer_token = None
         trace_ctxs: List[TraceContext] = []
+        infer_spans: List[Any] = []
+        # the batch's three stages as loop spans of one trace (always
+        # on, and `dml.worker_*` annotations in a device trace); the
+        # sampled requests' own fetch/infer/put spans are cut from the
+        # same walls
+        stages = TraceContext(TRACER.new_trace_id())
+        labels = {"job": batch.job_id, "batch": batch.batch_id}
         try:
-            with span("worker.fetch_inputs"):
+            with TRACER.loop_span(
+                "worker_fetch", stages, node=self._me, **labels
+            ):
                 if prep is None:
                     (paths, imgs, t_fetch, t_decode, t0,
                      t_prep_end) = await self._prepare(batch)
@@ -2208,7 +2232,30 @@ class JobService:
                 fanout = _StreamFanout(self, batch, paths)
             stream_kw = {"on_token": fanout.on_token} if fanout else {}
             infer_wall0 = time.time()
-            with span("worker.inference"):
+            # the sampled requests' `infer` spans are opened BEFORE the
+            # backend call so that what the backend records for a
+            # request (its `lm_request`) has a parent to name; the
+            # contextvar points there for the call alone
+            infer_spans = [
+                TRACER.start_span(
+                    "infer", ctx=c, node=self._me, t0=infer_wall0,
+                    labels={**labels, "model": batch.model,
+                            "shared": len(batch.files)},
+                )
+                for c in trace_ctxs
+            ]
+            infer_of = {
+                c.key: _dc.replace(sp.ctx(), key=c.key)
+                for c, sp in zip(trace_ctxs, infer_spans)
+            }
+            if infer_spans:
+                infer_token = CURRENT_CTXS.set(tuple(
+                    infer_of.get(c.key, c) for c in CURRENT_CTXS.get()
+                ))
+            with TRACER.loop_span(
+                "worker_infer", stages, node=self._me, model=batch.model,
+                **labels,
+            ):
                 if group_serving:
                     # formed-group PRIMARY: serve on the group's
                     # sharded engine (jobs/groups.py). The ACK
@@ -2264,19 +2311,16 @@ class JobService:
                 fanout.close()
             t_backend = (time.monotonic() - t1) + t_decode
             _M_INFER.observe(infer_time)
+            if infer_token is not None:
+                CURRENT_CTXS.reset(infer_token)
+                infer_token = None
             infer_wall1 = time.time()
-            for c in trace_ctxs:
+            for sp in infer_spans:
                 # the span covers the backend CALL wall (the request
                 # sat in this stage that long); the device-only
                 # portion rides as a label
-                TRACER.start_span(
-                    "infer", ctx=c, node=self._me, t0=infer_wall0,
-                    labels={"job": batch.job_id,
-                            "batch": batch.batch_id,
-                            "model": batch.model,
-                            "infer_s": round(infer_time, 6),
-                            "shared": len(batch.files)},
-                ).end(infer_wall1)
+                sp.label(infer_s=round(infer_time, 6))
+                sp.end(infer_wall1)
             # backends key results by the LOCAL path (the engine uses
             # the full path, others may use the basename), which
             # differs by how the input materialized (store-replica hit
@@ -2299,34 +2343,37 @@ class JobService:
                 blob = json.dumps(results)
                 if len(blob) <= 40_000:
                     inline_payload = results
-            t_put0 = time.monotonic()
-            if inline_payload is None:
-                out_name = f"output_{batch.job_id}_{batch.batch_id}_{self.node.me.port}.json"
-                tmp = os.path.join(self.store.cfg.download_path(), out_name)
-                os.makedirs(os.path.dirname(tmp), exist_ok=True)
-                with open(tmp, "w") as f:
-                    json.dump(results, f)
-                try:
-                    # timeout scales with the cluster's RPC envelope
-                    # (capped at the old fixed 60 s): a worker wedged
-                    # publishing output under churn holds its batch
-                    # un-ACKed (and the job un-finishable) far past an
-                    # aggressive-timing cluster's whole recovery window
-                    await self.store.put(
-                        tmp, out_name,
-                        timeout=min(
-                            60.0,
-                            4 * self.node.spec.timing.leader_rpc_timeout,
-                        ),
-                    )
-                except Exception as e:
-                    # store unavailable (e.g. mid-failover): the ACK
-                    # still carries the result timing; get-output will
-                    # miss this shard, which the reference tolerates
-                    # identically
-                    log.warning("%s: PUT of %s failed: %s",
-                                self._me, out_name, e)
-            t_put = time.monotonic() - t_put0
+            with TRACER.loop_span(
+                "worker_put", stages, node=self._me,
+                inline=int(inline_payload is not None), **labels,
+            ) as put_stage:
+                if inline_payload is None:
+                    out_name = f"output_{batch.job_id}_{batch.batch_id}_{self.node.me.port}.json"
+                    tmp = os.path.join(self.store.cfg.download_path(), out_name)
+                    os.makedirs(os.path.dirname(tmp), exist_ok=True)
+                    with open(tmp, "w") as f:
+                        json.dump(results, f)
+                    try:
+                        # timeout scales with the cluster's RPC envelope
+                        # (capped at the old fixed 60 s): a worker wedged
+                        # publishing output under churn holds its batch
+                        # un-ACKed (and the job un-finishable) far past an
+                        # aggressive-timing cluster's whole recovery window
+                        await self.store.put(
+                            tmp, out_name,
+                            timeout=min(
+                                60.0,
+                                4 * self.node.spec.timing.leader_rpc_timeout,
+                            ),
+                        )
+                    except Exception as e:
+                        # store unavailable (e.g. mid-failover): the ACK
+                        # still carries the result timing; get-output will
+                        # miss this shard, which the reference tolerates
+                        # identically
+                        log.warning("%s: PUT of %s failed: %s",
+                                    self._me, out_name, e)
+            t_put = put_stage.m1 - put_stage.m0
             _M_PUT.observe(t_put)
             put_wall1 = time.time()
             for c in trace_ctxs:
@@ -2391,6 +2438,10 @@ class JobService:
             # coordinator's on_batch_failed does the same promotion)
             self._promote_staged()
         finally:
+            for sp in infer_spans:
+                sp.end()  # no-op when closed; a failed call closes here
+            if infer_token is not None:
+                CURRENT_CTXS.reset(infer_token)
             if ctx_token is not None:
                 CURRENT_CTXS.reset(ctx_token)
             if fanout is not None:

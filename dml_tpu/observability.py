@@ -124,7 +124,8 @@ totals equal the (shared) registry instead of multiplying by the node
 count, while real one-process-per-node deployments sum normally.
 
 Also here, unchanged from the seed: ``profile()`` (jax.profiler trace
-context), ``span()`` (wall-clock spans), ``jsonl_logging()``.
+context) and ``jsonl_logging()``. Wall-clock spans live in ONE place,
+``tracing.TRACER`` (request spans and serve-loop spans).
 
 Metric map (lint-enforced)
 --------------------------
@@ -181,9 +182,12 @@ line when you add the metric.
     lm_kv_cache_hits_total           warm starts from cached prefixes
     lm_kv_cache_misses_total         lookups with no usable prefix
     lm_kv_cache_tokens_saved_total   prompt tokens not re-prefilled
-    lm_server_compile_events_total   decode-graph compile events
     lm_server_decode_tokens_total    tokens decoded (all slots)
-    lm_server_prefill_dispatch_seconds  prefill dispatch wall
+    lm_server_deliver_seconds        a dispatch's token delivery + callbacks
+    lm_server_first_token_seconds    placement -> first token value on host
+    lm_server_pack_seconds           issuing a dispatch's packed readback
+    lm_server_prefill_dispatch_seconds  a prefill group's enqueue-chain wall
+    lm_server_prefill_tokens_total   prefilled tokens by kind= prompt|padded
     lm_server_queue_wait_seconds     request queue wait
     lm_server_readback_seconds       device->host readback stalls
     lm_server_requests_completed_total  LM requests finished
@@ -217,6 +221,7 @@ line when you add the metric.
     request_e2e_latency_seconds      admission -> completion latency
     request_in_flight                admitted, not yet terminal
     request_queue_wait_seconds       admission -> dispatch wait
+    request_worker_wait_seconds      of formation: linger over, no worker free
     request_rejected_total           post-admission typed rejections
     request_session_affinity_evictions_total  session rows aged out
     request_session_affinity_hits_total  sessions routed to KV holder
@@ -242,7 +247,7 @@ line when you add the metric.
     store_report_delta_total         inventory re-reports by kind
     store_write_failures_total       local write failures (ENOSPC etc.)
     tracing_exemplars_total          tail-exemplar span captures by kind
-    tracing_spans_dropped_total      flight-recorder ring evictions
+    tracing_spans_dropped_total      ring evictions (ring=loop: loop spans)
     tracing_spans_total              finished spans observed by sampled=
     train_effective_batch            shard_batch x world by run=
     train_resharding_total           ckpt-restore re-shards by reason=
@@ -277,7 +282,6 @@ import os
 import threading
 import time
 import weakref
-from collections import defaultdict
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 # ----------------------------------------------------------------------
@@ -862,7 +866,7 @@ def bench_metrics_block() -> Dict[str, Any]:
 
 
 # ----------------------------------------------------------------------
-# jax profiling + wall-clock spans + JSONL logging (seed surface)
+# jax profiling + JSONL logging (seed surface)
 # ----------------------------------------------------------------------
 
 
@@ -878,39 +882,6 @@ def profile(logdir: str) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-class Spans:
-    """Process-wide wall-clock span registry (mean/count per label)."""
-
-    def __init__(self):
-        self._acc: Dict[str, List[float]] = defaultdict(list)
-
-    @contextlib.contextmanager
-    def span(self, label: str) -> Iterator[None]:
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self._acc[label].append(time.monotonic() - t0)
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        out = {}
-        for label, xs in sorted(self._acc.items()):
-            out[label] = {
-                "count": float(len(xs)),
-                "total_s": sum(xs),
-                "mean_s": sum(xs) / len(xs),
-                "max_s": max(xs),
-            }
-        return out
-
-    def reset(self) -> None:
-        self._acc.clear()
-
-
-SPANS = Spans()
-span = SPANS.span
 
 
 class _JsonFormatter(logging.Formatter):

@@ -84,7 +84,9 @@ so fixture trees exercise them selectively):
   marker no test uses is flagged (the mirror only stays honest while
   every entry is load-bearing).
 - ``drift-span-names`` — every literal ``start_span("<name>", ...)``
-  call site in the tree must use a name declared in
+  call site in the tree, and every ``loop_span("<name>", ...)`` /
+  ``loop_record("<name>", ...)`` (serve-loop spans), must use a name
+  declared in
   ``dml_tpu/tracing.py``'s ``SPAN_NAMES`` registry (the stage
   vocabulary the tail-attribution table reports); a registered name no
   call site emits is flagged, and a NON-literal span name in
@@ -841,12 +843,18 @@ def rule_summary(root: str, trees: Dict[str, ast.Module]) -> List[Finding]:
 
 TRACING_REL = "dml_tpu/tracing.py"
 
+#: the recorder's span-opening calls, each taking the span's name
+#: first: a request span, a serve-loop span, a loop span recorded
+#: after the fact
+_SPAN_CALLS = ("start_span", "loop_span", "loop_record")
+
 
 def collect_span_call_sites(
     trees: Dict[str, ast.Module],
 ) -> Tuple[Dict[str, List[Tuple[str, int]]], List[Tuple[str, int]]]:
     """-> (span name -> [(path, line), ...] for every LITERAL
-    ``start_span("<name>", ...)`` call, [(path, line), ...] of
+    ``start_span("<name>", ...)`` / ``loop_span("<name>", ...)`` /
+    ``loop_record("<name>", ...)`` call, [(path, line), ...] of
     non-literal call sites). tracing.py itself is excluded — its
     generic machinery passes names through variables by design."""
     literal: Dict[str, List[Tuple[str, int]]] = {}
@@ -857,7 +865,7 @@ def collect_span_call_sites(
         for node in ast.walk(tree):
             if not isinstance(node, ast.Call):
                 continue
-            if _call_name(node.func) != "start_span":
+            if _call_name(node.func) not in _SPAN_CALLS:
                 continue
             name_arg: Optional[ast.AST] = (
                 node.args[0] if node.args else None
@@ -926,18 +934,18 @@ def check_span_names(
         if name not in registry:
             path, line = sites[0]
             f(path, line, f"unregistered:{name}",
-              f"start_span({name!r}) uses a span name not declared in "
+              f"a span is opened as {name!r}, a name not declared in "
               "tracing.SPAN_NAMES — add it to the registry first, or "
               "the attribution table silently drops this stage")
     for name, line in sorted(registry.items()):
         if name not in literal and name not in tracing_literals:
             f(tracing_rel, line, f"unused:{name}",
-              f"SPAN_NAMES entry {name!r} has no start_span call site "
+              f"SPAN_NAMES entry {name!r} has no span-opening call site "
               "— a stage the table reports but nothing ever emits")
     for path, line in dynamic:
         if path.startswith("dml_tpu/"):
             f(path, line, f"dynamic:{path}:{line}",
-              "start_span with a non-literal name cannot be checked "
+              "a span opened with a non-literal name cannot be checked "
               "against SPAN_NAMES — pass the registry constant "
               "directly so the stage vocabulary stays closed")
     return fs

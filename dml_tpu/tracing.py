@@ -29,6 +29,16 @@ This module is the per-request causality layer:
   carrying a tail-exemplar event (``deadline_miss`` / ``shed`` /
   ``requeue`` / ``fallback``): the exemplars that explain the tail are
   never sampled away.
+- **Loop spans** (``Tracer.loop_span``) — spans of the SERVE LOOP,
+  whose work belongs to no single request: one decode dispatch and its
+  phases on the LM serving thread, a worker's batch stages, a store
+  operation. Always on, never sampled, kept in a bounded ring of their
+  own (a burst of requests cannot evict them, nor they a request's
+  spans), timed on ``time.monotonic()`` and placed on the wall clock by
+  ONE offset taken when the ``Tracer`` is made (``wall_of``), and
+  entered into the JAX profiler as ``TraceAnnotation("dml.<name>")`` so
+  that a device trace shows them on the profiler's own clock beside
+  the device's operations. ``summary()`` folds the ring per name.
 - **TRACE_PULL** (cluster/node.py) — leader aggregation of every
   node's recorder with the same tier-by-tier datagram degradation as
   METRICS_PULL; ``assemble_traces`` stitches the pulled spans into
@@ -61,6 +71,7 @@ import contextvars
 import hashlib
 import itertools
 import secrets
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -76,9 +87,10 @@ from .observability import METRICS
 #: the root span of a request's trace: admission -> terminal
 SPAN_ROOT = "request"
 
-#: Every name ``start_span(...)`` may emit, and therefore every stage
-#: the attribution table can report. tools/dmllint.py cross-checks all
-#: ``start_span("<literal>", ...)`` call sites in the tree against
+#: Every name ``start_span(...)`` / ``loop_span(...)`` /
+#: ``loop_record(...)`` may emit, and therefore every stage the
+#: attribution table can report. tools/dmllint.py cross-checks all
+#: literal call sites in the tree against
 #: this tuple — add the name HERE first, or the build fails. Keep the
 #: comment on each line: it is the one place the stage vocabulary is
 #: documented.
@@ -99,7 +111,27 @@ SPAN_NAMES = (
     "store_get",   # replicated store GET under a request's trace
     "result",      # job completion -> REQUEST_DONE push
     "marker",      # zero-duration exemplar marker (note_exemplar)
+    # -- loop spans (Tracer.loop_span: serve loop, not per request) --
+    "worker_fetch",      # worker: a batch's replica fetch + host decode
+    "worker_infer",      # worker: a batch's backend call
+    "worker_put",        # worker: a batch's output write + store PUT
+    "store_op_put",      # replicated store PUT, every one (untraced too)
+    "store_op_get",      # replicated store GET, every one (untraced too)
+    "lm_idle",           # LM driver thread waiting with no work
+    "lm_submit",         # LM driver: submit_many of the tickets taken this round
+    "lm_step",           # one decode dispatch, entry to exit
+    "lm_dispatch",       # enqueue of the chunk (or propose/verify) program
+    "lm_pack",           # issuing the packed readback's eager concatenate
+    "lm_readback",       # the blocking np.asarray: host waits for device
+    "lm_deliver",        # first tokens + req.deliver callbacks + retirements
+    "lm_place",          # _place_waiting: free slots take queued requests
+    "lm_prefill_group",  # one bucket group's build/prefill/insert/sample/merge
+    "lm_request",        # LM request: submit -> last token on the host
 )
+
+#: the loop ring's size: ten minutes at the chat cell's rate (about 10
+#: spans a decode dispatch x 3.7 dispatches/s = 22,200; PERF.md §5)
+LOOP_SPAN_BUDGET = 32768
 
 #: span events that force always-on exemplar capture: any span ending
 #: with one of these pins its whole trace in the recorder regardless
@@ -114,7 +146,9 @@ _M_SPANS = METRICS.counter(
     "finished spans observed by the flight recorder, by sampled=")
 _M_DROPPED = METRICS.counter(
     "tracing_spans_dropped_total",
-    "sampled spans evicted from the flight-recorder ring")
+    "spans evicted from a flight-recorder ring: sampled request spans "
+    "(no label) and serve-loop spans (ring=loop)")
+_M_DROPPED_LOOP = _M_DROPPED.labels(ring="loop")
 _M_EXEMPLARS = METRICS.counter(
     "tracing_exemplars_total",
     "tail-exemplar span captures, by kind= (deadline_miss|shed|...)")
@@ -229,6 +263,80 @@ class Span:
         return d
 
 
+#: ``jax.profiler.TraceAnnotation``, bound the first time a loop span
+#: is opened in a process that has JAX loaded (never imported from
+#: here: a control-plane node without JAX records the span and skips
+#: the annotation)
+_TRACE_ANNOTATION: Any = None
+
+
+def _annotate(name: str) -> Any:
+    """An entered profiler annotation ``dml.<name>``, or None where
+    JAX is not loaded. While no profiler trace runs this is one flag
+    test inside ``TraceMe``; while one runs, the span is in the same
+    ``.xplane.pb`` as the device's operations, on the profiler's own
+    clock."""
+    global _TRACE_ANNOTATION
+    cls = _TRACE_ANNOTATION
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation as cls
+
+        _TRACE_ANNOTATION = cls
+    ann = cls("dml." + name)
+    ann.__enter__()
+    return ann
+
+
+class LoopSpan:
+    """One live serve-loop span (``Tracer.loop_span``): opened where it
+    is made, recorded exactly once by ``end()`` or the context-manager
+    exit. ``m0``/``m1`` are ``time.monotonic()`` readings; the recorded
+    ``t0``/``t1`` are those plus the tracer's one wall offset. Has the
+    ``trace_id``/``span_id`` pair a child span takes as its parent."""
+
+    __slots__ = (
+        "trace_id", "span_id", "parent_id", "name", "node", "m0", "m1",
+        "labels", "events", "_tracer", "_ann",
+    )
+
+    def __init__(
+        self, tracer: "Tracer", name: str, parent: Any, node: str,
+        labels: Dict[str, Any], annotate: bool = True,
+    ):
+        self._tracer = tracer
+        self.name = name
+        # `parent` is whatever caused the span (a LoopSpan, a Span, a
+        # TraceContext: each has the pair); without one it roots a trace
+        self.trace_id = (tracer.new_trace_id() if parent is None
+                         else parent.trace_id)
+        self.parent_id = "" if parent is None else parent.span_id
+        self.span_id = tracer._new_span_id()
+        self.node = node
+        self.labels = labels
+        self.events: List[List[Any]] = []
+        self.m1: Optional[float] = None
+        self.m0 = time.monotonic()
+        self._ann = _annotate(name) if annotate else None
+
+    def label(self, **labels: Any) -> None:
+        self.labels.update(labels)
+
+    def end(self) -> None:
+        if self.m1 is not None:
+            return  # idempotent, like Span.end
+        self.m1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._record_loop(self)
+
+    def __enter__(self) -> "LoopSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
 #: batch-scoped contexts for code that cannot thread them through its
 #: call signature (store put/get under a worker's fetch, the LM group
 #: backends' prefill/handoff/decode internals): the service sets this
@@ -270,14 +378,22 @@ class Tracer:
         span_budget: int = 4096,
         slow_k: int = 32,
         exemplar_traces: int = 256,
+        loop_budget: int = LOOP_SPAN_BUDGET,
     ):
         self._lock = threading.Lock()
         self._salt = secrets.token_hex(3)
         self._span_counter = itertools.count(1)
         self._trace_counter = itertools.count(1)
+        # the ONE mapping from the loop spans' clock to the wall clock
+        # the request spans and the profiler's host plane stamp: taken
+        # once, so a wall clock that is stepped later can neither shrink
+        # nor stretch a span, and every loop span lines up with every
+        # other
+        self._wall_offset = time.time() - time.monotonic()
         self.configure(
             sample_rate=sample_rate, seed=seed, span_budget=span_budget,
             slow_k=slow_k, exemplar_traces=exemplar_traces,
+            loop_budget=loop_budget,
         )
 
     def configure(
@@ -287,10 +403,11 @@ class Tracer:
         span_budget: Optional[int] = None,
         slow_k: Optional[int] = None,
         exemplar_traces: Optional[int] = None,
+        loop_budget: Optional[int] = None,
     ) -> None:
         """(Re)configure knobs; omitted arguments keep their value.
-        Changing ``span_budget`` re-bounds the ring, carrying over the
-        newest spans that still fit."""
+        Changing ``span_budget`` (or ``loop_budget``) re-bounds that
+        ring, carrying over the newest spans that still fit."""
         with self._lock:
             if sample_rate is not None:
                 self.sample_rate = min(1.0, max(0.0, float(sample_rate)))
@@ -311,10 +428,18 @@ class Tracer:
                 self.max_exemplar_traces = max(4, int(exemplar_traces))
                 self._exemplars: "OrderedDict[str, List[Dict[str, Any]]]" \
                     = OrderedDict(getattr(self, "_exemplars", ()))
+            if loop_budget is not None:
+                self.loop_budget = max(16, int(loop_budget))
+                old = list(getattr(self, "_loop_ring", ()))
+                self._loop_ring: "deque[Dict[str, Any]]" = deque(
+                    old[-self.loop_budget:], maxlen=self.loop_budget
+                )
             if not hasattr(self, "dropped"):
                 self.dropped = 0
                 self.peak_spans = 0
                 self.recorded = 0
+                self.loop_dropped = 0
+                self.loop_recorded = 0
 
     # -- identity + sampling ------------------------------------------
 
@@ -372,6 +497,87 @@ class Tracer:
             self.head_sample(trace_id) if sampled is None else sampled,
             t0=t0, labels=labels, span_id=span_id,
         )
+
+    # -- loop spans ---------------------------------------------------
+
+    def wall_of(self, monotonic_s: float) -> float:
+        """Where a ``time.monotonic()`` reading lies on the spans' wall
+        clock (``t0``/``t1``): the reading plus the one offset taken
+        when this tracer was made. A reader with a window in monotonic
+        seconds maps it through here and guesses nothing."""
+        return monotonic_s + self._wall_offset
+
+    def loop_span(
+        self, name: str, parent: Any = None, *, node: str = "",
+        **labels: Any,
+    ) -> LoopSpan:
+        """Open a serve-loop span (use as a context manager, or call
+        ``end()``). ``parent`` is whatever caused it — a ``LoopSpan``,
+        a ``Span`` or a ``TraceContext`` — and gives the trace id and
+        the parent span id; without one the span roots a new trace, so
+        all spans of one decode dispatch share the dispatch's trace id.
+        Always recorded (no sampling), into the loop ring. Names MUST
+        come from ``SPAN_NAMES`` (dmllint cross-checks every literal
+        call site). Budget: under 20 us of host with no profiler
+        running — nothing per token may call this."""
+        return LoopSpan(self, name, parent, node, labels)
+
+    def loop_record(
+        self, name: str, m0: float, m1: float, parent: Any = None, *,
+        node: str = "",
+        events: Sequence[Tuple[str, float]] = (),
+        **labels: Any,
+    ) -> None:
+        """Record a loop span after the fact from two monotonic
+        readings (an interval that many dispatches overlap, like an LM
+        request's life in the grid: it is no stack frame of the serve
+        loop, so it carries no profiler annotation). ``events`` are
+        (name, monotonic seconds) instants inside it."""
+        span = LoopSpan(self, name, parent, node, labels, annotate=False)
+        span.m0, span.m1 = float(m0), float(m1)
+        span.events = [[n, float(m)] for n, m in events]
+        self._record_loop(span)
+
+    def _record_loop(self, span: LoopSpan) -> None:
+        off = self._wall_offset
+        d: Dict[str, Any] = {
+            "tid": span.trace_id, "sid": span.span_id,
+            "par": span.parent_id, "name": span.name, "node": span.node,
+            "t0": round(span.m0 + off, 6), "t1": round(span.m1 + off, 6),
+            "loop": 1,
+        }
+        if span.labels:
+            d["lb"] = span.labels
+        if span.events:
+            d["ev"] = [[n, round(m + off, 6)] for n, m in span.events]
+        with self._lock:
+            self.loop_recorded += 1
+            if len(self._loop_ring) == self.loop_budget:
+                self.loop_dropped += 1
+                _M_DROPPED_LOOP.inc()
+            self._loop_ring.append(d)
+
+    def loop_spans(self, name: Optional[str] = None) -> List[Dict[str, Any]]:
+        """The loop ring, oldest first (``name`` filters)."""
+        with self._lock:
+            rows = list(self._loop_ring)
+        if name is None:
+            return rows
+        return [d for d in rows if d["name"] == name]
+
+    def summary(self) -> Dict[str, Dict[str, float]]:
+        """count / total / mean / max seconds per loop-span name over
+        the ring (the CLI's ``profile spans``)."""
+        acc: Dict[str, List[float]] = {}
+        for d in self.loop_spans():
+            acc.setdefault(d["name"], []).append(d["t1"] - d["t0"])
+        return {
+            name: {
+                "count": float(len(xs)), "total_s": sum(xs),
+                "mean_s": sum(xs) / len(xs), "max_s": max(xs),
+            }
+            for name, xs in sorted(acc.items())
+        }
 
     def _record(self, span: Span) -> None:
         d = span.to_dict()
@@ -438,12 +644,14 @@ class Tracer:
         strip: bool = False,
     ) -> List[Dict[str, Any]]:
         """Finished spans this node holds: the ring, the slowest-K
-        roots, and every pinned exemplar trace, deduped by span id,
-        newest-last. ``trace_ids`` filters; ``max_spans`` keeps the
-        NEWEST — except exemplar-trace spans, which survive the cut
-        first (the recorder pinned them against ring eviction; a
-        collection cap must not un-pin them, or a deadline miss early
-        in a long run loses exactly the trace that explains it).
+        roots, every pinned exemplar trace and the loop ring, deduped
+        by span id, newest-last. ``trace_ids`` filters; ``max_spans``
+        keeps the NEWEST — except exemplar-trace spans, which survive
+        the cut first (the recorder pinned them against ring eviction;
+        a collection cap must not un-pin them, or a deadline miss early
+        in a long run loses exactly the trace that explains it). Under
+        a cap the two rings stay apart as they do in memory: loop spans
+        take at most half of it unless the request spans leave more.
         ``strip`` drops labels/events (the datagram-degraded form)."""
         want = set(trace_ids) if trace_ids is not None else None
         with self._lock:
@@ -451,6 +659,7 @@ class Tracer:
             rows.extend(d for _, d in self._slow)
             for spans in self._exemplars.values():
                 rows.extend(spans)
+            rows.extend(self._loop_ring)
             pinned_tids = set(self._exemplars)
         seen: set = set()
         out: List[Dict[str, Any]] = []
@@ -463,13 +672,19 @@ class Tracer:
             out.append(d)
         out.sort(key=lambda d: (d["t0"], d["sid"]))
         if max_spans is not None and len(out) > max_spans:
-            ex = [d for d in out if d["tid"] in pinned_tids]
-            if len(ex) >= max_spans:
-                out = ex[-max_spans:]
+            loops = [d for d in out if "loop" in d]
+            reqs = [d for d in out if "loop" not in d]
+            n_loop = min(len(loops),
+                         max(max_spans // 2, max_spans - len(reqs)))
+            room = max_spans - n_loop
+            ex = [d for d in reqs if d["tid"] in pinned_tids]
+            if len(ex) >= room:
+                reqs = ex[len(ex) - room:]
             else:
-                rest = [d for d in out if d["tid"] not in pinned_tids]
-                out = rest[-(max_spans - len(ex)):] + ex
-                out.sort(key=lambda d: (d["t0"], d["sid"]))
+                rest = [d for d in reqs if d["tid"] not in pinned_tids]
+                reqs = rest[len(rest) - (room - len(ex)):] + ex
+            out = reqs + loops[len(loops) - n_loop:]
+            out.sort(key=lambda d: (d["t0"], d["sid"]))
         if strip:
             out = [
                 {k: v for k, v in d.items() if k not in ("lb", "ev")}
@@ -510,6 +725,10 @@ class Tracer:
                 "exemplar_traces": len(self._exemplars),
                 "sample_rate": self.sample_rate,
                 "within_budget": self.peak_spans <= self.span_budget,
+                "loop_budget": self.loop_budget,
+                "loop_spans": len(self._loop_ring),
+                "loop_dropped": self.loop_dropped,
+                "loop_recorded": self.loop_recorded,
             }
 
     def reset(self) -> None:
@@ -517,11 +736,14 @@ class Tracer:
         configuration survives."""
         with self._lock:
             self._ring.clear()
+            self._loop_ring.clear()
             self._slow = []
             self._exemplars = OrderedDict()
             self.dropped = 0
             self.peak_spans = 0
             self.recorded = 0
+            self.loop_dropped = 0
+            self.loop_recorded = 0
 
 
 #: the process-wide recorder every subsystem writes into
@@ -606,12 +828,21 @@ def trace_e2e(spans: Sequence[Dict[str, Any]]) -> Optional[float]:
 def chrome_trace(spans: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
     """Chrome ``chrome://tracing`` / Perfetto JSON: one complete
     ('X') event per span — pid = recording node, tid = trace — plus an
-    instant ('i') event per span event. Times in microseconds as the
-    format demands."""
+    instant ('i') event per span event. Serve-loop spans that root a
+    trace of their own (a decode dispatch and its phases) share ONE
+    row per node, below the request rows, so a sampled request shows
+    above the dispatches that served it; a loop span under a request's
+    trace (``lm_request``) stays in that request's row. Times in
+    microseconds as the format demands."""
     nodes = sorted({str(d.get("node", "")) for d in spans})
     pid_of = {n: i + 1 for i, n in enumerate(nodes)}
-    tids = sorted({str(d.get("tid", "")) for d in spans})
-    tid_of = {t: i + 1 for i, t in enumerate(tids)}
+    req_tids = {str(d.get("tid", "")) for d in spans if "loop" not in d}
+
+    def row(d: Dict[str, Any]) -> str:
+        tid = str(d.get("tid", ""))
+        return tid if "loop" not in d or tid in req_tids else "~serve loop"
+
+    tid_of = {t: i + 1 for i, t in enumerate(sorted({row(d) for d in spans}))}
     events: List[Dict[str, Any]] = []
     for n, pid in pid_of.items():
         events.append({
@@ -620,7 +851,7 @@ def chrome_trace(spans: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
         })
     for d in spans:
         pid = pid_of[str(d.get("node", ""))]
-        tid = tid_of[str(d.get("tid", ""))]
+        tid = tid_of[row(d)]
         t0 = float(d.get("t0", 0.0))
         t1 = float(d.get("t1", t0))
         args: Dict[str, Any] = {
@@ -675,7 +906,7 @@ def cohort_attribution(
     # INSIDE the primary's infer span (that is the point of the
     # disaggregation: it all overlaps the batch's device window)
     detail = {"store_put", "store_get", "admission", "decode",
-              "prefill", "handoff", "marker"}
+              "prefill", "handoff", "marker", "lm_request"}
     covered = sum(v for k, v in mean_stages.items() if k not in detail)
     return {
         "n": n,

@@ -148,6 +148,16 @@ async def test_nodeapp_commands(tmp_path, capsys):
         out = capsys.readouterr().out
         assert "decode_cache" in out and "pipeline_depth" in out
 
+        # `profile spans` answers from the one recorder: the store
+        # operations and the job's worker stages above are in it
+        capsys.readouterr()
+        assert await app.handle("profile spans")
+        spans = json.loads(capsys.readouterr().out)
+        for name in ("store_op_put", "store_op_get", "worker_fetch",
+                     "worker_infer", "worker_put"):
+            assert spans[name]["count"] >= 1, name
+            assert spans[name]["max_s"] >= spans[name]["mean_s"] >= 0.0
+
         # stats + errors
         assert await app.handle("bps")
         assert await app.handle("fp-rate")

@@ -426,6 +426,22 @@ SPAN_USER_FIXTURE = textwrap.dedent("""
         TRACER.start_span(name, ctx=ctx).end()
 """)
 
+LOOP_SPAN_USER_FIXTURE = textwrap.dedent("""
+    from ..tracing import TRACER
+
+    def ok(step):
+        with TRACER.loop_span("fetch", step, rows=2):
+            pass
+
+    def bad(t0, t1):
+        with TRACER.loop_span("lm_not_a_phase"):
+            pass
+        TRACER.loop_record("lm_not_a_request", t0, t1)
+
+    def dynamic(name):
+        TRACER.loop_span(name).end()
+""")
+
 
 def test_span_name_extractors():
     trees = {
@@ -471,6 +487,30 @@ def test_span_name_drift_detected():
         collect_tracing_literals(tr), "dml_tpu/tracing.py",
     )
     assert not any("non-literal" in f.msg for f in fs3)
+
+
+def test_loop_span_call_sites_are_checked_like_start_span():
+    """`loop_span("<name>")` and `loop_record("<name>")` literals count
+    as call sites: an unregistered one is a finding, a registered one
+    keeps its name off the never-emitted list, a computed one in
+    dml_tpu/ is unverifiable."""
+    tr = ast.parse(TRACING_FIXTURE)
+    trees = {
+        "dml_tpu/tracing.py": tr,
+        "dml_tpu/inference/y.py": ast.parse(LOOP_SPAN_USER_FIXTURE),
+    }
+    literal, dynamic = collect_span_call_sites(trees)
+    assert set(literal) == {"fetch", "lm_not_a_phase", "lm_not_a_request"}
+    assert [p for p, _ in dynamic] == ["dml_tpu/inference/y.py"]
+    fs = check_span_names(
+        dmllint._module_const_strs(tr, "SPAN_NAMES"),
+        literal, dynamic, collect_tracing_literals(tr),
+        "dml_tpu/tracing.py",
+    )
+    msgs = " | ".join(f.msg for f in fs)
+    assert "'lm_not_a_phase'" in msgs and "'lm_not_a_request'" in msgs
+    assert "'fetch'" not in msgs  # the loop_span call site emits it
+    assert "'ghost'" in msgs and "non-literal" in msgs
 
 
 # ----------------------------------------------------------------------

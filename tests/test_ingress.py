@@ -234,6 +234,88 @@ def test_former_slack_expiry_dispatches_partial():
     assert not f.forming
 
 
+def _held_then_fed(f, clock):
+    """Linger runs out at 100.02; the ticks at 100.03 and 100.5 find no
+    worker free; the one at 100.8 does. The first request waited for a
+    worker from the first tick that held it, the second from its own
+    (later) arrival."""
+    f.add(_req(clock, 0), None)
+    clock.step(0.03)
+    assert f.due(hungry_models=set()) == []       # held: no worker free
+    clock.step(0.37)
+    f.add(_req(clock, 1), None)                   # arrives at 100.4
+    clock.step(0.10)
+    assert f.due(hungry_models=set()) == []
+    clock.step(0.30)
+    (fb,) = f.due(hungry_models={"m"})
+    return fb, [0.77, 0.40]
+
+
+def _fed_at_once(f, clock):
+    """A worker is free at the first tick past the linger: no wait for
+    one, whatever the linger and the tick took."""
+    f.add(_req(clock, 0), None)
+    assert f.due(hungry_models={"m"}) == []       # inside the linger
+    clock.step(0.05)
+    (fb,) = f.due(hungry_models={"m"})
+    return fb, [0.0]
+
+
+def _held_until_slack_ran_out(f, clock):
+    """Never hungry: the batch leaves when its slack expires, and all
+    of the time past the first held tick was a wait for a worker."""
+    f.add(_req(clock, 0), None)
+    clock.step(0.05)
+    assert f.due(hungry_models=set()) == []
+    clock.step(1.90)                              # dispatch_by = 101.935
+    (fb,) = f.due(hungry_models=set())
+    return fb, [1.90]
+
+
+def _full_slices_carry_the_wait(f, clock):
+    """A held batch that fills leaves in device-batch slices; each
+    request keeps the wait it had."""
+    f.add(_req(clock, 0), None)
+    clock.step(0.05)
+    assert f.due(hungry_models=set()) == []
+    clock.step(0.25)
+    for i in range(1, 8):
+        f.add(_req(clock, i), None)
+    clock.step(0.10)
+    (fb,) = f.due(hungry_models=set())
+    return fb, [0.35] + [0.10] * 7
+
+
+@pytest.mark.ingress
+@pytest.mark.parametrize("case", [
+    _held_then_fed, _fed_at_once, _held_until_slack_ran_out,
+    _full_slices_carry_the_wait,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_former_worker_wait(case):
+    """`worker_wait`: of a request's formation, the part it sat in a
+    batch whose linger had run out and that only the want of a free
+    worker still held. Never negative, never more than its own wait."""
+    clock = Clock()
+    f = BatchFormer(lambda m: 8, lambda m, n: 0.01 * n, now=clock)
+    fb, want = case(f, clock)
+    assert [r.worker_wait for r in fb.reqs] == pytest.approx(want)
+    for r in fb.reqs:
+        assert 0.0 <= r.worker_wait <= clock.t - r.arrival
+
+
+@pytest.mark.ingress
+def test_former_fixed_mode_never_waits_for_a_worker():
+    """The fixed baseline holds a batch for fullness, not for a worker."""
+    clock = Clock()
+    f = BatchFormer(lambda m: 4, lambda m, n: 0.01, mode="fixed", now=clock)
+    f.add(_req(clock, 0), None)
+    clock.step(1.0)
+    assert f.due(hungry_models=set()) == []
+    clock.step(1.1)
+    (fb,) = f.due(hungry_models=set())
+    assert fb.reqs[0].worker_wait == 0.0
+
+
 @pytest.mark.ingress
 def test_former_fixed_mode_waits_for_full():
     clock = Clock()
@@ -326,6 +408,10 @@ def test_request_end_to_end_inline_results(tmp_path):
                     {"label": chaos.STUB_MODEL, "score": 1.0}
                 ]
                 assert t["deadline_met"] in (True, False)
+                # of the formation wait, the part spent waiting for a
+                # free worker: never negative, never more than it
+                st = t["stages"]
+                assert 0.0 <= st["worker_wait"] <= st["formation"]
             # inline results: NO output_* store objects were created
             leader = next(
                 sn for sn in c.nodes.values() if sn.node.is_leader
@@ -348,6 +434,10 @@ def test_request_end_to_end_inline_results(tmp_path):
             assert admitted >= 6 and completed >= 6
             assert any(
                 k.startswith("request_e2e_latency_seconds")
+                for k in snap["histograms"]
+            )
+            assert any(
+                k.startswith("request_worker_wait_seconds")
                 for k in snap["histograms"]
             )
             # operator surface

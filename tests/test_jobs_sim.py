@@ -184,6 +184,41 @@ async def test_submit_job_end_to_end(tmp_path):
         assert coord.c1_stats()["ResNet50"]["total_queries"] == 10.0
 
 
+@pytest.mark.parametrize("depth,jobs,port,slow", [
+    (1, (("ResNet50", 40),), 22110, 0.0),
+    (2, (("ResNet50", 96), ("InceptionV3", 64)), 22130, 0.0),
+    (1, (("ResNet50", 64),), 22170, 0.4),
+], ids=["one_job", "two_jobs_depth_2", "batches_outlast_the_resend"])
+async def test_batch_timing_carries_the_leaders_dispatch_to_ack(
+        tmp_path, depth, jobs, port, slow):
+    """Every `batch_timing` entry holds `dispatch_to_ack`, the leader's
+    own wall from the FIRST send of WORKER_TASK_REQUEST to the ACK (what
+    the signal plane's liar check is handed), and it is never less than
+    the exec wall the worker reports inside it: staged batches included
+    (their clock starts at the stage's send), and batches that the
+    resend loop sent again while they ran (a wall from the last re-send
+    could never exceed the resend interval)."""
+    async with cluster(4, tmp_path, port) as sim:
+        await sim.wait_converged()
+        for j in sim.jobs.values():
+            j.set_pipeline_depth(depth)
+            if slow:
+                j.task_resend_after = 0.1
+        for be in sim.backends.values():
+            be.per_model_delay = {m: slow for m, _ in jobs}
+        client_u = sim.by_name("H4")
+        await sim.seed_images(client_u, 3)
+        client = sim.jobs[client_u]
+        ids = [await client.submit_job(m, n) for m, n in jobs]
+        for job_id, (_, n) in zip(ids, jobs):
+            done = await client.wait_job(job_id, timeout=20.0)
+            assert done["total_queries"] == n
+        rows = list(sim.coordinator_jobs().batch_timing)
+        assert len(rows) >= sum(-(-n // 32) for _, n in jobs)
+        for b in rows:
+            assert b["dispatch_to_ack"] >= b["exec"] >= slow, b
+
+
 async def test_submit_unknown_to_leader_fails_fast(tmp_path):
     """register_lm is per-node; if the leader never saw it, a submit
     for that model must be rejected at intake — not silently fed
@@ -1014,7 +1049,7 @@ async def test_pipeline_stage_cancel_on_second_model(tmp_path):
     """A second model's job arriving while stages are out must pull
     the staged batches back (fair split sees them) and cancel the
     workers' stages; both jobs then complete."""
-    async with cluster(4, tmp_path, 23200) as sim:
+    async with cluster(4, tmp_path, 26200) as sim:
         await sim.wait_converged()
         # staging machinery under test: pin static depth 2 (the
         # adaptive default commits depth on measurement and, un-
@@ -1198,7 +1233,7 @@ async def test_pipeline_orphaned_stage_self_promotes(tmp_path):
     a beat instead of stranding until the coordinator's resend."""
     from dml_tpu.cluster.wire import Message, MsgType
 
-    async with cluster(3, tmp_path, 23500) as sim:
+    async with cluster(3, tmp_path, 26500) as sim:
         await sim.wait_converged()
         client_u = sim.by_name("H3")
         files = await sim.seed_images(client_u, 2)

@@ -106,6 +106,43 @@ def test_lm_backend_serve_files(params, tmp_path):
         np.testing.assert_array_equal(results[p]["tokens"], expect)
 
 
+@pytest.mark.parametrize("overlap", [True, False],
+                         ids=["driver", "serial"])
+def test_lm_request_span_hangs_under_the_batch_trace_context(
+        params, tmp_path, overlap):
+    """A traced request's `lm_request` span takes the context the job
+    service set for its file (the worker's `infer` span) as its parent;
+    an unsampled or untraced file roots a trace of its own."""
+    from dml_tpu.tracing import CURRENT_CTXS, TRACER, TraceContext
+
+    rng = np.random.RandomState(5)
+    paths = []
+    for i, tp in enumerate((5, 9, 12)):
+        p = str(tmp_path / f"q{i}.tokens.txt")
+        write_prompt_file(p, rng.randint(0, CFG.vocab_size, tp))
+        paths.append(p)
+    be = LMBackend(params, CFG, max_new_tokens=4, max_slots=2,
+                   max_len=64, chunk=4)
+    be.overlap = overlap
+    TRACER.reset()
+    token = CURRENT_CTXS.set((
+        TraceContext("tA", "sInferA", True, key=paths[0]),
+        TraceContext("tB", "sInferB", False, key=paths[1]),
+    ))
+    try:
+        be.serve_files(paths)
+    finally:
+        CURRENT_CTXS.reset(token)
+        be.close()
+    by_prompt = {d["lb"]["prompt_tokens"]: d
+                 for d in TRACER.loop_spans("lm_request")}
+    TRACER.reset()
+    assert (by_prompt[5]["tid"], by_prompt[5]["par"]) == ("tA", "sInferA")
+    for tp in (9, 12):  # unsampled, and no context at all
+        assert by_prompt[tp]["par"] == ""
+        assert by_prompt[tp]["tid"] not in ("tA", "tB")
+
+
 async def _cluster_lm_run(params, tmp):
     from dml_tpu.cluster.introducer import IntroducerService
     from dml_tpu.cluster.node import Node

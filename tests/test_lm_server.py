@@ -519,3 +519,172 @@ def test_on_token_streams_prefilled_adoption(params):
         be.close()
     for i in range(2):
         assert got[i] == [int(t) for t in toks[i]]
+
+
+# ----------------------------------------------------------------------
+# serve-loop spans (tracing.Tracer.loop_span) and the counters beside them
+# ----------------------------------------------------------------------
+
+_PHASES = ("lm_dispatch", "lm_pack", "lm_readback", "lm_deliver", "lm_place")
+
+
+def _served_mixed_budgets(params):
+    """Mixed budgets through a 3-slot grid (queueing, slot reuse, a
+    budget-1 request that retires at placement), with the recorder and
+    the registry read before and after."""
+    from dml_tpu.observability import METRICS
+    from dml_tpu.tracing import TRACER
+
+    def metrics():
+        hist = METRICS.histogram("lm_server_first_token_seconds").items()
+        tok = METRICS.counter("lm_server_prefill_tokens_total")
+        return {"first_token_count": sum(v[0] for _, v in hist),
+                "prompt": tok.value(kind="prompt"),
+                "padded": tok.value(kind="padded")}
+
+    rng = np.random.RandomState(11)
+    reqs = [(rng.randint(0, CFG.vocab_size, n), b) for n, b in
+            ((7, 12), (16, 5), (23, 9), (40, 1), (5, 6), (30, 14), (9, 3))]
+    TRACER.reset()
+    before = metrics()
+    srv = LMServer(params, CFG, max_slots=3, max_len=64, chunk=4)
+    rids = srv.submit_many([p for p, _ in reqs], [b for _, b in reqs])
+    out = srv.run()
+    after = metrics()
+    spans = TRACER.loop_spans()
+    TRACER.reset()
+    return srv, reqs, rids, out, spans, {
+        k: after[k] - before[k] for k in after}
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    return _served_mixed_budgets(params)
+
+
+def test_spans_leave_greedy_tokens_what_they_were(params, served):
+    _, reqs, rids, out, _, _ = served
+    for rid, (p, n) in zip(rids, reqs):
+        np.testing.assert_array_equal(
+            out[rid], _isolated(params, p, n), err_msg=f"req {rid}")
+
+
+def test_every_chunk_step_holds_its_five_phases(served):
+    """Each `lm_step` of the plain chunk path has exactly one child of
+    each phase, in order, disjoint, inside the step, sharing its trace
+    id; the phases cover all but a sliver of the step."""
+    spans = served[4]
+    steps = [d for d in spans if d["name"] == "lm_step"]
+    assert len(steps) >= 4
+    for st in steps:
+        kids = sorted((d for d in spans if d["par"] == st["sid"]),
+                      key=lambda d: d["t0"])
+        assert [d["name"] for d in kids] == list(_PHASES)
+        assert {d["tid"] for d in kids} == {st["tid"]}
+        edges = [st["t0"]] + [x for d in kids for x in (d["t0"], d["t1"])] \
+            + [st["t1"]]
+        # one microsecond of slack: t0/t1 are rounded to it
+        assert all(b - a >= -2e-6 for a, b in zip(edges, edges[1:])), edges
+        assert st["lb"]["occupancy"] >= 1
+        assert st["lb"]["tokens"] == [d for d in kids
+                                      if d["name"] == "lm_deliver"][0]["lb"]["tokens"]
+    # what no step delivered, `_flush_firsts` did: a readback with no
+    # parent (the budget-1 request retired at placement), and only a
+    # readback or a placement at submit may stand outside a step
+    total = sum(b for _, b in served[1])
+    assert served[0].tokens_delivered == total
+    assert 0 < sum(st["lb"]["tokens"] for st in steps) <= total
+    assert {d["name"] for d in spans if not d["par"]} <= {
+        "lm_step", "lm_readback", "lm_place", "lm_request"}
+
+
+def test_prefill_groups_count_prompt_and_padded_tokens(served):
+    srv, reqs, _, _, spans, delta = served
+    groups = [d for d in spans if d["name"] == "lm_prefill_group"]
+    places = {d["sid"] for d in spans if d["name"] == "lm_place"}
+    assert groups and all(g["par"] in places for g in groups)
+    for g in groups:
+        lb = g["lb"]
+        kp = srv.max_slots if lb["bucket"] <= 256 else lb["padded_rows"]
+        assert lb["padded_rows"] == kp and 1 <= lb["rows"] <= kp
+        assert lb["padded_tokens"] == kp * lb["bucket"]
+        assert 0 < lb["prompt_tokens"] <= lb["rows"] * lb["bucket"]
+    assert sum(g["lb"]["rows"] for g in groups) == len(reqs)
+    assert sum(g["lb"]["prompt_tokens"] for g in groups) == sum(
+        p.size for p, _ in reqs)
+    # the counter's two kinds are the label sums
+    assert delta["prompt"] == sum(g["lb"]["prompt_tokens"] for g in groups)
+    assert delta["padded"] == sum(g["lb"]["padded_tokens"] for g in groups)
+    assert sum(d["lb"]["requests"] for d in spans
+               if d["name"] == "lm_place") == len(reqs)
+
+
+def test_every_request_span_is_ordered_and_counted(served):
+    _, reqs, _, _, spans, delta = served
+    rs = [d for d in spans if d["name"] == "lm_request"]
+    assert len(rs) == len(reqs) and delta["first_token_count"] == len(reqs)
+    assert sorted((d["lb"]["prompt_tokens"], d["lb"]["new_tokens"])
+                  for d in rs) == sorted((p.size, b) for p, b in reqs)
+    for d in rs:
+        ev = dict(d["ev"])
+        assert d["t0"] <= ev["placed"] <= ev["first_token"] <= d["t1"]
+        assert d["par"] == ""  # no worker's infer span here
+
+
+def test_spec_step_names_its_two_enqueues_lm_dispatch(params):
+    from dml_tpu.tracing import TRACER
+
+    rng = np.random.RandomState(12)
+    prompt = rng.randint(0, CFG.vocab_size, 9)
+    srv = LMServer(params, CFG, max_slots=2, max_len=64, chunk=4)
+    srv.enable_spec_decode(3, draft_params=params, draft_cfg=CFG)
+    TRACER.reset()
+    rid = srv.submit(prompt, 10)
+    out = srv.run()
+    spans = TRACER.loop_spans()
+    TRACER.reset()
+    np.testing.assert_array_equal(out[rid], _isolated(params, prompt, 10))
+    steps = [d for d in spans if d["name"] == "lm_step"]
+    assert steps
+    for st in steps:
+        kids = sorted((d for d in spans if d["par"] == st["sid"]),
+                      key=lambda d: d["t0"])
+        assert [d["name"] for d in kids] == [
+            "lm_dispatch", "lm_dispatch", "lm_pack", "lm_readback",
+            "lm_deliver", "lm_place"]
+        assert [d["lb"]["phase"] for d in kids[:2]] == ["propose", "verify"]
+
+
+def test_driver_records_idle_submit_and_request_parent(params):
+    """Through the LMDriver: an `lm_idle` span while it waits, an
+    `lm_submit` span that parents the burst's `lm_place`, and an
+    `lm_request` under the trace context handed in with the prompt."""
+    from dml_tpu.inference.lm_server import LMDriver
+    from dml_tpu.tracing import TRACER, TraceContext
+
+    rng = np.random.RandomState(13)
+    prompts = [rng.randint(0, CFG.vocab_size, n) for n in (6, 12)]
+    srv = LMServer(params, CFG, max_slots=2, max_len=64, chunk=4)
+    drv = LMDriver(srv)
+    TRACER.reset()
+    try:
+        ctx = TraceContext("tREQ", "sINFER", True, key="a.txt")
+        outs = drv.serve(prompts, [5, 7], trace=[ctx, None])
+    finally:
+        drv.stop()
+    spans = TRACER.loop_spans()
+    TRACER.reset()
+    for p, n, o in zip(prompts, (5, 7), outs):
+        np.testing.assert_array_equal(o, _isolated(params, p, n))
+    sub = [d for d in spans if d["name"] == "lm_submit"]
+    assert len(sub) == 1 and sub[0]["lb"]["requests"] == 2
+    assert sub[0]["lb"]["tickets"] == 1
+    # the ticket's wait for the thread: it was idle, so next to nothing
+    assert 0.0 <= sub[0]["lb"]["ticket_wait_s"] < 1.0
+    assert [d for d in spans if d["name"] == "lm_place"
+            and d["par"] == sub[0]["sid"]]
+    assert any(d["name"] == "lm_idle" for d in spans)
+    reqs = {d["lb"]["prompt_tokens"]: d for d in spans
+            if d["name"] == "lm_request"}
+    assert (reqs[6]["tid"], reqs[6]["par"]) == ("tREQ", "sINFER")
+    assert reqs[12]["par"] == "" and reqs[12]["tid"] != "tREQ"

@@ -299,6 +299,188 @@ def test_scheduler_requeue_notes_exemplar(tracer):
 
 
 # ----------------------------------------------------------------------
+# loop spans: the serve loop's ring, clock and profiler annotation
+# ----------------------------------------------------------------------
+
+
+class _StubAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: counts enters/exits."""
+
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _StubAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _StubAnnotation.log.append(("exit", self.name))
+
+
+def _loop_ring_bounded(t, monkeypatch):
+    for i in range(300):
+        t.loop_span("lm_step", i=i).end()
+    st = t.stats()
+    assert st["loop_spans"] == 64 and st["loop_budget"] == 64
+    assert st["loop_dropped"] == 300 - 64 and st["loop_recorded"] == 300
+    kept = [d["lb"]["i"] for d in t.loop_spans("lm_step")]
+    assert kept == list(range(300 - 64, 300))  # the newest survive
+
+
+def _loop_flood_evicts_no_request_span(t, monkeypatch):
+    for i in range(10):
+        t.start_span("fetch", trace_id=f"t{i}", node="n1").end()
+    for _ in range(500):
+        t.loop_span("lm_step").end()
+    st = t.stats()
+    assert st["spans"] == 10 and st["dropped"] == 0
+    assert {d["tid"] for d in t.dump() if "loop" not in d} == {
+        f"t{i}" for i in range(10)}
+
+
+def _request_flood_evicts_no_loop_span(t, monkeypatch):
+    for i in range(10):
+        t.loop_span("lm_step", i=i).end()
+    for i in range(500):
+        t.start_span("fetch", trace_id=f"t{i}", node="n1").end()
+    st = t.stats()
+    assert st["loop_spans"] == 10 and st["loop_dropped"] == 0
+    assert st["spans"] == 64 and st["dropped"] == 500 - 64
+    # a capped dump keeps both kinds apart too: neither starves
+    cut = t.dump(max_spans=16)
+    assert len(cut) == 16
+    assert sum("loop" in d for d in cut) == 8
+
+
+def _loop_parent_and_trace_ids(t, monkeypatch):
+    with t.loop_span("lm_step", occupancy=3) as step:
+        with t.loop_span("lm_dispatch", step) as child:
+            pass
+        t.loop_record("lm_request", step.m0, child.m1, step,
+                      events=(("placed", step.m0),), slot=2)
+    with t.loop_span("lm_step") as other:
+        pass
+    ctx = TraceContext("tREQ", "sINFER")
+    t.loop_record("lm_request", 1.0, 2.0, ctx)
+    rows = {d["sid"]: d for d in t.loop_spans()}
+    assert len(rows) == 5 and all(d["loop"] == 1 for d in rows.values())
+    assert rows[child.span_id]["par"] == step.span_id
+    assert rows[child.span_id]["tid"] == step.trace_id  # one dispatch,
+    assert rows[step.span_id]["par"] == ""              # one trace id
+    assert other.trace_id != step.trace_id
+    assert rows[step.span_id]["lb"] == {"occupancy": 3}
+    under = [d for d in rows.values() if d["tid"] == "tREQ"]
+    assert [d["par"] for d in under] == ["sINFER"]
+    rec = [d for d in rows.values()
+           if d["name"] == "lm_request" and d["tid"] == step.trace_id]
+    assert rec[0]["ev"] == [["placed", rows[step.span_id]["t0"]]]
+    assert rec[0]["lb"] == {"slot": 2}
+    # carried by dump / chrome_trace like any other span
+    doc = chrome_trace(t.dump())
+    names = [e["name"] for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert sorted(names) == ["lm_dispatch", "lm_request", "lm_request",
+                             "lm_step", "lm_step"]
+    assert t.dump(trace_ids=["tREQ"]) == under
+
+
+def _wall_of_maps_the_monotonic_clock(t, monkeypatch):
+    import time
+
+    m, w = time.monotonic(), time.time()
+    assert abs(t.wall_of(m) - w) < 0.05
+    assert t.wall_of(m + 2.5) - t.wall_of(m) == pytest.approx(2.5)
+    with t.loop_span("lm_step") as s:
+        pass
+    d = t.loop_spans()[0]
+    assert d["t0"] == pytest.approx(t.wall_of(s.m0), abs=1e-6)
+    assert d["t1"] == pytest.approx(t.wall_of(s.m1), abs=1e-6)
+
+
+def _summary_folds_the_ring_per_name(t, monkeypatch):
+    t.loop_record("lm_step", 10.0, 10.5)
+    t.loop_record("lm_step", 11.0, 11.25)
+    t.loop_record("lm_place", 11.0, 11.125)
+    s = t.summary()
+    assert list(s) == ["lm_place", "lm_step"]
+    assert s["lm_step"]["count"] == 2
+    assert s["lm_step"]["total_s"] == pytest.approx(0.75, abs=1e-5)
+    assert s["lm_step"]["mean_s"] == pytest.approx(0.375, abs=1e-5)
+    assert s["lm_step"]["max_s"] == pytest.approx(0.5, abs=1e-5)
+    t.reset()
+    assert t.summary() == {} and t.stats()["loop_recorded"] == 0
+
+
+def _stepped_wall_clock_leaves_durations_right(t, monkeypatch):
+    """time.time() jumps back an hour inside the span: the duration
+    stays the monotonic one and the span still ends after it starts."""
+    import time
+
+    real = time.time
+    with t.loop_span("lm_step") as s:
+        monkeypatch.setattr(time, "time", lambda: real() - 3600.0)
+        t.start_span("fetch", trace_id="tX", node="n").end()
+    monkeypatch.setattr(time, "time", real)
+    d = t.loop_spans()[0]
+    assert 0.0 <= d["t1"] - d["t0"] < 1.0
+    assert d["t1"] - d["t0"] == pytest.approx(s.m1 - s.m0, abs=2e-6)
+    assert abs(d["t0"] - real()) < 5.0  # still on the unstepped wall
+
+
+def _annotation_entered_once_per_span(t, monkeypatch):
+    monkeypatch.setattr(trc, "_TRACE_ANNOTATION", _StubAnnotation)
+    _StubAnnotation.log = []
+    with t.loop_span("lm_step") as step:
+        with t.loop_span("lm_pack", step):
+            pass
+    t.loop_record("lm_request", 1.0, 2.0)  # no stack frame: none
+    assert _StubAnnotation.log == [
+        ("enter", "dml.lm_step"), ("enter", "dml.lm_pack"),
+        ("exit", "dml.lm_pack"), ("exit", "dml.lm_step")]
+    step.end()  # idempotent: no second exit, no second record
+    assert len(_StubAnnotation.log) == 4 and len(t.loop_spans()) == 3
+
+
+def _annotation_skipped_where_jax_is_not_loaded(t, monkeypatch):
+    import sys
+
+    monkeypatch.setattr(trc, "_TRACE_ANNOTATION", None)
+    monkeypatch.delitem(sys.modules, "jax", raising=False)
+    with t.loop_span("lm_step"):
+        pass
+    assert trc._TRACE_ANNOTATION is None and "jax" not in sys.modules
+    assert len(t.loop_spans("lm_step")) == 1
+
+
+@pytest.mark.tracing
+@pytest.mark.parametrize("case", [
+    _loop_ring_bounded,
+    _loop_flood_evicts_no_request_span,
+    _request_flood_evicts_no_loop_span,
+    _loop_parent_and_trace_ids,
+    _wall_of_maps_the_monotonic_clock,
+    _summary_folds_the_ring_per_name,
+    _stepped_wall_clock_leaves_durations_right,
+    _annotation_entered_once_per_span,
+    _annotation_skipped_where_jax_is_not_loaded,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_loop_spans(case, monkeypatch):
+    case(Tracer(sample_rate=1.0, span_budget=64, loop_budget=64),
+         monkeypatch)
+
+
+@pytest.mark.tracing
+def test_every_loop_span_name_is_registered():
+    """The names the serve loop opens are in the closed registry (the
+    lint rule checks the call sites; this pins the vocabulary)."""
+    assert {"lm_idle", "lm_submit", "lm_step", "lm_dispatch", "lm_pack",
+            "lm_readback", "lm_deliver", "lm_place", "lm_prefill_group",
+            "lm_request", "worker_fetch", "worker_infer", "worker_put",
+            "store_op_put", "store_op_get"} <= set(SPAN_NAMES)
+
+
+# ----------------------------------------------------------------------
 # cluster end-to-end: stitched traces over TRACE_PULL
 # ----------------------------------------------------------------------
 

@@ -73,15 +73,17 @@ def test_spans_and_jsonl_logging(tmp_path):
     import json
     import logging
 
-    from dml_tpu.observability import Spans, jsonl_logging
+    from dml_tpu.observability import jsonl_logging
+    from dml_tpu.tracing import Tracer
 
-    spans = Spans()
-    with spans.span("put"):
+    spans = Tracer()  # the one span recorder; `profile spans` prints this
+    with spans.loop_span("store_op_put"):
         pass
-    with spans.span("put"):
+    with spans.loop_span("store_op_put"):
         pass
     s = spans.summary()
-    assert s["put"]["count"] == 2 and s["put"]["mean_s"] >= 0
+    assert s["store_op_put"]["count"] == 2
+    assert s["store_op_put"]["mean_s"] >= 0
 
     log_path = tmp_path / "node.jsonl"
     handler = jsonl_logging(str(log_path))
