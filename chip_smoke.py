@@ -397,8 +397,7 @@ async def lm_phase(
                                         "accepted", "accept_rate")},
             },
             "has_tpu_custom_call": be.server.kernel_report(),
-            # grouped bf16 caches stay on the einsum by measured policy
-            "decode_kernel_by_policy": uses_decode_kernel(be.cfg),
+            "decode_kernel_by_policy": uses_decode_kernel(),
         }
     finally:
         be.close()
@@ -447,7 +446,7 @@ def kernel_phase(seed: int, *, batch: int = 8, heads: int = 16,
             a.astype(jnp.float32) - b.astype(jnp.float32))))
 
     out: Dict[str, Any] = {}
-    err = max_err(jax.jit(decode_attention)(q, ck, cv, pos),
+    err = max_err(jax.jit(decode_attention)(q, ck, cv, pos + 1),
                   jax.jit(einsum_ref)(q, ck, cv, pos))
     out["decode_attention_bf16_max_err"] = err
     _require(err < 0.05, f"decode kernel (bf16 cache) off by {err}")
@@ -456,7 +455,7 @@ def kernel_phase(seed: int, *, batch: int = 8, heads: int = 16,
     vq, vsc = _kv_quantize(cv)
     got = jax.jit(
         lambda q, k, s, v, t, p: decode_attention(
-            q, k, v, p, k_scale=s, v_scale=t)
+            q, k, v, p + 1, k_scale=s, v_scale=t)
     )(q, kq, jnp.swapaxes(ksc, 2, 3), vq, jnp.swapaxes(vsc, 2, 3), pos)
     err = max_err(got, jax.jit(einsum_ref)(
         q, kq.astype(jnp.float32) * ksc, vq.astype(jnp.float32) * vsc, pos))

@@ -33,7 +33,6 @@ prefill so the dense-dispatch intermediate stays bounded).
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -138,12 +137,10 @@ def _kv_quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def _kv_dequant(q: jax.Array, scale: jax.Array) -> jax.Array:
-    """int8 + scale -> f32 — the EINSUM-path read side only (CPU/test
-    mesh, or DML_TPU_DECODE_KERNEL=0). XLA materializes this dequant
-    in HBM before the attention contraction, which is exactly why the
-    TPU path hands int8 caches to the Pallas kernel instead (inline
-    dequant in VMEM; see the dispatch policy in
-    batched_decode_step)."""
+    """int8 + scale -> f32 — the EINSUM-path read side only (the CPU /
+    test mesh). XLA materializes this dequant in HBM before the
+    attention contraction, which the Pallas kernel the TPU path uses
+    does not (inline dequant in VMEM)."""
     return q.astype(jnp.float32) * scale
 
 
@@ -293,31 +290,53 @@ def _kernel_on_mesh(kernel, mesh: Optional[Mesh], in_specs, out_specs):
     )
 
 
-def uses_decode_kernel(cfg: LMConfig) -> bool:
+def uses_decode_kernel() -> bool:
     """Whether `batched_decode_step` hands cache attention to the
-    Pallas kernel (ops/decode_attention.py) — the one place that
-    decides, so a caller can ask which path a config compiles to.
+    Pallas kernel (ops/decode_attention.py): on a TPU, for every cache
+    layout (MHA, grouped, MQA; bf16 or int8). Elsewhere the kernel
+    would only interpret, and the einsum below is the route and the
+    tests' oracle. The one place that decides, so a caller can ask.
 
-    The kernel replaces the einsum on TPU where it measured faster
-    (v5e, 2026-07 dispersion A/B, median of 5 paired slopes; not
-    re-measured on the current installation): int8 caches (6662 vs
-    4482 tok/s b8/4k — the einsum path materializes the dequantized
-    cache in HBM first), MHA (1057 vs 790 b1/4k — the full-width cache
-    is the most bandwidth-bound) and MQA (1950 vs 1792). Grouped bf16
-    caches (1 < KV < H) stay on the einsum: XLA's batched-matmul
-    schedule held 5676 vs 4912 at b8/4k. DML_TPU_DECODE_KERNEL=0/1
-    forces the path — the A/B lever that re-verifies the policy."""
-    force = os.environ.get("DML_TPU_DECODE_KERNEL")
-    return jax.default_backend() == "tpu" and (
-        force == "1"
-        or (
-            force != "0"
-            and (
-                cfg.kv_quant
-                or cfg.kv_heads == 1
-                or cfg.kv_heads == cfg.n_heads
-            )
-        )
+    The kernel fetches only the k-blocks that hold live rows of each
+    slot; the einsum streams the whole [B, T] grid behind a mask.
+    Grouped bf16 caches were the one layout an older policy kept on
+    the einsum; at no length measured is the einsum ahead there now,
+    so there is no policy and no lever (MHA, MQA and int8 caches took
+    the kernel before and were not measured again). On one TPU v5e
+    (my chip run, PR 26) at the benchmark's grouped bf16 cache (32
+    heads / 8 KV, D 128, 16 slots x 4,096 rows, 8 layers):
+
+    - a 32-step scan of `batched_decode_step`, bf16 weights, ms a
+      step: einsum 7.03 whatever the lengths; the dense kernel this
+      one replaces 6.88; this kernel 6.88 with every slot at ~4,000
+      rows (2% ahead of the einsum at full context, where the old A/B
+      on a chip that is gone had the dense kernel 13% behind), 4.52
+      with 13 slots live at the cells' lengths (median ~350 rows),
+      4.22 with 4 live;
+    - the jobs cell (`mistral7b_widths_l8.jobs`), tokens/s: the
+      einsum 1,175.7, the dense kernel forced onto the grouped cache
+      1,164.0, this kernel 1,486.3 — kernel against einsum is nothing,
+      skipping dead blocks is all of it."""
+    return jax.default_backend() == "tpu"
+
+
+def decode_block_rows(
+    cfg: LMConfig, max_len: int, mesh: Optional[Mesh] = None
+) -> Optional[int]:
+    """Cache rows per k-block that `batched_decode_step`'s kernel
+    fetches for a `max_len`-row cache of `cfg` (per device under
+    `mesh`), or None on the einsum route, which streams every row.
+    For a caller that reckons the rows a step reads from the slots'
+    lengths (LMServer's `kv_rows` accounting)."""
+    if not uses_decode_kernel():
+        return None
+    from ..ops.decode_attention import block_rows
+
+    tp = mesh.shape["tp"] if heads_axis(
+        mesh, cfg.n_heads, cfg.kv_heads) else 1
+    return block_rows(
+        cfg.kv_heads // tp, cfg.head_dim,
+        jnp.int8 if cfg.kv_quant else cfg.dtype, max_len,
     )
 
 
@@ -328,6 +347,7 @@ def batched_decode_step(
     tokens: jax.Array,  # [B] int32 — each slot's current input token
     pos: jax.Array,  # [B] int32 — each slot's own write position
     mesh: Optional[Mesh] = None,
+    lengths: Optional[jax.Array] = None,  # [B] int32 — rows each slot attends
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """decode_step with PER-SLOT positions — the continuous-batching
     primitive (inference/lm_server.py): every slot advances through
@@ -335,7 +355,13 @@ def batched_decode_step(
     decode together in one program. Identical math to decode_step
     (which is the pos-broadcast special case). `mesh` is the mesh the
     params are sharded over, if any: the Pallas cache-attention
-    kernel is then placed per device (`_kernel_on_mesh`)."""
+    kernel is then placed per device (`_kernel_on_mesh`).
+
+    Slot b attends cache rows < `lengths[b]`: `pos + 1` (the row just
+    written included) unless the caller knows better. A caller that
+    knows a slot is EMPTY passes 0 for it: its attention output is
+    zeros, its logits are garbage nobody reads, and the kernel route
+    fetches none of its cache (its row at `pos` is still written)."""
     hd = cfg.head_dim
     b = tokens.shape[0]
     grp = cfg.n_heads // cfg.kv_heads
@@ -344,9 +370,11 @@ def batched_decode_step(
     # layout-generic (bf16 {k, v} or kv_quant {k_q, ...}): every leaf
     # carries [B, KV, max_len, ...]
     max_len = next(iter(next(iter(cache.values())).values())).shape[2]
-    # per-slot validity: slot b sees cache positions <= pos[b]
-    valid = jnp.arange(max_len)[None, :] <= pos[:, None]  # [B, T]
-    use_kernel = uses_decode_kernel(cfg)
+    if lengths is None:
+        lengths = pos + 1
+    # per-slot validity: slot b sees cache rows < lengths[b]
+    valid = jnp.arange(max_len)[None, :] < lengths[:, None]  # [B, T]
+    use_kernel = uses_decode_kernel()
     if use_kernel:
         from ..ops.decode_attention import decode_attention
 
@@ -354,8 +382,8 @@ def batched_decode_step(
         q_spec = P(None, None, ax, None)  # [B, 1, H, D]
         c_spec = P(None, ax, None, None)  # [B, KV, T, D] / [B, KV, 1, T]
         kernel = _kernel_on_mesh(
-            lambda q, k, v, p, ks=None, vs=None: decode_attention(
-                q, k, v, p, k_scale=ks, v_scale=vs),
+            lambda q, k, v, n, ks=None, vs=None: decode_attention(
+                q, k, v, n, k_scale=ks, v_scale=vs),
             mesh,
             in_specs=(q_spec, c_spec, c_spec, P())
             + ((c_spec, c_spec) if cfg.kv_quant else ()),
@@ -398,7 +426,7 @@ def batched_decode_step(
                 new_cache[name] = lay
                 if use_kernel:
                     return kernel(
-                        q, lay["k_q"], lay["v_q"], pos,
+                        q, lay["k_q"], lay["v_q"], lengths,
                         lay["k_s"], lay["v_s"],
                     )
                 ck = _kv_dequant(
@@ -412,13 +440,17 @@ def batched_decode_step(
                 cv = upd(cache[name]["v"], vh.astype(cfg.dtype), axis=2)
                 new_cache[name] = {"k": ck, "v": cv}
                 if use_kernel:
-                    return kernel(q, ck, cv, pos)
+                    return kernel(q, ck, cv, lengths)
             qg = q.astype(jnp.float32).reshape(b, 1, cfg.kv_heads, grp, hd)
             s = jnp.einsum(
                 "bqkgd,bktd->bkgqt", qg, ck.astype(jnp.float32)
             ) * (hd**-0.5)
-            s = jnp.where(valid[:, None, None, None, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
+            vmask = valid[:, None, None, None, :]
+            s = jnp.where(vmask, s, -1e30)
+            # a live slot's p is already exactly 0 on dead rows; the
+            # select makes an EMPTY slot (all rows dead, softmax
+            # uniform) return zeros, as the kernel does
+            p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
             attn = jnp.einsum("bkgqt,bktd->bqkgd", p, cv.astype(jnp.float32))
             return attn.reshape(b, 1, cfg.n_heads, hd)
 

@@ -89,6 +89,7 @@ from .generate import (
     _sample,
     batched_decode_step,
     batched_verify_step,
+    decode_block_rows,
     heads_axis,
     init_cache,
     prefill,
@@ -129,6 +130,16 @@ _M_PREFILL_TOKENS = METRICS.counter(
     "device computed)")
 _M_PREFILL_PROMPT = _M_PREFILL_TOKENS.labels(kind="prompt")
 _M_PREFILL_PADDED = _M_PREFILL_TOKENS.labels(kind="padded")
+_M_KV_ROWS = METRICS.counter(
+    "lm_server_decode_kv_rows_total",
+    "cache rows of ONE layer over the chunk dispatches' decode steps by "
+    "kind=: live (rows the slots' lengths name), read (rows of the "
+    "k-blocks cache attention fetches for them; the whole grid on the "
+    "einsum route) and grid (steps x slots x max_len, what a "
+    "length-blind step streams)")
+_M_KV_LIVE = _M_KV_ROWS.labels(kind="live")
+_M_KV_READ = _M_KV_ROWS.labels(kind="read")
+_M_KV_GRID = _M_KV_ROWS.labels(kind="grid")
 _M_FIRST_TOKEN = METRICS.histogram(
     "lm_server_first_token_seconds",
     "slot placement -> the request's first token VALUE on the host "
@@ -552,9 +563,9 @@ class LMServer:
         """Which of this server's device programs hold a Pallas kernel
         (`tpu_custom_call`), asked of the programs as lowered for the
         devices the params live on: `prefill` (the flash kernel) and
-        `decode` (the cache-attention kernel, where
-        `generate.uses_decode_kernel` picks it). The switches choose
-        by backend and config without a word; this is the witness."""
+        `decode` (the cache-attention kernel, which
+        `generate.uses_decode_kernel` picks on a TPU). The switches
+        choose by backend without a word; this is the witness."""
         i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
         vec = i32((self.max_slots,))
         lowered = {
@@ -662,7 +673,14 @@ class LMServer:
         prompt + budget fits max_len, enforced at submit), so this is
         an identity for live requests, while a freed slot's pos pins
         at max_len instead of growing by `chunk` every step for the
-        life of the server."""
+        life of the server.
+
+        From `pos` alone an empty slot would therefore look like the
+        LONGEST sequence on the grid. The host knows better and has
+        already said so: `rid` is 0 exactly for an empty slot
+        (`_retire` zeroes it; request ids start at 1), so such a slot
+        attends 0 rows and cache attention fetches none of its rows.
+        Its clamped write stays (the invariant above)."""
         last = self.max_len - 1
         params = self._maybe_gather(params)
 
@@ -670,7 +688,8 @@ class LMServer:
             cache, cur, pos = carry
             pos_c = jnp.minimum(pos, last)
             logits, cache = batched_decode_step(
-                params, self.cfg, cache, cur, pos_c, mesh=self._mesh
+                params, self.cfg, cache, cur, pos_c, mesh=self._mesh,
+                lengths=jnp.where(rid > 0, pos_c + 1, 0),
             )
             nxt = self._sample_slots(logits, rid, pos_c + 1)
             return (cache, nxt, pos_c + 1), nxt
@@ -1162,6 +1181,8 @@ class LMServer:
         self._done[req.rid] = req
         req.slot = None
         self._slot_req[slot] = None
+        # 0 is not only "no request": `_chunk_impl` reads rid 0 as AN
+        # EMPTY SLOT, ATTEND NOTHING (request ids start at 1)
         self.rid_vec[slot] = 0
         _M_REQS_DONE.inc()
 
@@ -1427,6 +1448,32 @@ class LMServer:
         _M_SLOTS.set(sum(1 for r in self._slot_req if r is not None))
         step.label(tokens=delivered, firsts=first_n)
 
+    def _kv_rows(self) -> Tuple[int, int, int]:
+        """(live, read, grid) cache rows of one layer over the chunk
+        dispatch about to be issued — host arithmetic on what the
+        device will do, no readback. A live slot at step i attends
+        prompt + emitted + i rows (its clamped position + 1) and cache
+        attention fetches them in whole k-blocks; an empty slot names
+        the block of the slot before it, so only a run of them at the
+        head of the grid costs a block a step
+        (ops/decode_attention.py)."""
+        grid = self.chunk * self.max_slots * self.max_len
+        pos0 = np.asarray(
+            [r.prompt.size + r.emitted - 1
+             for r in self._slot_req if r is not None]
+        )
+        lens = np.minimum(
+            pos0[:, None] + np.arange(self.chunk), self.max_len - 1
+        ) + 1  # [live slots, chunk]
+        live = int(lens.sum())
+        bk = decode_block_rows(self.cfg, self.max_len, self._mesh)
+        if bk is None:
+            return live, grid, grid
+        read = int(np.minimum(-(-lens // bk) * bk, self.max_len).sum())
+        if self._slot_req[0] is None:
+            read += self.chunk * bk
+        return live, read, grid
+
     def _chunk_step(self, step: Any) -> None:
         """The plain chunked-scan dispatch (step()'s pre-spec body),
         as five phase spans under `step`: `lm_dispatch`, `lm_pack`,
@@ -1438,6 +1485,12 @@ class LMServer:
                 self.params, self.cache, self._cur_dev, self._pos_dev,
                 jnp.asarray(self.rid_vec),
             )
+        # reckoned while the device works, before delivery moves `emitted`
+        live, read, grid = self._kv_rows()
+        _M_KV_LIVE.inc(live)
+        _M_KV_READ.inc(read)
+        _M_KV_GRID.inc(grid)
+        step.label(kv_rows_live=live, kv_rows_read=read)
         packed = self._read_packed(
             step, [jnp.ravel(toks)] + [v for _, v in firsts]
         )

@@ -1,42 +1,50 @@
 """Decode-step attention over the KV cache as a Pallas TPU kernel.
 
-One autoregressive step attends a [B, 1, H, D] query against the full
-[B, KV, T, D] cache — pure HBM streaming, ~zero FLOPs per byte. The
-XLA einsum path has two measured problems on v5e (bench
-`lm.decode_kv_heads_4k_ctx_b1` / `lm.kv_cache_int8_4k_ctx_b8`, r3):
+One autoregressive step attends a [B, 1, H, D] query against a
+[B, KV, T, D] cache of which slot b holds `lengths[b]` live rows —
+pure HBM streaming, ~zero FLOPs per byte, so the bytes fetched are the
+whole cost. Under continuous batching most of the grid is dead: a slot
+at its 350th token of a 4,096-row cache is 8% live, an empty slot 0%.
+The XLA einsum (inference/generate.py `batched_decode_step`, the CPU
+and test oracle) streams every row of every slot behind a mask; this
+kernel fetches only the k-blocks that hold live rows.
 
-- int8 KV caches (`LMConfig.kv_quant`): XLA does NOT fuse the dequant
-  into the attention contraction — it materializes the whole cache as
-  f32 in HBM first (4 bytes written + re-read per 1-byte cache
-  element), making the half-size cache 0.59x the bf16 one. This
-  kernel dequantizes inline: int8 values and f32 scales stream into
-  VMEM, the f32 cache never exists in HBM, so int8's bandwidth
-  advantage is real (capacity AND speed).
-- MQA (KV=1): the grouped einsum leaves a [T, 64]-shaped stream whose
-  trailing dim under-fills the 128-wide lanes, and XLA's schedule read
-  4x less cache yet ran 24% SLOWER than GQA-4. Here every (batch,
-  kv-head) program streams its cache block through VMEM once,
-  grouped-query rows [G, T] in one dot, so MQA's smaller cache
-  actually buys time.
-
-Structure: grid (B, KV, k-blocks), online-softmax accumulation across
+Structure: grid (B, k-blocks), online-softmax accumulation across
 k-blocks in VMEM scratch (the decode-shaped sibling of
 flash_attention.py's forward kernel — G = H/KV query rows instead of
-a q-block). Per-slot validity (continuous batching: every slot sits
-at its own position) arrives as an additive [B, T] bias computed by
-XLA — 0 for cache positions <= pos[b], -1e30 beyond — so the kernel
-needs no scalar prefetch and one code path serves single-request and
-batched decode.
+a q-block); one grid instance streams ALL kv heads' blocks. The
+per-slot lengths are a scalar-prefetch operand
+(`pltpu.PrefetchScalarGridSpec`), read by both halves:
 
-Math is f32 end-to-end like the einsum oracle it replaces
-(inference/generate.py `batched_decode_step`), so parity holds to
-float-associativity noise. The reference has no attention anywhere
-(SURVEY §0); this serves the net-new LM path.
+- the k/v (and int8 scale) `index_map`s clamp the block index to the
+  slot's last live block, and an empty slot names the block the slot
+  before it ended on. Pallas issues a DMA only when the block index
+  changes between grid steps, so blocks past a slot's length and whole
+  empty slots are never fetched (a run of empty slots at the head of
+  the grid shares one block);
+- the body runs under `pl.when(block_start < length)`; inside the one
+  partly-live block validity is `iota < length`: scores of dead rows
+  are replaced (a select, so stale NaN cannot leak), their v rows
+  zeroed. A slot of length 0 returns zeros.
+
+Two things the einsum does badly on TPU stay solved here: an int8
+cache (`LMConfig.kv_quant`) is dequantized inline (int8 values and f32
+scales stream into VMEM; XLA materializes the whole cache as f32 in
+HBM first), and MQA's [T, 64] stream does not under-fill the lanes.
+
+Softmax statistics and accumulators are f32; the MXU operands are in
+the cache's own dtype (q·k products of bf16 operands are exact in
+f32; p is rounded to the cache's dtype for the p·v dot, as XLA's
+default-precision einsum does on TPU). Parity with the oracle holds
+to float-associativity noise in f32 and to bf16 rounding of p in bf16.
+
+Measured on one TPU v5e (my chip run, PR 26): see
+`generate.uses_decode_kernel`. The reference has no attention
+anywhere (SURVEY §0); this serves the net-new LM path.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -50,10 +58,22 @@ NEG_INF = -1e30
 LANES = 128  # scratch rows kept [G, 128]: full native tiles
 
 
-def _decode_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, bias_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, quantized, n_kv):
+def block_rows(kv: int, d: int, dtype, t: int, block_k: int = 2048) -> int:
+    """Cache rows per k-block for a [*, kv, t, d] cache of `dtype`.
+    One grid instance holds all KV heads' blocks, so the block is
+    clamped to keep each stream's VMEM buffer (cache dtype; bf16
+    temporaries for int8) at ~1 MB: 512 rows at KV 8 x D 128 x 2 B.
+    Also what a caller needs to reckon the rows a step fetches."""
+    itemsize = max(jnp.dtype(dtype).itemsize, 2)
+    cap = max(128, (2**20) // (kv * d * itemsize) // 128 * 128)
+    return min(block_k, cap, t)
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
+                   m_scr, l_scr, acc_scr, *, scale, quantized, n_kv, bk):
     ik = pl.program_id(1)
     nk = pl.num_programs(1)
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(ik == 0)
     def _init():
@@ -61,42 +81,52 @@ def _decode_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, bias_ref, o_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    bias = bias_ref[:]  # [1, bk], shared by every head
-    # static per-head loop: one grid instance streams ALL kv heads'
-    # blocks (a per-(b, head) grid at decode sizes is dominated by
-    # instance overhead — measured 42us vs XLA's 35us before folding
-    # the head loop in)
-    for h in range(n_kv):
-        # MXU dots take the cache's own dtype (int8 -> bf16 is EXACT
-        # for |v| <= 127); the per-position scales fold into the [G,
-        # bk] score/probability rows AFTER the dot — 16x fewer
-        # multiplies than dequantizing the [bk, D] block, and no f32
-        # cache temporary in VMEM
-        k = k_ref[h]
-        if quantized:
-            k = k.astype(jnp.bfloat16)
-        s = jax.lax.dot_general(
-            q_ref[h].astype(k.dtype), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [G, bk] f32
-        if quantized:
-            s = s * ks_ref[h]  # [1, bk] f32 scale row, exact in f32
-        s = s + bias
+    @pl.when(ik * bk < length)
+    def _block():
+        # rows of this block that are live, along lanes (scores) and
+        # along sublanes (v rows); all true except in the last block
+        live_t = ik * bk + jax.lax.broadcasted_iota(
+            jnp.int32, (1, bk), 1) < length
+        live_r = ik * bk + jax.lax.broadcasted_iota(
+            jnp.int32, (bk, 1), 0) < length
+        # static per-head loop: one grid instance streams ALL kv heads'
+        # blocks (a per-(b, head) grid at decode sizes is dominated by
+        # instance overhead)
+        for h in range(n_kv):
+            # MXU dots take the cache's own dtype (int8 -> bf16 is
+            # EXACT for |v| <= 127); the per-position scales fold into
+            # the [G, bk] score/probability rows AFTER the dot — 16x
+            # fewer multiplies than dequantizing the [bk, D] block,
+            # and no f32 cache temporary in VMEM
+            k = k_ref[h]
+            if quantized:
+                k = k.astype(jnp.bfloat16)
+            s = jax.lax.dot_general(
+                q_ref[h].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [G, bk] f32
+            if quantized:
+                s = s * ks_ref[h]  # [1, bk] f32 scale row, exact in f32
+            s = jnp.where(live_t, s, NEG_INF)
 
-        m_prev = m_scr[h, :, :1]  # [G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[h, :, :1] * alpha + jnp.sum(p, -1, keepdims=True)
-        v = v_ref[h]
-        if quantized:
-            p = p * vs_ref[h]  # fold the v scales into the prob rows
-            v = v.astype(jnp.bfloat16)
-        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-        l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            m_prev = m_scr[h, :, :1]  # [G, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[h, :, :1] * alpha + jnp.sum(p, -1, keepdims=True)
+            v = v_ref[h]
+            if quantized:
+                # fold the v scales into the prob rows (a dead row's
+                # scale is stale too: select, don't multiply)
+                p = jnp.where(live_t, p * vs_ref[h], 0.0)
+                v = v.astype(jnp.bfloat16)
+            # p is exactly 0 on dead rows, but 0 x stale NaN is NaN
+            v = jnp.where(live_r, v, jnp.zeros_like(v))
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32
+            )
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -109,7 +139,7 @@ def decode_attention(
     q: jax.Array,  # [B, 1, H, D]
     k: jax.Array,  # [B, KV, T, D] cache (cfg dtype, or int8 with scales)
     v: jax.Array,  # [B, KV, T, D]
-    pos: jax.Array,  # [B] int32 — slot b attends cache positions <= pos[b]
+    lengths: jax.Array,  # [B] int32 — slot b attends cache rows < lengths[b]
     *,
     k_scale: Optional[jax.Array] = None,  # [B, KV, 1, T] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
@@ -118,6 +148,12 @@ def decode_attention(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """One decode step of cache attention; returns [B, 1, H, D] f32.
+
+    `lengths[b]` is the number of cache rows slot b attends: `pos + 1`
+    for a slot that has just written row `pos`, 0 for an empty slot
+    (which returns zeros and fetches nothing); values are clipped to
+    [0, T]. Rows at or past a slot's length are never read into the
+    result, whatever they hold.
 
     The cache is head-major ([B, KV, T, D] — `init_cache`'s layout):
     each head's [T, D] plane is contiguous, so the blocked axes are
@@ -139,64 +175,66 @@ def decode_attention(
     scale = d ** -0.5 if scale is None else scale
     interpret = _interpret_default() if interpret is None else interpret
 
-    # one grid instance holds all KV heads' blocks: clamp bk so each
-    # stream's VMEM block (cache dtype; bf16 temporaries for int8)
-    # stays ~<=1 MB
-    itemsize = max(jnp.dtype(k.dtype).itemsize, 2)
-    bk_cap = max(128, (2**20) // (kv * d * itemsize) // 128 * 128)
-    bk = min(block_k, bk_cap, t)
-    pad = (-t) % bk
-    bias = jnp.where(
-        jnp.arange(t)[None, :] <= pos[:, None], 0.0, NEG_INF
-    ).astype(jnp.float32)[:, None, :]  # [B, 1, T]
-    if pad:
-        p4 = ((0, 0), (0, 0), (0, pad), (0, 0))
-        k = jnp.pad(k, p4)
-        v = jnp.pad(v, p4)
-        if quantized:
-            pT = ((0, 0), (0, 0), (0, 0), (0, pad))
-            k_scale = jnp.pad(k_scale, pT)
-            v_scale = jnp.pad(v_scale, pT)
-        bias = jnp.pad(bias, ((0, 0), (0, 0), (0, pad)),
-                       constant_values=NEG_INF)
-    nk = (t + pad) // bk
+    bk = block_rows(kv, d, k.dtype, t, block_k)
+    # a ragged last block needs no padded copy of the cache: whatever
+    # the out-of-range rows read as lies past every slot's length
+    nk = pl.cdiv(t, bk)
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, t)
+    # the slot whose blocks are on show at grid row b: b itself if it
+    # is live, else the last live slot before it (slot 0 if none)
+    src = jax.lax.cummax(
+        jnp.where(lengths > 0, jnp.arange(b, dtype=jnp.int32), 0)
+    )
+
+    def cache_block(b_, j, len_ref, src_ref):
+        s = src_ref[b_]
+        last = jnp.maximum(pl.cdiv(len_ref[s], bk) - 1, 0)
+        return s, jnp.where(len_ref[b_] > 0, jnp.minimum(j, last), last)
+
+    def kv_map(b_, j, len_ref, src_ref):
+        s, blk = cache_block(b_, j, len_ref, src_ref)
+        return s, 0, blk, 0
+
+    def sc_map(b_, j, len_ref, src_ref):
+        s, blk = cache_block(b_, j, len_ref, src_ref)
+        return s, 0, 0, blk
 
     qg = q[:, 0].reshape(b, kv, g, d)
-    q_spec = pl.BlockSpec((None, kv, g, d), lambda b_, j: (b_, 0, 0, 0))
-    kv_spec = pl.BlockSpec((None, kv, bk, d), lambda b_, j: (b_, 0, j, 0))
-    sc_spec = pl.BlockSpec((None, kv, 1, bk), lambda b_, j: (b_, 0, 0, j))
-    bias_spec = pl.BlockSpec((None, 1, bk), lambda b_, j: (b_, 0, j))
+    q_spec = pl.BlockSpec((None, kv, g, d), lambda b_, j, *_: (b_, 0, 0, 0))
+    kv_spec = pl.BlockSpec((None, kv, bk, d), kv_map)
+    sc_spec = pl.BlockSpec((None, kv, 1, bk), sc_map)
 
     if quantized:
-        ins = (qg, k, k_scale, v, v_scale, bias)
-        in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec, bias_spec]
+        ins = (qg, k, k_scale, v, v_scale)
+        in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
     else:
         # the scale streams don't exist: don't DMA dummy buffers
-        ins = (qg, k, v, bias)
-        in_specs = [q_spec, kv_spec, kv_spec, bias_spec]
+        ins = (qg, k, v)
+        in_specs = [q_spec, kv_spec, kv_spec]
 
-    def kernel(*refs):
+    def kernel(len_r, _src_r, q_r, *refs):  # src: the index maps' alone
         if quantized:
-            q_r, k_r, ks_r, v_r, vs_r, b_r, o_r = refs[:7]
-            scr = refs[7:]
+            k_r, ks_r, v_r, vs_r, *rest = refs
         else:
-            q_r, k_r, v_r, b_r, o_r = refs[:5]
+            k_r, v_r, *rest = refs
             ks_r = vs_r = None
-            scr = refs[5:]
-        _decode_kernel(q_r, k_r, ks_r, v_r, vs_r, b_r, o_r, *scr,
-                       scale=scale, quantized=quantized, n_kv=kv)
+        _decode_kernel(len_r, q_r, k_r, ks_r, v_r, vs_r, *rest,
+                       scale=scale, quantized=quantized, n_kv=kv, bk=bk)
 
     out = pl.pallas_call(
         kernel,
-        grid=(b, nk),
-        in_specs=in_specs,
-        out_specs=q_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, nk),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((kv, g, LANES), jnp.float32),  # running max
+                pltpu.VMEM((kv, g, LANES), jnp.float32),  # running denom
+                pltpu.VMEM((kv, g, d), jnp.float32),      # output accum
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, d), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((kv, g, LANES), jnp.float32),  # running max
-            pltpu.VMEM((kv, g, LANES), jnp.float32),  # running denom
-            pltpu.VMEM((kv, g, d), jnp.float32),      # output accum
-        ],
         interpret=interpret,
-    )(*ins)
+    )(lengths, src, *ins)
     return out.reshape(b, 1, h, d)

@@ -538,9 +538,12 @@ def _served_mixed_budgets(params):
     def metrics():
         hist = METRICS.histogram("lm_server_first_token_seconds").items()
         tok = METRICS.counter("lm_server_prefill_tokens_total")
+        rows = METRICS.counter("lm_server_decode_kv_rows_total")
         return {"first_token_count": sum(v[0] for _, v in hist),
                 "prompt": tok.value(kind="prompt"),
-                "padded": tok.value(kind="padded")}
+                "padded": tok.value(kind="padded"),
+                **{"kv_" + k: rows.value(kind=k)
+                   for k in ("live", "read", "grid")}}
 
     rng = np.random.RandomState(11)
     reqs = [(rng.randint(0, CFG.vocab_size, n), b) for n, b in
@@ -688,3 +691,125 @@ def test_driver_records_idle_submit_and_request_parent(params):
             if d["name"] == "lm_request"}
     assert (reqs[6]["tid"], reqs[6]["par"]) == ("tREQ", "sINFER")
     assert reqs[12]["par"] == "" and reqs[12]["tid"] != "tREQ"
+
+
+# ----------------------------------------------------------------------
+# live lengths: the kernel route, empty slots, the kv_rows accounting
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """Cache attention through the Pallas kernel, as on a TPU; on the
+    CPU it interprets. Function-scoped: every program traced under it
+    belongs to a server (or an eager call) made inside the test."""
+    from dml_tpu.inference import generate as g
+
+    monkeypatch.setattr(g, "uses_decode_kernel", lambda: True)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-4),
+                                        (jnp.bfloat16, 6e-2)])
+def test_decode_step_kernel_route_matches_einsum(
+        params, monkeypatch, dtype, atol):
+    """A grouped cache (4 heads / 2 KV), ragged positions, two EMPTY
+    slots whose pos is pinned at the last row and whose length is 0,
+    stale garbage in every row: the kernel route gives the einsum
+    route's logits for the live slots and its whole cache."""
+    import dataclasses
+
+    from dml_tpu.inference import generate as g
+
+    cfg = dataclasses.replace(CFG, dtype=dtype)
+    b, max_len = 5, 48
+    rng = np.random.RandomState(20)
+    cache = jax.tree_util.tree_map(
+        lambda x: jnp.asarray(rng.standard_normal(x.shape), x.dtype),
+        g.init_cache(cfg, b, max_len))
+    tokens = jnp.asarray(rng.randint(0, cfg.vocab_size, b), jnp.int32)
+    pos = jnp.asarray([3, max_len - 1, 17, max_len - 1, 31], jnp.int32)
+    lengths = jnp.asarray([4, 0, 18, 0, 32], jnp.int32)
+
+    def step():
+        logits, new = g.batched_decode_step(
+            params, cfg, cache, tokens, pos, lengths=lengths)
+        return np.asarray(logits), jax.tree_util.tree_map(
+            lambda x: np.asarray(x.astype(jnp.float32)), new)
+
+    want_logits, want_cache = step()
+    monkeypatch.setattr(g, "uses_decode_kernel", lambda: True)
+    got_logits, got_cache = step()
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(
+        got_logits[live], want_logits[live], atol=atol)
+    assert np.isfinite(got_logits).all()
+    jax.tree_util.tree_map(
+        lambda a, b_: np.testing.assert_allclose(a, b_, atol=atol),
+        got_cache, want_cache)
+    # and `pos + 1` is the default: the live slots read the same
+    np.testing.assert_allclose(
+        np.asarray(g.batched_decode_step(
+            params, cfg, cache, tokens, pos)[0])[live],
+        got_logits[live], atol=atol)
+
+
+def test_kernel_route_serves_the_same_tokens_through_reused_slots(
+        params, served, kernel_route):
+    """Seven requests through three slots on the kernel route: slots
+    free and refill, a freed slot's device pos stays pinned high while
+    its length is 0 — and every request gets the tokens the einsum
+    route served (the module's `served`, itself held to `generate`)."""
+    _, reqs, rids, out, _, _ = served
+    srv, _, k_rids, k_out, spans, delta = _served_mixed_budgets(params)
+    for rid, k_rid, (_, n) in zip(rids, k_rids, reqs):
+        np.testing.assert_array_equal(k_out[k_rid], out[rid])
+        assert k_out[k_rid].size == n
+    _check_kv_rows(srv, spans, delta)
+    # one block is the whole 64-row cache here, so what was skipped
+    # is the empty slots
+    assert delta["kv_read"] < delta["kv_grid"]
+
+
+def _check_kv_rows(srv, spans, delta):
+    steps = [d["lb"] for d in spans if d["name"] == "lm_step"]
+    grid = srv.chunk * srv.max_slots * srv.max_len
+    for lb in steps:
+        assert 0 < lb["kv_rows_live"] <= lb["kv_rows_read"] <= grid
+        # a live slot attends at least its prompt and at most max_len
+        assert lb["kv_rows_live"] <= lb["occupancy"] * srv.chunk * srv.max_len
+    assert delta["kv_live"] == sum(lb["kv_rows_live"] for lb in steps)
+    assert delta["kv_read"] == sum(lb["kv_rows_read"] for lb in steps)
+    assert delta["kv_grid"] == len(steps) * grid
+
+
+def test_kv_rows_labels_and_counter_add_up(served):
+    """On the einsum route every step streams the whole grid, and the
+    counter says so: read == grid."""
+    srv, _, _, _, spans, delta = served
+    _check_kv_rows(srv, spans, delta)
+    assert delta["kv_read"] == delta["kv_grid"]
+    assert 0 < delta["kv_live"] < delta["kv_grid"]
+
+
+def test_kv_rows_arithmetic_by_hand(params, monkeypatch, kernel_route):
+    """`_kv_rows` against hand arithmetic, at a block of 16 rows."""
+    from dml_tpu.inference import lm_server
+
+    monkeypatch.setattr(lm_server, "decode_block_rows", lambda *a: 16)
+    rng = np.random.RandomState(21)
+    srv = LMServer(params, CFG, max_slots=3, max_len=64, chunk=4)
+    # a budget of 1 retires at placement: slot 0 is empty again
+    srv.submit_many([rng.randint(0, CFG.vocab_size, n) for n in (5, 7, 23)],
+                    [1, 9, 9])
+    assert [r is not None for r in srv._slot_req] == [False, True, True]
+    assert list(srv.rid_vec) == [0, 2, 3]
+    # slot 1 attends 8..11 rows (one block of 16 each step), slot 2
+    # 24..27 (two blocks); the empty slot at the head costs one block
+    # a step, which every empty slot behind a live one shares
+    assert srv._kv_rows() == (38 + 102, 4 * 16 + 4 * 32 + 4 * 16,
+                              4 * 3 * 64)
+    srv.run()
+    # near max_len: lengths clamp at the last row, blocks at the cache
+    srv2 = LMServer(params, CFG, max_slots=1, max_len=64, chunk=4)
+    srv2.submit(rng.randint(0, CFG.vocab_size, 61), 3)
+    assert srv2._kv_rows() == (62 + 63 + 64 + 64, 4 * 64, 4 * 64)

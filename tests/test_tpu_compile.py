@@ -54,15 +54,22 @@ def compile_on_chip(topo, fn, *shapes):
     return text, time.monotonic() - t0
 
 
-B, H, D, T = 8, 16, 64, 4096  # the LM phase's decode shapes
+SMOKE = (8, 16, 64, 4096)  # B, H, D, T: the LM phase's decode shapes
+CELL = (16, 32, 128, 4096)  # the benchmark's mistral7b_widths_l8
 
 
-@pytest.mark.parametrize("kv,int8", [(4, False), (16, False), (1, False),
-                                     (4, True)])
-def test_decode_attention_compiles(topo, kv, int8):
+@pytest.mark.parametrize("shape,kv,int8", [
+    (SMOKE, 4, False), (SMOKE, 16, False), (SMOKE, 1, False),
+    (SMOKE, 4, True),
+    (CELL, 8, False),
+    ((8, 16, 64, 1000), 4, True),  # a ragged last k-block, no padded copy
+])
+def test_decode_attention_compiles(topo, shape, kv, int8):
     from dml_tpu.ops.decode_attention import decode_attention
 
-    q = ((B, 1, H, D), jnp.float32)
+    B, H, D, T = shape
+    # bf16 is what `rope` hands the kernel under a bf16 config
+    q = ((B, 1, H, D), jnp.bfloat16 if shape is CELL else jnp.float32)
     pos = ((B,), jnp.int32)
     if int8:
         cache, scale = ((B, kv, T, D), jnp.int8), ((B, kv, 1, T), jnp.float32)
@@ -164,5 +171,43 @@ def test_tp_sharded_lm_programs_compile(topo, monkeypatch, kv_quant):
     text = jax.jit(
         lambda p, c, t, q: batched_decode_step(p, cfg, c, t, q, mesh=mesh)
     ).lower(params, cache, vec, vec).compile().as_text()
-    # grouped bf16 caches stay on the einsum; int8 caches take the kernel
-    assert ("tpu_custom_call" in text) == kv_quant
+    # every cache layout takes the kernel, each device on its own heads
+    assert "tpu_custom_call" in text
+
+
+def test_chunk_program_of_a_grouped_bf16_config_holds_the_kernel(
+        topo, monkeypatch):
+    """The benchmark cell's decode program — `LMServer._chunk_impl`
+    itself, at mistral7b_widths_l8's widths and slot grid (depth cut to
+    1 layer) — compiles for the chip under the name the cell's
+    `trace_modules` looks for and holds the cache-attention kernel:
+    what `LMServer.kernel_report()["decode"]` reports on a chip. A
+    server cannot be built here (it allocates its cache on a device),
+    so the method runs on a bare instance holding what it reads."""
+    from dml_tpu.inference.generate import LMConfig, init_cache
+    from dml_tpu.inference.lm_server import LMServer
+    from dml_tpu.models.transformer import TransformerLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LMConfig(32000, 4096, 32, 1, 14336, dtype=jnp.bfloat16,
+                   n_kv_heads=8)
+    slots, max_len = 16, 4096
+    srv = object.__new__(LMServer)
+    srv.cfg, srv.max_len, srv.chunk, srv.temperature = cfg, max_len, 32, 0.0
+    srv._mesh = srv._gather_shardings = None
+    model = TransformerLM(
+        vocab_size=cfg.vocab_size, d_model=cfg.d_model, n_heads=cfg.n_heads,
+        n_layers=cfg.n_layers, d_ff=cfg.d_ff, dtype=cfg.dtype,
+        n_kv_heads=cfg.n_kv_heads,
+    )
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = functools.partial(jax.tree_util.tree_map, lambda s: (
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)))
+    params = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    text = jax.jit(srv._chunk_impl).lower(
+        params, cache, vec, vec, vec).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert text.lstrip().startswith("HloModule jit__chunk_impl")
