@@ -32,10 +32,10 @@ import numpy as np
 
 log = logging.getLogger(__name__)
 
-from ..jobs.cost_model import ModelCost
+from ..jobs.cost_model import ModelCost, lm_request_forwards
 from ..tracing import current_all_ctxs
 from .generate import LMConfig
-from .lm_server import LMDriver, LMServer
+from .lm_server import REMASKING, BlockDiffusion, LMDriver, LMServer
 
 
 def parse_prompt_file(
@@ -98,22 +98,179 @@ def parse_prompt_file(
     return ids, budget
 
 
+#: `lm_spec` keys that describe an architecture beyond TransformerLM's
+#: block. A spec that sets none of them is served as before: the flax
+#: module's tree, float32 storage.
+_ARCH_KEYS = (
+    "head_dim", "rope_theta", "qk_norm", "num_experts", "experts_per_token",
+    "expert_d_ff", "gated", "experts_held", "attention_mask",
+    "block_length", "param_dtype",
+)
+_DTYPES = ("bfloat16", "float32")
+
+
+def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
+    """The architecture an `lm_spec` describes, checked: every value
+    the serving code cannot honour raises here, before any weight is
+    made. Keys of other layers (`name`, `max_slots`, `kv_cache_mb`,
+    `spec_k`, ...) are not this function's and pass."""
+    d_model, heads = int(spec["d_model"]), int(spec.get("n_heads", 8))
+    a: Dict[str, Any] = {
+        "head_dim": int(spec.get("head_dim") or d_model // heads),
+        "rope_theta": float(spec.get("rope_theta", 10000.0)),
+        "qk_norm": bool(spec.get("qk_norm", False)),
+        "num_experts": int(spec.get("num_experts", 0) or 0),
+        "experts_per_token": int(spec.get("experts_per_token", 2)),
+        "gated": bool(spec.get("gated", False)),
+        "attention_mask": spec.get("attention_mask", "causal"),
+        "block_length": int(spec.get("block_length", 1)),
+        "param_dtype": spec.get("param_dtype", "float32"),
+    }
+    a["expert_d_ff"] = int(
+        spec.get("expert_d_ff") or spec.get("d_ff", 4 * d_model))
+    first, count = (
+        int(n) for n in spec.get("experts_held") or (0, a["num_experts"]))
+    a["experts_held"] = (first, count)
+    if a["head_dim"] < 2 or a["head_dim"] % 2:
+        raise ValueError(
+            f"head_dim {a['head_dim']}: rope rotates halves of a head "
+            f"and the attention kernels tile it; it must be even")
+    if a["rope_theta"] <= 0:
+        raise ValueError(f"rope_theta {a['rope_theta']}")
+    if a["attention_mask"] not in ("causal", "block_causal"):
+        raise ValueError(
+            f"unknown attention_mask {a['attention_mask']!r} "
+            f"(causal | block_causal)")
+    if a["block_length"] < 1 or (
+        a["attention_mask"] == "causal" and a["block_length"] != 1
+    ):
+        raise ValueError(
+            f"block_length {a['block_length']} under "
+            f"{a['attention_mask']!r} attention")
+    if a["param_dtype"] not in _DTYPES or spec.get(
+            "dtype", "bfloat16") not in _DTYPES:
+        raise ValueError(
+            f"dtype {spec.get('dtype')!r} / param_dtype "
+            f"{a['param_dtype']!r}: one of {_DTYPES}")
+    if a["num_experts"]:
+        e, k = a["num_experts"], a["experts_per_token"]
+        if not 0 < k <= e:
+            raise ValueError(f"experts_per_token {k} of {e} experts")
+        if first < 0 or count < 1 or first + count > e:
+            raise ValueError(
+                f"experts_held {[first, count]} lies outside the "
+                f"{e} routed experts")
+    elif spec.get("experts_held") or a["gated"]:
+        raise ValueError("experts_held / gated without num_experts")
+    if spec.get("denoising_steps") is not None or a["block_length"] > 1:
+        if a["attention_mask"] != "block_causal":
+            raise ValueError(
+                "denoising_steps needs attention_mask block_causal")
+        if int(spec.get("denoising_steps", 1)) < 1:
+            raise ValueError(
+                f"denoising_steps {spec.get('denoising_steps')}")
+        if spec.get("remasking", REMASKING[0]) not in REMASKING:
+            raise ValueError(
+                f"unknown remasking {spec.get('remasking')!r} "
+                f"(known: {REMASKING})")
+        mask_id = spec.get("mask_token_id")
+        if mask_id is None or not 0 <= int(mask_id) < int(
+                spec["vocab_size"]):
+            raise ValueError(
+                f"mask_token_id {mask_id!r} is no id of the vocabulary")
+    return a
+
+
+def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
+    """The weight tree of an architecture `lm_arch` describes, in ONE
+    jitted call: every matrix normal(0, 1 / sqrt(fan_in)) rounded to
+    `param_dtype` (what flax's defaults give TransformerLM), norms at 1
+    in float32, the router in float32. The layout is the one the
+    serving code indexes (`generate._apply_block`): `qkv` fused
+    [d, H*D + 2*KV*D], `proj` [H*D, d], `q_norm`/`k_norm` [D] under
+    `qk_norm`, and either `up`/`down` or, in an expert layer, `moe`
+    {router [d, E], w_up and w_down (and w_gate) stacked over the
+    experts HELD}."""
+    import jax
+    import jax.numpy as jnp
+
+    d, hd, kvw = cfg.d_model, cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    pdt = jnp.dtype(arch["param_dtype"])
+    held, f = arch["experts_held"][1], arch["expert_d_ff"]
+    shapes: Dict[str, Any] = {"embed": {"embedding": (cfg.vocab_size, d)}}
+    for i in range(cfg.n_layers):
+        blk: Dict[str, Any] = {
+            "ln_attn": {"scale": (d,)}, "ln_mlp": {"scale": (d,)},
+            "qkv": {"kernel": (d, cfg.q_width + 2 * kvw)},
+            "proj": {"kernel": (cfg.q_width, d)},
+        }
+        if cfg.qk_norm:
+            blk["q_norm"] = {"scale": (hd,)}
+            blk["k_norm"] = {"scale": (hd,)}
+        if arch["num_experts"]:
+            blk["moe"] = {
+                "router": {"kernel": (d, arch["num_experts"])},
+                "w_up": (held, d, f), "w_down": (held, f, d),
+            }
+            if arch["gated"]:
+                blk["moe"]["w_gate"] = (held, d, f)
+        else:
+            blk["up"] = {"kernel": (d, cfg.d_ff)}
+            blk["down"] = {"kernel": (cfg.d_ff, d)}
+        shapes[f"block_{i}"] = blk
+    shapes["ln_out"] = {"scale": (d,)}
+    shapes["lm_head"] = {"kernel": (d, cfg.vocab_size)}
+    is_shape = lambda x: isinstance(x, tuple)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=is_shape)
+
+    def make(key):
+        out = []
+        for i, (path, shape) in enumerate(flat):
+            name = [getattr(p, "key", "") for p in path]
+            if name[-1] == "scale":
+                out.append(jnp.ones(shape, jnp.float32))
+                continue
+            # the contracted axis: the second to last of a (stacked)
+            # kernel, the last of the embedding table
+            fan_in = shape[-1] if name[-1] == "embedding" else shape[-2]
+            w = jax.random.normal(
+                jax.random.fold_in(key, i), shape, jnp.float32
+            ) * fan_in ** -0.5
+            out.append(
+                w if "router" in name else w.astype(pdt))
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    return jax.jit(make)(jax.random.PRNGKey(int(seed)))
+
+
 def lm_spec_parts(spec: Dict[str, Any]):
     """(params, LMConfig) from a JSON-able LM spec — the construction
     half of `LMBackend.from_spec`, shared with the tp-sharded serving
     forms (inference/lm_sharded.py) which place the SAME deterministic
     tree with mesh shardings instead of single-device. Weights init
     from `seed` (identical tree on every node that loads the spec)
-    unless `weights` names a flax-msgpack file."""
+    unless `weights` names a flax-msgpack file.
+
+    The architecture is data (`lm_arch` checks it and raises on a
+    value the serving code cannot honour). A spec with none of
+    `_ARCH_KEYS` is TransformerLM's block, initialised by the flax
+    module and stored in float32 as ever; one that sets any of them
+    (another head size, a rope base, q/k norms, gated top-k experts,
+    the block-causal mask, `param_dtype`) gets `init_lm_params`' tree,
+    its matrices stored in `param_dtype`, every layer an expert layer
+    where `num_experts` is set."""
     import jax
     import jax.numpy as jnp
 
     from ..models.transformer import TransformerLM
 
+    arch = lm_arch(spec)
     dtype = {
         "bfloat16": jnp.bfloat16, "float32": jnp.float32,
     }[spec.get("dtype", "bfloat16")]
     d_model = int(spec["d_model"])
+    described = any(spec.get(k) is not None for k in _ARCH_KEYS)
     cfg = LMConfig(
         vocab_size=int(spec["vocab_size"]),
         d_model=d_model,
@@ -126,16 +283,28 @@ def lm_spec_parts(spec: Dict[str, Any]):
             if spec.get("n_kv_heads") is not None else None
         ),
         kv_quant=bool(spec.get("kv_quant", False)),
+        **({
+            "d_head": arch["head_dim"],
+            "rope_theta": arch["rope_theta"],
+            "qk_norm": arch["qk_norm"],
+            "experts_per_token": arch["experts_per_token"],
+            "experts_first": arch["experts_held"][0],
+            "attention_mask": arch["attention_mask"],
+            "block_length": arch["block_length"],
+        } if described else {}),
     )
-    model = TransformerLM(
-        vocab_size=cfg.vocab_size, d_model=cfg.d_model,
-        n_heads=cfg.n_heads, n_layers=cfg.n_layers, d_ff=cfg.d_ff,
-        dtype=cfg.dtype, n_kv_heads=cfg.n_kv_heads,
-    )
-    params = model.init(
-        jax.random.PRNGKey(int(spec.get("seed", 0))),
-        jnp.zeros((1, 8), jnp.int32),
-    )["params"]
+    if described:
+        params = init_lm_params(cfg, arch, int(spec.get("seed", 0)))
+    else:
+        model = TransformerLM(
+            vocab_size=cfg.vocab_size, d_model=cfg.d_model,
+            n_heads=cfg.n_heads, n_layers=cfg.n_layers, d_ff=cfg.d_ff,
+            dtype=cfg.dtype, n_kv_heads=cfg.n_kv_heads,
+        )
+        params = model.init(
+            jax.random.PRNGKey(int(spec.get("seed", 0))),
+            jnp.zeros((1, 8), jnp.int32),
+        )["params"]
     if spec.get("weights"):
         from ..models.params_io import variables_from_bytes
 
@@ -178,13 +347,14 @@ class LMBackend:
         spec_k: int = 0,
         spec_draft: Optional[Dict[str, Any]] = None,
         spec_min_accept: Optional[float] = None,
+        diffusion: Optional[BlockDiffusion] = None,
     ):
         self.cfg = cfg
         self.max_new_tokens = max_new_tokens
         self.server = LMServer(
             params, cfg, max_slots=max_slots, max_len=max_len,
             chunk=chunk, temperature=temperature, top_k=top_k, seed=seed,
-            gather_shardings=gather_shardings,
+            gather_shardings=gather_shardings, diffusion=diffusion,
         )
         # speculative decoding (spec_k > 0): a deterministic DRAFT
         # model from `spec_draft` (a model spec dict — normally
@@ -225,6 +395,11 @@ class LMBackend:
         # measured serving constants for the scheduler's cost model
         # (folded from real ACKs after the first batch either way)
         self._per_query = 0.05
+        # a block-diffusion model is priced by forwards, not tokens:
+        # seconds one forward over the grid took in the last dispatch
+        # (None until one was measured), times the forwards a request
+        # of the default budget costs, shared by the grid's slots
+        self._per_forward: Optional[float] = None
         # Concurrency: the LMServer is single-threaded MUTABLE state,
         # but serving callers are many (co-located workers, preemption
         # orphans). Two modes (VERDICT r4 item 2):
@@ -305,11 +480,12 @@ class LMBackend:
         # contexts by local path (contextvars ride asyncio.to_thread)
         by_path = {c.key: c for c in current_all_ctxs() if c.sampled}
         trace = [by_path.get(p) for p in paths] if by_path else None
+        fixed: List[Dict[str, Any]] = []
         if self.overlap:
             t0 = time.monotonic()
             toks = self.driver.serve(
                 prompts, budgets, on_dispatch=on_dispatch,
-                on_token=cbs, trace=trace,
+                on_token=cbs, trace=trace, fixed_at=fixed,
             )
             infer_time = time.monotonic() - t0
             results = {
@@ -329,16 +505,23 @@ class LMBackend:
                 # would also consume (and discard) results of any
                 # in-flight driver tickets sharing the grid
                 done = self.server.run(rids)
+                steps = self.server.take_fixed_at(rids)
+                fixed = [steps.get(rid) for rid in rids]
                 infer_time = time.monotonic() - t0
             results = {
                 p: {"tokens": [int(t) for t in done[rid]]}
                 for p, rid in zip(paths, rids)
             }
+        if self.server.diffusion is not None:
+            # per token, the denoising step of its block that fixed it
+            for p, steps in zip(paths, fixed):
+                results[p].update(steps)
         if paths:
             # overlap mode: a ticket's wall includes sharing the grid
             # with other in-flight batches — that IS its marginal
             # serving cost, which is what the fair-share model wants
             self._per_query = infer_time / len(paths)
+            self._per_forward = self.server.forward_seconds
         return results, infer_time, self.cost_constants()
 
     async def backend(
@@ -395,11 +578,28 @@ class LMBackend:
         conflate co-resident servers)."""
         return int(self.server.tokens_delivered)
 
+    def forwards_per_request(self, new_tokens: Optional[int] = None) -> int:
+        """Forwards over the grid a request of `new_tokens` (default:
+        the backend's budget) costs (`cost_model.lm_request_forwards`)."""
+        df = self.server.diffusion
+        return lm_request_forwards(
+            self.max_new_tokens if new_tokens is None else new_tokens,
+            self.cfg.block_length, df.steps if df is not None else 1)
+
+    def _priced(self) -> float:
+        """Seconds a request costs the scheduler: the measured wall a
+        query of the last ticket, or, for a block-diffusion model once
+        a dispatch was measured, forwards x seconds a forward ÷ slots."""
+        if self._per_forward is None:
+            return self._per_query
+        return (self._per_forward * self.forwards_per_request()
+                / self.server.max_slots)
+
     def cost_constants(self) -> Dict[str, float]:
         return {
             "load_time": 0.0,
-            "first_query": self._per_query,
-            "per_query": self._per_query,
+            "first_query": self._priced(),
+            "per_query": self._priced(),
             "batch_size": self.server.max_slots,
         }
 
@@ -407,8 +607,8 @@ class LMBackend:
         """Initial scheduler cost (refined from ACK measurements)."""
         return ModelCost(
             load_time=0.0,
-            first_query=self._per_query,
-            per_query=self._per_query,
+            first_query=self._priced(),
+            per_query=self._priced(),
             download_time=0.0,
             batch_size=self.server.max_slots,
         )
@@ -672,6 +872,17 @@ class LMBackend:
             spec_k=int(spec.get("spec_k", 0) or 0),
             spec_draft=LMBackend._draft_spec_of(spec),
             spec_min_accept=spec.get("spec_min_accept"),
+            # a block_causal model generates by diffusion over blocks:
+            # {"denoising_steps": 2, "remasking": ..., "mask_token_id": N}
+            # (lm_arch has checked them); `chunk` is then the tokens a
+            # slot a dispatch, in whole blocks
+            diffusion=(
+                BlockDiffusion(
+                    steps=int(spec.get("denoising_steps", 1)),
+                    mask_token_id=int(spec["mask_token_id"]),
+                    remasking=spec.get("remasking", REMASKING[0]),
+                ) if cfg.mask_block > 1 else None
+            ),
         )
         # operators pick the serving concurrency mode per deployment
         # ({"overlap": false}): the driver's cross-batch batching wins

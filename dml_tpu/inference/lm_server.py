@@ -26,6 +26,16 @@ sequences decoding together in ONE compiled program:
   the slot — no drain barrier, which is the whole point of continuous
   batching.
 
+A block-diffusion model (`LMServer(diffusion=BlockDiffusion(...))`, a
+`block_causal` `LMConfig`) is served by the same grid, queue, placement
+and driver with another dispatch (`_diffuse_impl`, `_diffuse_step`):
+every occupied slot advances by whole blocks, each block S denoising
+forwards and one commit forward, so a forward yields 0..B tokens a
+slot and a request is priced in forwards; requests join at a
+dispatch's end and leave after the block that meets their budget
+(tests/test_block_diffusion.py pins it against the benchmark's plain
+reference).
+
 Correctness contract (pinned by tests/test_lm_server.py): greedy
 outputs are IDENTICAL to running `generate` per request in isolation —
 batching is a throughput decision, never a semantics change. The
@@ -87,6 +97,7 @@ from ..tracing import TRACER, TraceContext
 from .generate import (
     LMConfig,
     _sample,
+    batched_block_step,
     batched_decode_step,
     batched_verify_step,
     decode_block_rows,
@@ -167,6 +178,35 @@ _M_OCCUPANCY = METRICS.histogram(
     "lm_server_slot_occupancy",
     "occupied slots per decode dispatch (grid utilization — the "
     "continuous-batching win/loss ledger)")
+_M_FORWARDS = METRICS.counter(
+    "lm_server_forwards_total",
+    "block-diffusion forwards over the slot grid by kind=: denoise "
+    "(a block's tokens against the cache and themselves, with the "
+    "head) and commit (the final tokens once more, their rows kept, "
+    "no head)")
+_M_FWD_DENOISE = _M_FORWARDS.labels(kind="denoise")
+_M_FWD_COMMIT = _M_FORWARDS.labels(kind="commit")
+_M_FIXED = METRICS.counter(
+    "lm_server_tokens_fixed_total",
+    "tokens fixed by denoising forwards and delivered to requests "
+    "(a prompt's tail in its first block and a last block's rows past "
+    "the budget are not counted)")
+_M_BLOCKS = METRICS.counter(
+    "lm_server_blocks_committed_total",
+    "blocks committed for occupied slots (a slot that finished inside "
+    "a dispatch still runs the dispatch's later blocks: counted too)")
+_M_MOE_ASSIGN = METRICS.counter(
+    "moe_assignments_total",
+    "(token, expert) assignments of occupied slots' tokens over the "
+    "expert layers of block-diffusion forwards")
+_M_MOE_TOUCHED = METRICS.histogram(
+    "moe_experts_touched",
+    "distinct routed experts one forward's tokens reach in one layer "
+    "(whose weights that forward has to read)")
+_M_MOE_LOAD = METRICS.histogram(
+    "moe_expert_load_max",
+    "assignments to the busiest expert over the mean over all routed "
+    "experts, one forward one layer")
 _M_SPEC_PROPOSED = METRICS.counter(
     "lm_specdec_proposed_total",
     "draft tokens proposed to the verify program")
@@ -235,6 +275,12 @@ class _Request:
     # rounds accepted spec_accepted draft tokens for this request)
     spec_rounds: int = 0
     spec_accepted: int = 0
+    # block diffusion only: per delivered token, the denoising step
+    # (1..S) of its block that fixed it
+    fixed_at: List[int] = dataclasses.field(default_factory=list)
+    # ... and what the last block held past the budget: (tokens, steps)
+    # of its surplus positions, so that the block can be rebuilt whole
+    beyond: Tuple[List[int], List[int]] = ((), ())
 
     def deliver(self, toks) -> None:
         """Append read-back token values to `out`, firing `on_token`
@@ -307,6 +353,40 @@ class _SpecState:
     rounds: int = 0
 
 
+#: the remasking rules a block-diffusion server knows
+REMASKING = ("low_confidence_static",)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """How a block-diffusion LM generates (`LMServer(diffusion=...)`;
+    the block length and the mask are the model's: `LMConfig`).
+
+    The sequence is tiled in blocks of B from position 0. Whole blocks
+    of the prompt are prefilled under the block-causal mask; the
+    prompt's tail (0..B-1 tokens) opens the first generated block as
+    fixed tokens. A block starts with `mask_token_id` at every unfixed
+    position; each of `steps` DENOISING forwards runs the block's B
+    tokens against the cache and themselves, takes the argmax and its
+    softmax probability at every masked position, and fixes the most
+    confident ones (`remasking` "low_confidence_static": the masked
+    count split evenly over the steps, the remainder to the first
+    steps — a fixed schedule, so the work does not depend on the
+    weights); one COMMIT forward then runs the final tokens once more
+    and keeps their K/V rows. The logits at a position predict that
+    position's own token (no shift). Greedy (temperature 0) only."""
+
+    steps: int
+    mask_token_id: int
+    remasking: str = REMASKING[0]
+
+    def __post_init__(self):
+        if self.steps < 1:
+            raise ValueError(f"denoising steps {self.steps}")
+        if self.remasking not in REMASKING:
+            raise ValueError(f"unknown remasking {self.remasking!r}")
+
+
 class LMServer:
     """Slot-based continuous batching over `batched_decode_step`.
 
@@ -327,8 +407,13 @@ class LMServer:
         top_k: Optional[int] = None,
         seed: int = 0,
         gather_shardings: Any = None,
+        diffusion: Optional[BlockDiffusion] = None,
     ):
-        """`gather_shardings` (a pytree of NamedShardings matching
+        """`diffusion` makes this a block-diffusion server (see
+        `BlockDiffusion` and `_diffuse_impl`): `chunk` is then the
+        tokens a slot a dispatch, in whole blocks.
+
+        `gather_shardings` (a pytree of NamedShardings matching
         `params`, normally all-replicated over a mesh whose HBM holds
         `params` tp-sharded) switches the server into the per-forward
         PARAM-GATHER serving form: every prefill/chunk dispatch
@@ -354,6 +439,20 @@ class LMServer:
         self.temperature = temperature
         self.top_k = top_k
         self._mesh = _mesh_of(params)
+        self.diffusion = diffusion
+        if (diffusion is None) != (cfg.mask_block == 1):
+            raise ValueError(
+                "a block-diffusion server needs a block_causal model "
+                "and a block_causal model a block-diffusion server")
+        if diffusion is not None:
+            if temperature != 0.0:
+                raise ValueError("block diffusion serves greedy only")
+            if not 0 <= diffusion.mask_token_id < cfg.vocab_size:
+                raise ValueError("mask_token_id outside the vocabulary")
+            if max_len % cfg.block_length:
+                raise ValueError(
+                    f"max_len {max_len} is no whole number of blocks "
+                    f"of {cfg.block_length}")
         self.cache = self._new_cache(cfg)
         # Decode state lives ON DEVICE (authoritative): `_cur_dev` the
         # next input token per slot, `_pos_dev` the next write
@@ -390,10 +489,17 @@ class LMServer:
         # as constants (rejected outright by remote compile services
         # for real model sizes). jax.jit's own cache handles one
         # compilation per distinct prompt bucket.
+        # a block-diffusion prefill reads no logits and hands back the
+        # bucket's own rows, not rows padded to max_len: a placement
+        # wave of equal budgets prefills a group a bucket at once, and
+        # seven groups' max_len-row caches (1.6 GB each at 32 slots x
+        # 4,096 rows x 12 KiB) do not fit beside an 8.7 GB model
         self._prefill = jax.jit(
             lambda p, pr, li: prefill(
-                self._maybe_gather(p), self.cfg, pr, self.max_len,
+                self._maybe_gather(p), self.cfg, pr,
+                self.max_len if diffusion is None else pr.shape[1],
                 logits_index=li, mesh=self._mesh,
+                head=diffusion is None,
             )
         )
         self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
@@ -428,6 +534,35 @@ class LMServer:
         self._verify_fn = None
         self._propose_fn = None
         self._draft_prefill = None
+        # rid -> the step that fixed each delivered token (and the
+        # last block's rows past the budget), kept from retirement
+        # until `take_fixed_at` (block diffusion only)
+        self._fixed_at: Dict[int, Dict[str, Any]] = {}
+        # block diffusion: the last dispatch's wall over its forwards
+        # (what LMBackend prices a request by); None = none measured
+        self.forward_seconds: Optional[float] = None
+        if diffusion is not None:
+            routers = [
+                blk["moe"]["router"]["kernel"].shape[-1]
+                for name, blk in params.items()
+                if name.startswith("block_") and "moe" in blk
+            ]
+            # (expert layers, routed experts): the routing counts' shape
+            self._routed = (len(routers), routers[0] if routers else 0)
+            # the current block of every slot, device-resident like
+            # cur/pos: `_pos_dev` is then the block's first row
+            b = cfg.block_length
+            self.blocks_per_dispatch = max(1, chunk // b)
+            self._blk_dev = jnp.full(
+                (max_slots, b), diffusion.mask_token_id, jnp.int32)
+            self._diffuse_fn = jax.jit(
+                self._diffuse_impl, donate_argnums=(1, 2, 3))
+            self._merge_blk = jax.jit(
+                lambda blk, vals, slot_map: jnp.where(
+                    (slot_map >= 0)[:, None],
+                    vals[jnp.clip(slot_map, 0, None)], blk),
+                donate_argnums=(0,),
+            )
         _M_SLOTS_TOTAL.set(max_slots)
 
     def enable_spec_decode(
@@ -474,6 +609,10 @@ class LMServer:
         speculation needs rejection resampling — out of scope, typed
         here). Enable before submitting work: a device draft's cache
         cannot adopt slots that were prefilled before it existed."""
+        if self.diffusion is not None:
+            raise ValueError(
+                "speculative decoding drafts tokens one at a time; a "
+                "block-diffusion server has no such step")
         if self.temperature != 0.0:
             raise ValueError(
                 "speculative decoding requires temperature == 0 "
@@ -588,6 +727,10 @@ class LMServer:
         path, bit-identical to today's behavior)."""
         from .kv_cache import WarmStart
 
+        if cache is not None and self.diffusion is not None:
+            raise ValueError(
+                "the KV prefix cache warm-starts causal prefills; a "
+                "block-diffusion server has none")
         self.kv_cache = cache
         self._warm = (
             WarmStart(cache, self.cfg, self.max_len)
@@ -630,9 +773,30 @@ class LMServer:
         scan steps only ever rewrite the LAST cache row — and this
         full-row overwrite then erases that too. Any future partial-row
         insert or unclamped scatter would break the pairing; keep both
-        sides together."""
+        sides together. (A block-diffusion server inserts the
+        prefilled rows alone — see below — and needs no such pairing:
+        its forwards attend rows under each slot's length only, and a
+        request's own forwards write every row from its first block on
+        before anything attends it.)"""
         # generic over the cache layout (bf16 {k, v} or kv_quant
         # {k_q, k_s, v_q, v_s}) — every leaf copies the same way
+        if self.diffusion is not None:
+            # the prefilled rows alone ([KV, bucket, D], written from
+            # the slot's row 0): what the last occupant left past them
+            # lies at or past this request's first block, where nothing
+            # attends a row before a forward of this request wrote it
+            return {
+                name: {
+                    key: jax.lax.dynamic_update_slice(
+                        kv[key],
+                        jax.lax.dynamic_index_in_dim(
+                            pcache[name][key], row, axis=0),
+                        (slot,) + (0,) * (kv[key].ndim - 1),
+                    )
+                    for key in kv
+                }
+                for name, kv in cache.items()
+            }
         return {
             name: {
                 key: kv[key].at[slot].set(
@@ -699,6 +863,82 @@ class LMServer:
         )
         return cache, cur, pos, toks  # toks: [chunk, slots]
 
+    def _diffuse_impl(self, params, cache, blk, pos, rid):
+        """`blocks_per_dispatch` whole blocks for every slot in one
+        dispatch: a `lax.scan` over blocks, each `steps` denoising
+        forwards and one commit forward (`BlockDiffusion`), all of
+        them `batched_block_step` under the block mask. `blk`
+        [slots, B] is each slot's current block (fixed tokens, the
+        mask id elsewhere), `pos` its first row, `rid` 0 for an empty
+        slot, which attends nothing (`_chunk_impl`'s rule); positions
+        are clamped as there, so a freed slot rewrites the grid's last
+        block until the next insert's full-row overwrite.
+
+        Which positions a step fixes: the block's masked count m at
+        its start gives step s (1-based) n_s = m // S + (s <= m % S)
+        of them, the most confident first (softmax probability of the
+        argmax, float32; ties to the lower position). A step with
+        n_s = 0 still runs: the schedule is fixed.
+
+        Returns (cache, blk' = all masks, pos', packed): ONE int32
+        vector of one fixed shape — tokens [R, slots, B], the step
+        that fixed each (0 = given) [R, slots, B], and the assignments
+        of occupied slots' tokens to each routed expert
+        [R, S + 1, layers, E] (nothing where the model has no expert
+        layer) — so that no readback ever compiles."""
+        df, cfg = self.diffusion, self.cfg
+        b, s_n = cfg.block_length, df.steps
+        last = self.max_len - b
+        mask_id = jnp.int32(df.mask_token_id)
+        params = self._maybe_gather(params)
+        live = rid > 0
+        where = jnp.arange(b, dtype=jnp.int32)[None, :]
+
+        def forward(cache, x, pos_c, head):
+            experts = {"live": live, "counts": []}
+            logits, cache = batched_block_step(
+                params, cfg, cache, x, pos_c, mask_block=b, live=live,
+                head=head, mesh=self._mesh, experts=experts)
+            counts = (jnp.stack(experts["counts"]) if experts["counts"]
+                      else jnp.zeros((0, 0), jnp.int32))
+            return logits, cache, counts
+
+        def block(carry, _):
+            cache, x, pos = carry
+            pos_c = jnp.minimum(pos, last)
+            masked0 = (x == mask_id).sum(-1)  # [slots]
+            fixed_at = jnp.where(x == mask_id, -1, 0).astype(jnp.int32)
+            routed = []
+            for s in range(1, s_n + 1):
+                logits, cache, counts = forward(cache, x, pos_c, True)
+                routed.append(counts)
+                best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                conf = jnp.exp(
+                    jnp.max(logits, axis=-1)
+                    - jax.nn.logsumexp(logits, axis=-1))
+                masked = x == mask_id
+                conf = jnp.where(masked, conf, -1.0)
+                # rank 0 = the most confident masked position
+                ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                    (conf[:, None, :] == conf[:, :, None])
+                    & (where[:, None, :] < where[:, :, None]))
+                rank = ahead.sum(-1)
+                n_s = masked0 // s_n + (s <= masked0 % s_n)
+                fix = masked & (rank < n_s[:, None])
+                x = jnp.where(fix, best, x)
+                fixed_at = jnp.where(fix, s, fixed_at)
+            _, cache, counts = forward(cache, x, pos_c, False)
+            routed.append(counts)
+            nxt = jnp.full_like(x, mask_id)
+            return (cache, nxt, pos_c + b), (x, fixed_at, jnp.stack(routed))
+
+        (cache, blk, pos), (toks, fixed_at, routed) = jax.lax.scan(
+            block, (cache, blk, pos), None,
+            length=self.blocks_per_dispatch)
+        packed = jnp.concatenate(
+            [toks.ravel(), fixed_at.ravel(), routed.ravel()])
+        return cache, blk, pos, packed
+
     def _propose_impl(self, draft_params, draft_cache, cur, pos):
         """k greedy draft steps from every slot's (cur, pos): returns
         (draft cache, proposals [slots, k]). The draft model shares
@@ -760,7 +1000,7 @@ class LMServer:
         start = jnp.minimum(pos, self.max_len - (k + 1))
         inputs = jnp.concatenate([cur[:, None], d_toks], axis=1)
         logits, cache = batched_verify_step(
-            params, self.cfg, cache, inputs, start
+            params, self.cfg, cache, inputs, start, mesh=self._mesh
         )
         # g[:, i] = target-greedy token for position start+i+1 (the
         # argmax after consuming inputs[:, i])
@@ -792,6 +1032,13 @@ class LMServer:
                 f"prompt {prompt.size} + budget {max_new_tokens} "
                 f"exceeds max_len {self.max_len}"
             )
+        if self.diffusion is not None and (
+            prompt == self.diffusion.mask_token_id
+        ).any():
+            raise ValueError(
+                f"the prompt holds the mask token "
+                f"{self.diffusion.mask_token_id}, which marks the "
+                f"positions still to generate")
         return prompt
 
     def submit_many(
@@ -1140,19 +1387,35 @@ class LMServer:
                         self._spec.draft_cache, dpcache,
                         jnp.int32(slot), jnp.int32(row),
                     )
-            # first generated tokens occupy position tp — the same
-            # (rid, position) streams the chunk sampler continues
-            firsts = self._sample_first(
-                logits, jnp.asarray(rids), jnp.asarray(tps)
-            )
             sm = jnp.asarray(slot_map)
-            self._cur_dev = self._merge_vec(self._cur_dev, firsts, sm)
-            self._pos_dev = self._merge_vec(
-                self._pos_dev, jnp.asarray(tps), sm
-            )
-            self._pending_first.append(
-                ([req for _, req in grp], firsts)
-            )
+            if self.diffusion is not None:
+                # whole blocks of the prompt are in the cache (what the
+                # prefill wrote past them is rewritten by the first
+                # block's forwards); its tail opens the first block
+                b = self.cfg.block_length
+                first_blk = np.full(
+                    (kp, b), self.diffusion.mask_token_id, np.int32)
+                for row, (_, req) in enumerate(grp):
+                    tail = req.prompt.size % b
+                    if tail:
+                        first_blk[row, :tail] = req.prompt[-tail:]
+                self._blk_dev = self._merge_blk(
+                    self._blk_dev, jnp.asarray(first_blk), sm)
+                self._pos_dev = self._merge_vec(
+                    self._pos_dev, jnp.asarray(tps // b * b), sm)
+            else:
+                # first generated tokens occupy position tp — the same
+                # (rid, position) streams the chunk sampler continues
+                firsts = self._sample_first(
+                    logits, jnp.asarray(rids), jnp.asarray(tps)
+                )
+                self._cur_dev = self._merge_vec(self._cur_dev, firsts, sm)
+                self._pos_dev = self._merge_vec(
+                    self._pos_dev, jnp.asarray(tps), sm
+                )
+                self._pending_first.append(
+                    ([req for _, req in grp], firsts)
+                )
             prompt_tokens = int(tps[:k].sum())
             span.label(padded_rows=kp, prompt_tokens=prompt_tokens,
                        padded_tokens=kp * bucket)
@@ -1163,7 +1426,9 @@ class LMServer:
         for slot, req in grp:
             _M_QUEUE_WAIT.observe(now - req.t_submit)
             req.t_placed = now
-            req.emitted = 1
+            # a first token is sampled at placement, except under block
+            # diffusion, whose tokens all come from denoising forwards
+            req.emitted = 0 if self.diffusion is not None else 1
             req.slot = slot
             self._slot_req[slot] = req
             self.rid_vec[slot] = req.rid
@@ -1179,6 +1444,12 @@ class LMServer:
         if self.kv_cache is not None and self.temperature == 0.0:
             self._capture_retired(slot, req)
         self._done[req.rid] = req
+        if self.diffusion is not None:
+            self._fixed_at[req.rid] = {
+                "fixed_at": list(req.fixed_at),
+                "beyond_budget": {"tokens": list(req.beyond[0]),
+                                  "fixed_at": list(req.beyond[1])},
+            }
         req.slot = None
         self._slot_req[slot] = None
         # 0 is not only "no request": `_chunk_impl` reads rid 0 as AN
@@ -1261,8 +1532,13 @@ class LMServer:
                 return
         occupancy = sum(1 for r in self._slot_req if r is not None)
         _M_OCCUPANCY.observe(occupancy)
-        dispatch = self._spec_step if self._use_spec() else self._chunk_step
-        with TRACER.loop_span("lm_step", occupancy=occupancy) as span:
+        mode, dispatch = (
+            ("diffusion", self._diffuse_step) if self.diffusion is not None
+            else ("spec", self._spec_step) if self._use_spec()
+            else ("chunk", self._chunk_step))
+        with TRACER.loop_span(
+            "lm_step", occupancy=occupancy, mode=mode
+        ) as span:
             dispatch(span)
         _M_STEP.observe(span.m1 - span.m0)
 
@@ -1532,6 +1808,98 @@ class LMServer:
         _M_DELIVER.observe(deliver.m1 - deliver.m0)
         self._finish_step(step, delivered, first_n)
 
+    def _diffuse_step(self, step: Any) -> None:
+        """The block-diffusion dispatch: every occupied slot advances
+        by `blocks_per_dispatch` whole blocks (`_diffuse_impl`), under
+        the same phase spans as `_chunk_step`. `lm_pack` is the issue
+        of the readback's host copy: the packing itself is part of the
+        device program and has ONE shape, so nothing here can compile.
+        Tokens are delivered a committed block at a time; a request
+        joins at a dispatch's end and leaves after the block that meets
+        its budget, whose surplus rows are dropped (the dispatch's
+        later blocks of that slot are work nobody reads)."""
+        df, b = self.diffusion, self.cfg.block_length
+        r_n, s_n = self.blocks_per_dispatch, df.steps
+        live = list(enumerate(self._slot_req))
+        with TRACER.loop_span("lm_dispatch", step):
+            (self.cache, self._blk_dev, self._pos_dev,
+             packed) = self._diffuse_fn(
+                self.params, self.cache, self._blk_dev, self._pos_dev,
+                jnp.asarray(self.rid_vec),
+            )
+        with TRACER.loop_span("lm_pack", step, arrays=1) as pack:
+            packed.copy_to_host_async()
+        with TRACER.loop_span("lm_readback", step) as readback:
+            out = np.asarray(packed)
+        _M_PACK.observe(pack.m1 - pack.m0)
+        _M_READBACK.observe(readback.m1 - readback.m0)
+        n = r_n * self.max_slots * b
+        toks = out[:n].reshape(r_n, self.max_slots, b)
+        fixed = out[n : 2 * n].reshape(r_n, self.max_slots, b)
+        with TRACER.loop_span("lm_deliver", step) as deliver:
+            delivered = retired = blocks = 0
+            for slot, req in live:
+                if req is None:
+                    continue
+                blocks += r_n
+                for r in range(r_n):
+                    if req.done:
+                        break
+                    new = fixed[r, slot] > 0  # 0 = the prompt's tail
+                    take = min(int(new.sum()),
+                               req.max_new_tokens - req.emitted)
+                    req.fixed_at.extend(
+                        int(f) for f in fixed[r, slot][new][:take])
+                    req.deliver(toks[r, slot][new][:take])
+                    req.emitted += take
+                    delivered += take
+                    if req.done:
+                        req.beyond = (
+                            [int(t) for t in toks[r, slot][new][take:]],
+                            [int(f) for f in fixed[r, slot][new][take:]])
+                if req.done:
+                    self._retire(slot)
+                    retired += 1
+            deliver.label(tokens=delivered, retired=retired)
+        _M_DELIVER.observe(deliver.m1 - deliver.m0)
+        _M_FWD_DENOISE.inc(r_n * s_n)
+        _M_FWD_COMMIT.inc(r_n)
+        _M_FIXED.inc(delivered)
+        _M_BLOCKS.inc(blocks)
+        labels = {}
+        layers, e = self._routed
+        if layers:
+            routed = out[2 * n :].reshape(-1, e).astype(np.float64)
+            routed = routed[routed.sum(-1) > 0]  # a forward, a layer
+            touched = (routed > 0).sum(-1)
+            load = routed.max(-1) / routed.mean(-1)
+            _M_MOE_ASSIGN.inc(float(routed.sum()))
+            for t, m in zip(touched, load):
+                _M_MOE_TOUCHED.observe(float(t))
+                _M_MOE_LOAD.observe(float(m))
+            if len(touched):
+                labels = {"experts_touched": round(float(touched.mean()), 3),
+                          "expert_load_max": round(float(load.mean()), 3)}
+        step.label(forwards=r_n * (s_n + 1), tokens_fixed=delivered,
+                   blocks_committed=blocks, **labels)
+        self._finish_step(step, delivered, 0)
+        self.forward_seconds = (
+            time.monotonic() - step.m0) / (r_n * (s_n + 1))
+
+    def take_fixed_at(
+        self, rids: Optional[Sequence[int]] = None
+    ) -> Dict[int, Dict[str, Any]]:
+        """Block diffusion: for retired requests (`rids`, or all),
+        {"fixed_at": per generated token the denoising step (1..S) that
+        fixed it, "beyond_budget": {"tokens", "fixed_at"} of the last
+        block's positions past the budget (a budget is met by trimming
+        the last block; with these the block can be rebuilt whole)};
+        removed from the server as `take_done` removes the tokens.
+        Empty for an autoregressive server."""
+        keys = list(self._fixed_at) if rids is None else [
+            r for r in rids if r in self._fixed_at]
+        return {r: self._fixed_at.pop(r) for r in keys}
+
     def has_work(self) -> bool:
         """True while any request is queued or occupying a slot."""
         return bool(self._queue) or any(
@@ -1610,6 +1978,9 @@ class _Ticket:
     rids: Optional[List[int]] = None
     remaining: int = 0
     results: Optional[Dict[int, np.ndarray]] = None
+    # block diffusion: rid -> `LMServer.take_fixed_at`'s record
+    fixed_at: Dict[int, Dict[str, Any]] = dataclasses.field(
+        default_factory=dict)
     error: Optional[BaseException] = None
 
 
@@ -1677,9 +2048,13 @@ class LMDriver:
             Sequence[Optional[Callable[[int], None]]]
         ] = None,
         trace: Optional[Sequence[Optional[TraceContext]]] = None,
+        fixed_at: Optional[List[Dict[str, Any]]] = None,
     ) -> List[np.ndarray]:
         """Blocking: decode `prompts`, return their completions in
-        order. `max_new_tokens` is an int or a per-prompt sequence
+        order. A caller that passes a list as `fixed_at` gets it
+        filled, in the same order, with a block-diffusion server's
+        record of the denoising step that fixed each token
+        (`LMServer.take_fixed_at`). `max_new_tokens` is an int or a per-prompt sequence
         (passed through to submit_many). Safe from any thread.
         `on_dispatch` fires (on the DRIVER thread) the moment the
         ticket's prompts are submitted to the server — the caller's
@@ -1707,6 +2082,8 @@ class LMDriver:
         if t.error is not None:
             raise t.error
         assert t.results is not None and t.rids is not None
+        if fixed_at is not None:
+            fixed_at.extend(t.fixed_at.get(rid) for rid in t.rids)
         return [t.results[rid] for rid in t.rids]
 
     def stop(self) -> None:
@@ -1796,11 +2173,14 @@ class LMDriver:
                     with self._cv:
                         self.steps += 1
                 done = srv.take_done()
+                fixed = srv.take_fixed_at(list(done))
             for rid, toks in done.items():
                 t = self._owner.pop(rid, None)
                 if t is None:
                     continue  # pre-driver submission via raw server API
                 t.results[rid] = toks
+                if rid in fixed:
+                    t.fixed_at[rid] = fixed[rid]
                 t.remaining -= 1
                 if t.remaining == 0:
                     self.tickets_served += 1

@@ -86,8 +86,9 @@ def quantize_lm_params(params: Dict[str, Any]) -> Dict[str, Any]:
                     moe = dict(v)
                     # per-(expert, out-channel) scales: [E, d, d_ff]
                     # keeps axes 0 and 2
-                    moe["w_up"] = _quant_tensor(v["w_up"], (0, 2))
-                    moe["w_down"] = _quant_tensor(v["w_down"], (0, 2))
+                    for w in ("w_up", "w_down", "w_gate"):
+                        if w in v:  # w_gate: gated experts only
+                            moe[w] = _quant_tensor(v[w], (0, 2))
                     blk[k] = moe
                 else:
                     blk[k] = v

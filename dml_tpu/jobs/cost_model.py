@@ -65,6 +65,20 @@ class ModelCost:
         return replace(self, **kw)
 
 
+def lm_request_forwards(
+    new_tokens: int, block_length: int = 1, denoising_steps: int = 1
+) -> int:
+    """Forwards over the slot grid that one LM request of `new_tokens`
+    costs — the unit an LM backend prices a request in. An
+    autoregressive model yields one token a forward. A block-diffusion
+    model (`block_length` B > 1) runs `denoising_steps` denoising
+    forwards and one commit forward for every block of B tokens, whole
+    blocks only, so its price follows blocks x (S + 1), not tokens."""
+    if block_length <= 1:
+        return int(new_tokens)
+    return -(-int(new_tokens) // block_length) * (denoising_steps + 1)
+
+
 def batch_exec_time(cost: ModelCost, batch: Optional[int] = None) -> float:
     """Predicted wall time of one batch on one worker.
 

@@ -185,7 +185,9 @@ line when you add the metric.
     lm_server_decode_kv_rows_total   decode cache rows by kind= live|read|grid
     lm_server_decode_tokens_total    tokens decoded (all slots)
     lm_server_deliver_seconds        a dispatch's token delivery + callbacks
+    lm_server_blocks_committed_total block-diffusion blocks committed
     lm_server_first_token_seconds    placement -> first token value on host
+    lm_server_forwards_total         block-diffusion forwards by kind= denoise|commit
     lm_server_pack_seconds           issuing a dispatch's packed readback
     lm_server_prefill_dispatch_seconds  a prefill group's enqueue-chain wall
     lm_server_prefill_tokens_total   prefilled tokens by kind= prompt|padded
@@ -198,6 +200,7 @@ line when you add the metric.
     lm_server_slots_total            configured decode slots
     lm_server_step_seconds           decode step wall
     lm_server_steps_total            decode steps executed
+    lm_server_tokens_fixed_total     block-diffusion tokens fixed and delivered
     lm_sharded_batches_total         LM batches on a group engine by mode
     lm_sharded_prefill_slabs_total   KV slabs built by prefill workers
     lm_sharded_tokens_total          tokens from group-sharded serving
@@ -214,6 +217,9 @@ line when you add the metric.
     metrics_relay_fallback_total     relay shards fallen back to direct
     metrics_relay_pulls_total        relay-shard aggregations by role
     metrics_relay_seconds            relay shard pull + pre-merge wall
+    moe_assignments_total            (token, expert) assignments of live slots
+    moe_expert_load_max              busiest expert / mean, a forward a layer
+    moe_experts_touched              distinct experts a forward reaches a layer
     request_admitted_total           front-door admissions per SLO class
     request_batch_fill_fraction      formed-batch fill quality
     request_batch_formation_seconds  batch formation wall

@@ -9,6 +9,11 @@ The XLA einsum (inference/generate.py `batched_decode_step`, the CPU
 and test oracle) streams every row of every slot behind a mask; this
 kernel fetches only the k-blocks that hold live rows.
 
+The query may hold Q > 1 rows a slot (speculative decoding's verify,
+block diffusion's forwards: `generate.batched_block_step`): a KV
+head's Q * G rows then share every fetched block, each row masked to
+its own limit (causal, or by blocks of `mask_block`).
+
 Structure: grid (B, k-blocks), online-softmax accumulation across
 k-blocks in VMEM scratch (the decode-shaped sibling of
 flash_attention.py's forward kernel — G = H/KV query rows instead of
@@ -70,10 +75,12 @@ def block_rows(kv: int, d: int, dtype, t: int, block_k: int = 2048) -> int:
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, quantized, n_kv, bk):
+                   m_scr, l_scr, acc_scr, *, scale, quantized, n_kv, bk,
+                   n_q=1, mask_block=1):
     ik = pl.program_id(1)
     nk = pl.num_programs(1)
     length = len_ref[pl.program_id(0)]
+    rows = q_ref.shape[1]  # n_q * G query rows a KV head, query-major
 
     @pl.when(ik == 0)
     def _init():
@@ -89,6 +96,16 @@ def _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
             jnp.int32, (1, bk), 1) < length
         live_r = ik * bk + jax.lax.broadcasted_iota(
             jnp.int32, (bk, 1), 0) < length
+        if n_q > 1:
+            # `length` counts the n_q rows this dispatch wrote; query
+            # i of them stops short of the rows of later queries
+            # (causal) or of later blocks (`mask_block`): a [rows, bk]
+            # mask in place of the [1, bk] one
+            qi = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, 1), 0) // (rows // n_q)
+            back = n_q - (qi // mask_block + 1) * mask_block
+            live_t = ik * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, bk), 1) < length - back
         # static per-head loop: one grid instance streams ALL kv heads'
         # blocks (a per-(b, head) grid at decode sizes is dominated by
         # instance overhead)
@@ -136,7 +153,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
 
 
 def decode_attention(
-    q: jax.Array,  # [B, 1, H, D]
+    q: jax.Array,  # [B, Q, H, D]; Q = 1 is the decode step
     k: jax.Array,  # [B, KV, T, D] cache (cfg dtype, or int8 with scales)
     v: jax.Array,  # [B, KV, T, D]
     lengths: jax.Array,  # [B] int32 — slot b attends cache rows < lengths[b]
@@ -146,8 +163,18 @@ def decode_attention(
     scale: Optional[float] = None,
     block_k: int = 2048,
     interpret: Optional[bool] = None,
+    mask_block: int = 1,
 ) -> jax.Array:
-    """One decode step of cache attention; returns [B, 1, H, D] f32.
+    """One decode step of cache attention; returns [B, Q, H, D] f32.
+
+    With Q > 1 query rows a slot (speculative decoding's verify, block
+    diffusion: `generate.batched_block_step`) `lengths[b]` counts the
+    Q rows the dispatch has just written at the slot's end, and query
+    i attends rows < lengths[b] - (Q - (i // mask_block + 1) *
+    mask_block): its own row and every earlier one (`mask_block` 1,
+    causal), or every row up to its own block of `mask_block` (Q a
+    multiple of it, the write position too). A KV head's Q * G query
+    rows share each fetched block, so the bytes are those of Q = 1.
 
     `lengths[b]` is the number of cache rows slot b attends: `pos + 1`
     for a slot that has just written row `pos`, 0 for an empty slot
@@ -162,9 +189,9 @@ def decode_attention(
     grouped-query with kv-major head order (head h = kv * G + g),
     matching `batched_decode_step`'s reshape. Pass `k_scale`/`v_scale`
     to read an int8 cache with inline dequant."""
-    b, one, h, d = q.shape
-    if one != 1:
-        raise ValueError(f"decode q must be [B,1,H,D], got {q.shape}")
+    b, n_q, h, d = q.shape
+    if n_q % mask_block:
+        raise ValueError(f"{n_q} query rows under blocks of {mask_block}")
     kv, t = k.shape[1], k.shape[2]
     if h % kv:
         raise ValueError(f"H {h} not divisible by KV {kv}")
@@ -199,8 +226,12 @@ def decode_attention(
         s, blk = cache_block(b_, j, len_ref, src_ref)
         return s, 0, 0, blk
 
-    qg = q[:, 0].reshape(b, kv, g, d)
-    q_spec = pl.BlockSpec((None, kv, g, d), lambda b_, j, *_: (b_, 0, 0, 0))
+    # a KV head's rows are query-major: row i * G + j is query i's
+    # head j of the group (for Q = 1 the plain [B, KV, G, D] view)
+    rows = n_q * g
+    qg = q.reshape(b, n_q, kv, g, d).swapaxes(1, 2).reshape(b, kv, rows, d)
+    q_spec = pl.BlockSpec(
+        (None, kv, rows, d), lambda b_, j, *_: (b_, 0, 0, 0))
     kv_spec = pl.BlockSpec((None, kv, bk, d), kv_map)
     sc_spec = pl.BlockSpec((None, kv, 1, bk), sc_map)
 
@@ -219,7 +250,8 @@ def decode_attention(
             k_r, v_r, *rest = refs
             ks_r = vs_r = None
         _decode_kernel(len_r, q_r, k_r, ks_r, v_r, vs_r, *rest,
-                       scale=scale, quantized=quantized, n_kv=kv, bk=bk)
+                       scale=scale, quantized=quantized, n_kv=kv, bk=bk,
+                       n_q=n_q, mask_block=mask_block)
 
     out = pl.pallas_call(
         kernel,
@@ -229,12 +261,12 @@ def decode_attention(
             in_specs=in_specs,
             out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((kv, g, LANES), jnp.float32),  # running max
-                pltpu.VMEM((kv, g, LANES), jnp.float32),  # running denom
-                pltpu.VMEM((kv, g, d), jnp.float32),      # output accum
+                pltpu.VMEM((kv, rows, LANES), jnp.float32),  # running max
+                pltpu.VMEM((kv, rows, LANES), jnp.float32),  # running denom
+                pltpu.VMEM((kv, rows, d), jnp.float32),      # output accum
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kv, rows, d), jnp.float32),
         interpret=interpret,
     )(lengths, src, *ins)
-    return out.reshape(b, 1, h, d)
+    return out.reshape(b, kv, n_q, g, d).swapaxes(1, 2).reshape(b, n_q, h, d)

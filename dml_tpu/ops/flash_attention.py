@@ -50,10 +50,24 @@ NEG_INF = -1e30
 LANES = 128
 
 
-def _causal_mask(s, iq, ik, block_q, block_k):
+def _causal_mask(s, iq, ik, block_q, block_k, causal=1):
+    """`causal` is the mask rule as one number (True counts as 1):
+    1 = position i attends j <= i; B > 1 = block-causal, i attends j
+    iff j // B <= i // B (full inside a block of B, causal across)."""
     qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if causal > 1:
+        qpos = qpos // causal * causal + (causal - 1)
     return jnp.where(qpos >= kpos, s, NEG_INF)
+
+
+def _below_diagonal(iq, ik, block_q, block_k, causal=1):
+    """Whether k-block `ik` holds a position some row of q-block `iq`
+    attends (blocks wholly above the diagonal are skipped)."""
+    last = iq * block_q + block_q - 1
+    if causal > 1:
+        last = last // causal * causal + (causal - 1)
+    return ik * block_k <= last
 
 
 def _kv_valid_mask(s, ik, block_k, t_kv):
@@ -82,7 +96,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             preferred_element_type=jnp.float32,
         ) * scale  # [bq, bk] f32
         if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
+            s = _causal_mask(s, iq, ik, block_q, block_k, causal)
         if padded_kv:
             s = _kv_valid_mask(s, ik, block_k, t_kv)
         m_prev = m_scr[:, :1]  # [bq, 1]
@@ -99,7 +113,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     if causal:
         # skip blocks entirely above the diagonal
-        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_body)
+        pl.when(_below_diagonal(iq, ik, block_q, block_k, causal))(_body)
     else:
         _body()
 
@@ -135,7 +149,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         ) * scale
         if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
+            s = _causal_mask(s, iq, ik, block_q, block_k, causal)
         if padded_kv:
             s = _kv_valid_mask(s, ik, block_k, t_kv)
         p = jnp.exp(s - lse)                   # [bq, bk]
@@ -154,7 +168,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )
 
     if causal:
-        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_body)
+        pl.when(_below_diagonal(iq, ik, block_q, block_k, causal))(_body)
     else:
         _body()
 
@@ -189,7 +203,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         ) * scale
         if causal:
-            s = _causal_mask(s, iq, ik, block_q, block_k)
+            s = _causal_mask(s, iq, ik, block_q, block_k, causal)
         if padded_kv:
             s = _kv_valid_mask(s, ik, block_k, t_kv)
         p = jnp.exp(s - lse)  # [bq, bk] f32
@@ -210,7 +224,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )
 
     if causal:
-        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_body)
+        pl.when(_below_diagonal(iq, ik, block_q, block_k, causal))(_body)
     else:
         _body()
 
@@ -462,9 +476,15 @@ def flash_attention(
     block_q: int = 1024,
     block_k: int = 1024,
     interpret: Optional[bool] = None,
+    mask_block: int = 1,
 ) -> jax.Array:
     """Blockwise (flash) attention. q, k, v: [B, T, H, D] (T of k/v may
     differ from q's); returns [B, Tq, H, D] in q's dtype.
+
+    `mask_block` B > 1 (with `causal`) is the block-causal rule of
+    block-diffusion models: position i attends j iff j // B <= i // B.
+    k-blocks wholly above that stepped diagonal are skipped as under
+    the causal rule; `mask_block` 1 is the causal kernel unchanged.
 
     Differentiable (custom VJP, both passes are Pallas kernels).
     `interpret=None` auto-selects: compiled on TPU, interpreter
@@ -476,8 +496,10 @@ def flash_attention(
         raise ValueError("causal attention needs equal q/k lengths")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     interpret = _interpret_default() if interpret is None else interpret
+    if mask_block < 1 or (mask_block > 1 and not causal):
+        raise ValueError(f"mask_block {mask_block} with causal={causal}")
     out = _flash(
-        _bhtd(q), _bhtd(k), _bhtd(v), causal, scale, block_q, block_k,
-        interpret,
+        _bhtd(q), _bhtd(k), _bhtd(v), mask_block if mask_block > 1 else causal,
+        scale, block_q, block_k, interpret,
     )
     return _bhtd(out)

@@ -118,11 +118,14 @@ def test_validation_errors():
     ck = jnp.zeros((2, 3, 16, 8))  # 4 heads % 3 kv != 0
     with pytest.raises(ValueError, match="not divisible"):
         decode_attention(q, ck, ck, jnp.zeros(2, jnp.int32))
-    with pytest.raises(ValueError, match="B,1,H,D"):
-        decode_attention(
-            jnp.zeros((2, 2, 4, 8)), ck, ck, jnp.zeros(2, jnp.int32)
-        )
     ok = jnp.zeros((2, 2, 16, 8))
+    # several query rows a slot are legal since PR 28; a block mask that
+    # does not divide them is not
+    with pytest.raises(ValueError, match="query rows under blocks"):
+        decode_attention(
+            jnp.zeros((2, 3, 4, 8)), ok, ok, jnp.zeros(2, jnp.int32),
+            mask_block=2,
+        )
     with pytest.raises(ValueError, match="both k_scale"):
         decode_attention(
             q, ok, ok, jnp.zeros(2, jnp.int32),

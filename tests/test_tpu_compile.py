@@ -88,6 +88,75 @@ def test_decode_attention_compiles(topo, shape, kv, int8):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("n_q,mask_block,int8", [
+    (4, 4, False),  # one block of 4: block diffusion's forwards
+    (4, 1, False),  # causal rows: speculation's verify
+    (4, 4, True),
+])
+def test_decode_attention_with_query_rows_compiles(
+        topo, n_q, mask_block, int8):
+    """The cache-attention kernel with several query rows a slot, at
+    sdar30b_a3b_l6's grid: 32 slots x 4,096 rows, 32 heads / 4 KV x 128
+    (n_q x 8 query rows a KV head)."""
+    from dml_tpu.ops.decode_attention import decode_attention
+
+    B, H, D, T, kv = 32, 32, 128, 4096, 4
+    q = ((B, n_q, H, D), jnp.bfloat16)
+    pos = ((B,), jnp.int32)
+    if int8:
+        cache, scale = ((B, kv, T, D), jnp.int8), ((B, kv, 1, T), jnp.float32)
+        text, _ = compile_on_chip(
+            topo,
+            lambda q, k, ks, v, vs, p: decode_attention(
+                q, k, v, p, k_scale=ks, v_scale=vs, interpret=False,
+                mask_block=mask_block),
+            q, cache, scale, cache, scale, pos,
+        )
+    else:
+        cache = ((B, kv, T, D), jnp.bfloat16)
+        text, _ = compile_on_chip(
+            topo, functools.partial(decode_attention, interpret=False,
+                                    mask_block=mask_block),
+            q, cache, cache, pos,
+        )
+    assert "tpu_custom_call" in text
+
+
+def test_block_causal_flash_attention_compiles(topo):
+    from dml_tpu.ops.flash_attention import flash_attention
+
+    x = ((2, 2048, 32, 128), jnp.bfloat16)
+    text, _ = compile_on_chip(
+        topo, lambda q, k, v: flash_attention(
+            q, k, v, causal=True, mask_block=4, interpret=False), x, x, x)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", [True, False])
+def test_expert_layer_compiles_to_grouped_matmuls(topo, monkeypatch, kernel):
+    """`expert_ffn` at sdar30b_a3b_l6's widths (128 gated experts of
+    2048 -> 768, top-8, bf16) for the 128 tokens of a diffusion forward:
+    on one TPU three calls of jax's Pallas grouped matmul; elsewhere
+    `ragged_dot`, which the TPU compiler also takes (it lowers it to a
+    grouped-matmul call of its own, fed by group metadata)."""
+    from dml_tpu.inference.generate import expert_ffn
+
+    if kernel:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    e, d, f = 128, 2048, 768
+    text, _ = compile_on_chip(
+        topo,
+        lambda r, g, u, dn, y: expert_ffn(
+            {"router": {"kernel": r}, "w_gate": g, "w_up": u, "w_down": dn},
+            y, jnp.bfloat16, 8)[0],
+        ((d, e), jnp.float32), ((e, d, f), jnp.bfloat16),
+        ((e, d, f), jnp.bfloat16), ((e, f, d), jnp.bfloat16),
+        ((32, 4, d), jnp.bfloat16),
+    )
+    assert text.count("tpu_custom_call") >= 3
+    assert ("ragged-dot-metadata" in text) is not kernel
+
+
 @pytest.mark.parametrize("backward", [False, True])
 def test_flash_attention_compiles(topo, backward):
     from dml_tpu.ops.flash_attention import flash_attention
@@ -211,3 +280,56 @@ def test_chunk_program_of_a_grouped_bf16_config_holds_the_kernel(
         params, cache, vec, vec, vec).compile().as_text()
     assert "tpu_custom_call" in text
     assert text.lstrip().startswith("HloModule jit__chunk_impl")
+
+
+def test_diffusion_dispatch_of_the_sdar_config_holds_the_kernels(
+        topo, monkeypatch):
+    """The other benchmark configuration's program: `LMServer.
+    _diffuse_impl` itself at sdar30b_a3b_l6's widths and slot grid (depth
+    cut to 1 layer, 2 blocks a dispatch), declared by `lm_spec_parts` as
+    an operator's spec declares it. It compiles for the chip under the
+    name the cell's `trace_modules` looks for, and holds the
+    cache-attention kernel and the grouped matmuls."""
+    from dml_tpu.inference.generate import init_cache
+    from dml_tpu.inference.lm_backend import lm_spec_parts
+    from dml_tpu.inference.lm_server import BlockDiffusion, LMServer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = {
+        "vocab_size": 151936, "d_model": 2048, "n_heads": 32,
+        "n_kv_heads": 4, "head_dim": 128, "n_layers": 1,
+        "rope_theta": 1e6, "qk_norm": True, "num_experts": 128,
+        "experts_per_token": 8, "expert_d_ff": 768, "gated": True,
+        "attention_mask": "block_causal", "block_length": 4,
+        "denoising_steps": 2, "mask_token_id": 151669,
+        "dtype": "bfloat16", "param_dtype": "bfloat16",
+    }
+    made = {}
+
+    def declared():
+        params, made["cfg"] = lm_spec_parts(spec)
+        return params
+
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = functools.partial(jax.tree_util.tree_map, lambda s: (
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)))
+    params = on_chip(jax.eval_shape(declared))
+    cfg = made["cfg"]
+    slots, max_len = 32, 4096
+    srv = object.__new__(LMServer)
+    srv.cfg, srv.max_len, srv.max_slots = cfg, max_len, slots
+    srv._mesh = srv._gather_shardings = None
+    srv.diffusion = BlockDiffusion(steps=2, mask_token_id=151669)
+    srv.blocks_per_dispatch = 2
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    blk = jax.ShapeDtypeStruct((slots, 4), jnp.int32, sharding=one)
+    text = jax.jit(srv._diffuse_impl).lower(
+        params, cache, blk, vec, vec).compile().as_text()
+    assert text.lstrip().startswith("HloModule jit__diffuse_impl")
+    # attention and three grouped matmuls a forward, three forwards; the
+    # commit forward reads no logits, so its LAST layer's expert matmuls
+    # are dead code (its K/V rows depend on the layer's input alone)
+    assert text.count("tpu_custom_call") == 4 * 3 - 3
+    assert "ragged-dot" not in text
+
