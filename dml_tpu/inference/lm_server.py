@@ -12,8 +12,10 @@ sequences decoding together in ONE compiled program:
   per-slot position/current-token vectors — static shapes, so one
   compilation serves every mix of requests;
 - `submit()` prefills the new request's prompt in one flash-attention
-  forward (prompt lengths bucketed to powers of two to bound distinct
-  compilations) and writes its cache rows into a free slot — placement
+  forward (prompt lengths bucketed to powers of two from 512, rows to
+  powers of two, to bound distinct compilations; a round's prompts
+  share the groups that pad least, `_prefill_groups`) and writes its
+  cache rows into a free slot — placement
   is FULLY async: the per-slot next-token/position state is
   device-resident, the first sampled token's value rides the next
   step's packed readback, and nothing blocks on the link;
@@ -235,6 +237,77 @@ def _bucket(n: int, lo: int = 16) -> int:
     while b < n:
         b *= 2
     return b
+
+
+#: the shortest prefill bucket (tokens a row) of a server whose max_len
+#: allows it. Under ~512 tokens a prefill call is bound by reading the
+#: weights once (6.15 GB of float32 = 7.5 ms on the benchmark's dense
+#: decoder, 7.25 GB of experts = 8.9 ms on its expert model, at
+#: 819 GB/s), so a shorter bucket buys nothing a call, and it would be
+#: one more compiled shape a row count. It is also the charge a group
+#: costs in `_prefill_groups`: every call re-reads every weight.
+_BUCKET_FLOOR = 512
+
+#: a server whose max_len is at most this has ONE bucket (its max_len),
+#: and pads every group's rows to max_slots: one prefill compilation,
+#: which a warm-up of single prompts covers. Tier-1's and the
+#: rehearsals' tiny servers are the only ones left on this form; a real
+#: server's buckets are all longer and take power-of-two rows.
+_FULL_ROWS_BUCKET_MAX = 256
+
+
+def _prefill_bucket(n: int, max_len: int) -> int:
+    """The padded length a prompt of n tokens is prefilled at alone."""
+    return min(max(_bucket(n), _BUCKET_FLOOR), max_len)
+
+
+def _group_rows(k: int, bucket: int, max_slots: int) -> int:
+    """The padded rows of a prefill group of k prompts."""
+    if bucket <= _FULL_ROWS_BUCKET_MAX:
+        return max_slots
+    return min(_bucket(k, lo=1), max_slots)
+
+
+def _prefill_groups(
+    lengths: Sequence[int], max_len: int, max_slots: int
+) -> List[Tuple[int, int, List[int]]]:
+    """Cut one placement round into prefill groups: `(bucket, rows,
+    members)` each, members indexing `lengths`, shortest bucket first.
+
+    Over the prompts sorted by length, the contiguous partition with the
+    least padded tokens (rows x bucket summed, a group's bucket being
+    its longest member's) plus `_BUCKET_FLOOR` a group, fewer groups on
+    a tie: a short prompt rides in a longer group's spare rows, and
+    nothing is split to save rows that a call's weight read costs again.
+    The (bucket, rows) shapes a round may form are a hard constraint
+    (each is a compilation, and a serving window tolerates none): rows
+    are `_group_rows` of the members, and never more than `_group_rows`
+    of the members whose OWN bucket is the group's, so a rider never
+    raises the rows past what equal-length prompts of that bucket form
+    alone."""
+    order = sorted(range(len(lengths)), key=lambda i: lengths[i])
+    own = [_prefill_bucket(lengths[i], max_len) for i in order]
+    # best[j]: (padded tokens + charges, groups, start of the last
+    # group) of the cheapest partition of the j shortest prompts
+    best: List[Tuple[int, int, int]] = [(0, 0, 0)]
+    for j in range(1, len(order) + 1):
+        bucket, native, cands = own[j - 1], 0, []
+        for i in range(j - 1, -1, -1):
+            native += own[i] == bucket
+            rows = _group_rows(j - i, bucket, max_slots)
+            if rows > _group_rows(native, bucket, max_slots):
+                break  # riders only: more of them never fit either
+            cost, groups, _ = best[i]
+            cands.append((cost + rows * bucket + _BUCKET_FLOOR,
+                          groups + 1, i))
+        best.append(min(cands))
+    out, j = [], len(order)
+    while j:
+        i = best[j][2]
+        out.append((own[j - 1], _group_rows(j - i, own[j - 1], max_slots),
+                    sorted(order[i:j])))
+        j = i
+    return out[::-1]
 
 
 @dataclasses.dataclass
@@ -488,7 +561,7 @@ class LMServer:
         # over them would bake the whole weight tree into the program
         # as constants (rejected outright by remote compile services
         # for real model sizes). jax.jit's own cache handles one
-        # compilation per distinct prompt bucket.
+        # compilation per distinct (rows, bucket) prefill group.
         # a block-diffusion prefill reads no logits and hands back the
         # bucket's own rows, not rows padded to max_len: a placement
         # wave of equal budgets prefills a group a bucket at once, and
@@ -1258,18 +1331,19 @@ class LMServer:
         """Free slots take queued requests, recorded as one `lm_place`
         span under `parent` (the dispatch's `lm_step`, the driver's
         `lm_submit`, or none) that holds one `lm_prefill_group` span
-        per bucket group."""
+        per prefill group."""
         with TRACER.loop_span("lm_place", parent) as span:
             span.label(requests=self._place_waiting_in(span))
 
     def _place_waiting_in(self, span: Any) -> int:
         """`_place_waiting`'s body; returns how many requests it placed."""
         # Placement is FULLY ASYNC and GROUP-BATCHED: free slots take
-        # queued requests bucket-by-bucket, each bucket group running
-        # ONE batched prefill (rows padded to a power-of-two group
-        # size to bound compilations), one row-indexed cache insert
-        # per request, one batched first-token sample, and fixed-shape
-        # masked merges into the device-resident cur/pos — nothing
+        # queued requests, the round is cut into the groups that pad
+        # least (`_prefill_groups`), each group running ONE batched
+        # prefill (rows padded to a power-of-two group size to bound
+        # compilations), one row-indexed cache insert per request, one
+        # batched first-token sample, and fixed-shape masked merges
+        # into the device-resident cur/pos — nothing
         # here blocks on the device, and the first tokens' VALUES ride
         # the next step's packed readback (or _flush_firsts). History:
         # r3 paid two blocking readbacks per prompt, r4 one per
@@ -1308,39 +1382,35 @@ class LMServer:
                     sum(1 for r in self._slot_req if r is not None)
                 )
                 return placed
-        groups: Dict[int, List[Tuple[int, _Request]]] = {}
-        for slot, req in pairs:
-            b = min(_bucket(req.prompt.size), self.max_len)
-            groups.setdefault(b, []).append((slot, req))
-        for bucket, grp in groups.items():
-            self._place_group(bucket, grp, span)
+        for bucket, rows, members in _prefill_groups(
+            [req.prompt.size for _, req in pairs],
+            self.max_len, self.max_slots,
+        ):
+            self._place_group(
+                bucket, rows, [pairs[i] for i in members], span)
         _M_SLOTS.set(sum(1 for r in self._slot_req if r is not None))
         return placed
 
     def _place_group(
-        self, bucket: int, grp: List[Tuple[int, _Request]], parent: Any
+        self, bucket: int, kp: int, grp: List[Tuple[int, _Request]],
+        parent: Any,
     ) -> None:
-        """One bucket group's placement: ONE batched prefill, one
-        row-indexed insert per request, one batched first-token sample
-        and the masked merges, all enqueued without waiting for the
-        device (the `lm_prefill_group` span and the
+        """One prefill group's placement (`_prefill_groups` chose its
+        members, its bucket and its kp padded rows): ONE batched
+        prefill, one row-indexed insert per request, one batched
+        first-token sample and the masked merges, all enqueued without
+        waiting for the device (the `lm_prefill_group` span and the
         `lm_server_prefill_dispatch_seconds` observation are that
         enqueue chain's host wall)."""
         k = len(grp)
+        riders = sum(
+            _prefill_bucket(req.prompt.size, self.max_len) < bucket
+            for _, req in grp
+        )
         with TRACER.loop_span(
-            "lm_prefill_group", parent, bucket=bucket, rows=k
+            "lm_prefill_group", parent, bucket=bucket, rows=k,
+            riders=riders,
         ) as span:
-            # group-row padding policy: short buckets pad straight to
-            # max_slots — ONE prefill compilation per bucket, which a
-            # 1-prompt warmup already covers (distinct (bucket, rows)
-            # shapes each cost seconds of compile; a k-sized
-            # group would mint up to 4 variants per bucket). Long
-            # buckets keep power-of-two padding: an 8-row 4k-token
-            # prefill's transient cache is real HBM.
-            kp = (
-                self.max_slots if bucket <= 256
-                else min(_bucket(k, lo=1), self.max_slots)
-            )
             padded = np.zeros((kp, bucket), np.int32)
             tps = np.ones(kp, np.int32)
             rids = np.zeros(kp, np.int32)

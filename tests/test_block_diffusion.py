@@ -97,24 +97,29 @@ def test_served_tokens_are_the_references_choice_at_every_step(steps):
     finally:
         be.close()
     for prompt, budget, got in zip(prompts, budgets, results):
-        assert len(got["tokens"]) == budget == len(got["fixed_at"])
-        assert (len(prompt) + budget
-                + len(got["beyond_budget"]["tokens"])) % 4 == 0
-        rows, st = _stats(spec, params, prompt, got)
-        for steps_of_block in rows["steps_of_blocks"]:
-            masked = sum(1 for s in steps_of_block if s > 0)
-            assert [steps_of_block.count(s) for s in range(1, steps + 1)] \
-                == REF.schedule(masked, steps)
-        n_final = rows["final_rows"]
-        for c in rows["copies"]:
-            at = c["row0"] - n_final
-            for j in c["fixed_now"]:
-                gap = st["best"][at + j] - st["scored"][at + j]
-                assert gap <= 1e-4, (c, j, gap)
-            left = [j for j in c["masked"] if j not in c["fixed_now"]]
-            if c["fixed_now"] and left:
-                assert (min(st["log_conf"][at + j] for j in c["fixed_now"])
-                        >= max(st["log_conf"][at + j] for j in left) - 1e-4)
+        _assert_the_references_choice(spec, params, prompt, budget, got)
+
+
+def _assert_the_references_choice(spec, params, prompt, budget, got):
+    steps = spec["denoising_steps"]
+    assert len(got["tokens"]) == budget == len(got["fixed_at"])
+    assert (len(prompt) + budget
+            + len(got["beyond_budget"]["tokens"])) % 4 == 0
+    rows, st = _stats(spec, params, prompt, got)
+    for steps_of_block in rows["steps_of_blocks"]:
+        masked = sum(1 for s in steps_of_block if s > 0)
+        assert [steps_of_block.count(s) for s in range(1, steps + 1)] \
+            == REF.schedule(masked, steps)
+    n_final = rows["final_rows"]
+    for c in rows["copies"]:
+        at = c["row0"] - n_final
+        for j in c["fixed_now"]:
+            gap = st["best"][at + j] - st["scored"][at + j]
+            assert gap <= 1e-4, (c, j, gap)
+        left = [j for j in c["masked"] if j not in c["fixed_now"]]
+        if c["fixed_now"] and left:
+            assert (min(st["log_conf"][at + j] for j in c["fixed_now"])
+                    >= max(st["log_conf"][at + j] for j in left) - 1e-4)
 
 
 def test_logits_at_every_step_and_committed_rows_match_one_full_forward():
@@ -180,6 +185,35 @@ def test_a_request_alone_equals_the_same_request_in_a_full_grid():
     finally:
         be.close()
     assert together == alone
+
+
+@pytest.mark.parametrize("lengths,shapes", [
+    # the short three share one group of the floor's bucket
+    ([5, 40, 70, 600], [(512, 4, 3, 0), (1024, 1, 1, 0)]),
+    # the short one takes the spare row of three of bucket 1024
+    ([6, 600, 801, 702], [(1024, 4, 4, 1)]),
+])
+def test_a_short_prompt_in_a_longer_group_is_served_as_alone(lengths, shapes):
+    """max_len 1024, so that groups take power-of-two rows and a bucket
+    over the floor exists: a prompt that shares a longer group (its
+    length no multiple of the block: it keeps its own tail and its own
+    `tps // b * b`) gets the tokens it gets alone, and they are the
+    reference's choice."""
+    spec = _spec(2, max_len=1024)
+    be, params, _ = _backend(spec)
+    prompts, budgets = _prompts(lengths, seed=3), [10, 7, 9, 12]
+    groups0 = len(TRACER.loop_spans("lm_prefill_group"))
+    try:
+        together = _serve(be, prompts, budgets)
+        groups = TRACER.loop_spans("lm_prefill_group")[groups0:]
+        alone = [_serve(be, [p], [b])[0] for p, b in zip(prompts, budgets)]
+    finally:
+        be.close()
+    assert [(g["lb"]["bucket"], g["lb"]["padded_rows"], g["lb"]["rows"],
+             g["lb"]["riders"]) for g in groups] == shapes
+    assert together == alone
+    _assert_the_references_choice(
+        spec, params, prompts[0], budgets[0], together[0])
 
 
 def test_requests_join_and_leave_at_block_boundaries():

@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from dml_tpu.inference.generate import LMConfig, generate
-from dml_tpu.inference.lm_server import LMServer, _bucket
+from dml_tpu.inference.lm_server import (
+    _BUCKET_FLOOR, LMServer, _bucket, _prefill_bucket, _prefill_groups)
 from dml_tpu.models.transformer import TransformerLM
 
 CFG = LMConfig(vocab_size=61, d_model=32, n_heads=4, n_layers=2, d_ff=64,
@@ -604,22 +605,145 @@ def test_every_chunk_step_holds_its_five_phases(served):
 def test_prefill_groups_count_prompt_and_padded_tokens(served):
     srv, reqs, _, _, spans, delta = served
     groups = [d for d in spans if d["name"] == "lm_prefill_group"]
-    places = {d["sid"] for d in spans if d["name"] == "lm_place"}
-    assert groups and all(g["par"] in places for g in groups)
+    places = [d for d in spans if d["name"] == "lm_place"]
+    assert groups and all(
+        g["par"] in {d["sid"] for d in places} for g in groups)
     for g in groups:
         lb = g["lb"]
-        kp = srv.max_slots if lb["bucket"] <= 256 else lb["padded_rows"]
-        assert lb["padded_rows"] == kp and 1 <= lb["rows"] <= kp
-        assert lb["padded_tokens"] == kp * lb["bucket"]
+        # max_len 64 is under the bucket floor: ONE bucket, its rows
+        # padded to max_slots, so a round is one group and nobody rides
+        assert lb["bucket"] == srv.max_len and lb["riders"] == 0
+        assert lb["padded_rows"] == srv.max_slots >= lb["rows"] >= 1
+        assert lb["padded_tokens"] == srv.max_slots * srv.max_len
         assert 0 < lb["prompt_tokens"] <= lb["rows"] * lb["bucket"]
+    assert len(groups) == sum(d["lb"]["requests"] > 0 for d in places)
     assert sum(g["lb"]["rows"] for g in groups) == len(reqs)
     assert sum(g["lb"]["prompt_tokens"] for g in groups) == sum(
         p.size for p, _ in reqs)
     # the counter's two kinds are the label sums
     assert delta["prompt"] == sum(g["lb"]["prompt_tokens"] for g in groups)
     assert delta["padded"] == sum(g["lb"]["padded_tokens"] for g in groups)
-    assert sum(d["lb"]["requests"] for d in spans
-               if d["name"] == "lm_place") == len(reqs)
+    assert sum(d["lb"]["requests"] for d in places) == len(reqs)
+
+
+@pytest.mark.parametrize("lengths,shapes", [
+    # the short three share one group of the floor's bucket
+    ([5, 40, 70, 600], [(512, 4, 3, 0), (1024, 1, 1, 0)]),
+    # the short one takes the spare row of three of bucket 1024
+    ([5, 600, 801, 702], [(1024, 4, 4, 1)]),
+])
+def test_short_prompt_in_a_longer_group_matches_generate(
+        params, lengths, shapes):
+    """max_len 1024, so that groups take power-of-two rows and a bucket
+    over the floor exists: whatever bucket and rows a prompt shares, it
+    is served `generate`'s tokens."""
+    from dml_tpu.tracing import TRACER
+
+    rng = np.random.RandomState(29)
+    prompts = [rng.randint(0, CFG.vocab_size, n) for n in lengths]
+    budgets = [9, 1, 6, 12]
+    srv = LMServer(params, CFG, max_slots=4, max_len=1024, chunk=4)
+    TRACER.reset()
+    rids = srv.submit_many(prompts, budgets)
+    out = srv.run()
+    groups = TRACER.loop_spans("lm_prefill_group")
+    TRACER.reset()
+    assert [(g["lb"]["bucket"], g["lb"]["padded_rows"], g["lb"]["rows"],
+             g["lb"]["riders"]) for g in groups] == shapes
+    for rid, p, n in zip(rids, prompts, budgets):
+        np.testing.assert_array_equal(
+            out[rid], _isolated(params, p, n), err_msg=f"len {p.size}")
+
+
+def _traffic_lengths(rng, k):
+    """k prompt lengths from the benchmark's traffic files: lognormal,
+    median 256, sigma 0.9, clipped to 32..2048."""
+    return np.clip(np.exp(rng.normal(np.log(256), 0.9, k)),
+                   32, 2048).astype(int).tolist()
+
+
+def _padded_before_pr29(lengths, max_len, max_slots):
+    """What the rule this one replaced padded a round to: a group a
+    power-of-two bucket from 16, max_slots rows up to bucket 256."""
+    groups = {}
+    for n in lengths:
+        b = min(_bucket(n), max_len)
+        groups[b] = groups.get(b, 0) + 1
+    return sum(
+        (max_slots if b <= 256 else min(_bucket(k, lo=1), max_slots)) * b
+        for b, k in groups.items())
+
+
+@pytest.mark.parametrize("case,a,b", [
+    *[("shapes", seed, slots) for seed in range(4) for slots in (16, 32)],
+    *[("equal", k, n) for k in (1, 2, 4, 8, 16) for n in (40, 300, 700, 2048)],
+    *[("no_worse", seed, slots) for seed in range(3) for slots in (16, 32)],
+    ("by_hand", 0, 0), ("tiny", 0, 0),
+])
+def test_prefill_groups_partition(case, a, b):
+    """`_prefill_groups` at a real server's size (max_len 4096), where
+    no CPU test can run the prefill: the shapes it may form, the
+    warm-up's contract, and what it saves."""
+    max_len = 4096
+    if case == "shapes":
+        rng, slots = np.random.RandomState(100 + a), b
+        for _ in range(200):
+            lens = _traffic_lengths(rng, rng.randint(1, slots + 1))
+            groups = _prefill_groups(lens, max_len, slots)
+            assert sorted(i for _, _, m in groups for i in m) == list(
+                range(len(lens)))
+            for bucket, rows, members in groups:
+                own = [_prefill_bucket(lens[i], max_len) for i in members]
+                assert bucket == max(own) >= _BUCKET_FLOOR
+                assert rows == _bucket(len(members), lo=1) <= slots
+                # a rider never raises the rows: equal-length prompts
+                # of this bucket alone (the warm-up) form this shape
+                assert rows <= _bucket(own.count(bucket), lo=1)
+    elif case == "equal":
+        # the warm-up's contract: 2^j equal-length prompts submitted
+        # alone are ONE group of 2^j rows
+        k, n = a, b
+        assert _prefill_groups([n] * k, max_len, 16) == [
+            (max(_bucket(n), _BUCKET_FLOOR), k, list(range(k)))]
+    elif case == "no_worse":
+        rng, slots, new, old = np.random.RandomState(200 + a), b, 0, 0
+        worse = 0
+        for _ in range(500):
+            lens = _traffic_lengths(rng, rng.randint(1, 9))
+            n = sum(r * bk for bk, r, _ in
+                    _prefill_groups(lens, max_len, slots))
+            o = _padded_before_pr29(lens, max_len, slots)
+            # never above the old rule's on 32 slots. On 16, a round
+            # that holds several prompts of ONE bucket <= 128 can be:
+            # that group was 16 x 128 = 2048 tokens for all of them
+            assert n <= o + (2 * _BUCKET_FLOOR if slots == 16 else 0), lens
+            worse, new, old = worse + (n > o), new + n, old + o
+        assert worse <= 5 and new < 0.6 * old
+    elif case == "by_hand":
+        g = _prefill_groups
+        # the issue's rounds: one short prompt alone; three short ones
+        assert g([200], max_len, 16) == [(512, 1, [0])]
+        assert g([40, 100, 200], max_len, 32) == [(512, 4, [0, 1, 2])]
+        # three of bucket 512 tie with 2 + 1 and stay whole; three of
+        # bucket 1024 do not
+        assert g([300] * 3, max_len, 16) == [(512, 4, [0, 1, 2])]
+        assert [x[:2] for x in g([600] * 3, max_len, 16)] == [
+            (1024, 1), (1024, 2)]
+        # a short prompt takes the spare row of three long ones, and
+        # is refused where it would raise the rows
+        assert g([700, 5, 900, 600], max_len, 16) == [
+            (1024, 4, [0, 1, 2, 3])]
+        assert g([700, 5, 900, 600, 800], max_len, 16) == [
+            (512, 1, [1]), (1024, 4, [0, 2, 3, 4])]
+        assert g([5, 40, 70, 600], max_len, 16) == [
+            (512, 4, [0, 1, 2]), (1024, 1, [3])]
+        # max_slots that is no power of two caps the rows
+        assert g([300] * 5, max_len, 6) == [(512, 6, [0, 1, 2, 3, 4])]
+    else:
+        # a server under the floor: one bucket, max_slots rows, one group
+        assert _prefill_groups([5, 40, 70, 128], 128, 4) == [
+            (128, 4, [0, 1, 2, 3])]
+        assert _prefill_groups([9], 64, 3) == [(64, 3, [0])]
 
 
 def test_every_request_span_is_ordered_and_counted(served):
