@@ -55,7 +55,7 @@ from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
-#: the repo's benchmarked LM (bench.py `_bench_lm` / `_bench_cluster_lm`)
+#: the smoke's LM: 198M parameters, grouped-query attention, bfloat16
 LM_SPEC: Dict[str, Any] = {
     "name": "SmokeLM", "vocab_size": 32000, "d_model": 1024,
     "n_heads": 16, "n_kv_heads": 4, "n_layers": 12, "d_ff": 4096,
@@ -411,8 +411,7 @@ async def lm_phase(
 def kernel_phase(seed: int, *, batch: int = 8, heads: int = 16,
                  kv_heads: int = 4, head_dim: int = 64,
                  context: int = 4096, image_hw: int = 299) -> Dict[str, Any]:
-    """Compiled (not interpreted) kernels against their jnp references,
-    with the bounds bench.py's on-device parity section uses."""
+    """Compiled (not interpreted) kernels against their jnp references."""
     import jax
     import jax.numpy as jnp
 

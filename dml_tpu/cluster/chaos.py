@@ -238,26 +238,23 @@ EVENT_KINDS = (
     "liar",
 )
 
-#: the adversarial scenario families `scenario_plan` generates and the
-#: bench chaos section + claim_check validate per-family ("churn" —
-#: sustained seeded join/leave, not one-off restarts — landed with the
-#: control-plane scale work and is claim_check-gated from round 12;
+#: the adversarial scenario families `scenario_plan` generates
+#: ("churn" — sustained seeded join/leave, not one-off restarts;
 #: "elastic" — capacity change as a first-class event: authenticated
 #: scale-out mid-load, graceful LEAVE scale-in, join flapping, and a
-#: forged-join storm — is claim_check-gated from round 18;
+#: forged-join storm;
 #: "liar" — a lying-metrics straggler whose self-reported walls stay
 #: clean while batches stall, flaggable only by the signal plane's
-#: dispatch->ACK cross-check — is claim_check-gated from round 19;
+#: dispatch->ACK cross-check;
 #: "autoscale" — chaos aimed at the CLOSED-LOOP CONTROLLER itself:
 #: thrashing square-wave load against the scale-out hysteresis, a
 #: lying straggler feeding the policy, a scale-in racing a traffic
 #: spike, and a leader kill between a decision firing and its
-#: actuation ACK — is claim_check-gated from round 20;
+#: actuation ACK;
 #: "train" — chaos aimed at a TrainJob's exactly-once step contract:
 #: a trainer killed mid-epoch, a leader killed inside the
 #: checkpoint-every-step window, and a join racing a step boundary —
-#: the sweep proves no global step lost or double-applied — is
-#: claim_check-gated from round 22)
+#: the sweep proves no global step lost or double-applied)
 SCENARIO_FAMILIES = ("asym", "disk", "dns", "skew", "fuzz", "churn",
                      "elastic", "liar", "autoscale", "train")
 

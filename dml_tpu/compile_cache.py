@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
 One rule for every entry point that compiles (`chip_smoke.py`,
-`bench.py`, the node CLI, the examples, `tests/conftest.py`):
+`benchmark/run.py`, the node CLI, the examples, `tests/conftest.py`):
 
 - `JAX_COMPILATION_CACHE_DIR` set: JAX reads it at import and the
   cache lives there — code sets no directory, so whoever launches the

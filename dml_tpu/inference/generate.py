@@ -6,10 +6,7 @@ serving half of the framework's LM path. Written TPU-first:
 - The prompt runs through `prefill`: ONE batched forward over
   [B, Tp] with the Pallas flash kernel doing causal attention (bf16
   MXU), filling the KV cache in a single pass — a 2k-token prompt
-  costs one ~6-11 ms forward instead of 2k scanned steps (~1.1 s) —
-  a ~100-170x prompt-processing speedup across v5e captures
-  (re-measured every bench run — `lm.prefill_2k_prompt` in the
-  latest BENCH_r* artifact).
+  costs one forward instead of 2k scanned steps.
 - New tokens then run under ONE `lax.scan` of `decode_step` inside
   one jit; the chip never returns to the host between tokens.
   Per-step attention is one [B,H,1,T] f32 matvec against the cached
@@ -52,13 +49,11 @@ class LMConfig:
 
     `kv_quant=True` stores the KV cache as int8 with one f32 scale per
     (position, kv-head) — ~1.9x less cache HBM than bf16, i.e. ~2x the
-    contexts/slots per chip, AND faster decode: the Pallas decode
-    kernel (ops/decode_attention.py) dequantizes inline while
-    streaming the int8 cache through VMEM, so the bandwidth saving is
-    real — ~1.2-1.4x bf16-cache decode at b8/4k on v5e (bench
-    `lm.kv_cache_int8_4k_ctx_b8`, re-measured every round; on the
-    XLA einsum path the dequant materializes in HBM and int8 LOSES
-    ~0.7x, which is why the kernel owns this config).
+    contexts/slots per chip: the Pallas decode kernel
+    (ops/decode_attention.py) dequantizes inline while streaming the
+    int8 cache through VMEM (on the XLA einsum path the dequant
+    materializes in HBM, which is why the kernel owns this config on
+    a TPU; no cell of the benchmark holds an int8 cache yet).
     Numerics: symmetric per-vector rounding on K and V (~0.4% each);
     greedy outputs can differ from the bf16-cache path on near-ties,
     so the serving stack treats kv_quant as a MODEL CONFIG, not a

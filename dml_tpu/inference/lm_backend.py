@@ -342,7 +342,6 @@ class LMBackend:
         temperature: float = 0.0,
         top_k: Optional[int] = None,
         seed: int = 0,
-        gather_shardings: Any = None,
         kv_cache_bytes: int = 0,
         spec_k: int = 0,
         spec_draft: Optional[Dict[str, Any]] = None,
@@ -354,7 +353,7 @@ class LMBackend:
         self.server = LMServer(
             params, cfg, max_slots=max_slots, max_len=max_len,
             chunk=chunk, temperature=temperature, top_k=top_k, seed=seed,
-            gather_shardings=gather_shardings, diffusion=diffusion,
+            diffusion=diffusion,
         )
         # speculative decoding (spec_k > 0): a deterministic DRAFT
         # model from `spec_draft` (a model spec dict — normally

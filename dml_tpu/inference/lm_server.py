@@ -63,16 +63,13 @@ it decodes alone or packed with others (pinned by
 test_sampled_request_independent_of_batch). Note the stream differs
 from `generate`'s split-chain, which is shape-coupled by design.
 
-Measured on a v5e in 2026-07 (12-layer 1024d GQA-4 LM, bf16, 1k
-cache; bench `lm.continuous_batching`; not re-measured on the current
-installation): 1 slot decoded at ~2.1-2.4k tok/s, 8 slots at ~9-9.7k
-tok/s aggregate — ~4.4-4.6x, because the weight stream (the per-step
-HBM bill) is shared by every slot and the per-slot cache writes are an
-unrolled dynamic_update_slice chain (a vmap'd update lowers to an XLA
-scatter that copies the whole cache; fixing that took 8 slots from
-1.32 to 0.83 ms/step, r4). The server makes several dispatches per
-request (prefill, insert, chunks); each costs host time, so the design
-below keeps them few and never blocks between them.
+Slots share the weight stream (the per-step HBM bill), and the
+per-slot cache writes are an unrolled dynamic_update_slice chain (a
+vmap'd update lowers to an XLA scatter that copies the whole cache).
+What a step costs on the chip is PERF.md §5. The server makes several
+dispatches per request (prefill, insert, chunks); each costs host
+time, so the design below keeps them few and never blocks between
+them.
 
 Net-new vs the reference (inference over single images, no sequence
 serving — SURVEY §0); the slot scheduler is the LM-serving analog of
@@ -479,32 +476,16 @@ class LMServer:
         temperature: float = 0.0,
         top_k: Optional[int] = None,
         seed: int = 0,
-        gather_shardings: Any = None,
         diffusion: Optional[BlockDiffusion] = None,
     ):
         """`diffusion` makes this a block-diffusion server (see
         `BlockDiffusion` and `_diffuse_impl`): `chunk` is then the
-        tokens a slot a dispatch, in whole blocks.
-
-        `gather_shardings` (a pytree of NamedShardings matching
-        `params`, normally all-replicated over a mesh whose HBM holds
-        `params` tp-sharded) switches the server into the per-forward
-        PARAM-GATHER serving form: every prefill/chunk dispatch
-        constrains the weights to those shardings at entry, so XLA
-        all-gathers the tp-sharded tree over ICI each dispatch and
-        then runs the replicated program. This is the pessimized form
-        the `cluster_lm_sharded` bench scores against weight-RESIDENT
-        serving (params sharded, no constraint — GSPMD partitions the
-        contractions in place; `dryrun_multichip` part 4 asserts that
-        form token-exact vs a single device). None = leave params as
-        they are placed (the default, and the resident form when the
-        caller device_put the tree with tp shardings)."""
+        tokens a slot a dispatch, in whole blocks."""
         if chunk < 1:
             raise ValueError("chunk must be >= 1")
         if max_slots < 1:
             raise ValueError("max_slots must be >= 1")
         self.params = params
-        self._gather_shardings = gather_shardings
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_len = max_len
@@ -569,7 +550,7 @@ class LMServer:
         # 4,096 rows x 12 KiB) do not fit beside an 8.7 GB model
         self._prefill = jax.jit(
             lambda p, pr, li: prefill(
-                self._maybe_gather(p), self.cfg, pr,
+                p, self.cfg, pr,
                 self.max_len if diffusion is None else pr.shape[1],
                 logits_index=li, mesh=self._mesh,
                 head=diffusion is None,
@@ -753,8 +734,7 @@ class LMServer:
 
     def spec_stats(self) -> Optional[Dict[str, Any]]:
         """Acceptance accounting (None when spec was never enabled):
-        the observable half of the speculation story — bench and
-        claim_check score the measured rate, not the configured one."""
+        the measured rate, not the configured one."""
         sp = self._spec
         if sp is None:
             return None
@@ -824,16 +804,6 @@ class LMServer:
             lambda: init_cache(cfg, self.max_slots, self.max_len),
             out_shardings=NamedSharding(self._mesh, P(None, ax)),
         )()
-
-    def _maybe_gather(self, params):
-        """Trace-time hook: under the param-gather serving form the
-        weight tree is constrained to `gather_shardings` at dispatch
-        entry (XLA inserts the ICI all-gather); otherwise identity."""
-        if self._gather_shardings is None:
-            return params
-        return jax.lax.with_sharding_constraint(
-            params, self._gather_shardings
-        )
 
     def _insert_impl(self, cache, pcache, slot, row):
         """Copy row `row` of a (possibly group-batched) prefilled
@@ -919,7 +889,6 @@ class LMServer:
         attends 0 rows and cache attention fetches none of its rows.
         Its clamped write stays (the invariant above)."""
         last = self.max_len - 1
-        params = self._maybe_gather(params)
 
         def body(carry, _):
             cache, cur, pos = carry
@@ -963,7 +932,6 @@ class LMServer:
         b, s_n = cfg.block_length, df.steps
         last = self.max_len - b
         mask_id = jnp.int32(df.mask_token_id)
-        params = self._maybe_gather(params)
         live = rid > 0
         where = jnp.arange(b, dtype=jnp.int32)[None, :]
 
@@ -1069,7 +1037,6 @@ class LMServer:
         delivering g_1..g_c is literally c plain greedy steps —
         bitwise-identical outputs, for ANY d_toks whatsoever."""
         k = self._spec.k
-        params = self._maybe_gather(params)
         start = jnp.minimum(pos, self.max_len - (k + 1))
         inputs = jnp.concatenate([cur[:, None], d_toks], axis=1)
         logits, cache = batched_verify_step(

@@ -5,7 +5,7 @@ PR 5's worker groups served IMAGE jobs sharded (param_gather
 ShardedInference) but deliberately forfeited the group's chips for LM
 rounds — the pool collapsed back to single-chip slots because the
 group engine could not run an LM forward. This module closes that
-gap with three serving forms over one group topology, all built on
+gap with two serving forms over one group topology, both built on
 the SAME deterministic params tree (`lm_backend.lm_spec_parts`) and
 the SAME continuous-batching server:
 
@@ -18,11 +18,6 @@ the SAME continuous-batching server:
   weight traffic per dispatch. `__graft_entry__.dryrun_multichip`
   part 4 asserts this decode form token-exact vs a single device
   (f32; greedy).
-- **param-gather** (the pessimized comparison form, and PR 5's image
-  analog): weights live tp-sharded but every dispatch constrains them
-  replicated, so XLA all-gathers the full tree over ICI per
-  prefill/chunk — the `cluster_lm_sharded` bench scores exactly this
-  tax.
 - **disaggregated**: `WorkerGroupSpec.roles` splits the group into
   prefill-role and decode-role members (Gemma-on-TPU serving
   comparison, arxiv 2605.25645: prefill is compute-bound, decode is
@@ -52,7 +47,7 @@ bytes, seconds) metric families; see the observability docstring map.
 
 Speculative decoding rides the same forms (`SPEC_DECODE_SUPPORT`):
 ``lm_spec["spec_k"] > 0`` arms a derived draft model locally on the
-resident/gather primary, while the disagg form puts the draft on the
+resident primary, while the disagg form puts the draft on the
 otherwise-idle prefill-role peers — `LMPrefillBackend` generates
 spec_k proposal tokens per request and ships them as an optional
 ``draft`` field in the slab header (old slabs/readers round-trip
@@ -61,16 +56,6 @@ round (`LMServer` shipped-draft verification). The pp>1 form is a
 typed exclusion (batch-granular stage schedule, no per-slot verify
 seam). Greedy outputs stay bitwise-identical in every placement —
 a lost or garbage proposal shortens acceptance, never changes tokens.
-
-``python -m dml_tpu.inference.lm_sharded`` is the bench subprocess
-entry (`cluster_lm_sharded` section): 5-node cluster on a virtual CPU
-mesh, steady-state tok/s for all three forms on the same dp=1×tp=2
-group, token-equality vs isolated generate(), a
-member-kill-mid-decode chaos case (tools/claim_check.py validates the
-block from round 8), and the round-21 raw-decode arms —
-`bench_specdec_arm` (plain vs speculative tok/s at a declared
-acceptance + real-draft auto-disable) and `bench_cb_arm`
-(step-granular adoption vs batch-drain TTFT under staggered load).
 """
 
 from __future__ import annotations
@@ -92,7 +77,7 @@ log = logging.getLogger(__name__)
 _M_SHARDED_BATCHES = METRICS.counter(
     "lm_sharded_batches_total",
     "LM batches served on a group's sharded engine, by serving mode "
-    "(resident|gather|disagg)")
+    "(resident|disagg|pp)")
 _M_SHARDED_TOKENS = METRICS.counter(
     "lm_sharded_tokens_total",
     "generated tokens delivered by group-sharded LM serving")
@@ -127,30 +112,13 @@ def shard_lm_params(params: Any, mesh) -> Any:
     return jax.device_put(params, partition_params(params, mesh))
 
 
-def replicated_shardings(params: Any, mesh) -> Any:
-    """All-replicated sharding tree over `mesh` — the constraint the
-    param-GATHER serving form applies at every dispatch entry."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    return jax.tree_util.tree_map(
-        lambda _: NamedSharding(mesh, P()), params
-    )
-
-
 def sharded_lm_backend(
     lm_spec: Dict[str, Any],
     mesh,
-    form: str = "resident",
     spec_draft_local: bool = True,
 ) -> "Any":
-    """An `LMBackend` whose server runs over `mesh`:
-
-    - ``form="resident"``: params tp-sharded in HBM, no per-forward
-      gather (the production form);
-    - ``form="gather"``: params tp-sharded in HBM but constrained
-      replicated at every dispatch (the per-forward all-gather tax
-      the bench scores against).
+    """An `LMBackend` whose server runs over `mesh`, its params
+    tp-sharded in HBM (`shard_lm_params`), no per-forward gather.
 
     ``lm_spec["spec_k"] > 0`` arms speculative decoding: a derived
     draft model (config.draft_lm_spec, or lm_spec["spec_draft"]
@@ -171,11 +139,8 @@ def sharded_lm_backend(
     extra thread hop buys nothing."""
     from .lm_backend import LMBackend, lm_spec_parts
 
-    if form not in ("resident", "gather"):
-        raise ValueError(f"unknown param form {form!r}")
     params, cfg = lm_spec_parts(lm_spec)
     sharded = shard_lm_params(params, mesh)
-    gather = replicated_shardings(params, mesh) if form == "gather" else None
     max_new = int(lm_spec.get("max_new_tokens", 32))
     spec_k = int(lm_spec.get("spec_k", 0) or 0)
     spec_draft = (
@@ -196,7 +161,6 @@ def sharded_lm_backend(
             else None
         ),
         seed=int(lm_spec.get("seed", 0)),
-        gather_shardings=gather,
         # same knob as LMBackend.from_spec: the sharded decode primary
         # warm-starts from its resident prefix cache too
         kv_cache_bytes=int(
@@ -990,7 +954,6 @@ class LMPrefillBackend:
 
     def __init__(
         self, params: Any, cfg, max_len: int = 1024,
-        min_prefill_s: float = 0.0,
         draft: Optional[Tuple[Any, Any]] = None,
         draft_k: int = 0,
     ):
@@ -1013,15 +976,6 @@ class LMPrefillBackend:
         self.draft = draft
         self.draft_k = int(draft_k)
         self.drafts_shipped = 0
-        #: per-request device-time floor (seconds). 0 in production.
-        #: The bench's handoff-ladder phase sets it so fan-out and
-        #: stream-overlap measurements exercise the handoff
-        #: ORCHESTRATION against a stable simulated device time —
-        #: on the in-process shared-core CPU sim one XLA prefill
-        #: already saturates the host, so raw peer compute cannot
-        #: scale there no matter what the orchestration does (same
-        #: declared-stub discipline as chaos/request bench backends).
-        self.min_prefill_s = float(min_prefill_s)
 
     def _prefill_fn(self, bucket: int):
         fn = self._fns.get(bucket)
@@ -1047,7 +1001,6 @@ class LMPrefillBackend:
 
         from .lm_server import _bucket
 
-        t0 = time.monotonic()
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         tp = int(prompt.size)
         if tp == 0:
@@ -1104,13 +1057,6 @@ class LMPrefillBackend:
                 self.drafts_shipped += 1
             except Exception as e:
                 log.warning("draft shipment failed (%r); slab only", e)
-        if self.min_prefill_s > 0:
-            # thread context (to_thread / slabs_bytes): a plain sleep
-            # pads this request to the declared floor without holding
-            # the event loop
-            left = self.min_prefill_s - (time.monotonic() - t0)
-            if left > 0:
-                time.sleep(left)
         return entry
 
     def slabs_bytes(
@@ -1712,8 +1658,8 @@ def check_hbm_budget(
     weight layout: a pp group passes when each member's slice
     (`pp_hbm_report.per_member_bytes`) fits; a non-pp group must fit
     the FULL tree per member (weight-resident tp shards storage too,
-    but the gather form and degradation-to-single-chip both
-    materialize the full tree, so the budget is the honest bound).
+    but degradation-to-single-chip materializes the full tree, so the
+    budget is the honest bound).
     ``pp`` overrides the spec's declared axis with the RESOLVED mesh
     size — a spec axis of -1 (fill remaining devices) must be checked
     against what it resolved to, not clamped to non-pp. Returns the
@@ -1749,7 +1695,6 @@ def check_hbm_budget(
 # per-slot verify seam — ROADMAP item 4 remainder).
 SPEC_DECODE_SUPPORT: Dict[str, Any] = {
     "resident": "local",
-    "gather": "local",
     "disagg": "shipped",
     "pp": False,
 }
@@ -1891,11 +1836,10 @@ def wire_lm_group(node, store, lm_spec: Dict[str, Any]):
             # disagg decode primary arms shipped-draft verification
             # only (SPEC_DECODE_SUPPORT["disagg"] = "shipped"): the
             # draft lives on prefill-role peers, so the primary's
-            # HBM and step loop carry zero draft cost; resident/
-            # gather forms host the draft locally ("local")
+            # HBM and step loop carry zero draft cost; the resident
+            # form hosts the draft locally ("local")
             be = sharded_lm_backend(
-                lm_spec, mesh, form="resident",
-                spec_draft_local=not disagg,
+                lm_spec, mesh, spec_draft_local=not disagg,
             )
             cap = float(
                 mesh.shape.get("dp", 1) * mesh.shape.get("tp", 1)
@@ -1915,892 +1859,3 @@ def wire_lm_group(node, store, lm_spec: Dict[str, Any]):
                     members=members, alive_fn=alive, capacity=cap,
                 )
     return gb, prefill
-
-
-# ----------------------------------------------------------------------
-# bench: the `cluster_lm_sharded` section's CPU-subprocess body
-# (python -m dml_tpu.inference.lm_sharded — same pattern as
-# jobs/groups: bench.py runs it with JAX_PLATFORMS=cpu and 8 virtual
-# devices)
-# ----------------------------------------------------------------------
-
-
-def bench_lm_sharded_serving(
-    n_prompts: int = 16,
-    new_tokens: int = 16,
-    base_port: int = 28961,
-    steady_s: float = 4.0,
-    tmp: str = "/tmp/dml_tpu_bench_lm_sharded",
-) -> Dict[str, Any]:
-    """Sharded LM serving forms through the FULL cluster pipeline on
-    one topology (H3 decode primary, H4+H5 prefill roles):
-
-    - param_gather vs weight-resident tp=2 (PR 6's comparison),
-    - PIPELINE-parallel pp=2 (layer stack split across members —
-      models deeper than one member's HBM; `pp_hbm_report` records
-      the budget story),
-    - disaggregated prefill/decode with the handoff ladder: whole-
-      slab pull vs chunk-STREAMED handoff (time-to-first-token must
-      strictly drop — decode adopts request 0 while request N still
-      prefills), and 1- vs 2-prefill-peer FAN-OUT on a prefill-heavy
-      workload (context-phase throughput must rise),
-    - a member-kill-MID-STREAM chaos case: the dying peer's in-flight
-      share demotes to typed per-request local-prefill fallbacks,
-      the job completes exactly once, tokens unchanged. The peers
-      are DRAFT peers too (draft_k > 0, shipped-draft verification
-      on the decode primary), so the kill also covers draft-proposal
-      loss mid-verify,
-    - after the cluster stops: the speculative-decoding A/B
-      (`bench_specdec_arm` — speedup at a declared acceptance,
-      real-draft auto-disable, token equality) and the
-      continuous-batching TTFT A/B (`bench_cb_arm` — step-granular
-      adoption vs batch-drain under staggered load).
-
-    5-node topology: leader + standby + the three-member group means
-    the formed group is the pool's ONLY slot, so every timed batch
-    flows through the group engine and mode rates compare serving
-    forms. What transfers to a pod is the token-equality contract
-    (every mode's merged outputs == isolated generate(), f32 greedy)
-    and the handoff/exactly-once machinery; tok/s and overlap ratios
-    on shared-core CPU devices are an honest lower bound."""
-    import os
-    import shutil
-
-    import jax
-
-    devices = jax.devices()
-    if len(devices) < 2:
-        return {
-            "skipped": True,
-            "reason": f"needs >= 2 devices for tp=2, have {len(devices)}",
-        }
-
-    import jax.numpy as jnp
-
-    from ..cluster.chaos import LocalCluster
-    from ..config import MeshSpec, Timing, WorkerGroupSpec, draft_lm_spec
-    from ..jobs.service import JobService
-    from ..parallel.mesh import make_mesh
-    from .generate import generate
-    from .lm_backend import LMBackend, lm_spec_parts, write_prompt_file
-
-    # d_model 384: big enough that the gathered form's 2× per-chip
-    # compute dominates its skipped partitioning overhead even on the
-    # shared-core CPU mesh (at d64 the overhead wins and the
-    # comparison would read backwards); small enough to compile in
-    # seconds per form. n_layers 4 so the pp=2 pipeline splits the
-    # stack evenly (2 blocks per stage).
-    lm_spec = {
-        "name": "ShardLM", "vocab_size": 128, "d_model": 384,
-        "n_heads": 4, "n_kv_heads": 2, "n_layers": 4, "d_ff": 1536,
-        "dtype": "float32", "max_new_tokens": new_tokens,
-        "max_slots": 4, "max_len": 128, "seed": 0, "chunk": 8,
-    }
-    params, cfg = lm_spec_parts(lm_spec)
-    mesh = make_mesh(MeshSpec(dp=1, tp=2), devices=devices[:2])
-    mesh_pp = make_mesh(
-        MeshSpec(dp=1, tp=1, pp=2), devices=devices[:2]
-    )
-    # the group-engine forms share one deterministic tree; the
-    # single-chip reference backend and the prefill workers use the
-    # plain (single-device) placement of the SAME tree
-    be_resident = sharded_lm_backend(lm_spec, mesh, form="resident")
-    be_gather = sharded_lm_backend(lm_spec, mesh, form="gather")
-    # the disagg decode primary arms SHIPPED-draft verification
-    # (SPEC_DECODE_SUPPORT["disagg"]): prefill peers run the derived
-    # draft and ship spec_k proposals in each slab header, the
-    # primary verifies them on the adoption round — so the kill-H5
-    # chaos case below doubles as the draft-peer-death-mid-verify
-    # case (typed fallback, exactly-once tokens, equality asserted)
-    spec_k_bench = 4
-    be_disagg = sharded_lm_backend(
-        {**lm_spec, "spec_k": spec_k_bench}, mesh, form="resident",
-        spec_draft_local=False,
-    )
-    be_pp = PipelinedLMBackend(lm_spec, mesh_pp)
-    be_single = LMBackend(
-        params, cfg, max_new_tokens=new_tokens,
-        max_slots=int(lm_spec["max_slots"]),
-        max_len=int(lm_spec["max_len"]), chunk=int(lm_spec["chunk"]),
-    )
-    # one prefill backend PER prefill-role node, so the fan-out phase
-    # can assert both peers actually built slabs; both carry the
-    # derived draft model (random weights — draft QUALITY is not what
-    # the handoff path scores; equality + exactly-once are)
-    draft_parts = lm_spec_parts(draft_lm_spec(lm_spec))
-    prefill_bes = {
-        "H4": LMPrefillBackend(
-            params, cfg, max_len=lm_spec["max_len"],
-            draft=draft_parts, draft_k=spec_k_bench,
-        ),
-        "H5": LMPrefillBackend(
-            params, cfg, max_len=lm_spec["max_len"],
-            draft=draft_parts, draft_k=spec_k_bench,
-        ),
-    }
-    # per-member HBM story: the pp split is what fits a member whose
-    # budget sits between its layer slice and the full tree
-    hbm = pp_hbm_report(lm_spec, 2)
-    hbm_budget = (hbm["per_member_bytes"] + hbm["full_bytes"]) // 2
-    group = WorkerGroupSpec(
-        "pd0", ("H3", "H4", "H5"), MeshSpec(dp=1, tp=2),
-        lm_models=("ShardLM",),
-        roles={"H3": "decode", "H4": "prefill", "H5": "prefill"},
-        hbm_bytes=hbm_budget,
-    )
-    model = "ShardLM"
-
-    async def run() -> Dict[str, Any]:
-        shutil.rmtree(tmp, ignore_errors=True)
-        os.makedirs(tmp, exist_ok=True)
-        services: Dict[str, JobService] = {}
-
-        def make_jobs(node, store):
-            uname = node.me.unique_name
-            alive = lambda: {  # noqa: E731
-                n.unique_name for n in node.membership.alive_nodes()
-            }
-            js = JobService(node, store)
-            members = node.spec.group_members_unique(group.name)
-            is_primary = bool(members) and uname == members[0]
-            if is_primary:
-                def disagg(handoff, fanout):
-                    return DisaggLMBackend(
-                        be_disagg, model_name=model,
-                        group_name=group.name, node=node, store=store,
-                        members=members, alive_fn=alive, capacity=3.0,
-                        prefill_timeout=8.0, handoff=handoff,
-                        fanout=fanout, draft_k=spec_k_bench,
-                    )
-
-                # mode-swapped during the run via set_mode below
-                js._lm_group_modes = {
-                    "resident": sharded_lm_group_backend(
-                        be_resident, model_name=model,
-                        group_name=group.name, members=members,
-                        alive_fn=alive, capacity=3.0, mode="resident",
-                    ),
-                    "gather": sharded_lm_group_backend(
-                        be_gather, model_name=model,
-                        group_name=group.name, members=members,
-                        alive_fn=alive, capacity=3.0, mode="gather",
-                    ),
-                    "pp": sharded_lm_group_backend(
-                        be_pp, model_name=model,
-                        group_name=group.name, members=members,
-                        alive_fn=alive, capacity=3.0, mode="pp",
-                    ),
-                    "disagg": disagg("stream", 0),
-                    "disagg_stream_f1": disagg("stream", 1),
-                    "disagg_stream_f2": disagg("stream", 2),
-                    "disagg_slab_f1": disagg("slab", 1),
-                }
-            pf = prefill_bes.get(node.me.name)
-            js.register_lm(
-                model, backend=be_single.backend, cost=be_single.cost(),
-                prefill=pf,
-                group_backend=(
-                    js._lm_group_modes["resident"] if is_primary
-                    else None
-                ),
-            )
-            services[uname] = js
-            return js
-
-        cluster = LocalCluster(
-            5, tmp, base_port,
-            timing=Timing(ping_interval=0.2, ack_timeout=0.3,
-                          cleanup_time=1.0, leader_rpc_timeout=10.0),
-            worker_groups=[group],
-            make_jobs=make_jobs,
-        )
-        try:
-            await cluster.start()
-            await cluster.wait_for(
-                cluster.converged, 20.0, "lm-sharded bench convergence"
-            )
-            members = cluster.spec.group_members_unique(group.name)
-            # the chaos phase kills a prefill peer: the client driving
-            # submit/wait/get-output must be NEITHER group member (a
-            # dead client wedges its own wait_job forever) nor the
-            # leader (client() excludes it)
-            client = cluster.client(avoid=members)
-            rng = np.random.RandomState(0)
-            reference: Dict[str, List[int]] = {}
-            for i in range(8):
-                prompt = rng.randint(0, cfg.vocab_size,
-                                     int(rng.randint(6, 24)))
-                fname = f"prompt_{i}.tokens.txt"
-                p = os.path.join(tmp, fname)
-                write_prompt_file(p, prompt)
-                await client.store.put(p, fname)
-                reference[fname] = [int(t) for t in np.asarray(generate(
-                    params, cfg,
-                    jnp.asarray(np.asarray(prompt, np.int32)[None]),
-                    new_tokens,
-                ))[0]]
-            # prefill-heavy files for the handoff-comparison phase:
-            # long prompts, tiny budgets — the wall IS context phase.
-            # LOCAL files only (never store-put): the steady-mode jobs
-            # wrap-sample every matching store object, and mixing
-            # budget-4 files into them would corrupt the tok/s
-            # accounting above
-            ctx_budget = 4
-            ctx_files = []
-            ctx_prompt_toks = 0
-            for i in range(6):
-                prompt = rng.randint(0, cfg.vocab_size,
-                                     int(rng.randint(48, 64)))
-                fname = f"ctx_{i}.tokens.txt"
-                p = os.path.join(tmp, fname)
-                write_prompt_file(p, prompt, max_new_tokens=ctx_budget)
-                ctx_files.append(fname)
-                ctx_prompt_toks += int(prompt.size)
-                reference[fname] = [int(t) for t in np.asarray(generate(
-                    params, cfg,
-                    jnp.asarray(np.asarray(prompt, np.int32)[None]),
-                    ctx_budget,
-                ))[0]]
-
-            primary_js = services[members[0]]
-
-            def set_mode(mode: str) -> Any:
-                gb = primary_js._lm_group_modes[mode]
-                pf = prefill_bes.get(
-                    cluster.spec.node_by_unique_name(members[0]).name
-                )
-                primary_js.register_lm(
-                    model, backend=be_single.backend,
-                    cost=be_single.cost(), prefill=pf,
-                    group_backend=gb,
-                )
-                return gb
-
-            async def timed_job(n=None) -> Tuple[float, Dict[str, Any]]:
-                n = n if n is not None else n_prompts
-                t0 = time.monotonic()
-                job_id = await client.jobs.submit_job(model, n)
-                done = await client.jobs.wait_job(job_id, timeout=600.0)
-                wall = time.monotonic() - t0
-                assert done["total_queries"] == n, done
-                merged = await client.jobs.get_output(
-                    job_id, os.path.join(tmp, f"out_{job_id}.json")
-                )
-                return wall, merged
-
-            def check_equal(merged: Dict[str, Any]) -> bool:
-                return bool(merged) and all(
-                    merged[f]["tokens"] == reference[f]
-                    for f in merged
-                )
-
-            modes_out: Dict[str, Any] = {}
-            all_equal = True
-            for mode in ("gather", "resident", "pp", "disagg"):
-                gb = set_mode(mode)
-                # warm the compiles outside the timed window
-                _, merged = await timed_job()
-                all_equal = all_equal and check_equal(merged)
-                t0 = time.monotonic()
-                tokens = 0
-                jobs = 0
-                while (
-                    time.monotonic() - t0 < steady_s or jobs < 2
-                ):
-                    _, merged = await timed_job()
-                    all_equal = all_equal and check_equal(merged)
-                    # n_prompts queries per job, each decoding the
-                    # shared default budget (the ctx_* files carry
-                    # directives but this phase samples prompt_*)
-                    tokens += n_prompts * new_tokens
-                    jobs += 1
-                wall = time.monotonic() - t0
-                entry = {
-                    "tok_s": round(tokens / wall, 1),
-                    "jobs": jobs,
-                    "wall_s": round(wall, 2),
-                    "outputs_equal": check_equal(merged),
-                }
-                if mode == "disagg":
-                    entry["handoffs"] = gb.handoffs
-                    entry["fallbacks"] = gb.fallbacks
-                    entry["handoff_bytes"] = gb.handoff_bytes
-                modes_out[mode] = entry
-
-            # ---- handoff ladder: whole-slab vs chunk-streamed, and
-            # 1- vs 2-peer fan-out, on the prefill-heavy files. The
-            # scheduler wrap-samples the WHOLE store set, so these
-            # jobs submit exactly len(ctx_files) queries after
-            # clearing the prompt_* files from sampling via explicit
-            # n = multiple of the file count — instead we drive the
-            # group backend DIRECTLY with the ctx paths: same engine,
-            # no sampling ambiguity, per-job ttft from the backend.
-            ctx_paths = [os.path.join(tmp, f) for f in ctx_files]
-
-            async def handoff_trial(mode: str) -> Dict[str, Any]:
-                gb = set_mode(mode)
-                pf_counts0 = {
-                    n: pf.slabs_built for n, pf in prefill_bes.items()
-                }
-                results, _, _ = await gb(model, ctx_paths)  # warm
-                assert all(
-                    results[p]["tokens"]
-                    == reference[os.path.basename(p)]
-                    for p in ctx_paths
-                )
-                walls, ttfts = [], []
-                for _ in range(3):
-                    t0 = time.monotonic()
-                    results, _, _ = await gb(model, ctx_paths)
-                    walls.append(time.monotonic() - t0)
-                    if gb.last_ttft_s is not None:
-                        ttfts.append(gb.last_ttft_s)
-                    ok = all(
-                        results[p]["tokens"]
-                        == reference[os.path.basename(p)]
-                        for p in ctx_paths
-                    )
-                    if not ok:
-                        return {"error": "outputs diverged"}
-                med_wall = sorted(walls)[len(walls) // 2]
-                med_ttft = (
-                    sorted(ttfts)[len(ttfts) // 2] if ttfts else None
-                )
-                return {
-                    "wall_s": round(med_wall, 3),
-                    "ttft_ms": (
-                        round(med_ttft * 1000, 1)
-                        if med_ttft is not None else None
-                    ),
-                    "ctx_tok_s": round(ctx_prompt_toks / med_wall, 1),
-                    "handoffs": gb.handoffs,
-                    "fallbacks": gb.fallbacks,
-                    "peer_slabs": {
-                        n: pf.slabs_built - pf_counts0[n]
-                        for n, pf in prefill_bes.items()
-                    },
-                }
-
-            # declared per-request prefill device floor for the
-            # ladder (and the chaos case below): the in-process sim
-            # shares 2 host cores between every "peer", so raw peer
-            # COMPUTE cannot scale with fan-out here no matter what
-            # the orchestration does — the floor (same declared-stub
-            # discipline as the chaos/request stub backends) makes
-            # the ladder measure what the handoff machinery actually
-            # controls: per-request overlap of transfer, adoption,
-            # and peer device time. Token equality still runs the
-            # real engine end-to-end.
-            prefill_floor_s = 0.12
-            for pf in prefill_bes.values():
-                pf.min_prefill_s = prefill_floor_s
-            handoff = {
-                "prompt_tokens_per_job": ctx_prompt_toks,
-                "budget_per_prompt": ctx_budget,
-                "simulated_prefill_floor_s": prefill_floor_s,
-                "slab_f1": await handoff_trial("disagg_slab_f1"),
-                "stream_f1": await handoff_trial("disagg_stream_f1"),
-                "stream_f2": await handoff_trial("disagg_stream_f2"),
-            }
-            s1, s2 = handoff["stream_f1"], handoff["stream_f2"]
-            sl = handoff["slab_f1"]
-            if sl.get("ttft_ms") and s1.get("ttft_ms"):
-                handoff["ttft_stream_ms"] = s1["ttft_ms"]
-                handoff["ttft_slab_ms"] = sl["ttft_ms"]
-                handoff["stream_vs_slab_ttft"] = round(
-                    sl["ttft_ms"] / max(s1["ttft_ms"], 1e-9), 2
-                )
-                handoff["stream_vs_slab_wall"] = round(
-                    sl["wall_s"] / max(s1["wall_s"], 1e-9), 2
-                )
-            if s1.get("ctx_tok_s") and s2.get("ctx_tok_s"):
-                handoff["fanout_ctx_speedup"] = round(
-                    s2["ctx_tok_s"] / max(s1["ctx_tok_s"], 1e-9), 2
-                )
-
-            # single-chip comparison rate on the SAME topology:
-            # grouping disabled, the members serve as individual
-            # chips (context for the mode rates; also re-checks
-            # equality through the ungrouped path)
-            set_mode("resident")
-            for js in services.values():
-                js.groups.enabled = False
-            _, merged = await timed_job()  # warm the ungrouped route
-            all_equal = all_equal and check_equal(merged)
-            t0 = time.monotonic()
-            sc_tokens = sc_jobs = 0
-            while time.monotonic() - t0 < steady_s or sc_jobs < 2:
-                _, merged = await timed_job()
-                all_equal = all_equal and check_equal(merged)
-                sc_tokens += n_prompts * new_tokens
-                sc_jobs += 1
-            tok_s_single = round(sc_tokens / (time.monotonic() - t0), 1)
-            for js in services.values():
-                js.groups.enabled = True
-
-            # ---- member-kill-MID-STREAM chaos: a prefill peer dies
-            # while its streamed share is in flight. The affected
-            # requests demote to typed local-prefill fallbacks
-            # (jobs_kv_handoff_total{result=fallback}), the group
-            # degrades on SWIM detection, the job completes exactly
-            # once with tokens unchanged, and the group re-forms when
-            # the peer returns.
-            gb_chaos = set_mode("disagg_stream_f2")
-            leader_js = services[cluster.leader_uname()]
-            fallbacks_before = gb_chaos.fallbacks
-            bytes_before = gb_chaos.handoff_bytes
-            victim = cluster.resolve_target("H5")
-            chaos_n = 4 * n_prompts
-            job_id = await client.jobs.submit_job(model, chaos_n)
-            # kill while slab bytes are actively flowing (mid-stream,
-            # not between batches)
-            for _ in range(400):
-                if gb_chaos.handoff_bytes > bytes_before:
-                    break
-                await asyncio.sleep(0.02)
-            await cluster.crash_node(victim)
-            # the degradation edge arrives with SWIM detection (~1-2s
-            # at this timing); wait for it so "degrades" is an
-            # observed fact, not a race against a fast job
-            try:
-                await cluster.wait_for(
-                    lambda: leader_js.groups.degradations.get(
-                        group.name, 0) >= 1,
-                    20.0, "group degradation edge",
-                )
-            except AssertionError:  # wait_for timeout
-                pass  # recorded as degraded=False below
-            done = await client.jobs.wait_job(job_id, timeout=600.0)
-            merged = await client.jobs.get_output(
-                job_id, os.path.join(tmp, "chaos_out.json")
-            )
-            chaos_equal = check_equal(merged)
-            gstats = leader_js.group_stats().get(group.name, {})
-            degraded = gstats.get("degradations", 0) >= 1
-            fallback_ticks = gb_chaos.fallbacks - fallbacks_before
-            await cluster.restart_node(victim)
-
-            def reformed() -> bool:
-                st = leader_js.group_stats().get(group.name, {})
-                return bool(st.get("formed"))
-
-            try:
-                await cluster.wait_for(reformed, 30.0, "group reform")
-                did_reform = True
-            except Exception:
-                did_reform = False
-            chaos = {
-                "member_killed": "H5 (prefill role, mid-stream)",
-                "completed": done["total_queries"] == chaos_n,
-                "exactly_once_tokens": chaos_equal,
-                # shipped-draft evidence: the dead peer was a DRAFT
-                # peer too (draft_k > 0), so this kill also covers
-                # draft-proposal loss mid-verify — acceptance may
-                # drop to the local-fallback path, tokens may not
-                "draft_k": spec_k_bench,
-                "drafts_shipped": sum(
-                    pf.drafts_shipped for pf in prefill_bes.values()
-                ),
-                "typed_fallbacks": fallback_ticks,
-                "degraded": degraded,
-                "reformed": did_reform,
-                # green = completed exactly once with unchanged
-                # tokens AND the kill was actually felt (per-request
-                # fallback or a degradation edge — whichever side of
-                # the SWIM race the kill landed on)
-                "verdict_green": bool(
-                    done["total_queries"] == chaos_n and chaos_equal
-                    and (fallback_ticks > 0 or degraded)
-                ),
-            }
-
-            return {
-                "nodes": 5,
-                "prompts_per_job": n_prompts,
-                "new_tokens_per_prompt": new_tokens,
-                "model_cfg": {
-                    k: lm_spec[k]
-                    for k in ("d_model", "n_heads", "n_kv_heads",
-                              "n_layers", "dtype", "max_slots")
-                },
-                "groups": {
-                    group.name: {
-                        "members": list(
-                            cluster.spec.group_members_unique(group.name)
-                        ),
-                        "mesh": {"dp": 1, "tp": 2},
-                        "pp_mesh": {"dp": 1, "tp": 1, "pp": 2},
-                        "lm_models": list(group.lm_models),
-                        "roles": dict(group.roles),
-                    }
-                },
-                "hbm": {
-                    **hbm,
-                    "budget_bytes": hbm_budget,
-                    # the acceptance story: the full tree does NOT
-                    # fit the configured member budget; the pp slice
-                    # does — only the pipelined layout serves
-                    "fits_only_pipelined": bool(
-                        hbm["per_member_bytes"] <= hbm_budget
-                        < hbm["full_bytes"]
-                    ),
-                },
-                "modes": modes_out,
-                "handoff": handoff,
-                "tok_s_param_gather": modes_out["gather"]["tok_s"],
-                "tok_s_resident": modes_out["resident"]["tok_s"],
-                "tok_s_pp": modes_out["pp"]["tok_s"],
-                "tok_s_disagg": modes_out["disagg"]["tok_s"],
-                "tok_s_single_chip": tok_s_single,
-                "resident_vs_gather": round(
-                    modes_out["resident"]["tok_s"]
-                    / max(modes_out["gather"]["tok_s"], 1e-9), 2
-                ),
-                "tokens_equal_single_chip": bool(all_equal and chaos_equal),
-                "kv_handoff_bytes": modes_out["disagg"]["handoff_bytes"],
-                "ttft_stream_ms": handoff.get("ttft_stream_ms"),
-                "stream_vs_slab_ttft": handoff.get("stream_vs_slab_ttft"),
-                "fanout_ctx_speedup": handoff.get("fanout_ctx_speedup"),
-                "chaos": chaos,
-                "note": "virtual CPU mesh: the equality flag (every "
-                        "mode's merged outputs == isolated generate() "
-                        "per prompt, f32 greedy) and the handoff/"
-                        "exactly-once machinery are the product "
-                        "claims; tok/s and overlap ratios on shared-"
-                        "core CPU devices are an honest lower bound "
-                        "on the ICI story",
-            }
-        finally:
-            await cluster.stop()
-            be_single.close()
-
-    result = asyncio.run(run())
-    if result.get("skipped") or result.get("error"):
-        return result
-    # ---- raw-decode arms, AFTER the cluster is down so heartbeat/
-    # gossip threads don't pollute the single-device A/B walls:
-    # speculative decoding (oracle proposer at a declared acceptance
-    # + real-draft auto-disable) and step-granular continuous
-    # batching (overlap-adoption vs batch-drain TTFT under staggered
-    # load). Top-level mirrors feed the bench summary + claim gates.
-    result["specdec"] = bench_specdec_arm(
-        params, cfg, lm_spec, new_tokens=max(new_tokens, 32)
-    )
-    result["cb"] = bench_cb_arm(
-        params, cfg, lm_spec, new_tokens=new_tokens
-    )
-    result["lm_specdec_speedup"] = result["specdec"].get("speedup")
-    result["lm_specdec_accept"] = result["specdec"].get("accept_rate")
-    result["lm_cb_ttft_ms"] = result["cb"].get("ttft_p99_overlap_ms")
-    return result
-
-
-def _pctl(vals: List[float], p: float) -> Optional[float]:
-    """Linear-interpolation percentile (loadgen's definition) over a
-    small sample — the CB arm's TTFT tail with a handful of waves."""
-    vs = sorted(vals)
-    if not vs:
-        return None
-    if len(vs) == 1:
-        return float(vs[0])
-    rank = (p / 100.0) * (len(vs) - 1)
-    lo = int(rank)
-    hi = min(lo + 1, len(vs) - 1)
-    frac = rank - lo
-    return float(vs[lo] * (1.0 - frac) + vs[hi] * frac)
-
-
-def bench_specdec_arm(
-    params,
-    cfg,
-    lm_spec: Dict[str, Any],
-    n_prompts: int = 8,
-    new_tokens: int = 32,
-    k: int = 4,
-    declared_accept: float = 0.8,
-) -> Dict[str, Any]:
-    """Raw-decode A/B on one device: plain chunked scan vs
-    speculative propose+verify over the SAME weights, prompts, and
-    seed (steady tok/s, full batch in flight).
-
-    The spec arm runs an ORACLE proposer pinned near a DECLARED
-    acceptance rate: proposals come from the precomputed
-    isolated-generate continuations with every 8th token position
-    corrupted, so the measured rate sits near `declared_accept`
-    instead of the perfect oracle's ~1.0. Same declared-stub
-    discipline as the handoff ladder's prefill floor: a real draft's
-    acceptance is a model-quality property this synthetic family
-    can't exhibit (any same-family small draft either nails the
-    target's argmax or whiffs completely), so the arm declares the
-    operating point and scores what the serving stack actually owns —
-    the propose/verify/commit machinery at that acceptance. Token
-    equality vs isolated generate() is asserted for BOTH arms
-    (proposal-independence: a corrupted proposal shortens acceptance,
-    never changes output).
-
-    A third run arms a REAL derived draft (config.draft_lm_spec,
-    fresh random weights — acceptance ~0 against this target) with a
-    break-even floor: the server must AUTO-DISABLE speculation
-    (reason="acceptance") and still emit exact tokens."""
-    import jax.numpy as jnp
-
-    from .generate import generate
-    from .lm_server import LMServer
-
-    rng = np.random.RandomState(11)
-    prompts = [
-        np.asarray(
-            rng.randint(0, cfg.vocab_size, int(rng.randint(6, 20))),
-            np.int32,
-        )
-        for _ in range(n_prompts)
-    ]
-    refs = [
-        [int(t) for t in np.asarray(generate(
-            params, cfg, jnp.asarray(p[None]), new_tokens
-        ))[0]]
-        for p in prompts
-    ]
-
-    def make_server() -> "Any":
-        return LMServer(
-            params, cfg,
-            max_slots=int(lm_spec.get("max_slots", 4)),
-            max_len=int(lm_spec["max_len"]),
-            chunk=int(lm_spec["chunk"]),
-        )
-
-    ref_of: Dict[int, List[int]] = {}
-
-    def oracle(reqs, kk: int) -> np.ndarray:
-        rows = np.zeros((len(reqs), kk), np.int32)
-        for i, r in enumerate(reqs):
-            ref = ref_of[r.rid]
-            for j in range(kk):
-                e = r.emitted + j
-                tok = ref[e] if e < len(ref) else 0
-                if e % 8 == 7:
-                    # deliberate miss: pins measured acceptance near
-                    # the declared rate (~0.8 at k=4 / period 8)
-                    tok = (tok + 1) % cfg.vocab_size
-                rows[i, j] = tok
-        return rows
-
-    def drain(srv) -> Tuple[float, List[List[int]]]:
-        t0 = time.monotonic()
-        rids = srv.submit_many(prompts, new_tokens)
-        for rid, ref in zip(rids, refs):
-            ref_of[rid] = ref
-        done = srv.run(rids)
-        wall = time.monotonic() - t0
-        return wall, [[int(t) for t in done[rid]] for rid in rids]
-
-    total = n_prompts * new_tokens
-    srv_a = make_server()
-    drain(srv_a)  # warm: prefill buckets + chunk program
-    wall_plain, outs_plain = drain(srv_a)
-    srv_b = make_server()
-    srv_b.enable_spec_decode(k, proposer=oracle, min_accept=0.0)
-    drain(srv_b)  # warm: prefill buckets + spec_verify program
-    wall_spec, outs_spec = drain(srv_b)
-    stats = srv_b.spec_stats() or {}
-    accept = stats.get("accept_rate")
-
-    # auto-disable: real derived draft, random weights, break-even
-    # floor — speculation must disarm itself, outputs must not move
-    from ..config import draft_lm_spec
-    from .lm_backend import lm_spec_parts
-
-    dparams, dcfg = lm_spec_parts(draft_lm_spec(lm_spec))
-    srv_c = make_server()
-    srv_c.enable_spec_decode(
-        k, draft_params=dparams, draft_cfg=dcfg,
-        min_accept=0.3, min_samples=16,
-    )
-    _, outs_auto = drain(srv_c)
-    st_auto = srv_c.spec_stats() or {}
-    auto_ok = bool(
-        not st_auto.get("enabled", True)
-        and st_auto.get("disabled_reason") == "acceptance"
-        and outs_auto == refs
-    )
-
-    eq = bool(outs_plain == refs and outs_spec == refs)
-    tok_s_plain = total / max(wall_plain, 1e-9)
-    tok_s_spec = total / max(wall_spec, 1e-9)
-    speedup = round(tok_s_spec / max(tok_s_plain, 1e-9), 2)
-    return {
-        "k": k,
-        "prompts": n_prompts,
-        "new_tokens_per_prompt": new_tokens,
-        "declared_accept": declared_accept,
-        "accept_rate": accept,
-        "spec_rounds": stats.get("rounds"),
-        "tok_s_plain": round(tok_s_plain, 1),
-        "tok_s_spec": round(tok_s_spec, 1),
-        "speedup": speedup,
-        "outputs_equal": eq,
-        "auto_disable": {
-            "draft_layers": int(dcfg.n_layers),
-            "disabled": not st_auto.get("enabled", True),
-            "reason": st_auto.get("disabled_reason"),
-            "accept_rate": st_auto.get("accept_rate"),
-            "outputs_equal": bool(outs_auto == refs),
-        },
-        "verdict_green": bool(speedup > 1.0 and eq and auto_ok),
-    }
-
-
-def bench_cb_arm(
-    params,
-    cfg,
-    lm_spec: Dict[str, Any],
-    n_waves: int = 6,
-    wave_size: int = 2,
-    new_tokens: int = 16,
-    stagger_s: float = 0.05,
-) -> Dict[str, Any]:
-    """Step-granular continuous batching TTFT A/B under sustained
-    staggered load, same seed both arms: `n_waves` request waves land
-    `stagger_s` apart while earlier waves are still decoding.
-
-    - OVERLAP arm: every wave enters ONE LMDriver — a late wave's
-      prompts adopt free/retired slots at the next step boundary
-      mid-flight, so its first token never waits for the running
-      batch to drain.
-    - DRAIN arm: the pre-driver serial discipline (one lock around
-      submit+run), i.e. wave N+1's prefill cannot start until wave N
-      fully drains — the batch-drain latency continuous batching
-      removes.
-
-    p99 TTFT (client-observed first token per wave) must be strictly
-    lower on the overlap arm; outputs must equal isolated generate()
-    on both (the LMServer batching-exactness contract, no matter how
-    tickets interleave)."""
-    import threading
-
-    import jax.numpy as jnp
-
-    from .generate import generate
-    from .lm_server import LMDriver, LMServer
-
-    rng = np.random.RandomState(13)
-    waves = [
-        [
-            np.asarray(
-                rng.randint(0, cfg.vocab_size, int(rng.randint(6, 16))),
-                np.int32,
-            )
-            for _ in range(wave_size)
-        ]
-        for _ in range(n_waves)
-    ]
-    refs = [
-        [
-            [int(t) for t in np.asarray(generate(
-                params, cfg, jnp.asarray(p[None]), new_tokens
-            ))[0]]
-            for p in w
-        ]
-        for w in waves
-    ]
-
-    def make_server():
-        return LMServer(
-            params, cfg,
-            max_slots=int(lm_spec.get("max_slots", 4)),
-            max_len=int(lm_spec["max_len"]),
-            chunk=int(lm_spec["chunk"]),
-        )
-
-    def run_arm(overlap: bool) -> Tuple[List[float], List[Any], Any]:
-        srv = make_server()
-        driver = LMDriver(srv) if overlap else None
-        lock = threading.Lock()
-        # warm every compile (prefill buckets + chunk) outside the
-        # timed window so neither arm pays XLA wall in its TTFT
-        if overlap:
-            driver.serve(waves[0], new_tokens)
-        else:
-            rids = srv.submit_many(waves[0], new_tokens)
-            srv.run(rids)
-        ttfts: List[Optional[float]] = [None] * n_waves
-        outs: List[Any] = [None] * n_waves
-        t0 = time.monotonic()
-
-        def one_wave(i: int) -> None:
-            t_due = t0 + i * stagger_s
-            delay = t_due - time.monotonic()
-            if delay > 0:
-                time.sleep(delay)
-            t_sub = time.monotonic()
-            first = [False]
-
-            def stamp(_tok: int) -> None:
-                if not first[0]:
-                    first[0] = True
-                    ttfts[i] = time.monotonic() - t_sub
-            cbs = [stamp] + [None] * (wave_size - 1)
-            if overlap:
-                toks = driver.serve(waves[i], new_tokens, on_token=cbs)
-                outs[i] = [[int(t) for t in seq] for seq in toks]
-            else:
-                with lock:
-                    rids = srv.submit_many(
-                        waves[i], new_tokens, on_token=cbs
-                    )
-                    done = srv.run(rids)
-                outs[i] = [
-                    [int(t) for t in done[rid]] for rid in rids
-                ]
-
-        threads = [
-            threading.Thread(target=one_wave, args=(i,), daemon=True)
-            for i in range(n_waves)
-        ]
-        for th in threads:
-            th.start()
-        for th in threads:
-            th.join(timeout=300.0)
-        if driver is not None:
-            driver.stop()
-        return [t for t in ttfts if t is not None], outs, srv
-
-    ttft_ov, outs_ov, _ = run_arm(overlap=True)
-    ttft_dr, outs_dr, _ = run_arm(overlap=False)
-    eq = bool(outs_ov == refs and outs_dr == refs)
-    p99_ov = _pctl(ttft_ov, 99)
-    p99_dr = _pctl(ttft_dr, 99)
-    return {
-        "waves": n_waves,
-        "wave_size": wave_size,
-        "stagger_ms": round(stagger_s * 1e3, 1),
-        "new_tokens_per_prompt": new_tokens,
-        "ttft_p50_overlap_ms": round(_pctl(ttft_ov, 50) * 1e3, 1),
-        "ttft_p99_overlap_ms": round(p99_ov * 1e3, 1),
-        "ttft_p50_drain_ms": round(_pctl(ttft_dr, 50) * 1e3, 1),
-        "ttft_p99_drain_ms": round(p99_dr * 1e3, 1),
-        "drain_vs_overlap_p99": round(p99_dr / max(p99_ov, 1e-9), 2),
-        "outputs_equal": eq,
-        "verdict_green": bool(eq and p99_ov < p99_dr),
-    }
-
-
-def _value_of(counter_name: str) -> float:
-    """Sum of one counter across all label children (bench helper)."""
-    try:
-        snap = METRICS.snapshot()
-        return sum(
-            float(v) for k, v in snap.get("counters", {}).items()
-            if k == counter_name or k.startswith(counter_name + "{")
-        )
-    except Exception:
-        return 0.0
-
-
-def _main() -> None:  # pragma: no cover - bench subprocess entry
-    print(json.dumps(bench_lm_sharded_serving(), default=str))
-
-
-if __name__ == "__main__":  # pragma: no cover
-    _main()

@@ -7,27 +7,14 @@ int8 with a per-output-channel float scale cuts the weight bytes
 1.57x vs bf16 (2.9x vs f32) with no activation-calibration step;
 accuracy loss is bounded by per-channel rounding (~0.4%).
 
-What this buys, measured on v5e (198M-param GQA-4 LM, B=1, 512-token
-cache; re-captured every bench run — `lm.decode_weight_forms_b1` in
-the latest BENCH_r* artifact):
-
-- f32-resident weights:  ~1.1-1.5k tok/s
-- bf16-resident weights: ~1.8-2.2k tok/s (stable across captures)
-- int8 + dequant-at-use: ~2.3-4.5k tok/s (BIMODAL across captures)
-
-i.e. on a clean chip int8 has not lost to bf16 on the current
-toolchain and often wins ~2x (when XLA fuses the int8 read + dequant
-into the matvec the per-token HBM bill drops with the weight bytes) —
-but the fusion is memory-state sensitive and the claim does NOT hold
-unconditionally: with ~1 GB of CNN weights co-resident the same
-program measured ~1056 tok/s, below the bf16 range (the bench frees
-the chip first), and even clean-chip captures split between ~2.3k
-and ~4.5k. On an
-earlier toolchain the dequant materialized per scan step and int8
-LOST outright. The capacity side is deterministic: 1.33x less HBM
-than the bf16 tree end-to-end (the f32 embed dominates the
-remainder). `LongContextLM.generate` serves bf16-cast weights by
-default and offers `quantize_weights=True`.
+What this buys: fewer weight bytes per decode step, which turns into
+tokens a second only where XLA fuses the int8 read and the dequant
+into the matvec (it has flipped across toolchains, and no cell of the
+benchmark measures it: the benchmark uses this path as the control of
+its `correct` check, not for speed). The capacity side is
+deterministic: 1.33x less HBM than the bf16 tree end-to-end (the f32
+embed dominates the remainder). `LongContextLM.generate` serves
+bf16-cast weights by default and offers `quantize_weights=True`.
 
 Scope: the 2-D matmul kernels of TransformerLM blocks (qkv, proj,
 up, down, lm_head) and the stacked MoE expert tensors (w_up, w_down,

@@ -33,11 +33,8 @@ cluster scheduler that shape:
   the scheduler keeps every acked batch counted exactly once no
   matter how the group reshuffles mid-job.
 - **Observability**: ``jobs_group_*`` metrics (formed gauge, member
-  liveness, degradation/reform counters, group-served batch counter),
-  `JobService.group_stats()` in the CLI ``breakdown`` verb, and the
-  ``cluster_sharded_serving`` bench section (``python -m
-  dml_tpu.jobs.groups`` on a virtual CPU mesh) whose output-equality
-  flag tools/claim_check.py validates.
+  liveness, degradation/reform counters, group-served batch counter)
+  and `JobService.group_stats()` in the CLI ``breakdown`` verb.
 
 Module stays jax-free at import time (the chaos/CLI stub paths build
 directories and stub group backends without touching a device); the
@@ -837,159 +834,11 @@ def wire_group_backend(node) -> Optional[Any]:
     )
 
 
-# ----------------------------------------------------------------------
-# bench: sharded cluster serving on a virtual CPU mesh
-# (`python -m dml_tpu.jobs.groups` — bench.py runs it as a subprocess
-# with JAX_PLATFORMS=cpu and 8 virtual devices, same pattern as
-# tools/ring_vs_ulysses)
-# ----------------------------------------------------------------------
-
-
-def bench_sharded_serving(
-    n_queries: int = 64,
-    n_files: int = 16,
-    base_port: int = 28941,
-    image_size: Tuple[int, int] = (64, 64),
-    batch: int = 8,
-    model: str = "ResNet50",
-    tmp: str = "/tmp/dml_tpu_bench_sharded",
-) -> Dict[str, Any]:
-    """End-to-end sharded cluster serving vs the single-chip pipeline.
-
-    Stands up the SAME `chaos.LocalCluster` chassis the soaks
-    validate — 5 nodes, H4+H5 pooled into one dp=1×tp=2 group whose
-    primary serves on a ``param_gather`` ShardedInference — serves an
-    image job through the full store/scheduler/ACK pipeline, then
-    disables grouping and serves the identical job on single chips.
-    Records q/s both ways, the group topology in force, and the
-    output-equality flag (merged job outputs must match KEY FOR KEY,
-    BIT FOR BIT — the param_gather contract) that
-    tools/claim_check.py holds the artifact to. float32 so the
-    equality claim is about reduction order, not dtype noise."""
-    import os
-    import shutil
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    devices = jax.devices()
-    if len(devices) < 2:
-        return {
-            "skipped": True,
-            "reason": f"needs >= 2 devices for tp=2, have {len(devices)}",
-        }
-
-    from ..cluster.chaos import LocalCluster
-    from ..config import MeshSpec, Timing, WorkerGroupSpec
-    from ..parallel.inference import ShardedInference
-    from ..parallel.mesh import make_mesh
-    from .service import JobService
-
-    from ..models.params_io import init_variables
-    from ..models.registry import get_model
-
-    spec = get_model(model)
-    variables = init_variables(
-        spec, seed=0, dtype=jnp.float32, image_size=image_size
-    )
-    mesh_group = make_mesh(MeshSpec(dp=1, tp=2), devices=devices[:2])
-    mesh_one = make_mesh(MeshSpec(), devices=devices[:1])
-    si_group = ShardedInference(
-        model, mesh_group, batch_size=batch, variables=variables,
-        dtype=jnp.float32, param_gather=True,
-    )
-    si_one = ShardedInference(
-        model, mesh_one, batch_size=batch, variables=variables,
-        dtype=jnp.float32,
-    )
-    # pay both compiles BEFORE the timed serves: the q/s ratio must
-    # compare serving, not who ate the XLA warmup
-    warm = np.zeros((1, *image_size, 3), np.uint8)
-    si_group(warm)
-    si_one(warm)
-    group = WorkerGroupSpec("tp0", ("H4", "H5"), MeshSpec(dp=1, tp=2))
-
-    async def run() -> Dict[str, Any]:
-        shutil.rmtree(tmp, ignore_errors=True)
-        os.makedirs(tmp, exist_ok=True)
-        cluster = LocalCluster(
-            5, tmp, base_port,
-            timing=Timing(ping_interval=0.2, ack_timeout=0.3,
-                          cleanup_time=1.0, leader_rpc_timeout=10.0),
-            worker_groups=[group],
-            make_jobs=lambda node, store: _make_sharded_jobs(
-                node, store, JobService, si_group, si_one, group,
-                image_size, model, batch,
-            ),
-        )
-        try:
-            await cluster.start()
-            await cluster.wait_for(
-                cluster.converged, 20.0, "sharded bench convergence"
-            )
-            stack = [sn for _, sn in sorted(cluster.nodes.items())]
-            client = stack[-1]
-            from PIL import Image
-
-            rng = np.random.RandomState(0)
-            for i in range(n_files):
-                p = os.path.join(tmp, f"img_{i}.jpeg")
-                Image.fromarray(
-                    rng.randint(0, 255, (96, 96, 3), np.uint8)
-                ).save(p)
-                await client.store.put(p, f"img_{i}.jpeg")
-
-            async def timed_job() -> Tuple[float, Dict[str, Any]]:
-                t0 = time.monotonic()
-                job_id = await client.jobs.submit_job(model, n_queries)
-                done = await client.jobs.wait_job(job_id, timeout=600.0)
-                wall = time.monotonic() - t0
-                assert done["total_queries"] == n_queries
-                merged = await client.jobs.get_output(
-                    job_id, os.path.join(tmp, f"out_{job_id}.json")
-                )
-                return wall, merged
-
-            wall_g, merged_g = await timed_job()
-            leader = next(sn for sn in stack if sn.node.is_leader)
-            group_stats = leader.jobs.group_stats()
-            for sn in stack:
-                sn.jobs.groups.enabled = False
-            wall_s, merged_s = await timed_job()
-            equal = merged_g == merged_s and bool(merged_g)
-            return {
-                "nodes": 5,
-                "queries": n_queries,
-                "model": model,
-                "image_size": list(image_size),
-                "groups": {
-                    name: g for name, g in group_stats.items()
-                    if isinstance(g, dict)
-                },
-                "qps_sharded": round(n_queries / wall_g, 1),
-                "qps_single_chip": round(n_queries / wall_s, 1),
-                "sharded_vs_single": round(wall_s / wall_g, 2),
-                "equal_outputs": equal,
-                "outputs_compared": len(merged_g),
-                "note": "virtual CPU mesh (the bench chip is one "
-                        "device); the equality flag is the product "
-                        "claim — param_gather tp keeps group outputs "
-                        "bit-identical to single-chip — while the q/s "
-                        "ratio on shared-core CPU devices is an "
-                        "honest lower bound, not the ICI story",
-            }
-        finally:
-            await cluster.stop()
-
-    return asyncio.run(run())
-
-
 def _make_sharded_jobs(
     node, store, JobService, si_group, si_one, group: WorkerGroupSpec,
     image_size, model: str, batch: int,
 ):
-    """Per-node JobService for the sharded bench/dryrun cluster: every
+    """Per-node JobService for the sharded dryrun/test cluster: every
     node can serve single-chip batches on the 1-device engine; the
     group primary additionally carries the group's sharded engine."""
     uname = node.me.unique_name
@@ -1007,13 +856,3 @@ def _make_sharded_jobs(
     js = JobService(node, store, infer_backend=single, group_backend=gb)
     js.scheduler.set_batch_size(model, batch)
     return js
-
-
-def _main() -> None:  # pragma: no cover - bench subprocess entry
-    import json
-
-    print(json.dumps(bench_sharded_serving(), default=str))
-
-
-if __name__ == "__main__":  # pragma: no cover
-    _main()

@@ -318,7 +318,7 @@ def save_npz_fixture(
     variables tree (flat 'a/b/c' keys) plus, optionally, the imagenet
     class-index JSON embedded as a string — so any environment that
     can place ONE file runs the full label-parity report
-    (tools/imagenet_parity.py; VERDICT r3 item 9). Produce it where
+    (tools/imagenet_parity.py). Produce it where
     weights ARE obtainable with:
 
         variables = init_variables(get_model("ResNet50"))

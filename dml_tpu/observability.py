@@ -7,8 +7,8 @@ confirms batch-size changes, C5 the current worker->batch assignments
 (reference worker.py:1394-1428, 1744-1808). This module is the
 TPU-native generalization of that console: a typed, process-wide
 metrics registry every subsystem writes into, plus the exposition
-surfaces (Prometheus text, JSON dumps, leader-aggregated METRICS_PULL,
-bench-artifact blocks) that make the numbers reachable.
+surfaces (Prometheus text, JSON dumps, leader-aggregated METRICS_PULL)
+that make the numbers reachable.
 
 Metric model
 ------------
@@ -84,8 +84,7 @@ attribution (formation | dispatch | fetch | infer | put |
 unattributed), so the counter alone says WHERE the tail is lost,
 ``request_queue_wait_seconds`` admission->dispatch wait and
 ``request_e2e_latency_seconds`` admission->completion latency
-histograms per class — the p50/p95/p99 source of the
-``request_serving`` bench section — ``request_in_flight`` gauge,
+histograms per class, ``request_in_flight`` gauge,
 ``request_batch_fill_fraction`` / ``request_batch_formation_seconds``
 continuous-batch-formation quality, and
 ``request_stream_tokens_total`` LM tokens pushed into per-request
@@ -93,8 +92,8 @@ data-plane token streams on workers),
 ``cluster_*`` (SWIM suspicion/failure/false-positive events,
 alive-node gauge), ``membership_gossip_*`` (the bounded delta-gossip
 piggyback: payloads built and member entries carried, labeled
-``mode=`` delta|full — the O(K)-vs-O(N) per-datagram story the
-``control_plane_scale`` bench scores), ``metrics_relay_*`` (two-level
+``mode=`` delta|full — the O(K)-vs-O(N) per-datagram story),
+``metrics_relay_*`` (two-level
 METRICS_PULL aggregation: relay-shard pulls by ``role=`` leader|relay,
 per-shard wall, and shards that fell back to direct pulls),
 ``store_report_delta_*`` (the replica inventory re-report fan-in:
@@ -113,9 +112,6 @@ Exposition
   reference coordinator's console.
 - ``to_prometheus_text()`` — Prometheus exposition format (CLI
   ``profile metrics prom``), scrape-ready.
-- ``bench_metrics_block()`` — the ``metrics`` block embedded in bench
-  artifacts so BENCH_r*.json carries per-stage breakdowns
-  (tools/claim_check.py validates its presence from round 6 on).
 
 In-process simulations (tests) run many nodes in ONE process sharing
 this module-global registry; snapshots carry the pid and
@@ -859,17 +855,6 @@ def strip_buckets(snap: Dict[str, Any]) -> Dict[str, Any]:
     }
     out["stripped"] = True
     return out
-
-
-def bench_metrics_block() -> Dict[str, Any]:
-    """The ``metrics`` block bench.py embeds in every artifact:
-    summarized registry contents, so BENCH_r*.json carries per-stage
-    breakdowns (lm_server decode counters, worker stage timings,
-    transport totals) alongside the headline numbers.
-    tools/claim_check.py validates this block's presence and shape."""
-    block = summarize_snapshot(METRICS.snapshot())
-    block["schema"] = 1
-    return block
 
 
 # ----------------------------------------------------------------------

@@ -44,18 +44,13 @@ class ShardedInference:
       part 5) so a degradation/reformation mid-job can never change
       what a query returns.
 
-    The LM serving stack carries BOTH forms too
-    (inference/lm_sharded.py): its production group engine keeps
-    weights resident tp-sharded with NO per-forward gather — the
-    Megatron form, token-exact for greedy decode per
-    ``dryrun_multichip`` part 4 — while
-    ``LMServer(gather_shardings=...)`` reproduces this class's
-    param_gather form as the measured per-dispatch all-gather tax
-    (`cluster_lm_sharded` bench). The CNN path here keeps
-    param_gather as its default serving form because image batches
-    are one forward per batch (one gather), whereas LM decode pays
-    the gather EVERY chunk dispatch — which is exactly why the LM
-    path must not use it.
+    The LM serving stack (inference/lm_sharded.py) keeps weights
+    resident tp-sharded with NO per-forward gather — the Megatron
+    form, token-exact for greedy decode per ``dryrun_multichip``
+    part 4. The CNN path here keeps param_gather as its default
+    serving form because image batches are one forward per batch
+    (one gather), whereas LM decode would pay the gather EVERY chunk
+    dispatch — which is exactly why the LM path does not use it.
     """
 
     def __init__(

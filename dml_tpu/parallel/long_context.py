@@ -210,18 +210,14 @@ class LongContextLM:
         per-token top-2 routing.
 
         Decode is HBM-bound, so by default the f32 master weights are
-        cast once to the model dtype for serving (1.4-1.9x tok/s
-        across v5e captures, re-measured per round: bench
-        `lm.decode_weight_forms_b1`) — that keeps a second parameter
-        copy resident;
+        cast once to the model dtype for serving — that keeps a
+        second parameter copy resident;
         pass `serve_dtype_cast=False` to stream the training tree
         directly when HBM is too tight for the copy.
         `quantize_weights=True` serves weight-only int8 instead
-        (inference/quantize.py; capacity AND ~2x decode on the
-        current toolchain — bench `lm.decode_weight_forms_b1`);
+        (inference/quantize.py: less HBM, see its docstring);
         `kv_quant=True` stores the KV cache as int8 + per-position
-        scales (~1.9x less cache HBM — bench
-        `lm.kv_cache_int8_4k_ctx_b8`). Serving forms are cached per
+        scales (~1.9x less cache HBM). Serving forms are cached per
         training step."""
         from ..inference.generate import LMConfig, generate as _generate
 
@@ -246,9 +242,8 @@ class LongContextLM:
             self._gen_cache[key] = fn
         # serving weights: decode is HBM-bound, so streaming f32 master
         # weights wastes half the bandwidth — serve a model-dtype
-        # (bf16) cast by default (1.4-1.9x tok/s vs f32 across v5e
-        # captures, bench `lm.decode_weight_forms_b1`), or the int8
-        # tree (capacity always; throughput when the read fuses).
+        # (bf16) cast by default, or the int8 tree (capacity always;
+        # throughput when the read fuses).
         # All forms carry the training shardings through (XLA gathers
         # what each op needs; force-replicating would defeat tp
         # sharding for models that only fit partitioned).

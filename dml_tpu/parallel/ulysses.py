@@ -26,8 +26,7 @@ fits per-device at H/sp heads (fewer, bigger collectives; one kernel
 launch); prefer ring when sp exceeds the head count (MQA/GQA-heavy
 models) or T must scale past single-device memory even per head
 group. Measured backing (tools/ring_vs_ulysses.py, HLO collective
-footprint; `ring_vs_ulysses` in the latest BENCH_r* artifact): at
-T=4096 H=8 sp=8 ring moves 28 MB/device over 7 serialized
+footprint): at T=4096 H=8 sp=8 ring moves 28 MB/device over 7 serialized
 ppermute rounds vs ulysses' 8 MB in 4 one-shot all_to_alls; at
 T=8192 H=16 sp=4, 96 MB vs 64 MB; at H=4 sp=8 ulysses cannot run
 (heads % sp != 0) and ring is the only strategy. Both are

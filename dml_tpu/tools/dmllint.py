@@ -5,7 +5,7 @@ recurring hazard classes in the asyncio control plane: fire-and-forget
 tasks that wedge teardown (the PR-3 ``wait_for`` wedge), blanket
 ``except Exception: pass`` that eats real bugs, wire-message handlers
 drifting from the ``MsgType`` enum, and hand-mirrored lists (pytest
-markers, claim_check summary keys, the observability docstring map)
+markers, the observability docstring map)
 silently desynchronizing. This module catches those classes
 mechanically at test time — ``tests/test_dmllint.py`` enforces ZERO
 un-baselined findings in tier-1 — instead of re-discovering them one
@@ -28,8 +28,8 @@ Exit codes (CI contract): 0 = clean, 1 = un-baselined findings,
 Rule catalog
 ------------
 
-Async-hazard rules (pure AST, per file, over ``dml_tpu/`` + ``tests/``
-+ ``bench.py``):
+Async-hazard rules (pure AST, per file, over ``dml_tpu/`` +
+``tests/``):
 
 - ``naked-task`` — ``asyncio.create_task(...)`` / ``ensure_future``
   as a bare expression statement: the handle is neither stored, reaped
@@ -72,12 +72,6 @@ so fixture trees exercise them selectively):
   of ``observability.py``'s module docstring vs every
   ``*.counter/gauge/histogram("name", ...)`` registration in
   ``dml_tpu/``: both directions must match exactly.
-- ``drift-summary-keys`` — ``tools/claim_check.py``'s summary-only
-  gates read keys off the bench compact line; every key a gate reads
-  must exist in ``bench.py``'s summary dict AND survive the
-  last-resort compact-line trim (``_COMPACT_KEEP_KEYS``), and every
-  ``_COMPACT_DROP_ORDER`` / keep entry must be a real summary key —
-  a typo'd key silently never gates / never trims.
 - ``drift-pytest-markers`` — markers used in ``tests/`` must be
   registered in ``pytest.ini``; the ``pytest.ini`` registry and the
   ``tests/conftest.py`` mirror must be identical sets; a registered
@@ -154,7 +148,6 @@ R_BLOCKING = "blocking-async"
 R_UNSEEDED = "unseeded-seam"
 R_WIRE = "drift-wire-handlers"
 R_METRICS = "drift-metrics-map"
-R_SUMMARY = "drift-summary-keys"
 R_MARKERS = "drift-pytest-markers"
 R_SPANS = "drift-span-names"
 R_ALERTS = "drift-alert-names"
@@ -165,7 +158,7 @@ R_STALE = "baseline-stale"
 
 ALL_RULES = (
     R_NAKED, R_SILENT, R_BLOCKING, R_UNSEEDED,
-    R_WIRE, R_METRICS, R_SUMMARY, R_MARKERS, R_SPANS, R_ALERTS,
+    R_WIRE, R_METRICS, R_MARKERS, R_SPANS, R_ALERTS,
     R_RACE, R_PAYLOAD, R_STALE,
 )
 
@@ -229,8 +222,8 @@ def _rel(root: str, path: str) -> str:
 
 
 def scan_paths(root: str) -> List[str]:
-    """The lint surface: dml_tpu/ + tests/ + bench.py (deterministic
-    order; __pycache__ excluded)."""
+    """The lint surface: dml_tpu/ + tests/ (deterministic order;
+    __pycache__ excluded)."""
     out: List[str] = []
     for sub in ("dml_tpu", "tests"):
         base = os.path.join(root, sub)
@@ -239,9 +232,6 @@ def scan_paths(root: str) -> List[str]:
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     out.append(os.path.join(dirpath, fn))
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        out.append(bench)
     return out
 
 
@@ -700,29 +690,15 @@ def rule_metrics(root: str, trees: Dict[str, ast.Module]) -> List[Finding]:
 
 
 # ----------------------------------------------------------------------
-# drift-summary-keys
+# drift-span-names
 # ----------------------------------------------------------------------
 
+TRACING_REL = "dml_tpu/tracing.py"
 
-def extract_bench_summary_keys(tree: ast.Module) -> Dict[str, int]:
-    """Keys bench.py can emit in its summary: every dict literal
-    assigned to a name ``summary`` plus ``summary[<const>] = ...``."""
-    keys: Dict[str, int] = {}
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Assign):
-            t0 = node.targets[0]
-            if (isinstance(t0, ast.Name) and t0.id == "summary"
-                    and isinstance(node.value, ast.Dict)):
-                for k in node.value.keys:
-                    if isinstance(k, ast.Constant) and isinstance(k.value, str):
-                        keys.setdefault(k.value, k.lineno)
-            if (isinstance(t0, ast.Subscript)
-                    and isinstance(t0.value, ast.Name)
-                    and t0.value.id == "summary"
-                    and isinstance(t0.slice, ast.Constant)
-                    and isinstance(t0.slice.value, str)):
-                keys.setdefault(t0.slice.value, node.lineno)
-    return keys
+#: the recorder's span-opening calls, each taking the span's name
+#: first: a request span, a serve-loop span, a loop span recorded
+#: after the fact
+_SPAN_CALLS = ("start_span", "loop_span", "loop_record")
 
 
 def _module_const_strs(tree: ast.Module, name: str) -> Optional[Dict[str, int]]:
@@ -737,116 +713,6 @@ def _module_const_strs(tree: ast.Module, name: str) -> Optional[Dict[str, int]]:
                     if isinstance(e, ast.Constant) and isinstance(e.value, str)
                 }
     return None
-
-
-def extract_claim_gate_keys(tree: ast.Module) -> Dict[str, int]:
-    """Summary keys claim_check's summary-only gates read: inside any
-    function that binds ``X = <...>.get("summary") ...``, every
-    ``X.get("k")`` / ``X["k"]`` constant key."""
-    keys: Dict[str, int] = {}
-    for fn in ast.walk(tree):
-        if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            continue
-        bound: Set[str] = set()
-        for node in ast.walk(fn):
-            if isinstance(node, ast.Assign) and isinstance(
-                node.targets[0], ast.Name
-            ):
-                for sub in ast.walk(node.value):
-                    if (isinstance(sub, ast.Call)
-                            and isinstance(sub.func, ast.Attribute)
-                            and sub.func.attr == "get" and sub.args
-                            and isinstance(sub.args[0], ast.Constant)
-                            and sub.args[0].value == "summary"):
-                        bound.add(node.targets[0].id)
-        if not bound:
-            continue
-        for node in ast.walk(fn):
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr == "get"
-                    and isinstance(node.func.value, ast.Name)
-                    and node.func.value.id in bound and node.args
-                    and isinstance(node.args[0], ast.Constant)
-                    and isinstance(node.args[0].value, str)):
-                keys.setdefault(node.args[0].value, node.lineno)
-            if (isinstance(node, ast.Subscript)
-                    and isinstance(node.value, ast.Name)
-                    and node.value.id in bound
-                    and isinstance(node.slice, ast.Constant)
-                    and isinstance(node.slice.value, str)):
-                keys.setdefault(node.slice.value, node.lineno)
-    return keys
-
-
-def check_summary(
-    summary_keys: Dict[str, int],
-    keep_keys: Optional[Dict[str, int]],
-    drop_keys: Optional[Dict[str, int]],
-    gate_keys: Dict[str, int],
-    bench_rel: str,
-    claim_rel: str,
-) -> List[Finding]:
-    fs: List[Finding] = []
-
-    def f(path: str, line: int, subject: str, msg: str) -> None:
-        fs.append(Finding(path=path, line=line, rule=R_SUMMARY, msg=msg,
-                          key=f"{R_SUMMARY}:{subject}"))
-
-    if keep_keys is None:
-        f(bench_rel, 1, "no-keep-list",
-          "bench.py has no module-level _COMPACT_KEEP_KEYS tuple — the "
-          "last-resort compact-line survivors must be declared where "
-          "the linter (and claim_check) can see them")
-        keep_keys = {}
-    for k, line in sorted(gate_keys.items()):
-        if k not in summary_keys:
-            f(claim_rel, line, f"gate-not-emitted:{k}",
-              f"claim_check summary gate reads {k!r} but bench.py "
-              "never emits that summary key — the gate can never fire")
-        elif keep_keys and k not in keep_keys:
-            f(claim_rel, line, f"gate-trimmed:{k}",
-              f"claim_check summary gate reads {k!r} but the key does "
-              "not survive bench.py's last-resort compact-line trim "
-              "(_COMPACT_KEEP_KEYS) — a trimmed driver tail would "
-              "silently skip the gate")
-    for k, line in sorted((drop_keys or {}).items()):
-        if k not in summary_keys:
-            f(bench_rel, line, f"drop-unknown:{k}",
-              f"_COMPACT_DROP_ORDER entry {k!r} is not a summary key — "
-              "a typo here means some other key never gets trimmed")
-    for k, line in sorted(keep_keys.items()):
-        if k not in summary_keys:
-            f(bench_rel, line, f"keep-unknown:{k}",
-              f"_COMPACT_KEEP_KEYS entry {k!r} is not a summary key — "
-              "the last-resort line would carry a null nobody emits")
-    return fs
-
-
-def rule_summary(root: str, trees: Dict[str, ast.Module]) -> List[Finding]:
-    bench_rel, claim_rel = "bench.py", "dml_tpu/tools/claim_check.py"
-    if bench_rel not in trees or claim_rel not in trees:
-        return []
-    bench_tree = trees[bench_rel]
-    return check_summary(
-        extract_bench_summary_keys(bench_tree),
-        _module_const_strs(bench_tree, "_COMPACT_KEEP_KEYS"),
-        _module_const_strs(bench_tree, "_COMPACT_DROP_ORDER"),
-        extract_claim_gate_keys(trees[claim_rel]),
-        bench_rel, claim_rel,
-    )
-
-
-# ----------------------------------------------------------------------
-# drift-span-names
-# ----------------------------------------------------------------------
-
-TRACING_REL = "dml_tpu/tracing.py"
-
-#: the recorder's span-opening calls, each taking the span's name
-#: first: a request span, a serve-loop span, a loop span recorded
-#: after the fact
-_SPAN_CALLS = ("start_span", "loop_span", "loop_record")
 
 
 def collect_span_call_sites(
@@ -1300,7 +1166,7 @@ def run_lint(
         rel = _rel(root, path)
         trees[rel] = _parse(path, rel)  # raises LintInternalError
         findings.extend(analyze_tree(trees[rel], rel))
-    for rule_fn in (rule_wire, rule_metrics, rule_summary, rule_markers,
+    for rule_fn in (rule_wire, rule_metrics, rule_markers,
                     rule_spans, rule_alerts,
                     dmlflow.rule_race, dmlflow.rule_payloads):
         findings.extend(rule_fn(root, trees))
@@ -1330,34 +1196,6 @@ def run_lint(
     return LintResult(
         findings=new, suppressed=suppressed, baseline_size=len(baseline)
     )
-
-
-def bench_block(root: Optional[str] = None) -> Dict[str, Any]:
-    """The ``lint`` block bench.py embeds in artifacts (claim_check
-    validates it from round 11): the verdict, the un-baselined finding
-    count, and the baseline size. Never raises — a broken linter must
-    not kill a bench run (the error lands in the block instead)."""
-    try:
-        res = run_lint(root)
-
-        def n(rule: str) -> int:
-            return sum(
-                1 for f in res.findings + res.suppressed if f.rule == rule
-            )
-
-        return {
-            "lint_clean": res.clean,
-            "findings": len(res.findings),
-            "baseline_size": res.baseline_size,
-            # flow-aware pass counts (round-16 gate): findings INCLUDING
-            # baselined ones, so the artifact records how many flagged
-            # sites exist even on a clean tree
-            "race_findings": n(R_RACE),
-            "payload_findings": n(R_PAYLOAD),
-            "rules": list(ALL_RULES),
-        }
-    except Exception as e:  # defensive: bench preamble must survive
-        return {"lint_clean": False, "error": repr(e)}
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:

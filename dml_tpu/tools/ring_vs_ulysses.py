@@ -23,8 +23,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count=8`); the collective
 STRUCTURE in the lowered program is what transfers to the pod — byte
 counts are exact, wall-times on a host mesh are not (ICI overlap is
 modeled by the compiler, not the host). `python -m
-dml_tpu.tools.ring_vs_ulysses` prints the JSON table; bench.py embeds
-it in the artifact as `ring_vs_ulysses`.
+dml_tpu.tools.ring_vs_ulysses` prints the JSON table.
 
 Net-new vs the reference (no sequence models, SURVEY §0).
 """

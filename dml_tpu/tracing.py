@@ -48,15 +48,13 @@ This module is the per-request causality layer:
   per-stage seconds; ``ingress/loadgen.summarize`` joins completions
   against these to report where the p99 cohort's time went
   (queue-wait vs formation vs dispatch vs prefill vs handoff vs
-  decode vs result-return), and the ``request_serving`` bench section
-  embeds the result as its ``tracing`` block (claim_check-gated).
+  decode vs result-return).
 
 Overhead discipline: every recorder update is a host-side O(1) dict /
 deque operation outside any jitted device step (same contract as the
 metrics registry), sampling is decided ONCE at admission, and an
 unsampled request's spans are recorded only if they end up tail
-exemplars — the bench measures a sampling=0 rerun against the traced
-run and records both.
+exemplars.
 
 In-process simulations run many nodes in ONE process sharing this
 module-global ``TRACER`` (like ``observability.METRICS``); spans carry

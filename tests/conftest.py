@@ -3,7 +3,8 @@
 Must run before any jax import — pytest loads conftest first, so env
 vars set here take effect for the whole test session. Multi-chip
 sharding paths are validated on this virtual mesh; the chip is reached
-only through `chip_smoke.py` / `bench.py`, one process at a time.
+only through `chip_smoke.py` / `benchmark/run.py`, one process at a
+time.
 """
 
 import os

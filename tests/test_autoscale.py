@@ -513,64 +513,6 @@ def test_affinity_purge_labels_leave_vs_failure(tmp_path):
 # (i) controller-aimed chaos family (slow, >=3 seeds)
 # ----------------------------------------------------------------------
 
-def test_claim_check_autoscale_gate(tmp_path):
-    """The round-20 artifact gate: a healthy block passes, a skip is
-    exempt, pre-round-20 artifacts are exempt, and each gutted
-    variant (one-sided win, restart, red sweep, one-directional
-    loop, nondeterministic replay) is named in a violation."""
-    from dml_tpu.tools import claim_check as cc
-
-    ok = {
-        "autoscale_slo_min_saved": 0.25,
-        "autoscale_idle_min_saved": 0.09,
-        "static": {"restarts": 0, "sweep_ok": True},
-        "autoscaled": {"restarts": 0, "sweep_ok": True},
-        "decisions_applied": {"scale_out": 2, "scale_in": 2},
-        "replay_deterministic_ok": True,
-        "autoscale_ok": True,
-    }
-
-    def art(name, doc):
-        p = str(tmp_path / name)
-        with open(p, "w") as f:
-            json.dump(doc, f)
-        return p
-
-    assert cc.check_autoscale_block(
-        art("ok.json", {"matrix": {"autoscale": ok}})) == []
-    assert cc.check_autoscale_block(art("skip.json", {
-        "matrix": {"_skipped": {"autoscale": "wall budget"},
-                   "cluster_serving": {}},
-    })) == []
-    assert cc.check_autoscale_block(art(
-        "BENCH_r19.json", {"matrix": {"cluster_serving": {}}})) == []
-    problems = cc.check_autoscale_block(
-        art("lost.json", {"matrix": {"cluster_serving": {}}}))
-    assert any("no `autoscale` section" in p for p in problems)
-    cases = [
-        (dict(ok, autoscale_idle_min_saved=-0.1),
-         "autoscale_idle_min_saved"),
-        (dict(ok, autoscaled={"restarts": 1, "sweep_ok": True}),
-         "restarts"),
-        (dict(ok, static={"restarts": 0, "sweep_ok": False}),
-         "sweep_ok"),
-        (dict(ok, decisions_applied={"scale_out": 2}), "scale_in"),
-        (dict(ok, replay_deterministic_ok=False),
-         "replay_deterministic_ok"),
-        (dict(ok, autoscale_ok=False), "own"),
-    ]
-    for i, (block, needle) in enumerate(cases):
-        problems = cc.check_autoscale_block(
-            art(f"bad{i}.json", {"matrix": {"autoscale": block}}))
-        assert any(needle in p for p in problems), (needle, problems)
-    # summary-only driver captures gate on the compact-line keys
-    problems = cc.check_autoscale_block(art("sum.json", {
-        "_summary_only": True,
-        "summary": {"autoscale_ok": False,
-                    "autoscale_slo_min_saved": -0.2},
-    }))
-    assert len(problems) == 2
-
 
 @pytest.mark.slow
 @pytest.mark.chaos

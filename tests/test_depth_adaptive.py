@@ -2,12 +2,10 @@
 pure-logic determinism under an injected clock, the commit/fallback/
 drift/abort state machine, the service wiring on the in-process
 chaos.LocalCluster (tier-1-speed smoke: one full probe cycle through
-the real coordinator ACK path), a leader kill mid-probe, and the
-claim_check validation of the new round-6 bench fields."""
+the real coordinator ACK path) and a leader kill mid-probe."""
 
 import asyncio
 import contextlib
-import json
 import os
 import shutil
 
@@ -425,189 +423,6 @@ def test_group_ack_duplicates_freshness_gated(tmp_path):
             await c.stop()
 
     asyncio.run(run())
-
-
-# ----------------------------------------------------------------------
-# claim_check: the round-6 bench fields (adaptive verdict,
-# steady-state LM) + compact-summary / provenance plumbing
-# ----------------------------------------------------------------------
-
-
-GOOD_CS = {
-    "qps_end_to_end": 100.0,
-    "qps_unpipelined": 80.0,
-    "qps_pipelined_static": 90.0,
-    "pipelining_speedup": 1.11,
-    "pipelining_speedup_static": 1.13,
-    "adaptive": {"state": "settled", "depth": 2,
-                 "last_probe": {"winner": 2}},
-}
-
-GOOD_CLM = {
-    "gen_tok_per_s_end_to_end": 1800.0,
-    "steady_state": {
-        "measured_steady_s": 16.2,
-        "gen_tok_per_s_steady": 2400.0,
-        "curve_tok_per_s": [[i + 1.0, 2400.0] for i in range(18)],
-    },
-}
-
-
-def _artifact(tmp_path, name, matrix):
-    p = str(tmp_path / f"{name}.json")
-    with open(p, "w") as f:
-        json.dump({"matrix": matrix}, f)
-    return p
-
-
-def test_claim_check_serving_fields(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    ok = _artifact(tmp_path, "ok", {
-        "cluster_serving": GOOD_CS, "cluster_lm_serving": GOOD_CLM,
-    })
-    assert cc.check_serving_block(ok) == []
-    # sections skipped by the wall budget are honestly exempt
-    assert cc.check_serving_block(_artifact(tmp_path, "skip", {
-        "_skipped": {"cluster_serving": "budget",
-                     "cluster_lm_serving": "budget"},
-    })) == []
-    # pre-round-6 artifacts are exempt
-    assert cc.check_serving_block(_artifact(
-        tmp_path, "BENCH_r05x", {"cluster_serving": {}}
-    )) == []
-    # a committed depth that LOSES to a forced static beyond probe
-    # noise fails the artifact (the r5 0.91x failure mode)
-    bad = cc.check_serving_block(_artifact(tmp_path, "lost", {
-        "cluster_serving": dict(GOOD_CS, pipelining_speedup=0.85),
-    }))
-    assert any("probe noise" in p for p in bad)
-    # a missing adaptive verdict fails
-    cs = dict(GOOD_CS)
-    cs.pop("adaptive")
-    bad = cc.check_serving_block(
-        _artifact(tmp_path, "noad", {"cluster_serving": cs})
-    )
-    assert any("adaptive" in p for p in bad)
-    # an LM section without the steady-state phase fails; so does a
-    # too-short window or a missing curve
-    clm = dict(GOOD_CLM)
-    clm.pop("steady_state")
-    bad = cc.check_serving_block(
-        _artifact(tmp_path, "noss", {"cluster_lm_serving": clm})
-    )
-    assert any("steady_state missing" in p for p in bad)
-    bad = cc.check_serving_block(_artifact(tmp_path, "short", {
-        "cluster_lm_serving": dict(GOOD_CLM, steady_state=dict(
-            GOOD_CLM["steady_state"], measured_steady_s=3.0)),
-    }))
-    assert any("still a transient" in p for p in bad)
-    bad = cc.check_serving_block(_artifact(tmp_path, "flat", {
-        "cluster_lm_serving": dict(GOOD_CLM, steady_state=dict(
-            GOOD_CLM["steady_state"], curve_tok_per_s=[[1.0, 5.0]])),
-    }))
-    assert any("curve" in p for p in bad)
-
-
-def test_compact_summary_line_fits_and_parses():
-    """The driver keeps a 2,000-char stdout tail; the final standalone
-    summary line must fit it with headroom, parse alone, and keep its
-    most essential keys under trimming."""
-    from bench import COMPACT_SUMMARY_BUDGET, compact_summary_line
-
-    summary = {
-        "headline_qps": 14388.3, "headline_mfu": 0.5462,
-        "cluster_qps": 74.6, "cluster_pipelining": 1.02,
-        "cluster_lm_steady_tok_s": 2400.0,
-        "section_errors": [], "sections_skipped": [],
-        # a fat key that trimming should drop first (wide enough to
-        # push the line past the budget on its own)
-        "section_wall_s": {
-            f"a_very_long_section_name_{i}": 123.456 for i in range(60)
-        },
-        "kv_heads_tok_s": {"mha": 1051.8, "gqa4": 2165.2, "mqa": 2006.6},
-    }
-    line = compact_summary_line(
-        {"qps": 14388.3}, "TPU_v5e(...)", 4.0, summary)
-    assert len(line) <= COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert doc["bench_summary_v1"] is True
-    assert doc["summary"]["cluster_qps"] == 74.6
-    assert "section_wall_s" not in doc["summary"]  # trimmed
-    # the original dict is not mutated by trimming
-    assert "section_wall_s" in summary
-
-
-def test_load_bench_recovers_driver_wrapper_forms(tmp_path):
-    from dml_tpu.tools.parity_table import load_bench
-
-    big = json.dumps({"metric": "x", "matrix": {"a": 1},
-                      "summary": {"headline_qps": 14000.0,
-                                  "cluster_qps": 75.0}})
-    compact = json.dumps({"bench_summary_v1": True,
-                          "summary": {"headline_qps": 14000.0}},
-                         separators=(",", ":"))
-
-    def wrapper(name, tail):
-        p = str(tmp_path / name)
-        with open(p, "w") as f:
-            json.dump({"cmd": "bench", "rc": 0, "tail": tail,
-                       "parsed": None}, f)
-        return p
-
-    # intact artifact line: parsed whole
-    d = load_bench(wrapper("whole.json", big + "\n"))
-    assert d["matrix"] == {"a": 1} and "_summary_only" not in d
-    # intact artifact line FOLLOWED by the compact line (the exact
-    # round-6+ stdout shape): the FULL artifact must win — trailing
-    # data must not downgrade it to summary-only
-    d = load_bench(wrapper("both.json", big + "\n" + compact + "\n"))
-    assert d["matrix"] == {"a": 1} and "_summary_only" not in d
-    # truncated artifact line + compact summary line: compact wins
-    d = load_bench(wrapper(
-        "compact.json", big[big.index('"matrix"'):] + "\n" + compact))
-    assert d["_summary_only"] and d["summary"]["headline_qps"] == 14000.0
-    # truncated artifact line only: trailing summary salvaged (cut
-    # mid-object, with the summary key + object intact at the end —
-    # the shape the driver's 2,000-char tail produced in r3..r5)
-    d = load_bench(wrapper("salvage.json", big[big.index('"matrix"'):]))
-    assert d["_summary_only"] and d["summary"]["cluster_qps"] == 75.0
-    # nothing recoverable
-    d = load_bench(wrapper("junk.json", "no json here"))
-    assert d.get("_unparseable_wrapper")
-
-
-def test_parity_source_check(tmp_path):
-    """A PARITY table stamped from a preview while the same-round
-    driver capture parses is flagged; the repo itself must be clean."""
-    from dml_tpu.tools import claim_check as cc
-
-    def parity(src):
-        p = tmp_path / "PARITY.md"
-        p.write_text(
-            f"<!-- BENCH-TABLE:BEGIN source={src} sha1=abc -->\n"
-            "<!-- BENCH-TABLE:END -->\n"
-        )
-        return str(p)
-
-    # preview source, no driver capture: fine (driver hasn't run yet)
-    assert cc.check_parity_source(parity("BENCH_r09_preview.json")) == []
-    # driver capture exists and parses: violation
-    compact = json.dumps({"bench_summary_v1": True, "summary": {}},
-                         separators=(",", ":"))
-    with open(tmp_path / "BENCH_r09.json", "w") as f:
-        json.dump({"cmd": "b", "rc": 0, "tail": compact}, f)
-    bad = cc.check_parity_source(parity("BENCH_r09_preview.json"))
-    assert bad and "BENCH_r09.json" in bad[0]
-    # unparseable driver capture: preview stands
-    with open(tmp_path / "BENCH_r09.json", "w") as f:
-        json.dump({"cmd": "b", "rc": 0, "tail": "garbage"}, f)
-    assert cc.check_parity_source(parity("BENCH_r09_preview.json")) == []
-    # driver source: always fine
-    assert cc.check_parity_source(parity("BENCH_r09.json")) == []
-    # THE REPO: the committed PARITY.md must not be preview-stamped
-    # while a parseable same-round driver capture sits next to it
-    assert cc.check_parity_source() == []
 
 
 def test_overlap_headroom_bound():

@@ -25,14 +25,11 @@ from dml_tpu.tools.dmllint import (
     check_markers,
     check_metrics,
     check_span_names,
-    check_summary,
     check_wire,
     collect_alert_call_sites,
     collect_metric_registrations,
     collect_span_call_sites,
     collect_tracing_literals,
-    extract_bench_summary_keys,
-    extract_claim_gate_keys,
     extract_handler_owners,
     extract_msgtype_members,
     extract_msgtype_refs,
@@ -339,57 +336,6 @@ def test_metric_map_drift_detected():
 def test_metric_map_missing_section_detected():
     fs = check_metrics(None, {}, "dml_tpu/obs.py")
     assert len(fs) == 1 and "no 'Metric map" in fs[0].msg
-
-
-# ----------------------------------------------------------------------
-# drift-summary-keys
-# ----------------------------------------------------------------------
-
-BENCH_FIXTURE = textwrap.dedent("""
-    _COMPACT_DROP_ORDER = ("b", "typo_drop")
-    _COMPACT_KEEP_KEYS = ("a", "typo_keep")
-
-    def emit(g):
-        summary = {"a": g("a"), "b": g("b"), "c": g("c")}
-        summary["interrupted"] = True
-        return summary
-""")
-
-CLAIM_FIXTURE = textwrap.dedent("""
-    def check_x(data):
-        s = data.get("summary") or {}
-        if s.get("a") is None:
-            return []
-        if s["ghost_key"]:
-            return ["bad"]
-        return [s.get("c")]
-""")
-
-
-def test_summary_extractors():
-    b = ast.parse(BENCH_FIXTURE)
-    assert set(extract_bench_summary_keys(b)) == {"a", "b", "c", "interrupted"}
-    gk = extract_claim_gate_keys(ast.parse(CLAIM_FIXTURE))
-    assert set(gk) == {"a", "ghost_key", "c"}
-
-
-def test_summary_drift_detected():
-    b = ast.parse(BENCH_FIXTURE)
-    fs = check_summary(
-        extract_bench_summary_keys(b),
-        dmllint._module_const_strs(b, "_COMPACT_KEEP_KEYS"),
-        dmllint._module_const_strs(b, "_COMPACT_DROP_ORDER"),
-        extract_claim_gate_keys(ast.parse(CLAIM_FIXTURE)),
-        "bench.py", "claim_check.py",
-    )
-    msgs = " | ".join(f.msg for f in fs)
-    assert "'ghost_key' but bench.py never emits" in msgs
-    assert "'c' but the key does not survive" in msgs       # gate-trimmed
-    assert "_COMPACT_DROP_ORDER entry 'typo_drop'" in msgs
-    assert "_COMPACT_KEEP_KEYS entry 'typo_keep'" in msgs
-    # and the missing-keep-list degradation is itself a finding
-    fs2 = check_summary({"a": 1}, None, None, {}, "bench.py", "c.py")
-    assert any("no module-level _COMPACT_KEEP_KEYS" in f.msg for f in fs2)
 
 
 # ----------------------------------------------------------------------
@@ -762,83 +708,6 @@ def test_repo_zero_unbaselined_findings():
     # every suppression corresponds to a live finding (no stale
     # entries — apply_baseline would have surfaced them above)
     assert len(res.suppressed) == res.baseline_size
-
-
-def test_bench_block_shape():
-    block = dmllint.bench_block()
-    assert block["lint_clean"] is True
-    assert block["findings"] == 0
-    assert isinstance(block["baseline_size"], int)
-    # round-16 flow-aware pass counts (baselined findings included):
-    # their presence in every artifact is what claim_check gates on
-    assert isinstance(block["race_findings"], int)
-    assert isinstance(block["payload_findings"], int)
-    assert {"race-yield-hazard", "drift-wire-payloads"} <= set(block["rules"])
-
-
-# ----------------------------------------------------------------------
-# claim_check round-11 gate
-# ----------------------------------------------------------------------
-
-
-def _artifact(tmp_path, name, doc):
-    p = tmp_path / name
-    p.write_text(json.dumps(doc))
-    return str(p)
-
-
-def test_claim_check_lint_gate(tmp_path):
-    from dml_tpu.tools.claim_check import check_lint_block
-
-    good = {"metric": "x", "matrix": {
-        "lint": {"lint_clean": True, "findings": 0, "baseline_size": 1}}}
-    assert check_lint_block(_artifact(tmp_path, "BENCH_r11.json", good)) == []
-    # pre-round-11 artifacts exempt, even without the block
-    old = {"metric": "x", "matrix": {}}
-    assert check_lint_block(_artifact(tmp_path, "BENCH_r10.json", old)) == []
-    # round 11+: missing block is a violation
-    assert check_lint_block(_artifact(tmp_path, "BENCH_r12.json", old))
-    # dirty tree is a violation
-    bad = {"metric": "x", "matrix": {
-        "lint": {"lint_clean": False, "findings": 3, "baseline_size": 1}}}
-    probs = check_lint_block(_artifact(tmp_path, "BENCH_r11b.json", bad))
-    assert any("lint_clean" in p for p in probs)
-    # oversized baseline is a violation
-    fat = {"metric": "x", "matrix": {
-        "lint": {"lint_clean": True, "findings": 0, "baseline_size": 99}}}
-    probs = check_lint_block(_artifact(tmp_path, "BENCH_r11c.json", fat))
-    assert any("baseline_size" in p for p in probs)
-
-
-def test_claim_check_lint_gate_summary_only(tmp_path):
-    from dml_tpu.tools.claim_check import check_lint_block
-
-    line = json.dumps({"bench_summary_v1": True,
-                       "summary": {"lint_clean": False}})
-    doc = {"tail": "garbage prefix\n" + line + "\n"}
-    probs = check_lint_block(_artifact(tmp_path, "BENCH_r11.json", doc))
-    assert any("lint_clean is false" in p for p in probs)
-    ok_line = json.dumps({"bench_summary_v1": True,
-                          "summary": {"lint_clean": True}})
-    doc = {"tail": ok_line + "\n"}
-    assert check_lint_block(
-        _artifact(tmp_path, "BENCH_r11d.json", doc)) == []
-
-
-def test_compact_line_keeps_lint_clean():
-    """The round-11 summary-only gate can only fire if lint_clean
-    survives bench.py's last-resort compact-line trim."""
-    import bench
-
-    assert "lint_clean" in bench._COMPACT_KEEP_KEYS
-    hl = {"qps": 100.0}
-    fat_summary = {k: "x" * 50 for k in
-                   [f"pad_{i}" for i in range(200)]}
-    fat_summary["lint_clean"] = True
-    line = bench.compact_summary_line(hl, "cpu", 4.0, fat_summary)
-    assert len(line) <= bench.COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert doc["summary"]["lint_clean"] is True
 
 
 # ----------------------------------------------------------------------
@@ -1276,17 +1145,23 @@ def test_rules_and_paths_filters(tmp_path):
     (tmp_path / "dml_tpu").mkdir()
     (tmp_path / "dml_tpu" / "racy.py").write_text(RACY_SRC)
     (tmp_path / "dml_tpu" / "hazard.py").write_text(HAZARD_SRC)
+    # the lint surface is dml_tpu/ + tests/: a script at the root is
+    # not scanned
+    (tmp_path / "bench.py").write_text(HAZARD_SRC)
     root = str(tmp_path)
     res = run_lint(root)
     assert sorted({f.rule for f in res.findings}) == [
         "naked-task", "race-yield-hazard"]
+    assert {f.path for f in res.findings} == {
+        "dml_tpu/hazard.py", "dml_tpu/racy.py"}
     only_race = run_lint(root, rules=["race-yield-hazard"])
     assert {f.rule for f in only_race.findings} == {"race-yield-hazard"}
     only_file = run_lint(root, paths=["dml_tpu/hazard.py"])
     assert {f.path for f in only_file.findings} == {"dml_tpu/hazard.py"}
     # unknown rule name is an internal error (exit 2 via CLI)
-    with pytest.raises(LintInternalError, match="unknown rule"):
-        run_lint(root, rules=["no-such-rule"])
+    for gone in ("no-such-rule", "drift-summary-keys"):
+        with pytest.raises(LintInternalError, match="unknown rule"):
+            run_lint(root, rules=[gone])
     assert dmllint.main(["--root", root, "--rules", "no-such-rule"]) == 2
 
 
@@ -1339,60 +1214,3 @@ def test_flow_findings_deterministic(tmp_path):
     r2 = run_lint(str(tmp_path))
     assert [f.key for f in r1.findings] == [f.key for f in r2.findings]
     assert len(r1.findings) == 2
-
-
-# ----------------------------------------------------------------------
-# claim_check round-16 flow gate + compact-line survival
-# ----------------------------------------------------------------------
-
-
-def test_claim_check_flow_lint_gate(tmp_path):
-    from dml_tpu.tools.claim_check import check_lint_block
-
-    base_block = {"lint_clean": True, "findings": 0, "baseline_size": 2}
-    flow_block = dict(base_block, race_findings=0, payload_findings=1,
-                      rules=["race-yield-hazard", "drift-wire-payloads"])
-    ok = {"metric": "x", "matrix": {"lint": flow_block}}
-    assert check_lint_block(_artifact(tmp_path, "BENCH_r16.json", ok)) == []
-    # pre-flow rounds don't need the counts
-    old = {"metric": "x", "matrix": {"lint": base_block}}
-    assert check_lint_block(_artifact(tmp_path, "BENCH_r15.json", old)) == []
-    # round 16+: missing counts or missing rules are violations
-    probs = check_lint_block(_artifact(tmp_path, "BENCH_r16b.json", old))
-    assert any("race_findings" in p for p in probs)
-    norules = {"metric": "x", "matrix": {"lint": dict(
-        flow_block, rules=["naked-task"])}}
-    probs = check_lint_block(_artifact(tmp_path, "BENCH_r16c.json", norules))
-    assert any("flow-aware rules" in p for p in probs)
-
-
-def test_claim_check_flow_lint_gate_summary_only(tmp_path):
-    from dml_tpu.tools.claim_check import check_lint_block
-
-    line = json.dumps({"bench_summary_v1": True, "summary": {
-        "lint_clean": True, "lint_race": 0, "lint_payload": 1}})
-    doc = {"tail": line + "\n"}
-    assert check_lint_block(_artifact(tmp_path, "BENCH_r16d.json", doc)) == []
-    bare = json.dumps({"bench_summary_v1": True,
-                       "summary": {"lint_clean": True}})
-    probs = check_lint_block(
-        _artifact(tmp_path, "BENCH_r16e.json", {"tail": bare + "\n"}))
-    assert any("lint_race" in p for p in probs)
-    # pre-flow summary-only captures stay exempt
-    assert check_lint_block(
-        _artifact(tmp_path, "BENCH_r15b.json", {"tail": bare + "\n"})) == []
-
-
-def test_compact_line_keeps_flow_counts():
-    import bench
-
-    assert "lint_race" in bench._COMPACT_KEEP_KEYS
-    assert "lint_payload" in bench._COMPACT_KEEP_KEYS
-    hl = {"qps": 100.0}
-    fat = {k: "x" * 50 for k in [f"pad_{i}" for i in range(200)]}
-    fat.update(lint_clean=True, lint_race=0, lint_payload=1)
-    line = bench.compact_summary_line(hl, "cpu", 4.0, fat)
-    assert len(line) <= bench.COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert doc["summary"]["lint_race"] == 0
-    assert doc["summary"]["lint_payload"] == 1

@@ -17,8 +17,6 @@ Layers covered:
 - scheduler: the DepthController pool-size re-probe trigger
 - chaos: the `elastic` scenario family, JOIN forgeries in
   fuzz_datagrams, scale_out/scale_in on LocalCluster
-- bench/claim_check: the round-18 elastic_capacity gate + compact-line
-  key survival
 """
 
 import asyncio
@@ -878,122 +876,3 @@ def test_elastic_scenario_sweeps_green():
     assert {"scale_out", "scale_in", "join_storm"} <= kinds
     assert rep.invariants.checks.get("forged_joins", {}).get(
         "rejected", 0) > 0
-
-
-# ----------------------------------------------------------------------
-# bench + claim_check: the round-18 elastic_capacity gate
-# ----------------------------------------------------------------------
-
-
-GOOD_ELASTIC = {
-    "nodes": 4,
-    "joiners": ["127.0.0.1:30045", "127.0.0.1:30046"],
-    "qps_before": 345.6,
-    "qps_after": 590.2,
-    "scaleout_gain": 1.71,
-    "pool_slots_before": 2,
-    "pool_slots_after": 4,
-    "restarts": 0,
-    "scale_in_graceful": [True, True],
-    "storm": {"sent": 32, "rejected": 24},
-    "sweep_ok": True,
-    "sweep_failures": [],
-    "elastic_ok": True,
-}
-
-
-def _artifact(tmp_path, name, doc):
-    path = str(tmp_path / f"{name}.json")
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return path
-
-
-def test_claim_check_elastic_block(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    ok = _artifact(tmp_path, "BENCH_r18", {
-        "matrix": {"elastic_capacity": GOOD_ELASTIC,
-                   "cluster_serving": {}},
-    })
-    assert cc.check_elastic_block(ok) == []
-    # pre-round-18 artifacts are exempt
-    old = _artifact(tmp_path, "BENCH_r17", {
-        "matrix": {"cluster_serving": {}},
-    })
-    assert cc.check_elastic_block(old) == []
-    # wall-budget skip is honestly exempt
-    skip = _artifact(tmp_path, "BENCH_r19", {
-        "matrix": {"_skipped": {"elastic_capacity": "budget"},
-                   "cluster_serving": {}},
-    })
-    assert cc.check_elastic_block(skip) == []
-    # losing the section silently is a violation
-    lost = _artifact(tmp_path, "BENCH_r20", {
-        "matrix": {"cluster_serving": {}},
-    })
-    assert any("no `elastic_capacity`" in p
-               for p in cc.check_elastic_block(lost))
-    # throughput NOT rising fails the gate
-    bad = dict(GOOD_ELASTIC, qps_after=340.0, scaleout_gain=0.98)
-    p = cc.check_elastic_block(_artifact(tmp_path, "BENCH_r21", {
-        "matrix": {"elastic_capacity": bad}}))
-    assert any("RAISE measured throughput" in x for x in p)
-    # a restart disqualifies the gain
-    bad = dict(GOOD_ELASTIC, restarts=1)
-    p = cc.check_elastic_block(_artifact(tmp_path, "BENCH_r22", {
-        "matrix": {"elastic_capacity": bad}}))
-    assert any("zero restarts" in x for x in p)
-    # a silent (non-graceful) scale-in fails
-    bad = dict(GOOD_ELASTIC, scale_in_graceful=[True, False])
-    p = cc.check_elastic_block(_artifact(tmp_path, "BENCH_r23", {
-        "matrix": {"elastic_capacity": bad}}))
-    assert any("announce LEAVE" in x for x in p)
-    # a storm that moved nothing fails
-    bad = dict(GOOD_ELASTIC, storm={"sent": 32, "rejected": 0})
-    p = cc.check_elastic_block(_artifact(tmp_path, "BENCH_r24", {
-        "matrix": {"elastic_capacity": bad}}))
-    assert any("rejection counters" in x for x in p)
-    # a red sweep fails
-    bad = dict(GOOD_ELASTIC, sweep_ok=False,
-               sweep_failures=["phantom"], elastic_ok=False)
-    p = cc.check_elastic_block(_artifact(tmp_path, "BENCH_r25", {
-        "matrix": {"elastic_capacity": bad}}))
-    assert any("invariant sweep" in x for x in p)
-
-
-def test_claim_check_elastic_summary_only(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    def cap(name, summary):
-        return _artifact(tmp_path, name, {
-            "bench_summary_v1": True, "_summary_only": True,
-            "summary": summary,
-        })
-
-    ok = cap("BENCH_r18", {"elastic_scaleout_gain": 1.71,
-                           "elastic_ok": True})
-    assert cc.check_elastic_block(ok) == []
-    bad = cap("BENCH_r19", {"elastic_scaleout_gain": 0.97,
-                            "elastic_ok": False})
-    p = cc.check_elastic_block(bad)
-    assert any("elastic_scaleout_gain" in x for x in p)
-    assert any("elastic_ok" in x for x in p)
-
-
-def test_compact_line_keeps_elastic_keys():
-    """The last-resort compact-line trim must keep the keys the
-    round-18 summary-only gate reads."""
-    import bench
-
-    for key in ("elastic_scaleout_gain", "elastic_ok"):
-        assert key in bench._COMPACT_KEEP_KEYS
-    summary = {k: "x" * 400 for k in bench._COMPACT_DROP_ORDER}
-    summary.update({k: 1.5 for k in bench._COMPACT_KEEP_KEYS})
-    summary["elastic_ok"] = True
-    summary["elastic_scaleout_gain"] = 1.71
-    line = bench.compact_summary_line({"qps": 1.0}, "cpu", 4.0, summary)
-    assert len(line) <= bench.COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert doc["summary"]["elastic_ok"] is True
-    assert doc["summary"]["elastic_scaleout_gain"] == 1.71

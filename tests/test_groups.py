@@ -13,14 +13,11 @@ Coverage layers:
   pool), member restart -> re-formation, leader failover;
 - the real sharded path: ShardedInference param_gather bitwise
   equality (TinyNet, cheap) — the full-cluster ResNet50 equality case
-  lives in tests/test_jobs_sim.py and __graft_entry__ part 5;
-- claim_check's cluster_sharded_serving gate + the compact summary's
-  sharded keys.
+  lives in tests/test_jobs_sim.py and __graft_entry__ part 5.
 """
 
 import asyncio
 import contextlib
-import json
 import os
 import shutil
 
@@ -654,120 +651,3 @@ def test_param_gather_bitwise_equality():
         0, 255, (6, 32, 32, 3), np.uint8
     )
     np.testing.assert_array_equal(sh(imgs), one(imgs))
-
-
-# ----------------------------------------------------------------------
-# claim_check: the cluster_sharded_serving gate (round 7+)
-# ----------------------------------------------------------------------
-
-
-GOOD_SHARDED = {
-    "nodes": 5,
-    "queries": 64,
-    "qps_sharded": 3.8,
-    "qps_single_chip": 17.7,
-    "sharded_vs_single": 0.21,
-    "equal_outputs": True,
-    "groups": {"tp0": {
-        "members": ["127.0.0.1:28944", "127.0.0.1:28945"],
-        "primary": "127.0.0.1:28944",
-        "mesh": {"dp": 1, "tp": 2},
-        "formed": True,
-    }},
-}
-
-
-def _artifact(tmp_path, name, doc):
-    p = str(tmp_path / f"{name}.json")
-    with open(p, "w") as f:
-        json.dump(doc, f)
-    return p
-
-
-def test_claim_check_sharded_block(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    ok = _artifact(tmp_path, "BENCH_r07a", {
-        "matrix": {"cluster_sharded_serving": GOOD_SHARDED},
-    })
-    assert cc.check_sharded_block(ok) == []
-    # pre-round-7 artifacts exempt
-    assert cc.check_sharded_block(_artifact(
-        tmp_path, "BENCH_r06x", {"matrix": {}},
-    )) == []
-    # wall-budget skip and in-block skip are honest exemptions
-    assert cc.check_sharded_block(_artifact(tmp_path, "BENCH_r07b", {
-        "matrix": {"_skipped": {"cluster_sharded_serving": "budget"}},
-    })) == []
-    assert cc.check_sharded_block(_artifact(tmp_path, "BENCH_r07c", {
-        "matrix": {"cluster_sharded_serving": {
-            "skipped": True, "reason": "one device"}},
-    })) == []
-    # missing section (and not recorded skipped) from round 7 fails
-    bad = cc.check_sharded_block(_artifact(tmp_path, "BENCH_r07d", {
-        "matrix": {"cluster_serving": {"qps_end_to_end": 1.0}},
-    }))
-    assert any("no `cluster_sharded_serving`" in p for p in bad)
-    # equality flag false = sharded serving changes answers: fail
-    bad = cc.check_sharded_block(_artifact(tmp_path, "BENCH_r07e", {
-        "matrix": {"cluster_sharded_serving": dict(
-            GOOD_SHARDED, equal_outputs=False)},
-    }))
-    assert any("bitwise-equal" in p for p in bad)
-    # zero / missing q/s fails
-    bad = cc.check_sharded_block(_artifact(tmp_path, "BENCH_r07f", {
-        "matrix": {"cluster_sharded_serving": dict(
-            GOOD_SHARDED, qps_sharded=0.0)},
-    }))
-    assert any("qps_sharded" in p for p in bad)
-    # topology must be echoed
-    bad = cc.check_sharded_block(_artifact(tmp_path, "BENCH_r07g", {
-        "matrix": {"cluster_sharded_serving": dict(
-            GOOD_SHARDED, groups={})},
-    }))
-    assert any("topology" in p for p in bad)
-    # summary-only driver captures (truncated tail -> only the compact
-    # line survives): gated on the compact sharded_equal flag
-    def wrapper(name, equal):
-        line = json.dumps({
-            "bench_summary_v1": True,
-            "summary": {"sharded_qps": 3.8, "sharded_equal": equal},
-        })
-        return _artifact(tmp_path, name, {
-            "cmd": "bench", "rc": 0,
-            "tail": '{"metric": "truncated...\n' + line + "\n",
-        })
-
-    assert cc.check_sharded_block(wrapper("BENCH_r07h", True)) == []
-    bad = cc.check_sharded_block(wrapper("BENCH_r07i", False))
-    assert any("diverged" in p for p in bad)
-
-
-def test_compact_summary_keeps_sharded_keys():
-    """The last-resort trim must keep sharded_qps + sharded_equal (the
-    round-7 summary gate keys) inside the 1,500-char budget."""
-    from bench import COMPACT_SUMMARY_BUDGET, compact_summary_line
-
-    summary = {
-        "headline_qps": 14388.3,
-        "cluster_qps": 74.6,
-        "sharded_qps": 3.8,
-        "sharded_equal": True,
-        "sharded_vs_single": 0.21,
-        "cluster_lm_steady_tok_s": 2400.0,
-        "section_errors": [], "sections_skipped": [],
-        # fat filler to force the last-resort path
-        "section_wall_s": {
-            f"a_very_long_section_name_{i}": 123.456 for i in range(90)
-        },
-        "kv_heads_tok_s": {
-            f"form_{i}": 1000.0 + i for i in range(40)
-        },
-        "chaos_scenarios_ok": {f"fam_{i}": True for i in range(40)},
-        "lm_tok_s": {f"cfg_{i}": 100.0 for i in range(40)},
-    }
-    line = compact_summary_line({"qps": 14388.3}, "dev", 4.0, summary)
-    assert len(line) <= COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert doc["summary"]["sharded_qps"] == 3.8
-    assert doc["summary"]["sharded_equal"] is True

@@ -7,7 +7,6 @@ same chassis the soaks validate)."""
 
 import asyncio
 import contextlib
-import json
 import math
 import os
 import shutil
@@ -716,122 +715,6 @@ def test_wait_job_survives_dropped_success_push(tmp_path):
             assert done["total_queries"] == 16
 
     asyncio.run(run())
-
-
-# ----------------------------------------------------------------------
-# claim_check round-9 request gate (ISSUE 7 satellite)
-# ----------------------------------------------------------------------
-
-GOOD_REQUEST = {
-    "p50_ms": 57.0, "p95_ms": 145.4, "p99_ms": 556.0,
-    "goodput_qps": 59.2, "shed_ratio": 0.0,
-    "continuous_vs_fixed_p99": 17.8,
-    "saturation_goodput_ratio": 1.17,
-    "failover": {
-        "all_terminal_exactly_once": True, "completed": 220,
-        "shed": 37, "rejected": 1, "n": 258,
-    },
-}
-
-
-def _artifact(tmp_path, name, doc):
-    p = str(tmp_path / f"{name}.json")
-    with open(p, "w") as f:
-        json.dump(doc, f)
-    return p
-
-
-@pytest.mark.ingress
-def test_claim_check_request_block(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    ok = _artifact(tmp_path, "BENCH_r09a", {
-        "matrix": {"request_serving": GOOD_REQUEST},
-    })
-    assert cc.check_request_block(ok) == []
-    # pre-round-9 artifacts exempt
-    assert cc.check_request_block(_artifact(
-        tmp_path, "BENCH_r08x", {"matrix": {}},
-    )) == []
-    # budget-skip and in-block skip are honest exemptions
-    assert cc.check_request_block(_artifact(tmp_path, "BENCH_r09b", {
-        "matrix": {"_skipped": {"request_serving": "budget"}},
-    })) == []
-    # missing section from round 9 fails
-    bad = cc.check_request_block(_artifact(tmp_path, "BENCH_r09c", {
-        "matrix": {"cluster_serving": {"qps_end_to_end": 1.0}},
-    }))
-    assert any("no `request_serving`" in p for p in bad)
-    # nonfinite / zero percentiles fail
-    bad = cc.check_request_block(_artifact(tmp_path, "BENCH_r09d", {
-        "matrix": {"request_serving": dict(GOOD_REQUEST, p99_ms=None)},
-    }))
-    assert any("p99_ms" in p for p in bad)
-    # unordered percentiles fail
-    bad = cc.check_request_block(_artifact(tmp_path, "BENCH_r09e", {
-        "matrix": {"request_serving": dict(GOOD_REQUEST, p50_ms=999.0)},
-    }))
-    assert any("not ordered" in p for p in bad)
-    # shed ratio must be in [0, 1)
-    bad = cc.check_request_block(_artifact(tmp_path, "BENCH_r09f", {
-        "matrix": {"request_serving": dict(GOOD_REQUEST, shed_ratio=1.0)},
-    }))
-    assert any("shed_ratio" in p for p in bad)
-    # continuous formation must beat fixed on light-load p99
-    bad = cc.check_request_block(_artifact(tmp_path, "BENCH_r09g", {
-        "matrix": {"request_serving": dict(
-            GOOD_REQUEST, continuous_vs_fixed_p99=0.9)},
-    }))
-    assert any("continuous" in p for p in bad)
-    # ...while matching throughput at saturation
-    bad = cc.check_request_block(_artifact(tmp_path, "BENCH_r09h", {
-        "matrix": {"request_serving": dict(
-            GOOD_REQUEST, saturation_goodput_ratio=0.5)},
-    }))
-    assert any("saturation" in p for p in bad)
-    # failover case must be green
-    bad = cc.check_request_block(_artifact(tmp_path, "BENCH_r09i", {
-        "matrix": {"request_serving": dict(GOOD_REQUEST, failover={
-            "all_terminal_exactly_once": False, "completed": 3})},
-    }))
-    assert any("exactly one" in p for p in bad)
-    # summary-only driver captures gate on the compact keys
-    assert cc.check_request_block(_artifact(tmp_path, "BENCH_r09j", {
-        "_summary_only": True,
-        "summary": {"req_p99_ms": 556.0, "req_shed_ratio": 0.0,
-                    "req_failover_ok": True},
-    })) == []
-    bad = cc.check_request_block(_artifact(tmp_path, "BENCH_r09k", {
-        "_summary_only": True,
-        "summary": {"req_p99_ms": 556.0, "req_failover_ok": False},
-    }))
-    assert any("req_failover_ok" in p for p in bad)
-
-
-@pytest.mark.ingress
-def test_compact_summary_trim_keeps_request_keys():
-    """The last-resort compact-line trim must keep the request-serving
-    trio claim_check's summary-only gate reads."""
-    import bench
-
-    summary = {k: 1.0 for k in (
-        "headline_qps", "req_p99_ms", "req_goodput_qps",
-        "req_shed_ratio",
-    )}
-    summary["req_failover_ok"] = True
-    summary["section_errors"] = []
-    summary["sections_skipped"] = []
-    # force the last-resort path with an absurd pile of filler keys
-    for i in range(400):
-        summary[f"filler_{i}"] = "x" * 40
-    line = bench.compact_summary_line(
-        {"qps": 1.0}, "cpu", 4.0, summary
-    )
-    assert len(line) <= bench.COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    for k in ("req_p99_ms", "req_goodput_qps", "req_shed_ratio",
-              "req_failover_ok"):
-        assert k in doc["summary"]
 
 
 @pytest.mark.ingress

@@ -1300,8 +1300,7 @@ def test_group_sharded_serving_outputs_equal_single_chip(tmp_path):
     get_output merge), with every served result asserted EQUAL to the
     single-chip path on the same bytes. TinyNet keeps the XLA compiles
     tier-1-cheap; the ResNet50 form of the same assertion runs in
-    __graft_entry__.dryrun_multichip part 5 and the
-    cluster_sharded_serving bench section."""
+    __graft_entry__.dryrun_multichip part 5."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -9,11 +9,10 @@ the LMServer warm placement (greedy equality vs `generate`, mixed
 budgets, bucket boundaries, kv_quant), the LMBackend / DisaggLMBackend
 hooks, the multi-turn loadgen chaining semantics, the router's
 session-affinity counters and relayed session rows across a leader
-kill, and the round-17 claim_check gate."""
+kill."""
 
 import asyncio
 import contextlib
-import json
 import os
 import shutil
 
@@ -783,109 +782,3 @@ def test_session_map_eviction_ticks_counter(tmp_path):
             assert "s1" not in router._session_node
 
     asyncio.run(run())
-
-
-# ----------------------------------------------------------------------
-# claim_check round-17 gate + compact-line survival
-# ----------------------------------------------------------------------
-
-GOOD_KV = {
-    "hit_ratio": 0.86, "hits": 12, "misses": 2, "tokens_saved": 640,
-    "ttft_ms_cold": 410.0, "ttft_ms_warm": 120.0,
-    "warm_vs_cold_ttft": 3.42, "warm_equals_cold": True,
-    "failover": {"killed_leader": "n1@x", "completed": 8,
-                 "turns_total": 8, "warm_equals_cold": True},
-}
-
-
-def _artifact(tmp_path, name, doc):
-    p = str(tmp_path / f"{name}.json")
-    with open(p, "w") as f:
-        json.dump(doc, f)
-    return p
-
-
-@pytest.mark.kvcache
-def test_claim_check_kv_cache_block(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    req = {"p50_ms": 1.0}  # presence only; the request gate owns it
-    ok = _artifact(tmp_path, "BENCH_r17a", {
-        "matrix": {"request_serving": dict(req, kv_cache=GOOD_KV)},
-    })
-    assert cc.check_kv_cache_block(ok) == []
-    # pre-round-17 artifacts exempt
-    assert cc.check_kv_cache_block(_artifact(
-        tmp_path, "BENCH_r16x",
-        {"matrix": {"request_serving": dict(req)}},
-    )) == []
-    # budget-skip honest exemption
-    assert cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17b", {
-        "matrix": {"_skipped": {"request_serving": "budget"}},
-    })) == []
-    # missing block from round 17 fails
-    bad = cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17c", {
-        "matrix": {"request_serving": dict(req)},
-    }))
-    assert any("kv_cache" in p for p in bad)
-    # zero hit ratio fails (the locality promise unfunded)
-    bad = cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17d", {
-        "matrix": {"request_serving": dict(
-            req, kv_cache=dict(GOOD_KV, hit_ratio=0.0))},
-    }))
-    assert any("hit_ratio" in p for p in bad)
-    # warm TTFT must strictly beat cold
-    bad = cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17e", {
-        "matrix": {"request_serving": dict(
-            req, kv_cache=dict(GOOD_KV, warm_vs_cold_ttft=0.98))},
-    }))
-    assert any("warm_vs_cold_ttft" in p for p in bad)
-    # tokens_saved must move
-    bad = cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17f", {
-        "matrix": {"request_serving": dict(
-            req, kv_cache=dict(GOOD_KV, tokens_saved=0))},
-    }))
-    assert any("tokens_saved" in p for p in bad)
-    # token equality is non-negotiable
-    bad = cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17g", {
-        "matrix": {"request_serving": dict(
-            req, kv_cache=dict(GOOD_KV, warm_equals_cold=False))},
-    }))
-    assert any("warm_equals_cold" in p for p in bad)
-    # ...including across the failover sub-case
-    bad = cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17h", {
-        "matrix": {"request_serving": dict(req, kv_cache=dict(
-            GOOD_KV,
-            failover={"completed": 0, "warm_equals_cold": False},
-        ))},
-    }))
-    assert any("failover" in p for p in bad)
-    # summary-only driver captures gate on the compact keys
-    assert cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17i", {
-        "bench_summary_v1": True, "_summary_only": True,
-        "summary": {"kv_hit_ratio": 0.8, "kv_warm_vs_cold_ttft": 3.1},
-    })) == []
-    bad = cc.check_kv_cache_block(_artifact(tmp_path, "BENCH_r17j", {
-        "bench_summary_v1": True, "_summary_only": True,
-        "summary": {"kv_hit_ratio": 0.0, "kv_warm_vs_cold_ttft": 0.9},
-    }))
-    assert any("kv_hit_ratio" in p for p in bad)
-    assert any("kv_warm_vs_cold_ttft" in p for p in bad)
-
-
-@pytest.mark.kvcache
-def test_compact_summary_trim_keeps_kv_keys():
-    import bench
-
-    summary = {k: 1.0 for k in (
-        "headline_qps", "kv_hit_ratio", "kv_warm_vs_cold_ttft",
-    )}
-    summary["section_errors"] = []
-    summary["sections_skipped"] = []
-    for i in range(400):
-        summary[f"filler_{i}"] = "x" * 40
-    line = bench.compact_summary_line({"qps": 1.0}, "cpu", 4.0, summary)
-    assert len(line) <= bench.COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert "kv_hit_ratio" in doc["summary"]
-    assert "kv_warm_vs_cold_ttft" in doc["summary"]

@@ -468,29 +468,30 @@ def test_serve_prefilled_requires_greedy(parts):
 
 
 @pytest.mark.sharded
-def test_sharded_forms_token_identical(tmp_path, parts):
-    """Weight-resident AND param-gather serving over a tp=2 mesh both
-    produce token-identical outputs to single-chip generate() — the
-    dryrun tp-decode contract through the backend adapter."""
+@pytest.mark.parametrize("tp", [2, 4])
+def test_sharded_resident_token_identical(tmp_path, parts, tp):
+    """Weight-resident serving over a tp mesh produces token-identical
+    outputs to single-chip generate() — the dryrun tp-decode contract
+    through the backend adapter. tp=2 divides the 2 KV heads (the
+    cache shards by head); tp=4 does not (4 query heads over 2 KV
+    heads: `heads_axis` leaves the cache whole)."""
     params, cfg = parts
-    mesh = make_mesh(MeshSpec(dp=1, tp=2), devices=jax.devices()[:2])
+    mesh = make_mesh(MeshSpec(dp=1, tp=tp), devices=jax.devices()[:tp])
     prompts = _prompts()
     paths = []
     for i, p in enumerate(prompts):
         fp = str(tmp_path / f"p{i}.tokens.txt")
         write_prompt_file(fp, p)
         paths.append(fp)
-    for form in ("resident", "gather"):
-        be = sharded_lm_backend(SPEC, mesh, form=form)
-        assert be.overlap is False
-        results, infer_time, cost = be.serve_files(paths)
-        assert infer_time > 0 and cost["per_query"] > 0
-        for fp, p in zip(paths, prompts):
-            np.testing.assert_array_equal(
-                results[fp]["tokens"],
-                _expect(params, cfg, p, NEW_TOKENS),
-                err_msg=form,
-            )
+    be = sharded_lm_backend(SPEC, mesh)
+    assert be.overlap is False
+    results, infer_time, cost = be.serve_files(paths)
+    assert infer_time > 0 and cost["per_query"] > 0
+    for fp, p in zip(paths, prompts):
+        np.testing.assert_array_equal(
+            results[fp]["tokens"],
+            _expect(params, cfg, p, NEW_TOKENS),
+        )
 
 
 @pytest.mark.sharded
@@ -501,7 +502,7 @@ def test_sharded_group_backend_degrades(tmp_path, parts):
 
     params, cfg = parts
     mesh = make_mesh(MeshSpec(dp=1, tp=2), devices=jax.devices()[:2])
-    be = sharded_lm_backend(SPEC, mesh, form="resident")
+    be = sharded_lm_backend(SPEC, mesh)
     alive = {"a", "b"}
     gb = sharded_lm_group_backend(
         be, model_name="ShardLM", group_name="g0",
@@ -713,7 +714,7 @@ async def _disagg_cluster_run(tmp):
 
     params, cfg = lm_spec_parts(SPEC)
     mesh = make_mesh(MeshSpec(dp=1, tp=2), devices=jax.devices()[:2])
-    be_dis = sharded_lm_backend(SPEC, mesh, form="resident")
+    be_dis = sharded_lm_backend(SPEC, mesh)
     be_single = LMBackend(params, cfg, max_new_tokens=NEW_TOKENS,
                           max_slots=2, max_len=64, chunk=4)
     prefill_be = LMPrefillBackend(params, cfg, max_len=64)
@@ -847,148 +848,3 @@ async def _disagg_cluster_run(tmp):
 @pytest.mark.disagg
 def test_disagg_cluster_handoff_and_fallback(tmp_path):
     asyncio.run(_disagg_cluster_run(str(tmp_path)))
-
-
-# ----------------------------------------------------------------------
-# claim_check: the cluster_lm_sharded gate (round 8+) + compact line
-# ----------------------------------------------------------------------
-
-
-GOOD_LM_SHARDED = {
-    "nodes": 5,
-    "tok_s_param_gather": 210.0,
-    "tok_s_resident": 350.0,
-    "tok_s_disagg": 280.0,
-    "resident_vs_gather": 1.67,
-    "tokens_equal_single_chip": True,
-    "kv_handoff_bytes": 41872,
-    "modes": {"disagg": {"handoffs": 9, "fallbacks": 0,
-                         "handoff_bytes": 41872}},
-    "groups": {"tp0": {
-        "members": ["127.0.0.1:28964", "127.0.0.1:28965"],
-        "primary": "127.0.0.1:28964",
-        "mesh": {"dp": 1, "tp": 2},
-        "roles": {"127.0.0.1:28964": "decode",
-                  "127.0.0.1:28965": "prefill"},
-    }},
-}
-
-
-def _artifact(tmp_path, name, doc):
-    import json
-
-    p = str(tmp_path / f"{name}.json")
-    with open(p, "w") as f:
-        json.dump(doc, f)
-    return p
-
-
-def test_claim_check_lm_sharded_block(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    ok = _artifact(tmp_path, "BENCH_r08a", {
-        "matrix": {"cluster_lm_sharded": GOOD_LM_SHARDED},
-    })
-    assert cc.check_lm_sharded_block(ok) == []
-    # pre-round-8 artifacts exempt
-    assert cc.check_lm_sharded_block(_artifact(
-        tmp_path, "BENCH_r07x", {"matrix": {}},
-    )) == []
-    # budget-skip and in-block skip are honest exemptions
-    assert cc.check_lm_sharded_block(_artifact(tmp_path, "BENCH_r08b", {
-        "matrix": {"_skipped": {"cluster_lm_sharded": "budget"}},
-    })) == []
-    assert cc.check_lm_sharded_block(_artifact(tmp_path, "BENCH_r08c", {
-        "matrix": {"cluster_lm_sharded": {
-            "skipped": True, "reason": "one device"}},
-    })) == []
-    # missing section from round 8 fails
-    bad = cc.check_lm_sharded_block(_artifact(tmp_path, "BENCH_r08d", {
-        "matrix": {"cluster_serving": {"qps_end_to_end": 1.0}},
-    }))
-    assert any("no `cluster_lm_sharded`" in p for p in bad)
-    # equality false = sharded LM serving changes answers
-    bad = cc.check_lm_sharded_block(_artifact(tmp_path, "BENCH_r08e", {
-        "matrix": {"cluster_lm_sharded": dict(
-            GOOD_LM_SHARDED, tokens_equal_single_chip=False)},
-    }))
-    assert any("token-identical" in p for p in bad)
-    # every mode must have measured a finite positive rate
-    for key in ("tok_s_param_gather", "tok_s_resident", "tok_s_disagg"):
-        bad = cc.check_lm_sharded_block(_artifact(
-            tmp_path, f"BENCH_r08f{key[-3:]}", {
-                "matrix": {"cluster_lm_sharded": dict(
-                    GOOD_LM_SHARDED, **{key: 0.0})},
-            },
-        ))
-        assert any(key in p for p in bad), key
-    # recorded handoffs with zero bytes = the slab never moved
-    bad = cc.check_lm_sharded_block(_artifact(tmp_path, "BENCH_r08g", {
-        "matrix": {"cluster_lm_sharded": dict(
-            GOOD_LM_SHARDED, kv_handoff_bytes=0)},
-    }))
-    assert any("kv_handoff_bytes" in p for p in bad)
-    # disagg served with neither handoffs nor fallbacks = broken
-    # accounting
-    bad = cc.check_lm_sharded_block(_artifact(tmp_path, "BENCH_r08h", {
-        "matrix": {"cluster_lm_sharded": dict(
-            GOOD_LM_SHARDED,
-            modes={"disagg": {"handoffs": 0, "fallbacks": 0}})},
-    }))
-    assert any("accounting" in p for p in bad)
-    # topology echo required
-    bad = cc.check_lm_sharded_block(_artifact(tmp_path, "BENCH_r08i", {
-        "matrix": {"cluster_lm_sharded": dict(GOOD_LM_SHARDED,
-                                              groups={})},
-    }))
-    assert any("topology" in p for p in bad)
-    # summary-only captures gate on the compact lm_sharded_equal flag
-    import json
-
-    def wrapper(name, equal):
-        line = json.dumps({
-            "bench_summary_v1": True,
-            "summary": {"lm_sharded_toks": 350.0,
-                        "lm_sharded_equal": equal},
-        })
-        return _artifact(tmp_path, name, {
-            "cmd": "bench", "rc": 0,
-            "tail": '{"metric": "truncated...\n' + line + "\n",
-        })
-
-    assert cc.check_lm_sharded_block(wrapper("BENCH_r08j", True)) == []
-    bad = cc.check_lm_sharded_block(wrapper("BENCH_r08k", False))
-    assert any("diverged" in p for p in bad)
-
-
-def test_compact_summary_keeps_lm_sharded_keys():
-    """The last-resort trim keeps lm_sharded_toks / lm_disagg_toks /
-    lm_sharded_equal (the round-8 summary gate keys) inside the
-    1,500-char budget."""
-    import json
-
-    from bench import COMPACT_SUMMARY_BUDGET, compact_summary_line
-
-    summary = {
-        "headline_qps": 14388.3,
-        "cluster_qps": 74.6,
-        "lm_sharded_toks": 350.0,
-        "lm_disagg_toks": 280.0,
-        "lm_sharded_equal": True,
-        "lm_sharded_vs_gather": 1.67,
-        "lm_kv_handoff_bytes": 41872,
-        "section_errors": [], "sections_skipped": [],
-        # fat filler to force the last-resort path
-        "section_wall_s": {
-            f"a_very_long_section_name_{i}": 123.456 for i in range(90)
-        },
-        "kv_heads_tok_s": {f"form_{i}": 1000.0 + i for i in range(40)},
-        "chaos_scenarios_ok": {f"fam_{i}": True for i in range(40)},
-        "lm_tok_s": {f"cfg_{i}": 100.0 for i in range(40)},
-    }
-    line = compact_summary_line({"qps": 14388.3}, "dev", 4.0, summary)
-    assert len(line) <= COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert doc["summary"]["lm_sharded_toks"] == 350.0
-    assert doc["summary"]["lm_disagg_toks"] == 280.0
-    assert doc["summary"]["lm_sharded_equal"] is True

@@ -1,8 +1,7 @@
 """Typed metrics registry (observability.py): label fan-out, fixed
 log-spaced histogram buckets, percentile math against numpy, cross-node
-snapshot merging, the exposition surfaces (Prometheus text, bench
-block), and the claim_check gate that keeps the bench honest about
-carrying the block."""
+snapshot merging and the exposition surfaces (Prometheus text, the
+summarized snapshot)."""
 
 import json
 import math
@@ -15,7 +14,6 @@ from dml_tpu.observability import (
     DEFAULT_TIME_BUCKETS,
     METRICS,
     MetricsRegistry,
-    bench_metrics_block,
     hist_quantile,
     log_buckets,
     merge_snapshots,
@@ -23,7 +21,6 @@ from dml_tpu.observability import (
     summarize_histogram,
     summarize_snapshot,
 )
-from dml_tpu.tools import claim_check as cc
 
 
 # ----------------------------------------------------------------------
@@ -310,74 +307,3 @@ def test_summarize_snapshot_shape():
     assert set(s["histograms"]["h"]) >= {"count", "mean", "p50", "p95", "p99"}
 
 
-def test_bench_metrics_block_shape():
-    """The block bench.py embeds: summarized registry + schema stamp.
-    Uses the process-global registry, so only shape is asserted."""
-    METRICS.counter("test_obs_block_total").inc()
-    block = bench_metrics_block()
-    assert block["schema"] == 1
-    for key in ("counters", "gauges", "histograms"):
-        assert isinstance(block[key], dict)
-    assert block["counters"]["test_obs_block_total"] >= 1.0
-    json.dumps(block)  # artifact-embeddable
-
-
-# ----------------------------------------------------------------------
-# claim_check: the bench must carry the metrics block from round 6 on
-# ----------------------------------------------------------------------
-
-
-def _artifact(tmp_path, name, payload):
-    p = tmp_path / name
-    p.write_text(json.dumps(payload))
-    return str(p)
-
-
-def test_claim_check_flags_missing_metrics_block(tmp_path):
-    path = _artifact(tmp_path, "BENCH_r06.json", {"matrix": {}})
-    problems = cc.check_metrics_block(path)
-    assert problems and "no `metrics` block" in problems[0]
-
-
-def test_claim_check_exempts_pre_metrics_rounds(tmp_path):
-    path = _artifact(tmp_path, "BENCH_r05.json", {"matrix": {}})
-    assert cc.check_metrics_block(path) == []
-    # the shipped canonical artifact passes (exempt or carrying it)
-    assert cc.run_metrics_check() == []
-
-
-def test_claim_check_accepts_valid_block(tmp_path):
-    METRICS.counter("lm_server_decode_tokens_total").inc(0)  # ensure registered
-    block = bench_metrics_block()
-    block["counters"]["lm_server_decode_tokens_total"] = 512.0
-    path = _artifact(tmp_path, "BENCH_r07.json", {
-        "matrix": {}, "metrics": block,
-    })
-    assert cc.check_metrics_block(path) == []
-
-
-def test_claim_check_requires_nonzero_decode_counters_when_lm_ran(tmp_path):
-    block = {"schema": 1, "counters": {}, "gauges": {}, "histograms": {}}
-    ran = _artifact(tmp_path, "BENCH_r06_ran.json", {
-        "matrix": {}, "metrics": block,
-    })
-    problems = cc.check_metrics_block(ran)
-    assert problems and "decode_tokens" in problems[0]
-    # but a wall-budget-skipped LM run is exempt from the nonzero check
-    skipped = _artifact(tmp_path, "BENCH_r06_skip.json", {
-        "matrix": {"_skipped": {"lm": "budget", "cluster_lm_serving": "b"}},
-        "metrics": block,
-    })
-    assert cc.check_metrics_block(skipped) == []
-
-
-def test_claim_check_flags_malformed_block(tmp_path):
-    path = _artifact(tmp_path, "BENCH_r06m.json", {
-        "matrix": {}, "metrics": {"schema": 1, "counters": {}},
-    })
-    problems = cc.check_metrics_block(path)
-    assert any("gauges" in p for p in problems)
-    errored = _artifact(tmp_path, "BENCH_r06e.json", {
-        "matrix": {}, "metrics": {"error": "Boom()"},
-    })
-    assert "capture failed" in cc.check_metrics_block(errored)[0]

@@ -1,10 +1,9 @@
 """Control-plane scale coverage (ISSUE 11): bounded delta gossip
 (determinism, bit-compatibility at small N, counterfactual convergence
 vs full-table exchange), two-level relay metrics aggregation, store
-inventory delta re-reports, sustained-churn plan generation, the
-in-process scale probe, and the round-12 claim_check gates."""
+inventory delta re-reports, sustained-churn plan generation and the
+in-process scale probe."""
 
-import json
 
 import pytest
 
@@ -340,10 +339,8 @@ def test_churn_plan_deterministic_paired_and_rotating():
 
 def test_churn_is_a_scenario_family():
     from dml_tpu.cluster.chaos import SCENARIO_FAMILIES, scenario_plan
-    from dml_tpu.tools import claim_check as cc
 
     assert "churn" in SCENARIO_FAMILIES
-    assert set(cc.CHAOS_SCENARIO_FAMILIES) == set(SCENARIO_FAMILIES)
     plan = scenario_plan("churn", 2)
     kinds = {e.kind for e in plan.events}
     assert {"crash", "restart", "put", "get"} <= kinds
@@ -413,131 +410,3 @@ async def test_relay_fallback_covers_dead_relay(tmp_path):
         assert peers[0].unique_name in view["unreachable"]
     finally:
         await c.stop()
-
-
-# ----------------------------------------------------------------------
-# claim_check round-12 gates + compact-line survival
-# ----------------------------------------------------------------------
-
-
-def _good_scale_block():
-    probe = {
-        "converge_s": 2.2, "detect_s": 3.8, "election_s": 5.4,
-        "bytes_per_node_s": 20000.0,
-    }
-    return {
-        "ns": [16, 64, 128],
-        "matrix": {"16": {"delta": dict(probe)},
-                   "64": {"delta": dict(probe)},
-                   "128": {"delta": dict(probe)}},
-        "churn": {"ok": True, "failures": [], "crash_restart_pairs": 9},
-        "bytes_vs_full_by_n": {"16": 1.0, "64": 0.35, "128": 0.27},
-        "detect_ratio_vs_small_n": 1.4,
-        "metrics_wall_ratio_vs_small_n": 1.2,
-        "straggler_serial_vs_relay": 3.9,
-        "scale_converge_s": 2.2,
-        "scale_detect_s": 3.8,
-        "scale_election_s": 5.4,
-        "scale_bytes_per_node_s": 20000.0,
-        "verdicts": {}, "scale_ok": True,
-    }
-
-
-def _artifact(tmp_path, name, doc):
-    path = str(tmp_path / f"{name}.json")
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return path
-
-
-def test_claim_check_scale_block(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    good = _good_scale_block()
-    ok = _artifact(tmp_path, "BENCH_r12", {
-        "matrix": {"control_plane_scale": good, "cluster_serving": {}},
-    })
-    assert cc.check_scale_block(ok) == []
-    # pre-round-12 artifacts are exempt
-    old = _artifact(tmp_path, "BENCH_r11", {
-        "matrix": {"cluster_serving": {}},
-    })
-    assert cc.check_scale_block(old) == []
-    # wall-budget skip is honestly exempt
-    skip = _artifact(tmp_path, "BENCH_r13", {
-        "matrix": {"_skipped": {"control_plane_scale": "budget"},
-                   "cluster_serving": {}},
-    })
-    assert cc.check_scale_block(skip) == []
-    # losing the section silently is a violation
-    lost = _artifact(tmp_path, "BENCH_r14", {
-        "matrix": {"cluster_serving": {}},
-    })
-    assert any("no `control_plane_scale`" in p
-               for p in cc.check_scale_block(lost))
-    # delta NOT below full-table at 64 fails
-    bad = dict(good, bytes_vs_full_by_n={"16": 1.0, "64": 1.02,
-                                         "128": 0.4})
-    p = cc.check_scale_block(_artifact(tmp_path, "BENCH_r15", {
-        "matrix": {"control_plane_scale": bad},
-    }))
-    assert any("strictly below full-table" in x for x in p)
-    # detection blowing past 1.5x of small-N fails
-    bad = dict(good, detect_ratio_vs_small_n=1.7)
-    p = cc.check_scale_block(_artifact(tmp_path, "BENCH_r16", {
-        "matrix": {"control_plane_scale": bad},
-    }))
-    assert any("detect_ratio" in x for x in p)
-    # a red churn sweep fails
-    bad = dict(good, churn={"ok": False, "failures": ["x"],
-                            "crash_restart_pairs": 9})
-    p = cc.check_scale_block(_artifact(tmp_path, "BENCH_r17", {
-        "matrix": {"control_plane_scale": bad},
-    }))
-    assert any("churn" in x for x in p)
-    # a probe that timed out (None wall) is a violation, not a skip
-    bad = dict(good, scale_detect_s=None)
-    p = cc.check_scale_block(_artifact(tmp_path, "BENCH_r18", {
-        "matrix": {"control_plane_scale": bad},
-    }))
-    assert any("scale_detect_s" in x for x in p)
-
-
-def test_claim_check_scale_summary_only(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    def cap(name, summary):
-        return _artifact(tmp_path, name, {
-            "bench_summary_v1": True, "_summary_only": True,
-            "summary": summary,
-        })
-
-    ok = cap("BENCH_r20", {"scale_converge_s": 2.2,
-                           "scale_detect_s": 3.8,
-                           "scale_bytes_per_node_s": 20000.0,
-                           "scale_ok": True})
-    assert cc.check_scale_block(ok) == []
-    bad = cap("BENCH_r21", {"scale_converge_s": 2.2, "scale_ok": False})
-    assert any("scale_ok" in p for p in cc.check_scale_block(bad))
-    bad = cap("BENCH_r22", {"scale_detect_s": 0})
-    assert any("scale_detect_s" in p for p in cc.check_scale_block(bad))
-
-
-def test_compact_line_keeps_scale_keys():
-    """The last-resort compact-line trim must keep the keys the
-    round-12 summary-only gate reads."""
-    import bench
-
-    for key in ("scale_converge_s", "scale_detect_s",
-                "scale_bytes_per_node_s", "scale_ok"):
-        assert key in bench._COMPACT_KEEP_KEYS
-    summary = {k: "x" * 400 for k in bench._COMPACT_DROP_ORDER}
-    summary.update({k: 1.5 for k in bench._COMPACT_KEEP_KEYS})
-    summary["scale_ok"] = True
-    line = bench.compact_summary_line(
-        {"qps": 1.0}, "cpu", 4.0, summary
-    )
-    assert len(line) <= bench.COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert doc["summary"]["scale_ok"] is True
-    assert doc["summary"]["scale_detect_s"] == 1.5

@@ -1,6 +1,6 @@
 """Speculative decoding + step-granular continuous batching
 (inference/lm_server.py, inference/generate.batched_verify_step,
-ingress linger scaling, the round-21 claim_check gate).
+ingress linger scaling).
 
 The load-bearing contract is PROPOSAL INDEPENDENCE: verification
 commits only TARGET-greedy tokens, so any proposal stream — a perfect
@@ -12,7 +12,6 @@ batching adoption seam: a request adopted mid-`step()` (from an
 `on_token` callback, racing slot retirement) is delivered exactly
 once and never reads another slot's stale verify/chunk column."""
 
-import json
 
 import jax
 import jax.numpy as jnp
@@ -501,89 +500,3 @@ def test_summarize_tpot_none_when_nothing_streamed():
                     e2e_s=0.2, deadline_met=True)]
     s = summarize(rows, 1.0)
     assert s["tpot_ms"] == {"p50": None, "p95": None, "p99": None}
-
-
-# ----------------------------------------------------------------------
-# the round-21 claim_check gate
-# ----------------------------------------------------------------------
-
-def test_claim_check_specdec_gate(tmp_path):
-    """A healthy block passes, skips and pre-round-21 artifacts are
-    exempt, and each gutted variant (token drift, acceptance
-    accounting drift, sub-break-even ship, missing auto-disable,
-    drain-beats-overlap, red verdicts) is named in a violation."""
-    from dml_tpu.tools import claim_check as cc
-
-    ok_spec = {
-        "outputs_equal": True,
-        "accept_rate": 0.84,
-        "declared_accept": 0.8,
-        "speedup": 2.5,
-        "auto_disable": {
-            "disabled": True, "reason": "acceptance",
-            "outputs_equal": True,
-        },
-        "verdict_green": True,
-    }
-    ok_cb = {
-        "outputs_equal": True,
-        "drain_vs_overlap_p99": 1.6,
-        "ttft_p99_overlap_ms": 340.0,
-        "verdict_green": True,
-    }
-    ok = {"tok_s_sharded": 100.0, "specdec": ok_spec, "cb": ok_cb}
-
-    def art(name, doc):
-        p = str(tmp_path / name)
-        with open(p, "w") as f:
-            json.dump(doc, f)
-        return p
-
-    assert cc.check_specdec_block(
-        art("ok.json", {"matrix": {"cluster_lm_sharded": ok}})) == []
-    assert cc.check_specdec_block(art("skip.json", {
-        "matrix": {"_skipped": {"cluster_lm_sharded": "wall budget"},
-                   "cluster_serving": {}},
-    })) == []
-    assert cc.check_specdec_block(art(
-        "BENCH_r20.json", {"matrix": {"cluster_serving": {}}})) == []
-    problems = cc.check_specdec_block(
-        art("lost.json", {"matrix": {"cluster_serving": {}}}))
-    assert any("no `cluster_lm_sharded` section" in p for p in problems)
-    cases = [
-        (dict(ok, specdec=dict(ok_spec, outputs_equal=False)),
-         "outputs_equal"),
-        (dict(ok, specdec=dict(ok_spec, accept_rate=0.0)),
-         "accept_rate"),
-        (dict(ok, specdec=dict(ok_spec, accept_rate=0.4)),
-         "declared"),
-        (dict(ok, specdec=dict(ok_spec, speedup=0.9)), "speedup"),
-        (dict(ok, specdec=dict(
-            ok_spec, auto_disable={"disabled": False,
-                                   "outputs_equal": True})),
-         "break-even"),
-        (dict(ok, specdec=dict(ok_spec, verdict_green=False)),
-         "verdict_green"),
-        (dict(ok, cb=dict(ok_cb, drain_vs_overlap_p99=0.9)),
-         "drain_vs_overlap_p99"),
-        (dict(ok, cb=dict(ok_cb, outputs_equal=None)), "adoption"),
-        ({"tok_s_sharded": 100.0, "cb": ok_cb}, "must carry"),
-    ]
-    for i, (block, needle) in enumerate(cases):
-        problems = cc.check_specdec_block(
-            art(f"bad{i}.json", {"matrix": {"cluster_lm_sharded": block}}))
-        assert any(needle in p for p in problems), (needle, problems)
-    # summary-only driver captures gate on the compact-line keys:
-    # present-but-bad fails, absent/None passes (a trimmed tail is
-    # not a violation)
-    problems = cc.check_specdec_block(art("sum.json", {
-        "_summary_only": True,
-        "summary": {"lm_specdec_speedup": 0.7,
-                    "lm_specdec_accept": 1.4,
-                    "lm_cb_ttft_ms": -1.0},
-    }))
-    assert len(problems) == 3
-    assert cc.check_specdec_block(art("sum_none.json", {
-        "_summary_only": True,
-        "summary": {"lm_specdec_speedup": None},
-    })) == []

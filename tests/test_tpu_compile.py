@@ -263,7 +263,7 @@ def test_chunk_program_of_a_grouped_bf16_config_holds_the_kernel(
     slots, max_len = 16, 4096
     srv = object.__new__(LMServer)
     srv.cfg, srv.max_len, srv.chunk, srv.temperature = cfg, max_len, 32, 0.0
-    srv._mesh = srv._gather_shardings = None
+    srv._mesh = None
     model = TransformerLM(
         vocab_size=cfg.vocab_size, d_model=cfg.d_model, n_heads=cfg.n_heads,
         n_layers=cfg.n_layers, d_ff=cfg.d_ff, dtype=cfg.dtype,
@@ -318,7 +318,7 @@ def test_diffusion_dispatch_of_the_sdar_config_holds_the_kernels(
     slots, max_len = 32, 4096
     srv = object.__new__(LMServer)
     srv.cfg, srv.max_len, srv.max_slots = cfg, max_len, slots
-    srv._mesh = srv._gather_shardings = None
+    srv._mesh = None
     srv.diffusion = BlockDiffusion(steps=2, mask_token_id=151669)
     srv.blocks_per_dispatch = 2
     cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))

@@ -734,7 +734,7 @@ def test_disagg_ingress_request_yields_full_stitched_trace(
     }
     params, cfg = lm_spec_parts(SPEC)
     mesh = make_mesh(MeshSpec(dp=1, tp=2), devices=jax.devices()[:2])
-    be_dis = sharded_lm_backend(SPEC, mesh, form="resident")
+    be_dis = sharded_lm_backend(SPEC, mesh)
     be_single = LMBackend(params, cfg, max_new_tokens=8, max_slots=2,
                           max_len=64, chunk=4)
     prefill_be = LMPrefillBackend(params, cfg, max_len=64)
@@ -815,121 +815,6 @@ def test_disagg_ingress_request_yields_full_stitched_trace(
             be_single.close()
 
     asyncio.run(run())
-
-
-# ----------------------------------------------------------------------
-# claim_check: the round-14 tracing gate + compact-line survival
-# ----------------------------------------------------------------------
-
-
-GOOD_TRACING = {
-    "sample_rate": 1.0,
-    "spans_collected": 900,
-    "traces_collected": 120,
-    "p99_attribution": {
-        "n": 3, "mean_e2e_ms": 140.0,
-        "stage_ms": {"formation": 90.0, "infer": 40.0},
-        "attributed_ms": 133.0, "attributed_fraction": 0.95,
-    },
-    "p99_attrib_ok": True,
-    "deadline_misses": 4,
-    "miss_exemplar_coverage": 1.0,
-    "recorder": {"span_budget": 4096, "peak_spans": 3200,
-                 "dropped": 0, "recorded": 3200,
-                 "within_budget": True},
-    "overhead": {"p50_ms_traced": 40.0, "p99_ms_traced": 140.0,
-                 "p50_ms_untraced": 39.0, "p99_ms_untraced": 138.0,
-                 "p99_traced_vs_untraced": 1.014},
-}
-
-
-def _artifact(tmp_path, name, doc):
-    p = str(tmp_path / f"{name}.json")
-    with open(p, "w") as f:
-        json.dump(doc, f)
-    return p
-
-
-@pytest.mark.tracing
-def test_claim_check_tracing_block(tmp_path):
-    from dml_tpu.tools import claim_check as cc
-
-    def art(name, tracing=GOOD_TRACING, extra=None):
-        block = {"p99_ms": 150.0, "tracing": tracing}
-        if tracing is None:
-            block.pop("tracing")
-        block.update(extra or {})
-        return _artifact(tmp_path, name, {
-            "matrix": {"request_serving": block},
-        })
-
-    assert cc.check_tracing_block(art("BENCH_r14a")) == []
-    # pre-round-14 artifacts exempt
-    assert cc.check_tracing_block(_artifact(
-        tmp_path, "BENCH_r13x",
-        {"matrix": {"request_serving": {"p99_ms": 1.0}}},
-    )) == []
-    # skipped section exempt
-    assert cc.check_tracing_block(_artifact(tmp_path, "BENCH_r14b", {
-        "matrix": {"_skipped": {"request_serving": "budget"}},
-    })) == []
-    # missing tracing block from round 14 fails
-    bad = cc.check_tracing_block(art("BENCH_r14c", tracing=None))
-    assert any("without a `tracing` block" in p for p in bad)
-    # attribution below 0.9 fails both gates
-    weak = dict(GOOD_TRACING, p99_attrib_ok=False, p99_attribution=dict(
-        GOOD_TRACING["p99_attribution"], attributed_fraction=0.6))
-    bad = cc.check_tracing_block(art("BENCH_r14d", tracing=weak))
-    assert any("p99_attrib_ok" in p for p in bad)
-    assert any("attributed_fraction" in p for p in bad)
-    # a deadline miss without an exemplar trace fails
-    bad = cc.check_tracing_block(art(
-        "BENCH_r14e",
-        tracing=dict(GOOD_TRACING, miss_exemplar_coverage=0.75)))
-    assert any("miss_exemplar_coverage" in p for p in bad)
-    # blown span budget fails
-    bad = cc.check_tracing_block(art(
-        "BENCH_r14f",
-        tracing=dict(GOOD_TRACING, recorder=dict(
-            GOOD_TRACING["recorder"], within_budget=False))))
-    assert any("within_budget" in p for p in bad)
-    # unmeasured or pathological overhead fails
-    bad = cc.check_tracing_block(art(
-        "BENCH_r14g",
-        tracing=dict(GOOD_TRACING, overhead={})))
-    assert any("overhead" in p for p in bad)
-    bad = cc.check_tracing_block(art(
-        "BENCH_r14h",
-        tracing=dict(GOOD_TRACING, overhead=dict(
-            GOOD_TRACING["overhead"], p99_traced_vs_untraced=3.2))))
-    assert any("perturbing" in p for p in bad)
-    # summary-only capture gates on the compact key
-    assert cc.check_tracing_block(_artifact(tmp_path, "BENCH_r14i", {
-        "_summary_only": True,
-        "summary": {"trace_p99_attrib_ok": True},
-    })) == []
-    bad = cc.check_tracing_block(_artifact(tmp_path, "BENCH_r14j", {
-        "_summary_only": True,
-        "summary": {"trace_p99_attrib_ok": False},
-    }))
-    assert any("trace_p99_attrib_ok" in p for p in bad)
-
-
-@pytest.mark.tracing
-def test_compact_summary_trim_keeps_tracing_key():
-    """The last-resort compact-line trim must keep the key the
-    round-14 summary-only gate reads."""
-    import bench
-
-    assert "trace_p99_attrib_ok" in bench._COMPACT_KEEP_KEYS
-    summary = {k: 1 for k in bench._COMPACT_KEEP_KEYS}
-    summary.update({f"pad_{i}": "x" * 40 for i in range(60)})
-    line = bench.compact_summary_line(
-        {"qps": 1.0}, "cpu", 1.0, summary
-    )
-    assert len(line) <= bench.COMPACT_SUMMARY_BUDGET
-    doc = json.loads(line)
-    assert "trace_p99_attrib_ok" in doc["summary"]
 
 
 @pytest.mark.tracing

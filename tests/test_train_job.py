@@ -16,7 +16,6 @@ Layers covered:
   restore re-shard at a step boundary with the LR rescaled; a leader
   killed mid-run is adopted from the store checkpoint by the promoted
   coordinator with no step lost or double-applied (slow)
-- bench/claim_check: the round-22 cluster_training artifact gate
 """
 
 import asyncio
@@ -360,70 +359,3 @@ def test_leader_kill_adoption_no_step_lost(tmp_path):
             await cluster.stop()
 
     asyncio.run(run())
-
-
-# ----------------------------------------------------------------------
-# (d) the round-22 artifact gate
-# ----------------------------------------------------------------------
-
-def test_claim_check_train_gate(tmp_path):
-    """The round-22 artifact gate: a healthy block passes, a skip is
-    exempt, pre-round-22 artifacts are exempt, and each gutted
-    variant (flat scaling, shrinking curve, no join re-shard, a
-    restart, red sweep, interactive p99 past its deadline) is named
-    in a violation."""
-    from dml_tpu.tools import claim_check as cc
-
-    ok = {
-        "scaleout_gain": 2.4,
-        "scaling_curve": [
-            {"world": 1, "examples_per_s": 40.0},
-            {"world": 3, "examples_per_s": 96.0},
-        ],
-        "join_reshards": 2,
-        "restarts": 0,
-        "sweep_ok": True,
-        "mixed": {"interactive_p99_with_trainer_s": 0.3,
-                  "interactive_deadline_s": 2.0},
-        "train_elastic_ok": True,
-    }
-
-    def art(name, doc):
-        p = str(tmp_path / name)
-        with open(p, "w") as f:
-            json.dump(doc, f)
-        return p
-
-    assert cc.check_train_block(
-        art("ok.json", {"matrix": {"cluster_training": ok}})) == []
-    assert cc.check_train_block(art("skip.json", {
-        "matrix": {"_skipped": {"cluster_training": "wall budget"},
-                   "cluster_serving": {}},
-    })) == []
-    assert cc.check_train_block(art(
-        "BENCH_r21.json", {"matrix": {"cluster_serving": {}}})) == []
-    problems = cc.check_train_block(
-        art("lost.json", {"matrix": {"cluster_serving": {}}}))
-    assert any("no `cluster_training` section" in p for p in problems)
-    cases = [
-        (dict(ok, scaleout_gain=0.98), "scaleout_gain"),
-        (dict(ok, scaling_curve=[
-            {"world": 3, "examples_per_s": 90.0},
-            {"world": 1, "examples_per_s": 40.0}]), "world"),
-        (dict(ok, join_reshards=0), "join_reshards"),
-        (dict(ok, restarts=1), "restarts"),
-        (dict(ok, sweep_ok=False), "sweep_ok"),
-        (dict(ok, mixed={"interactive_p99_with_trainer_s": 3.1,
-                         "interactive_deadline_s": 2.0}), "p99"),
-        (dict(ok, train_elastic_ok=False), "own"),
-    ]
-    for i, (block, needle) in enumerate(cases):
-        problems = cc.check_train_block(art(
-            f"bad{i}.json", {"matrix": {"cluster_training": block}}))
-        assert any(needle in p for p in problems), (needle, problems)
-    # summary-only driver captures gate on the compact-line keys
-    problems = cc.check_train_block(art("sum.json", {
-        "_summary_only": True,
-        "summary": {"train_elastic_ok": False, "train_step_qps": 0.0},
-    }))
-    assert len(problems) == 2
