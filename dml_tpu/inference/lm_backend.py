@@ -259,7 +259,13 @@ def lm_spec_parts(spec: Dict[str, Any]):
     (another head size, a rope base, q/k norms, gated top-k experts,
     the block-causal mask, `param_dtype`) gets `init_lm_params`' tree,
     its matrices stored in `param_dtype`, every layer an expert layer
-    where `num_experts` is set."""
+    where `num_experts` is set.
+
+    What is returned is the tree as STORED. A server does not multiply
+    a float32 tree under bfloat16 compute as stored, nor cast it in
+    every program: `LMServer` keeps `quantize.resident_params(params,
+    cfg.dtype)`, the block matrices and expert tensors cast once, and
+    holds no reference to this tree."""
     import jax
     import jax.numpy as jnp
 

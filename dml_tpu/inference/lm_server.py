@@ -104,6 +104,7 @@ from .generate import (
     init_cache,
     prefill,
 )
+from .quantize import quantized_bytes, resident_params
 
 log = logging.getLogger(__name__)
 
@@ -169,6 +170,12 @@ _M_DELIVER = METRICS.histogram(
     "lm_server_deliver_seconds",
     "a dispatch's token delivery: first tokens, every request's "
     "on_token callbacks, retirements")
+_M_WEIGHT_BYTES = METRICS.gauge(
+    "lm_server_weight_bytes",
+    "the weight tree's bytes by form= handed (as the server was given "
+    "it) | resident (as it keeps it: block matrices and expert tensors "
+    "stored wider than the compute dtype cast to it); equal where "
+    "nothing had to be cast")
 _M_SLOTS = METRICS.gauge(
     "lm_server_slots_active", "occupied decode slots")
 _M_SLOTS_TOTAL = METRICS.gauge(
@@ -237,11 +244,14 @@ def _bucket(n: int, lo: int = 16) -> int:
 
 
 #: the shortest prefill bucket (tokens a row) of a server whose max_len
-#: allows it. Under ~512 tokens a prefill call is bound by reading the
-#: weights once (6.15 GB of float32 = 7.5 ms on the benchmark's dense
-#: decoder, 7.25 GB of experts = 8.9 ms on its expert model, at
-#: 819 GB/s), so a shorter bucket buys nothing a call, and it would be
-#: one more compiled shape a row count. It is also the charge a group
+#: allows it. It was chosen (PR 29) when a call under ~512 tokens was
+#: bound by reading the weights once: 6.15 GB of float32 on the
+#: benchmark's dense decoder, 7.25 GB of experts on its expert model.
+#: Since the dense decoder's matrices are resident in bfloat16 (3.6 GB
+#: a call) a 512-token row is bound by its matmuls there (a (512, 1)
+#: call: 10.2 -> 9.5 ms, PERF.md PR 31), so a shorter bucket would pay
+#: for short prompts; it stays because it is one more compiled shape a
+#: row count and the set-ups warm these. It is also the charge a group
 #: costs in `_prefill_groups`: every call re-reads every weight.
 _BUCKET_FLOOR = 512
 
@@ -457,6 +467,20 @@ class BlockDiffusion:
             raise ValueError(f"unknown remasking {self.remasking!r}")
 
 
+def _hold_resident(params: Any, dtype, tree: str) -> Tuple[Any, int, int]:
+    """(`resident_params(params, dtype)`, bytes handed, bytes resident)
+    under a one-off `lm_weights_resident` loop span that carries both
+    counts (`tree` "target" or "draft"): a trace shows whether the
+    cast engaged, and equal counts say the tree was multiplied as
+    handed."""
+    with TRACER.loop_span("lm_weights_resident", tree=tree) as span:
+        handed = quantized_bytes(params)[0]
+        params = resident_params(params, dtype)
+        resident = quantized_bytes(params)[0]
+        span.label(handed_bytes=handed, resident_bytes=resident)
+    return params, handed, resident
+
+
 class LMServer:
     """Slot-based continuous batching over `batched_decode_step`.
 
@@ -464,6 +488,15 @@ class LMServer:
     >>> a = srv.submit(prompt_a, max_new_tokens=64)
     >>> b = srv.submit(prompt_b, max_new_tokens=32)
     >>> results = srv.run()          # {rid: np.ndarray of new tokens}
+
+    `self.params` is the tree the programs multiply, not the tree the
+    caller handed over: block matrices and expert tensors stored wider
+    than `cfg.dtype` are held in it (`quantize.resident_params`; the
+    embedding, norms, router, head and any int8 leaf are the caller's
+    arrays), cast once here and in no dispatch or prefill call. The server keeps no
+    reference to what it was handed, so a float32 checkpoint served in
+    bfloat16 is freed when its caller lets go of it
+    (`lm_server_weight_bytes{form=handed|resident}` has both sizes).
     """
 
     def __init__(
@@ -485,14 +518,17 @@ class LMServer:
             raise ValueError("chunk must be >= 1")
         if max_slots < 1:
             raise ValueError("max_slots must be >= 1")
-        self.params = params
+        self.params, handed, resident = _hold_resident(
+            params, cfg.dtype, "target")
+        _M_WEIGHT_BYTES.set(handed, form="handed")
+        _M_WEIGHT_BYTES.set(resident, form="resident")
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_len = max_len
         self.chunk = chunk
         self.temperature = temperature
         self.top_k = top_k
-        self._mesh = _mesh_of(params)
+        self._mesh = _mesh_of(self.params)
         self.diffusion = diffusion
         if (diffusion is None) != (cfg.mask_block == 1):
             raise ValueError(
@@ -598,7 +634,7 @@ class LMServer:
         if diffusion is not None:
             routers = [
                 blk["moe"]["router"]["kernel"].shape[-1]
-                for name, blk in params.items()
+                for name, blk in self.params.items()
                 if name.startswith("block_") and "moe" in blk
             ]
             # (expert layers, routed experts): the routing counts' shape
@@ -697,6 +733,9 @@ class LMServer:
                 "enable_spec_decode on a busy server: active slots "
                 "have no draft cache rows to verify against"
             )
+        if draft_params is not None:
+            draft_params = _hold_resident(
+                draft_params, draft_cfg.dtype, "draft")[0]
         self._spec = _SpecState(
             k=int(k), draft_params=draft_params, draft_cfg=draft_cfg,
             proposer=proposer, min_accept=float(min_accept),

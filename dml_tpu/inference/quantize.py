@@ -23,6 +23,14 @@ float (tiny, or precision-sensitive). The quantized tree is a drop-in
 params pytree for `generate`/`decode_step`/`prefill`: `kernel_of`
 dequantizes at use.
 
+A float tree stored wider than it is computed in is not cast at use by
+a server: `resident_params` holds the same block matrices and expert
+tensors in the compute dtype once, when an `LMServer` is built, so a
+float32 checkpoint served in bfloat16 is rounded one time and not in
+every program that multiplies it (`kernel_of`'s `astype` is then a
+no-op). Quantized leaves stay as they are: an int8 tree is still
+dequantized at use.
+
 Net-new vs the reference (it serves f32 Keras CNNs on CPU,
 models.py:23-71).
 """
@@ -34,8 +42,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-# params keys quantized at each block level
+# params keys quantized at each block level: 2-D kernels, the stacked
+# tensors of an expert layer (w_gate: gated experts only), the head
 _BLOCK_MATMULS = ("qkv", "proj", "up", "down")
+_EXPERT_MATMULS = ("w_up", "w_down", "w_gate")
 _TOP_MATMULS = ("lm_head",)
 
 
@@ -57,34 +67,73 @@ def _dequant(t: Dict[str, jax.Array], dtype) -> jax.Array:
     return (t["q"].astype(jnp.float32) * t["scale"]).astype(dtype)
 
 
+def _map_block_matmuls(params: Dict[str, Any], kernel_fn, expert_fn):
+    """The same tree with `kernel_fn` applied to every block's 2-D
+    matmul kernel (`_BLOCK_MATMULS`) and `expert_fn` to every stacked
+    expert tensor (`_EXPERT_MATMULS`): the leaves `_apply_block` and
+    `expert_ffn` read through `kernel_of`. Everything else (embedding,
+    norms, router, head) is the caller's own object."""
+    out: Dict[str, Any] = {}
+    for name, sub in params.items():
+        if not name.startswith("block_"):
+            out[name] = sub
+            continue
+        blk: Dict[str, Any] = {}
+        for k, v in sub.items():
+            if k in _BLOCK_MATMULS:
+                blk[k] = {**v, "kernel": kernel_fn(v["kernel"])}
+            elif k == "moe":
+                blk[k] = {**v, **{
+                    w: expert_fn(v[w]) for w in _EXPERT_MATMULS if w in v}}
+            else:
+                blk[k] = v
+        out[name] = blk
+    return out
+
+
 def quantize_lm_params(params: Dict[str, Any]) -> Dict[str, Any]:
     """TransformerLM params -> same-structure tree with the big matmul
     kernels replaced by {"q": int8, "scale": f32} pairs. Consumable by
     inference/generate.py (which dequantizes at use); training keeps
     the float tree."""
-    out: Dict[str, Any] = {}
-    for name, sub in params.items():
-        if name.startswith("block_"):
-            blk: Dict[str, Any] = {}
-            for k, v in sub.items():
-                if k in _BLOCK_MATMULS:
-                    blk[k] = {"kernel": _quant_tensor(v["kernel"], (-1,))}
-                elif k == "moe":
-                    moe = dict(v)
-                    # per-(expert, out-channel) scales: [E, d, d_ff]
-                    # keeps axes 0 and 2
-                    for w in ("w_up", "w_down", "w_gate"):
-                        if w in v:  # w_gate: gated experts only
-                            moe[w] = _quant_tensor(v[w], (0, 2))
-                    blk[k] = moe
-                else:
-                    blk[k] = v
-            out[name] = blk
-        elif name in _TOP_MATMULS:
-            out[name] = {"kernel": _quant_tensor(sub["kernel"], (-1,))}
-        else:
-            out[name] = sub
+    out = _map_block_matmuls(
+        params,
+        lambda w: _quant_tensor(w, (-1,)),
+        # per-(expert, out-channel) scales: [E, d, d_ff] keeps axes 0, 2
+        lambda w: _quant_tensor(w, (0, 2)),
+    )
+    for name in _TOP_MATMULS:
+        if name in out:
+            out[name] = {**out[name], "kernel": _quant_tensor(
+                out[name]["kernel"], (-1,))}
     return out
+
+
+def resident_params(params: Dict[str, Any], dtype) -> Dict[str, Any]:
+    """The tree a server keeps: every leaf that `kernel_of(node, dtype)`
+    would cast on its way into a block's matmul (`_BLOCK_MATMULS`, the
+    stacked expert tensors) held in `dtype` already, so the programs
+    that take the tree multiply what they are handed and no dispatch
+    makes a copy. The values are the roundings `astype` makes at use,
+    made once.
+
+    Left exactly as handed (the same arrays): the embedding, every
+    norm, the router, `lm_head` (a float32 head multiplies in float32:
+    `generate._lm_head`), every quantized leaf, and every leaf no wider
+    than `dtype` (in it already, or stored narrower than the compute
+    dtype: that one is widened at use as before, so the resident tree
+    never takes more bytes than the handed one). A tree stored in the
+    compute dtype, a float32 tree under float32 compute and an int8
+    tree come back leaf for leaf. A cast leaf keeps the sharding it was
+    placed with."""
+    dtype = jnp.dtype(dtype)
+
+    def hold(w):
+        if is_quantized(w) or w.dtype.itemsize <= dtype.itemsize:
+            return w
+        return w.astype(dtype)
+
+    return _map_block_matmuls(params, hold, hold)
 
 
 def is_quantized(leaf: Any) -> bool:
@@ -98,7 +147,10 @@ def kernel_of(node: Any, dtype) -> jax.Array:
     bare tensor (MoE w_up/w_down), or the quantized forms of either;
     returns the kernel in `dtype` regardless — the generate path's one
     weight-access point, so quantized and float trees serve
-    identically."""
+    identically. A quantized kernel is dequantized here, at use; a
+    float one in another dtype is cast here (`generate` on a float32
+    checkpoint). A server's tree is not narrowed here: `resident_params`
+    has made that cast once and this `astype` returns its operand."""
     kern = (
         node["kernel"]
         if isinstance(node, dict) and "kernel" in node

@@ -197,6 +197,7 @@ line when you add the metric.
     lm_server_step_seconds           decode step wall
     lm_server_steps_total            decode steps executed
     lm_server_tokens_fixed_total     block-diffusion tokens fixed and delivered
+    lm_server_weight_bytes           weight tree bytes by form= handed|resident
     lm_sharded_batches_total         LM batches on a group engine by mode
     lm_sharded_prefill_slabs_total   KV slabs built by prefill workers
     lm_sharded_tokens_total          tokens from group-sharded serving

@@ -115,6 +115,7 @@ SPAN_NAMES = (
     "worker_put",        # worker: a batch's output write + store PUT
     "store_op_put",      # replicated store PUT, every one (untraced too)
     "store_op_get",      # replicated store GET, every one (untraced too)
+    "lm_weights_resident",  # LMServer built: the tree cast to its resident form
     "lm_idle",           # LM driver thread waiting with no work
     "lm_submit",         # LM driver: submit_many of the tickets taken this round
     "lm_step",           # one decode dispatch, entry to exit

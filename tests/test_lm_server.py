@@ -594,12 +594,14 @@ def test_every_chunk_step_holds_its_five_phases(served):
                                       if d["name"] == "lm_deliver"][0]["lb"]["tokens"]
     # what no step delivered, `_flush_firsts` did: a readback with no
     # parent (the budget-1 request retired at placement), and only a
-    # readback or a placement at submit may stand outside a step
+    # readback, a placement at submit or the constructor's one-off
+    # cast of the tree may stand outside a step
     total = sum(b for _, b in served[1])
     assert served[0].tokens_delivered == total
     assert 0 < sum(st["lb"]["tokens"] for st in steps) <= total
     assert {d["name"] for d in spans if not d["par"]} <= {
-        "lm_step", "lm_readback", "lm_place", "lm_request"}
+        "lm_step", "lm_readback", "lm_place", "lm_request",
+        "lm_weights_resident"}
 
 
 def test_prefill_groups_count_prompt_and_padded_tokens(served):
