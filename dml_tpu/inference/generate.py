@@ -42,6 +42,50 @@ from .quantize import kernel_of
 
 RMS_EPS = 1e-6  # flax nn.RMSNorm default, as used by TransformerLM
 
+#: what one layer of a `layer_pattern` can be (the letters of the
+#: `nemotron_h` configs' `hybrid_override_pattern`): a Mamba-2
+#: state-space mixer, an attention mixer, an expert feed-forward mixer
+LAYER_KINDS = {"M": "state-space", "*": "attention", "E": "expert"}
+ROUTER_SCORING = ("softmax", "sigmoid")
+ACTIVATIONS = ("silu", "relu2")
+
+
+@dataclass(frozen=True)
+class SSMConfig:
+    """A Mamba-2 mixer's sizes: `heads` x `head_dim` inner channels,
+    `groups` groups of heads sharing one B and one C of `state`
+    numbers, a causal depthwise convolution over the last
+    `conv_kernel` positions, the prefill scan's `chunk`."""
+
+    heads: int
+    head_dim: int
+    state: int
+    groups: int = 1
+    conv_kernel: int = 4
+    chunk: int = 128
+
+    def __post_init__(self):
+        if min(self.heads, self.head_dim, self.state, self.groups,
+               self.chunk) < 1 or self.conv_kernel < 2:
+            raise ValueError(f"state-space sizes {self}")
+        if self.heads % self.groups:
+            raise ValueError(
+                f"{self.groups} groups do not divide {self.heads} heads")
+
+    @property
+    def d_inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """Channels the convolution runs over: x, B and C."""
+        return self.d_inner + 2 * self.groups * self.state
+
+    @property
+    def in_width(self) -> int:
+        """Columns of `in_proj`: z | x B C | dt."""
+        return self.d_inner + self.conv_width + self.heads
+
 
 @dataclass(frozen=True)
 class LMConfig:
@@ -82,6 +126,20 @@ class LMConfig:
     # j // block_length <= i // block_length
     attention_mask: str = "causal"
     block_length: int = 1
+    # One mixer a layer, by kind (`LAYER_KINDS`), where a model is no
+    # stack of "attention, then feed-forward" blocks; None = such a
+    # stack. A pattern's block holds ONE norm (`ln`) and its mixer.
+    layer_pattern: Optional[str] = None
+    ssm: Optional[SSMConfig] = None
+    rope: bool = True  # False: q and k are attended as projected
+    norm_eps: float = RMS_EPS
+    # the expert layers' router: "softmax" (top-k of the softmax,
+    # renormalised) or "sigmoid" (top-k of sigmoid + the tree's
+    # selection bias, gates the chosen sigmoids renormalised), gates
+    # times `router_scale`; the feed-forward activation
+    router_scoring: str = "softmax"
+    router_scale: float = 1.0
+    activation: str = "silu"
 
     def __post_init__(self):
         kv = self.n_kv_heads
@@ -99,6 +157,35 @@ class LMConfig:
             raise ValueError(
                 f"block_length {self.block_length} under "
                 f"{self.attention_mask!r} attention")
+        if self.router_scoring not in ROUTER_SCORING:
+            raise ValueError(f"unknown router scoring {self.router_scoring!r}")
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"unknown activation {self.activation!r}")
+        pat = self.layer_pattern
+        if pat is not None:
+            if not pat or set(pat) - set(LAYER_KINDS):
+                raise ValueError(
+                    f"layer_pattern {pat!r}: one of {sorted(LAYER_KINDS)} "
+                    f"a layer")
+            if len(pat) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern {pat!r} has {len(pat)} layers, "
+                    f"n_layers is {self.n_layers}")
+        if self.has_state != (self.ssm is not None):
+            raise ValueError(
+                "state-space sizes (`ssm`) come with a layer_pattern "
+                "that holds a state-space layer, and only with one")
+
+    @property
+    def kinds(self) -> Tuple[Optional[str], ...]:
+        """Each layer's kind (`LAYER_KINDS`); None = a classic block."""
+        return tuple(self.layer_pattern or (None,) * self.n_layers)
+
+    @property
+    def has_state(self) -> bool:
+        """Whether a sequence carries recurrent state beside K/V rows:
+        state that cannot be cut by token or rolled back."""
+        return "M" in (self.layer_pattern or "")
 
     @property
     def head_dim(self) -> int:
@@ -133,26 +220,64 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
     makes every per-step cache write one contiguous D-row per head.
     Scales live time-on-lanes ([B, KV, 1, max_len]) because the
     kernel folds them into [G, T-block] score rows — storing them
-    that way saves a per-step transpose of every scale plane."""
+    that way saves a per-step transpose of every scale plane.
+
+    Under a `layer_pattern` a slot's state goes by the layer's kind:
+    K/V rows in an attention layer; in a state-space layer the last
+    `conv_kernel - 1` rows of the convolution's input ([B, K-1, C], the
+    model's dtype) and the scan state ([B, H, P, N], float32: the
+    recurrence runs for as many steps as a sequence has tokens); nothing
+    in an expert layer, which has no entry."""
     shape = (batch, cfg.kv_heads, max_len, cfg.head_dim)
-    if cfg.kv_quant:
-        sshape = (batch, cfg.kv_heads, 1, max_len)
-        return {
-            f"block_{i}": {
+    sshape = (batch, cfg.kv_heads, 1, max_len)
+
+    def layer(kind):
+        if kind == "E":  # an expert layer carries nothing
+            return None
+        if kind == "M":
+            s = cfg.ssm
+            return {
+                "conv": jnp.zeros(
+                    (batch, s.conv_kernel - 1, s.conv_width), cfg.dtype),
+                "ssm": jnp.zeros(
+                    (batch, s.heads, s.head_dim, s.state), jnp.float32),
+            }
+        if cfg.kv_quant:
+            return {
                 "k_q": jnp.zeros(shape, jnp.int8),
                 "k_s": jnp.zeros(sshape, jnp.float32),
                 "v_q": jnp.zeros(shape, jnp.int8),
                 "v_s": jnp.zeros(sshape, jnp.float32),
             }
-            for i in range(cfg.n_layers)
-        }
-    return {
-        f"block_{i}": {
+        return {
             "k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype),
         }
-        for i in range(cfg.n_layers)
-    }
+
+    layers = {f"block_{i}": layer(k) for i, k in enumerate(cfg.kinds)}
+    return {name: lay for name, lay in layers.items() if lay is not None}
+
+
+def cache_rows(cache: Dict[str, Any]) -> int:
+    """Rows a slot's K/V planes hold (`max_len`), read off the first
+    attention layer's leaves; 0 for a cache with no attention layer."""
+    for lay in cache.values():
+        for key in ("k", "k_q"):
+            if key in lay:
+                return lay[key].shape[2]
+    return 0
+
+
+def state_bytes(cache: Dict[str, Any]) -> Dict[str, int]:
+    """Bytes of a slot-grid cache by kind of leaf: `kv` (attention
+    layers' rows and scales), `conv` and `scan` (a state-space layer's
+    convolution window and recurrent state)."""
+    out = {"kv": 0, "conv": 0, "scan": 0}
+    for lay in cache.values():
+        for key, leaf in lay.items():
+            kind = {"conv": "conv", "ssm": "scan"}.get(key, "kv")
+            out[kind] += int(leaf.size) * leaf.dtype.itemsize
+    return out
 
 
 def _kv_quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -172,14 +297,26 @@ def _kv_dequant(q: jax.Array, scale: jax.Array) -> jax.Array:
     return q.astype(jnp.float32) * scale
 
 
-def _rms_norm(x: jax.Array, scale: jax.Array, dtype) -> jax.Array:
+def _rms_norm(x: jax.Array, scale: jax.Array, dtype,
+              eps: float = RMS_EPS) -> jax.Array:
     # flax RMSNorm: reduce in f32, scale, cast back to module dtype
     xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + RMS_EPS)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (y * scale.astype(jnp.float32)).astype(dtype)
 
 
-_MOE_CHUNK = 4096  # tokens per expert-layer chunk at prefill
+def _activation(name: str):
+    """A feed-forward activation by its `lm_spec` name."""
+    if name == "relu2":
+        return lambda x: jnp.square(jax.nn.relu(x))
+    return jax.nn.silu
+
+
+_MOE_CHUNK = 4096  # tokens per expert-layer chunk at prefill, at most
+#: ... and (token, expert) assignments a chunk: the sorted copies and the
+#: grouped matmuls' float32 outputs are [tokens x k, width] whatever share
+#: of the experts is held (4,096 tokens at top-8; 1,408 at top-22)
+_MOE_ROWS = 32768
 
 
 def uses_grouped_kernel(mesh: Optional[Mesh] = None) -> bool:
@@ -239,32 +376,47 @@ def expert_ffn(
     first: int = 0,
     live: Optional[jax.Array] = None,  # [B] bool: rows that count
     mesh: Optional[Mesh] = None,
+    scoring: str = "softmax",
+    scale: float = 1.0,
+    activation: str = "silu",
 ) -> Tuple[jax.Array, jax.Array]:
     """The serve-time expert layer: dropless top-`k` routing over ALL
     the routed experts, computed for the experts this tree HOLDS.
 
-    Route: softmax over the router's E outputs in float32, the `k`
-    largest, their gates renormalised to sum to 1. No capacity, so no
-    dropped token (training-time capacity drops are a batching
-    artifact, not part of the learned function). Compute: the n * k
+    Route, in float32 over the router's E outputs: `scoring` "softmax"
+    takes the `k` largest of the softmax and renormalises them to sum
+    to 1; "sigmoid" takes the `k` experts with the largest sigmoid
+    plus the tree's selection bias (`router.bias`, for the choice
+    only) and renormalises the chosen sigmoids; either way times
+    `scale`. No capacity, so no dropped token (training-time capacity
+    drops are a batching artifact, not part of the learned function).
+    Compute: the n * k
     (token, expert) assignments are sorted by expert and run through
     ONE grouped matmul a matrix (`_grouped_matmul`: row groups of the
     sorted tokens against the stacked expert weights), so the FLOPs
     are those of the experts chosen and the weights read are those of
     the experts touched. Experts are gated
-    (`w_gate`: down(SiLU(gate x) * up x)) or plain (down(SiLU(up x)),
-    parallel/moe.py's MoEMLP), by what the tree holds.
+    (`w_gate`: down(act(gate x) * up x)) or plain (down(act(up x)),
+    parallel/moe.py's MoEMLP), by what the tree holds, `activation`
+    SiLU or squared ReLU. Where the tree holds `latent_down` and
+    `latent_up` the experts live in a latent width between the two
+    (x -> latent, experts, -> hidden; the router still reads x);
+    where it holds `shared_up` and `shared_down`, an ungated expert
+    every token takes is added in the hidden width.
 
     The tree holds `held = w_up.shape[0]` experts: routed experts
     `first .. first + held - 1`. Assignments to the others add
     nothing here, in the program and in the reference alike (on a
-    deployment they are another chip's part of the sum).
+    deployment they are another chip's part of the sum; the latent
+    up-projection is linear, so the shares still add up).
 
     Returns (out [B, T, d], counts [E] int32: assignments to each
     routed expert from the rows `live` marks, all rows by default).
-    Token runs over `_MOE_CHUNK` go through a `lax.map`, so the sorted
-    copies stay bounded at prefill."""
+    Token runs over the chunk (`_MOE_CHUNK` tokens, fewer where `k` is
+    large: `_MOE_ROWS` assignments) go through a `lax.map`, so the
+    sorted copies stay bounded at prefill."""
     router = moe["router"]["kernel"]
+    bias = moe["router"].get("bias")
     e = router.shape[-1]
     gated = "w_gate" in moe
     w_up = kernel_of(moe["w_up"], dtype)
@@ -274,16 +426,29 @@ def expert_ffn(
     if not 0 < k <= e or first < 0 or first + held > e:
         raise ValueError(
             f"experts {first}..{first + held - 1} top-{k} of {e} routed")
+    if scoring not in ROUTER_SCORING:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    act = _activation(activation)
+    latent = "latent_down" in moe
+    shared = "shared_up" in moe
 
     grouped = functools.partial(_grouped_matmul, mesh=mesh)
 
     def run(args):  # tok [n, d], counted [n] -> out [n, d], counts [E]
         tok, counted = args
         n = tok.shape[0]
-        gates = jax.nn.softmax(
-            tok.astype(jnp.float32) @ router.astype(jnp.float32), axis=-1)
-        top_g, top_i = jax.lax.top_k(gates, k)  # [n, k]
+        logits = tok.astype(jnp.float32) @ router.astype(jnp.float32)
+        if scoring == "softmax":
+            gates = jax.nn.softmax(logits, axis=-1)
+            top_g, top_i = jax.lax.top_k(gates, k)  # [n, k]
+        else:
+            gates = jax.nn.sigmoid(logits)
+            _, top_i = jax.lax.top_k(
+                gates if bias is None else gates + bias, k)
+            top_g = jnp.take_along_axis(gates, top_i, axis=-1)
         top_g = top_g / jnp.maximum(top_g.sum(-1, keepdims=True), 1e-9)
+        if scale != 1.0:
+            top_g = top_g * scale
         counts = jnp.zeros(e, jnp.int32).at[top_i.reshape(-1)].add(
             jnp.repeat(counted.astype(jnp.int32), k))
         local = top_i.reshape(-1) - first
@@ -291,12 +456,13 @@ def expert_ffn(
         key = jnp.where(here, local, held)  # absent experts sort last
         order = jnp.argsort(key)
         sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
-        xs = tok[order // k]  # [n * k, d], grouped by expert
+        src = tok @ kernel_of(moe["latent_down"], dtype) if latent else tok
+        xs = src[order // k]  # [n * k, d or latent], grouped by expert
         h = grouped(xs, w_up, sizes)
         if gated:
-            h = jax.nn.silu(grouped(xs, w_gate, sizes)) * h
+            h = act(grouped(xs, w_gate, sizes)) * h
         else:
-            h = jax.nn.silu(h)
+            h = act(h)
         o = grouped(h.astype(dtype), w_down, sizes)  # [n * k, d] f32
         g = jnp.where(here, top_g.reshape(-1), 0.0)[order]
         # rows past the last group hold nothing that counts: select,
@@ -304,20 +470,28 @@ def expert_ffn(
         o = jnp.where(g[:, None] > 0.0, o * g[:, None], 0.0)
         back = jnp.zeros(n * k, jnp.int32).at[order].set(
             jnp.arange(n * k, dtype=jnp.int32))
-        return o[back].reshape(n, k, -1).sum(1).astype(dtype), counts
+        out = o[back].reshape(n, k, -1).sum(1).astype(dtype)
+        if latent:
+            out = out @ kernel_of(moe["latent_up"], dtype)
+        if shared:
+            out = out + act(
+                tok @ kernel_of(moe["shared_up"], dtype)
+            ) @ kernel_of(moe["shared_down"], dtype)
+        return out, counts
 
     d = y.shape[-1]
     tok = y.reshape(-1, d)
     n = tok.shape[0]
     counted = jnp.ones(n, bool) if live is None else jnp.repeat(
         live, n // live.shape[0])
-    if n <= _MOE_CHUNK:
+    chunk = min(_MOE_CHUNK, max(128, _MOE_ROWS // k // 128 * 128))
+    if n <= chunk:
         out, counts = run((tok, counted))
         return out.reshape(y.shape), counts
-    pad = (-n) % _MOE_CHUNK
+    pad = (-n) % chunk
     out, counts = jax.lax.map(run, (
-        jnp.pad(tok, ((0, pad), (0, 0))).reshape(-1, _MOE_CHUNK, d),
-        jnp.pad(counted, (0, pad)).reshape(-1, _MOE_CHUNK),
+        jnp.pad(tok, ((0, pad), (0, 0))).reshape(-1, chunk, d),
+        jnp.pad(counted, (0, pad)).reshape(-1, chunk),
     ))
     return out.reshape(-1, d)[:n].reshape(y.shape), counts.sum(0)
 
@@ -330,6 +504,200 @@ def _moe_ffn(moe: Dict[str, Any], y: jax.Array, dtype, k: int = 2,
     return expert_ffn(moe, y, dtype, k, first)[0]
 
 
+def ssm_scan_chunked(
+    x: jax.Array,  # [B, T, H, P]
+    dt: jax.Array,  # [B, T, H] float32 step sizes; 0 = a position to skip
+    a: jax.Array,  # [H] float32, negative
+    bm: jax.Array,  # [B, T, G, N]
+    cm: jax.Array,  # [B, T, G, N]
+    h0: jax.Array,  # [B, H, P, N] float32
+    chunk: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """The Mamba-2 recurrence `h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x)
+    B_t`, `y_t = h_t C_t` over T positions, chunk-wise: inside a chunk
+    of `chunk` positions by matrix products under the decay mask
+    (`y_i += sum_{j<=i} (C_i . B_j) exp(sum_{j<m<=i} dt_m a) dt_j x_j`),
+    across chunks by carrying `h` (a `lax.scan` over the chunks'
+    summed states). Head h reads group `h // (H / G)`'s B and C. The
+    products take their operands in `x`'s dtype and accumulate in
+    float32; decays, cumulative sums and the carried state are
+    float32. Returns (y [B, T, H, P] float32, h_T [B, H, P, N]).
+
+    A position with dt = 0 leaves the state exactly as it was (decay
+    1, input 0), which is how a row padded to a bucket keeps the state
+    of its own length, and how T is padded to whole chunks here."""
+    b, t, hh, p = x.shape
+    g, n = bm.shape[2:]
+    r = hh // g
+    f32 = jnp.float32
+    pad = (-t) % chunk
+    if pad:
+        x, dt, bm, cm = (
+            jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+            for v in (x, dt, bm, cm))
+    c = (t + pad) // chunk
+    x = x.reshape(b, c, chunk, g, r, p)
+    bm = bm.reshape(b, c, chunk, g, n)
+    cm = cm.reshape(b, c, chunk, g, n)
+    dt = dt.reshape(b, c, chunk, g, r)
+    cum = jnp.cumsum(dt * a.reshape(g, r), axis=2)  # [B, C, L, G, R]
+    xdt = x * dt[..., None].astype(x.dtype)
+    # inside a chunk: position l takes from s <= l
+    scores = jnp.einsum("bclgn,bcsgn->bcgls", cm, bm,
+                        preferred_element_type=f32)
+    seg = cum[:, :, :, None] - cum[:, :, None, :]  # [B, C, L, S, G, R]
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None, None]
+    decay = jnp.exp(jnp.where(tri, seg, -jnp.inf))
+    w = scores[..., None].transpose(0, 1, 3, 4, 2, 5) * decay
+    y = jnp.einsum("bclsgr,bcsgrp->bclgrp", w.astype(x.dtype), xdt,
+                   preferred_element_type=f32)
+    # each chunk's own contribution to the state at its end
+    to_end = jnp.exp(cum[:, :, -1:] - cum)  # [B, C, L, G, R]
+    states = jnp.einsum(
+        "bclgn,bclgrp->bcgrpn", bm, xdt * to_end[..., None].astype(x.dtype),
+        preferred_element_type=f32)
+    whole = jnp.exp(cum[:, :, -1])  # [B, C, G, R]: a chunk's total decay
+
+    def carry(h, args):  # h: the state at a chunk's start
+        dec, st = args
+        return h * dec[..., None, None] + st, h
+
+    h_end, starts = jax.lax.scan(
+        carry, h0.reshape(b, g, r, p, n),
+        (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(states, 1, 0)))
+    starts = jnp.moveaxis(starts, 0, 1)  # [B, C, G, R, P, N]
+    y = y + jnp.einsum(
+        "bclgn,bcgrpn->bclgrp", cm, starts.astype(x.dtype),
+        preferred_element_type=f32) * jnp.exp(cum)[..., None]
+    return (y.reshape(b, t + pad, hh, p)[:, :t],
+            h_end.reshape(b, hh, p, n))
+
+
+def ssm_scan_step(x, dt, a, bm, cm, h):
+    """One position of `ssm_scan_chunked`'s recurrence, elementwise in
+    float32 over the state: x [B, H, P], dt [B, H], bm and cm
+    [B, G, N], h [B, H, P, N] -> (y [B, H, P], h')."""
+    b, hh, p = x.shape
+    g, n = bm.shape[1:]
+    f32 = jnp.float32
+    bh = jnp.repeat(bm.astype(f32), hh // g, axis=1)  # [B, H, N]
+    ch = jnp.repeat(cm.astype(f32), hh // g, axis=1)
+    h = h * jnp.exp(dt * a)[..., None, None] + (
+        (dt[..., None] * x.astype(f32))[..., None] * bh[:, :, None, :])
+    return jnp.sum(h * ch[:, :, None, :], axis=-1), h
+
+
+def ssm_mixer(
+    p: Dict[str, Any],  # a block's "ssm" subtree
+    cfg: LMConfig,
+    y: jax.Array,  # [B, T, d], normalised
+    state: Optional[Dict[str, jax.Array]] = None,
+    lengths: Optional[jax.Array] = None,  # [B] int32: rows' own lengths
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The Mamba-2 mixer in both its forms, which share everything but
+    the recurrence: `[z | xBC | dt] = in_proj y`; `xBC <- SiLU(conv(xBC)
+    + bias)`, a causal depthwise convolution over the last
+    `conv_kernel` positions; `dt <- softplus(dt + dt_bias)`, `A =
+    -exp(A_log)` a head; the recurrence (`ssm_scan_chunked` over T
+    positions from `state`, or `ssm_scan_step` for the one position of
+    a decode step); `+ D x`; the gate and the norm `RMSNorm(y *
+    SiLU(z))` over each group's channels; `out_proj`.
+
+    `state` is {"conv": the last K-1 rows of xBC BEFORE the
+    convolution [B, K-1, C], "ssm": h [B, H, P, N] float32}, None = a
+    sequence's start (zeros). Returns (out [B, T, d], the state after
+    the T positions). With `lengths` row b holds lengths[b] <= T real
+    positions: past them dt is 0, so h stays, and the convolution
+    window is taken at the row's own length (zeros on the left of a
+    row shorter than the window): the state a row padded to a bucket
+    hands back is the state of the unpadded row."""
+    s = cfg.ssm
+    b, t, _ = y.shape
+    f32 = jnp.float32
+    di, gn, kk = s.d_inner, s.groups * s.state, s.conv_kernel
+    zxbcdt = y @ kernel_of(p["in_proj"], cfg.dtype)
+    z = zxbcdt[..., :di]
+    xbc = zxbcdt[..., di:di + s.conv_width]
+    dt = zxbcdt[..., di + s.conv_width:]
+    left = (jnp.zeros((b, kk - 1, s.conv_width), xbc.dtype)
+            if state is None else state["conv"].astype(xbc.dtype))
+    full = jnp.concatenate([left, xbc], axis=1)  # [B, K-1+T, C]
+    w = p["conv"]["kernel"].astype(f32)  # [K, C]
+    conv = p["conv"]["bias"].astype(f32) + sum(
+        full[:, i:i + t].astype(f32) * w[i] for i in range(kk))
+    xbc = jax.nn.silu(conv).astype(cfg.dtype)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+    if lengths is None:
+        window = full[:, t:]
+    else:
+        dt = jnp.where(
+            jnp.arange(t)[None, :, None] < lengths[:, None, None], dt, 0.0)
+        window = jax.vmap(
+            lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, kk - 1, 0)
+        )(full, lengths.astype(jnp.int32))
+    a = -jnp.exp(p["A_log"].astype(f32))
+    x = xbc[..., :di].reshape(b, t, s.heads, s.head_dim)
+    bm = xbc[..., di:di + gn].reshape(b, t, s.groups, s.state)
+    cm = xbc[..., di + gn:].reshape(b, t, s.groups, s.state)
+    if state is not None and t == 1:
+        out, h = ssm_scan_step(
+            x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], state["ssm"])
+        out = out[:, None]
+    else:
+        h0 = (jnp.zeros((b, s.heads, s.head_dim, s.state), f32)
+              if state is None else state["ssm"])
+        out, h = ssm_scan_chunked(x, dt, a, bm, cm, h0, s.chunk)
+    out = out + p["D"].astype(f32)[:, None] * x.astype(f32)
+    out = out.reshape(b, t, di) * jax.nn.silu(z.astype(f32))
+    grp = out.reshape(b, t, s.groups, di // s.groups)
+    grp = grp * jax.lax.rsqrt(
+        jnp.mean(grp * grp, axis=-1, keepdims=True) + cfg.norm_eps)
+    out = (grp.reshape(b, t, di)
+           * p["norm"]["scale"].astype(f32)).astype(cfg.dtype)
+    return out @ kernel_of(p["out_proj"], cfg.dtype), {
+        "conv": window.astype(cfg.dtype), "ssm": h}
+
+
+def _attention(blk, cfg: LMConfig, y, positions, attn_fn):
+    """The attention mixer on normalised `y`: (its output through
+    `proj`, k, v)."""
+    b, t = y.shape[:2]
+    h, hd, kv, qw = cfg.n_heads, cfg.head_dim, cfg.kv_heads, cfg.q_width
+    qkv = y @ kernel_of(blk["qkv"], cfg.dtype)  # [B, T, qw + 2*kv*hd]
+    q = qkv[..., :qw].reshape(b, t, h, hd)
+    k = qkv[..., qw : qw + kv * hd].reshape(b, t, kv, hd)
+    v = qkv[..., qw + kv * hd :]
+    if cfg.qk_norm:
+        q = _rms_norm(q, blk["q_norm"]["scale"], cfg.dtype, cfg.norm_eps)
+        k = _rms_norm(k, blk["k_norm"]["scale"], cfg.dtype, cfg.norm_eps)
+    if cfg.rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    v = v.reshape(b, t, kv, hd)
+    attn = attn_fn(q, k, v)  # k/v carry kv heads; the closure decides
+    attn = attn.reshape(b, t, qw).astype(cfg.dtype)
+    return attn @ kernel_of(blk["proj"], cfg.dtype), k, v
+
+
+def _feed_forward(blk, cfg: LMConfig, y, experts, mesh):
+    """The feed-forward mixer on normalised `y`: the expert layer where
+    the block holds one, else the two-matrix MLP."""
+    if "moe" in blk:
+        out, counts = expert_ffn(
+            blk["moe"], y, cfg.dtype, cfg.experts_per_token,
+            cfg.experts_first,
+            live=None if experts is None else experts["live"], mesh=mesh,
+            scoring=cfg.router_scoring, scale=cfg.router_scale,
+            activation=cfg.activation,
+        )
+        if experts is not None:
+            experts["counts"].append(counts)
+        return out
+    y = y @ kernel_of(blk["up"], cfg.dtype)
+    y = _activation(cfg.activation)(y)
+    return y @ kernel_of(blk["down"], cfg.dtype)
+
+
 def _apply_block(
     blk: Dict[str, Any],
     cfg: LMConfig,
@@ -338,14 +706,24 @@ def _apply_block(
     attn_fn,  # (q, k, v) [B,T,H,D] -> [B,T,H,D]
     experts: Optional[Dict[str, Any]] = None,
     mesh: Optional[Mesh] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """ONE transformer block — the single copy of the layer math that
-    decode (T=1, cache attention) and prefill (T=Tp, flash attention)
-    both run, so they cannot drift apart. Returns (x_out, k, v); the
-    caller owns what the attention closure and the cache do with k/v.
-    Matches models/transformer.py layer-for-layer where `cfg` is at
-    its defaults; `cfg.d_head`, `rope_theta`, `qk_norm` and the expert
-    keys are the architectures `lm_spec` describes beyond it.
+    kind: Optional[str] = None,
+    ssm_fn=None,  # (ssm subtree, y [B,T,d]) -> [B,T,d]
+) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
+    """ONE layer — the single copy of the layer math that decode (T=1,
+    cache attention, one recurrence step) and prefill (T=Tp, flash
+    attention, the chunked scan) both run, so they cannot drift apart.
+    Returns (x_out, k, v); the caller owns what the attention closure
+    and the cache do with k/v (None from a layer without attention).
+
+    `kind` None is the classic block, attention then feed-forward, each
+    under its own norm: it matches models/transformer.py
+    layer-for-layer where `cfg` is at its defaults; `cfg.d_head`,
+    `rope_theta`, `qk_norm` and the expert keys are the architectures
+    `lm_spec` describes beyond it. A kind of `LAYER_KINDS` (a
+    `layer_pattern`'s layer) is `x + mixer(norm(x))` with that ONE
+    mixer: attention, the expert feed-forward, or the state-space
+    mixer, which the caller's `ssm_fn` closure runs (`ssm_mixer` with
+    the state it owns).
 
     `positions` is [T] (shared across the batch: prefill, plain
     decode) or [B, T] (per-example: continuous-batching decode, where
@@ -354,37 +732,19 @@ def _apply_block(
     {"live": [B] bool or None, "counts": []}: every expert layer
     appends its [E] assignment counts to the list.
     """
-    b, t = x.shape[:2]
-    h, hd, kv, qw = cfg.n_heads, cfg.head_dim, cfg.kv_heads, cfg.q_width
-    y = _rms_norm(x, blk["ln_attn"]["scale"], cfg.dtype)
-    qkv = y @ kernel_of(blk["qkv"], cfg.dtype)  # [B, T, qw + 2*kv*hd]
-    q = qkv[..., :qw].reshape(b, t, h, hd)
-    k = qkv[..., qw : qw + kv * hd].reshape(b, t, kv, hd)
-    v = qkv[..., qw + kv * hd :]
-    if cfg.qk_norm:
-        q = _rms_norm(q, blk["q_norm"]["scale"], cfg.dtype)
-        k = _rms_norm(k, blk["k_norm"]["scale"], cfg.dtype)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    v = v.reshape(b, t, kv, hd)
-    attn = attn_fn(q, k, v)  # k/v carry kv heads; the closure decides
-    attn = attn.reshape(b, t, qw).astype(cfg.dtype)
-    x = x + attn @ kernel_of(blk["proj"], cfg.dtype)
-    y = _rms_norm(x, blk["ln_mlp"]["scale"], cfg.dtype)
-    if "moe" in blk:
-        out, counts = expert_ffn(
-            blk["moe"], y, cfg.dtype, cfg.experts_per_token,
-            cfg.experts_first,
-            live=None if experts is None else experts["live"], mesh=mesh,
-        )
-        if experts is not None:
-            experts["counts"].append(counts)
+    if kind is None:
+        y = _rms_norm(x, blk["ln_attn"]["scale"], cfg.dtype, cfg.norm_eps)
+        out, k, v = _attention(blk, cfg, y, positions, attn_fn)
         x = x + out
-    else:
-        y = y @ kernel_of(blk["up"], cfg.dtype)
-        y = jax.nn.silu(y)
-        x = x + y @ kernel_of(blk["down"], cfg.dtype)
-    return x, k, v
+        y = _rms_norm(x, blk["ln_mlp"]["scale"], cfg.dtype, cfg.norm_eps)
+        return x + _feed_forward(blk, cfg, y, experts, mesh), k, v
+    y = _rms_norm(x, blk["ln"]["scale"], cfg.dtype, cfg.norm_eps)
+    if kind == "*":
+        out, k, v = _attention(blk, cfg, y, positions, attn_fn)
+        return x + out, k, v
+    if kind == "E":
+        return x + _feed_forward(blk, cfg, y, experts, mesh), None, None
+    return x + ssm_fn(blk["ssm"], y), None, None
 
 
 def _lm_head(params: Dict[str, Any], cfg: LMConfig, x: jax.Array) -> jax.Array:
@@ -393,7 +753,7 @@ def _lm_head(params: Dict[str, Any], cfg: LMConfig, x: jax.Array) -> jax.Array:
     TransformerLM's does; one stored in the model's compute dtype
     (`lm_spec`'s `param_dtype`) multiplies in it and accumulates in
     float32, so no float32 copy of it is ever made."""
-    x = _rms_norm(x, params["ln_out"]["scale"], cfg.dtype)
+    x = _rms_norm(x, params["ln_out"]["scale"], cfg.dtype, cfg.norm_eps)
     kern = params["lm_head"]["kernel"]
     if not isinstance(kern, dict) and kern.dtype == cfg.dtype != jnp.float32:
         return jnp.matmul(x, kern, preferred_element_type=jnp.float32)
@@ -508,6 +868,7 @@ def batched_decode_step(
     pos: jax.Array,  # [B] int32 — each slot's own write position
     mesh: Optional[Mesh] = None,
     lengths: Optional[jax.Array] = None,  # [B] int32 — rows each slot attends
+    experts: Optional[Dict[str, Any]] = None,
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """decode_step with PER-SLOT positions — the continuous-batching
     primitive (inference/lm_server.py): every slot advances through
@@ -521,15 +882,20 @@ def batched_decode_step(
     written included) unless the caller knows better. A caller that
     knows a slot is EMPTY passes 0 for it: its attention output is
     zeros, its logits are garbage nobody reads, and the kernel route
-    fetches none of its cache (its row at `pos` is still written)."""
+    fetches none of its cache (its row at `pos` is still written).
+
+    A state-space layer advances its slot's state by the one position
+    (`ssm_mixer`'s step form), whatever the slot holds: an empty
+    slot's state is garbage nobody reads, overwritten whole by the
+    next placement. `experts` is `_apply_block`'s."""
     hd = cfg.head_dim
     b = tokens.shape[0]
     grp = cfg.n_heads // cfg.kv_heads
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)[:, None, :]
     positions = pos[:, None]  # [B, 1] — rope's per-example form
-    # layout-generic (bf16 {k, v} or kv_quant {k_q, ...}): every leaf
+    # layout-generic (bf16 {k, v} or kv_quant {k_q, ...}): every K/V leaf
     # carries [B, KV, max_len, ...]
-    max_len = next(iter(next(iter(cache.values())).values())).shape[2]
+    max_len = cache_rows(cache)
     if lengths is None:
         lengths = pos + 1
     # per-slot validity: slot b sees cache rows < lengths[b]
@@ -551,8 +917,12 @@ def batched_decode_step(
         )
 
     new_cache: Dict[str, Any] = {}
-    for i in range(cfg.n_layers):
+    for i, kind in enumerate(cfg.kinds):
         name = f"block_{i}"
+
+        def ssm_fn(p, y, name=name):
+            out, new_cache[name] = ssm_mixer(p, cfg, y, cache[name])
+            return out
 
         def attn_fn(q, k, v, name=name):
             # k/v arrive [B, 1, KV, D]; the cache is head-major.
@@ -615,7 +985,8 @@ def batched_decode_step(
             return attn.reshape(b, 1, cfg.n_heads, hd)
 
         x, _, _ = _apply_block(
-            params[name], cfg, x, positions, attn_fn, mesh=mesh)
+            params[name], cfg, x, positions, attn_fn, experts, mesh,
+            kind, ssm_fn)
 
     return _head(params, cfg, x), new_cache
 
@@ -678,8 +1049,15 @@ def batched_block_step(
     grp = cfg.n_heads // cfg.kv_heads
     if t % mask_block:
         raise ValueError(f"{t} rows under blocks of {mask_block}")
+    if cfg.has_state:
+        # rows written here may be dropped (a rejected draft) or written
+        # again (a block's next forward): K/V rows allow both, a scan
+        # state that has taken them in allows neither
+        raise ValueError(
+            "the multi-token cached forward cannot roll a state-space "
+            "layer's state back; speculation and block diffusion need it to")
     x = params["embed"]["embedding"][tokens].astype(cfg.dtype)  # [B,T,d]
-    max_len = next(iter(next(iter(cache.values())).values())).shape[2]
+    max_len = cache_rows(cache)
     pos = jnp.minimum(pos, max_len - t)
     positions = pos[:, None] + jnp.arange(t)[None, :]  # [B, T] per-example
     back = jnp.asarray(_rows_back(t, mask_block), jnp.int32)
@@ -708,7 +1086,7 @@ def batched_block_step(
         )  # [B, T, max_len]
 
     new_cache: Dict[str, Any] = {}
-    for i in range(cfg.n_layers):
+    for i, kind in enumerate(cfg.kinds):
         name = f"block_{i}"
 
         def attn_fn(q, k, v, name=name):
@@ -768,7 +1146,7 @@ def batched_block_step(
             return attn.reshape(b, t, cfg.n_heads, hd)
 
         x, _, _ = _apply_block(
-            params[name], cfg, x, positions, attn_fn, experts, mesh)
+            params[name], cfg, x, positions, attn_fn, experts, mesh, kind)
 
     # logits at EVERY position (not _head's single-row squeeze): the
     # verifier needs the target's next-token argmax after each
@@ -815,7 +1193,12 @@ def prefill(
     bucket-PADDED prompts, and causal masking guarantees the logits at
     the true last prompt position are untouched by the pad tail, so
     reading them here keeps the server's first token numerically
-    IDENTICAL to an unpadded `generate` call.
+    IDENTICAL to an unpadded `generate` call. A state-space layer is
+    causal too, but its STATE is what the last position left, so with
+    a `logits_index` every row's state is taken at its own length,
+    `logits_index + 1` (`ssm_mixer`'s `lengths`): the cache a padded
+    row hands back holds, beside its K/V rows, the convolution window
+    and scan state of the unpadded prompt.
 
     The old path pushed the prompt through the decode scan one token
     at a time — O(Tp) sequential [B,1] steps that leave the MXU idle.
@@ -847,12 +1230,28 @@ def prefill(
             v = jnp.repeat(v, grp, axis=2)
         return flash(q, k, v)
 
+    # rows' own lengths, where the caller gave them: a state-space
+    # layer must not take a padded position into its state
+    lengths = None
+    if cfg.has_state and logits_index is not None:
+        lengths = jnp.broadcast_to(
+            jnp.asarray(logits_index, jnp.int32) + 1, (b,))
+
     cache: Dict[str, Any] = {}
     pad4 = ((0, 0), (0, 0), (0, pad), (0, 0))  # head-major: pad T axis 2
-    for i in range(cfg.n_layers):
+    for i, kind in enumerate(cfg.kinds):
+        name = f"block_{i}"
+
+        def ssm_fn(p, y, name=name):
+            out, cache[name] = ssm_mixer(p, cfg, y, lengths=lengths)
+            return out
+
         x, k, v = _apply_block(
-            params[f"block_{i}"], cfg, x, positions, attn_fn, mesh=mesh
+            params[name], cfg, x, positions, attn_fn, mesh=mesh, kind=kind,
+            ssm_fn=ssm_fn,
         )
+        if k is None:
+            continue
         kh = jnp.swapaxes(k, 1, 2)  # [B, KV, Tp, D] — cache layout
         vh = jnp.swapaxes(v, 1, 2)
         if cfg.kv_quant:

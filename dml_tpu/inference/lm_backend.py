@@ -34,7 +34,9 @@ log = logging.getLogger(__name__)
 
 from ..jobs.cost_model import ModelCost, lm_request_forwards
 from ..tracing import current_all_ctxs
-from .generate import LMConfig
+from .generate import (
+    ACTIVATIONS, LAYER_KINDS, ROUTER_SCORING, LMConfig, SSMConfig,
+)
 from .lm_server import REMASKING, BlockDiffusion, LMDriver, LMServer
 
 
@@ -104,8 +106,16 @@ def parse_prompt_file(
 _ARCH_KEYS = (
     "head_dim", "rope_theta", "qk_norm", "num_experts", "experts_per_token",
     "expert_d_ff", "gated", "experts_held", "attention_mask",
-    "block_length", "param_dtype",
+    "block_length", "param_dtype", "layer_pattern", "ssm", "rope",
+    "norm_eps", "router", "expert_latent", "shared_expert_d_ff",
+    "activation",
 )
+_SSM_KEYS = ("heads", "head_dim", "state", "groups", "conv_kernel", "chunk")
+_ROUTER_KEYS = ("scoring", "bias", "scale")
+#: the range a state-space head's initial step size is drawn from
+#: (log-uniform) and its floor: the `nemotron_h` configs' `time_step_min`,
+#: `time_step_max`, `time_step_floor`, which shape the initialisation only
+_DT_INIT = (0.001, 0.1, 1e-4)
 _DTYPES = ("bfloat16", "float32")
 
 
@@ -128,6 +138,73 @@ def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
     }
     a["expert_d_ff"] = int(
         spec.get("expert_d_ff") or spec.get("d_ff", 4 * d_model))
+    a["layer_pattern"] = spec.get("layer_pattern")
+    a["ssm"] = spec.get("ssm")
+    a["rope"] = spec.get("rope", "rotary")
+    a["norm_eps"] = float(spec.get("norm_eps", 1e-6))
+    router = dict(spec.get("router") or {})
+    a["router"] = {
+        "scoring": router.get("scoring", "softmax"),
+        "bias": bool(router.get("bias", False)),
+        "scale": float(router.get("scale", 1.0)),
+    }
+    a["expert_latent"] = int(spec.get("expert_latent", 0) or 0)
+    a["shared_expert_d_ff"] = int(spec.get("shared_expert_d_ff", 0) or 0)
+    a["activation"] = spec.get("activation", "silu")
+    pat = a["layer_pattern"]
+    if pat is not None:
+        if not isinstance(pat, str) or not pat or set(pat) - set(LAYER_KINDS):
+            raise ValueError(
+                f"layer_pattern {pat!r}: a string of one mixer a layer, "
+                f"{LAYER_KINDS}")
+        if int(spec.get("n_layers", len(pat))) != len(pat):
+            raise ValueError(
+                f"n_layers {spec['n_layers']} is not layer_pattern "
+                f"{pat!r}'s length {len(pat)}")
+        if ("E" in pat) != bool(a["num_experts"]):
+            raise ValueError(
+                f"layer_pattern {pat!r} and num_experts "
+                f"{a['num_experts']}: expert layers and routed experts "
+                f"come together")
+    if ("M" in (pat or "")) != (a["ssm"] is not None):
+        raise ValueError(
+            f"ssm sizes {a['ssm']!r} under layer_pattern {pat!r}: a "
+            f"state-space layer and its sizes come together")
+    if a["ssm"] is not None:
+        ssm = a["ssm"]
+        if not isinstance(ssm, dict) or set(ssm) - set(_SSM_KEYS) or not {
+                "heads", "head_dim", "state"} <= set(ssm):
+            raise ValueError(
+                f"ssm {ssm!r}: heads, head_dim and state, and of "
+                f"{_SSM_KEYS} no other key")
+        a["ssm"] = SSMConfig(**{k: int(v) for k, v in ssm.items()})
+        if a["attention_mask"] != "causal":
+            raise ValueError(
+                "a state-space layer reads its sequence in order: "
+                "attention_mask block_causal (generation by diffusion) "
+                "has no meaning for it")
+    if a["rope"] not in ("rotary", "none"):
+        raise ValueError(f"unknown rope {a['rope']!r} (rotary | none)")
+    if not 0 < a["norm_eps"] < 1:
+        raise ValueError(f"norm_eps {a['norm_eps']}")
+    if set(router) - set(_ROUTER_KEYS):
+        raise ValueError(f"router {router!r}: keys of {_ROUTER_KEYS}")
+    if a["router"]["scoring"] not in ROUTER_SCORING:
+        raise ValueError(
+            f"unknown router scoring {a['router']['scoring']!r} "
+            f"({' | '.join(ROUTER_SCORING)})")
+    if a["router"]["bias"] and a["router"]["scoring"] != "sigmoid":
+        raise ValueError(
+            "a router's selection bias corrects sigmoid scores; under "
+            "softmax scoring the serving code has no use for one")
+    if a["router"]["scale"] <= 0:
+        raise ValueError(f"router scale {a['router']['scale']}")
+    if a["activation"] not in ACTIVATIONS:
+        raise ValueError(
+            f"unknown activation {a['activation']!r} "
+            f"({' | '.join(ACTIVATIONS)})")
+    if min(a["expert_latent"], a["shared_expert_d_ff"]) < 0:
+        raise ValueError("expert_latent / shared_expert_d_ff below 0")
     first, count = (
         int(n) for n in spec.get("experts_held") or (0, a["num_experts"]))
     a["experts_held"] = (first, count)
@@ -160,8 +237,11 @@ def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
             raise ValueError(
                 f"experts_held {[first, count]} lies outside the "
                 f"{e} routed experts")
-    elif spec.get("experts_held") or a["gated"]:
-        raise ValueError("experts_held / gated without num_experts")
+    elif (spec.get("experts_held") or a["gated"] or spec.get("router")
+          or a["expert_latent"] or a["shared_expert_d_ff"]):
+        raise ValueError(
+            "experts_held / gated / router / expert_latent / "
+            "shared_expert_d_ff without num_experts")
     if spec.get("denoising_steps") is not None or a["block_length"] > 1:
         if a["attention_mask"] != "block_causal":
             raise ValueError(
@@ -189,35 +269,72 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
     serving code indexes (`generate._apply_block`): `qkv` fused
     [d, H*D + 2*KV*D], `proj` [H*D, d], `q_norm`/`k_norm` [D] under
     `qk_norm`, and either `up`/`down` or, in an expert layer, `moe`
-    {router [d, E], w_up and w_down (and w_gate) stacked over the
-    experts HELD}."""
+    {router [d, E] (and its selection `bias` [E]), w_up and w_down
+    (and w_gate) stacked over the experts HELD, in the latent width
+    between `latent_down` [d, L] and `latent_up` [L, d] where the
+    experts live in one, `shared_up`/`shared_down` where a shared
+    expert stands beside them}.
+
+    Under a `layer_pattern` a block holds one norm (`ln`) and its
+    mixer's leaves alone: `qkv` and `proj`, or `moe`, or `ssm`
+    {in_proj [d, z | xBC | dt], conv {kernel [K, C], bias [C]}, A_log,
+    D, dt_bias [H], norm {scale [d_inner]}, out_proj [d_inner, d]}. A
+    state-space layer's small leaves are float32 and start as Mamba-2's
+    do: A_log the log of uniform(1, 16), D at 1, dt_bias the inverse
+    softplus of a log-uniform step (`_DT_INIT`), the convolution
+    uniform within 1 / sqrt(K)."""
     import jax
     import jax.numpy as jnp
 
     d, hd, kvw = cfg.d_model, cfg.head_dim, cfg.kv_heads * cfg.head_dim
     pdt = jnp.dtype(arch["param_dtype"])
     held, f = arch["experts_held"][1], arch["expert_d_ff"]
-    shapes: Dict[str, Any] = {"embed": {"embedding": (cfg.vocab_size, d)}}
-    for i in range(cfg.n_layers):
-        blk: Dict[str, Any] = {
-            "ln_attn": {"scale": (d,)}, "ln_mlp": {"scale": (d,)},
-            "qkv": {"kernel": (d, cfg.q_width + 2 * kvw)},
-            "proj": {"kernel": (cfg.q_width, d)},
+    latent, shared = arch["expert_latent"], arch["shared_expert_d_ff"]
+    attention: Dict[str, Any] = {
+        "qkv": {"kernel": (d, cfg.q_width + 2 * kvw)},
+        "proj": {"kernel": (cfg.q_width, d)},
+    }
+    if cfg.qk_norm:
+        attention["q_norm"] = {"scale": (hd,)}
+        attention["k_norm"] = {"scale": (hd,)}
+    if arch["num_experts"]:
+        width = latent or d
+        moe: Dict[str, Any] = {
+            "router": {"kernel": (d, arch["num_experts"])},
+            "w_up": (held, width, f), "w_down": (held, f, width),
         }
-        if cfg.qk_norm:
-            blk["q_norm"] = {"scale": (hd,)}
-            blk["k_norm"] = {"scale": (hd,)}
-        if arch["num_experts"]:
-            blk["moe"] = {
-                "router": {"kernel": (d, arch["num_experts"])},
-                "w_up": (held, d, f), "w_down": (held, f, d),
-            }
-            if arch["gated"]:
-                blk["moe"]["w_gate"] = (held, d, f)
-        else:
-            blk["up"] = {"kernel": (d, cfg.d_ff)}
-            blk["down"] = {"kernel": (cfg.d_ff, d)}
-        shapes[f"block_{i}"] = blk
+        if arch["router"]["bias"]:
+            moe["router"]["bias"] = (arch["num_experts"],)
+        if arch["gated"]:
+            moe["w_gate"] = (held, width, f)
+        if latent:
+            moe["latent_down"] = {"kernel": (d, latent)}
+            moe["latent_up"] = {"kernel": (latent, d)}
+        if shared:
+            moe["shared_up"] = {"kernel": (d, shared)}
+            moe["shared_down"] = {"kernel": (shared, d)}
+        ffn: Dict[str, Any] = {"moe": moe}
+    else:
+        ffn = {"up": {"kernel": (d, cfg.d_ff)},
+               "down": {"kernel": (cfg.d_ff, d)}}
+    ssm: Dict[str, Any] = {}
+    if cfg.ssm is not None:
+        s = cfg.ssm
+        ssm = {"ssm": {
+            "in_proj": {"kernel": (d, s.in_width)},
+            "conv": {"kernel": (s.conv_kernel, s.conv_width),
+                     "bias": (s.conv_width,)},
+            "A_log": (s.heads,), "D": (s.heads,), "dt_bias": (s.heads,),
+            "norm": {"scale": (s.d_inner,)},
+            "out_proj": {"kernel": (s.d_inner, d)},
+        }}
+    mixers = {"*": attention, "E": ffn, "M": ssm}
+    shapes: Dict[str, Any] = {"embed": {"embedding": (cfg.vocab_size, d)}}
+    for i, kind in enumerate(cfg.kinds):
+        shapes[f"block_{i}"] = (
+            {"ln_attn": {"scale": (d,)}, "ln_mlp": {"scale": (d,)},
+             **attention, **ffn} if kind is None
+            else {"ln": {"scale": (d,)}, **mixers[kind]})
     shapes["ln_out"] = {"scale": (d,)}
     shapes["lm_head"] = {"kernel": (d, cfg.vocab_size)}
     is_shape = lambda x: isinstance(x, tuple)
@@ -228,17 +345,29 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
         out = []
         for i, (path, shape) in enumerate(flat):
             name = [getattr(p, "key", "") for p in path]
-            if name[-1] == "scale":
+            k = jax.random.fold_in(key, i)
+            if name[-1] in ("scale", "D"):
                 out.append(jnp.ones(shape, jnp.float32))
-                continue
-            # the contracted axis: the second to last of a (stacked)
-            # kernel, the last of the embedding table
-            fan_in = shape[-1] if name[-1] == "embedding" else shape[-2]
-            w = jax.random.normal(
-                jax.random.fold_in(key, i), shape, jnp.float32
-            ) * fan_in ** -0.5
-            out.append(
-                w if "router" in name else w.astype(pdt))
+            elif name[-1] == "A_log":
+                out.append(jnp.log(jax.random.uniform(
+                    k, shape, jnp.float32, 1.0, 16.0)))
+            elif name[-1] == "dt_bias":
+                lo, hi, floor = _DT_INIT
+                dt = jnp.maximum(floor, jnp.exp(jax.random.uniform(
+                    k, shape, jnp.float32, jnp.log(lo), jnp.log(hi))))
+                out.append(dt + jnp.log(-jnp.expm1(-dt)))
+            elif "conv" in name:
+                lim = cfg.ssm.conv_kernel ** -0.5
+                out.append(jax.random.uniform(
+                    k, shape, jnp.float32, -lim, lim))
+            elif name[-1] == "bias":  # the router's selection bias
+                out.append(0.05 * jax.random.normal(k, shape, jnp.float32))
+            else:
+                # the contracted axis: the second to last of a (stacked)
+                # kernel, the last of the embedding table
+                fan_in = shape[-1] if name[-1] == "embedding" else shape[-2]
+                w = jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5
+                out.append(w if "router" in name else w.astype(pdt))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     return jax.jit(make)(jax.random.PRNGKey(int(seed)))
@@ -259,7 +388,9 @@ def lm_spec_parts(spec: Dict[str, Any]):
     (another head size, a rope base, q/k norms, gated top-k experts,
     the block-causal mask, `param_dtype`) gets `init_lm_params`' tree,
     its matrices stored in `param_dtype`, every layer an expert layer
-    where `num_experts` is set.
+    where `num_experts` is set, or, under `layer_pattern`, one mixer a
+    layer (state-space, attention, expert feed-forward) with
+    `n_layers` the pattern's length.
 
     What is returned is the tree as STORED. A server does not multiply
     a float32 tree under bfloat16 compute as stored, nor cast it in
@@ -281,7 +412,8 @@ def lm_spec_parts(spec: Dict[str, Any]):
         vocab_size=int(spec["vocab_size"]),
         d_model=d_model,
         n_heads=int(spec.get("n_heads", 8)),
-        n_layers=int(spec.get("n_layers", 2)),
+        n_layers=(len(arch["layer_pattern"]) if arch["layer_pattern"]
+                  else int(spec.get("n_layers", 2))),
         d_ff=int(spec.get("d_ff", 4 * d_model)),
         dtype=dtype,
         n_kv_heads=(
@@ -297,6 +429,13 @@ def lm_spec_parts(spec: Dict[str, Any]):
             "experts_first": arch["experts_held"][0],
             "attention_mask": arch["attention_mask"],
             "block_length": arch["block_length"],
+            "layer_pattern": arch["layer_pattern"],
+            "ssm": arch["ssm"],
+            "rope": arch["rope"] == "rotary",
+            "norm_eps": arch["norm_eps"],
+            "router_scoring": arch["router"]["scoring"],
+            "router_scale": arch["router"]["scale"],
+            "activation": arch["activation"],
         } if described else {}),
     )
     if described:

@@ -103,6 +103,7 @@ from .generate import (
     heads_axis,
     init_cache,
     prefill,
+    state_bytes,
 )
 from .quantize import quantized_bytes, resident_params
 
@@ -160,8 +161,9 @@ _M_STEP = METRICS.histogram(
     "one chunked decode step incl. its packed readback")
 _M_PACK = METRICS.histogram(
     "lm_server_pack_seconds",
-    "issuing a dispatch's packed readback: one eager concatenate whose "
-    "arity varies, so it may load or compile a program")
+    "issuing a dispatch's packed readback: one concatenate of fixed "
+    "shapes (a diffusion dispatch packs on the device: the host copy's "
+    "issue)")
 _M_READBACK = METRICS.histogram(
     "lm_server_readback_seconds",
     "blocking device->host readbacks (the serve loop's only stalls); "
@@ -170,6 +172,11 @@ _M_DELIVER = METRICS.histogram(
     "lm_server_deliver_seconds",
     "a dispatch's token delivery: first tokens, every request's "
     "on_token callbacks, retirements")
+_M_STATE_BYTES = METRICS.gauge(
+    "lm_server_state_bytes",
+    "the slot grid's bytes by kind= kv (attention layers' rows) | conv | "
+    "scan (a state-space layer's convolution window and recurrent state, "
+    "which every decode step reads and writes whole for every slot)")
 _M_WEIGHT_BYTES = METRICS.gauge(
     "lm_server_weight_bytes",
     "the weight tree's bytes by form= handed (as the server was given "
@@ -204,11 +211,19 @@ _M_BLOCKS = METRICS.counter(
 _M_MOE_ASSIGN = METRICS.counter(
     "moe_assignments_total",
     "(token, expert) assignments of occupied slots' tokens over the "
-    "expert layers of block-diffusion forwards")
+    "expert layers of decode steps and block-diffusion forwards by "
+    "where=: held (an expert this tree holds computes it) | absent "
+    "(another chip's part of the sum)")
+_M_MOE_HELD = _M_MOE_ASSIGN.labels(where="held")
+_M_MOE_ABSENT = _M_MOE_ASSIGN.labels(where="absent")
 _M_MOE_TOUCHED = METRICS.histogram(
     "moe_experts_touched",
-    "distinct routed experts one forward's tokens reach in one layer "
-    "(whose weights that forward has to read)")
+    "distinct routed experts one forward's tokens reach in one layer, "
+    "over ALL the routed experts")
+_M_MOE_TOUCHED_HELD = METRICS.histogram(
+    "moe_experts_touched_held",
+    "distinct HELD experts one forward's tokens reach in one layer "
+    "(whose weights that forward has to read here)")
 _M_MOE_LOAD = METRICS.histogram(
     "moe_expert_load_max",
     "assignments to the busiest expert over the mean over all routed "
@@ -275,8 +290,18 @@ def _group_rows(k: int, bucket: int, max_slots: int) -> int:
     return min(_bucket(k, lo=1), max_slots)
 
 
+#: the most tokens (padded rows x bucket) a prefill group of a model with
+#: a state-space layer holds, unless it is one row: the chunked scan's
+#: float32 transients (decay masks [tokens x chunk] a head, chunk states)
+#: grow with the tokens, and beside 9.3 GB of weights and a 64-slot grid
+#: a (512, 64) group does not fit. A bound on the group, so that no slot,
+#: width or row has to give way (PERF.md section 4 has the arithmetic).
+_STATE_GROUP_TOKENS = 8192
+
+
 def _prefill_groups(
-    lengths: Sequence[int], max_len: int, max_slots: int
+    lengths: Sequence[int], max_len: int, max_slots: int,
+    max_tokens: Optional[int] = None,
 ) -> List[Tuple[int, int, List[int]]]:
     """Cut one placement round into prefill groups: `(bucket, rows,
     members)` each, members indexing `lengths`, shortest bucket first.
@@ -291,7 +316,8 @@ def _prefill_groups(
     are `_group_rows` of the members, and never more than `_group_rows`
     of the members whose OWN bucket is the group's, so a rider never
     raises the rows past what equal-length prompts of that bucket form
-    alone."""
+    alone; and under `max_tokens` no group of more than one row holds
+    more padded tokens than that."""
     order = sorted(range(len(lengths)), key=lambda i: lengths[i])
     own = [_prefill_bucket(lengths[i], max_len) for i in order]
     # best[j]: (padded tokens + charges, groups, start of the last
@@ -302,8 +328,9 @@ def _prefill_groups(
         for i in range(j - 1, -1, -1):
             native += own[i] == bucket
             rows = _group_rows(j - i, bucket, max_slots)
-            if rows > _group_rows(native, bucket, max_slots):
-                break  # riders only: more of them never fit either
+            if rows > _group_rows(native, bucket, max_slots) or (
+                    max_tokens and j - i > 1 and rows * bucket > max_tokens):
+                break  # riders only, or too many tokens: so is any more
             cost, groups, _ = best[i]
             cands.append((cost + rows * bucket + _BUCKET_FLOOR,
                           groups + 1, i))
@@ -315,6 +342,16 @@ def _prefill_groups(
                     sorted(order[i:j])))
         j = i
     return out[::-1]
+
+
+def _routing_numbers(counts, lo: int, hi: int) -> list:
+    """One expert layer's routing in five numbers, from its assignment
+    counts [..., E] (a device or a host array): the assignments, those
+    to the held experts `lo .. hi - 1`, the distinct experts reached,
+    the distinct held ones, the assignments to the busiest expert."""
+    held = counts[..., lo:hi]
+    return [counts.sum(-1), held.sum(-1), (counts > 0).sum(-1),
+            (held > 0).sum(-1), counts.max(-1)]
 
 
 @dataclasses.dataclass
@@ -543,7 +580,19 @@ class LMServer:
                 raise ValueError(
                     f"max_len {max_len} is no whole number of blocks "
                     f"of {cfg.block_length}")
+        if cfg.has_state and (diffusion is not None or self._mesh is not None):
+            # a state-space layer's state is whole-sequence and per slot:
+            # the denoising forwards rewrite rows it has already taken in,
+            # and no sharding rule places it over a mesh yet
+            raise ValueError(
+                "a model with a state-space layer is served by the plain "
+                "chunked loop on one device: block diffusion and the "
+                "sharded forms cannot hold its state")
         self.cache = self._new_cache(cfg)
+        for kind, n in state_bytes(self.cache).items():
+            _M_STATE_BYTES.set(n, kind=kind)
+        # the most padded tokens a prefill group of several rows holds
+        self._group_tokens = _STATE_GROUP_TOKENS if cfg.has_state else None
         # Decode state lives ON DEVICE (authoritative): `_cur_dev` the
         # next input token per slot, `_pos_dev` the next write
         # position. Placement writes them with device scatters and the
@@ -561,12 +610,17 @@ class LMServer:
         # _M_TOKENS is process-global; steady-state measurement wants
         # THIS server's stream without registry key coupling)
         self.tokens_delivered = 0
-        # placement groups whose first tokens haven't been read back
-        # yet: (requests in row order, device [group_rows] tokens —
-        # rows past the requests are group padding). Flushed into the
-        # next step's packed readback, or by _flush_firsts when a
-        # contained request retires with no step following.
-        self._pending_first: List[Tuple[List[_Request], jax.Array]] = []
+        # first tokens sampled at placement whose VALUES haven't been
+        # read back yet: `_firsts_dev[slot]` holds the token (scattered
+        # there by the placement's masked merge, like cur/pos), and
+        # `_pending_first` maps the slot to the request that waits for
+        # it. One vector of one shape, so the packed readback that
+        # carries it has ONE shape too and never compiles in steady
+        # state. Read by the next step's packed readback, or by
+        # _flush_firsts when a request retires with no step following
+        # or its slot is placed again before one.
+        self._firsts_dev = jnp.zeros(max_slots, jnp.int32)
+        self._pending_first: Dict[int, _Request] = {}
         self._queue: List[_Request] = []
         self._done: Dict[int, _Request] = {}
         self._rid = 0
@@ -631,14 +685,20 @@ class LMServer:
         # block diffusion: the last dispatch's wall over its forwards
         # (what LMBackend prices a request by); None = none measured
         self.forward_seconds: Optional[float] = None
+        moes = [
+            blk["moe"] for name, blk in self.params.items()
+            if name.startswith("block_") and "moe" in blk
+        ]
+        # (expert layers, routed experts): the routing counts' shape;
+        # and the experts this tree holds, [first, first + held)
+        self._routed = (
+            len(moes),
+            moes[0]["router"]["kernel"].shape[-1] if moes else 0)
+        self._held = (
+            cfg.experts_first,
+            cfg.experts_first + jax.tree_util.tree_leaves(
+                moes[0]["w_up"])[0].shape[0] if moes else 0)
         if diffusion is not None:
-            routers = [
-                blk["moe"]["router"]["kernel"].shape[-1]
-                for name, blk in self.params.items()
-                if name.startswith("block_") and "moe" in blk
-            ]
-            # (expert layers, routed experts): the routing counts' shape
-            self._routed = (len(routers), routers[0] if routers else 0)
             # the current block of every slot, device-resident like
             # cur/pos: `_pos_dev` is then the block's first row
             b = cfg.block_length
@@ -703,6 +763,8 @@ class LMServer:
             raise ValueError(
                 "speculative decoding drafts tokens one at a time; a "
                 "block-diffusion server has no such step")
+        self._refuse_state("speculative decoding (a rejected draft's "
+                           "rows are dropped)")
         if self.temperature != 0.0:
             raise ValueError(
                 "speculative decoding requires temperature == 0 "
@@ -823,11 +885,25 @@ class LMServer:
             raise ValueError(
                 "the KV prefix cache warm-starts causal prefills; a "
                 "block-diffusion server has none")
+        if cache is not None:
+            self._refuse_state("the KV prefix cache (a prefix is a cut "
+                               "of cached rows)")
         self.kv_cache = cache
         self._warm = (
             WarmStart(cache, self.cfg, self.max_len)
             if cache is not None else None
         )
+
+    def _refuse_state(self, what: str) -> None:
+        """Raise where this server's model (or its draft) carries a
+        state-space layer's state, which holds a whole sequence in one
+        array: it cannot be cut at a token or rolled back, as K/V rows
+        can, and `what` needs one of the two."""
+        if self.cfg.has_state:
+            raise ValueError(
+                f"{what} needs state that can be cut by token or rolled "
+                f"back; this model's state-space layers carry a scan "
+                f"state and a convolution window that allow neither")
 
     def _new_cache(self, cfg: LMConfig):
         """An empty slot-grid cache for `cfg`. Under a mesh every
@@ -859,7 +935,13 @@ class LMServer:
         prefilled rows alone — see below — and needs no such pairing:
         its forwards attend rows under each slot's length only, and a
         request's own forwards write every row from its first block on
-        before anything attends it.)"""
+        before anything attends it.)
+
+        A state-space layer's leaves (`conv`, `ssm`) are copied like
+        any other, and the invariant holds for them in its plainest
+        form: an empty slot's state is advanced by every dispatch with
+        whatever it holds, read by nobody, and overwritten WHOLE here
+        with the state the prefill took at the row's own length."""
         # generic over the cache layout (bf16 {k, v} or kv_quant
         # {k_q, k_s, v_q, v_s}) — every leaf copies the same way
         if self.diffusion is not None:
@@ -926,23 +1008,37 @@ class LMServer:
         already said so: `rid` is 0 exactly for an empty slot
         (`_retire` zeroes it; request ids start at 1), so such a slot
         attends 0 rows and cache attention fetches none of its rows.
-        Its clamped write stays (the invariant above)."""
+        Its clamped write stays (the invariant above).
+
+        A model with expert layers returns a fifth array: the routing
+        of occupied slots' tokens reduced on the device to five numbers
+        a step a layer ([chunk, layers, 5]: assignments, those to held
+        experts, distinct experts reached, distinct held experts
+        reached, assignments to the busiest expert), which ride the
+        dispatch's packed readback."""
         last = self.max_len - 1
+        lo, hi = self._held
 
         def body(carry, _):
             cache, cur, pos = carry
             pos_c = jnp.minimum(pos, last)
+            experts = ({"live": rid > 0, "counts": []}
+                       if self._routed[0] else None)
             logits, cache = batched_decode_step(
                 params, self.cfg, cache, cur, pos_c, mesh=self._mesh,
-                lengths=jnp.where(rid > 0, pos_c + 1, 0),
+                lengths=jnp.where(rid > 0, pos_c + 1, 0), experts=experts,
             )
             nxt = self._sample_slots(logits, rid, pos_c + 1)
-            return (cache, nxt, pos_c + 1), nxt
+            if experts is None:
+                return (cache, nxt, pos_c + 1), (nxt,)
+            routed = jnp.stack(_routing_numbers(
+                jnp.stack(experts["counts"]), lo, hi), -1).astype(jnp.int32)
+            return (cache, nxt, pos_c + 1), (nxt, routed)
 
-        (cache, cur, pos), toks = jax.lax.scan(
+        (cache, cur, pos), out = jax.lax.scan(
             body, (cache, cur, pos), None, length=self.chunk
         )
-        return cache, cur, pos, toks  # toks: [chunk, slots]
+        return (cache, cur, pos) + out  # toks [chunk, slots] (, routed)
 
     def _diffuse_impl(self, params, cache, blk, pos, rid):
         """`blocks_per_dispatch` whole blocks for every slot in one
@@ -1225,6 +1321,7 @@ class LMServer:
         enabled without a local device draft, and are silently
         dropped otherwise — a shipped draft can accelerate but never
         affect output values (proposal-independence)."""
+        self._refuse_state("submit_prefilled (a slab of K/V rows by token)")
         prompt = self._validate(prompt, max_new_tokens)
         slot = next(
             (s for s in range(self.max_slots)
@@ -1362,6 +1459,11 @@ class LMServer:
         if not pairs:
             return 0
         placed = len(pairs)
+        if any(slot in self._pending_first for slot, _ in pairs):
+            # a request that retired at placement (a budget of 1) left
+            # its first token unread in a slot that is placed again
+            # before any step: read it before it is overwritten
+            self._flush_firsts()
         if self._warm is not None and self.temperature == 0.0:
             # KV-prefix warm starts intercept placement REQUEST BY
             # REQUEST: a prompt extending a cached prefix adopts the
@@ -1390,7 +1492,7 @@ class LMServer:
                 return placed
         for bucket, rows, members in _prefill_groups(
             [req.prompt.size for _, req in pairs],
-            self.max_len, self.max_slots,
+            self.max_len, self.max_slots, self._group_tokens,
         ):
             self._place_group(
                 bucket, rows, [pairs[i] for i in members], span)
@@ -1416,6 +1518,9 @@ class LMServer:
         with TRACER.loop_span(
             "lm_prefill_group", parent, bucket=bucket, rows=k,
             riders=riders,
+            # rows whose state-space state the prefill takes at their
+            # own length and the inserts copy
+            **({"state_rows": k} if self.cfg.has_state else {}),
         ) as span:
             padded = np.zeros((kp, bucket), np.int32)
             tps = np.ones(kp, np.int32)
@@ -1489,9 +1594,10 @@ class LMServer:
                 self._pos_dev = self._merge_vec(
                     self._pos_dev, jnp.asarray(tps), sm
                 )
-                self._pending_first.append(
-                    ([req for _, req in grp], firsts)
-                )
+                self._firsts_dev = self._merge_vec(
+                    self._firsts_dev, firsts, sm)
+                for slot, req in grp:
+                    self._pending_first[slot] = req
             prompt_tokens = int(tps[:k].sum())
             span.label(padded_rows=kp, prompt_tokens=prompt_tokens,
                        padded_tokens=kp * bucket)
@@ -1563,18 +1669,19 @@ class LMServer:
         except Exception as e:
             log.warning("kv-cache capture failed at retire: %r", e)
 
+    def _take_firsts(self) -> Dict[int, _Request]:
+        """The requests whose first token the next readback of
+        `_firsts_dev` delivers, by slot; none are pending after."""
+        firsts, self._pending_first = self._pending_first, {}
+        return firsts
+
     @staticmethod
-    def _distribute_firsts(entries, vals, off) -> int:
-        """Append each pending group's first tokens to its requests'
-        outputs from the packed buffer `vals` starting at `off`; rows
-        past a group's real requests are padding. Shared by step()'s
-        packed readback and _flush_firsts — the offset walk must stay
-        identical or tokens land on the wrong requests."""
-        for reqs, v in entries:
-            for i, req in enumerate(reqs):
-                req.deliver([int(vals[off + i])])
-            off += int(v.shape[0])
-        return off
+    def _distribute_firsts(firsts: Dict[int, _Request], vals, off) -> None:
+        """Hand each pending request its first token from the packed
+        buffer `vals`, where `_firsts_dev` starts at `off`. Shared by
+        step()'s packed readback and _flush_firsts."""
+        for slot, req in firsts.items():
+            req.deliver([int(vals[off + slot])])
 
     def _flush_firsts(self) -> None:
         """Read back any placement-time first tokens that haven't
@@ -1584,15 +1691,13 @@ class LMServer:
         pending request is actually done)."""
         if not self._pending_first:
             return
-        entries = self._pending_first
-        self._pending_first = []
-        with TRACER.loop_span("lm_readback", arrays=len(entries)) as rb:
-            vals = np.asarray(jnp.concatenate([v for _, v in entries]))
+        firsts = self._take_firsts()
+        with TRACER.loop_span("lm_readback", arrays=1) as rb:
+            vals = np.asarray(self._firsts_dev)
         _M_READBACK.observe(rb.m1 - rb.m0)
-        self._distribute_firsts(entries, vals, 0)
-        flushed = sum(len(reqs) for reqs, _ in entries)
-        self.tokens_delivered += flushed
-        _M_TOKENS.inc(flushed)
+        self._distribute_firsts(firsts, vals, 0)
+        self.tokens_delivered += len(firsts)
+        _M_TOKENS.inc(len(firsts))
 
     def step(self) -> None:
         """One decode dispatch: every active slot advances — a
@@ -1657,8 +1762,7 @@ class LMServer:
         sp = self._spec
         k = sp.k
         b = self.max_slots
-        firsts = self._pending_first
-        self._pending_first = []
+        firsts = self._take_firsts()
         real = [False] * b  # slots whose proposals count toward rate
         propose = TRACER.loop_span("lm_dispatch", step, phase="propose")
         if sp.draft_params is not None:
@@ -1708,10 +1812,9 @@ class LMServer:
                 d_toks,
             )
         packed = self._read_packed(
-            step, [jnp.ravel(toks), acc] + [v for _, v in firsts]
-        )
+            step, [jnp.ravel(toks), acc, self._firsts_dev])
         n = b * k
-        first_n = sum(len(reqs) for reqs, _ in firsts)
+        first_n = len(firsts)
         with TRACER.loop_span("lm_deliver", step) as deliver:
             tokm = packed[:n].reshape(b, k)
             accs = packed[n : n + b]
@@ -1774,14 +1877,15 @@ class LMServer:
         self._finish_step(step, delivered, first_n)
 
     def _read_packed(self, step: Any, arrays: List[jax.Array]) -> np.ndarray:
-        """ONE packed readback per step: the dispatch's tokens plus any
-        placement first tokens deferred since the last one. cur/pos
-        never come back to the host (device-authoritative). Two phases
-        under `step`: `lm_pack` issues the eager concatenate, whose
-        arity varies with the placement groups pending, so it may load
-        or compile a program; `lm_readback` is the blocking np.asarray,
-        which stalls the host until the device drains and is the ONLY
-        such stall in the serve loop."""
+        """ONE packed readback per step: the dispatch's tokens, the
+        first tokens placements deferred since the last one
+        (`_firsts_dev`, whole) and what else the dispatch counted.
+        cur/pos never come back to the host (device-authoritative). Two
+        phases under `step`: `lm_pack` issues the concatenate, whose
+        operands have the same shapes in every step of a server's mode,
+        so it compiles once, in the first; `lm_readback` is the
+        blocking np.asarray, which stalls the host until the device
+        drains and is the ONLY such stall in the serve loop."""
         with TRACER.loop_span("lm_pack", step, arrays=len(arrays)) as pack:
             packed = jnp.concatenate(arrays)
         with TRACER.loop_span("lm_readback", step) as readback:
@@ -1809,6 +1913,8 @@ class LMServer:
         the block of the slot before it, so only a run of them at the
         head of the grid costs a block a step
         (ops/decode_attention.py)."""
+        if "*" not in (self.cfg.layer_pattern or "*"):
+            return 0, 0, 0  # no attention layer, no rows
         grid = self.chunk * self.max_slots * self.max_len
         pos0 = np.asarray(
             [r.prompt.size + r.emitted - 1
@@ -1830,27 +1936,34 @@ class LMServer:
         """The plain chunked-scan dispatch (step()'s pre-spec body),
         as five phase spans under `step`: `lm_dispatch`, `lm_pack`,
         `lm_readback`, `lm_deliver`, `lm_place`."""
-        firsts = self._pending_first
-        self._pending_first = []
+        firsts = self._take_firsts()
         with TRACER.loop_span("lm_dispatch", step):
-            self.cache, self._cur_dev, self._pos_dev, toks = self._chunk_fn(
-                self.params, self.cache, self._cur_dev, self._pos_dev,
-                jnp.asarray(self.rid_vec),
-            )
+            self.cache, self._cur_dev, self._pos_dev, toks, *routed = (
+                self._chunk_fn(
+                    self.params, self.cache, self._cur_dev, self._pos_dev,
+                    jnp.asarray(self.rid_vec),
+                ))
         # reckoned while the device works, before delivery moves `emitted`
         live, read, grid = self._kv_rows()
         _M_KV_LIVE.inc(live)
         _M_KV_READ.inc(read)
         _M_KV_GRID.inc(grid)
         step.label(kv_rows_live=live, kv_rows_read=read)
+        if self.cfg.has_state:
+            # every occupied slot's state was read and written whole by
+            # each of the dispatch's steps
+            step.label(state_slots=step.labels["occupancy"])
         packed = self._read_packed(
-            step, [jnp.ravel(toks)] + [v for _, v in firsts]
-        )
+            step, [jnp.ravel(toks), self._firsts_dev]
+            + [jnp.ravel(r) for r in routed])
         n = self.chunk * self.max_slots
         # deferred first tokens ride this readback: they are delivered
         # tokens of this step (the chunk takes below cover budget - 1
         # of each request, the placement-time first covers the rest)
-        first_n = sum(len(reqs) for reqs, _ in firsts)
+        first_n = len(firsts)
+        if routed:
+            self._note_routing(step, packed[n + self.max_slots:].reshape(
+                self.chunk, self._routed[0], 5))
         with TRACER.loop_span("lm_deliver", step) as deliver:
             toks = packed[:n].reshape(self.chunk, self.max_slots)
             # snapshot occupancy BEFORE any deliver() fires user
@@ -1942,25 +2055,40 @@ class LMServer:
         _M_FWD_COMMIT.inc(r_n)
         _M_FIXED.inc(delivered)
         _M_BLOCKS.inc(blocks)
-        labels = {}
         layers, e = self._routed
         if layers:
-            routed = out[2 * n :].reshape(-1, e).astype(np.float64)
-            routed = routed[routed.sum(-1) > 0]  # a forward, a layer
-            touched = (routed > 0).sum(-1)
-            load = routed.max(-1) / routed.mean(-1)
-            _M_MOE_ASSIGN.inc(float(routed.sum()))
-            for t, m in zip(touched, load):
-                _M_MOE_TOUCHED.observe(float(t))
-                _M_MOE_LOAD.observe(float(m))
-            if len(touched):
-                labels = {"experts_touched": round(float(touched.mean()), 3),
-                          "expert_load_max": round(float(load.mean()), 3)}
+            lo, hi = self._held
+            self._note_routing(step, np.stack(_routing_numbers(
+                out[2 * n :].reshape(-1, e), lo, hi), -1))
         step.label(forwards=r_n * (s_n + 1), tokens_fixed=delivered,
-                   blocks_committed=blocks, **labels)
+                   blocks_committed=blocks)
         self._finish_step(step, delivered, 0)
         self.forward_seconds = (
             time.monotonic() - step.m0) / (r_n * (s_n + 1))
+
+    def _note_routing(self, step: Any, routed: np.ndarray) -> None:
+        """A dispatch's routing into the counters and onto its `lm_step`
+        span. `routed` [..., 5], a forward a layer: the assignments of
+        occupied slots' tokens, those to held experts, the distinct
+        experts they reach, the distinct held ones, the assignments to
+        the busiest expert (`_chunk_impl` reduces its counts to these on
+        the device; a diffusion dispatch reads the counts back whole)."""
+        routed = routed.reshape(-1, 5).astype(np.float64)
+        routed = routed[routed[:, 0] > 0]  # forwards that had a token
+        if not len(routed):
+            return
+        total, held, touched, touched_held, busiest = routed.T
+        load = busiest * self._routed[1] / total
+        _M_MOE_HELD.inc(float(held.sum()))
+        _M_MOE_ABSENT.inc(float((total - held).sum()))
+        for t, th, m in zip(touched, touched_held, load):
+            _M_MOE_TOUCHED.observe(float(t))
+            _M_MOE_TOUCHED_HELD.observe(float(th))
+            _M_MOE_LOAD.observe(float(m))
+        step.label(
+            experts_touched=round(float(touched.mean()), 3),
+            experts_touched_held=round(float(touched_held.mean()), 3),
+            expert_load_max=round(float(load.mean()), 3))
 
     def take_fixed_at(
         self, rids: Optional[Sequence[int]] = None
@@ -1994,9 +2122,7 @@ class LMServer:
         readback the deferred-first protocol exists to remove — the
         driver calls take_done every loop iteration, right after
         step() defers the newly placed round's firsts."""
-        if any(
-            r.done for reqs, _ in self._pending_first for r in reqs
-        ):
+        if any(r.done for r in self._pending_first.values()):
             self._flush_firsts()
         out = {
             rid: np.asarray(r.out, np.int32)

@@ -308,6 +308,11 @@ class PipelinedLMBackend:
         if cfg.kv_quant:
             raise ValueError("pipeline serving supports bf16/f32 "
                              "KV cache layouts only (no kv_quant)")
+        if cfg.layer_pattern is not None:
+            raise ValueError(
+                "pipeline serving stacks identical blocks over `pp` and "
+                "carries K/V rows a stage; a layer_pattern's layers differ "
+                "in kind and a state-space layer's state is no K/V row")
         if cfg.n_layers % self.pp:
             raise ValueError(
                 f"n_layers {cfg.n_layers} not divisible by pp {self.pp}"
@@ -959,6 +964,12 @@ class LMPrefillBackend:
     ):
         import jax
 
+        if cfg.has_state:
+            raise ValueError(
+                "a prefill worker ships a slab of K/V rows by token; a "
+                "state-space layer's scan state and convolution window "
+                "are not such rows (LMServer.submit_prefilled refuses "
+                "them too)")
         self.params = params
         self.cfg = cfg
         self.max_len = int(max_len)

@@ -46,6 +46,10 @@ import jax.numpy as jnp
 # tensors of an expert layer (w_gate: gated experts only), the head
 _BLOCK_MATMULS = ("qkv", "proj", "up", "down")
 _EXPERT_MATMULS = ("w_up", "w_down", "w_gate")
+# ... the 2-D kernels an expert layer holds beside its experts (the latent
+# projections, the shared expert) and a state-space mixer's two
+_MOE_MATMULS = ("latent_down", "latent_up", "shared_up", "shared_down")
+_SSM_MATMULS = ("in_proj", "out_proj")
 _TOP_MATMULS = ("lm_head",)
 
 
@@ -69,10 +73,13 @@ def _dequant(t: Dict[str, jax.Array], dtype) -> jax.Array:
 
 def _map_block_matmuls(params: Dict[str, Any], kernel_fn, expert_fn):
     """The same tree with `kernel_fn` applied to every block's 2-D
-    matmul kernel (`_BLOCK_MATMULS`) and `expert_fn` to every stacked
-    expert tensor (`_EXPERT_MATMULS`): the leaves `_apply_block` and
-    `expert_ffn` read through `kernel_of`. Everything else (embedding,
-    norms, router, head) is the caller's own object."""
+    matmul kernel (`_BLOCK_MATMULS`, an expert layer's `_MOE_MATMULS`,
+    a state-space mixer's `_SSM_MATMULS`) and `expert_fn` to every
+    stacked expert tensor (`_EXPERT_MATMULS`): the leaves
+    `_apply_block`, `expert_ffn` and `ssm_mixer` read through
+    `kernel_of`. Everything else (embedding, norms, router, head, a
+    state-space mixer's convolution and per-head vectors) is the
+    caller's own object."""
     out: Dict[str, Any] = {}
     for name, sub in params.items():
         if not name.startswith("block_"):
@@ -84,7 +91,14 @@ def _map_block_matmuls(params: Dict[str, Any], kernel_fn, expert_fn):
                 blk[k] = {**v, "kernel": kernel_fn(v["kernel"])}
             elif k == "moe":
                 blk[k] = {**v, **{
-                    w: expert_fn(v[w]) for w in _EXPERT_MATMULS if w in v}}
+                    w: expert_fn(v[w]) for w in _EXPERT_MATMULS if w in v
+                }, **{
+                    w: {**v[w], "kernel": kernel_fn(v[w]["kernel"])}
+                    for w in _MOE_MATMULS if w in v}}
+            elif k == "ssm":
+                blk[k] = {**v, **{
+                    w: {**v[w], "kernel": kernel_fn(v[w]["kernel"])}
+                    for w in _SSM_MATMULS}}
             else:
                 blk[k] = v
         out[name] = blk

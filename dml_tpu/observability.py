@@ -194,6 +194,7 @@ line when you add the metric.
     lm_server_slot_occupancy         busy decode slots per dispatched step
     lm_server_slots_active           busy decode slots
     lm_server_slots_total            configured decode slots
+    lm_server_state_bytes            slot grid bytes by kind= kv|conv|scan
     lm_server_step_seconds           decode step wall
     lm_server_steps_total            decode steps executed
     lm_server_tokens_fixed_total     block-diffusion tokens fixed and delivered
@@ -215,8 +216,10 @@ line when you add the metric.
     metrics_relay_pulls_total        relay-shard aggregations by role
     metrics_relay_seconds            relay shard pull + pre-merge wall
     moe_assignments_total            (token, expert) assignments of live slots
+                                     by where= held|absent
     moe_expert_load_max              busiest expert / mean, a forward a layer
     moe_experts_touched              distinct experts a forward reaches a layer
+    moe_experts_touched_held         ... of the experts this tree holds
     request_admitted_total           front-door admissions per SLO class
     request_batch_fill_fraction      formed-batch fill quality
     request_batch_formation_seconds  batch formation wall

@@ -258,7 +258,8 @@ def test_counters_count_forwards_blocks_and_assignments():
         ("lm_server_forwards_total", ("kind", "commit")),
         ("lm_server_tokens_fixed_total",),
         ("lm_server_blocks_committed_total",),
-        ("moe_assignments_total",))}
+        ("moe_assignments_total", ("where", "held")),
+        ("moe_assignments_total", ("where", "absent")))}
     try:
         _serve(be, _prompts([8]), [8])  # one dispatch of 2 blocks
     finally:
@@ -268,8 +269,10 @@ def test_counters_count_forwards_blocks_and_assignments():
     assert delta[("lm_server_forwards_total", ("kind", "commit"))] == 2
     assert delta[("lm_server_tokens_fixed_total",)] == 8
     assert delta[("lm_server_blocks_committed_total",)] == 2
-    # 6 forwards x 2 layers x 4 tokens of the one occupied slot x top-3
-    assert delta[("moe_assignments_total",)] == 6 * 2 * 4 * 3
+    # 6 forwards x 2 layers x 4 tokens of the one occupied slot x top-3,
+    # every one to an expert this tree holds (it holds them all)
+    assert delta[("moe_assignments_total", ("where", "held"))] == 6 * 2 * 4 * 3
+    assert delta[("moe_assignments_total", ("where", "absent"))] == 0
 
 
 @pytest.mark.parametrize("bad,match", [

@@ -264,6 +264,7 @@ def test_chunk_program_of_a_grouped_bf16_config_holds_the_kernel(
     srv = object.__new__(LMServer)
     srv.cfg, srv.max_len, srv.chunk, srv.temperature = cfg, max_len, 32, 0.0
     srv._mesh = None
+    srv._routed, srv._held = (0, 0), (0, 0)  # no expert layer
     model = TransformerLM(
         vocab_size=cfg.vocab_size, d_model=cfg.d_model, n_heads=cfg.n_heads,
         n_layers=cfg.n_layers, d_ff=cfg.d_ff, dtype=cfg.dtype,
@@ -280,6 +281,57 @@ def test_chunk_program_of_a_grouped_bf16_config_holds_the_kernel(
         params, cache, vec, vec, vec).compile().as_text()
     assert "tpu_custom_call" in text
     assert text.lstrip().startswith("HloModule jit__chunk_impl")
+
+
+def test_chunk_program_of_the_state_space_config_fits_the_chip(
+        topo, monkeypatch):
+    """The third benchmark configuration's decode program —
+    `LMServer._chunk_impl` at nemotron3_super_l11_ep4's published widths,
+    pattern and slot grid (9.3 GB of weights, 64 slots of K/V rows, conv
+    windows and float32 scan states) — compiles for the chip, updates
+    the grid in place (its temporaries stay under 1 GiB beside 10 GiB of
+    arguments) and holds its kernels: the cache-attention kernel of the
+    one attention layer and two grouped matmuls an expert layer."""
+    import json
+    import os
+
+    from dml_tpu.inference.generate import init_cache
+    from dml_tpu.inference.lm_backend import lm_spec_parts
+    from dml_tpu.inference.lm_server import LMServer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron3_super_l11_ep4.json")) as f:
+        spec = json.load(f)["lm_spec"]
+    made = {}
+
+    def declared():
+        params, made["cfg"] = lm_spec_parts(spec)
+        return params
+
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = functools.partial(jax.tree_util.tree_map, lambda s: (
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)))
+    params = on_chip(jax.eval_shape(declared))
+    cfg = made["cfg"]
+    slots, max_len = spec["max_slots"], spec["max_len"]
+    srv = object.__new__(LMServer)
+    srv.cfg, srv.max_len, srv.max_slots = cfg, max_len, slots
+    srv.chunk, srv.temperature, srv._mesh = spec["chunk"], 0.0, None
+    srv._routed = (cfg.layer_pattern.count("E"), spec["num_experts"])
+    srv._held = (0, spec["experts_held"][1])
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    compiled = jax.jit(srv._chunk_impl, donate_argnums=(1, 2, 3)).lower(
+        params, cache, vec, vec, vec).compile()
+    text = compiled.as_text()
+    assert text.lstrip().startswith("HloModule jit__chunk_impl")
+    assert text.count("tpu_custom_call") == 1 + 2 * 5
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 10 * 2 ** 30
+    assert m.temp_size_in_bytes < 2 ** 30
+    assert m.alias_size_in_bytes >= 1.5 * 2 ** 30  # the grid, in place
 
 
 def test_diffusion_dispatch_of_the_sdar_config_holds_the_kernels(
