@@ -107,7 +107,11 @@ observability:
   profile spans                     serve-loop span stats per name (count,
                                     total, mean, max) over the recorder's
                                     loop ring: LM dispatch phases, worker
-                                    stages, store ops (TRACER.summary())
+                                    stages, store ops (TRACER.summary());
+                                    worker_infer's joined_mean (batches that
+                                    entered the backend beside another) and
+                                    lm_step's waiting_mean (requests queued
+                                    without a slot at a dispatch)
   profile trace start [dir]         capture a jax.profiler (XLA) trace
   profile trace stop                stop + write the trace
   trace [dump]                      this node's flight recorder: finished

@@ -1717,8 +1717,11 @@ class LMServer:
             ("diffusion", self._diffuse_step) if self.diffusion is not None
             else ("spec", self._spec_step) if self._use_spec()
             else ("chunk", self._chunk_step))
+        # `waiting`: requests queued without a slot as the dispatch is
+        # issued (the grid has a backlog to refill from)
         with TRACER.loop_span(
-            "lm_step", occupancy=occupancy, mode=mode
+            "lm_step", occupancy=occupancy, mode=mode,
+            waiting=len(self._queue),
         ) as span:
             dispatch(span)
         _M_STEP.observe(span.m1 - span.m0)

@@ -94,6 +94,13 @@ _M_PROBE_ABORTS = METRICS.counter(
 class DepthController:
     """Probe-and-commit controller for ``Scheduler.pipeline_depth``.
 
+    Governs the models served BATCH AFTER BATCH: the image engine and
+    any backend that does not declare ``on_dispatch``. A model whose
+    batches JOIN a running slot grid (``Scheduler.set_joins_grid``)
+    is staged whatever this controller reads, its queue is not
+    counted into the probe's backlog and its ACKs are not folded in:
+    a "depth 1" phase would not be in force for it.
+
     Round 5's artifact of record measured static depth-2 pipelining as
     a pessimization (0.91×/0.85× vs the depth-1 serial loop) while r4's
     captures had it winning 1.47–1.57× — like the sync-vs-pipelined
@@ -560,6 +567,15 @@ class Scheduler:
         # a staged batch would instantly widen the preempting model's
         # footprint beyond its computed share).
         self.pipeline_depth = 1
+        # Models whose batches JOIN a running slot grid (a continuous-
+        # batching backend that declares `on_dispatch`; register_lm
+        # records it on every node). For these the worker is not the
+        # unit of capacity, the server's slots are: a second batch at
+        # a busy worker costs the device nothing and refills the slots
+        # its first one frees, so `_assign_free` stages one whatever
+        # `pipeline_depth` reads. Two batches a worker, not N: the
+        # worker protocol has one stage.
+        self.joins_grid: set = set()
         # per-slot capacity from the last schedule() call (worker ->
         # weight; absent = 1.0). Group primaries carry their group's
         # aggregate capacity here (jobs/groups.py).
@@ -655,6 +671,24 @@ class Scheduler:
 
     def set_cost(self, model: str, cost: ModelCost) -> None:
         self.costs[model] = cost
+
+    def set_joins_grid(self, model: str, joins: bool = True) -> None:
+        """Record what `model`'s registered backend IS: one whose
+        batches join a running slot grid (it declares `on_dispatch`),
+        or one served batch after batch. Carried like a cost: set on
+        every node by `register_lm`, kept by `snapshot`/`restore`."""
+        if joins:
+            self.joins_grid.add(model)
+        else:
+            self.joins_grid.discard(model)
+
+    def probe_backlog(self) -> int:
+        """Queued batches of the models the `DepthController` governs
+        (what its probe may count on being fed)."""
+        return sum(
+            len(q) for m, q in self.queues.items()
+            if m not in self.joins_grid
+        )
 
     def set_batch_size(self, model: str, batch_size: int) -> None:
         """C3 verb (reference SET_BATCH_SIZE, worker.py:1028-1037):
@@ -946,7 +980,13 @@ class Scheduler:
         sessions' KV state); everything else — including affinity
         batches whose target is busy or gone — pours in reference
         FIFO order. Affinity is a placement preference, never a
-        gate: no batch waits for its target."""
+        gate: no batch waits for its target.
+
+        Then every busy worker without a stage takes one: at
+        `pipeline_depth` > 1 (the `DepthController`'s verdict for
+        models served batch after batch), or whatever the depth reads
+        when the model's batches join a running slot grid
+        (`joins_grid`)."""
         q = self._queue(model)
         out: List[Assignment] = []
         free = self._free_workers(workers)
@@ -968,7 +1008,7 @@ class Scheduler:
         for w, batch in zip(free, self._take_batches(model, len(free))):
             self.in_progress[w] = batch
             out.append(Assignment(worker=w, batch=batch))
-        if self.pipeline_depth > 1:
+        if self.pipeline_depth > 1 or model in self.joins_grid:
             stageable = [
                 w for w in workers
                 if w in self.in_progress and w not in self.prefetch
@@ -1408,6 +1448,7 @@ class Scheduler:
                 }
                 for m, c in self.costs.items()
             },
+            "joins_grid": sorted(self.joins_grid),
         }
 
     def restore(self, snap: Dict[str, Any]) -> None:
@@ -1416,6 +1457,7 @@ class Scheduler:
         self._job_counter = max(self._job_counter, int(snap["job_counter"]))
         for m, c in snap.get("costs", {}).items():
             self.costs[m] = ModelCost(**c)
+        self.joins_grid.update(snap.get("joins_grid", ()))
         self.queues = {
             m: deque(Batch(**b) for b in batches)
             for m, batches in snap.get("queues", {}).items()

@@ -28,6 +28,7 @@ TPU-specific deltas from the reference (SURVEY §7 hard part #2):
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import itertools
 import json
 import logging
@@ -70,6 +71,10 @@ _M_INFER = METRICS.histogram(
     "backend infer call per batch (device forward + dispatch)")
 _M_PUT = METRICS.histogram(
     "worker_put_seconds", "output JSON write + replicated store PUT")
+_M_JOINED = METRICS.counter(
+    "jobs_batches_joined_total",
+    "batches that entered their backend while another batch of the "
+    "same worker was still in its inference, per model")
 _M_ACKS = METRICS.counter(
     "coordinator_batch_acks_total",
     "worker batch ACKs processed by the coordinator, per model")
@@ -272,6 +277,12 @@ class JobService:
         self._staged: Optional[
             Tuple[Tuple[int, int], Batch, str, asyncio.Task]
         ] = None
+        # running batches whose backend has them (their `on_dispatch`
+        # fired, or their inference returned): a stage of a model that
+        # joins a running grid enters the backend behind these at
+        # once; and how many batches are inside their inference now
+        self._handed: set = set()
+        self._inferring = 0
         self._bg_tasks: set = set()
         # client-side completion futures; bounded so fire-and-forget
         # submitters don't leak (evicted callers fall back to polling)
@@ -638,7 +649,17 @@ class JobService:
         never answers. `prefill` (prefill-role members) is an
         `LMPrefillBackend` serving LM_PREFILL_REQUEST: it builds each
         batch's KV-cache slab and this service exposes the bytes on
-        the data plane for the decode primary to pull."""
+        the data plane for the decode primary to pull.
+
+        A `backend` that declares an `on_dispatch` parameter (the
+        `LMBackend` contract) is one whose batches JOIN a running slot
+        grid. That declared contract, not the model's name and not the
+        `DepthController`, decides how its batches are pipelined: the
+        scheduler stages a next batch on every worker that runs one
+        (`Scheduler.set_joins_grid`), and the worker lets a staged
+        batch enter the backend as soon as the batch before it has
+        been handed over (`_h_task_request`). A backend without the
+        parameter is served batch after batch, under the controller."""
         if group_backend is not None:
             self._lm_group_backends[name] = group_backend
         if prefill is not None:
@@ -649,9 +670,8 @@ class JobService:
             # LMBackend contract) opt in to promote-at-dispatch: the
             # staged next batch starts the moment this batch's prompts
             # are submitted to the backend's continuous-batching
-            # driver, instead of after its decode drains — the
-            # generic-path analog of the engine path's
-            # promote-at-dispatch (VERDICT r4 item 2).
+            # driver, instead of after its decode drains — and a stage
+            # that lands later, mid-drain, starts at once.
             try:
                 import inspect
 
@@ -665,6 +685,14 @@ class JobService:
             except (TypeError, ValueError):
                 self._backend_dispatch_aware[name] = False
                 self._backend_token_aware[name] = False
+            # the coordinator's half of the same fact: this model's
+            # batches JOIN a running slot grid, so the scheduler stages
+            # a next batch on every worker that runs one, whatever the
+            # depth controller reads (every node records it: leader,
+            # standby, workers)
+            self.scheduler.set_joins_grid(
+                name, self._backend_dispatch_aware[name]
+            )
         self.model_patterns[name] = tuple(patterns)
         if cost is not None:
             self.scheduler.set_cost(name, cost)
@@ -815,8 +843,11 @@ class JobService:
     def set_pipeline_depth(self, depth: Optional[int]) -> None:
         """`None` → adaptive (probe-and-commit DepthController, the
         product default); an int → static depth, controller off (the
-        bench's forced-comparison runs and reference-faithful depth-1
-        use this)."""
+        forced-comparison runs and reference-faithful depth-1 use
+        this). Either way the depth governs the models served batch
+        after batch; a model whose batches join a running slot grid
+        (`register_lm`, a backend declaring `on_dispatch`) is staged
+        at any depth."""
         if depth is None:
             self.depth_ctl = DepthController()
             self.scheduler.pipeline_depth = self.depth_ctl.depth
@@ -937,8 +968,9 @@ class JobService:
             # count counts as drift — the committed pipelining depth
             # re-validates against the pool that exists NOW
             self.depth_ctl.on_pool_size(len(pool))
-            queued = sum(len(q) for q in self.scheduler.queues.values())
-            self.scheduler.pipeline_depth = self.depth_ctl.tick(queued)
+            self.scheduler.pipeline_depth = self.depth_ctl.tick(
+                self.scheduler.probe_backlog()
+            )
         assigns = self.scheduler.schedule(
             pool, weights=self._pool_weights
         )
@@ -1259,9 +1291,14 @@ class JobService:
         if cur is not None and sat is not None and sat[0] == cur.key:
             self._assigned_at[msg.sender] = sat
             del self._staged_at[msg.sender]
-        if self.depth_ctl is not None and fresh_ack:
+        if (
+            self.depth_ctl is not None and fresh_ack
+            and st_pre.model not in self.scheduler.joins_grid
+        ):
             # adaptive depth: fold the ACK (and its stage walls) into
-            # the probe/drift machinery and apply what it decides
+            # the probe/drift machinery and apply what it decides. Not
+            # the ACKs of a model whose batches join a running grid:
+            # it is staged at any depth, so no phase measures it
             self.scheduler.pipeline_depth = self.depth_ctl.on_ack(
                 int(d.get("n_images", 0)),
                 fetch=float(d.get("fetch_time", 0.0)),
@@ -1927,7 +1964,18 @@ class JobService:
                 name=f"{self.node.me}-prep-{key[0]}-{key[1]}",
             )
             self._staged = (key, batch, msg.sender, prep)
-            if not self._running:
+            if self._running and self._handed.issuperset(
+                self._running
+            ) and self._joins_grid(batch.model):
+                # every running batch is already in the backend's
+                # hands and this model's batches join its running
+                # grid: enter now, behind them, instead of waiting out
+                # their drain (the slots they free would stand empty).
+                # A batch still on its way in promotes the stage at
+                # its `on_dispatch`, so the worker's batches reach the
+                # backend in the order the scheduler sent them.
+                self._promote_staged()
+            elif not self._running:
                 # UDP reorder: the stage outran its same-round primary.
                 # Hold it staged (executing it now would later be
                 # cancelled as a 'preemption' when the primary lands);
@@ -1949,6 +1997,7 @@ class JobService:
             for t in self._running.values():
                 t.cancel()
             self._running.clear()
+            self._handed.clear()
             if self._staged is not None and self._staged[0] != key:
                 self._staged[3].cancel()
                 self._staged = None
@@ -2024,12 +2073,30 @@ class JobService:
             self._staged[3].cancel()
             self._staged = None
 
+    def _joins_grid(self, model: str) -> bool:
+        """Will a batch of `model` join a running slot grid HERE: its
+        backend declares `on_dispatch` and this node is not serving
+        the model on a group engine (which takes batch after batch)."""
+        return bool(
+            self._backend_dispatch_aware.get(model)
+            and not self._group_serves(model)
+        )
+
+    def _batch_handed(self, key: Tuple[int, int]) -> None:
+        """The backend has batch `key` (its `on_dispatch` fired, or its
+        inference returned): whatever is staged may follow it in."""
+        if key in self._running:
+            self._handed.add(key)
+        self._promote_staged()
+
     def _promote_staged(self) -> None:
         """Start executing the staged batch (its prepare is already in
         flight). Called the moment the current batch's inference is
-        dispatched (engine path) or finished (generic path): the
-        coordinator performs the matching in_progress promotion when
-        the current batch's ACK arrives."""
+        dispatched (engine path, dispatch-aware backends) or finished
+        (generic path), and for a stage that lands after that moment
+        on a model that joins a running grid, at once
+        (`_h_task_request`): the coordinator performs the matching
+        in_progress promotion when the current batch's ACK arrives."""
         if self._staged is None:
             return
         key, batch, coordinator, prep = self._staged
@@ -2133,6 +2200,26 @@ class JobService:
                 _, old = self._decode_cache.popitem(last=False)
                 self._decode_cache_used -= old.nbytes
         return np.stack(out)
+
+    @contextlib.contextmanager
+    def _infer_stage(self, batch: Batch, stages: TraceContext, labels):
+        """A batch's `worker_infer` loop span. A batch that enters the
+        backend while another batch of this worker is still inside its
+        inference JOINED it (a continuous-batching grid holds both):
+        label `joined`, and the counter that says the two-batches-a-
+        worker rule engages."""
+        joined = int(self._inferring > 0)
+        if joined:
+            _M_JOINED.inc(model=batch.model)
+        self._inferring += 1
+        try:
+            with TRACER.loop_span(
+                "worker_infer", stages, node=self._me, model=batch.model,
+                joined=joined, **labels,
+            ):
+                yield
+        finally:
+            self._inferring -= 1
 
     async def _execute(
         self,
@@ -2252,10 +2339,7 @@ class JobService:
                 infer_token = CURRENT_CTXS.set(tuple(
                     infer_of.get(c.key, c) for c in CURRENT_CTXS.get()
                 ))
-            with TRACER.loop_span(
-                "worker_infer", stages, node=self._me, model=batch.model,
-                **labels,
-            ):
+            with self._infer_stage(batch, stages, labels):
                 if group_serving:
                     # formed-group PRIMARY: serve on the group's
                     # sharded engine (jobs/groups.py). The ACK
@@ -2286,20 +2370,21 @@ class JobService:
                     # next batch promotes the moment this batch's
                     # prompts enter the continuous-batching driver, so
                     # its decode JOINS the grid while this one drains
-                    # (VERDICT r4 item 2). The callback fires on the
-                    # driver thread — hop back to the loop.
+                    # (VERDICT r4 item 2); a stage that lands after
+                    # that moment enters at once (`_h_task_request`).
+                    # The callback fires on the driver thread — hop
+                    # back to the loop.
                     loop = asyncio.get_running_loop()
                     results, infer_time, cost = await be(
                         batch.model, paths,
                         on_dispatch=lambda: loop.call_soon_threadsafe(
-                            self._promote_staged
+                            self._batch_handed, batch.key
                         ),
                         **stream_kw,
                     )
-                    # also promote now: covers backends whose serial
-                    # mode never fires the callback, and a NEW stage
-                    # that landed mid-drain (engine path does the same)
-                    self._promote_staged()
+                    # also now: covers backends whose serial mode
+                    # never fires the callback
+                    self._batch_handed(batch.key)
                 else:
                     results, infer_time, cost = await be(
                         batch.model, paths, **stream_kw
@@ -2451,6 +2536,7 @@ class JobService:
             t = self._running.get(batch.key)
             if t is not None and t is asyncio.current_task():
                 del self._running[batch.key]
+                self._handed.discard(batch.key)
 
     async def _fetch_inputs(self, batch: Batch) -> List[str]:
         """Materialize the batch's images locally: local store hit if

@@ -52,6 +52,12 @@ slot occupancy, compile events, readback stalls), ``worker_*``
 ``jobs_pipeline_depth`` / ``jobs_depth_*`` (the probe-adaptive
 worker-pipelining controller: depth in force, per-phase probe-rate
 histogram by depth, probe-cycle counters by trigger and aborts),
+``jobs_batches_joined_total`` per model (batches that entered their
+backend while another batch of the same worker was still in its
+inference: a worker's second batch of a continuous-batching model
+joining the slot grid; the `worker_infer` loop span carries the same
+fact as its label ``joined``, and `lm_step`'s label ``waiting`` is the
+requests queued without a slot as a dispatch was issued),
 ``jobs_group_*`` (tensor-parallel worker groups, jobs/groups.py:
 ``jobs_group_formed`` gauge — 1 while every member is alive and
 schedulable, ``jobs_group_members_alive`` gauge,
@@ -148,6 +154,7 @@ line when you add the metric.
     cluster_suspicions_total         SWIM suspicion events
     coordinator_batch_acks_total     batch ACKs seen by the coordinator
     jobs_batch_exec_seconds          per-model batch execution wall
+    jobs_batches_joined_total        batches entering a backend beside another
     jobs_completed_total             jobs reaching terminal success
     jobs_depth_probe_aborts_total    depth probes aborted (stall/timeout)
     jobs_depth_probe_qps             probe-phase throughput by depth

@@ -111,14 +111,14 @@ SPAN_NAMES = (
     "marker",      # zero-duration exemplar marker (note_exemplar)
     # -- loop spans (Tracer.loop_span: serve loop, not per request) --
     "worker_fetch",      # worker: a batch's replica fetch + host decode
-    "worker_infer",      # worker: a batch's backend call
+    "worker_infer",      # worker: a batch's backend call (label joined)
     "worker_put",        # worker: a batch's output write + store PUT
     "store_op_put",      # replicated store PUT, every one (untraced too)
     "store_op_get",      # replicated store GET, every one (untraced too)
     "lm_weights_resident",  # LMServer built: the tree cast to its resident form
     "lm_idle",           # LM driver thread waiting with no work
     "lm_submit",         # LM driver: submit_many of the tickets taken this round
-    "lm_step",           # one decode dispatch, entry to exit
+    "lm_step",           # one decode dispatch, entry to exit (label waiting)
     "lm_dispatch",       # enqueue of the chunk (or propose/verify) program
     "lm_pack",           # issuing the packed readback's eager concatenate
     "lm_readback",       # the blocking np.asarray: host waits for device
@@ -127,6 +127,14 @@ SPAN_NAMES = (
     "lm_prefill_group",  # one bucket group's build/prefill/insert/sample/merge
     "lm_request",        # LM request: submit -> last token on the host
 )
+
+#: loop-span labels `Tracer.summary` (``profile spans``) averages beside
+#: the walls: `worker_infer`'s ``joined`` (1 when the batch entered its
+#: backend while another batch of the worker was still in inference)
+#: and `lm_step`'s ``waiting`` (requests queued without a slot as the
+#: dispatch was issued) say whether a worker's second batch keeps the
+#: slot grid fed
+SUMMARY_LABELS = ("joined", "waiting")
 
 #: the loop ring's size: ten minutes at the chat cell's rate (about 10
 #: spans a decode dispatch x 3.7 dispatches/s = 22,200; PERF.md §5)
@@ -566,14 +574,22 @@ class Tracer:
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         """count / total / mean / max seconds per loop-span name over
-        the ring (the CLI's ``profile spans``)."""
+        the ring (the CLI's ``profile spans``), and the mean of each
+        ``SUMMARY_LABELS`` label over the spans that carry it."""
         acc: Dict[str, List[float]] = {}
+        lab: Dict[str, Dict[str, List[float]]] = {}
         for d in self.loop_spans():
             acc.setdefault(d["name"], []).append(d["t1"] - d["t0"])
+            for k in SUMMARY_LABELS:
+                if k in d.get("lb", ()):
+                    lab.setdefault(d["name"], {}).setdefault(
+                        k, []).append(float(d["lb"][k]))
         return {
             name: {
                 "count": float(len(xs)), "total_s": sum(xs),
                 "mean_s": sum(xs) / len(xs), "max_s": max(xs),
+                **{f"{k}_mean": sum(v) / len(v)
+                   for k, v in lab.get(name, {}).items()},
             }
             for name, xs in sorted(acc.items())
         }

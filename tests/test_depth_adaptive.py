@@ -285,6 +285,57 @@ def test_probe_cycle_smoke_on_local_cluster(tmp_path):
 
 
 @pytest.mark.adaptive
+@pytest.mark.parametrize("joins,port", [(True, 23440), (False, 23460)],
+                         ids=["joins_a_grid", "batch_after_batch"])
+def test_the_probe_is_fed_only_by_models_it_governs(tmp_path, joins, port):
+    """The same job (8 batches, over the 4-batch probe backlog) through a
+    backend that declares `on_dispatch` and one that does not. The first
+    kind is staged from the first round with the controller at its
+    unprobed depth 1, and neither its backlog nor its ACKs reach the
+    controller (no "depth 1" phase would be in force for it): it stays
+    in warmup, no probe, no abort. The second kind is the controller's:
+    one full probe cycle."""
+    from _gridstub import MODEL, StubGrid, drain, grid_cluster, prompt_name
+
+    grid = StubGrid(8)
+
+    async def run():
+        async with grid_cluster(3, port, tmp_path, grid, joins) as c:
+            for sn in c.nodes.values():
+                sn.jobs.depth_ctl.probe_batches = 2
+                sn.jobs.depth_ctl.min_probe_backlog = 4
+            client = c.client()
+            for i in range(8):
+                await client.store.put_bytes(
+                    prompt_name(i, 3), b"1 2 3\n", timeout=20.0)
+            leader = next(
+                sn for sn in c.nodes.values() if sn.node.is_leader)
+            ctl = leader.jobs.depth_ctl
+            job_id = await client.jobs.submit_job(
+                MODEL, 64, timeout=15.0, retries=5)
+            staged_at_depth_1 = []
+            done = await drain(
+                grid, asyncio.ensure_future(
+                    client.jobs.wait_job(job_id, timeout=30.0)),
+                lambda: staged_at_depth_1.append(bool(
+                    ctl.depth == 1 and ctl.state == "warmup"
+                    and leader.jobs.scheduler.prefetch)))
+            assert done["total_queries"] == 64
+            if joins:
+                assert any(staged_at_depth_1)
+                assert ctl.state == "warmup" and ctl.depth == 1, (
+                    ctl.explain())
+                assert ctl.probes == 0 and ctl.aborted_probes == 0
+                assert leader.jobs.scheduler.pipeline_depth == 1
+            else:
+                assert not any(staged_at_depth_1)
+                assert ctl.state == "settled" and ctl.probes == 1, (
+                    ctl.explain())
+
+    asyncio.run(run())
+
+
+@pytest.mark.adaptive
 def test_leader_kill_mid_probe_recovers(tmp_path):
     """Chaos: the coordinator dies WHILE its controller is probing.
     Failover must complete the job exactly once (shadow relays), end

@@ -261,6 +261,25 @@ def test_driver_on_dispatch_fires_before_completion(params):
         drv.stop()
 
 
+def test_lm_step_says_how_many_requests_waited_without_a_slot(params):
+    """`lm_step`'s label `waiting`: requests queued in the server
+    without a slot as the dispatch is issued. Three requests over one
+    slot: two wait behind the first, one behind the second, none
+    behind the last; `profile spans` averages it."""
+    from dml_tpu.tracing import TRACER
+
+    srv = LMServer(params, CFG, max_slots=1, max_len=32, chunk=2)
+    TRACER.reset()
+    rids = srv.submit_many([np.array([1, 2, 3], np.int32)] * 3, 4)
+    assert len(srv.run(rids)) == 3
+    steps = TRACER.loop_spans("lm_step")
+    waited = [d["lb"]["waiting"] for d in steps]
+    assert waited == sorted(waited, reverse=True), waited
+    assert waited[0] == 2 and waited[-1] == 0 and 1 in waited
+    assert TRACER.summary()["lm_step"]["waiting_mean"] == pytest.approx(
+        sum(waited) / len(waited))
+
+
 def test_driver_validation_error_propagates_to_caller(params):
     from dml_tpu.inference.lm_server import LMDriver
 

@@ -5,6 +5,10 @@ These are the tests the reference never had (SURVEY §4): the
 preempt/requeue/failover state machine exercised deterministically.
 """
 
+import json
+
+import pytest
+
 from dml_tpu.jobs.cost_model import ModelCost, batch_exec_time, fair_split, query_rate
 from dml_tpu.jobs.scheduler import Scheduler
 
@@ -514,3 +518,87 @@ def test_class_weights_cap_by_availability():
     classes = [a.batch.slo_class for a in out]
     assert classes.count("interactive") == 1
     assert classes.count("batch") == 3
+
+
+# ------------------------------------- models whose batches join a slot grid
+# (a backend that declares `on_dispatch`: `Scheduler.set_joins_grid`); each
+# case over both kinds of model, at the depth controller's unprobed depth 1
+
+KINDS = pytest.mark.parametrize(
+    "joins", [True, False], ids=["joins_a_grid", "batch_after_batch"])
+
+
+def make_kind(joins):
+    s, clock = make()
+    s.set_joins_grid("a", joins)
+    assert s.pipeline_depth == 1
+    return s, clock
+
+
+@KINDS
+def test_depth_1_stages_only_a_model_that_joins_a_grid(joins):
+    s, _ = make_kind(joins)
+    s.submit_job(1, "a", ["x"], 50, "c")  # 5 batches of 10
+    out = s.schedule(["w1", "w2"])
+    assert [a.staged for a in out] == (
+        [False, False, True, True] if joins else [False, False])
+    assert set(s.in_progress) == {"w1", "w2"}
+    assert set(s.prefetch) == ({"w1", "w2"} if joins else set())
+    # two batches a worker, not N: nothing more goes out
+    assert s.schedule(["w1", "w2"]) == []
+    # an ACK promotes the stage and the next round stages again
+    s.on_batch_done("w1", 1, 0, 0.1, 10)
+    out = s.schedule(["w1", "w2"])
+    assert [(a.worker, a.staged) for a in out] == [("w1", joins)]
+
+
+@KINDS
+def test_the_probes_backlog_leaves_joining_models_out(joins):
+    s, _ = make_kind(joins)
+    s.submit_job(1, "a", ["x"], 50, "c")
+    s.submit_job(2, "b", ["x"], 30, "c")
+    assert s.probe_backlog() == (3 if joins else 8)
+
+
+@KINDS
+def test_snapshot_restore_keeps_what_the_backend_is(joins):
+    s, _ = make_kind(joins)
+    s.submit_job(1, "a", ["x"], 30, "c")
+    s.schedule(["w1"])
+    snap = json.loads(json.dumps(s.snapshot()))
+    r = Scheduler({}, now=Clock())
+    r.restore(snap)
+    assert ("a" in r.joins_grid) == joins
+    # in-flight batches folded back in order; staged the same way
+    out = r.schedule(["w1"])
+    assert [(a.batch.batch_id, a.staged) for a in out] == (
+        [(0, False), (1, True)] if joins else [(0, False)])
+
+
+@KINDS
+def test_a_standby_that_takes_over_stages_the_same_way(joins):
+    """The standby's own `register_lm` recorded the fact; its shadow
+    holds what the relays gave it, and it never assigned anything."""
+    primary, _ = make_kind(joins)
+    standby, _ = make_kind(joins)
+    primary.submit_job(1, "a", ["x"], 40, "c")
+    standby.submit_job(1, "a", ["x"], 40, "c", batch_size=10)
+    primary.schedule(["w1", "w2"])
+    primary.on_batch_done("w1", 1, 0, 0.1, 10)
+    standby.shadow_prune(1, 0, 10)
+    out = standby.schedule(["w1", "w2"])
+    assert [(a.batch.batch_id, a.staged) for a in out] == (
+        [(1, False), (2, False), (3, True)] if joins
+        else [(1, False), (2, False)])
+
+
+@KINDS
+def test_a_second_model_unstages_whatever_the_first_is(joins):
+    s, _ = make_kind(joins)
+    s.pipeline_depth = 2  # the other kind stages at depth 2 only
+    s.submit_job(1, "a", ["x"], 40, "c")
+    s.schedule(["w1", "w2"])
+    assert len(s.prefetch) == 2
+    s.submit_job(2, "b", ["x"], 20, "c")
+    s.schedule(["w1", "w2"])
+    assert not s.prefetch and len(s.pop_revoked_stages()) == 2

@@ -412,6 +412,23 @@ def _summary_folds_the_ring_per_name(t, monkeypatch):
     assert t.summary() == {} and t.stats()["loop_recorded"] == 0
 
 
+def _summary_averages_the_labels_that_say_the_grid_is_fed(t, monkeypatch):
+    """`profile spans` lists `worker_infer`'s `joined` and `lm_step`'s
+    `waiting` as means over the spans that carry them, and no other
+    label."""
+    t.loop_record("worker_infer", 1.0, 2.0, joined=0, batch=0)
+    t.loop_record("worker_infer", 1.5, 2.5, joined=1, batch=1)
+    t.loop_record("lm_step", 3.0, 3.5, waiting=6, occupancy=8)
+    t.loop_record("lm_step", 3.5, 4.0, waiting=2, occupancy=8)
+    t.loop_record("lm_step", 4.0, 4.5, occupancy=8)  # an older writer
+    t.loop_record("lm_place", 4.0, 4.25, requests=3)
+    s = t.summary()
+    assert s["worker_infer"]["joined_mean"] == 0.5
+    assert s["lm_step"]["waiting_mean"] == 4.0
+    assert s["lm_step"]["count"] == 3
+    assert set(s["lm_place"]) == {"count", "total_s", "mean_s", "max_s"}
+
+
 def _stepped_wall_clock_leaves_durations_right(t, monkeypatch):
     """time.time() jumps back an hour inside the span: the duration
     stays the monotonic one and the span still ends after it starts."""
@@ -461,6 +478,7 @@ def _annotation_skipped_where_jax_is_not_loaded(t, monkeypatch):
     _loop_parent_and_trace_ids,
     _wall_of_maps_the_monotonic_clock,
     _summary_folds_the_ring_per_name,
+    _summary_averages_the_labels_that_say_the_grid_is_fed,
     _stepped_wall_clock_leaves_durations_right,
     _annotation_entered_once_per_span,
     _annotation_skipped_where_jax_is_not_loaded,
