@@ -48,6 +48,13 @@ RMS_EPS = 1e-6  # flax nn.RMSNorm default, as used by TransformerLM
 LAYER_KINDS = {"M": "state-space", "*": "attention", "E": "expert"}
 ROUTER_SCORING = ("softmax", "sigmoid")
 ACTIVATIONS = ("silu", "relu2")
+#: what an attention layer caches a token: K and V rows of `KV x D`
+#: ("grouped": multi-head, grouped-query, multi-query), or ONE row of a
+#: compressed latent and a shared rope key ("latent": `LatentConfig`)
+ATTENTION_KINDS = ("grouped", "latent")
+#: which columns rope rotates together: the two halves of a head
+#: (i, i + D/2), or neighbours (2i, 2i + 1)
+ROPE_PAIRINGS = ("half", "interleaved")
 
 
 @dataclass(frozen=True)
@@ -88,8 +95,62 @@ class SSMConfig:
 
 
 @dataclass(frozen=True)
+class LatentConfig:
+    """Latent attention's widths (multi-head latent attention, as the
+    DeepSeek-V3 family publishes it): the query's low rank `q_rank`,
+    the cached latent's `kv_rank`, and a head's three parts: `nope_dim`
+    key columns that carry no position, `rope_dim` that rope rotates
+    (ONE such key a token, shared by every head), `v_dim` values."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+    def __post_init__(self):
+        if min(self.q_rank, self.kv_rank, self.nope_dim, self.v_dim) < 1 or (
+                self.rope_dim < 2 or self.rope_dim % 2):
+            raise ValueError(f"latent attention widths {self}")
+        if self.v_dim > self.kv_rank:
+            raise ValueError(
+                f"v_dim {self.v_dim} over kv_rank {self.kv_rank}: the "
+                f"absorbed form reads a head's values out of the latent")
+
+    @property
+    def key_width(self) -> int:
+        """A head's query and key width in the expanded form."""
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def row_width(self) -> int:
+        """Values a token caches a layer: the latent and the rope key."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def row_stride(self) -> int:
+        """Columns a cached row takes: `row_width` filled up with zeros
+        to whole tiles of 128 lanes. The chip lays a row out so whatever
+        the program says (576 values take 640), and a leaf that says 576
+        is given ANOTHER layout at a program's edge (rows along the
+        lanes), which every dispatch then copies the whole grid into the
+        kernel's and back (ahead-of-time compile, PR 36: two copies of
+        3 GB a dispatch and 4.1 GB of temporaries). The leaf states the
+        stride, so programs hand it on as it is."""
+        return -(-self.row_width // 128) * 128
+
+
+@dataclass(frozen=True)
 class LMConfig:
     """Shape config mirroring TransformerLM's fields.
+
+    What a layer caches a token goes by its kind of attention
+    (`ATTENTION_KINDS`): grouped (`latent` None), K and V rows of
+    `kv_heads x head_dim` each (int8 with scales under `kv_quant`);
+    latent (`latent` holds its widths), one row of `latent.row_width`
+    values, the normalised latent and the roped shared key
+    (`_latent_attention`), which both forms of that attention read. A
+    state-space layer caches no rows (`init_cache`).
 
     `kv_quant=True` stores the KV cache as int8 with one f32 scale per
     (position, kv-head) — ~1.9x less cache HBM than bf16, i.e. ~2x the
@@ -140,8 +201,21 @@ class LMConfig:
     router_scoring: str = "softmax"
     router_scale: float = 1.0
     activation: str = "silu"
+    latent: Optional[LatentConfig] = None  # latent attention's widths
+    rope_pairing: str = "half"  # of `ROPE_PAIRINGS`
 
     def __post_init__(self):
+        if self.rope_pairing not in ROPE_PAIRINGS:
+            raise ValueError(f"unknown rope pairing {self.rope_pairing!r}")
+        if self.latent is not None and (
+                self.kv_quant or self.qk_norm or self.n_kv_heads is not None
+                or self.d_head is not None or self.layer_pattern is not None
+                or self.attention_mask != "causal" or not self.rope):
+            raise ValueError(
+                "latent attention caches one bf16/f32 row a token under "
+                "the causal mask in a stack of classic blocks: no "
+                "kv_quant, qk_norm, n_kv_heads, d_head, layer_pattern, "
+                "block_causal mask, and rope on")
         kv = self.n_kv_heads
         if kv is not None and (kv <= 0 or self.n_heads % kv):
             raise ValueError(
@@ -206,12 +280,28 @@ class LMConfig:
         return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
 
 
+#: every leaf a layer of a slot-grid cache can hold: its kind
+#: (`state_bytes`) and the axis its rows run along (None: state that
+#: has no rows). The one place that knows; an unknown leaf is an error.
+CACHE_LEAVES = {
+    "k": ("kv", 2), "v": ("kv", 2), "k_q": ("kv", 2), "v_q": ("kv", 2),
+    "k_s": ("kv", 3), "v_s": ("kv", 3), "latent": ("latent", 2),
+    "conv": ("conv", None), "ssm": ("scan", None),
+}
+
+
 def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
-    """Pre-allocated KV cache: one [B, KV, max_len, D] pair per layer
+    """Pre-allocated cache, by the layer's kind of attention. Grouped
+    attention: one [B, KV, max_len, D] pair per layer
     — KV = n_kv_heads under GQA, so the cache (and each decode step's
     HBM reads of it) shrinks n_heads/n_kv_heads-fold. Under
     `cfg.kv_quant` each tensor is int8 plus a [B, KV, max_len, 1] f32
-    scale (symmetric per-(position, head) quantization).
+    scale (symmetric per-(position, head) quantization). Latent
+    attention: ONE leaf a layer, `latent` [B, 1, max_len, row_stride]
+    (the normalised latent | the roped shared key | zeros to whole
+    lane tiles: `LatentConfig`), in the layout of a single-KV-head
+    plane so that everything that cuts, copies or streams rows treats
+    it as one.
 
     Layout is head-major ([B, KV, T, D], not [B, T, KV, D]): each
     head's rows are a contiguous [T, D] plane, which is what the
@@ -232,6 +322,9 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
     sshape = (batch, cfg.kv_heads, 1, max_len)
 
     def layer(kind):
+        if cfg.latent is not None:
+            return {"latent": jnp.zeros(
+                (batch, 1, max_len, cfg.latent.row_stride), cfg.dtype)}
         if kind == "E":  # an expert layer carries nothing
             return None
         if kind == "M":
@@ -259,24 +352,25 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
 
 
 def cache_rows(cache: Dict[str, Any]) -> int:
-    """Rows a slot's K/V planes hold (`max_len`), read off the first
-    attention layer's leaves; 0 for a cache with no attention layer."""
+    """Rows a slot holds (`max_len`), read off the first leaf that has
+    rows (`CACHE_LEAVES`); 0 for a cache of state alone."""
     for lay in cache.values():
-        for key in ("k", "k_q"):
-            if key in lay:
-                return lay[key].shape[2]
+        for key, leaf in lay.items():
+            axis = CACHE_LEAVES[key][1]
+            if axis is not None:
+                return leaf.shape[axis]
     return 0
 
 
 def state_bytes(cache: Dict[str, Any]) -> Dict[str, int]:
-    """Bytes of a slot-grid cache by kind of leaf: `kv` (attention
-    layers' rows and scales), `conv` and `scan` (a state-space layer's
-    convolution window and recurrent state)."""
-    out = {"kv": 0, "conv": 0, "scan": 0}
+    """Bytes of a slot-grid cache by kind of leaf: `kv` (grouped
+    attention's rows and scales), `latent` (latent attention's rows),
+    `conv` and `scan` (a state-space layer's convolution window and
+    recurrent state)."""
+    out = {"kv": 0, "latent": 0, "conv": 0, "scan": 0}
     for lay in cache.values():
         for key, leaf in lay.items():
-            kind = {"conv": "conv", "ssm": "scan"}.get(key, "kv")
-            out[kind] += int(leaf.size) * leaf.dtype.itemsize
+            out[CACHE_LEAVES[key][0]] += int(leaf.size) * leaf.dtype.itemsize
     return out
 
 
@@ -401,8 +495,9 @@ def expert_ffn(
     SiLU or squared ReLU. Where the tree holds `latent_down` and
     `latent_up` the experts live in a latent width between the two
     (x -> latent, experts, -> hidden; the router still reads x);
-    where it holds `shared_up` and `shared_down`, an ungated expert
-    every token takes is added in the hidden width.
+    where it holds `shared_up` and `shared_down`, an expert every
+    token takes is added in the hidden width (gated where the tree
+    holds `shared_gate` too, plain otherwise).
 
     The tree holds `held = w_up.shape[0]` experts: routed experts
     `first .. first + held - 1`. Assignments to the others add
@@ -431,6 +526,7 @@ def expert_ffn(
     act = _activation(activation)
     latent = "latent_down" in moe
     shared = "shared_up" in moe
+    shared_gated = "shared_gate" in moe
 
     grouped = functools.partial(_grouped_matmul, mesh=mesh)
 
@@ -474,9 +570,10 @@ def expert_ffn(
         if latent:
             out = out @ kernel_of(moe["latent_up"], dtype)
         if shared:
-            out = out + act(
-                tok @ kernel_of(moe["shared_up"], dtype)
-            ) @ kernel_of(moe["shared_down"], dtype)
+            up = tok @ kernel_of(moe["shared_up"], dtype)
+            mid = (act(tok @ kernel_of(moe["shared_gate"], dtype)) * up
+                   if shared_gated else act(up))
+            out = out + mid @ kernel_of(moe["shared_down"], dtype)
         return out, counts
 
     d = y.shape[-1]
@@ -658,9 +755,97 @@ def ssm_mixer(
         "conv": window.astype(cfg.dtype), "ssm": h}
 
 
-def _attention(blk, cfg: LMConfig, y, positions, attn_fn):
+def rope_interleaved(x: jax.Array, positions: jax.Array,
+                     base: float = 10000.0) -> jax.Array:
+    """`rope` under the other pairing: columns (2i, 2i + 1) rotate
+    together by `positions * base ** (-2i / D)`. x [B, T, H, D];
+    positions [T] or [B, T]. The pair's partner comes by ONE product
+    with a constant signed permutation (exact in any dtype: each output
+    is a single +-x), which keeps the columns on the lanes where a
+    reshape to [..., D/2, 2] would put a 2 there."""
+    d = x.shape[-1]
+    freqs = base ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
+    angles = jnp.repeat(
+        positions[..., None].astype(jnp.float32) * freqs, 2, axis=-1)
+    if positions.ndim == 1:
+        angles = angles[None]
+    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    i = jnp.arange(d)
+    # partner[2i] = -x[2i + 1], partner[2i + 1] = x[2i]
+    swap = jnp.zeros((d, d), x.dtype).at[i ^ 1, i].set(
+        jnp.where(i % 2 == 0, -1, 1).astype(x.dtype))
+    partner = jnp.matmul(x, swap, precision=jax.lax.Precision.HIGHEST)
+    return (x * cos + partner * sin).astype(x.dtype)
+
+
+def _rope_of(cfg: LMConfig):
+    return rope_interleaved if cfg.rope_pairing == "interleaved" else rope
+
+
+def _latent_attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed):
+    """Latent attention on normalised `y`, the ONE copy of its
+    projections, norms and rope, in its two algebraically equal forms:
+    (its output through `proj`, the rows to cache [B, T, row_width]).
+
+    Query: `c_q = RMSNorm(q_a y)`, `[q_nope | q_rope] = q_b c_q` a
+    head, rope on `q_rope`. Keys and values: `[c | k_r] = kv_a y`, `c
+    <- RMSNorm(c)`, `k_r <- rope(k_r)`, one rope key a token for every
+    head; a head's `k_nope = c w_uk[h]` and `v = c w_uv[h]` (the
+    published `kv_b`, held as its two halves a head, [H, kv_rank, .]
+    each). Scores are `(q_nope . k_nope + q_rope . k_r) / sqrt(nope_dim
+    + rope_dim)`. What a token caches is `[c | k_r]`.
+
+    Expanded (`absorbed` False; prefill): per-head keys `[k_nope |
+    k_r]` and values are rebuilt from the rows of THIS call and go
+    through `attn_fn(q, k, v)`, keys wider than values. Absorbed
+    (decode, the multi-token cached step): `q~ = w_uk[h] q_nope` (a
+    head's query in the latent's own space), `attn_fn([q~ | q_rope],
+    rows)` attends the cached rows as ONE shared head of key width
+    `row_width` whose values are its first `kv_rank` columns, and
+    `w_uv[h]` is applied after: no per-head key or value is ever
+    made."""
+    m, h, dt = cfg.latent, cfg.n_heads, cfg.dtype
+    b, t = y.shape[:2]
+    rope_fn = _rope_of(cfg)
+    c_q = _rms_norm(y @ kernel_of(blk["q_a"], dt), blk["q_a_norm"]["scale"],
+                    dt, cfg.norm_eps)
+    q = (c_q @ kernel_of(blk["q_b"], dt)).reshape(b, t, h, m.key_width)
+    q_nope = q[..., :m.nope_dim]
+    q_rope = rope_fn(q[..., m.nope_dim:], positions, cfg.rope_theta)
+    kva = y @ kernel_of(blk["kv_a"], dt)
+    c = _rms_norm(kva[..., :m.kv_rank], blk["kv_a_norm"]["scale"], dt,
+                  cfg.norm_eps)
+    k_r = rope_fn(kva[..., None, m.kv_rank:], positions, cfg.rope_theta)
+    rows = jnp.concatenate([c, k_r[:, :, 0]], axis=-1)
+    w_uk, w_uv = kernel_of(blk["w_uk"], dt), kernel_of(blk["w_uv"], dt)
+    f32 = jnp.float32
+    if absorbed:
+        q_lat = jnp.einsum("bthn,hcn->bthc", q_nope, w_uk,
+                           preferred_element_type=f32).astype(dt)
+        o_lat = attn_fn(jnp.concatenate([q_lat, q_rope], axis=-1), rows)
+        attn = jnp.einsum("bthc,hcv->bthv", o_lat.astype(dt), w_uv,
+                          preferred_element_type=f32)
+    else:
+        k_nope = jnp.einsum("btc,hcn->bthn", c, w_uk,
+                            preferred_element_type=f32).astype(dt)
+        v = jnp.einsum("btc,hcv->bthv", c, w_uv,
+                       preferred_element_type=f32).astype(dt)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_r, (b, t, h, m.rope_dim))], axis=-1)
+        attn = attn_fn(jnp.concatenate([q_nope, q_rope], axis=-1), k, v)
+    attn = attn.reshape(b, t, h * m.v_dim).astype(dt)
+    return attn @ kernel_of(blk["proj"], dt), rows
+
+
+def _attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed=False):
     """The attention mixer on normalised `y`: (its output through
-    `proj`, k, v)."""
+    `proj`, k, v); under latent attention (the rows to cache, None) in
+    k's and v's place, by the form `absorbed` names
+    (`_latent_attention`)."""
+    if cfg.latent is not None:
+        out, rows = _latent_attention(
+            blk, cfg, y, positions, attn_fn, absorbed)
+        return out, rows, None
     b, t = y.shape[:2]
     h, hd, kv, qw = cfg.n_heads, cfg.head_dim, cfg.kv_heads, cfg.q_width
     qkv = y @ kernel_of(blk["qkv"], cfg.dtype)  # [B, T, qw + 2*kv*hd]
@@ -671,8 +856,8 @@ def _attention(blk, cfg: LMConfig, y, positions, attn_fn):
         q = _rms_norm(q, blk["q_norm"]["scale"], cfg.dtype, cfg.norm_eps)
         k = _rms_norm(k, blk["k_norm"]["scale"], cfg.dtype, cfg.norm_eps)
     if cfg.rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = _rope_of(cfg)(q, positions, cfg.rope_theta)
+        k = _rope_of(cfg)(k, positions, cfg.rope_theta)
     v = v.reshape(b, t, kv, hd)
     attn = attn_fn(q, k, v)  # k/v carry kv heads; the closure decides
     attn = attn.reshape(b, t, qw).astype(cfg.dtype)
@@ -681,7 +866,11 @@ def _attention(blk, cfg: LMConfig, y, positions, attn_fn):
 
 def _feed_forward(blk, cfg: LMConfig, y, experts, mesh):
     """The feed-forward mixer on normalised `y`: the expert layer where
-    the block holds one, else the two-matrix MLP."""
+    the block holds one (`moe`), else the dense MLP: gated,
+    `down(act(gate y) * up y)`, where the block holds a `gate` matrix,
+    else the two-matrix `down(act(up y))`. A stack may hold both kinds
+    (leading dense layers under expert layers): the block's own leaves
+    decide."""
     if "moe" in blk:
         out, counts = expert_ffn(
             blk["moe"], y, cfg.dtype, cfg.experts_per_token,
@@ -693,8 +882,12 @@ def _feed_forward(blk, cfg: LMConfig, y, experts, mesh):
         if experts is not None:
             experts["counts"].append(counts)
         return out
-    y = y @ kernel_of(blk["up"], cfg.dtype)
-    y = _activation(cfg.activation)(y)
+    act = _activation(cfg.activation)
+    if "gate" in blk:
+        y = act(y @ kernel_of(blk["gate"], cfg.dtype)) * (
+            y @ kernel_of(blk["up"], cfg.dtype))
+    else:
+        y = act(y @ kernel_of(blk["up"], cfg.dtype))
     return y @ kernel_of(blk["down"], cfg.dtype)
 
 
@@ -708,6 +901,7 @@ def _apply_block(
     mesh: Optional[Mesh] = None,
     kind: Optional[str] = None,
     ssm_fn=None,  # (ssm subtree, y [B,T,d]) -> [B,T,d]
+    absorbed: bool = False,  # latent attention's form (`_latent_attention`)
 ) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
     """ONE layer — the single copy of the layer math that decode (T=1,
     cache attention, one recurrence step) and prefill (T=Tp, flash
@@ -734,7 +928,7 @@ def _apply_block(
     """
     if kind is None:
         y = _rms_norm(x, blk["ln_attn"]["scale"], cfg.dtype, cfg.norm_eps)
-        out, k, v = _attention(blk, cfg, y, positions, attn_fn)
+        out, k, v = _attention(blk, cfg, y, positions, attn_fn, absorbed)
         x = x + out
         y = _rms_norm(x, blk["ln_mlp"]["scale"], cfg.dtype, cfg.norm_eps)
         return x + _feed_forward(blk, cfg, y, experts, mesh), k, v
@@ -852,12 +1046,70 @@ def decode_block_rows(
         return None
     from ..ops.decode_attention import block_rows
 
+    if cfg.latent is not None:  # one shared plane of latent rows
+        return block_rows(1, cfg.latent.row_stride, cfg.dtype, max_len)
     tp = mesh.shape["tp"] if heads_axis(
         mesh, cfg.n_heads, cfg.kv_heads) else 1
     return block_rows(
         cfg.kv_heads // tp, cfg.head_dim,
         jnp.int8 if cfg.kv_quant else cfg.dtype, max_len,
     )
+
+
+def _write_rows(c: jax.Array, u: jax.Array, pos: jax.Array,
+                axis: int) -> jax.Array:
+    """Cache leaf `c` with slot b's rows `u[b]` written from row
+    `pos[b]` along `axis`. Per-slot writes are an UNROLLED chain of
+    dynamic_update_slice — a vmap over per-slot positions lowers to a
+    scatter, and XLA scatters on TPU copy the whole operand (measured:
+    the copy tripled decode's cache traffic)."""
+    for bi in range(c.shape[0]):
+        start = [bi] + [0] * (c.ndim - 1)
+        start[axis] = pos[bi]
+        c = jax.lax.dynamic_update_slice(c, u[bi : bi + 1], start)
+    return c
+
+
+def _latent_rows(cfg: LMConfig, rows: jax.Array) -> jax.Array:
+    """The rows `_latent_attention` hands back [B, T, row_width] as the
+    cache holds them: [B, 1, T, row_stride], zeros in the spare
+    columns (a query's spare columns are zeros too, so they add nothing
+    to a score)."""
+    m = cfg.latent
+    return jnp.pad(rows[:, None].astype(cfg.dtype),
+                   ((0, 0),) * 3 + ((0, m.row_stride - m.row_width),))
+
+
+def _latent_cached(cfg: LMConfig, leaf: jax.Array, q: jax.Array,
+                   rows: jax.Array, pos: jax.Array, lengths: jax.Array,
+                   valid: Optional[jax.Array], mask_block: int = 1):
+    """The absorbed form against the cache, for both cached steps:
+    write `rows` [B, Q, W] into `leaf` [B, 1, T, S] from `pos` (W the
+    row's width, S its stride: `_latent_rows`), then attend q [B, Q,
+    H, W] over slot b's rows < lengths[b] (query i of Q stopping short
+    as `decode_attention` says). Returns (the heads' outputs in the
+    latent's space [B, Q, H, kv_rank] f32, the leaf).
+
+    On a TPU the Pallas kernel reads each live block ONCE, as keys and
+    as values (`decode_attention`'s shared plane); elsewhere (`valid`
+    [B, Q, T] given) an einsum over the grid in float32, the oracle."""
+    m = cfg.latent
+    scale = m.key_width ** -0.5
+    leaf = _write_rows(leaf, _latent_rows(cfg, rows), pos, axis=2)
+    q = jnp.pad(q, ((0, 0),) * 3 + ((0, m.row_stride - m.row_width),))
+    if valid is None:
+        from ..ops.decode_attention import decode_attention
+
+        return decode_attention(
+            q, leaf, None, lengths, scale=scale, v_width=m.kv_rank,
+            mask_block=mask_block), leaf
+    lat = leaf[:, 0].astype(jnp.float32)  # [B, T, W]
+    s = jnp.einsum("bqhw,btw->bhqt", q.astype(jnp.float32), lat) * scale
+    vmask = valid[:, None]
+    s = jnp.where(vmask, s, -1e30)
+    # zeros for an empty slot, as the kernel returns
+    p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
+    return jnp.einsum("bhqt,btc->bqhc", p, lat[..., :m.kv_rank]), leaf
 
 
 def batched_decode_step(
@@ -901,7 +1153,7 @@ def batched_decode_step(
     # per-slot validity: slot b sees cache rows < lengths[b]
     valid = jnp.arange(max_len)[None, :] < lengths[:, None]  # [B, T]
     use_kernel = uses_decode_kernel()
-    if use_kernel:
+    if use_kernel and cfg.latent is None:
         from ..ops.decode_attention import decode_attention
 
         ax = heads_axis(mesh, cfg.n_heads, cfg.kv_heads)
@@ -924,22 +1176,17 @@ def batched_decode_step(
             out, new_cache[name] = ssm_mixer(p, cfg, y, cache[name])
             return out
 
-        def attn_fn(q, k, v, name=name):
-            # k/v arrive [B, 1, KV, D]; the cache is head-major.
-            # Per-slot writes are an UNROLLED chain of
-            # dynamic_update_slice — a vmap over per-slot positions
-            # lowers to a scatter, and XLA scatters on TPU copy the
-            # whole operand (measured: the copy tripled decode's
-            # cache traffic)
-            def upd(c, u, axis):
-                for bi in range(b):
-                    start = [bi] + [0] * (c.ndim - 1)
-                    start[axis] = pos[bi]
-                    c = jax.lax.dynamic_update_slice(
-                        c, u[bi : bi + 1], start
-                    )
-                return c
+        def latent_fn(q, rows, name=name):
+            out, leaf = _latent_cached(
+                cfg, cache[name]["latent"], q, rows, pos, lengths,
+                None if use_kernel else valid[:, None])
+            new_cache[name] = {"latent": leaf}
+            return out
 
+        def attn_fn(q, k, v, name=name):
+            # k/v arrive [B, 1, KV, D]; the cache is head-major
+            # (`_write_rows` on how the rows are written)
+            upd = functools.partial(_write_rows, pos=pos)
             kh = jnp.swapaxes(k, 1, 2)  # [B, KV, 1, D]
             vh = jnp.swapaxes(v, 1, 2)
             if cfg.kv_quant:
@@ -985,8 +1232,9 @@ def batched_decode_step(
             return attn.reshape(b, 1, cfg.n_heads, hd)
 
         x, _, _ = _apply_block(
-            params[name], cfg, x, positions, attn_fn, experts, mesh,
-            kind, ssm_fn)
+            params[name], cfg, x, positions,
+            attn_fn if cfg.latent is None else latent_fn, experts, mesh,
+            kind, ssm_fn, absorbed=True)
 
     return _head(params, cfg, x), new_cache
 
@@ -1062,9 +1310,11 @@ def batched_block_step(
     positions = pos[:, None] + jnp.arange(t)[None, :]  # [B, T] per-example
     back = jnp.asarray(_rows_back(t, mask_block), jnp.int32)
     lengths = pos + t if live is None else jnp.where(live, pos + t, 0)
-    # the kernel takes T * G rows a KV head in whole sublane tiles
-    use_kernel = uses_decode_kernel() and (t * grp) % 8 == 0
-    if use_kernel:
+    # the kernel takes T * G rows a KV head in whole sublane tiles (the
+    # latent plane is one KV head under all H query heads)
+    per_kv = cfg.n_heads if cfg.latent is not None else grp
+    use_kernel = uses_decode_kernel() and (t * per_kv) % 8 == 0
+    if use_kernel and cfg.latent is None:
         from ..ops.decode_attention import decode_attention
 
         ax = heads_axis(mesh, cfg.n_heads, cfg.kv_heads)
@@ -1078,7 +1328,7 @@ def batched_block_step(
             + ((c_spec, c_spec) if cfg.kv_quant else ()),
             out_specs=q_spec,
         )
-    else:
+    if not use_kernel:
         # per-(slot, query) validity: query i sees rows < its limit
         valid = (
             jnp.arange(max_len)[None, None, :]
@@ -1089,19 +1339,17 @@ def batched_block_step(
     for i, kind in enumerate(cfg.kinds):
         name = f"block_{i}"
 
+        def latent_fn(q, rows, name=name):
+            out, leaf = _latent_cached(
+                cfg, cache[name]["latent"], q, rows, pos, lengths,
+                None if use_kernel else valid, mask_block)
+            new_cache[name] = {"latent": leaf}
+            return out
+
         def attn_fn(q, k, v, name=name):
             # k/v arrive [B, T, KV, D]; write each slot's contiguous
-            # [KV, T, D] block at its own start row (unrolled — see
-            # batched_decode_step on why not a vmap'd scatter)
-            def upd(c, u, axis):
-                for bi in range(b):
-                    start = [bi] + [0] * (c.ndim - 1)
-                    start[axis] = pos[bi]
-                    c = jax.lax.dynamic_update_slice(
-                        c, u[bi : bi + 1], start
-                    )
-                return c
-
+            # [KV, T, D] block at its own start row (`_write_rows`)
+            upd = functools.partial(_write_rows, pos=pos)
             kh = jnp.swapaxes(k, 1, 2)  # [B, KV, T, D]
             vh = jnp.swapaxes(v, 1, 2)
             if cfg.kv_quant:
@@ -1146,7 +1394,9 @@ def batched_block_step(
             return attn.reshape(b, t, cfg.n_heads, hd)
 
         x, _, _ = _apply_block(
-            params[name], cfg, x, positions, attn_fn, experts, mesh, kind)
+            params[name], cfg, x, positions,
+            attn_fn if cfg.latent is None else latent_fn, experts, mesh,
+            kind, absorbed=True)
 
     # logits at EVERY position (not _head's single-row squeeze): the
     # verifier needs the target's next-token argmax after each
@@ -1251,6 +1501,9 @@ def prefill(
             ssm_fn=ssm_fn,
         )
         if k is None:
+            continue
+        if cfg.latent is not None:  # k: the rows to cache [B, Tp, W]
+            cache[name] = {"latent": jnp.pad(_latent_rows(cfg, k), pad4)}
             continue
         kh = jnp.swapaxes(k, 1, 2)  # [B, KV, Tp, D] — cache layout
         vh = jnp.swapaxes(v, 1, 2)

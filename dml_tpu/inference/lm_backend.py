@@ -35,7 +35,8 @@ log = logging.getLogger(__name__)
 from ..jobs.cost_model import ModelCost, lm_request_forwards
 from ..tracing import current_all_ctxs
 from .generate import (
-    ACTIVATIONS, LAYER_KINDS, ROUTER_SCORING, LMConfig, SSMConfig,
+    ACTIVATIONS, ATTENTION_KINDS, LAYER_KINDS, ROPE_PAIRINGS, ROUTER_SCORING,
+    LatentConfig, LMConfig, SSMConfig,
 )
 from .lm_server import REMASKING, BlockDiffusion, LMDriver, LMServer
 
@@ -108,10 +109,15 @@ _ARCH_KEYS = (
     "expert_d_ff", "gated", "experts_held", "attention_mask",
     "block_length", "param_dtype", "layer_pattern", "ssm", "rope",
     "norm_eps", "router", "expert_latent", "shared_expert_d_ff",
-    "activation",
+    "activation", "attention", "latent_attention", "rope_pairing",
+    "dense_layers",
 )
 _SSM_KEYS = ("heads", "head_dim", "state", "groups", "conv_kernel", "chunk")
 _ROUTER_KEYS = ("scoring", "bias", "scale")
+#: latent attention's widths under the names the DeepSeek-V3 family's
+#: configs publish them by, in `LatentConfig`'s order
+_LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim")
 #: the range a state-space head's initial step size is drawn from
 #: (log-uniform) and its floor: the `nemotron_h` configs' `time_step_min`,
 #: `time_step_max`, `time_step_floor`, which shape the initialisation only
@@ -151,6 +157,48 @@ def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
     a["expert_latent"] = int(spec.get("expert_latent", 0) or 0)
     a["shared_expert_d_ff"] = int(spec.get("shared_expert_d_ff", 0) or 0)
     a["activation"] = spec.get("activation", "silu")
+    a["attention"] = spec.get("attention", "grouped")
+    a["latent_attention"] = spec.get("latent_attention")
+    a["rope_pairing"] = spec.get("rope_pairing", "half")
+    a["dense_layers"] = int(spec.get("dense_layers", 0) or 0)
+    if a["attention"] not in ATTENTION_KINDS:
+        raise ValueError(
+            f"unknown attention {a['attention']!r} "
+            f"({' | '.join(ATTENTION_KINDS)})")
+    if a["rope_pairing"] not in ROPE_PAIRINGS:
+        raise ValueError(
+            f"unknown rope_pairing {a['rope_pairing']!r} "
+            f"({' | '.join(ROPE_PAIRINGS)})")
+    if (a["attention"] == "latent") != (a["latent_attention"] is not None):
+        raise ValueError(
+            f"attention {a['attention']!r} with latent_attention "
+            f"{a['latent_attention']!r}: latent attention and its widths "
+            f"come together")
+    if a["latent_attention"] is not None:
+        lat = a["latent_attention"]
+        if not isinstance(lat, dict) or set(lat) != set(_LATENT_KEYS):
+            raise ValueError(
+                f"latent_attention {lat!r}: exactly {_LATENT_KEYS}")
+        a["latent_attention"] = LatentConfig(
+            *(int(lat[k]) for k in _LATENT_KEYS))
+        # what a latent row cannot be, or what no code here does with one
+        for key, why in (
+                ("kv_quant", "its one row a token is cached unquantized"),
+                ("qk_norm", "its norms are on the two latents, not a head"),
+                ("n_kv_heads", "every head reads the one shared latent"),
+                ("head_dim", "a head's widths are latent_attention's"),
+                ("layer_pattern", "it is served in classic blocks")):
+            if spec.get(key):
+                raise ValueError(f"{key} under latent attention: {why}")
+        if a["attention_mask"] != "causal" or a["rope"] != "rotary":
+            raise ValueError(
+                "latent attention is served under the causal mask with "
+                "rope on its rope columns")
+    if a["dense_layers"] < 0 or (a["dense_layers"] and (
+            not a["num_experts"] or a["layer_pattern"] is not None)):
+        raise ValueError(
+            f"dense_layers {a['dense_layers']}: the leading layers of a "
+            f"stack of classic blocks whose other layers hold experts")
     pat = a["layer_pattern"]
     if pat is not None:
         if not isinstance(pat, str) or not pat or set(pat) - set(LAYER_KINDS):
@@ -237,10 +285,10 @@ def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
             raise ValueError(
                 f"experts_held {[first, count]} lies outside the "
                 f"{e} routed experts")
-    elif (spec.get("experts_held") or a["gated"] or spec.get("router")
+    elif (spec.get("experts_held") or spec.get("router")
           or a["expert_latent"] or a["shared_expert_d_ff"]):
         raise ValueError(
-            "experts_held / gated / router / expert_latent / "
+            "experts_held / router / expert_latent / "
             "shared_expert_d_ff without num_experts")
     if spec.get("denoising_steps") is not None or a["block_length"] > 1:
         if a["attention_mask"] != "block_causal":
@@ -268,12 +316,20 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
     in float32, the router in float32. The layout is the one the
     serving code indexes (`generate._apply_block`): `qkv` fused
     [d, H*D + 2*KV*D], `proj` [H*D, d], `q_norm`/`k_norm` [D] under
-    `qk_norm`, and either `up`/`down` or, in an expert layer, `moe`
+    `qk_norm`, and either `up`/`down` (and `gate` under `gated`) or,
+    in an expert layer, `moe`
     {router [d, E] (and its selection `bias` [E]), w_up and w_down
     (and w_gate) stacked over the experts HELD, in the latent width
     between `latent_down` [d, L] and `latent_up` [L, d] where the
-    experts live in one, `shared_up`/`shared_down` where a shared
-    expert stands beside them}.
+    experts live in one, `shared_up`/`shared_down` (and `shared_gate`
+    under `gated`) where a shared expert stands beside them}. Of a
+    stack with experts the first `dense_layers` blocks hold the dense
+    MLP of `d_ff` instead. Under latent attention a block holds, in
+    `qkv`'s place, `q_a` [d, q_rank], `q_a_norm`, `q_b` [q_rank, H *
+    (nope + rope)], `kv_a` [d, kv_rank + rope], `kv_a_norm`
+    [kv_rank], and the published `kv_b` as its two halves a head,
+    `w_uk` [H, kv_rank, nope] and `w_uv` [H, kv_rank, v] (the absorbed
+    form multiplies them apart); `proj` is [H * v, d].
 
     Under a `layer_pattern` a block holds one norm (`ln`) and its
     mixer's leaves alone: `qkv` and `proj`, or `moe`, or `ssm`
@@ -294,6 +350,18 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
         "qkv": {"kernel": (d, cfg.q_width + 2 * kvw)},
         "proj": {"kernel": (cfg.q_width, d)},
     }
+    if cfg.latent is not None:
+        m, h = cfg.latent, cfg.n_heads
+        attention = {
+            "q_a": {"kernel": (d, m.q_rank)},
+            "q_a_norm": {"scale": (m.q_rank,)},
+            "q_b": {"kernel": (m.q_rank, h * m.key_width)},
+            "kv_a": {"kernel": (d, m.row_width)},
+            "kv_a_norm": {"scale": (m.kv_rank,)},
+            "w_uk": (h, m.kv_rank, m.nope_dim),
+            "w_uv": (h, m.kv_rank, m.v_dim),
+            "proj": {"kernel": (h * m.v_dim, d)},
+        }
     if cfg.qk_norm:
         attention["q_norm"] = {"scale": (hd,)}
         attention["k_norm"] = {"scale": (hd,)}
@@ -313,10 +381,15 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
         if shared:
             moe["shared_up"] = {"kernel": (d, shared)}
             moe["shared_down"] = {"kernel": (shared, d)}
+            if arch["gated"]:
+                moe["shared_gate"] = {"kernel": (d, shared)}
         ffn: Dict[str, Any] = {"moe": moe}
-    else:
-        ffn = {"up": {"kernel": (d, cfg.d_ff)},
-               "down": {"kernel": (cfg.d_ff, d)}}
+    dense: Dict[str, Any] = {"up": {"kernel": (d, cfg.d_ff)},
+                             "down": {"kernel": (cfg.d_ff, d)}}
+    if arch["gated"]:
+        dense["gate"] = {"kernel": (d, cfg.d_ff)}
+    if not arch["num_experts"]:
+        ffn = dense
     ssm: Dict[str, Any] = {}
     if cfg.ssm is not None:
         s = cfg.ssm
@@ -333,7 +406,8 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
     for i, kind in enumerate(cfg.kinds):
         shapes[f"block_{i}"] = (
             {"ln_attn": {"scale": (d,)}, "ln_mlp": {"scale": (d,)},
-             **attention, **ffn} if kind is None
+             **attention, **(dense if i < arch["dense_layers"] else ffn)}
+            if kind is None
             else {"ln": {"scale": (d,)}, **mixers[kind]})
     shapes["ln_out"] = {"scale": (d,)}
     shapes["lm_head"] = {"kernel": (d, cfg.vocab_size)}
@@ -385,8 +459,10 @@ def lm_spec_parts(spec: Dict[str, Any]):
     value the serving code cannot honour). A spec with none of
     `_ARCH_KEYS` is TransformerLM's block, initialised by the flax
     module and stored in float32 as ever; one that sets any of them
-    (another head size, a rope base, q/k norms, gated top-k experts,
-    the block-causal mask, `param_dtype`) gets `init_lm_params`' tree,
+    (another head size, a rope base or pairing, q/k norms, latent
+    attention, a gated MLP, gated top-k experts under leading dense
+    layers, the block-causal mask, `param_dtype`) gets
+    `init_lm_params`' tree,
     its matrices stored in `param_dtype`, every layer an expert layer
     where `num_experts` is set, or, under `layer_pattern`, one mixer a
     layer (state-space, attention, expert feed-forward) with
@@ -422,7 +498,8 @@ def lm_spec_parts(spec: Dict[str, Any]):
         ),
         kv_quant=bool(spec.get("kv_quant", False)),
         **({
-            "d_head": arch["head_dim"],
+            "d_head": (None if arch["attention"] == "latent"
+                       else arch["head_dim"]),
             "rope_theta": arch["rope_theta"],
             "qk_norm": arch["qk_norm"],
             "experts_per_token": arch["experts_per_token"],
@@ -436,6 +513,8 @@ def lm_spec_parts(spec: Dict[str, Any]):
             "router_scoring": arch["router"]["scoring"],
             "router_scale": arch["router"]["scale"],
             "activation": arch["activation"],
+            "latent": arch["latent_attention"],
+            "rope_pairing": arch["rope_pairing"],
         } if described else {}),
     )
     if described:

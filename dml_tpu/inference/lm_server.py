@@ -174,8 +174,9 @@ _M_DELIVER = METRICS.histogram(
     "on_token callbacks, retirements")
 _M_STATE_BYTES = METRICS.gauge(
     "lm_server_state_bytes",
-    "the slot grid's bytes by kind= kv (attention layers' rows) | conv | "
-    "scan (a state-space layer's convolution window and recurrent state, "
+    "the slot grid's bytes by kind= kv (grouped attention's K and V "
+    "rows) | latent (latent attention's one row a token) | conv | scan "
+    "(a state-space layer's convolution window and recurrent state, "
     "which every decode step reads and writes whole for every slot)")
 _M_WEIGHT_BYTES = METRICS.gauge(
     "lm_server_weight_bytes",
@@ -297,6 +298,18 @@ def _group_rows(k: int, bucket: int, max_slots: int) -> int:
 #: a (512, 64) group does not fit. A bound on the group, so that no slot,
 #: width or row has to give way (PERF.md section 4 has the arithmetic).
 _STATE_GROUP_TOKENS = 8192
+
+#: ... and of a model with latent attention: no two rows of the shortest
+#: bucket, so every prompt is prefilled alone. A row of 512 tokens and more
+#: is bound by its matmuls at such a model's depth (a 2,048-token row of
+#: 40 layers is 7.4 TFLOP beside one 9.5 GB weight read: 40-60 ms beside
+#: 12), so rows that share a call save little; what they cost is a
+#: compilation a (bucket, rows) shape, a minute each at that depth, and
+#: the bytes of the rows a group hands back (50 KB a token over 40 layers:
+#: 0.2 GB a 4,096-token row) beside 9.6 GB of weights and a 3.4 GB grid
+#: (ahead-of-time compile, PR 36: a 1 x 4,096 call holds 2.0 GiB of
+#: temporaries, a 4 x 4,096 call 1.6 GiB and 0.8 GiB of rows).
+_LATENT_GROUP_TOKENS = _BUCKET_FLOOR
 
 
 def _prefill_groups(
@@ -588,11 +601,23 @@ class LMServer:
                 "a model with a state-space layer is served by the plain "
                 "chunked loop on one device: block diffusion and the "
                 "sharded forms cannot hold its state")
+        if cfg.latent is not None and self._mesh is not None:
+            # no rule yet says where a latent row lives over a mesh: it
+            # has no heads to divide, and every head reads all of it
+            raise ValueError(
+                "a model with latent attention is served on one device: "
+                "the sharded forms have no placement for its shared rows")
         self.cache = self._new_cache(cfg)
         for kind, n in state_bytes(self.cache).items():
             _M_STATE_BYTES.set(n, kind=kind)
         # the most padded tokens a prefill group of several rows holds
-        self._group_tokens = _STATE_GROUP_TOKENS if cfg.has_state else None
+        self._group_tokens = (
+            _STATE_GROUP_TOKENS if cfg.has_state
+            else _LATENT_GROUP_TOKENS if cfg.latent is not None else None)
+        # whether a prefill hands back the bucket's own rows, not rows
+        # padded to max_len, and an insert writes those alone
+        # (`_insert_impl` says why either kind of server may)
+        self._bucket_rows = diffusion is not None or cfg.latent is not None
         # Decode state lives ON DEVICE (authoritative): `_cur_dev` the
         # next input token per slot, `_pos_dev` the next write
         # position. Placement writes them with device scatters and the
@@ -637,11 +662,13 @@ class LMServer:
         # bucket's own rows, not rows padded to max_len: a placement
         # wave of equal budgets prefills a group a bucket at once, and
         # seven groups' max_len-row caches (1.6 GB each at 32 slots x
-        # 4,096 rows x 12 KiB) do not fit beside an 8.7 GB model
+        # 4,096 rows x 12 KiB) do not fit beside an 8.7 GB model. So
+        # does a latent-attention prefill (its logits it reads): a
+        # group's rows over 40 layers padded to 4,096 are 0.2 GB a row
         self._prefill = jax.jit(
             lambda p, pr, li: prefill(
                 p, self.cfg, pr,
-                self.max_len if diffusion is None else pr.shape[1],
+                pr.shape[1] if self._bucket_rows else self.max_len,
                 logits_index=li, mesh=self._mesh,
                 head=diffusion is None,
             )
@@ -888,6 +915,13 @@ class LMServer:
         if cache is not None:
             self._refuse_state("the KV prefix cache (a prefix is a cut "
                                "of cached rows)")
+        if cache is not None and self.cfg.latent is not None:
+            # a latent row CAN be cut by token; what cannot take one is
+            # the suffix prefill (`kv_cache.WarmStart`), which attends a
+            # prefix's K and V rows by name
+            raise ValueError(
+                "the KV prefix cache's suffix prefill attends K and V "
+                "rows; it has no form for latent attention's rows yet")
         self.kv_cache = cache
         self._warm = (
             WarmStart(cache, self.cfg, self.max_len)
@@ -935,7 +969,11 @@ class LMServer:
         prefilled rows alone — see below — and needs no such pairing:
         its forwards attend rows under each slot's length only, and a
         request's own forwards write every row from its first block on
-        before anything attends it.)
+        before anything attends it. A latent-attention server does the
+        same, for the same reason: cache attention reads rows under a
+        slot's length only, and a request's own steps write every row
+        from its prompt's end on, the last row included, before a
+        length reaches it.)
 
         A state-space layer's leaves (`conv`, `ssm`) are copied like
         any other, and the invariant holds for them in its plainest
@@ -944,7 +982,7 @@ class LMServer:
         with the state the prefill took at the row's own length."""
         # generic over the cache layout (bf16 {k, v} or kv_quant
         # {k_q, k_s, v_q, v_s}) — every leaf copies the same way
-        if self.diffusion is not None:
+        if self._bucket_rows:
             # the prefilled rows alone ([KV, bucket, D], written from
             # the slot's row 0): what the last occupant left past them
             # lies at or past this request's first block, where nothing
@@ -1521,6 +1559,8 @@ class LMServer:
             # rows whose state-space state the prefill takes at their
             # own length and the inserts copy
             **({"state_rows": k} if self.cfg.has_state else {}),
+            # latent attention's form here: keys and values rebuilt
+            **({"attn": "expanded"} if self.cfg.latent is not None else {}),
         ) as span:
             padded = np.zeros((kp, bucket), np.int32)
             tps = np.ones(kp, np.int32)
@@ -1542,6 +1582,14 @@ class LMServer:
             # row's logits at its true last prompt position identical
             # to an UNPADDED prefill's, so first tokens match
             # generate() exactly despite bucket AND group padding
+            if self.cfg.latent is not None:
+                # ONE group's rows at a time: a call's outputs are
+                # allocated as it is enqueued, and a round of sixteen
+                # long prompts enqueued at once would hold 2-3 GB of
+                # rows to insert that the chip does not have. The wait
+                # is for the last group's inserts; the host's few ms of
+                # preparing this one are then the device's idle
+                jax.block_until_ready(self.cache)
             logits, pcache = self._prefill(
                 self.params, jnp.asarray(padded),
                 jnp.asarray(tps - 1),
@@ -1722,6 +1770,9 @@ class LMServer:
         with TRACER.loop_span(
             "lm_step", occupancy=occupancy, mode=mode,
             waiting=len(self._queue),
+            # latent attention's form in every cached step: the cached
+            # rows attended as they are
+            **({"attn": "absorbed"} if self.cfg.latent is not None else {}),
         ) as span:
             dispatch(span)
         _M_STEP.observe(span.m1 - span.m0)

@@ -313,6 +313,10 @@ class PipelinedLMBackend:
                 "pipeline serving stacks identical blocks over `pp` and "
                 "carries K/V rows a stage; a layer_pattern's layers differ "
                 "in kind and a state-space layer's state is no K/V row")
+        if cfg.latent is not None:
+            raise ValueError(
+                "pipeline serving carries K and V rows a stage and attends "
+                "them itself; it has no form for latent attention's rows")
         if cfg.n_layers % self.pp:
             raise ValueError(
                 f"n_layers {cfg.n_layers} not divisible by pp {self.pp}"
@@ -970,6 +974,13 @@ class LMPrefillBackend:
                 "state-space layer's scan state and convolution window "
                 "are not such rows (LMServer.submit_prefilled refuses "
                 "them too)")
+        if cfg.latent is not None:
+            # `LMServer.submit_prefilled` adopts a slab of latent rows as
+            # it adopts any; the chunk-streamed wire format that carries
+            # one from here is untried on such a leaf
+            raise ValueError(
+                "a prefill worker's slab wire format is untried on latent "
+                "attention's rows: serve such a model on one server")
         self.params = params
         self.cfg = cfg
         self.max_len = int(max_len)

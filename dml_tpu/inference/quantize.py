@@ -44,11 +44,15 @@ import jax.numpy as jnp
 
 # params keys quantized at each block level: 2-D kernels, the stacked
 # tensors of an expert layer (w_gate: gated experts only), the head
-_BLOCK_MATMULS = ("qkv", "proj", "up", "down")
+_BLOCK_MATMULS = ("qkv", "proj", "up", "down", "gate", "q_a", "q_b", "kv_a")
 _EXPERT_MATMULS = ("w_up", "w_down", "w_gate")
+# ... latent attention's up-projections, stacked over heads as the expert
+# tensors are over experts ([H, in, out]: one scale a head and channel)
+_HEAD_MATMULS = ("w_uk", "w_uv")
 # ... the 2-D kernels an expert layer holds beside its experts (the latent
 # projections, the shared expert) and a state-space mixer's two
-_MOE_MATMULS = ("latent_down", "latent_up", "shared_up", "shared_down")
+_MOE_MATMULS = ("latent_down", "latent_up", "shared_up", "shared_down",
+                "shared_gate")
 _SSM_MATMULS = ("in_proj", "out_proj")
 _TOP_MATMULS = ("lm_head",)
 
@@ -75,7 +79,8 @@ def _map_block_matmuls(params: Dict[str, Any], kernel_fn, expert_fn):
     """The same tree with `kernel_fn` applied to every block's 2-D
     matmul kernel (`_BLOCK_MATMULS`, an expert layer's `_MOE_MATMULS`,
     a state-space mixer's `_SSM_MATMULS`) and `expert_fn` to every
-    stacked expert tensor (`_EXPERT_MATMULS`): the leaves
+    stacked tensor (`_EXPERT_MATMULS` over experts, `_HEAD_MATMULS`
+    over heads): the leaves
     `_apply_block`, `expert_ffn` and `ssm_mixer` read through
     `kernel_of`. Everything else (embedding, norms, router, head, a
     state-space mixer's convolution and per-head vectors) is the
@@ -89,6 +94,8 @@ def _map_block_matmuls(params: Dict[str, Any], kernel_fn, expert_fn):
         for k, v in sub.items():
             if k in _BLOCK_MATMULS:
                 blk[k] = {**v, "kernel": kernel_fn(v["kernel"])}
+            elif k in _HEAD_MATMULS:
+                blk[k] = expert_fn(v)
             elif k == "moe":
                 blk[k] = {**v, **{
                     w: expert_fn(v[w]) for w in _EXPERT_MATMULS if w in v

@@ -76,7 +76,7 @@ def block_rows(kv: int, d: int, dtype, t: int, block_k: int = 2048) -> int:
 
 def _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale, quantized, n_kv, bk,
-                   n_q=1, mask_block=1):
+                   n_q=1, mask_block=1, v_width=None):
     ik = pl.program_id(1)
     nk = pl.num_programs(1)
     length = len_ref[pl.program_id(0)]
@@ -131,7 +131,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_new = l_scr[h, :, :1] * alpha + jnp.sum(p, -1, keepdims=True)
-            v = v_ref[h]
+            # one shared plane (`v_width`): the values are the first
+            # columns of the block the keys came in, fetched once
+            v = k[:, :v_width] if v_ref is None else v_ref[h]
             if quantized:
                 # fold the v scales into the prob rows (a dead row's
                 # scale is stale too: select, don't multiply)
@@ -155,7 +157,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
 def decode_attention(
     q: jax.Array,  # [B, Q, H, D]; Q = 1 is the decode step
     k: jax.Array,  # [B, KV, T, D] cache (cfg dtype, or int8 with scales)
-    v: jax.Array,  # [B, KV, T, D]
+    v: Optional[jax.Array],  # [B, KV, T, D]; None: k's first `v_width` columns
     lengths: jax.Array,  # [B] int32 — slot b attends cache rows < lengths[b]
     *,
     k_scale: Optional[jax.Array] = None,  # [B, KV, 1, T] f32 (int8 cache)
@@ -164,8 +166,10 @@ def decode_attention(
     block_k: int = 2048,
     interpret: Optional[bool] = None,
     mask_block: int = 1,
+    v_width: Optional[int] = None,
 ) -> jax.Array:
-    """One decode step of cache attention; returns [B, Q, H, D] f32.
+    """One decode step of cache attention; returns [B, Q, H, D] f32
+    ([B, Q, H, v_width] over a shared plane).
 
     With Q > 1 query rows a slot (speculative decoding's verify, block
     diffusion: `generate.batched_block_step`) `lengths[b]` counts the
@@ -188,8 +192,24 @@ def decode_attention(
     constraint admits without a materialized transpose. H = KV * G
     grouped-query with kv-major head order (head h = kv * G + g),
     matching `batched_decode_step`'s reshape. Pass `k_scale`/`v_scale`
-    to read an int8 cache with inline dequant."""
+    to read an int8 cache with inline dequant.
+
+    `v=None` with `v_width` is latent attention's absorbed form
+    (`generate._latent_attention`): keys and values are ONE stream, a
+    [B, 1, T, D] plane of cached latent rows whose first `v_width`
+    columns are also the values. Each block is fetched once; all H
+    query heads' rows score its D columns and accumulate over its
+    first `v_width`. `scale` is then the caller's (the model's key
+    width is not D)."""
     b, n_q, h, d = q.shape
+    if (v is None) != (v_width is not None):
+        raise ValueError("v_width comes with v=None, and only with it")
+    if v is None and (k_scale is not None or scale is None
+                      or not 0 < v_width <= d):
+        raise ValueError(
+            "a shared plane is read unquantized, under the caller's "
+            "scale, its values the first v_width <= D columns")
+    dv = d if v_width is None else v_width
     if n_q % mask_block:
         raise ValueError(f"{n_q} query rows under blocks of {mask_block}")
     kv, t = k.shape[1], k.shape[2]
@@ -232,12 +252,17 @@ def decode_attention(
     qg = q.reshape(b, n_q, kv, g, d).swapaxes(1, 2).reshape(b, kv, rows, d)
     q_spec = pl.BlockSpec(
         (None, kv, rows, d), lambda b_, j, *_: (b_, 0, 0, 0))
+    o_spec = pl.BlockSpec(
+        (None, kv, rows, dv), lambda b_, j, *_: (b_, 0, 0, 0))
     kv_spec = pl.BlockSpec((None, kv, bk, d), kv_map)
     sc_spec = pl.BlockSpec((None, kv, 1, bk), sc_map)
 
     if quantized:
         ins = (qg, k, k_scale, v, v_scale)
         in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
+    elif v is None:
+        ins = (qg, k)
+        in_specs = [q_spec, kv_spec]
     else:
         # the scale streams don't exist: don't DMA dummy buffers
         ins = (qg, k, v)
@@ -246,12 +271,15 @@ def decode_attention(
     def kernel(len_r, _src_r, q_r, *refs):  # src: the index maps' alone
         if quantized:
             k_r, ks_r, v_r, vs_r, *rest = refs
+        elif v is None:
+            k_r, *rest = refs
+            ks_r = v_r = vs_r = None
         else:
             k_r, v_r, *rest = refs
             ks_r = vs_r = None
         _decode_kernel(len_r, q_r, k_r, ks_r, v_r, vs_r, *rest,
                        scale=scale, quantized=quantized, n_kv=kv, bk=bk,
-                       n_q=n_q, mask_block=mask_block)
+                       n_q=n_q, mask_block=mask_block, v_width=v_width)
 
     out = pl.pallas_call(
         kernel,
@@ -259,14 +287,15 @@ def decode_attention(
             num_scalar_prefetch=2,
             grid=(b, nk),
             in_specs=in_specs,
-            out_specs=q_spec,
+            out_specs=o_spec,
             scratch_shapes=[
                 pltpu.VMEM((kv, rows, LANES), jnp.float32),  # running max
                 pltpu.VMEM((kv, rows, LANES), jnp.float32),  # running denom
-                pltpu.VMEM((kv, rows, d), jnp.float32),      # output accum
+                pltpu.VMEM((kv, rows, dv), jnp.float32),     # output accum
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kv, rows, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kv, rows, dv), jnp.float32),
         interpret=interpret,
     )(lengths, src, *ins)
-    return out.reshape(b, kv, n_q, g, d).swapaxes(1, 2).reshape(b, n_q, h, d)
+    return out.reshape(b, kv, n_q, g, dv).swapaxes(1, 2).reshape(
+        b, n_q, h, dv)

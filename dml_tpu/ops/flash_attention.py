@@ -261,9 +261,11 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
 
 
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
-    """q,k,v: [B,H,T,D]. Returns (out [B,H,T,D], lse [B,H,T]) f32 lse."""
+    """q,k: [B,H,T,D], v: [B,H,T,Dv] (Dv = D as a rule; a model whose
+    values are narrower than its keys hands them as they are). Returns
+    (out [B,H,T,Dv], lse [B,H,T]) f32 lse."""
     b, h, t, d = q.shape
-    t_kv = k.shape[2]
+    t_kv, dv = k.shape[2], v.shape[3]
     bq = min(block_q, t)
     bk = min(block_k, t_kv)
     qp = _pad_seq(q, bq)
@@ -275,7 +277,8 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
 
     q_spec = pl.BlockSpec((None, None, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
     kv_spec = pl.BlockSpec((None, None, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0))
-    o_spec = pl.BlockSpec((None, None, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
+    v_spec = pl.BlockSpec((None, None, bk, dv), lambda b_, h_, i, j: (b_, h_, j, 0))
+    o_spec = pl.BlockSpec((None, None, bq, dv), lambda b_, h_, i, j: (b_, h_, i, 0))
     # rows stored [B, H, T, 1]: trailing singleton lane dim keeps the
     # block's last-two-dims (bq, 1) legal for Mosaic (bs0 == as0)
     lse_spec = pl.BlockSpec((None, None, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
@@ -287,16 +290,16 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec, v_spec],
         out_specs=[o_spec, lse_spec],
         out_shape=[
-            jax.ShapeDtypeStruct(qp.shape, q.dtype),
+            jax.ShapeDtypeStruct((*qp.shape[:3], dv), q.dtype),
             jax.ShapeDtypeStruct((*qp.shape[:3], 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, LANES), jnp.float32),  # running denominator
-            pltpu.VMEM((bq, d), jnp.float32),      # output accumulator
+            pltpu.VMEM((bq, dv), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
     )(qp, kp, vp)
@@ -318,7 +321,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, g_lse, causal, scale, block_q,
     entirely)."""
     has_glse = g_lse is not None
     b, h, t, d = q.shape
-    t_kv = k.shape[2]
+    t_kv, dv = k.shape[2], v.shape[3]
     bq = min(block_q, t)
     bk = min(block_k, t_kv)
     # delta_i = sum_d dO_i O_i — the rowwise correction term
@@ -347,10 +350,13 @@ def _flash_bwd_impl(q, k, v, out, lse, g, g_lse, causal, scale, block_q,
 
     q_spec = pl.BlockSpec((None, None, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
     kv_spec = pl.BlockSpec((None, None, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0))
+    # v and the output's cotangent are Dv wide (see the forward)
+    v_spec = pl.BlockSpec((None, None, bk, dv), lambda b_, h_, i, j: (b_, h_, j, 0))
+    g_spec = pl.BlockSpec((None, None, bq, dv), lambda b_, h_, i, j: (b_, h_, i, 0))
     row_spec = pl.BlockSpec((None, None, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
 
     ins = [qp, kp, vp, gp, lsep, deltap] + ([glsep] if has_glse else [])
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec] + (
+    in_specs = [q_spec, kv_spec, v_spec, g_spec, row_spec, row_spec] + (
         [row_spec] if has_glse else []
     )
     dq = pl.pallas_call(
@@ -370,8 +376,10 @@ def _flash_bwd_impl(q, k, v, out, lse, g, g_lse, causal, scale, block_q,
     # transposed grid: q-block innermost so dk/dv accumulate in scratch
     q_spec_t = pl.BlockSpec((None, None, bq, d), lambda b_, h_, j, i: (b_, h_, i, 0))
     kv_spec_t = pl.BlockSpec((None, None, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0))
+    v_spec_t = pl.BlockSpec((None, None, bk, dv), lambda b_, h_, j, i: (b_, h_, j, 0))
+    g_spec_t = pl.BlockSpec((None, None, bq, dv), lambda b_, h_, j, i: (b_, h_, i, 0))
     row_spec_t = pl.BlockSpec((None, None, bq, 1), lambda b_, h_, j, i: (b_, h_, i, 0))
-    in_specs_t = [q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
+    in_specs_t = [q_spec_t, kv_spec_t, v_spec_t, g_spec_t, row_spec_t,
                   row_spec_t] + ([row_spec_t] if has_glse else [])
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -381,14 +389,14 @@ def _flash_bwd_impl(q, k, v, out, lse, g, g_lse, causal, scale, block_q,
         ),
         grid=(b, h, nk, nq),
         in_specs=in_specs_t,
-        out_specs=[kv_spec_t, kv_spec_t],
+        out_specs=[kv_spec_t, v_spec_t],
         out_shape=[
             jax.ShapeDtypeStruct(kp.shape, k.dtype),
             jax.ShapeDtypeStruct(vp.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
     )(*ins)
@@ -479,7 +487,9 @@ def flash_attention(
     mask_block: int = 1,
 ) -> jax.Array:
     """Blockwise (flash) attention. q, k, v: [B, T, H, D] (T of k/v may
-    differ from q's); returns [B, Tq, H, D] in q's dtype.
+    differ from q's); returns [B, Tq, H, D] in q's dtype. v may be
+    narrower (or wider) than q and k, [B, T, H, Dv]: the output then is
+    [B, Tq, H, Dv], and nothing is padded to the wider of the two.
 
     `mask_block` B > 1 (with `causal`) is the block-causal rule of
     block-diffusion models: position i attends j iff j // B <= i // B.

@@ -385,3 +385,94 @@ def test_diffusion_dispatch_of_the_sdar_config_holds_the_kernels(
     assert text.count("tpu_custom_call") == 4 * 3 - 3
     assert "ragged-dot" not in text
 
+
+
+def test_decode_attention_over_a_shared_plane_compiles(topo):
+    """Latent attention's absorbed form at joyai_llm_flash_ep16's grid: 16
+    slots x 4,096 cached rows of 640 columns (576 values), all 32 heads'
+    rows against ONE plane that is keys and, in its first 512 columns,
+    values; one and four query rows a slot."""
+    from dml_tpu.ops.decode_attention import decode_attention
+
+    for n_q in (1, 4):
+        text, _ = compile_on_chip(
+            topo,
+            lambda q, k, n: decode_attention(
+                q, k, None, n, scale=192 ** -0.5, v_width=512,
+                interpret=False),
+            ((16, n_q, 32, 640), jnp.bfloat16),
+            ((16, 1, 4096, 640), jnp.bfloat16), ((16,), jnp.int32))
+        assert "tpu_custom_call" in text
+
+
+def test_flash_attention_with_narrower_values_compiles(topo):
+    """The expanded form's prefill at the published widths: keys of 192
+    (128 | 64), values of 128, nothing padded to the other."""
+    from dml_tpu.ops.flash_attention import flash_attention
+
+    text, _ = compile_on_chip(
+        topo, functools.partial(flash_attention, causal=True, interpret=False),
+        ((1, 4096, 32, 192), jnp.bfloat16), ((1, 4096, 32, 192), jnp.bfloat16),
+        ((1, 4096, 32, 128), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+def test_latent_attention_programs_of_the_joyai_config_fit_the_chip(
+        topo, monkeypatch):
+    """The fourth benchmark configuration's two programs at
+    joyai_llm_flash_ep16's published widths, slot grid and chunk, the depth
+    cut to the dense layer and two expert layers (the full depth compiles
+    in 35 s and 70 s: `benchmark/tools`' way, PERF.md section 4):
+    `LMServer._chunk_impl` holds the shared-plane kernel a layer and three
+    grouped matmuls an expert layer, updates the grid in place and hands
+    the cache on in the layout it took it in (no copy of a leaf: a
+    576-column leaf was copied whole twice a dispatch); a 1 x 2,048
+    prefill holds the flash kernel a layer."""
+    import json
+    import os
+
+    from dml_tpu.inference.generate import init_cache, prefill
+    from dml_tpu.inference.lm_backend import lm_spec_parts
+    from dml_tpu.inference.lm_server import LMServer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "joyai_llm_flash_ep16.json")) as f:
+        spec = {**json.load(f)["lm_spec"], "n_layers": 3}
+    made = {}
+
+    def declared():
+        params, made["cfg"] = lm_spec_parts(spec)
+        return params
+
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = functools.partial(jax.tree_util.tree_map, lambda s: (
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)))
+    params = on_chip(jax.eval_shape(declared))
+    cfg = made["cfg"]
+    slots, max_len = spec["max_slots"], spec["max_len"]
+    srv = object.__new__(LMServer)
+    srv.cfg, srv.max_len, srv.max_slots = cfg, max_len, slots
+    srv.chunk, srv.temperature, srv._mesh = spec["chunk"], 0.0, None
+    srv._routed = (2, spec["num_experts"])
+    srv._held = (0, spec["experts_held"][1])
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))
+    leaf = cache["block_0"]["latent"]
+    assert leaf.shape == (16, 1, 4096, 640)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    compiled = jax.jit(srv._chunk_impl, donate_argnums=(1, 2, 3)).lower(
+        params, cache, vec, vec, vec).compile()
+    text = compiled.as_text()
+    assert text.lstrip().startswith("HloModule jit__chunk_impl")
+    assert text.count("tpu_custom_call") == 3 + 3 * 2
+    assert "bf16[16,1,4096,640]{3,2,1,0:T(8,128)(2,1)} copy(" not in text
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2 ** 28
+    assert m.alias_size_in_bytes >= 3 * leaf.size * 2  # the grid, in place
+    prompt = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one)
+    at = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one)
+    text = jax.jit(
+        lambda p, x, i: prefill(p, cfg, x, x.shape[1], logits_index=i)
+    ).lower(params, prompt, at).compile().as_text()
+    assert text.count("tpu_custom_call") == 3 + 3 * 2
