@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -407,10 +407,40 @@ def _activation(name: str):
 
 
 _MOE_CHUNK = 4096  # tokens per expert-layer chunk at prefill, at most
-#: ... and (token, expert) assignments a chunk: the sorted copies and the
-#: grouped matmuls' float32 outputs are [tokens x k, width] whatever share
-#: of the experts is held (4,096 tokens at top-8; 1,408 at top-22)
+#: ... and (token, expert) assignments a chunk. Over all tokens x k are
+#: laid out the routing's INDICES alone (`top_k`'s choices and gates, the
+#: sort by expert, the counts: 4-byte elements); the sorted copies, the
+#: grouped matmuls' float32 outputs, activations and gate products are
+#: [window, width], over `moe_window`'s rows of the HELD assignments. Where
+#: every expert is held the one window is the whole chunk (32,768 rows at
+#: 4,096 tokens and top-8; 1,408 tokens at top-22), which this bounds
 _MOE_ROWS = 32768
+
+
+def moe_window(n: int, k: int, e: int, held: int) -> int:
+    """The rows `expert_ffn` lays its per-assignment work out over, for
+    `n` tokens top-`k` of `e` routed experts with `held` of them here:
+    the held assignments a uniform routing gives, `n k held / e`, and a
+    quarter more, in whole tiles of 128 rows. Windows of that many rows
+    are run until the held assignments are through, so the number is a
+    size and never a capacity. Where it reaches `n k` (every expert is
+    held, or all the assignments are one tile) the one window is all
+    the rows, in no loop."""
+    rows = n * k
+    if held >= e:
+        return rows
+    want = -(-rows * held * 5 // (e * 4))
+    return min(-(-want // 128) * 128, rows)
+
+
+def moe_layout(n: int, k: int, e: int, held: int) -> Tuple[int, int, int]:
+    """(chunks, tokens a chunk, `moe_window` of a chunk) of an
+    `expert_ffn` call over `n` tokens: what the call lays out, from
+    shapes alone (a server's span labels are this arithmetic)."""
+    chunk = min(_MOE_CHUNK, max(128, _MOE_ROWS // k // 128 * 128))
+    chunks = 1 if n <= chunk else -(-n // chunk)
+    per = n if chunks == 1 else chunk
+    return chunks, per, moe_window(per, k, e, held)
 
 
 def uses_grouped_kernel(mesh: Optional[Mesh] = None) -> bool:
@@ -436,7 +466,11 @@ def _grouped_matmul(x: jax.Array, w: jax.Array, sizes: jax.Array,
     On one TPU this is jax's own Pallas grouped matmul (`megablox.gmm`)
     with tiles of 128 rows and the whole of k and n (up to 2,048 each):
     a tile visit loads one expert's [k, n] panel once, so a decode-sized
-    call is bound by the weights of the experts touched. `ragged_dot`
+    call is bound by the weights of the experts touched. Its grid runs
+    over the ACTIVE row tiles alone (`num_active_tiles`, a traced scalar
+    it reckons from `sizes`): tiles past the last group are never
+    visited, so a call costs what its groups' rows cost whatever `m` is;
+    what the `m` rows cost is the caller's arrays around it. `ragged_dot`
     computes the same thing everywhere else (the tests' oracle) and was
     the first form on the TPU too, where the compiler lowers it to a
     grouped-matmul call of its own. Measured on one TPU v5e (my chip
@@ -473,6 +507,7 @@ def expert_ffn(
     scoring: str = "softmax",
     scale: float = 1.0,
     activation: str = "silu",
+    windows: Optional[List[jax.Array]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The serve-time expert layer: dropless top-`k` routing over ALL
     the routed experts, computed for the experts this tree HOLDS.
@@ -485,11 +520,13 @@ def expert_ffn(
     `scale`. No capacity, so no dropped token (training-time capacity
     drops are a batching artifact, not part of the learned function).
     Compute: the n * k
-    (token, expert) assignments are sorted by expert and run through
-    ONE grouped matmul a matrix (`_grouped_matmul`: row groups of the
-    sorted tokens against the stacked expert weights), so the FLOPs
-    are those of the experts chosen and the weights read are those of
-    the experts touched. Experts are gated
+    (token, expert) assignments are sorted by expert, the absent
+    experts' last, so the held ones are the sort's first positions;
+    their rows run through ONE grouped matmul a matrix
+    (`_grouped_matmul`: row groups of the sorted tokens against the
+    stacked expert weights), so the FLOPs are those of the experts
+    chosen and the weights read are those of the experts touched.
+    Experts are gated
     (`w_gate`: down(act(gate x) * up x)) or plain (down(act(up x)),
     parallel/moe.py's MoEMLP), by what the tree holds, `activation`
     SiLU or squared ReLU. Where the tree holds `latent_down` and
@@ -505,11 +542,26 @@ def expert_ffn(
     deployment they are another chip's part of the sum; the latent
     up-projection is linear, so the shares still add up).
 
+    What is laid out over all n * k assignments is INDICES (4-byte
+    elements: the choices and gates, the sort, the counts). The sorted
+    copies of the tokens, the grouped matmuls' float32 outputs, the
+    activations and the gate products are [window, width], a window
+    being `moe_window`'s rows of the held positions; a window's rows
+    are added into the tokens' [n, width] float32 sums (a row
+    scatter-add), cast once to `dtype`. As many windows run as the
+    held rows need, under a loop whose trip count is read from the
+    routing: whatever the routing, every held assignment is computed.
+    Where the window is all n * k rows (every expert held; one tile of
+    assignments) there is no loop, and the rows go back to their
+    tokens by the sort's inverse and a sum over k.
+
     Returns (out [B, T, d], counts [E] int32: assignments to each
-    routed expert from the rows `live` marks, all rows by default).
+    routed expert from the rows `live` marks, all rows by default). A
+    call that loops appends to `windows`, where the caller hands a
+    list, the windows it ran past its chunks' first (int32 scalar).
     Token runs over the chunk (`_MOE_CHUNK` tokens, fewer where `k` is
     large: `_MOE_ROWS` assignments) go through a `lax.map`, so the
-    sorted copies stay bounded at prefill."""
+    index arrays stay bounded at prefill (`moe_layout`)."""
     router = moe["router"]["kernel"]
     bias = moe["router"].get("bias")
     e = router.shape[-1]
@@ -533,6 +585,8 @@ def expert_ffn(
     def run(args):  # tok [n, d], counted [n] -> out [n, d], counts [E]
         tok, counted = args
         n = tok.shape[0]
+        window = moe_window(n, k, e, held)
+        looped = window < n * k  # else ONE window of all the rows
         logits = tok.astype(jnp.float32) @ router.astype(jnp.float32)
         if scoring == "softmax":
             gates = jax.nn.softmax(logits, axis=-1)
@@ -545,28 +599,73 @@ def expert_ffn(
         top_g = top_g / jnp.maximum(top_g.sum(-1, keepdims=True), 1e-9)
         if scale != 1.0:
             top_g = top_g * scale
-        counts = jnp.zeros(e, jnp.int32).at[top_i.reshape(-1)].add(
-            jnp.repeat(counted.astype(jnp.int32), k))
+
+        def tally(idx, weights, bins):
+            # weights summed by idx, by compare-and-sum: the serial
+            # scatter-add of 32,768 updates is 0.29 ms on a TPU v5e, this
+            # 0.0x (PERF.md section 6, PR 37). The one-window programs
+            # keep the scatter, and so the text, they had
+            return jnp.sum(jnp.where(
+                idx[:, None] == jnp.arange(bins, dtype=idx.dtype),
+                weights[:, None], 0), axis=0)
+
+        if looped:
+            counts = tally(top_i.reshape(-1),
+                           jnp.repeat(counted.astype(jnp.int32), k), e)
+        else:
+            counts = jnp.zeros(e, jnp.int32).at[top_i.reshape(-1)].add(
+                jnp.repeat(counted.astype(jnp.int32), k))
         local = top_i.reshape(-1) - first
         here = (local >= 0) & (local < held)
         key = jnp.where(here, local, held)  # absent experts sort last
         order = jnp.argsort(key)
-        sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
-        src = tok @ kernel_of(moe["latent_down"], dtype) if latent else tok
-        xs = src[order // k]  # [n * k, d or latent], grouped by expert
-        h = grouped(xs, w_up, sizes)
-        if gated:
-            h = act(grouped(xs, w_gate, sizes)) * h
+        if looped:
+            sizes = tally(key, jnp.ones_like(key), held)
         else:
-            h = act(h)
-        o = grouped(h.astype(dtype), w_down, sizes)  # [n * k, d] f32
-        g = jnp.where(here, top_g.reshape(-1), 0.0)[order]
-        # rows past the last group hold nothing that counts: select,
-        # so that whatever they read as cannot leak
-        o = jnp.where(g[:, None] > 0.0, o * g[:, None], 0.0)
-        back = jnp.zeros(n * k, jnp.int32).at[order].set(
-            jnp.arange(n * k, dtype=jnp.int32))
-        out = o[back].reshape(n, k, -1).sum(1).astype(dtype)
+            sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
+        src = tok @ kernel_of(moe["latent_down"], dtype) if latent else tok
+
+        def experts(xs, sizes):  # rows grouped by expert -> f32 rows
+            h = grouped(xs, w_up, sizes)
+            if gated:
+                h = act(grouped(xs, w_gate, sizes)) * h
+            else:
+                h = act(h)
+            return grouped(h.astype(dtype), w_down, sizes)
+
+        def weighed(o, g):
+            # rows past the last group hold nothing that counts: select,
+            # so that whatever they read as cannot leak
+            return jnp.where(g[:, None] > 0.0, o * g[:, None], 0.0)
+
+        if not looped:
+            o = experts(src[order // k], sizes)  # [n * k, d] f32
+            o = weighed(o, jnp.where(here, top_g.reshape(-1), 0.0)[order])
+            back = jnp.zeros(n * k, jnp.int32).at[order].set(
+                jnp.arange(n * k, dtype=jnp.int32))
+            out = o[back].reshape(n, k, -1).sum(1).astype(dtype)
+            further = ()
+        else:
+            # the held assignments are the sort's first `ends[-1]`
+            # positions: window w takes positions [w window, (w + 1)
+            # window) of them, its groups the experts' runs clipped to it
+            ends = jnp.cumsum(sizes)
+            trips = jnp.maximum(-(-ends[-1] // window), 1)
+            order = jnp.pad(order, (0, -(n * k) % window))
+            row = jnp.arange(window, dtype=jnp.int32)
+
+            def one(w, acc):
+                lo = w * window
+                at = jax.lax.dynamic_slice(order, (lo,), (window,))
+                of = at // k  # the rows' tokens
+                o = experts(src[of], jnp.clip(ends - lo, 0, window)
+                            - jnp.clip(ends - sizes - lo, 0, window))
+                g = jnp.where(lo + row < ends[-1], top_g.reshape(-1)[at], 0.0)
+                return acc.at[of].add(weighed(o, g))
+
+            out = jax.lax.fori_loop(0, trips, one, jnp.zeros(
+                (n, w_down.shape[-1]), jnp.float32)).astype(dtype)
+            further = (trips - 1,)
         if latent:
             out = out @ kernel_of(moe["latent_up"], dtype)
         if shared:
@@ -574,23 +673,27 @@ def expert_ffn(
             mid = (act(tok @ kernel_of(moe["shared_gate"], dtype)) * up
                    if shared_gated else act(up))
             out = out + mid @ kernel_of(moe["shared_down"], dtype)
-        return out, counts
+        return (out, counts) + further
 
     d = y.shape[-1]
     tok = y.reshape(-1, d)
     n = tok.shape[0]
     counted = jnp.ones(n, bool) if live is None else jnp.repeat(
         live, n // live.shape[0])
-    chunk = min(_MOE_CHUNK, max(128, _MOE_ROWS // k // 128 * 128))
-    if n <= chunk:
-        out, counts = run((tok, counted))
-        return out.reshape(y.shape), counts
-    pad = (-n) % chunk
-    out, counts = jax.lax.map(run, (
-        jnp.pad(tok, ((0, pad), (0, 0))).reshape(-1, chunk, d),
-        jnp.pad(counted, (0, pad)).reshape(-1, chunk),
-    ))
-    return out.reshape(-1, d)[:n].reshape(y.shape), counts.sum(0)
+    chunks, chunk, _ = moe_layout(n, k, e, held)
+    if chunks == 1:
+        out, counts, *further = run((tok, counted))
+        out = out.reshape(y.shape)
+    else:
+        pad = (-n) % chunk
+        out, counts, *further = jax.lax.map(run, (
+            jnp.pad(tok, ((0, pad), (0, 0))).reshape(-1, chunk, d),
+            jnp.pad(counted, (0, pad)).reshape(-1, chunk),
+        ))
+        out, counts = out.reshape(-1, d)[:n].reshape(y.shape), counts.sum(0)
+    if windows is not None and further:
+        windows.append(further[0].sum())
+    return out, counts
 
 
 def _moe_ffn(moe: Dict[str, Any], y: jax.Array, dtype, k: int = 2,
@@ -878,6 +981,7 @@ def _feed_forward(blk, cfg: LMConfig, y, experts, mesh):
             live=None if experts is None else experts["live"], mesh=mesh,
             scoring=cfg.router_scoring, scale=cfg.router_scale,
             activation=cfg.activation,
+            windows=None if experts is None else experts.get("windows"),
         )
         if experts is not None:
             experts["counts"].append(counts)
@@ -924,7 +1028,9 @@ def _apply_block(
     every slot sits at its own position) — rope handles both forms.
     `experts` (a caller that wants the routing's counts) is
     {"live": [B] bool or None, "counts": []}: every expert layer
-    appends its [E] assignment counts to the list.
+    appends its [E] assignment counts to the list, and to a list under
+    "windows", where the caller put one, the windows it ran past its
+    first (`expert_ffn`; nothing where its shapes give one window).
     """
     if kind is None:
         y = _rms_norm(x, blk["ln_attn"]["scale"], cfg.dtype, cfg.norm_eps)
@@ -1427,6 +1533,7 @@ def prefill(
     logits_index: Optional[jax.Array] = None,
     mesh: Optional[Mesh] = None,
     head: bool = True,
+    experts: Optional[Dict[str, Any]] = None,
 ) -> Tuple[Optional[jax.Array], Dict[str, Any]]:
     """Process the WHOLE prompt in one forward: returns (logits at the
     last prompt position [B, V], cache filled for positions < Tp).
@@ -1436,7 +1543,8 @@ def prefill(
     the logits.
 
     `mesh` is the mesh the params are sharded over, if any: the flash
-    kernel is then placed per device (`_kernel_on_mesh`).
+    kernel is then placed per device (`_kernel_on_mesh`). `experts` is
+    `_apply_block`'s, for a caller that wants the expert layers' counts.
 
     `logits_index` (scalar) selects which position's logits to return
     instead of the last — the continuous-batching server prefills
@@ -1497,8 +1605,8 @@ def prefill(
             return out
 
         x, k, v = _apply_block(
-            params[name], cfg, x, positions, attn_fn, mesh=mesh, kind=kind,
-            ssm_fn=ssm_fn,
+            params[name], cfg, x, positions, attn_fn, experts=experts,
+            mesh=mesh, kind=kind, ssm_fn=ssm_fn,
         )
         if k is None:
             continue

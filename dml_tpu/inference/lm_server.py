@@ -102,6 +102,7 @@ from .generate import (
     decode_block_rows,
     heads_axis,
     init_cache,
+    moe_layout,
     prefill,
     state_bytes,
 )
@@ -229,6 +230,14 @@ _M_MOE_LOAD = METRICS.histogram(
     "moe_expert_load_max",
     "assignments to the busiest expert over the mean over all routed "
     "experts, one forward one layer")
+_M_MOE_WINDOWS = METRICS.counter(
+    "moe_windows_total",
+    "windows of held assignments the expert layers ran "
+    "(`generate.expert_ffn`), a layer a forward a chunk, by kind=: first "
+    "(every call's one) | further (past it: the held rows outran "
+    "`generate.moe_window`'s size, and nothing was dropped)")
+_M_MOE_WINDOWS_FIRST = _M_MOE_WINDOWS.labels(kind="first")
+_M_MOE_WINDOWS_FURTHER = _M_MOE_WINDOWS.labels(kind="further")
 _M_SPEC_PROPOSED = METRICS.counter(
     "lm_specdec_proposed_total",
     "draft tokens proposed to the verify program")
@@ -666,13 +675,7 @@ class LMServer:
         # does a latent-attention prefill (its logits it reads): a
         # group's rows over 40 layers padded to 4,096 are 0.2 GB a row
         self._prefill = jax.jit(
-            lambda p, pr, li: prefill(
-                p, self.cfg, pr,
-                pr.shape[1] if self._bucket_rows else self.max_len,
-                logits_index=li, mesh=self._mesh,
-                head=diffusion is None,
-            )
-        )
+            lambda p, pr, li: self._prefill_impl(p, pr, li))
         self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
         self._chunk_fn = jax.jit(
             self._chunk_impl, donate_argnums=(1, 2, 3)
@@ -725,6 +728,17 @@ class LMServer:
             cfg.experts_first,
             cfg.experts_first + jax.tree_util.tree_leaves(
                 moes[0]["w_up"])[0].shape[0] if moes else 0)
+        # where the tree holds a SHARE of the routed experts, how many
+        # windows an expert layer runs is read from the routing
+        # (`generate.expert_ffn`): a prefill hands its windows past the
+        # first back as a third output, added up here on the device
+        # until the next packed readback carries them (as first tokens
+        # ride it); None = every call is one window, known from shapes
+        self._windows_dev = (
+            jnp.zeros(1, jnp.int32)
+            if moes and diffusion is None
+            and self._held[1] - self._held[0] < self._routed[1] else None)
+        self._no_windows = self._windows_dev
         if diffusion is not None:
             # the current block of every slot, device-resident like
             # cur/pos: `_pos_dev` is then the block's first row
@@ -954,6 +968,24 @@ class LMServer:
             out_shardings=NamedSharding(self._mesh, P(None, ax)),
         )()
 
+    def _prefill_impl(self, params, prompts, last):
+        """A prefill group's program: `prefill` over the padded rows,
+        logits at each row's `last` position. Where the expert layers'
+        windows are read from the routing (`_windows_dev`), a third
+        output [1]: the windows past each call's first, all layers."""
+        experts = (None if self._windows_dev is None
+                   else {"live": None, "counts": [], "windows": []})
+        logits, pcache = prefill(
+            params, self.cfg, prompts,
+            prompts.shape[1] if self._bucket_rows else self.max_len,
+            logits_index=last, mesh=self._mesh,
+            head=self.diffusion is None, experts=experts,
+        )
+        if experts is None:
+            return logits, pcache
+        return logits, pcache, sum(
+            experts["windows"], jnp.zeros((), jnp.int32)).reshape(1)
+
     def _insert_impl(self, cache, pcache, slot, row):
         """Copy row `row` of a (possibly group-batched) prefilled
         cache into `slot`. Stale tail positions past the prompt are
@@ -1052,15 +1084,17 @@ class LMServer:
         of occupied slots' tokens reduced on the device to five numbers
         a step a layer ([chunk, layers, 5]: assignments, those to held
         experts, distinct experts reached, distinct held experts
-        reached, assignments to the busiest expert), which ride the
-        dispatch's packed readback."""
+        reached, assignments to the busiest expert; a sixth where the
+        layers' windows are read from the routing, `expert_ffn`: those
+        a layer ran past its first), which ride the dispatch's packed
+        readback."""
         last = self.max_len - 1
         lo, hi = self._held
 
         def body(carry, _):
             cache, cur, pos = carry
             pos_c = jnp.minimum(pos, last)
-            experts = ({"live": rid > 0, "counts": []}
+            experts = ({"live": rid > 0, "counts": [], "windows": []}
                        if self._routed[0] else None)
             logits, cache = batched_decode_step(
                 params, self.cfg, cache, cur, pos_c, mesh=self._mesh,
@@ -1069,8 +1103,10 @@ class LMServer:
             nxt = self._sample_slots(logits, rid, pos_c + 1)
             if experts is None:
                 return (cache, nxt, pos_c + 1), (nxt,)
-            routed = jnp.stack(_routing_numbers(
-                jnp.stack(experts["counts"]), lo, hi), -1).astype(jnp.int32)
+            numbers = _routing_numbers(jnp.stack(experts["counts"]), lo, hi)
+            if experts["windows"]:  # a sixth: windows past the first
+                numbers.append(jnp.stack(experts["windows"]))
+            routed = jnp.stack(numbers, -1).astype(jnp.int32)
             return (cache, nxt, pos_c + 1), (nxt, routed)
 
         (cache, cur, pos), out = jax.lax.scan(
@@ -1590,10 +1626,12 @@ class LMServer:
                 # is for the last group's inserts; the host's few ms of
                 # preparing this one are then the device's idle
                 jax.block_until_ready(self.cache)
-            logits, pcache = self._prefill(
+            logits, pcache, *further = self._prefill(
                 self.params, jnp.asarray(padded),
                 jnp.asarray(tps - 1),
             )
+            if further:  # rides the next packed readback
+                self._windows_dev = self._windows_dev + further[0]
             for row, (slot, req) in enumerate(grp):
                 self.cache = self._insert(
                     self.cache, pcache, jnp.int32(slot), jnp.int32(row)
@@ -1649,6 +1687,16 @@ class LMServer:
             prompt_tokens = int(tps[:k].sum())
             span.label(padded_rows=kp, prompt_tokens=prompt_tokens,
                        padded_tokens=kp * bucket)
+            layers, e = self._routed
+            if layers:
+                # what an expert layer lays out for this group, from
+                # shapes: assignments, and rows at one window a chunk
+                top = self.cfg.experts_per_token
+                chunks, per, window = moe_layout(
+                    kp * bucket, top, e, self._held[1] - self._held[0])
+                span.label(moe_rows=chunks * per * top,
+                           moe_rows_laid=chunks * window)
+                _M_MOE_WINDOWS_FIRST.inc(layers * chunks)
         now = span.m1
         _M_PREFILL.observe(now - span.m0)
         _M_PREFILL_PROMPT.inc(prompt_tokens)
@@ -1933,19 +1981,27 @@ class LMServer:
     def _read_packed(self, step: Any, arrays: List[jax.Array]) -> np.ndarray:
         """ONE packed readback per step: the dispatch's tokens, the
         first tokens placements deferred since the last one
-        (`_firsts_dev`, whole) and what else the dispatch counted.
+        (`_firsts_dev`, whole), what else the dispatch counted and,
+        last, the prefills' windows (`_windows_dev`, where it is kept).
         cur/pos never come back to the host (device-authoritative). Two
         phases under `step`: `lm_pack` issues the concatenate, whose
         operands have the same shapes in every step of a server's mode,
         so it compiles once, in the first; `lm_readback` is the
         blocking np.asarray, which stalls the host until the device
         drains and is the ONLY such stall in the serve loop."""
+        if self._windows_dev is not None:
+            arrays = arrays + [self._windows_dev]
         with TRACER.loop_span("lm_pack", step, arrays=len(arrays)) as pack:
             packed = jnp.concatenate(arrays)
         with TRACER.loop_span("lm_readback", step) as readback:
             out = np.asarray(packed)
         _M_PACK.observe(pack.m1 - pack.m0)
         _M_READBACK.observe(readback.m1 - readback.m0)
+        if self._windows_dev is not None:
+            # the prefills' windows past their first since the last one
+            _M_MOE_WINDOWS_FURTHER.inc(int(out[-1]))
+            self._windows_dev = self._no_windows
+            out = out[:-1]
         return out
 
     def _finish_step(self, step: Any, delivered: int, first_n: int) -> None:
@@ -2017,7 +2073,7 @@ class LMServer:
         first_n = len(firsts)
         if routed:
             self._note_routing(step, packed[n + self.max_slots:].reshape(
-                self.chunk, self._routed[0], 5))
+                self.chunk, self._routed[0], -1))
         with TRACER.loop_span("lm_deliver", step) as deliver:
             toks = packed[:n].reshape(self.chunk, self.max_slots)
             # snapshot occupancy BEFORE any deliver() fires user
@@ -2126,12 +2182,17 @@ class LMServer:
         occupied slots' tokens, those to held experts, the distinct
         experts they reach, the distinct held ones, the assignments to
         the busiest expert (`_chunk_impl` reduces its counts to these on
-        the device; a diffusion dispatch reads the counts back whole)."""
-        routed = routed.reshape(-1, 5).astype(np.float64)
+        the device; a diffusion dispatch reads the counts back whole).
+        Every row is one call of an expert layer, so one first window;
+        a sixth number is the windows that call ran past it."""
+        routed = routed.reshape(-1, routed.shape[-1]).astype(np.float64)
+        _M_MOE_WINDOWS_FIRST.inc(len(routed))
+        if routed.shape[-1] > 5:
+            _M_MOE_WINDOWS_FURTHER.inc(float(routed[:, 5].sum()))
         routed = routed[routed[:, 0] > 0]  # forwards that had a token
         if not len(routed):
             return
-        total, held, touched, touched_held, busiest = routed.T
+        total, held, touched, touched_held, busiest = routed.T[:5]
         load = busiest * self._routed[1] / total
         _M_MOE_HELD.inc(float(held.sum()))
         _M_MOE_ABSENT.inc(float((total - held).sum()))

@@ -227,6 +227,8 @@ line when you add the metric.
     moe_expert_load_max              busiest expert / mean, a forward a layer
     moe_experts_touched              distinct experts a forward reaches a layer
     moe_experts_touched_held         ... of the experts this tree holds
+    moe_windows_total                windows of held assignments the expert
+                                     layers ran by kind= first|further
     request_admitted_total           front-door admissions per SLO class
     request_batch_fill_fraction      formed-batch fill quality
     request_batch_formation_seconds  batch formation wall

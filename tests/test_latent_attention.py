@@ -329,6 +329,147 @@ def test_the_sixteen_shares_of_an_expert_layer_add_up_to_the_uncut_layer(
     np.testing.assert_allclose(total, want, atol=2e-5)
 
 
+# ----------------------------------------------------------------------
+# the expert layer over windows of the rows it holds
+# ----------------------------------------------------------------------
+
+
+def _share(held, seed=11):
+    """A gated expert layer of 16 routed experts top-4 with its gated
+    shared expert, cut to the first `held` experts."""
+    whole = ref.make_params({**SPEC, "experts_held": [0, 16]}, seed)
+    moe = whole["block_1"]["moe"]
+    return {**moe, **{w: moe[w][:held] for w in ("w_up", "w_gate", "w_down")}}
+
+
+def _layer(moe, y, monkeypatch, window, **kw):
+    """(out, counts, windows past the first or None) of `expert_ffn` with
+    every call laid out over `window` rows (None: all its rows, the form
+    without a loop; 0: the size its shapes give)."""
+    if window != 0:
+        monkeypatch.setattr(
+            g, "moe_window",
+            lambda n, k, e, held: n * k if window is None else window)
+    past = []
+    out, counts = g.expert_ffn(
+        moe, y, jnp.float32, 4, scoring="sigmoid", scale=2.5, windows=past,
+        **kw)
+    monkeypatch.undo()
+    return np.asarray(out), np.asarray(counts), (
+        int(past[0]) if past else None)
+
+
+def _assert_the_same_float32_sums(got, want):
+    """Equal up to the ORDER of float32 additions: the loop adds a
+    token's held terms in the sort's order (by expert), the form without
+    a loop along the token's top-k list (measured 5e-7 on outputs of
+    spread ~1; a sum carried in bfloat16 reads 1e-2)."""
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=4e-6 * max(1.0, np.abs(want).max()))
+
+
+@pytest.mark.parametrize("live", [False, True])
+@pytest.mark.parametrize("held", [1, 4, 16])
+def test_windows_of_held_rows_give_the_one_windows_sums(
+        monkeypatch, held, live):
+    """Held shares 1/16, 1/4 and 1: windows of 16 rows against ONE window
+    under the same loop, against the form without a loop over all 400
+    rows, and against the size the shapes give (128 rows for a share, all
+    rows where every expert is held). Not bit for bit: XLA's CPU
+    `ragged_dot` rounds a row otherwise in a call of another row count."""
+    moe = _share(held)
+    y = jax.random.normal(jax.random.PRNGKey(6), (2, 50, 64))
+    kw = {"live": jnp.asarray([True, False])} if live else {}
+    want, counts, none = _layer(moe, y, monkeypatch, None, **kw)
+    assert none is None and int(counts.sum()) == (50 if live else 100) * 4
+    got, c16, past = _layer(moe, y, monkeypatch, 16, **kw)
+    one, _, none = _layer(moe, y, monkeypatch, 399, **kw)
+    assert none == (held == 16)  # all 400 rows held: a second window of 1
+    _assert_the_same_float32_sums(got, one)
+    _assert_the_same_float32_sums(got, want)
+    np.testing.assert_array_equal(c16, counts)
+    # the held assignments of ALL rows are computed, counted or not
+    _, every, _ = _layer(moe, y, monkeypatch, None)
+    assert past == max(-(-int(every[:held].sum()) // 16), 1) - 1
+    assert past > 0
+    by_shape, _, past = _layer(moe, y, monkeypatch, 0, **kw)
+    _assert_the_same_float32_sums(by_shape, want)
+    assert past == (None if held == 16 else 0)
+
+
+@pytest.mark.parametrize("push,windows", [(10.0, 4), (-10.0, 1)])
+def test_a_routing_forced_onto_or_off_the_held_experts_drops_nothing(
+        monkeypatch, push, windows):
+    """A selection bias that sends every token to the 4 held experts: 800
+    held rows over windows of 256 (the size 200 tokens top-4 of 16 with 4
+    held give) are four windows, every assignment computed. The opposite
+    bias: no held row, one window of nothing, the shared expert alone."""
+    moe = _share(4)
+    bias = np.zeros(16, np.float32)
+    bias[:4] = push
+    moe = {**moe, "router": {**moe["router"], "bias": jnp.asarray(bias)}}
+    y = jax.random.normal(jax.random.PRNGKey(8), (1, 200, 64))
+    assert g.moe_layout(200, 4, 16, 4) == (1, 200, 256)
+    want, counts, _ = _layer(moe, y, monkeypatch, None)
+    got, c, past = _layer(moe, y, monkeypatch, 0)
+    assert int(counts[:4].sum()) == (800 if push > 0 else 0)
+    assert past == windows - 1
+    _assert_the_same_float32_sums(got, want)
+    np.testing.assert_array_equal(c, counts)
+    # ... and both are the reference's layer over the held experts
+    m = ref._dims({**SPEC, "experts_held": [0, 4]})
+    np.testing.assert_allclose(
+        got[0], ref.experts(y[0], moe, m, "f32"), atol=2e-5)
+    if push < 0:
+        routed = {k: v for k, v in moe.items() if not k.startswith("shared")}
+        zeros, _, _ = _layer(routed, y, monkeypatch, 0)
+        assert not zeros.any()
+
+
+def test_the_window_is_a_size_taken_from_shapes():
+    # the benchmark's cells: a 4,096-token prefill row and a decode step
+    # of 16 slots at 16 of 256 experts top-8; a chunk of 1,408 tokens and
+    # a decode step of 64 slots at 128 of 512 top-22; every expert held
+    assert g.moe_layout(4096, 8, 256, 16) == (1, 4096, 2560)
+    assert g.moe_layout(512, 8, 256, 16) == (1, 512, 384)
+    assert g.moe_layout(16, 8, 256, 16) == (1, 16, 128)  # one tile: all
+    assert g.moe_layout(2048, 22, 512, 128) == (2, 1408, 9728)
+    assert g.moe_layout(64, 22, 512, 128) == (1, 64, 512)
+    assert g.moe_layout(8 * 2048, 8, 128, 128) == (4, 4096, 32768)
+    assert g.moe_layout(128, 8, 128, 128) == (1, 128, 1024)
+
+
+def test_windows_are_counted_and_a_prefill_group_says_what_it_lays_out(
+        model, monkeypatch):
+    """Windows of 8 rows, so that the decode step (4 slots x top-4 = 16
+    rows) loops too: the prefills' windows past the first ride the next
+    packed readback, the decode steps' ride beside the routing's numbers."""
+    params, cfg = model
+    monkeypatch.setattr(
+        g, "moe_window", lambda n, k, e, held: min(8, n * k))
+    ran = METRICS.counter("moe_windows_total")
+    before = {w: ran.value(kind=w) for w in ("first", "further")}
+    TRACER.reset()
+    prompts = [_tokens(n, seed=n) for n in (30, 9)]
+    srv, got = _serve(params, cfg, prompts, budget=9)
+    monkeypatch.undo()
+    for p, toks in zip(prompts, got):
+        np.testing.assert_array_equal(toks, _alone(params, cfg, p, 9))
+    groups = TRACER.loop_spans("lm_prefill_group")
+    steps = TRACER.loop_spans("lm_step")
+    for s in groups:  # 2 expert layers top-4; one chunk a call
+        assert s["lb"]["moe_rows"] == s["lb"]["padded_tokens"] * 4
+        assert s["lb"]["moe_rows_laid"] == 8
+    # a layer's first window: every prefill group, every decode step
+    first = ran.value(kind="first") - before["first"]
+    assert first == 2 * len(groups) + 2 * 4 * len(steps)
+    # 4 of 16 experts held: about a quarter of the rows, in windows of 8
+    further = ran.value(kind="further") - before["further"]
+    rows = sum(s["lb"]["moe_rows"] for s in groups)
+    assert rows / 4 / 8 * 2 * 0.3 < further < rows / 4 / 8 * 2 * 3
+    assert int(np.asarray(srv._windows_dev)[0]) == 0  # all read
+
+
 def test_the_int8_weight_path_serves_the_latent_tree(model):
     from dml_tpu.inference.quantize import quantize_lm_params
 

@@ -267,6 +267,97 @@ def test_the_sigmoid_router_against_a_plain_top_k(bias, scale):
     assert int(counts.sum()) == 7 * 3
 
 
+def _windowed(monkeypatch, window, spec, params, y, **kw):
+    """(out, counts, windows past the first or None) of the latent expert
+    layer laid out over `window` rows a call (None: all its rows, the
+    form without a loop; 0: the size its shapes give)."""
+    if window != 0:
+        monkeypatch.setattr(
+            G, "moe_window",
+            lambda n, k, e, held: n * k if window is None else window)
+    cfg = lm_spec_parts(spec)[1]
+    past = []
+    out, counts = G.expert_ffn(
+        params["block_0"]["moe"], y, jnp.float32, cfg.experts_per_token,
+        cfg.experts_first, scoring=cfg.router_scoring,
+        scale=cfg.router_scale, activation=cfg.activation, windows=past,
+        **kw)
+    monkeypatch.undo()
+    return np.asarray(out), np.asarray(counts), (
+        int(past[0]) if past else None)
+
+
+def _float32_sums(got, want):
+    # equal up to the order of float32 additions (PERF.md section 3)
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=4e-6 * max(1.0, np.abs(want).max()))
+
+
+@pytest.mark.parametrize("live", [False, True])
+@pytest.mark.parametrize("first,held", [(6, 2), (8, 8), (0, 32)])
+def test_latent_experts_over_windows_give_the_one_windows_sums(
+        monkeypatch, first, held, live):
+    """Held shares 1/16, 1/4 and 1 of 32 ungated relu-squared experts
+    top-6 in the latent width: windows of 24 rows against one window
+    under the same loop, against the form without a loop over all 606
+    rows, and against the size the shapes give."""
+    whole = REF.make_params(_moe_spec([0, 32]), 3)["block_0"]["moe"]
+    spec = _moe_spec([first, held])
+    params = {"block_0": {"moe": {
+        **whole, "w_up": whole["w_up"][first:first + held],
+        "w_down": whole["w_down"][first:first + held]}}}
+    y = jax.random.normal(jax.random.PRNGKey(3), (1, 101, 32))
+    kw = {"live": jnp.asarray([False])} if live else {}
+    want, counts, none = _windowed(monkeypatch, None, spec, params, y, **kw)
+    assert none is None and int(counts.sum()) == (0 if live else 606)
+    got, c, past = _windowed(monkeypatch, 24, spec, params, y, **kw)
+    one, _, none = _windowed(monkeypatch, 605, spec, params, y, **kw)
+    assert none == (held == 32)  # all 606 rows held: a second window of 1
+    _float32_sums(got, one)
+    _float32_sums(got, want)
+    np.testing.assert_array_equal(c, counts)
+    _, every, _ = _windowed(monkeypatch, None, spec, params, y)
+    here = int(every[first:first + held].sum())
+    assert past == max(-(-here // 24), 1) - 1 and past > 0
+    by_shape, _, past = _windowed(monkeypatch, 0, spec, params, y, **kw)
+    _float32_sums(by_shape, want)
+    assert past == (None if held == 32 else 0)
+    # ... and the reference's own share of the layer
+    np.testing.assert_allclose(
+        got[0], REF.experts(y[0], params["block_0"]["moe"],
+                            REF._dims(spec), "f32"), atol=2e-4)
+
+
+@pytest.mark.parametrize("push,windows", [(10.0, 3), (-10.0, 1)])
+def test_latent_experts_drop_nothing_when_every_token_takes_the_held(
+        monkeypatch, push, windows):
+    """A selection bias on the 8 held experts of 32, top-6: all 606
+    assignments are held rows where the shapes give windows of 256 (three
+    windows, nothing dropped); the opposite bias leaves no held row, and
+    the layer is its shared expert."""
+    spec = _moe_spec([8, 8])
+    moe = REF.make_params(spec, 3)["block_0"]["moe"]
+    bias = np.zeros(32, np.float32)
+    bias[8:16] = push
+    moe = {**moe, "router": {**moe["router"], "bias": jnp.asarray(bias)}}
+    params = {"block_0": {"moe": moe}}
+    y = jax.random.normal(jax.random.PRNGKey(4), (1, 101, 32))
+    assert G.moe_layout(101, 6, 32, 8) == (1, 101, 256)
+    want, counts, _ = _windowed(monkeypatch, None, spec, params, y)
+    got, c, past = _windowed(monkeypatch, 0, spec, params, y)
+    assert int(counts[8:16].sum()) == (606 if push > 0 else 0)
+    assert past == windows - 1
+    _float32_sums(got, want)
+    np.testing.assert_array_equal(c, counts)
+    np.testing.assert_allclose(
+        got[0], REF.experts(y[0], moe, REF._dims(spec), "f32"), atol=2e-4)
+    if push < 0:
+        np.testing.assert_allclose(
+            got[0], REF.experts(y[0], moe, REF._dims(spec), "f32")
+            - REF.experts(y[0], moe, REF._dims(spec), "f32", shared=False),
+            atol=2e-4)
+
+
 # ----------------------------------------------------------------------
 # through the server: slots, placement, reuse
 # ----------------------------------------------------------------------
@@ -375,6 +466,41 @@ def test_spans_and_counters_carry_the_state_and_the_routing():
         2 * 4 * s["heads"] * s["head_dim"] * s["state"] * 4)
     assert state.value(kind="conv") == 2 * 4 * 3 * (64 + 2 * 2 * 16) * 4
     assert state.value(kind="kv") == 4 * 2 * 2 * 64 * 16 * 4
+
+
+def test_windows_ride_the_readbacks_and_the_group_says_what_it_lays_out(
+        monkeypatch):
+    """Windows of 4 rows, so that a decode step (4 slots x top-3) loops as
+    a prefill does: the steps' windows past the first are the routing's
+    sixth number, the prefills' ride the next packed readback."""
+    monkeypatch.setattr(G, "moe_window", lambda n, k, e, held: min(4, n * k))
+    spec = _spec()
+    be, params, _ = _backend(spec)
+    ran = METRICS.counter("moe_windows_total")
+    before = {w: ran.value(kind=w) for w in ("first", "further")}
+    g0 = len(TRACER.loop_spans("lm_prefill_group"))
+    n0 = len(TRACER.loop_spans("lm_step"))
+    prompts = _prompts([6, 9])
+    try:
+        got = _serve(be, prompts, [6, 6])
+        left = int(np.asarray(be.server._windows_dev)[0])
+    finally:
+        be.close()
+    monkeypatch.undo()
+    for p, toks in zip(prompts, got):
+        _assert_the_references_choice(spec, params, p, 6, toks)
+    groups = TRACER.loop_spans("lm_prefill_group")[g0:]
+    steps = TRACER.loop_spans("lm_step")[n0:]
+    assert groups and steps and left == 0
+    for d in groups:  # top-3, one chunk a call, one window of 4 rows
+        assert d["lb"]["moe_rows"] == d["lb"]["padded_tokens"] * 3
+        assert d["lb"]["moe_rows_laid"] == 4
+    first = ran.value(kind="first") - before["first"]
+    assert first == 2 * len(groups) + 2 * 4 * len(steps)
+    # 4 of 16 experts held: a quarter of the rows or so, 4 a window
+    rows = sum(d["lb"]["moe_rows"] for d in groups)
+    further = ran.value(kind="further") - before["further"]
+    assert rows / 4 / 4 * 2 * 0.3 < further < rows / 4 / 4 * 2 * 3
 
 
 # ----------------------------------------------------------------------

@@ -157,6 +157,70 @@ def test_expert_layer_compiles_to_grouped_matmuls(topo, monkeypatch, kernel):
     assert ("ragged-dot-metadata" in text) is not kernel
 
 
+def _all_held(r, gt, u, dn, y):
+    from dml_tpu.inference.generate import expert_ffn
+
+    return expert_ffn(
+        {"router": {"kernel": r}, "w_gate": gt, "w_up": u, "w_down": dn},
+        y, jnp.bfloat16, 8)
+
+
+def _a_share(r, b, gt, u, dn, sg, su, sd, y):
+    from dml_tpu.inference.generate import expert_ffn
+
+    return expert_ffn(
+        {"router": {"kernel": r, "bias": b}, "w_gate": gt, "w_up": u,
+         "w_down": dn, "shared_gate": {"kernel": sg},
+         "shared_up": {"kernel": su}, "shared_down": {"kernel": sd}},
+        y, jnp.bfloat16, 8, scoring="sigmoid", scale=2.5)
+
+
+def _share_shapes(e, held, y, d=2048, f=768):
+    bf = jnp.bfloat16
+    return (((d, e), jnp.float32), ((e,), jnp.float32), ((held, d, f), bf),
+            ((held, d, f), bf), ((held, f, d), bf), ((d, f), bf),
+            ((d, f), bf), ((f, d), bf), (y + (d,), bf))
+
+
+@pytest.mark.parametrize("fn,shapes,sha", [
+    # sdar30b_a3b_l6: every expert held, a diffusion forward's 128 tokens
+    (_all_held, (((2048, 128), jnp.float32),)
+     + (((128, 2048, 768), jnp.bfloat16),) * 2
+     + (((128, 768, 2048), jnp.bfloat16), ((32, 4, 2048), jnp.bfloat16)),
+     "ae2472d56dcc6fcbc866d0f81e4880be811c0c2424afc9bf8209b5e39cfc1a1a"),
+    # joyai_llm_flash_ep16's decode step: 16 slots x top-8 = one tile
+    (_a_share, _share_shapes(256, 16, (16, 1)),
+     "e8275a3558b86fd3988387d706286f5c60f3f33be5374b722136c3db47a0a042"),
+])
+def test_one_window_of_all_rows_lowers_to_the_text_it_had(
+        topo, fn, shapes, sha):
+    """Where `moe_window` gives all the rows (every expert held; the
+    assignments one tile) `expert_ffn` is the program it was before
+    windows: the sha256 of its lowered text for the described chip,
+    `ragged_dot` form, is commit f8941f8's (PR 37 computed both sides
+    with this very function; the Pallas form differs from any other
+    commit's in the source lines its serialized kernel body carries)."""
+    import hashlib
+
+    one = SingleDeviceSharding(topo.devices[0])
+    text = jax.jit(lambda *a: fn(*a)).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in shapes)
+    ).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == sha
+
+
+def test_expert_layer_over_windows_compiles_with_its_loop(topo, monkeypatch):
+    """A 1,024-token prefill row at joyai_llm_flash_ep16's widths (16 of
+    256 gated experts held, top-8): windows of 640 rows under a loop whose
+    trip count is read from the routing, three Pallas grouped matmuls in
+    its body, and no [8,192, .] float32 array anywhere."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text, _ = compile_on_chip(
+        topo, lambda *a: _a_share(*a), *_share_shapes(256, 16, (1, 1024)))
+    assert text.count("tpu_custom_call") >= 3
+    assert "f32[640,2048]" in text and "f32[8192," not in text
+
+
 @pytest.mark.parametrize("backward", [False, True])
 def test_flash_attention_compiles(topo, backward):
     from dml_tpu.ops.flash_attention import flash_attention
