@@ -114,6 +114,13 @@ observability:
                                     without a slot at a dispatch)
   profile trace start [dir]         capture a jax.profiler (XLA) trace
   profile trace stop                stop + write the trace
+  profile trace read [dir]          the newest trace under dir as an account
+                                    of its window in the program's own names:
+                                    device-busy seconds by program and model
+                                    part (the jax.named_scope names of
+                                    tracing.PARTS, by self time), device-idle
+                                    seconds by the serving thread's span open
+                                    in each gap (tracing.read_profile)
   trace [dump]                      this node's flight recorder: finished
                                     request spans (bounded ring) + slowest-K
                                     + deadline-miss/shed/requeue/fallback
@@ -372,11 +379,22 @@ class NodeApp:
                 import jax
 
                 jax.profiler.stop_trace()
-                print("trace written (view with TensorBoard profile/Perfetto)")
+                print("trace written (view with TensorBoard profile/Perfetto, "
+                      "or 'profile trace read')")
+            elif a[0] == "trace" and len(a) >= 2 and a[1] == "read":
+                from .tracing import find_profile, read_profile
+
+                logdir = a[2] if len(a) > 2 else "/tmp/dml_tpu_trace"
+                path = find_profile(logdir)
+                if path is None:
+                    print(f"no trace under {logdir} "
+                          "('profile trace start' / 'stop' write one)")
+                else:
+                    print(json.dumps(read_profile(path), indent=2))
             else:
                 print("usage: profile metrics [prom|json|cluster] | "
                       "profile spans | profile trace start [dir] | "
-                      "profile trace stop")
+                      "profile trace stop | profile trace read [dir]")
         elif cmd == "trace":
             from . import tracing as trc
 

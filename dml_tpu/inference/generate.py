@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.transformer import rope
+from ..tracing import PARTS
 from .quantize import kernel_of
 
 RMS_EPS = 1e-6  # flax nn.RMSNorm default, as used by TransformerLM
@@ -55,6 +56,17 @@ ATTENTION_KINDS = ("grouped", "latent")
 #: which columns rope rotates together: the two halves of a head
 #: (i, i + D/2), or neighbours (2i, 2i + 1)
 ROPE_PAIRINGS = ("half", "interleaved")
+
+
+def part(name: str):
+    """`jax.named_scope(name)` for a part of the model (`tracing.PARTS`,
+    the one table of them): what is written under it carries the name on
+    its scope path, in the lowered text's debug info and in a profiler
+    trace (`tracing.read_profile`). Metadata alone: no compiled program
+    changes by it."""
+    if name not in PARTS:
+        raise ValueError(f"{name!r} is not in tracing.PARTS")
+    return jax.named_scope(name)
 
 
 @dataclass(frozen=True)
@@ -587,18 +599,19 @@ def expert_ffn(
         n = tok.shape[0]
         window = moe_window(n, k, e, held)
         looped = window < n * k  # else ONE window of all the rows
-        logits = tok.astype(jnp.float32) @ router.astype(jnp.float32)
-        if scoring == "softmax":
-            gates = jax.nn.softmax(logits, axis=-1)
-            top_g, top_i = jax.lax.top_k(gates, k)  # [n, k]
-        else:
-            gates = jax.nn.sigmoid(logits)
-            _, top_i = jax.lax.top_k(
-                gates if bias is None else gates + bias, k)
-            top_g = jnp.take_along_axis(gates, top_i, axis=-1)
-        top_g = top_g / jnp.maximum(top_g.sum(-1, keepdims=True), 1e-9)
-        if scale != 1.0:
-            top_g = top_g * scale
+        with part("moe_route"):
+            logits = tok.astype(jnp.float32) @ router.astype(jnp.float32)
+            if scoring == "softmax":
+                gates = jax.nn.softmax(logits, axis=-1)
+                top_g, top_i = jax.lax.top_k(gates, k)  # [n, k]
+            else:
+                gates = jax.nn.sigmoid(logits)
+                _, top_i = jax.lax.top_k(
+                    gates if bias is None else gates + bias, k)
+                top_g = jnp.take_along_axis(gates, top_i, axis=-1)
+            top_g = top_g / jnp.maximum(top_g.sum(-1, keepdims=True), 1e-9)
+            if scale != 1.0:
+                top_g = top_g * scale
 
         def tally(idx, weights, bins):
             # weights summed by idx, by compare-and-sum: the serial
@@ -609,21 +622,24 @@ def expert_ffn(
                 idx[:, None] == jnp.arange(bins, dtype=idx.dtype),
                 weights[:, None], 0), axis=0)
 
-        if looped:
-            counts = tally(top_i.reshape(-1),
-                           jnp.repeat(counted.astype(jnp.int32), k), e)
-        else:
-            counts = jnp.zeros(e, jnp.int32).at[top_i.reshape(-1)].add(
-                jnp.repeat(counted.astype(jnp.int32), k))
-        local = top_i.reshape(-1) - first
-        here = (local >= 0) & (local < held)
-        key = jnp.where(here, local, held)  # absent experts sort last
-        order = jnp.argsort(key)
-        if looped:
-            sizes = tally(key, jnp.ones_like(key), held)
-        else:
-            sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
-        src = tok @ kernel_of(moe["latent_down"], dtype) if latent else tok
+        with part("moe_route"):
+            if looped:
+                counts = tally(top_i.reshape(-1),
+                               jnp.repeat(counted.astype(jnp.int32), k), e)
+            else:
+                counts = jnp.zeros(e, jnp.int32).at[top_i.reshape(-1)].add(
+                    jnp.repeat(counted.astype(jnp.int32), k))
+            local = top_i.reshape(-1) - first
+            here = (local >= 0) & (local < held)
+            key = jnp.where(here, local, held)  # absent experts sort last
+            order = jnp.argsort(key)
+            if looped:
+                sizes = tally(key, jnp.ones_like(key), held)
+            else:
+                sizes = jnp.zeros(held + 1, jnp.int32).at[key].add(1)[:held]
+        with part("moe_experts"):
+            src = (tok @ kernel_of(moe["latent_down"], dtype) if latent
+                   else tok)
 
         def experts(xs, sizes):  # rows grouped by expert -> f32 rows
             h = grouped(xs, w_up, sizes)
@@ -639,20 +655,23 @@ def expert_ffn(
             return jnp.where(g[:, None] > 0.0, o * g[:, None], 0.0)
 
         if not looped:
-            o = experts(src[order // k], sizes)  # [n * k, d] f32
-            o = weighed(o, jnp.where(here, top_g.reshape(-1), 0.0)[order])
-            back = jnp.zeros(n * k, jnp.int32).at[order].set(
-                jnp.arange(n * k, dtype=jnp.int32))
-            out = o[back].reshape(n, k, -1).sum(1).astype(dtype)
+            with part("moe_experts"):
+                o = experts(src[order // k], sizes)  # [n * k, d] f32
+                o = weighed(
+                    o, jnp.where(here, top_g.reshape(-1), 0.0)[order])
+                back = jnp.zeros(n * k, jnp.int32).at[order].set(
+                    jnp.arange(n * k, dtype=jnp.int32))
+                out = o[back].reshape(n, k, -1).sum(1).astype(dtype)
             further = ()
         else:
             # the held assignments are the sort's first `ends[-1]`
             # positions: window w takes positions [w window, (w + 1)
             # window) of them, its groups the experts' runs clipped to it
-            ends = jnp.cumsum(sizes)
-            trips = jnp.maximum(-(-ends[-1] // window), 1)
-            order = jnp.pad(order, (0, -(n * k) % window))
-            row = jnp.arange(window, dtype=jnp.int32)
+            with part("moe_route"):
+                ends = jnp.cumsum(sizes)
+                trips = jnp.maximum(-(-ends[-1] // window), 1)
+                order = jnp.pad(order, (0, -(n * k) % window))
+                row = jnp.arange(window, dtype=jnp.int32)
 
             def one(w, acc):
                 lo = w * window
@@ -663,16 +682,19 @@ def expert_ffn(
                 g = jnp.where(lo + row < ends[-1], top_g.reshape(-1)[at], 0.0)
                 return acc.at[of].add(weighed(o, g))
 
-            out = jax.lax.fori_loop(0, trips, one, jnp.zeros(
-                (n, w_down.shape[-1]), jnp.float32)).astype(dtype)
+            with part("moe_experts"):
+                out = jax.lax.fori_loop(0, trips, one, jnp.zeros(
+                    (n, w_down.shape[-1]), jnp.float32)).astype(dtype)
             further = (trips - 1,)
         if latent:
-            out = out @ kernel_of(moe["latent_up"], dtype)
+            with part("moe_experts"):
+                out = out @ kernel_of(moe["latent_up"], dtype)
         if shared:
-            up = tok @ kernel_of(moe["shared_up"], dtype)
-            mid = (act(tok @ kernel_of(moe["shared_gate"], dtype)) * up
-                   if shared_gated else act(up))
-            out = out + mid @ kernel_of(moe["shared_down"], dtype)
+            with part("moe_shared"):
+                up = tok @ kernel_of(moe["shared_up"], dtype)
+                mid = (act(tok @ kernel_of(moe["shared_gate"], dtype)) * up
+                       if shared_gated else act(up))
+                out = out + mid @ kernel_of(moe["shared_down"], dtype)
         return (out, counts) + further
 
     d = y.shape[-1]
@@ -815,47 +837,52 @@ def ssm_mixer(
     b, t, _ = y.shape
     f32 = jnp.float32
     di, gn, kk = s.d_inner, s.groups * s.state, s.conv_kernel
-    zxbcdt = y @ kernel_of(p["in_proj"], cfg.dtype)
-    z = zxbcdt[..., :di]
-    xbc = zxbcdt[..., di:di + s.conv_width]
-    dt = zxbcdt[..., di + s.conv_width:]
-    left = (jnp.zeros((b, kk - 1, s.conv_width), xbc.dtype)
-            if state is None else state["conv"].astype(xbc.dtype))
-    full = jnp.concatenate([left, xbc], axis=1)  # [B, K-1+T, C]
-    w = p["conv"]["kernel"].astype(f32)  # [K, C]
-    conv = p["conv"]["bias"].astype(f32) + sum(
-        full[:, i:i + t].astype(f32) * w[i] for i in range(kk))
-    xbc = jax.nn.silu(conv).astype(cfg.dtype)
-    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
-    if lengths is None:
-        window = full[:, t:]
-    else:
-        dt = jnp.where(
-            jnp.arange(t)[None, :, None] < lengths[:, None, None], dt, 0.0)
-        window = jax.vmap(
-            lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, kk - 1, 0)
-        )(full, lengths.astype(jnp.int32))
-    a = -jnp.exp(p["A_log"].astype(f32))
-    x = xbc[..., :di].reshape(b, t, s.heads, s.head_dim)
-    bm = xbc[..., di:di + gn].reshape(b, t, s.groups, s.state)
-    cm = xbc[..., di + gn:].reshape(b, t, s.groups, s.state)
-    if state is not None and t == 1:
-        out, h = ssm_scan_step(
-            x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], state["ssm"])
-        out = out[:, None]
-    else:
-        h0 = (jnp.zeros((b, s.heads, s.head_dim, s.state), f32)
-              if state is None else state["ssm"])
-        out, h = ssm_scan_chunked(x, dt, a, bm, cm, h0, s.chunk)
-    out = out + p["D"].astype(f32)[:, None] * x.astype(f32)
-    out = out.reshape(b, t, di) * jax.nn.silu(z.astype(f32))
-    grp = out.reshape(b, t, s.groups, di // s.groups)
-    grp = grp * jax.lax.rsqrt(
-        jnp.mean(grp * grp, axis=-1, keepdims=True) + cfg.norm_eps)
-    out = (grp.reshape(b, t, di)
-           * p["norm"]["scale"].astype(f32)).astype(cfg.dtype)
-    return out @ kernel_of(p["out_proj"], cfg.dtype), {
-        "conv": window.astype(cfg.dtype), "ssm": h}
+    with part("ssm_proj"):
+        zxbcdt = y @ kernel_of(p["in_proj"], cfg.dtype)
+        z = zxbcdt[..., :di]
+        xbc = zxbcdt[..., di:di + s.conv_width]
+        dt = zxbcdt[..., di + s.conv_width:]
+        left = (jnp.zeros((b, kk - 1, s.conv_width), xbc.dtype)
+                if state is None else state["conv"].astype(xbc.dtype))
+        full = jnp.concatenate([left, xbc], axis=1)  # [B, K-1+T, C]
+        w = p["conv"]["kernel"].astype(f32)  # [K, C]
+        conv = p["conv"]["bias"].astype(f32) + sum(
+            full[:, i:i + t].astype(f32) * w[i] for i in range(kk))
+        xbc = jax.nn.silu(conv).astype(cfg.dtype)
+        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+        if lengths is None:
+            window = full[:, t:]
+        else:
+            dt = jnp.where(
+                jnp.arange(t)[None, :, None] < lengths[:, None, None],
+                dt, 0.0)
+            window = jax.vmap(
+                lambda row, n: jax.lax.dynamic_slice_in_dim(
+                    row, n, kk - 1, 0)
+            )(full, lengths.astype(jnp.int32))
+        a = -jnp.exp(p["A_log"].astype(f32))
+        x = xbc[..., :di].reshape(b, t, s.heads, s.head_dim)
+        bm = xbc[..., di:di + gn].reshape(b, t, s.groups, s.state)
+        cm = xbc[..., di + gn:].reshape(b, t, s.groups, s.state)
+    with part("ssm_scan"):
+        if state is not None and t == 1:
+            out, h = ssm_scan_step(
+                x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], state["ssm"])
+            out = out[:, None]
+        else:
+            h0 = (jnp.zeros((b, s.heads, s.head_dim, s.state), f32)
+                  if state is None else state["ssm"])
+            out, h = ssm_scan_chunked(x, dt, a, bm, cm, h0, s.chunk)
+    with part("ssm_proj"):
+        out = out + p["D"].astype(f32)[:, None] * x.astype(f32)
+        out = out.reshape(b, t, di) * jax.nn.silu(z.astype(f32))
+        grp = out.reshape(b, t, s.groups, di // s.groups)
+        grp = grp * jax.lax.rsqrt(
+            jnp.mean(grp * grp, axis=-1, keepdims=True) + cfg.norm_eps)
+        out = (grp.reshape(b, t, di)
+               * p["norm"]["scale"].astype(f32)).astype(cfg.dtype)
+        return out @ kernel_of(p["out_proj"], cfg.dtype), {
+            "conv": window.astype(cfg.dtype), "ssm": h}
 
 
 def rope_interleaved(x: jax.Array, positions: jax.Array,
@@ -910,34 +937,40 @@ def _latent_attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed):
     m, h, dt = cfg.latent, cfg.n_heads, cfg.dtype
     b, t = y.shape[:2]
     rope_fn = _rope_of(cfg)
-    c_q = _rms_norm(y @ kernel_of(blk["q_a"], dt), blk["q_a_norm"]["scale"],
-                    dt, cfg.norm_eps)
-    q = (c_q @ kernel_of(blk["q_b"], dt)).reshape(b, t, h, m.key_width)
-    q_nope = q[..., :m.nope_dim]
-    q_rope = rope_fn(q[..., m.nope_dim:], positions, cfg.rope_theta)
-    kva = y @ kernel_of(blk["kv_a"], dt)
-    c = _rms_norm(kva[..., :m.kv_rank], blk["kv_a_norm"]["scale"], dt,
-                  cfg.norm_eps)
-    k_r = rope_fn(kva[..., None, m.kv_rank:], positions, cfg.rope_theta)
-    rows = jnp.concatenate([c, k_r[:, :, 0]], axis=-1)
-    w_uk, w_uv = kernel_of(blk["w_uk"], dt), kernel_of(blk["w_uv"], dt)
     f32 = jnp.float32
-    if absorbed:
-        q_lat = jnp.einsum("bthn,hcn->bthc", q_nope, w_uk,
+    with part("attn_proj"):
+        c_q = _rms_norm(y @ kernel_of(blk["q_a"], dt),
+                        blk["q_a_norm"]["scale"], dt, cfg.norm_eps)
+        q = (c_q @ kernel_of(blk["q_b"], dt)).reshape(b, t, h, m.key_width)
+        q_nope = q[..., :m.nope_dim]
+        q_rope = rope_fn(q[..., m.nope_dim:], positions, cfg.rope_theta)
+        kva = y @ kernel_of(blk["kv_a"], dt)
+        c = _rms_norm(kva[..., :m.kv_rank], blk["kv_a_norm"]["scale"], dt,
+                      cfg.norm_eps)
+        k_r = rope_fn(kva[..., None, m.kv_rank:], positions, cfg.rope_theta)
+        rows = jnp.concatenate([c, k_r[:, :, 0]], axis=-1)
+        w_uk, w_uv = kernel_of(blk["w_uk"], dt), kernel_of(blk["w_uv"], dt)
+        if absorbed:
+            q_lat = jnp.einsum("bthn,hcn->bthc", q_nope, w_uk,
+                               preferred_element_type=f32).astype(dt)
+            q = jnp.concatenate([q_lat, q_rope], axis=-1)
+        else:
+            k_nope = jnp.einsum("btc,hcn->bthn", c, w_uk,
+                                preferred_element_type=f32).astype(dt)
+            v = jnp.einsum("btc,hcv->bthv", c, w_uv,
                            preferred_element_type=f32).astype(dt)
-        o_lat = attn_fn(jnp.concatenate([q_lat, q_rope], axis=-1), rows)
-        attn = jnp.einsum("bthc,hcv->bthv", o_lat.astype(dt), w_uv,
-                          preferred_element_type=f32)
-    else:
-        k_nope = jnp.einsum("btc,hcn->bthn", c, w_uk,
-                            preferred_element_type=f32).astype(dt)
-        v = jnp.einsum("btc,hcv->bthv", c, w_uv,
-                       preferred_element_type=f32).astype(dt)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_r, (b, t, h, m.rope_dim))], axis=-1)
-        attn = attn_fn(jnp.concatenate([q_nope, q_rope], axis=-1), k, v)
-    attn = attn.reshape(b, t, h * m.v_dim).astype(dt)
-    return attn @ kernel_of(blk["proj"], dt), rows
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_r, (b, t, h, m.rope_dim))],
+                axis=-1)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    # the closure names its own parts (`attn_core`, `cache_write`)
+    attn = attn_fn(q, rows) if absorbed else attn_fn(q, k, v)
+    with part("attn_proj"):
+        if absorbed:
+            attn = jnp.einsum("bthc,hcv->bthv", attn.astype(dt), w_uv,
+                              preferred_element_type=f32)
+        attn = attn.reshape(b, t, h * m.v_dim).astype(dt)
+        return attn @ kernel_of(blk["proj"], dt), rows
 
 
 def _attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed=False):
@@ -951,20 +984,24 @@ def _attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed=False):
         return out, rows, None
     b, t = y.shape[:2]
     h, hd, kv, qw = cfg.n_heads, cfg.head_dim, cfg.kv_heads, cfg.q_width
-    qkv = y @ kernel_of(blk["qkv"], cfg.dtype)  # [B, T, qw + 2*kv*hd]
-    q = qkv[..., :qw].reshape(b, t, h, hd)
-    k = qkv[..., qw : qw + kv * hd].reshape(b, t, kv, hd)
-    v = qkv[..., qw + kv * hd :]
-    if cfg.qk_norm:
-        q = _rms_norm(q, blk["q_norm"]["scale"], cfg.dtype, cfg.norm_eps)
-        k = _rms_norm(k, blk["k_norm"]["scale"], cfg.dtype, cfg.norm_eps)
-    if cfg.rope:
-        q = _rope_of(cfg)(q, positions, cfg.rope_theta)
-        k = _rope_of(cfg)(k, positions, cfg.rope_theta)
-    v = v.reshape(b, t, kv, hd)
-    attn = attn_fn(q, k, v)  # k/v carry kv heads; the closure decides
-    attn = attn.reshape(b, t, qw).astype(cfg.dtype)
-    return attn @ kernel_of(blk["proj"], cfg.dtype), k, v
+    with part("attn_proj"):
+        qkv = y @ kernel_of(blk["qkv"], cfg.dtype)  # [B, T, qw + 2*kv*hd]
+        q = qkv[..., :qw].reshape(b, t, h, hd)
+        k = qkv[..., qw : qw + kv * hd].reshape(b, t, kv, hd)
+        v = qkv[..., qw + kv * hd :]
+        if cfg.qk_norm:
+            q = _rms_norm(q, blk["q_norm"]["scale"], cfg.dtype, cfg.norm_eps)
+            k = _rms_norm(k, blk["k_norm"]["scale"], cfg.dtype, cfg.norm_eps)
+        if cfg.rope:
+            q = _rope_of(cfg)(q, positions, cfg.rope_theta)
+            k = _rope_of(cfg)(k, positions, cfg.rope_theta)
+        v = v.reshape(b, t, kv, hd)
+    # k/v carry kv heads; the closure decides, and names its own parts
+    # (`attn_core`, `cache_write`)
+    attn = attn_fn(q, k, v)
+    with part("attn_proj"):
+        attn = attn.reshape(b, t, qw).astype(cfg.dtype)
+        return attn @ kernel_of(blk["proj"], cfg.dtype), k, v
 
 
 def _feed_forward(blk, cfg: LMConfig, y, experts, mesh):
@@ -974,7 +1011,7 @@ def _feed_forward(blk, cfg: LMConfig, y, experts, mesh):
     else the two-matrix `down(act(up y))`. A stack may hold both kinds
     (leading dense layers under expert layers): the block's own leaves
     decide."""
-    if "moe" in blk:
+    if "moe" in blk:  # names its own parts (`moe_*`)
         out, counts = expert_ffn(
             blk["moe"], y, cfg.dtype, cfg.experts_per_token,
             cfg.experts_first,
@@ -987,12 +1024,13 @@ def _feed_forward(blk, cfg: LMConfig, y, experts, mesh):
             experts["counts"].append(counts)
         return out
     act = _activation(cfg.activation)
-    if "gate" in blk:
-        y = act(y @ kernel_of(blk["gate"], cfg.dtype)) * (
-            y @ kernel_of(blk["up"], cfg.dtype))
-    else:
-        y = act(y @ kernel_of(blk["up"], cfg.dtype))
-    return y @ kernel_of(blk["down"], cfg.dtype)
+    with part("mlp"):
+        if "gate" in blk:
+            y = act(y @ kernel_of(blk["gate"], cfg.dtype)) * (
+                y @ kernel_of(blk["up"], cfg.dtype))
+        else:
+            y = act(y @ kernel_of(blk["up"], cfg.dtype))
+        return y @ kernel_of(blk["down"], cfg.dtype)
 
 
 def _apply_block(
@@ -1032,13 +1070,18 @@ def _apply_block(
     "windows", where the caller put one, the windows it ran past its
     first (`expert_ffn`; nothing where its shapes give one window).
     """
+    # a mixer's norm goes by the mixer's (first) part
+    ffn = "moe_route" if "moe" in blk else "mlp"
     if kind is None:
-        y = _rms_norm(x, blk["ln_attn"]["scale"], cfg.dtype, cfg.norm_eps)
+        with part("attn_proj"):
+            y = _rms_norm(x, blk["ln_attn"]["scale"], cfg.dtype, cfg.norm_eps)
         out, k, v = _attention(blk, cfg, y, positions, attn_fn, absorbed)
         x = x + out
-        y = _rms_norm(x, blk["ln_mlp"]["scale"], cfg.dtype, cfg.norm_eps)
+        with part(ffn):
+            y = _rms_norm(x, blk["ln_mlp"]["scale"], cfg.dtype, cfg.norm_eps)
         return x + _feed_forward(blk, cfg, y, experts, mesh), k, v
-    y = _rms_norm(x, blk["ln"]["scale"], cfg.dtype, cfg.norm_eps)
+    with part({"*": "attn_proj", "E": ffn}.get(kind, "ssm_proj")):
+        y = _rms_norm(x, blk["ln"]["scale"], cfg.dtype, cfg.norm_eps)
     if kind == "*":
         out, k, v = _attention(blk, cfg, y, positions, attn_fn)
         return x + out, k, v
@@ -1053,16 +1096,20 @@ def _lm_head(params: Dict[str, Any], cfg: LMConfig, x: jax.Array) -> jax.Array:
     TransformerLM's does; one stored in the model's compute dtype
     (`lm_spec`'s `param_dtype`) multiplies in it and accumulates in
     float32, so no float32 copy of it is ever made."""
-    x = _rms_norm(x, params["ln_out"]["scale"], cfg.dtype, cfg.norm_eps)
-    kern = params["lm_head"]["kernel"]
-    if not isinstance(kern, dict) and kern.dtype == cfg.dtype != jnp.float32:
-        return jnp.matmul(x, kern, preferred_element_type=jnp.float32)
-    return x.astype(jnp.float32) @ kernel_of(params["lm_head"], jnp.float32)
+    with part("head"):
+        x = _rms_norm(x, params["ln_out"]["scale"], cfg.dtype, cfg.norm_eps)
+        kern = params["lm_head"]["kernel"]
+        if (not isinstance(kern, dict)
+                and kern.dtype == cfg.dtype != jnp.float32):
+            return jnp.matmul(x, kern, preferred_element_type=jnp.float32)
+        return x.astype(jnp.float32) @ kernel_of(
+            params["lm_head"], jnp.float32)
 
 
 def _head(params: Dict[str, Any], cfg: LMConfig, x_last: jax.Array) -> jax.Array:
     """Final norm + lm head on [B, 1, d] -> [B, V] f32 logits."""
-    return _lm_head(params, cfg, x_last)[:, 0, :]
+    with part("head"):
+        return _lm_head(params, cfg, x_last)[:, 0, :]
 
 
 def decode_step(
@@ -1169,11 +1216,12 @@ def _write_rows(c: jax.Array, u: jax.Array, pos: jax.Array,
     dynamic_update_slice — a vmap over per-slot positions lowers to a
     scatter, and XLA scatters on TPU copy the whole operand (measured:
     the copy tripled decode's cache traffic)."""
-    for bi in range(c.shape[0]):
-        start = [bi] + [0] * (c.ndim - 1)
-        start[axis] = pos[bi]
-        c = jax.lax.dynamic_update_slice(c, u[bi : bi + 1], start)
-    return c
+    with part("cache_write"):
+        for bi in range(c.shape[0]):
+            start = [bi] + [0] * (c.ndim - 1)
+            start[axis] = pos[bi]
+            c = jax.lax.dynamic_update_slice(c, u[bi : bi + 1], start)
+        return c
 
 
 def _latent_rows(cfg: LMConfig, rows: jax.Array) -> jax.Array:
@@ -1201,21 +1249,23 @@ def _latent_cached(cfg: LMConfig, leaf: jax.Array, q: jax.Array,
     [B, Q, T] given) an einsum over the grid in float32, the oracle."""
     m = cfg.latent
     scale = m.key_width ** -0.5
-    leaf = _write_rows(leaf, _latent_rows(cfg, rows), pos, axis=2)
-    q = jnp.pad(q, ((0, 0),) * 3 + ((0, m.row_stride - m.row_width),))
-    if valid is None:
-        from ..ops.decode_attention import decode_attention
+    with part("cache_write"):
+        leaf = _write_rows(leaf, _latent_rows(cfg, rows), pos, axis=2)
+    with part("attn_core"):
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, m.row_stride - m.row_width),))
+        if valid is None:
+            from ..ops.decode_attention import decode_attention
 
-        return decode_attention(
-            q, leaf, None, lengths, scale=scale, v_width=m.kv_rank,
-            mask_block=mask_block), leaf
-    lat = leaf[:, 0].astype(jnp.float32)  # [B, T, W]
-    s = jnp.einsum("bqhw,btw->bhqt", q.astype(jnp.float32), lat) * scale
-    vmask = valid[:, None]
-    s = jnp.where(vmask, s, -1e30)
-    # zeros for an empty slot, as the kernel returns
-    p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
-    return jnp.einsum("bhqt,btc->bqhc", p, lat[..., :m.kv_rank]), leaf
+            return decode_attention(
+                q, leaf, None, lengths, scale=scale, v_width=m.kv_rank,
+                mask_block=mask_block), leaf
+        lat = leaf[:, 0].astype(jnp.float32)  # [B, T, W]
+        s = jnp.einsum("bqhw,btw->bhqt", q.astype(jnp.float32), lat) * scale
+        vmask = valid[:, None]
+        s = jnp.where(vmask, s, -1e30)
+        # zeros for an empty slot, as the kernel returns
+        p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
+        return jnp.einsum("bhqt,btc->bqhc", p, lat[..., :m.kv_rank]), leaf
 
 
 def batched_decode_step(
@@ -1249,7 +1299,8 @@ def batched_decode_step(
     hd = cfg.head_dim
     b = tokens.shape[0]
     grp = cfg.n_heads // cfg.kv_heads
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)[:, None, :]
+    with part("embed"):
+        x = params["embed"]["embedding"][tokens].astype(cfg.dtype)[:, None, :]
     positions = pos[:, None]  # [B, 1] — rope's per-example form
     # layout-generic (bf16 {k, v} or kv_quant {k_q, ...}): every K/V leaf
     # carries [B, KV, max_len, ...]
@@ -1292,50 +1343,55 @@ def batched_decode_step(
         def attn_fn(q, k, v, name=name):
             # k/v arrive [B, 1, KV, D]; the cache is head-major
             # (`_write_rows` on how the rows are written)
-            upd = functools.partial(_write_rows, pos=pos)
-            kh = jnp.swapaxes(k, 1, 2)  # [B, KV, 1, D]
-            vh = jnp.swapaxes(v, 1, 2)
-            if cfg.kv_quant:
-                kq, ks = _kv_quantize(kh)
-                vq, vs = _kv_quantize(vh)
-                lay = {
-                    "k_q": upd(cache[name]["k_q"], kq, axis=2),
-                    "k_s": upd(cache[name]["k_s"],
-                               jnp.swapaxes(ks, 2, 3), axis=3),
-                    "v_q": upd(cache[name]["v_q"], vq, axis=2),
-                    "v_s": upd(cache[name]["v_s"],
-                               jnp.swapaxes(vs, 2, 3), axis=3),
-                }
-                new_cache[name] = lay
-                if use_kernel:
-                    return kernel(
-                        q, lay["k_q"], lay["v_q"], lengths,
-                        lay["k_s"], lay["v_s"],
+            with part("cache_write"):
+                upd = functools.partial(_write_rows, pos=pos)
+                kh = jnp.swapaxes(k, 1, 2)  # [B, KV, 1, D]
+                vh = jnp.swapaxes(v, 1, 2)
+                if cfg.kv_quant:
+                    kq, ks = _kv_quantize(kh)
+                    vq, vs = _kv_quantize(vh)
+                    lay = {
+                        "k_q": upd(cache[name]["k_q"], kq, axis=2),
+                        "k_s": upd(cache[name]["k_s"],
+                                   jnp.swapaxes(ks, 2, 3), axis=3),
+                        "v_q": upd(cache[name]["v_q"], vq, axis=2),
+                        "v_s": upd(cache[name]["v_s"],
+                                   jnp.swapaxes(vs, 2, 3), axis=3),
+                    }
+                    new_cache[name] = lay
+                else:
+                    ck = upd(cache[name]["k"], kh.astype(cfg.dtype), axis=2)
+                    cv = upd(cache[name]["v"], vh.astype(cfg.dtype), axis=2)
+                    new_cache[name] = {"k": ck, "v": cv}
+            with part("attn_core"):
+                if cfg.kv_quant:
+                    if use_kernel:
+                        return kernel(
+                            q, lay["k_q"], lay["v_q"], lengths,
+                            lay["k_s"], lay["v_s"],
+                        )
+                    ck = _kv_dequant(
+                        lay["k_q"], jnp.swapaxes(lay["k_s"], 2, 3)
                     )
-                ck = _kv_dequant(
-                    lay["k_q"], jnp.swapaxes(lay["k_s"], 2, 3)
-                )
-                cv = _kv_dequant(
-                    lay["v_q"], jnp.swapaxes(lay["v_s"], 2, 3)
-                )
-            else:
-                ck = upd(cache[name]["k"], kh.astype(cfg.dtype), axis=2)
-                cv = upd(cache[name]["v"], vh.astype(cfg.dtype), axis=2)
-                new_cache[name] = {"k": ck, "v": cv}
-                if use_kernel:
+                    cv = _kv_dequant(
+                        lay["v_q"], jnp.swapaxes(lay["v_s"], 2, 3)
+                    )
+                elif use_kernel:
                     return kernel(q, ck, cv, lengths)
-            qg = q.astype(jnp.float32).reshape(b, 1, cfg.kv_heads, grp, hd)
-            s = jnp.einsum(
-                "bqkgd,bktd->bkgqt", qg, ck.astype(jnp.float32)
-            ) * (hd**-0.5)
-            vmask = valid[:, None, None, None, :]
-            s = jnp.where(vmask, s, -1e30)
-            # a live slot's p is already exactly 0 on dead rows; the
-            # select makes an EMPTY slot (all rows dead, softmax
-            # uniform) return zeros, as the kernel does
-            p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
-            attn = jnp.einsum("bkgqt,bktd->bqkgd", p, cv.astype(jnp.float32))
-            return attn.reshape(b, 1, cfg.n_heads, hd)
+                qg = q.astype(jnp.float32).reshape(
+                    b, 1, cfg.kv_heads, grp, hd)
+                s = jnp.einsum(
+                    "bqkgd,bktd->bkgqt", qg, ck.astype(jnp.float32)
+                ) * (hd**-0.5)
+                vmask = valid[:, None, None, None, :]
+                s = jnp.where(vmask, s, -1e30)
+                # a live slot's p is already exactly 0 on dead rows; the
+                # select makes an EMPTY slot (all rows dead, softmax
+                # uniform) return zeros, as the kernel does
+                p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
+                attn = jnp.einsum(
+                    "bkgqt,bktd->bqkgd", p, cv.astype(jnp.float32))
+                return attn.reshape(b, 1, cfg.n_heads, hd)
 
         x, _, _ = _apply_block(
             params[name], cfg, x, positions,
@@ -1410,7 +1466,8 @@ def batched_block_step(
         raise ValueError(
             "the multi-token cached forward cannot roll a state-space "
             "layer's state back; speculation and block diffusion need it to")
-    x = params["embed"]["embedding"][tokens].astype(cfg.dtype)  # [B,T,d]
+    with part("embed"):
+        x = params["embed"]["embedding"][tokens].astype(cfg.dtype)  # [B,T,d]
     max_len = cache_rows(cache)
     pos = jnp.minimum(pos, max_len - t)
     positions = pos[:, None] + jnp.arange(t)[None, :]  # [B, T] per-example
@@ -1455,49 +1512,54 @@ def batched_block_step(
         def attn_fn(q, k, v, name=name):
             # k/v arrive [B, T, KV, D]; write each slot's contiguous
             # [KV, T, D] block at its own start row (`_write_rows`)
-            upd = functools.partial(_write_rows, pos=pos)
-            kh = jnp.swapaxes(k, 1, 2)  # [B, KV, T, D]
-            vh = jnp.swapaxes(v, 1, 2)
-            if cfg.kv_quant:
-                kq, ks = _kv_quantize(kh)
-                vq, vs = _kv_quantize(vh)
-                lay = {
-                    "k_q": upd(cache[name]["k_q"], kq, axis=2),
-                    "k_s": upd(cache[name]["k_s"],
-                               jnp.swapaxes(ks, 2, 3), axis=3),
-                    "v_q": upd(cache[name]["v_q"], vq, axis=2),
-                    "v_s": upd(cache[name]["v_s"],
-                               jnp.swapaxes(vs, 2, 3), axis=3),
-                }
-                new_cache[name] = lay
-                if use_kernel:
-                    return kernel(q, lay["k_q"], lay["v_q"], lengths,
-                                  lay["k_s"], lay["v_s"])
-                ck = _kv_dequant(
-                    lay["k_q"], jnp.swapaxes(lay["k_s"], 2, 3)
-                )
-                cv = _kv_dequant(
-                    lay["v_q"], jnp.swapaxes(lay["v_s"], 2, 3)
-                )
-            else:
-                ck = upd(cache[name]["k"], kh.astype(cfg.dtype), axis=2)
-                cv = upd(cache[name]["v"], vh.astype(cfg.dtype), axis=2)
-                new_cache[name] = {"k": ck, "v": cv}
-                if use_kernel:
+            with part("cache_write"):
+                upd = functools.partial(_write_rows, pos=pos)
+                kh = jnp.swapaxes(k, 1, 2)  # [B, KV, T, D]
+                vh = jnp.swapaxes(v, 1, 2)
+                if cfg.kv_quant:
+                    kq, ks = _kv_quantize(kh)
+                    vq, vs = _kv_quantize(vh)
+                    lay = {
+                        "k_q": upd(cache[name]["k_q"], kq, axis=2),
+                        "k_s": upd(cache[name]["k_s"],
+                                   jnp.swapaxes(ks, 2, 3), axis=3),
+                        "v_q": upd(cache[name]["v_q"], vq, axis=2),
+                        "v_s": upd(cache[name]["v_s"],
+                                   jnp.swapaxes(vs, 2, 3), axis=3),
+                    }
+                    new_cache[name] = lay
+                else:
+                    ck = upd(cache[name]["k"], kh.astype(cfg.dtype), axis=2)
+                    cv = upd(cache[name]["v"], vh.astype(cfg.dtype), axis=2)
+                    new_cache[name] = {"k": ck, "v": cv}
+            with part("attn_core"):
+                if cfg.kv_quant:
+                    if use_kernel:
+                        return kernel(q, lay["k_q"], lay["v_q"], lengths,
+                                      lay["k_s"], lay["v_s"])
+                    ck = _kv_dequant(
+                        lay["k_q"], jnp.swapaxes(lay["k_s"], 2, 3)
+                    )
+                    cv = _kv_dequant(
+                        lay["v_q"], jnp.swapaxes(lay["v_s"], 2, 3)
+                    )
+                elif use_kernel:
                     return kernel(q, ck, cv, lengths)
-            # the oracle's route, in float32 as `batched_decode_step`'s
-            # (it widens the cache: what the kernel route never does)
-            qg = q.astype(jnp.float32).reshape(b, t, cfg.kv_heads, grp, hd)
-            s = jnp.einsum(
-                "bqkgd,bktd->bkgqt", qg, ck.astype(jnp.float32)
-            ) * (hd**-0.5)
-            vmask = valid[:, None, None, :, :]
-            s = jnp.where(vmask, s, -1e30)
-            # the select returns zeros for an empty slot (all rows
-            # dead, softmax uniform), as the kernel does
-            p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
-            attn = jnp.einsum("bkgqt,bktd->bqkgd", p, cv.astype(jnp.float32))
-            return attn.reshape(b, t, cfg.n_heads, hd)
+                # the oracle's route, in float32 as `batched_decode_step`'s
+                # (it widens the cache: what the kernel route never does)
+                qg = q.astype(jnp.float32).reshape(
+                    b, t, cfg.kv_heads, grp, hd)
+                s = jnp.einsum(
+                    "bqkgd,bktd->bkgqt", qg, ck.astype(jnp.float32)
+                ) * (hd**-0.5)
+                vmask = valid[:, None, None, :, :]
+                s = jnp.where(vmask, s, -1e30)
+                # the select returns zeros for an empty slot (all rows
+                # dead, softmax uniform), as the kernel does
+                p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
+                attn = jnp.einsum(
+                    "bkgqt,bktd->bqkgd", p, cv.astype(jnp.float32))
+                return attn.reshape(b, t, cfg.n_heads, hd)
 
         x, _, _ = _apply_block(
             params[name], cfg, x, positions,
@@ -1567,7 +1629,8 @@ def prefill(
     from ..ops.flash_attention import flash_attention
 
     b, tp = prompt.shape
-    x = params["embed"]["embedding"][prompt].astype(cfg.dtype)  # [B,Tp,d]
+    with part("embed"):
+        x = params["embed"]["embedding"][prompt].astype(cfg.dtype)  # [B,Tp,d]
     positions = jnp.arange(tp)
     pad = max_len - tp
     grp = cfg.n_heads // cfg.kv_heads
@@ -1583,10 +1646,11 @@ def prefill(
         # flash kernel is head-symmetric: broadcast GQA kv heads to
         # full heads for the prefill pass (the cache below keeps the
         # compact layout _apply_block returned)
-        if grp > 1:
-            k = jnp.repeat(k, grp, axis=2)
-            v = jnp.repeat(v, grp, axis=2)
-        return flash(q, k, v)
+        with part("attn_core"):
+            if grp > 1:
+                k = jnp.repeat(k, grp, axis=2)
+                v = jnp.repeat(v, grp, axis=2)
+            return flash(q, k, v)
 
     # rows' own lengths, where the caller gave them: a state-space
     # layer must not take a padded position into its state
@@ -1610,41 +1674,46 @@ def prefill(
         )
         if k is None:
             continue
-        if cfg.latent is not None:  # k: the rows to cache [B, Tp, W]
-            cache[name] = {"latent": jnp.pad(_latent_rows(cfg, k), pad4)}
-            continue
-        kh = jnp.swapaxes(k, 1, 2)  # [B, KV, Tp, D] — cache layout
-        vh = jnp.swapaxes(v, 1, 2)
-        if cfg.kv_quant:
-            kq, ks = _kv_quantize(kh)
-            vq, vs = _kv_quantize(vh)
-            padT = ((0, 0), (0, 0), (0, 0), (0, pad))  # scales: T on lanes
-            cache[f"block_{i}"] = {
-                "k_q": jnp.pad(kq, pad4),
-                "k_s": jnp.pad(jnp.swapaxes(ks, 2, 3), padT),
-                "v_q": jnp.pad(vq, pad4),
-                "v_s": jnp.pad(jnp.swapaxes(vs, 2, 3), padT),
-            }
-        else:
-            cache[f"block_{i}"] = {
-                "k": jnp.pad(kh.astype(cfg.dtype), pad4),
-                "v": jnp.pad(vh.astype(cfg.dtype), pad4),
-            }
+        with part("cache_write"):  # the call's rows in the cache's layout
+            if cfg.latent is not None:  # k: the rows to cache [B, Tp, W]
+                cache[name] = {
+                    "latent": jnp.pad(_latent_rows(cfg, k), pad4)}
+                continue
+            kh = jnp.swapaxes(k, 1, 2)  # [B, KV, Tp, D] — cache layout
+            vh = jnp.swapaxes(v, 1, 2)
+            if cfg.kv_quant:
+                kq, ks = _kv_quantize(kh)
+                vq, vs = _kv_quantize(vh)
+                # scales: T on lanes
+                padT = ((0, 0), (0, 0), (0, 0), (0, pad))
+                cache[f"block_{i}"] = {
+                    "k_q": jnp.pad(kq, pad4),
+                    "k_s": jnp.pad(jnp.swapaxes(ks, 2, 3), padT),
+                    "v_q": jnp.pad(vq, pad4),
+                    "v_s": jnp.pad(jnp.swapaxes(vs, 2, 3), padT),
+                }
+            else:
+                cache[f"block_{i}"] = {
+                    "k": jnp.pad(kh.astype(cfg.dtype), pad4),
+                    "v": jnp.pad(vh.astype(cfg.dtype), pad4),
+                }
 
     if not head:
         return None, cache
-    if logits_index is None:
-        x_last = x[:, -1:]
-    elif jnp.ndim(logits_index) == 0:
-        x_last = jax.lax.dynamic_slice_in_dim(x, logits_index, 1, axis=1)
-    else:
-        # per-row indices: a batched-placement prefill packs prompts
-        # of different true lengths into one bucket, so each row reads
-        # its own last-prompt position (LMServer group placement)
-        x_last = jax.vmap(
-            lambda row, i: jax.lax.dynamic_slice_in_dim(row, i, 1, axis=0)
-        )(x, logits_index.astype(jnp.int32))
-    return _head(params, cfg, x_last), cache
+    with part("head"):
+        if logits_index is None:
+            x_last = x[:, -1:]
+        elif jnp.ndim(logits_index) == 0:
+            x_last = jax.lax.dynamic_slice_in_dim(x, logits_index, 1, axis=1)
+        else:
+            # per-row indices: a batched-placement prefill packs prompts
+            # of different true lengths into one bucket, so each row reads
+            # its own last-prompt position (LMServer group placement)
+            x_last = jax.vmap(
+                lambda row, i: jax.lax.dynamic_slice_in_dim(
+                    row, i, 1, axis=0)
+            )(x, logits_index.astype(jnp.int32))
+        return _head(params, cfg, x_last), cache
 
 
 def _sample(logits, rng, temperature: float, top_k: Optional[int]):

@@ -103,6 +103,7 @@ from .generate import (
     heads_axis,
     init_cache,
     moe_layout,
+    part,
     prefill,
     state_bytes,
 )
@@ -114,11 +115,26 @@ log = logging.getLogger(__name__)
 # updates are host-side O(1) dict writes OUTSIDE the jitted chunk /
 # prefill programs, at per-DISPATCH granularity (a step covers
 # chunk × slots tokens), so the decode path's device rate is
-# unaffected. Handles are bound once at import: no name lookups on
-# the hot path. Beside them the serve loop records loop spans
+# unaffected: a dispatch makes a fixed handful of CALLS, whatever it
+# holds. What a dispatch counts a step a layer (an expert model's
+# routing) goes in by one `Histogram.observe_many` a histogram
+# (`_note_routing`), never by a loop of `observe`: that loop ran after
+# the readback had drained the device and before anything new was
+# enqueued, and was most of one cell's device idle (PERF.md, PR 38).
+# Handles are bound once at import: no name lookups on the hot path.
+# Beside them the serve loop records loop spans
 # (tracing.Tracer.loop_span: one per dispatch PHASE, never per token
 # or per slot), which say where inside a dispatch the host's time went
-# and which the JAX profiler shows as `dml.lm_*` annotations.
+# and which the JAX profiler shows as `dml.lm_*` annotations. The
+# serving thread's time is tiled by them: `lm_step` (children
+# `lm_dispatch`, `lm_pack`, `lm_readback`, `lm_route`, `lm_deliver`,
+# `lm_place`), `lm_submit`, `lm_idle` and, between them, `lm_turn`.
+# Across them lies `lm_exposed` (= `lm_server_exposed_seconds`): from
+# where the thread returns from blocking on the NEWEST program it
+# enqueued (the device's queue is then empty) to where the next
+# program has been enqueued, or the thread goes idle: the device's
+# exposure to the host over a whole run, with no profiler
+# (`LMServer._drained`, `_fed`).
 _M_REQS = METRICS.counter(
     "lm_server_requests_total", "requests submitted to the slot grid")
 _M_REQS_DONE = METRICS.counter(
@@ -169,6 +185,12 @@ _M_READBACK = METRICS.histogram(
     "lm_server_readback_seconds",
     "blocking device->host readbacks (the serve loop's only stalls); "
     "a decode dispatch's excludes issuing the pack")
+_M_EXPOSED = METRICS.histogram(
+    "lm_server_exposed_seconds",
+    "stretches the serving thread left the device with nothing queued: "
+    "from its return from a blocking wait on the newest program it "
+    "enqueued to the next program enqueued, or to going idle (the "
+    "`lm_exposed` loop span, one observation a span)")
 _M_DELIVER = METRICS.histogram(
     "lm_server_deliver_seconds",
     "a dispatch's token delivery: first tokens, every request's "
@@ -655,6 +677,9 @@ class LMServer:
         # or its slot is placed again before one.
         self._firsts_dev = jnp.zeros(max_slots, jnp.int32)
         self._pending_first: Dict[int, _Request] = {}
+        # (monotonic time, which wait) since when the device has had
+        # nothing queued, or None while it has work (`_drained`, `_fed`)
+        self._exposed: Optional[Tuple[float, str]] = None
         self._queue: List[_Request] = []
         self._done: Dict[int, _Request] = {}
         self._rid = 0
@@ -688,9 +713,8 @@ class LMServer:
         # the same (few, power-of-two) kp variants the group prefill
         # itself mints, not one per slot assignment
         self._merge_vec = jax.jit(
-            lambda vec, vals, slot_map: jnp.where(
-                slot_map >= 0, vals[jnp.clip(slot_map, 0, None)], vec
-            ),
+            lambda vec, vals, slot_map: self._merge_impl(
+                vec, vals, slot_map, False),
             donate_argnums=(0,),
         )
         # per-row first-token sampling for a placement group (same
@@ -749,9 +773,8 @@ class LMServer:
             self._diffuse_fn = jax.jit(
                 self._diffuse_impl, donate_argnums=(1, 2, 3))
             self._merge_blk = jax.jit(
-                lambda blk, vals, slot_map: jnp.where(
-                    (slot_map >= 0)[:, None],
-                    vals[jnp.clip(slot_map, 0, None)], blk),
+                lambda blk, vals, slot_map: self._merge_impl(
+                    blk, vals, slot_map, True),
                 donate_argnums=(0,),
             )
         _M_SLOTS_TOTAL.set(max_slots)
@@ -1014,34 +1037,46 @@ class LMServer:
         with the state the prefill took at the row's own length."""
         # generic over the cache layout (bf16 {k, v} or kv_quant
         # {k_q, k_s, v_q, v_s}) — every leaf copies the same way
-        if self._bucket_rows:
-            # the prefilled rows alone ([KV, bucket, D], written from
-            # the slot's row 0): what the last occupant left past them
-            # lies at or past this request's first block, where nothing
-            # attends a row before a forward of this request wrote it
+        with part("insert"):
+            if self._bucket_rows:
+                # the prefilled rows alone ([KV, bucket, D], written from
+                # the slot's row 0): what the last occupant left past
+                # them lies at or past this request's first block, where
+                # nothing attends a row before a forward of this request
+                # wrote it
+                return {
+                    name: {
+                        key: jax.lax.dynamic_update_slice(
+                            kv[key],
+                            jax.lax.dynamic_index_in_dim(
+                                pcache[name][key], row, axis=0),
+                            (slot,) + (0,) * (kv[key].ndim - 1),
+                        )
+                        for key in kv
+                    }
+                    for name, kv in cache.items()
+                }
             return {
                 name: {
-                    key: jax.lax.dynamic_update_slice(
-                        kv[key],
+                    key: kv[key].at[slot].set(
                         jax.lax.dynamic_index_in_dim(
-                            pcache[name][key], row, axis=0),
-                        (slot,) + (0,) * (kv[key].ndim - 1),
+                            pcache[name][key], row, axis=0, keepdims=False
+                        )
                     )
                     for key in kv
                 }
                 for name, kv in cache.items()
             }
-        return {
-            name: {
-                key: kv[key].at[slot].set(
-                    jax.lax.dynamic_index_in_dim(
-                        pcache[name][key], row, axis=0, keepdims=False
-                    )
-                )
-                for key in kv
-            }
-            for name, kv in cache.items()
-        }
+
+    @staticmethod
+    def _merge_impl(old, vals, slot_map, rows: bool):
+        """`old` [max_slots(, B)] with slot s taking `vals[slot_map[s]]`
+        where `slot_map[s]` >= 0 (`rows`: whole rows of a matrix): the
+        placement-time write of cur / pos / firsts / a first block."""
+        with part("insert"):
+            take = slot_map >= 0
+            return jnp.where(take[:, None] if rows else take,
+                             vals[jnp.clip(slot_map, 0, None)], old)
 
     def _sample_slots(self, logits, rid, write_pos):
         """Per-slot sampling: the token that will occupy position
@@ -1049,18 +1084,19 @@ class LMServer:
         fold_in(fold_in(base, rid), write_pos) — its own
         counter-derived stream, so a request's sampled output does not
         depend on what else is in the batch (advisor finding, r2)."""
-        if self.temperature == 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        keys = jax.vmap(
-            lambda r, p: jax.random.fold_in(
-                jax.random.fold_in(self._base_rng, r), p
-            )
-        )(rid, write_pos)
-        return jax.vmap(
-            lambda k, lg: _sample(
-                lg[None], k, self.temperature, self.top_k
-            )[0]
-        )(keys, logits)
+        with part("head"):
+            if self.temperature == 0.0:
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            keys = jax.vmap(
+                lambda r, p: jax.random.fold_in(
+                    jax.random.fold_in(self._base_rng, r), p
+                )
+            )(rid, write_pos)
+            return jax.vmap(
+                lambda k, lg: _sample(
+                    lg[None], k, self.temperature, self.top_k
+                )[0]
+            )(keys, logits)
 
     def _chunk_impl(self, params, cache, cur, pos, rid):
         """`chunk` batched decode steps in one dispatch. Per-slot pos
@@ -1103,10 +1139,12 @@ class LMServer:
             nxt = self._sample_slots(logits, rid, pos_c + 1)
             if experts is None:
                 return (cache, nxt, pos_c + 1), (nxt,)
-            numbers = _routing_numbers(jnp.stack(experts["counts"]), lo, hi)
-            if experts["windows"]:  # a sixth: windows past the first
-                numbers.append(jnp.stack(experts["windows"]))
-            routed = jnp.stack(numbers, -1).astype(jnp.int32)
+            with part("moe_route"):  # the routing's numbers, a layer
+                numbers = _routing_numbers(
+                    jnp.stack(experts["counts"]), lo, hi)
+                if experts["windows"]:  # a sixth: windows past the first
+                    numbers.append(jnp.stack(experts["windows"]))
+                routed = jnp.stack(numbers, -1).astype(jnp.int32)
             return (cache, nxt, pos_c + 1), (nxt, routed)
 
         (cache, cur, pos), out = jax.lax.scan(
@@ -1156,37 +1194,41 @@ class LMServer:
         def block(carry, _):
             cache, x, pos = carry
             pos_c = jnp.minimum(pos, last)
-            masked0 = (x == mask_id).sum(-1)  # [slots]
-            fixed_at = jnp.where(x == mask_id, -1, 0).astype(jnp.int32)
+            with part("diffuse_select"):
+                masked0 = (x == mask_id).sum(-1)  # [slots]
+                fixed_at = jnp.where(x == mask_id, -1, 0).astype(jnp.int32)
             routed = []
             for s in range(1, s_n + 1):
                 logits, cache, counts = forward(cache, x, pos_c, True)
                 routed.append(counts)
-                best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                conf = jnp.exp(
-                    jnp.max(logits, axis=-1)
-                    - jax.nn.logsumexp(logits, axis=-1))
-                masked = x == mask_id
-                conf = jnp.where(masked, conf, -1.0)
-                # rank 0 = the most confident masked position
-                ahead = (conf[:, None, :] > conf[:, :, None]) | (
-                    (conf[:, None, :] == conf[:, :, None])
-                    & (where[:, None, :] < where[:, :, None]))
-                rank = ahead.sum(-1)
-                n_s = masked0 // s_n + (s <= masked0 % s_n)
-                fix = masked & (rank < n_s[:, None])
-                x = jnp.where(fix, best, x)
-                fixed_at = jnp.where(fix, s, fixed_at)
+                with part("diffuse_select"):
+                    best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    conf = jnp.exp(
+                        jnp.max(logits, axis=-1)
+                        - jax.nn.logsumexp(logits, axis=-1))
+                    masked = x == mask_id
+                    conf = jnp.where(masked, conf, -1.0)
+                    # rank 0 = the most confident masked position
+                    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                        (conf[:, None, :] == conf[:, :, None])
+                        & (where[:, None, :] < where[:, :, None]))
+                    rank = ahead.sum(-1)
+                    n_s = masked0 // s_n + (s <= masked0 % s_n)
+                    fix = masked & (rank < n_s[:, None])
+                    x = jnp.where(fix, best, x)
+                    fixed_at = jnp.where(fix, s, fixed_at)
             _, cache, counts = forward(cache, x, pos_c, False)
             routed.append(counts)
-            nxt = jnp.full_like(x, mask_id)
+            with part("diffuse_select"):
+                nxt = jnp.full_like(x, mask_id)
             return (cache, nxt, pos_c + b), (x, fixed_at, jnp.stack(routed))
 
         (cache, blk, pos), (toks, fixed_at, routed) = jax.lax.scan(
             block, (cache, blk, pos), None,
             length=self.blocks_per_dispatch)
-        packed = jnp.concatenate(
-            [toks.ravel(), fixed_at.ravel(), routed.ravel()])
+        with part("pack"):
+            packed = jnp.concatenate(
+                [toks.ravel(), fixed_at.ravel(), routed.ravel()])
         return cache, blk, pos, packed
 
     def _propose_impl(self, draft_params, draft_cache, cur, pos):
@@ -1208,7 +1250,8 @@ class LMServer:
             logits, cache = batched_decode_step(
                 draft_params, cfg, cache, tok, pc, mesh=self._mesh
             )
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with part("head"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (cache, nxt, pc + 1), nxt
 
         (draft_cache, _, _), d = jax.lax.scan(
@@ -1253,12 +1296,13 @@ class LMServer:
         )
         # g[:, i] = target-greedy token for position start+i+1 (the
         # argmax after consuming inputs[:, i])
-        g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, k+1]
-        match = (d_toks == g[:, :k]).astype(jnp.int32)
-        a = jnp.sum(jnp.cumprod(match, axis=1), axis=1)  # [B] 0..k
-        c = jnp.minimum(a + 1, k)
-        cur2 = jnp.take_along_axis(g, (c - 1)[:, None], axis=1)[:, 0]
-        return cache, cur2, start + c, g[:, :k], a
+        with part("head"):  # the greedy tokens and what of them commits
+            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, k+1]
+            match = (d_toks == g[:, :k]).astype(jnp.int32)
+            a = jnp.sum(jnp.cumprod(match, axis=1), axis=1)  # [B] 0..k
+            c = jnp.minimum(a + 1, k)
+            cur2 = jnp.take_along_axis(g, (c - 1)[:, None], axis=1)[:, 0]
+            return cache, cur2, start + c, g[:, :k], a
 
     # -- public API ----------------------------------------------------
 
@@ -1457,6 +1501,7 @@ class LMServer:
         self.cache = self._insert(
             self.cache, pcache, jnp.int32(slot), jnp.int32(0)
         )
+        self._fed()
         slot_map = np.full(self.max_slots, -1, np.int32)
         slot_map[slot] = 0
         sm = jnp.asarray(slot_map)
@@ -1503,6 +1548,29 @@ class LMServer:
             self._spec.draft_cache, pcache, jnp.int32(slot),
             jnp.int32(0),
         )
+
+    def _drained(self, after: str) -> None:
+        """The serving thread has just returned from blocking on the
+        NEWEST program it enqueued (`after`: `readback` a dispatch's
+        packed readback, `firsts` a stray read of the first tokens,
+        `insert_wait` a latent prefill group waiting out the last
+        group's inserts): whatever it enqueued before is done too, so
+        the device's queue is empty from here until `_fed`."""
+        if self._exposed is None:
+            self._exposed = (time.monotonic(), after)
+
+    def _fed(self) -> None:
+        """A program has been enqueued (or the driver goes idle, the
+        run ends: no work is no exposure): the stretch since `_drained`
+        is one `lm_exposed` span and one observation of
+        `lm_server_exposed_seconds`. Recorded from its two readings: it
+        runs across spans and is no frame of its own."""
+        if self._exposed is None:
+            return
+        (t0, after), self._exposed = self._exposed, None
+        now = time.monotonic()
+        TRACER.loop_record("lm_exposed", t0, now, after=after)
+        _M_EXPOSED.observe(now - t0)
 
     def _place_waiting(self, parent: Any = None) -> None:
         """Free slots take queued requests, recorded as one `lm_place`
@@ -1626,10 +1694,12 @@ class LMServer:
                 # is for the last group's inserts; the host's few ms of
                 # preparing this one are then the device's idle
                 jax.block_until_ready(self.cache)
+                self._drained("insert_wait")
             logits, pcache, *further = self._prefill(
                 self.params, jnp.asarray(padded),
                 jnp.asarray(tps - 1),
             )
+            self._fed()
             if further:  # rides the next packed readback
                 self._windows_dev = self._windows_dev + further[0]
             for row, (slot, req) in enumerate(grp):
@@ -1790,6 +1860,7 @@ class LMServer:
         firsts = self._take_firsts()
         with TRACER.loop_span("lm_readback", arrays=1) as rb:
             vals = np.asarray(self._firsts_dev)
+        self._drained("firsts")
         _M_READBACK.observe(rb.m1 - rb.m0)
         self._distribute_firsts(firsts, vals, 0)
         self.tokens_delivered += len(firsts)
@@ -1807,21 +1878,23 @@ class LMServer:
             self._place_waiting()
             if not any(r is not None for r in self._slot_req):
                 return
-        occupancy = sum(1 for r in self._slot_req if r is not None)
-        _M_OCCUPANCY.observe(occupancy)
-        mode, dispatch = (
-            ("diffusion", self._diffuse_step) if self.diffusion is not None
-            else ("spec", self._spec_step) if self._use_spec()
-            else ("chunk", self._chunk_step))
         # `waiting`: requests queued without a slot as the dispatch is
         # issued (the grid has a backlog to refill from)
         with TRACER.loop_span(
-            "lm_step", occupancy=occupancy, mode=mode,
-            waiting=len(self._queue),
+            "lm_step", waiting=len(self._queue),
             # latent attention's form in every cached step: the cached
             # rows attended as they are
             **({"attn": "absorbed"} if self.cfg.latent is not None else {}),
         ) as span:
+            # the dispatch's own reckoning is the span's self time
+            occupancy = sum(1 for r in self._slot_req if r is not None)
+            _M_OCCUPANCY.observe(occupancy)
+            mode, dispatch = (
+                ("diffusion", self._diffuse_step)
+                if self.diffusion is not None
+                else ("spec", self._spec_step) if self._use_spec()
+                else ("chunk", self._chunk_step))
+            span.label(occupancy=occupancy, mode=mode)
             dispatch(span)
         _M_STEP.observe(span.m1 - span.m0)
 
@@ -1879,6 +1952,7 @@ class LMServer:
                 sp.draft_params, sp.draft_cache,
                 self._cur_dev, self._pos_dev,
             )
+            self._fed()
         else:
             # host-side proposals: shipped drafts first (consumed
             # once), then the proposer callable for the rest. Slots
@@ -1913,6 +1987,7 @@ class LMServer:
                 self.params, self.cache, self._cur_dev, self._pos_dev,
                 d_toks,
             )
+            self._fed()
         packed = self._read_packed(
             step, [jnp.ravel(toks), acc, self._firsts_dev])
         n = b * k
@@ -1992,9 +2067,11 @@ class LMServer:
         if self._windows_dev is not None:
             arrays = arrays + [self._windows_dev]
         with TRACER.loop_span("lm_pack", step, arrays=len(arrays)) as pack:
-            packed = jnp.concatenate(arrays)
+            with part("pack"):
+                packed = jnp.concatenate(arrays)
         with TRACER.loop_span("lm_readback", step) as readback:
             out = np.asarray(packed)
+        self._drained("readback")
         _M_PACK.observe(pack.m1 - pack.m0)
         _M_READBACK.observe(readback.m1 - readback.m0)
         if self._windows_dev is not None:
@@ -2053,6 +2130,7 @@ class LMServer:
                     self.params, self.cache, self._cur_dev, self._pos_dev,
                     jnp.asarray(self.rid_vec),
                 ))
+            self._fed()
         # reckoned while the device works, before delivery moves `emitted`
         live, read, grid = self._kv_rows()
         _M_KV_LIVE.inc(live)
@@ -2072,8 +2150,10 @@ class LMServer:
         # of each request, the placement-time first covers the rest)
         first_n = len(firsts)
         if routed:
-            self._note_routing(step, packed[n + self.max_slots:].reshape(
-                self.chunk, self._routed[0], -1))
+            with TRACER.loop_span("lm_route", step):
+                self._note_routing(
+                    step, packed[n + self.max_slots:].reshape(
+                        self.chunk, self._routed[0], -1))
         with TRACER.loop_span("lm_deliver", step) as deliver:
             toks = packed[:n].reshape(self.chunk, self.max_slots)
             # snapshot occupancy BEFORE any deliver() fires user
@@ -2126,10 +2206,12 @@ class LMServer:
                 self.params, self.cache, self._blk_dev, self._pos_dev,
                 jnp.asarray(self.rid_vec),
             )
+            self._fed()
         with TRACER.loop_span("lm_pack", step, arrays=1) as pack:
             packed.copy_to_host_async()
         with TRACER.loop_span("lm_readback", step) as readback:
             out = np.asarray(packed)
+        self._drained("readback")
         _M_PACK.observe(pack.m1 - pack.m0)
         _M_READBACK.observe(readback.m1 - readback.m0)
         n = r_n * self.max_slots * b
@@ -2168,8 +2250,9 @@ class LMServer:
         layers, e = self._routed
         if layers:
             lo, hi = self._held
-            self._note_routing(step, np.stack(_routing_numbers(
-                out[2 * n :].reshape(-1, e), lo, hi), -1))
+            with TRACER.loop_span("lm_route", step):
+                self._note_routing(step, np.stack(_routing_numbers(
+                    out[2 * n :].reshape(-1, e), lo, hi), -1))
         step.label(forwards=r_n * (s_n + 1), tokens_fixed=delivered,
                    blocks_committed=blocks)
         self._finish_step(step, delivered, 0)
@@ -2196,10 +2279,10 @@ class LMServer:
         load = busiest * self._routed[1] / total
         _M_MOE_HELD.inc(float(held.sum()))
         _M_MOE_ABSENT.inc(float((total - held).sum()))
-        for t, th, m in zip(touched, touched_held, load):
-            _M_MOE_TOUCHED.observe(float(t))
-            _M_MOE_TOUCHED_HELD.observe(float(th))
-            _M_MOE_LOAD.observe(float(m))
+        # one call a histogram a dispatch, whatever the steps x layers
+        _M_MOE_TOUCHED.observe_many(touched)
+        _M_MOE_TOUCHED_HELD.observe_many(touched_held)
+        _M_MOE_LOAD.observe_many(load)
         step.label(
             experts_touched=round(float(touched.mean()), 3),
             experts_touched_held=round(float(touched_held.mean()), 3),
@@ -2261,11 +2344,15 @@ class LMServer:
         if rids is None:
             while self.has_work():
                 self.step()
-            return self.take_done()
+            done = self.take_done()
+            self._fed()  # nothing left to feed the device with
+            return done
         want = set(rids)
         while (want - set(self._done)) and self.has_work():
             self.step()
         self._flush_firsts()  # a wanted budget-1 rid may have no step
+        if not self.has_work():
+            self._fed()
         out = {}
         for rid in want:
             r = self._done.pop(rid, None)
@@ -2460,15 +2547,29 @@ class LMDriver:
 
     def _loop_inner(self) -> None:
         srv = self.server
+        # the thread's time is tiled by loop spans: `lm_idle`,
+        # `lm_submit`, `lm_step` (the server's) and, from the end of one
+        # of them to the start of the next, `lm_turn`: finished requests
+        # to their tickets, the tickets' events, the locks
+        turn: Any = None
+
+        def turned() -> None:
+            if turn is not None:
+                turn.end()
+
         while True:
             with self._cv:
                 if not (self._incoming or srv.has_work() or self._stop):
+                    turned()
+                    srv._fed()  # no work is no exposure of the device
                     with TRACER.loop_span("lm_idle"):
                         while not (
                             self._incoming or srv.has_work() or self._stop
                         ):
                             self._cv.wait()
+                    turn = TRACER.loop_span("lm_turn")
                 if self._stop and not self._incoming and not srv.has_work():
+                    turned()
                     return
                 new = self._incoming
                 self._incoming = []
@@ -2478,6 +2579,7 @@ class LMDriver:
             # the grid
             with self._server_lock:
                 if new:
+                    turned()
                     with TRACER.loop_span(
                         "lm_submit", tickets=len(new),
                         requests=sum(len(t.prompts) for t in new),
@@ -2485,8 +2587,11 @@ class LMDriver:
                         span.label(ticket_wait_s=round(sum(
                             span.m0 - t.t_queued for t in new), 6))
                         self._submit_tickets(new, span)
+                    turn = TRACER.loop_span("lm_turn")
                 if srv.has_work():
+                    turned()
                     srv.step()
+                    turn = TRACER.loop_span("lm_turn")
                     with self._cv:
                         self.steps += 1
                 done = srv.take_done()

@@ -27,7 +27,12 @@ one metric object fans out into per-label-set children. Updates are
 host-side, O(1), lock-protected dict writes — they live OUTSIDE any
 jitted device step, so instrumentation cannot perturb a compiled
 program (the continuous-batching decode path updates a handful of
-counters per CHUNK dispatch, not per token).
+counters per CHUNK dispatch, not per token). The rule counts CALLS a
+dispatch: where a dispatch holds many observations of one histogram
+(an expert model's routing, a step a layer), they go in by ONE
+``Histogram.observe_many`` (one lock, one ``searchsorted``, one
+``bincount``; what a loop of ``observe`` would leave), never by a
+loop on the serving thread.
 
 Reference C1–C5 -> registry map
 -------------------------------
@@ -47,7 +52,12 @@ Reference C1–C5 -> registry map
 
 Beyond the reference (net-new subsystems get the same treatment):
 ``lm_server_*`` (queue wait, prefill dispatch, per-step decode tokens,
-slot occupancy, compile events, readback stalls), ``worker_*``
+slot occupancy, compile events, readback stalls, and
+``lm_server_exposed_seconds``: each stretch the serving thread left
+the device with nothing queued, the `lm_exposed` loop span's length;
+beside it the loop spans `lm_route` (a dispatch's routing into the
+``moe_*`` counters) and `lm_turn` (the driver thread between two
+dispatches) close the thread's account, tracing.py), ``worker_*``
 (fetch/infer/put stage timings, decode-cache hits),
 ``jobs_pipeline_depth`` / ``jobs_depth_*`` (the probe-adaptive
 worker-pipelining controller: depth in force, per-phase probe-rate
@@ -125,9 +135,11 @@ this module-global registry; snapshots carry the pid and
 totals equal the (shared) registry instead of multiplying by the node
 count, while real one-process-per-node deployments sum normally.
 
-Also here, unchanged from the seed: ``profile()`` (jax.profiler trace
-context) and ``jsonl_logging()``. Wall-clock spans live in ONE place,
-``tracing.TRACER`` (request spans and serve-loop spans).
+Also here, unchanged from the seed: ``jsonl_logging()``. Wall-clock
+spans live in ONE place, ``tracing.TRACER`` (request spans and
+serve-loop spans); a device trace is started and stopped by the CLI's
+``profile trace start|stop`` and read by ``tracing.read_profile``
+(``profile trace read``).
 
 Metric map (lint-enforced)
 --------------------------
@@ -188,6 +200,7 @@ line when you add the metric.
     lm_server_decode_kv_rows_total   decode cache rows by kind= live|read|grid
     lm_server_decode_tokens_total    tokens decoded (all slots)
     lm_server_deliver_seconds        a dispatch's token delivery + callbacks
+    lm_server_exposed_seconds        device left with nothing queued, a stretch
     lm_server_blocks_committed_total block-diffusion blocks committed
     lm_server_first_token_seconds    placement -> first token value on host
     lm_server_forwards_total         block-diffusion forwards by kind= denoise|commit
@@ -290,7 +303,6 @@ line when you add the metric.
 from __future__ import annotations
 
 import bisect
-import contextlib
 import json
 import logging
 import math
@@ -298,7 +310,9 @@ import os
 import threading
 import time
 import weakref
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 # ----------------------------------------------------------------------
 # typed metrics registry
@@ -376,17 +390,23 @@ class _GaugeChild(_Child):
 
 
 class _HistChild(_Child):
+    def _state(self) -> List[Any]:
+        """This series' state (made on first use; call under the lock):
+        [count, sum, min, max, bucket_counts], the last bucket the +Inf
+        overflow."""
+        m = self._m
+        st = m._values.get(self._key)
+        if st is None:
+            st = m._values[self._key] = [
+                0, 0.0, math.inf, -math.inf, [0] * (len(m.edges) + 1)
+            ]
+        return st
+
     def observe(self, v: float) -> None:
         m = self._m
         v = float(v)
         with m._lock:
-            st = m._values.get(self._key)
-            if st is None:
-                # [count, sum, min, max, bucket_counts]; the last
-                # bucket is the +Inf overflow
-                st = m._values[self._key] = [
-                    0, 0.0, math.inf, -math.inf, [0] * (len(m.edges) + 1)
-                ]
+            st = self._state()
             st[0] += 1
             st[1] += v
             if v < st[2]:
@@ -394,6 +414,28 @@ class _HistChild(_Child):
             if v > st[3]:
                 st[3] = v
             st[4][bisect.bisect_left(m.edges, v)] += 1
+
+    def observe_many(self, values: Any) -> None:
+        m = self._m
+        v = np.asarray(values, np.float64).ravel()
+        if not v.size:
+            return
+        # the buckets a loop of `observe` would have chosen one by one
+        hits = np.bincount(
+            np.searchsorted(m._edge_array, v, side="left"),
+            minlength=len(m.edges) + 1)
+        with m._lock:
+            st = self._state()
+            st[0] += int(v.size)
+            # left to right from the running sum, as the loop adds
+            # (`np.sum` pairs its terms and can end a digit away)
+            st[1] = float(np.add.accumulate(
+                np.concatenate(([st[1]], v)))[-1])
+            st[2] = min(st[2], float(v.min()))
+            st[3] = max(st[3], float(v.max()))
+            buckets = st[4]
+            for i in np.flatnonzero(hits):
+                buckets[i] += int(hits[i])
 
 
 class _Metric:
@@ -467,9 +509,19 @@ class Histogram(_Metric):
         if list(edges) != sorted(edges) or len(set(edges)) != len(edges):
             raise ValueError(f"{name}: bucket edges must strictly increase")
         self.edges = edges
+        self._edge_array = np.asarray(edges, np.float64)
 
     def observe(self, v: float, **labels: Any) -> None:
         self.labels(**labels).observe(v)
+
+    def observe_many(self, values: Any, **labels: Any) -> None:
+        """Every value of `values` (any array-like of numbers) observed
+        at once: one lock, one `searchsorted` and one `bincount`, and
+        count, sum, min, max and every bucket exactly what a loop of
+        `observe` over them leaves. For a caller that holds a
+        dispatch's worth of observations (a step a layer of an expert
+        model): the per-dispatch rule counts CALLS, not values."""
+        self.labels(**labels).observe_many(values)
 
 
 class MetricsRegistry:
@@ -871,22 +923,8 @@ def strip_buckets(snap: Dict[str, Any]) -> Dict[str, Any]:
 
 
 # ----------------------------------------------------------------------
-# jax profiling + JSONL logging (seed surface)
+# JSONL logging (seed surface)
 # ----------------------------------------------------------------------
-
-
-@contextlib.contextmanager
-def profile(logdir: str) -> Iterator[None]:
-    """Capture a jax.profiler trace (view with TensorBoard's profile
-    plugin or Perfetto). Wrap a few representative steps, not a whole
-    run — traces are large."""
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 class _JsonFormatter(logging.Formatter):

@@ -296,6 +296,8 @@ def decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, rows, dv), jnp.float32),
         interpret=interpret,
+        # the kernel's name in a profiler trace (a part: tracing.PARTS)
+        name="decode_attention",
     )(lengths, src, *ins)
     return out.reshape(b, kv, n_q, g, dv).swapaxes(1, 2).reshape(
         b, n_q, h, dv)

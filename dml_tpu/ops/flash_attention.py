@@ -302,6 +302,8 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((bq, dv), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
+        # the kernel's name in a profiler trace (a part: tracing.PARTS)
+        name="flash_attention",
     )(qp, kp, vp)
     return out[:, :, :t], lse[:, :, :t, 0]
 
@@ -371,6 +373,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, g_lse, causal, scale, block_q,
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(*ins)[:, :, :t]
 
     # transposed grid: q-block innermost so dk/dv accumulate in scratch
@@ -399,6 +402,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, g_lse, causal, scale, block_q,
             pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(*ins)
     return dq, dk[:, :, :t_kv], dv[:, :, :t_kv]
 

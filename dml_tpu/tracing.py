@@ -39,6 +39,14 @@ This module is the per-request causality layer:
   entered into the JAX profiler as ``TraceAnnotation("dml.<name>")`` so
   that a device trace shows them on the profiler's own clock beside
   the device's operations. ``summary()`` folds the ring per name.
+- **Parts and the device account** (``PARTS``, ``read_profile``) — the
+  model's programs name their parts with ``jax.named_scope`` (embedding,
+  attention projections, cache attention, expert routing, ... one table
+  here, applied in inference/generate.py and inference/lm_server.py),
+  so a profiler trace can be read in the program's own words:
+  ``read_profile`` folds one ``.xplane.pb`` into device-busy seconds by
+  (program, part) and device-idle seconds by the serving thread's span
+  open in each gap. CLI: ``profile trace read [dir]``.
 - **TRACE_PULL** (cluster/node.py) — leader aggregation of every
   node's recorder with the same tier-by-tier datagram degradation as
   METRICS_PULL; ``assemble_traces`` stitches the pulled spans into
@@ -66,15 +74,21 @@ by the node count.
 from __future__ import annotations
 
 import contextvars
+import glob
 import hashlib
 import itertools
+import os
+import re
 import secrets
+import struct
 import sys
 import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from .observability import METRICS
 
@@ -123,9 +137,13 @@ SPAN_NAMES = (
     "lm_pack",           # issuing the packed readback's eager concatenate
     "lm_readback",       # the blocking np.asarray: host waits for device
     "lm_deliver",        # first tokens + req.deliver callbacks + retirements
+    "lm_route",          # a dispatch's routing numbers into the moe_* counters
     "lm_place",          # _place_waiting: free slots take queued requests
     "lm_prefill_group",  # one bucket group's build/prefill/insert/sample/merge
     "lm_request",        # LM request: submit -> last token on the host
+    "lm_turn",           # LM driver between two dispatches: results, locks
+    "lm_exposed",        # device left with nothing queued: a blocking wait's
+                         # return -> the next program enqueued (label after)
 )
 
 #: loop-span labels `Tracer.summary` (``profile spans``) averages beside
@@ -135,6 +153,38 @@ SPAN_NAMES = (
 #: dispatch was issued) say whether a worker's second batch keeps the
 #: slot grid fed
 SUMMARY_LABELS = ("joined", "waiting")
+
+#: The parts of a model's programs: the `jax.named_scope` names that
+#: inference/generate.py (`part`) and the programs of
+#: inference/lm_server.py put around each part's work, and therefore
+#: every part `read_profile` can report device time under. Scopes are
+#: metadata: they change no compiled program (tests/test_parts.py holds
+#: the lowered text to that). The two Pallas kernels carry their names
+#: through `pl.pallas_call(name=)`, inside `attn_core`: the innermost
+#: name of this table on an operation's scope path is its part.
+PARTS = (
+    "embed",             # token ids -> rows of the embedding table
+    "attn_proj",         # attention's norms, projections, rope, output proj
+    "attn_core",         # attention over the cache / the call's rows: glue
+    "decode_attention",  # ... the cache kernel (ops/decode_attention.py)
+    "flash_attention",   # ... the prefill kernel (ops/flash_attention.py)
+    "cache_write",       # rows into the slot grid / a prefill's rows laid out
+    "mlp",               # dense feed-forward, its norm included
+    "moe_route",         # expert layer: norm, scores, top-k, sort, counts
+    "moe_experts",       # expert layer: gathers, grouped matmuls, scatter-add
+    "moe_shared",        # expert layer: the shared expert
+    "ssm_proj",          # state-space mixer: norm, projections, conv, gate
+    "ssm_scan",          # state-space mixer: the recurrence (chunks, a step)
+    "head",              # final norm, logits, argmax / sample
+    "diffuse_select",    # block diffusion: confidences, ranks, fix, commit
+    "insert",            # a prefilled row into its slot; cur/pos/firsts merges
+    "pack",              # the packed readback's concatenate
+)
+
+#: `read_profile`'s names for what carries no name of the program's:
+#: device time of an operation under no part, idle time in no span
+UNSCOPED = "unscoped"
+UNATTRIBUTED = "unattributed"
 
 #: the loop ring's size: ten minutes at the chat cell's rate (about 10
 #: spans a decode dispatch x 3.7 dispatches/s = 22,200; PERF.md §5)
@@ -931,4 +981,240 @@ def cohort_attribution(
         "attributed_fraction": (
             round(covered / mean_e2e, 4) if mean_e2e > 0 else None
         ),
+    }
+
+
+# ----------------------------------------------------------------------
+# the device account: one profiler trace in the program's own names
+# ----------------------------------------------------------------------
+
+
+def _pb_varint(buf: bytes, i: int) -> Tuple[int, int]:
+    r = shift = 0
+    while True:
+        c = buf[i]
+        i += 1
+        r |= (c & 0x7F) << shift
+        if c < 0x80:
+            return r, i
+        shift += 7
+
+
+def _pb_fields(buf: bytes) -> Iterable[Tuple[int, Any]]:
+    """(field number, value) of one protobuf message: an int for a
+    varint, bytes for a length-delimited or fixed-width field."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _pb_varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            v, i = _pb_varint(buf, i)
+        elif wire == 2:
+            ln, i = _pb_varint(buf, i)
+            v, i = buf[i:i + ln], i + ln
+        elif wire in (1, 5):
+            ln = 8 if wire == 1 else 4
+            v, i = buf[i:i + ln], i + ln
+        else:
+            raise ValueError(f"protobuf wire type {wire} in a profiler trace")
+        yield key >> 3, v
+
+
+def _xplane(buf: bytes) -> Dict[str, Any]:
+    """One `XPlane` of a profiler trace: its name, its own stats by
+    name, its lines as (name, [(metadata id, start ns, end ns)]) and
+    its event metadata as id -> (name, {stat name: value}).
+
+    `jax.profiler.ProfileData` reads the same file, but hands out an
+    event's OWN stats only; an operation's scope path (`tf_op`) and
+    program (`program_id`) are stats of its event METADATA, so this
+    reads the wire format itself (xplane.proto's field numbers)."""
+    name, lines, stats, stat_names, metas = "", [], [], {}, {}
+    for f, v in _pb_fields(buf):
+        if f == 2:
+            name = v.decode()
+        elif f == 3:
+            lines.append(v)
+        elif f == 6:
+            stats.append(v)
+        elif f in (4, 5):  # map entries: key = 1, value = 2
+            entry = dict(_pb_fields(v))
+            into = stat_names if f == 5 else metas
+            into[entry.get(1, 0)] = entry.get(2, b"")
+    stat_names = {
+        k: dict(_pb_fields(v)).get(2, b"").decode()
+        for k, v in stat_names.items()}
+
+    def stat(buf: bytes) -> Tuple[str, Any]:
+        d = dict(_pb_fields(buf))
+        key = stat_names.get(d.get(1), "")
+        if 5 in d:
+            return key, d[5].decode(errors="replace")
+        if 7 in d:  # a reference to an interned string
+            return key, stat_names.get(d[7], "")
+        if 2 in d:
+            return key, struct.unpack("<d", d[2])[0]
+        return key, d.get(3, d.get(4, d.get(6)))
+
+    meta: Dict[int, Tuple[str, Dict[str, Any]]] = {}
+    for mid, v in metas.items():
+        mname, mstats = "", {}
+        for f, x in _pb_fields(v):
+            if f == 2:
+                mname = x.decode(errors="replace")
+            elif f == 5:
+                k, val = stat(x)
+                mstats[k] = val
+        meta[mid] = (mname, mstats)
+    out_lines = []
+    for buf_line in lines:
+        lname, t0, events = "", 0, []
+        for f, v in _pb_fields(buf_line):
+            if f == 2:
+                lname = v.decode()
+            elif f == 3:
+                t0 = v
+            elif f == 4:
+                mid = off = dur = 0
+                for g, x in _pb_fields(v):
+                    if g == 1:
+                        mid = x
+                    elif g == 2:
+                        off = x
+                    elif g == 3:
+                        dur = x
+                events.append((mid, off, off + dur))
+        # a line's `timestamp_ns` plus an event's offset in ps
+        out_lines.append((lname, [
+            (mid, t0 + a * 1e-3, t0 + b * 1e-3) for mid, a, b in events]))
+    return {"name": name, "stats": dict(stat(b) for b in stats),
+            "lines": out_lines, "meta": meta}
+
+
+def part_of(scope_path: str) -> str:
+    """The part an operation belongs to: the innermost name of `PARTS`
+    on its scope path (`jit(f)/jit(main)/while/body/attn_proj/dot_general`),
+    `UNSCOPED` where there is none."""
+    for seg in reversed(scope_path.rstrip(":").split("/")):
+        if seg in PARTS:
+            return seg
+    return UNSCOPED
+
+
+def find_profile(trace_dir: str) -> Optional[str]:
+    """The newest `.xplane.pb` under a `jax.profiler` log directory."""
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    return found[-1] if found else None
+
+
+def read_profile(xplane_path: str, top: int = 10) -> Dict[str, Any]:
+    """One profiler trace (`.xplane.pb`) as an account of its window in
+    the program's own names.
+
+    BUSY: the device's `XLA Ops` line, each operation's SELF time (its
+    interval less its children's: a `while` spans its body, a call its
+    callee), summed by program (the XLA module the operation's
+    `program_id` names, e.g. `jit__chunk_impl`) and part (`part_of` its
+    `tf_op` stat, the scope path `jax.named_scope` wrote; `UNSCOPED`
+    for the rest, whose longest operations are listed). Self times add
+    up to the union of the intervals, which is the busy time.
+
+    IDLE: the window less that union, gap by gap, each gap under the
+    innermost (shortest) `dml.*` annotation open at its middle on any
+    host thread (the loop spans `Tracer.loop_span` enters into the
+    profiler), `UNATTRIBUTED` where none is open.
+
+    The window is the span of the device's operations, first start to
+    last end (of the `dml.*` annotations in a file with no device
+    plane): what the device was WATCHED over. The session's own
+    `profile_start_time` .. `profile_stop_time` is longer by the
+    profiler's start and stop (0.3 s of a 5 s trace on the chip, my
+    chip run, PR 38), in which nothing is recorded and nothing can be
+    said. busy_s + idle_s = window_s by construction. Of several chips,
+    the first that ran anything."""
+    with open(xplane_path, "rb") as fh:
+        planes = [_xplane(v) for f, v in _pb_fields(fh.read()) if f == 1]
+    device = next(
+        (p for p in planes if re.match(r"^/device:(TPU|GPU):\d+$", p["name"])
+         and any(n == "XLA Ops" and ev for n, ev in p["lines"])), None)
+    spans: List[Tuple[float, float, str]] = []
+    for p in planes:
+        if p["name"].startswith("/host:"):
+            for _, events in p["lines"]:
+                spans += [(a, b, p["meta"][mid][0][4:])
+                          for mid, a, b in events
+                          if p["meta"].get(mid, ("",))[0].startswith("dml.")]
+    ops = sorted(
+        (ev for n, evs in (device["lines"] if device else ())
+         if n == "XLA Ops" for ev in evs),
+        key=lambda e: (e[1], -e[2]))
+    edges = ([t for _, a, b in ops for t in (a, b)]
+             or [t for a, b, _ in spans for t in (a, b)] or [0.0])
+    lo, hi = min(edges), max(edges)
+
+    programs = {}
+    for n, evs in (device["lines"] if device else ()):
+        if n == "XLA Modules":
+            for mid, _, _ in evs:
+                m = re.match(r"^(.*)\((\d+)\)$", device["meta"][mid][0])
+                if m:
+                    programs[m.group(2)] = m.group(1)
+    # self time: a stack of the operations open at each start
+    self_ns = [b - a for _, a, b in ops]
+    stack: List[int] = []
+    busy_iv: List[List[float]] = []
+    for i, (_, a, b) in enumerate(ops):
+        while stack and ops[stack[-1]][2] <= a:
+            stack.pop()
+        if stack:
+            self_ns[stack[-1]] -= min(b, ops[stack[-1]][2]) - a
+        elif busy_iv and a <= busy_iv[-1][1]:
+            busy_iv[-1][1] = max(busy_iv[-1][1], b)
+        else:
+            busy_iv.append([a, b])
+        if stack and b > ops[stack[-1]][2]:
+            continue  # overlaps its parent's end: no frame of its own
+        stack.append(i)
+    busy: Dict[str, Dict[str, float]] = {}
+    unscoped: Dict[str, float] = {}
+    for (mid, _, _), ns in zip(ops, self_ns):
+        name, stats = device["meta"].get(mid, ("", {}))
+        kind = programs.get(str(stats.get("program_id")), "other")
+        part = part_of(str(stats.get("tf_op", "")))
+        row = busy.setdefault(kind, {})
+        row[part] = row.get(part, 0.0) + ns * 1e-9
+        if part == UNSCOPED:
+            op = name.split(" = ", 1)[0].lstrip("%")[:80]
+            key = (f"{kind}:{op}", str(stats.get("tf_op", "")))
+            unscoped[key] = unscoped.get(key, 0.0) + ns * 1e-9
+
+    # idle: the window less the busy intervals, named gap by gap
+    cuts = [lo] + [t for iv in busy_iv for t in iv] + [hi]
+    iv = np.asarray([(a, b) for a, b, _ in spans], np.float64).reshape(-1, 2)
+    idle: Dict[str, float] = {}
+    for a, b in zip(cuts[0::2], cuts[1::2]):
+        if b <= a:
+            continue
+        mid_t = 0.5 * (a + b)
+        hit = np.flatnonzero((iv[:, 0] <= mid_t) & (iv[:, 1] > mid_t))
+        who = UNATTRIBUTED
+        if len(hit):
+            who = spans[hit[np.argmin(iv[hit, 1] - iv[hit, 0])]][2]
+        idle[who] = idle.get(who, 0.0) + (b - a) * 1e-9
+    busy_s = sum(b - a for a, b in busy_iv) * 1e-9
+    return {
+        "window_s": (hi - lo) * 1e-9,
+        "busy_s": busy_s,
+        "idle_s": (hi - lo) * 1e-9 - busy_s,
+        "operations": len(ops),
+        "annotations": len(spans),
+        "busy": {k: dict(sorted(v.items(), key=lambda kv: -kv[1]))
+                 for k, v in sorted(
+                     busy.items(), key=lambda kv: -sum(kv[1].values()))},
+        "idle": dict(sorted(idle.items(), key=lambda kv: -kv[1])),
+        # [program:operation, seconds, its scope path (often none: an
+        # operation the compiler made carries no name of the program's)]
+        "unscoped_ops": [[k, v, path] for (k, path), v in sorted(
+            unscoped.items(), key=lambda kv: -kv[1])[:top]],
     }

@@ -620,7 +620,7 @@ def test_every_chunk_step_holds_its_five_phases(served):
     assert 0 < sum(st["lb"]["tokens"] for st in steps) <= total
     assert {d["name"] for d in spans if not d["par"]} <= {
         "lm_step", "lm_readback", "lm_place", "lm_request",
-        "lm_weights_resident"}
+        "lm_weights_resident", "lm_exposed"}
 
 
 def test_prefill_groups_count_prompt_and_padded_tokens(served):

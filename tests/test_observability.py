@@ -96,6 +96,37 @@ def test_histogram_edges_must_increase():
         reg.histogram("h", edges=[1.0, 1.0, 2.0])
 
 
+@pytest.mark.parametrize("values", [
+    [],
+    [0.5],
+    [1e-9, 1e-4, 1e-4 * 10 ** (1 / 6), 3.0, 100.0, 1e6],  # edges, overflow
+    list(np.random.default_rng(11).lognormal(-3.0, 2.5, size=936)),
+    list(np.random.default_rng(12).integers(0, 129, size=312)),
+], ids=["none", "one", "edges", "floats", "counts"])
+def test_observe_many_leaves_what_a_loop_of_observe_leaves(values):
+    """Count, sum, min, max and every bucket, exactly (the sum is added
+    left to right as the loop adds it), on top of what was there and
+    under a label; an empty batch leaves no series behind."""
+    reg = MetricsRegistry()
+    loop = reg.histogram("loop_seconds", "one by one")
+    many = reg.histogram("many_seconds", "all at once")
+    for h in (loop, many):
+        h.observe(0.125, layer="a")  # the running state both add onto
+    for v in values:
+        loop.observe(v, layer="a")
+    many.observe_many(np.asarray(values, np.float64), layer="a")
+    many.observe_many(values, layer="b")
+    (_, was), = loop.items()
+    got = dict(many.items())
+    assert got[(("layer", "a"),)] == was
+    if values:
+        n, total, lo, hi, buckets = got[(("layer", "b"),)]
+        assert (n, lo, hi) == (len(values), min(values), max(values))
+        assert sum(buckets) == n and total == pytest.approx(sum(values))
+    else:
+        assert (("layer", "b"),) not in got
+
+
 def test_percentiles_against_numpy():
     """Bucketed quantiles must land within one bucket RATIO of numpy's
     exact sample quantiles — that is the accuracy the fixed log-spaced

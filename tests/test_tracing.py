@@ -494,7 +494,7 @@ def test_every_loop_span_name_is_registered():
     lint rule checks the call sites; this pins the vocabulary)."""
     assert {"lm_idle", "lm_submit", "lm_step", "lm_dispatch", "lm_pack",
             "lm_readback", "lm_deliver", "lm_place", "lm_prefill_group",
-            "lm_request", "worker_fetch", "worker_infer", "worker_put",
+            "lm_request", "lm_route", "lm_turn", "lm_exposed", "worker_fetch", "worker_infer", "worker_put",
             "store_op_put", "store_op_get"} <= set(SPAN_NAMES)
 
 
