@@ -1183,7 +1183,22 @@ def uses_decode_kernel() -> bool:
     - the jobs cell (`mistral7b_widths_l8.jobs`), tokens/s: the
       einsum 1,175.7, the dense kernel forced onto the grouped cache
       1,164.0, this kernel 1,486.3 — kernel against einsum is nothing,
-      skipping dead blocks is all of it."""
+      skipping dead blocks is all of it.
+
+    Since PR 39 the kernel's grid is the list of live (slot, k-block)
+    pairs, where PR 26 kept a (slots, blocks of T) grid and skipped the
+    dead steps' DMAs and bodies at ~0.5 us each; the KV heads' softmax
+    chains run side by side, and a block is 1 MB of copies a step (256
+    rows here, where PR 26 took 512). The kernel alone (my chip runs,
+    PR 39; a 32-step scan of 8 calls, us a call, PR 26's kernel -> the
+    dead steps gone at its blocks -> as landed): 116.9 -> 64.4 -> 51.2
+    at the jobs cell's lengths (16 slots live, 20 of 128 blocks of
+    512), 42.3 -> 16.0 -> 14.0 with 3 slots live, 367.7 -> 367.8 ->
+    360.5 with every slot at 4,000 rows (no dead step to drop there);
+    an int8 cache 108.2 -> 59.8 -> 39.5 and 339.2 -> 326.3 -> 212.8;
+    the other cells' shapes 179.5 -> 74.3 (32 slots, KV 4, four query
+    rows), 299.5 -> 112.0 (64 slots, KV 2), 115.0 -> 92.1 (the shared
+    640-column plane). The jobs cell 2,077.8 -> 2,250.2 tokens/s."""
     return jax.default_backend() == "tpu"
 
 
@@ -1200,7 +1215,8 @@ def decode_block_rows(
     from ..ops.decode_attention import block_rows
 
     if cfg.latent is not None:  # one shared plane of latent rows
-        return block_rows(1, cfg.latent.row_stride, cfg.dtype, max_len)
+        return block_rows(
+            1, cfg.latent.row_stride, cfg.dtype, max_len, shared=True)
     tp = mesh.shape["tp"] if heads_axis(
         mesh, cfg.n_heads, cfg.kv_heads) else 1
     return block_rows(

@@ -164,11 +164,13 @@ _M_KV_ROWS = METRICS.counter(
     "cache rows of ONE layer over the chunk dispatches' decode steps by "
     "kind=: live (rows the slots' lengths name), read (rows of the "
     "k-blocks cache attention fetches for them; the whole grid on the "
-    "einsum route) and grid (steps x slots x max_len, what a "
-    "length-blind step streams)")
+    "einsum route), grid (steps x slots x max_len, what a length-blind "
+    "step streams) and blocks (not rows: the k-blocks the kernel "
+    "visits, one grid step each; 0 on the einsum route)")
 _M_KV_LIVE = _M_KV_ROWS.labels(kind="live")
 _M_KV_READ = _M_KV_ROWS.labels(kind="read")
 _M_KV_GRID = _M_KV_ROWS.labels(kind="grid")
+_M_KV_BLOCKS = _M_KV_ROWS.labels(kind="blocks")
 _M_FIRST_TOKEN = METRICS.histogram(
     "lm_server_first_token_seconds",
     "slot placement -> the request's first token VALUE on the host "
@@ -2091,33 +2093,35 @@ class LMServer:
         _M_SLOTS.set(sum(1 for r in self._slot_req if r is not None))
         step.label(tokens=delivered, firsts=first_n)
 
-    def _kv_rows(self) -> Tuple[int, int, int]:
-        """(live, read, grid) cache rows of one layer over the chunk
-        dispatch about to be issued — host arithmetic on what the
-        device will do, no readback. A live slot at step i attends
-        prompt + emitted + i rows (its clamped position + 1) and cache
-        attention fetches them in whole k-blocks; an empty slot names
-        the block of the slot before it, so only a run of them at the
-        head of the grid costs a block a step
-        (ops/decode_attention.py)."""
+    def _kv_rows(self) -> Tuple[int, int, int, int]:
+        """(live, read, grid, blocks) of one layer over the chunk
+        dispatch about to be issued: cache rows live, fetched and in
+        the whole grid, and the k-blocks the kernel visits. Host
+        arithmetic on what the device will do, no readback. A live
+        slot at step i attends prompt + emitted + i rows (its clamped
+        position + 1); cache attention walks the live (slot, k-block)
+        pairs alone, `cdiv(rows, block)` a slot, and fetches each
+        whole; an empty slot has none (a step with every slot empty
+        visits one block of no live rows: ops/decode_attention.py)."""
         if "*" not in (self.cfg.layer_pattern or "*"):
-            return 0, 0, 0  # no attention layer, no rows
+            return 0, 0, 0, 0  # no attention layer, no rows
         grid = self.chunk * self.max_slots * self.max_len
         pos0 = np.asarray(
             [r.prompt.size + r.emitted - 1
-             for r in self._slot_req if r is not None]
+             for r in self._slot_req if r is not None], np.int64
         )
         lens = np.minimum(
             pos0[:, None] + np.arange(self.chunk), self.max_len - 1
         ) + 1  # [live slots, chunk]
         live = int(lens.sum())
         bk = decode_block_rows(self.cfg, self.max_len, self._mesh)
-        if bk is None:
-            return live, grid, grid
-        read = int(np.minimum(-(-lens // bk) * bk, self.max_len).sum())
-        if self._slot_req[0] is None:
-            read += self.chunk * bk
-        return live, read, grid
+        if bk is None:  # the einsum streams every row; it has no blocks
+            return live, grid, grid, 0
+        nblk = -(-lens // bk)
+        rows = np.minimum(nblk * bk, self.max_len)  # T's last block is short
+        blocks = np.maximum(nblk.sum(axis=0), 1)  # a step
+        read = np.maximum(rows.sum(axis=0), min(bk, self.max_len))
+        return live, int(read.sum()), grid, int(blocks.sum())
 
     def _chunk_step(self, step: Any) -> None:
         """The plain chunked-scan dispatch (step()'s pre-spec body),
@@ -2132,11 +2136,12 @@ class LMServer:
                 ))
             self._fed()
         # reckoned while the device works, before delivery moves `emitted`
-        live, read, grid = self._kv_rows()
+        live, read, grid, blocks = self._kv_rows()
         _M_KV_LIVE.inc(live)
         _M_KV_READ.inc(read)
         _M_KV_GRID.inc(grid)
-        step.label(kv_rows_live=live, kv_rows_read=read)
+        _M_KV_BLOCKS.inc(blocks)
+        step.label(kv_rows_live=live, kv_rows_read=read, kv_blocks=blocks)
         if self.cfg.has_state:
             # every occupied slot's state was read and written whole by
             # each of the dispatch's steps

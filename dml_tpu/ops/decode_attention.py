@@ -14,23 +14,39 @@ block diffusion's forwards: `generate.batched_block_step`): a KV
 head's Q * G rows then share every fetched block, each row masked to
 its own limit (causal, or by blocks of `mask_block`).
 
-Structure: grid (B, k-blocks), online-softmax accumulation across
-k-blocks in VMEM scratch (the decode-shaped sibling of
-flash_attention.py's forward kernel — G = H/KV query rows instead of
-a q-block); one grid instance streams ALL kv heads' blocks. The
-per-slot lengths are a scalar-prefetch operand
+Structure: the grid is the list of LIVE (slot, k-block) pairs,
+`sum_b cdiv(lengths[b], block)` steps and not slots x blocks of T
+(`work_list`: slot and block of every item, made from the lengths by a
+cumulative sum; the grid's bound is a traced scalar, as megablox.gmm's
+`num_active_tiles` is). Online-softmax accumulation across a slot's
+items in VMEM scratch (the decode-shaped sibling of
+flash_attention.py's forward kernel, G = H/KV query rows instead of a
+q-block); one grid step streams ALL kv heads' blocks. Lengths and the
+work list are scalar-prefetch operands
 (`pltpu.PrefetchScalarGridSpec`), read by both halves:
 
-- the k/v (and int8 scale) `index_map`s clamp the block index to the
-  slot's last live block, and an empty slot names the block the slot
-  before it ended on. Pallas issues a DMA only when the block index
-  changes between grid steps, so blocks past a slot's length and whole
-  empty slots are never fetched (a run of empty slots at the head of
-  the grid shares one block);
-- the body runs under `pl.when(block_start < length)`; inside the one
-  partly-live block validity is `iota < length`: scores of dead rows
-  are replaced (a select, so stale NaN cannot leak), their v rows
-  zeroed. A slot of length 0 returns zeros.
+- the `index_map`s name item w's slot and block, so no step, no DMA
+  and no test exists for a block past a slot's length or for an empty
+  slot, and the pipeline's look-ahead fetches the next LIVE block, a
+  slot boundary or a run of empty slots between them or not;
+- the body starts a slot's softmax on its block 0 and writes its
+  output on its last; inside the one partly-live block validity is
+  `iota < length`: scores of dead rows are replaced (a select, so
+  stale NaN cannot leak), their v rows zeroed. No item visits an empty
+  slot, whose output the caller's select zeroes: it returns zeros and
+  reads nothing.
+
+A dead step of the (slots, blocks of T) grid this replaces cost ~0.5 us
+with its DMA skipped and its body under `pl.when` (my chip run, PR 39:
+107 of 128 steps a call at the dense jobs cell's lengths, 117 -> 64 us
+a call at the same blocks of 512 rows; 42 -> 16 with 3 of 16 slots
+live). A grid over slots with an in-body loop and its own
+double-buffered copies measured 11-22% behind this form at every
+shape, and ahead at no block. The block's arithmetic is ONE chain for
+all KV heads (a batch dimension of the two dots): a static loop over
+heads ran eight chains end to end, 2.35 us a step of 256 rows whose
+copies take 1.28, and held the block at 512 rows; side by side they
+hide under the copies of 256 (`block_rows` on the block: 51 us a call).
 
 Two things the einsum does badly on TPU stay solved here: an int8
 cache (`LMConfig.kv_quant`) is dequantized inline (int8 values and f32
@@ -43,13 +59,14 @@ f32; p is rounded to the cache's dtype for the p·v dot, as XLA's
 default-precision einsum does on TPU). Parity with the oracle holds
 to float-associativity noise in f32 and to bf16 rounding of p in bf16.
 
-Measured on one TPU v5e (my chip run, PR 26): see
+Measured on one TPU v5e (my chip runs, PR 26 and PR 39): see
 `generate.uses_decode_kernel`. The reference has no attention
 anywhere (SURVEY §0); this serves the net-new LM path.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -63,95 +80,141 @@ NEG_INF = -1e30
 LANES = 128  # scratch rows kept [G, 128]: full native tiles
 
 
-def block_rows(kv: int, d: int, dtype, t: int, block_k: int = 2048) -> int:
-    """Cache rows per k-block for a [*, kv, t, d] cache of `dtype`.
-    One grid instance holds all KV heads' blocks, so the block is
-    clamped to keep each stream's VMEM buffer (cache dtype; bf16
-    temporaries for int8) at ~1 MB: 512 rows at KV 8 x D 128 x 2 B.
-    Also what a caller needs to reckon the rows a step fetches."""
-    itemsize = max(jnp.dtype(dtype).itemsize, 2)
-    cap = max(128, (2**20) // (kv * d * itemsize) // 128 * 128)
-    return min(block_k, cap, t)
+def block_rows(kv: int, d: int, dtype, t: int, shared: bool = False) -> int:
+    """Cache rows per k-block for a [*, kv, t, d] cache of `dtype`
+    (`shared`: one plane that is keys and values, else a K and a V
+    stream): the power of two nearest 1 MB of copies a STEP, all KV
+    heads of every stream together, between 128 (the int8 scale
+    stream's lane tile) and 2,048, never past `t`: 256 rows at KV 8 x
+    D 128 x 2 B x 2 streams. Also what a caller needs to reckon the
+    rows a step fetches.
+
+    The rule trades a step's chain of arithmetic (scores, max, exp,
+    sum, values: ~1.2 us however few rows, the heads' chains side by
+    side) against the rounding of a slot's last block: a step whose
+    copies take less than the chain is bound by the chain, one whose
+    copies take more fetches dead rows for nothing. On one TPU v5e
+    (my chip run, PR 39; us a call at the cells' lengths / every slot
+    at 4,000 rows; 1 MB a step marked *): KV 8 reads 63 / 440 at 128
+    rows, 54 / 364 at 256*, 65 / 364 at 512; KV 4 with 32 query rows
+    83 / 448 at 256, 77 / 367 at 512*, 104 / 366 at 1,024; KV 2 with
+    64 slots 108 / 446 at 512, 115 / 365 at 1,024*, 189 / 365 at
+    2,048; the shared 640-column plane 103 / 141 at 512, 96 / 121 at
+    1,024*; an int8 cache at KV 8 (half the bytes a row) 40 / 264 at
+    256, 39 / 213 at 512*. The copies alone run at 91% of the chip's
+    bandwidth at any block from 128 rows up. At the rule's two ends
+    (16 slots): MHA at KV 32 lands on the floor, 2 MB a step, 178 /
+    1,425 at 128* against 168 / 1,408 at 64 and 195 / 1,425 at 256
+    (PR 26's kernel 391 / 2,284: its 32 chains end to end); MQA at
+    KV 1 takes 2,048 rows, 27 / 50 against 20 / 62 at 1,024 and 18 /
+    87 at 512 (PR 26's 47 / 50): short slots would like less, full
+    ones lose more by it. An int8 cache at KV 32 reads 120 / 839 at
+    128* and 116 / 728 at 256: its step is bound by the casts, not
+    the copies, and nothing but tests reaches it."""
+    row = (1 if shared else 2) * kv * d * jnp.dtype(dtype).itemsize
+    nearest = 2 ** round(math.log2(2**20 / row))
+    return min(max(128, nearest), 2048, t)
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, quantized, n_kv, bk,
-                   n_q=1, mask_block=1, v_width=None):
-    ik = pl.program_id(1)
-    nk = pl.num_programs(1)
-    length = len_ref[pl.program_id(0)]
-    rows = q_ref.shape[1]  # n_q * G query rows a KV head, query-major
+def work_list(lengths: jax.Array, bk: int, n_items: int):
+    """The kernel's work: the live (slot, k-block) pairs in slot order,
+    `sum_b cdiv(lengths[b], bk)` of them. Returns (slot_of, block_of,
+    n): int32 [n_items] each and the traced count, n_items >= n being
+    the static room (slots x blocks of T). Item w is block
+    `block_of[w]` of slot `slot_of[w]`; a slot's first item has block
+    0, its last `cdiv(length, bk) - 1`; an empty slot has none. Items
+    past n repeat the last one (the pipeline's look-ahead then names a
+    block it holds already). With every slot empty n is 1 and the one
+    item is a block of no live rows, so the grid is never empty."""
+    b = lengths.shape[0]
+    nblk = (lengths + bk - 1) // bk
+    ends = jnp.cumsum(nblk)  # items of slots <= b
+    n = jnp.maximum(ends[-1], 1)
+    w = jnp.minimum(jnp.arange(n_items, dtype=jnp.int32), n - 1)
+    # slot s owns item w iff ends[s - 1] <= w < ends[s]: s is the count
+    # of slots that end at or before w, its first item their blocks' sum
+    before = ends[None, :] <= w[:, None]  # [n_items, B]
+    slot_of = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
+    block_of = w - jnp.sum(
+        jnp.where(before, nblk[None, :], 0), axis=1, dtype=jnp.int32)
+    return slot_of, block_of, n
 
-    @pl.when(ik == 0)
+
+def _decode_kernel(len_ref, slot_ref, blk_ref, q_ref, k_ref, ks_ref, v_ref,
+                   vs_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, quantized,
+                   bk, n_q=1, mask_block=1, v_width=None):
+    w = pl.program_id(0)
+    ik = blk_ref[w]
+    length = len_ref[slot_ref[w]]
+
+    @pl.when(ik == 0)  # a slot's first item
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(ik * bk < length)
-    def _block():
-        # rows of this block that are live, along lanes (scores) and
-        # along sublanes (v rows); all true except in the last block
+    # every item is a live block: no test, and none of a dead one
+    rows = q_ref.shape[1]  # n_q * G query rows a KV head, query-major
+    # rows of this block that are live, along lanes (scores) and
+    # along sublanes (v rows); all true except in the last block
+    live_t = ik * bk + jax.lax.broadcasted_iota(
+        jnp.int32, (1, bk), 1) < length
+    live_r = ik * bk + jax.lax.broadcasted_iota(
+        jnp.int32, (bk, 1), 0) < length
+    if n_q > 1:
+        # `length` counts the n_q rows this dispatch wrote; query
+        # i of them stops short of the rows of later queries
+        # (causal) or of later blocks (`mask_block`): a [rows, bk]
+        # mask in place of the [1, bk] one
+        qi = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) // (rows // n_q)
+        back = n_q - (qi // mask_block + 1) * mask_block
         live_t = ik * bk + jax.lax.broadcasted_iota(
-            jnp.int32, (1, bk), 1) < length
-        live_r = ik * bk + jax.lax.broadcasted_iota(
-            jnp.int32, (bk, 1), 0) < length
-        if n_q > 1:
-            # `length` counts the n_q rows this dispatch wrote; query
-            # i of them stops short of the rows of later queries
-            # (causal) or of later blocks (`mask_block`): a [rows, bk]
-            # mask in place of the [1, bk] one
-            qi = jax.lax.broadcasted_iota(
-                jnp.int32, (rows, 1), 0) // (rows // n_q)
-            back = n_q - (qi // mask_block + 1) * mask_block
-            live_t = ik * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, bk), 1) < length - back
-        # static per-head loop: one grid instance streams ALL kv heads'
-        # blocks (a per-(b, head) grid at decode sizes is dominated by
-        # instance overhead)
-        for h in range(n_kv):
-            # MXU dots take the cache's own dtype (int8 -> bf16 is
-            # EXACT for |v| <= 127); the per-position scales fold into
-            # the [G, bk] score/probability rows AFTER the dot — 16x
-            # fewer multiplies than dequantizing the [bk, D] block,
-            # and no f32 cache temporary in VMEM
-            k = k_ref[h]
-            if quantized:
-                k = k.astype(jnp.bfloat16)
-            s = jax.lax.dot_general(
-                q_ref[h].astype(k.dtype), k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [G, bk] f32
-            if quantized:
-                s = s * ks_ref[h]  # [1, bk] f32 scale row, exact in f32
-            s = jnp.where(live_t, s, NEG_INF)
+            jnp.int32, (rows, bk), 1) < length - back
+    # all KV heads at once, a batch dimension of the two dots: one
+    # grid step streams ALL kv heads' blocks (a per-(b, head) grid at
+    # decode sizes is dominated by instance overhead). MXU dots take
+    # the cache's own dtype (int8 -> bf16 is EXACT for |v| <= 127);
+    # the per-position scales fold into the [G, bk] score/probability
+    # rows AFTER the dot: 16x fewer multiplies than dequantizing the
+    # [bk, D] block, and no f32 cache temporary in VMEM
+    k = k_ref[...]  # [KV, bk, D]
+    if quantized:
+        k = k.astype(jnp.bfloat16)
+    s = jax.lax.dot_general(
+        q_ref[...].astype(k.dtype), k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [KV, rows, bk] f32
+    if quantized:
+        s = s * ks_ref[...]  # [KV, 1, bk] f32 scale rows, exact in f32
+    s = jnp.where(live_t, s, NEG_INF)
 
-            m_prev = m_scr[h, :, :1]  # [G, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_scr[h, :, :1] * alpha + jnp.sum(p, -1, keepdims=True)
-            # one shared plane (`v_width`): the values are the first
-            # columns of the block the keys came in, fetched once
-            v = k[:, :v_width] if v_ref is None else v_ref[h]
-            if quantized:
-                # fold the v scales into the prob rows (a dead row's
-                # scale is stale too: select, don't multiply)
-                p = jnp.where(live_t, p * vs_ref[h], 0.0)
-                v = v.astype(jnp.bfloat16)
-            # p is exactly 0 on dead rows, but 0 x stale NaN is NaN
-            v = jnp.where(live_r, v, jnp.zeros_like(v))
-            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32
-            )
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+    m_prev = m_scr[:, :, :1]  # [KV, rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_scr[:, :, :1] * alpha + jnp.sum(p, -1, keepdims=True)
+    # one shared plane (`v_width`): the values are the first columns
+    # of the block the keys came in, fetched once
+    v = k[:, :, :v_width] if v_ref is None else v_ref[...]
+    if quantized:
+        # fold the v scales into the prob rows (a dead row's scale is
+        # stale too: select, don't multiply)
+        p = jnp.where(live_t, p * vs_ref[...], 0.0)
+        v = v.astype(jnp.bfloat16)
+    # p is exactly 0 on dead rows, but 0 x stale NaN is NaN
+    v = jnp.where(live_r, v, jnp.zeros_like(v))
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ik == nk - 1)
+    @pl.when((ik + 1) * bk >= length)  # its last
     def _finish():
-        for h in range(n_kv):
-            l = jnp.maximum(l_scr[h, :, :1], 1e-30)
-            o_ref[h] = (acc_scr[h] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[:, :, :1], 1e-30)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention(
@@ -163,7 +226,7 @@ def decode_attention(
     k_scale: Optional[jax.Array] = None,  # [B, KV, 1, T] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
-    block_k: int = 2048,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     mask_block: int = 1,
     v_width: Optional[int] = None,
@@ -222,38 +285,28 @@ def decode_attention(
     scale = d ** -0.5 if scale is None else scale
     interpret = _interpret_default() if interpret is None else interpret
 
-    bk = block_rows(kv, d, k.dtype, t, block_k)
+    rows = n_q * g  # query rows a KV head
+    bk = (block_rows(kv, d, k.dtype, t, v is None) if block_k is None
+          else min(block_k, t))
     # a ragged last block needs no padded copy of the cache: whatever
     # the out-of-range rows read as lies past every slot's length
-    nk = pl.cdiv(t, bk)
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, t)
-    # the slot whose blocks are on show at grid row b: b itself if it
-    # is live, else the last live slot before it (slot 0 if none)
-    src = jax.lax.cummax(
-        jnp.where(lengths > 0, jnp.arange(b, dtype=jnp.int32), 0)
-    )
+    slot_of, block_of, n_live = work_list(lengths, bk, b * pl.cdiv(t, bk))
 
-    def cache_block(b_, j, len_ref, src_ref):
-        s = src_ref[b_]
-        last = jnp.maximum(pl.cdiv(len_ref[s], bk) - 1, 0)
-        return s, jnp.where(len_ref[b_] > 0, jnp.minimum(j, last), last)
+    def slot_map(w, len_ref, slot_ref, blk_ref):
+        return slot_ref[w], 0, 0, 0
 
-    def kv_map(b_, j, len_ref, src_ref):
-        s, blk = cache_block(b_, j, len_ref, src_ref)
-        return s, 0, blk, 0
+    def kv_map(w, len_ref, slot_ref, blk_ref):
+        return slot_ref[w], 0, blk_ref[w], 0
 
-    def sc_map(b_, j, len_ref, src_ref):
-        s, blk = cache_block(b_, j, len_ref, src_ref)
-        return s, 0, 0, blk
+    def sc_map(w, len_ref, slot_ref, blk_ref):
+        return slot_ref[w], 0, 0, blk_ref[w]
 
     # a KV head's rows are query-major: row i * G + j is query i's
     # head j of the group (for Q = 1 the plain [B, KV, G, D] view)
-    rows = n_q * g
     qg = q.reshape(b, n_q, kv, g, d).swapaxes(1, 2).reshape(b, kv, rows, d)
-    q_spec = pl.BlockSpec(
-        (None, kv, rows, d), lambda b_, j, *_: (b_, 0, 0, 0))
-    o_spec = pl.BlockSpec(
-        (None, kv, rows, dv), lambda b_, j, *_: (b_, 0, 0, 0))
+    q_spec = pl.BlockSpec((None, kv, rows, d), slot_map)
+    o_spec = pl.BlockSpec((None, kv, rows, dv), slot_map)
     kv_spec = pl.BlockSpec((None, kv, bk, d), kv_map)
     sc_spec = pl.BlockSpec((None, kv, 1, bk), sc_map)
 
@@ -268,7 +321,7 @@ def decode_attention(
         ins = (qg, k, v)
         in_specs = [q_spec, kv_spec, kv_spec]
 
-    def kernel(len_r, _src_r, q_r, *refs):  # src: the index maps' alone
+    def kernel(len_r, slot_r, blk_r, q_r, *refs):
         if quantized:
             k_r, ks_r, v_r, vs_r, *rest = refs
         elif v is None:
@@ -277,15 +330,16 @@ def decode_attention(
         else:
             k_r, v_r, *rest = refs
             ks_r = vs_r = None
-        _decode_kernel(len_r, q_r, k_r, ks_r, v_r, vs_r, *rest,
-                       scale=scale, quantized=quantized, n_kv=kv, bk=bk,
+        _decode_kernel(len_r, slot_r, blk_r, q_r, k_r, ks_r, v_r, vs_r, *rest,
+                       scale=scale, quantized=quantized, bk=bk,
                        n_q=n_q, mask_block=mask_block, v_width=v_width)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, nk),
+            num_scalar_prefetch=3,
+            # one step a live (slot, k-block) pair: the bound is traced
+            grid=(n_live,),
             in_specs=in_specs,
             out_specs=o_spec,
             scratch_shapes=[
@@ -298,6 +352,8 @@ def decode_attention(
         interpret=interpret,
         # the kernel's name in a profiler trace (a part: tracing.PARTS)
         name="decode_attention",
-    )(lengths, src, *ins)
+    )(lengths, slot_of, block_of, *ins)
+    # no item visits an empty slot, so nothing was written there
+    out = jnp.where(lengths[:, None, None, None] > 0, out, 0.0)
     return out.reshape(b, kv, n_q, g, dv).swapaxes(1, 2).reshape(
         b, n_q, h, dv)

@@ -9,7 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dml_tpu.ops.decode_attention import block_rows, decode_attention
+from dml_tpu.ops.decode_attention import (
+    block_rows, decode_attention, work_list,
+)
 
 
 def oracle(q, ck, cv, lengths):
@@ -235,10 +237,92 @@ def test_ragged_last_block_needs_no_padding():
     )
 
 
+@pytest.mark.parametrize("lengths", [
+    [0, 0, 5, BK, 0, 3 * BK, 1, 2 * BK + 7, 0],  # the ragged case's
+    [BK - 1, BK, BK + 1],
+    [0, 0, 2 * BK],  # empty slots at the head
+    [3 * BK, 0, 0],  # and at the tail
+    [1],
+])
+def test_work_list_by_hand(lengths):
+    """`sum cdiv(len, bk)` items in slot order, a slot's first item its
+    block 0 and its last `cdiv(len, bk) - 1`, nothing for an empty
+    slot; the room past the count repeats the last item, so the
+    pipeline's look-ahead names a block it already holds."""
+    room = len(lengths) * 3
+    slot_of, block_of, n = jax.jit(
+        lambda x: work_list(x, BK, room))(jnp.asarray(lengths, jnp.int32))
+    slot_of, block_of, n = np.asarray(slot_of), np.asarray(block_of), int(n)
+    want = [(s, j) for s, x in enumerate(lengths) for j in range(-(-x // BK))]
+    assert n == len(want) == sum(-(-x // BK) for x in lengths)
+    assert list(zip(slot_of[:n], block_of[:n])) == want
+    assert slot_of.shape == block_of.shape == (room,)
+    assert (slot_of[n:] == slot_of[n - 1]).all()
+    assert (block_of[n:] == block_of[n - 1]).all()
+    for s, x in enumerate(lengths):
+        mine = block_of[:n][slot_of[:n] == s]
+        if x == 0:
+            assert mine.size == 0
+        else:  # first and last marks, as the kernel reads them
+            assert mine[0] == 0 and (mine[-1] + 1) * BK >= x > mine[-1] * BK
+
+
+def test_work_list_of_an_empty_grid_is_one_dead_block():
+    """Every slot empty: one item of no live rows (a slot of length 0,
+    block 0), so the grid has a step and the kernel an output."""
+    slot_of, block_of, n = work_list(jnp.zeros(4, jnp.int32), BK, 12)
+    assert int(n) == 1
+    assert (np.asarray(block_of) == 0).all()
+    assert (np.asarray(slot_of) == 3).all()  # any slot: all are empty
+
+
+def test_work_list_under_jit_feeds_a_traced_grid():
+    """The grid's bound is a traced scalar: one compiled program serves
+    every set of lengths, and agrees with the eager call."""
+    q, kvs, _, deq = _case(15, 4, 2, 8, 3 * BK, 16, "f32")
+    fn = jax.jit(lambda n: decode_attention(q, *kvs, n, block_k=BK))
+    for lengths in ([0, 5, 0, 3 * BK], [BK + 1, 0, 0, 0], [0, 0, 0, 0]):
+        n = jnp.asarray(lengths, jnp.int32)
+        got = np.asarray(fn(n))
+        live = np.asarray(lengths) > 0
+        np.testing.assert_allclose(
+            got[live], np.asarray(oracle(q, *deq, n))[live], atol=2e-5)
+        assert np.array_equal(got[~live], np.zeros_like(got[~live]))
+    assert fn._cache_size() == 1
+
+
 def test_block_rows_at_the_cell_widths():
-    """KV 8 x D 128 x bf16: ~1 MB a stream is 512 rows, so a live slot
-    at the cells' median length (~350 rows) is one block of eight."""
-    assert block_rows(8, 128, jnp.bfloat16, 4096) == 512
-    assert block_rows(8, 128, jnp.int8, 4096) == 512  # bf16 temporaries
-    assert block_rows(1, 64, jnp.bfloat16, 4096) == 2048  # block_k
+    """The power of two nearest 1 MB of copies a step (K and V, or the
+    one shared plane), at the four cells' shapes: what measured
+    fastest at the cells' lengths AND no slower than the parent's
+    blocks at full context (`block_rows` has the readings). A live
+    slot at the dense cells' median length (~350 rows) is two blocks
+    of 256, and only its live blocks are grid steps."""
+    bf16 = jnp.bfloat16
+    assert block_rows(8, 128, bf16, 4096) == 256  # mistral7b_widths_l8
+    assert block_rows(4, 128, bf16, 4096) == 512  # sdar30b_a3b_l6
+    assert block_rows(2, 128, bf16, 4096) == 1024  # nemotron3_super_l11_ep4
+    # joyai_llm_flash_ep16's plane is one stream of 1,280 B a row
+    assert block_rows(1, 640, bf16, 4096, shared=True) == 1024
+    assert block_rows(8, 128, jnp.int8, 4096) == 512  # half the bytes a row
+    assert block_rows(1, 64, bf16, 4096) == 2048  # MQA: the ceiling
+    assert block_rows(32, 128, bf16, 4096) == 128  # MHA: the floor,
+    assert block_rows(32, 128, jnp.int8, 4096) % 128 == 0  # a lane tile
     assert block_rows(2, 16, jnp.float32, 40) == 40  # never past T
+
+
+@pytest.mark.parametrize("kv,d,dtype,t", [
+    (8, 128, jnp.bfloat16, 4096), (4, 128, jnp.bfloat16, 4096),
+    (2, 128, jnp.bfloat16, 4096), (1, 640, jnp.bfloat16, 4096),
+    (4, 64, jnp.int8, 1000), (16, 64, jnp.float32, 4096),
+])
+def test_block_rows_is_a_block_mosaic_takes(kv, d, dtype, t):
+    """Whole sublane tiles of the cache's dtype and whole lane tiles of
+    the int8 scale row, or the whole of T; the streams' double buffers
+    well inside the 16 MiB of VMEM a v5e kernel may scope."""
+    shared = kv == 1 and d == 640
+    bk = block_rows(kv, d, dtype, t, shared)
+    assert bk == t or bk % 128 == 0
+    assert 128 <= bk <= 2048 or bk == t
+    # K and V (or the plane), double-buffered, in bf16 at the least
+    assert 2 * 2 * kv * bk * d * max(jnp.dtype(dtype).itemsize, 2) <= 8 * 2**20

@@ -563,7 +563,7 @@ def _served_mixed_budgets(params):
                 "prompt": tok.value(kind="prompt"),
                 "padded": tok.value(kind="padded"),
                 **{"kv_" + k: rows.value(kind=k)
-                   for k in ("live", "read", "grid")}}
+                   for k in ("live", "read", "grid", "blocks")}}
 
     rng = np.random.RandomState(11)
     reqs = [(rng.randint(0, CFG.vocab_size, n), b) for n, b in
@@ -924,6 +924,7 @@ def _check_kv_rows(srv, spans, delta):
         assert lb["kv_rows_live"] <= lb["occupancy"] * srv.chunk * srv.max_len
     assert delta["kv_live"] == sum(lb["kv_rows_live"] for lb in steps)
     assert delta["kv_read"] == sum(lb["kv_rows_read"] for lb in steps)
+    assert delta["kv_blocks"] == sum(lb["kv_blocks"] for lb in steps)
     assert delta["kv_grid"] == len(steps) * grid
 
 
@@ -949,12 +950,50 @@ def test_kv_rows_arithmetic_by_hand(params, monkeypatch, kernel_route):
     assert [r is not None for r in srv._slot_req] == [False, True, True]
     assert list(srv.rid_vec) == [0, 2, 3]
     # slot 1 attends 8..11 rows (one block of 16 each step), slot 2
-    # 24..27 (two blocks); the empty slot at the head costs one block
-    # a step, which every empty slot behind a live one shares
-    assert srv._kv_rows() == (38 + 102, 4 * 16 + 4 * 32 + 4 * 16,
-                              4 * 3 * 64)
+    # 24..27 (two blocks); the empty slot at the head has no item in
+    # the kernel's work list: nothing fetched, no block visited
+    assert srv._kv_rows() == (38 + 102, 4 * 16 + 4 * 32, 4 * 3 * 64,
+                              4 * 1 + 4 * 2)
     srv.run()
     # near max_len: lengths clamp at the last row, blocks at the cache
     srv2 = LMServer(params, CFG, max_slots=1, max_len=64, chunk=4)
     srv2.submit(rng.randint(0, CFG.vocab_size, 61), 3)
-    assert srv2._kv_rows() == (62 + 63 + 64 + 64, 4 * 64, 4 * 64)
+    assert srv2._kv_rows() == (62 + 63 + 64 + 64, 4 * 64, 4 * 64, 4 * 4)
+    # a block that does not divide the cache: the last one is short
+    monkeypatch.setattr(lm_server, "decode_block_rows", lambda *a: 48)
+    assert srv2._kv_rows() == (62 + 63 + 64 + 64, 4 * 64, 4 * 64, 4 * 2)
+
+
+def test_kv_rows_are_what_the_kernel_walks(params, monkeypatch, kernel_route):
+    """`read` and `blocks` against the kernel's own work list: at every
+    step of the dispatch, `blocks` items and `read` rows of whole
+    blocks, whatever slots stand empty; a dispatch with no live slot
+    still visits one block a step (the grid is never empty)."""
+    from dml_tpu.inference import lm_server
+    from dml_tpu.ops.decode_attention import work_list
+
+    bk = 16
+    monkeypatch.setattr(lm_server, "decode_block_rows", lambda *a: bk)
+    rng = np.random.RandomState(22)
+    srv = LMServer(params, CFG, max_slots=4, max_len=64, chunk=4)
+    srv.submit_many(
+        [rng.randint(0, CFG.vocab_size, n) for n in (33, 3, 16, 47)],
+        [9, 1, 9, 9])
+    assert [r is not None for r in srv._slot_req] == [True, False, True, True]
+    live, read, _, blocks = srv._kv_rows()
+    pos0 = np.asarray([33, -1, 16, 47])  # the row each slot writes next
+    items = rows = 0
+    for i in range(srv.chunk):
+        lens = np.where(pos0 >= 0, pos0 + i + 1, 0)
+        slot_of, block_of, n = work_list(jnp.asarray(lens, jnp.int32), bk, 16)
+        n = int(n)
+        assert n == sum(-(-x // bk) for x in lens)
+        items += n
+        rows += n * bk
+        assert not (np.asarray(slot_of)[:n] == 1).any()  # the empty slot
+    assert (read, blocks) == (rows, items)
+    assert live == sum(int(np.where(pos0 >= 0, pos0 + i + 1, 0).sum())
+                       for i in range(srv.chunk))
+    srv.run()
+    empty = LMServer(params, CFG, max_slots=2, max_len=64, chunk=4)
+    assert empty._kv_rows() == (0, 4 * bk, 4 * 2 * 64, 4)

@@ -71,9 +71,20 @@ def _named(lowered):
     return {p for p in PARTS if re.search(r'["/]%s["/]' % p, text)}
 
 
+@pytest.fixture
+def fresh_traces():
+    """A trace keeps the Python stack it was made under, and jax's
+    caches hand the trace of an inner jitted function (`jnp.where`,
+    `jnp.clip`) to the next caller with the same shapes: one made
+    inside `decode_attention` by another file's test in this worker
+    would put the kernel's name into a program here that never calls
+    it (the joyai prefill did, one whole run in some)."""
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("config_name", sorted(WANTED))
 def test_programs_name_their_parts_and_nothing_else_changes(
-        config_name, monkeypatch):
+        config_name, monkeypatch, fresh_traces):
     decode, more, less = WANTED[config_name]
     scoped = _programs(config_name)
     assert _named(scoped["decode"]) == decode

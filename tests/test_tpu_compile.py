@@ -45,6 +45,18 @@ def topo():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture
+def chip_routes(monkeypatch):
+    """The kernel switches ask the backend; this answers for the chip.
+    `monkeypatch` puts the function back, but a trace made meanwhile
+    keeps the route it took (`uses_decode_kernel` baked in) in jax's
+    caches, where a later CPU lowering in this worker would find it
+    (tests/test_parts.py did): clear what the test leaves."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    jax.clear_caches()
+
+
 def compile_on_chip(topo, fn, *shapes):
     """Compile `fn` for one described chip; returns (text, seconds)."""
     one = SingleDeviceSharding(topo.devices[0])
@@ -56,12 +68,14 @@ def compile_on_chip(topo, fn, *shapes):
 
 SMOKE = (8, 16, 64, 4096)  # B, H, D, T: the LM phase's decode shapes
 CELL = (16, 32, 128, 4096)  # the benchmark's mistral7b_widths_l8
+HYBRID = (64, 32, 128, 4096)  # nemotron3_super_l11_ep4: 64 slots, 2 KV heads
 
 
 @pytest.mark.parametrize("shape,kv,int8", [
     (SMOKE, 4, False), (SMOKE, 16, False), (SMOKE, 1, False),
     (SMOKE, 4, True),
     (CELL, 8, False),
+    (HYBRID, 2, False),
     ((8, 16, 64, 1000), 4, True),  # a ragged last k-block, no padded copy
 ])
 def test_decode_attention_compiles(topo, shape, kv, int8):
@@ -69,7 +83,8 @@ def test_decode_attention_compiles(topo, shape, kv, int8):
 
     B, H, D, T = shape
     # bf16 is what `rope` hands the kernel under a bf16 config
-    q = ((B, 1, H, D), jnp.bfloat16 if shape is CELL else jnp.float32)
+    q = ((B, 1, H, D),
+         jnp.bfloat16 if shape in (CELL, HYBRID) else jnp.float32)
     pos = ((B,), jnp.int32)
     if int8:
         cache, scale = ((B, kv, T, D), jnp.int8), ((B, kv, 1, T), jnp.float32)
@@ -133,7 +148,7 @@ def test_block_causal_flash_attention_compiles(topo):
 
 
 @pytest.mark.parametrize("kernel", [True, False])
-def test_expert_layer_compiles_to_grouped_matmuls(topo, monkeypatch, kernel):
+def test_expert_layer_compiles_to_grouped_matmuls(topo, request, kernel):
     """`expert_ffn` at sdar30b_a3b_l6's widths (128 gated experts of
     2048 -> 768, top-8, bf16) for the 128 tokens of a diffusion forward:
     on one TPU three calls of jax's Pallas grouped matmul; elsewhere
@@ -142,7 +157,7 @@ def test_expert_layer_compiles_to_grouped_matmuls(topo, monkeypatch, kernel):
     from dml_tpu.inference.generate import expert_ffn
 
     if kernel:
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        request.getfixturevalue("chip_routes")
     e, d, f = 128, 2048, 768
     text, _ = compile_on_chip(
         topo,
@@ -209,12 +224,11 @@ def test_one_window_of_all_rows_lowers_to_the_text_it_had(
     assert hashlib.sha256(text.encode()).hexdigest() == sha
 
 
-def test_expert_layer_over_windows_compiles_with_its_loop(topo, monkeypatch):
+def test_expert_layer_over_windows_compiles_with_its_loop(topo, chip_routes):
     """A 1,024-token prefill row at joyai_llm_flash_ep16's widths (16 of
     256 gated experts held, top-8): windows of 640 rows under a loop whose
     trip count is read from the routing, three Pallas grouped matmuls in
     its body, and no [8,192, .] float32 array anywhere."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text, _ = compile_on_chip(
         topo, lambda *a: _a_share(*a), *_share_shapes(256, 16, (1, 1024)))
     assert text.count("tpu_custom_call") >= 3
@@ -258,7 +272,7 @@ def test_fused_normalize_compiles_fast(topo, shape, mode):
 
 
 @pytest.mark.parametrize("kv_quant", [False, True])
-def test_tp_sharded_lm_programs_compile(topo, monkeypatch, kv_quant):
+def test_tp_sharded_lm_programs_compile(topo, chip_routes, kv_quant):
     """The LM's prefill and decode step on a tp=4 mesh of the described
     chips, LM-phase widths (depth cut to 2 layers). GSPMD refuses to
     partition a Mosaic kernel, so both kernels must sit in a shard_map
@@ -271,8 +285,6 @@ def test_tp_sharded_lm_programs_compile(topo, monkeypatch, kv_quant):
     from dml_tpu.parallel.mesh import make_mesh
     from dml_tpu.parallel.sharding import partition_params
 
-    # the kernel switches ask the backend; the test answers for the chip
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = make_mesh(MeshSpec(dp=1, tp=4), devices=list(topo.devices))
     cfg = LMConfig(32000, 1024, 16, 2, 4096, dtype=jnp.bfloat16,
                    n_kv_heads=4, kv_quant=kv_quant)
@@ -308,21 +320,16 @@ def test_tp_sharded_lm_programs_compile(topo, monkeypatch, kv_quant):
     assert "tpu_custom_call" in text
 
 
-def test_chunk_program_of_a_grouped_bf16_config_holds_the_kernel(
-        topo, monkeypatch):
-    """The benchmark cell's decode program — `LMServer._chunk_impl`
-    itself, at mistral7b_widths_l8's widths and slot grid (depth cut to
-    1 layer) — compiles for the chip under the name the cell's
-    `trace_modules` looks for and holds the cache-attention kernel:
-    what `LMServer.kernel_report()["decode"]` reports on a chip. A
-    server cannot be built here (it allocates its cache on a device),
-    so the method runs on a bare instance holding what it reads."""
+def _chunk_program_text(topo, n_layers):
+    """`LMServer._chunk_impl` itself at mistral7b_widths_l8's widths
+    and slot grid, `n_layers` deep, compiled for the chip. A server
+    cannot be built here (it allocates its cache on a device), so the
+    method runs on a bare instance holding what it reads."""
     from dml_tpu.inference.generate import LMConfig, init_cache
     from dml_tpu.inference.lm_server import LMServer
     from dml_tpu.models.transformer import TransformerLM
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = LMConfig(32000, 4096, 32, 1, 14336, dtype=jnp.bfloat16,
+    cfg = LMConfig(32000, 4096, 32, n_layers, 14336, dtype=jnp.bfloat16,
                    n_kv_heads=8)
     slots, max_len = 16, 4096
     srv = object.__new__(LMServer)
@@ -341,14 +348,39 @@ def test_chunk_program_of_a_grouped_bf16_config_holds_the_kernel(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
     cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))
     vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
-    text = jax.jit(srv._chunk_impl).lower(
+    return jax.jit(srv._chunk_impl).lower(
         params, cache, vec, vec, vec).compile().as_text()
+
+
+def test_chunk_program_of_a_grouped_bf16_config_holds_the_kernel(
+        topo, chip_routes):
+    """The benchmark cell's decode program (depth cut to 1 layer)
+    compiles for the chip under the name the cell's `trace_modules`
+    looks for and holds the cache-attention kernel: what
+    `LMServer.kernel_report()["decode"]` reports on a chip."""
+    text = _chunk_program_text(topo, 1)
     assert "tpu_custom_call" in text
     assert text.lstrip().startswith("HloModule jit__chunk_impl")
 
 
+def test_chunk_program_makes_its_work_list_once_a_step(topo, chip_routes):
+    """`decode_attention` builds its work list in every call, one a
+    layer, from lengths that are a step's: the compiler merges the
+    copies, so every layer's kernel takes the SAME count, lengths,
+    slots and blocks (its four scalar-prefetch operands) and a step
+    pays for one list, not one a layer."""
+    import re
+
+    text = _chunk_program_text(topo, 2)
+    calls = re.findall(
+        r"custom-call\(([^)]*)\)[^\n]*tpu_custom_call", text)
+    assert len(calls) == 2
+    lists = {tuple(c.split(", ")[:4]) for c in calls}
+    assert len(lists) == 1, lists
+
+
 def test_chunk_program_of_the_state_space_config_fits_the_chip(
-        topo, monkeypatch):
+        topo, chip_routes):
     """The third benchmark configuration's decode program —
     `LMServer._chunk_impl` at nemotron3_super_l11_ep4's published widths,
     pattern and slot grid (9.3 GB of weights, 64 slots of K/V rows, conv
@@ -363,7 +395,6 @@ def test_chunk_program_of_the_state_space_config_fits_the_chip(
     from dml_tpu.inference.lm_backend import lm_spec_parts
     from dml_tpu.inference.lm_server import LMServer
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            "nemotron3_super_l11_ep4.json")) as f:
@@ -399,7 +430,7 @@ def test_chunk_program_of_the_state_space_config_fits_the_chip(
 
 
 def test_diffusion_dispatch_of_the_sdar_config_holds_the_kernels(
-        topo, monkeypatch):
+        topo, chip_routes):
     """The other benchmark configuration's program: `LMServer.
     _diffuse_impl` itself at sdar30b_a3b_l6's widths and slot grid (depth
     cut to 1 layer, 2 blocks a dispatch), declared by `lm_spec_parts` as
@@ -410,7 +441,6 @@ def test_diffusion_dispatch_of_the_sdar_config_holds_the_kernels(
     from dml_tpu.inference.lm_backend import lm_spec_parts
     from dml_tpu.inference.lm_server import BlockDiffusion, LMServer
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     spec = {
         "vocab_size": 151936, "d_model": 2048, "n_heads": 32,
         "n_kv_heads": 4, "head_dim": 128, "n_layers": 1,
@@ -482,7 +512,7 @@ def test_flash_attention_with_narrower_values_compiles(topo):
 
 
 def test_latent_attention_programs_of_the_joyai_config_fit_the_chip(
-        topo, monkeypatch):
+        topo, chip_routes):
     """The fourth benchmark configuration's two programs at
     joyai_llm_flash_ep16's published widths, slot grid and chunk, the depth
     cut to the dense layer and two expert layers (the full depth compiles
@@ -499,7 +529,6 @@ def test_latent_attention_programs_of_the_joyai_config_fit_the_chip(
     from dml_tpu.inference.lm_backend import lm_spec_parts
     from dml_tpu.inference.lm_server import LMServer
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            "joyai_llm_flash_ep16.json")) as f:
