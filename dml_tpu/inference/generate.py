@@ -30,11 +30,13 @@ prefill so the dense-dispatch intermediate stays bounded).
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.transformer import rope
@@ -153,6 +155,140 @@ class LatentConfig:
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN's numbers (arXiv:2309.00071, as the published configs'
+    `rope_parameters` spell them): frequencies that turn fewer than
+    `beta_slow` times over `original_max_position` positions are
+    divided by `factor`, those that turn more than `beta_fast` times
+    are kept, the ones between are blended by a linear ramp; cos and
+    sin are multiplied by `attention_factor` (None: 0.1 ln(factor) +
+    1)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    def __post_init__(self):
+        if self.factor < 1 or self.original_max_position < 1 or not (
+                0 < self.beta_slow < self.beta_fast):
+            raise ValueError(f"YaRN numbers {self}")
+
+
+@dataclass(frozen=True)
+class RopeConfig:
+    """One rope: the base `theta`, the columns it rotates (the FIRST
+    `rotary_dim` of a head, paired (i, i + rotary_dim / 2); None = the
+    whole head; the others pass as projected) and YaRN's scaling of the
+    frequencies, if any. `rope_table` has the numbers."""
+
+    theta: float = 10000.0
+    rotary_dim: Optional[int] = None
+    yarn: Optional[YarnConfig] = None
+
+    def __post_init__(self):
+        r = self.rotary_dim
+        if self.theta <= 0 or (r is not None and (r < 2 or r % 2)):
+            raise ValueError(f"rope {self}")
+
+    @property
+    def plain(self) -> bool:
+        """Whether this is `theta ** (-2i / D)` over the whole head:
+        what `rope` and `rope_interleaved` compute from the base."""
+        return self.rotary_dim is None and self.yarn is None
+
+
+@functools.lru_cache(maxsize=None)
+def rope_table(rope: RopeConfig, head_dim: int) -> Tuple[np.ndarray, float]:
+    """(`rotary_dim / 2` frequencies in float32, the factor on cos and
+    sin) of `rope` in a head of `head_dim` columns, computed once a
+    (rope, head size) in float64. Plain: `f_i = theta ** (-2i / d)`
+    over the d rotated columns, factor 1. YaRN: `c(r) = d ln(L / (2 pi
+    r)) / (2 ln theta)` is the index of the frequency that turns r
+    times over the L original positions; `low = floor(c(beta_fast))`,
+    `high = ceil(c(beta_slow))`, clipped to [0, d / 2 - 1]; `ramp_i =
+    clip((i - low) / (high - low), 0, 1)`; `freq_i = (f_i / factor)
+    ramp_i + f_i (1 - ramp_i)`."""
+    d = head_dim if rope.rotary_dim is None else rope.rotary_dim
+    if d > head_dim:
+        raise ValueError(f"{d} rotated columns in a head of {head_dim}")
+    half = d // 2
+    f = rope.theta ** (-np.arange(half, dtype=np.float64) / half)
+    y = rope.yarn
+    if y is None:
+        return f.astype(np.float32), 1.0
+
+    def turns(r):
+        return d * math.log(y.original_max_position / (2 * math.pi * r)) / (
+            2 * math.log(rope.theta))
+
+    low = min(max(math.floor(turns(y.beta_fast)), 0), half - 1)
+    high = min(max(math.ceil(turns(y.beta_slow)), 0), half - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    factor = (0.1 * math.log(y.factor) + 1.0 if y.attention_factor is None
+              else y.attention_factor)
+    return (f / y.factor * ramp + f * (1 - ramp)).astype(np.float32), factor
+
+
+def rope_by_table(x: jax.Array, positions: jax.Array, freqs: np.ndarray,
+                  factor: float = 1.0) -> jax.Array:
+    """`rope` from a frequency table (`rope_table`): the first `2
+    len(freqs)` columns of x [B, T, H, D] rotate, column i with column
+    i + len(freqs), by `positions * freqs[i]`, cos and sin times
+    `factor`; the columns past them pass. positions [T] or [B, T]."""
+    half = len(freqs)
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(freqs)
+    if positions.ndim == 1:
+        angles = angles[None]
+    cos = (jnp.cos(angles) * factor)[:, :, None, :]
+    sin = (jnp.sin(angles) * factor)[:, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, x[..., 2 * half:]],
+        axis=-1).astype(x.dtype)
+
+
+@dataclass(frozen=True)
+class AttentionType:
+    """What the attention layers of one type share: the query heads
+    (over the config's `kv_heads` of `head_dim`), the rope, the window
+    (None: position i attends every j <= i, and a slot caches a row a
+    token; W: i attends j iff 0 <= i - j < W, and a slot caches a RING
+    of `W` rows, row p mod W holding position p: `init_cache`), and
+    whether a sigmoid gate a head, a linear map of the layer's normed
+    input (`head_gate`), scales the heads' outputs before `proj`."""
+
+    n_heads: int
+    rope: RopeConfig = RopeConfig()
+    window: Optional[int] = None
+    gate: bool = False
+
+    def __post_init__(self):
+        if self.n_heads < 1 or (self.window is not None and self.window < 1):
+            raise ValueError(f"attention layer type {self}")
+
+
+@dataclass(frozen=True)
+class AttentionLayers:
+    """A stack whose attention layers differ by TYPE: the types by name
+    and each layer's type, ONE description that `LMConfig.attn`
+    answers "layer i's heads, rope, window, gate" from."""
+
+    types: Tuple[Tuple[str, AttentionType], ...]
+    layers: Tuple[str, ...]
+
+    def __post_init__(self):
+        names = [n for n, _ in self.types]
+        if len(set(names)) != len(names) or set(self.layers) - set(names):
+            raise ValueError(
+                f"attention layers {self.layers} of types {names}")
+
+    def of(self, i: int) -> AttentionType:
+        return dict(self.types)[self.layers[i]]
+
+
+@dataclass(frozen=True)
 class LMConfig:
     """Shape config mirroring TransformerLM's fields.
 
@@ -162,7 +298,12 @@ class LMConfig:
     latent (`latent` holds its widths), one row of `latent.row_width`
     values, the normalised latent and the roped shared key
     (`_latent_attention`), which both forms of that attention read. A
-    state-space layer caches no rows (`init_cache`).
+    state-space layer caches no rows (`init_cache`). Under
+    `attention_layers` the grouped layers go by TYPE (`AttentionType`):
+    a full layer caches a K and a V row a token as above, `max_len`
+    rows a slot; a window layer the last `window` tokens' alone, a ring
+    of that many rows a slot whatever `max_len` is; heads, rope and
+    the output gate are the type's too (`attn`, `layer_rows`).
 
     `kv_quant=True` stores the KV cache as int8 with one f32 scale per
     (position, kv-head) — ~1.9x less cache HBM than bf16, i.e. ~2x the
@@ -215,10 +356,38 @@ class LMConfig:
     activation: str = "silu"
     latent: Optional[LatentConfig] = None  # latent attention's widths
     rope_pairing: str = "half"  # of `ROPE_PAIRINGS`
+    # attention layers that differ by type (heads, rope, window, gate);
+    # None = every layer `n_heads` heads, `rope_theta`, no window, no gate
+    attention_layers: Optional[AttentionLayers] = None
 
     def __post_init__(self):
         if self.rope_pairing not in ROPE_PAIRINGS:
             raise ValueError(f"unknown rope pairing {self.rope_pairing!r}")
+        al = self.attention_layers
+        if al is not None:
+            if len(al.layers) != self.n_layers:
+                raise ValueError(
+                    f"attention_layers names {len(al.layers)} layers, "
+                    f"n_layers is {self.n_layers}")
+            if (self.latent is not None or self.layer_pattern is not None
+                    or self.attention_mask != "causal" or not self.rope
+                    or self.qk_norm or self.rope_pairing != "half"):
+                raise ValueError(
+                    "attention layers by type are grouped attention under "
+                    "the causal mask in a stack of classic blocks, rope in "
+                    "halves: no latent attention, layer_pattern, "
+                    "block_causal mask, qk_norm, interleaved pairing, and "
+                    "rope on")
+            if self.has_ring and self.kv_quant:
+                raise ValueError(
+                    "a window layer's ring of rows is cached unquantized: "
+                    "no kv_quant")
+            for _, t in al.types:
+                if t.n_heads % self.kv_heads:
+                    raise ValueError(
+                        f"{self.kv_heads} KV heads do not divide a layer "
+                        f"type's {t.n_heads} query heads")
+                rope_table(t.rope, self.head_dim)  # raises on its widths
         if self.latent is not None and (
                 self.kv_quant or self.qk_norm or self.n_kv_heads is not None
                 or self.d_head is not None or self.layer_pattern is not None
@@ -274,6 +443,29 @@ class LMConfig:
         return "M" in (self.layer_pattern or "")
 
     @property
+    def has_ring(self) -> bool:
+        """Whether some layer caches a ring of its window's rows: state
+        that is no row a token either (a ring cannot be cut by token
+        from its start, nor rolled back past what it overwrote)."""
+        al = self.attention_layers
+        return al is not None and any(
+            al.of(i).window is not None for i in range(self.n_layers))
+
+    def attn(self, i: int) -> AttentionType:
+        """Attention layer i's type: its heads, rope, window and gate."""
+        if self.attention_layers is None:
+            return AttentionType(self.n_heads, RopeConfig(self.rope_theta))
+        return self.attention_layers.of(i)
+
+    def layer_rows(self, i: int, max_len: int) -> int:
+        """Cache rows a slot holds in attention layer i: the ring of a
+        window layer (`window` rows: position p lives in row p mod
+        window, so the window's 512 are there and no other; a window of
+        `max_len` and more is a full layer's plane), else `max_len`."""
+        w = self.attn(i).window
+        return max_len if w is None else min(w, max_len)
+
+    @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
 
@@ -298,6 +490,10 @@ class LMConfig:
 CACHE_LEAVES = {
     "k": ("kv", 2), "v": ("kv", 2), "k_q": ("kv", 2), "v_q": ("kv", 2),
     "k_s": ("kv", 3), "v_s": ("kv", 3), "latent": ("latent", 2),
+    # a window layer's ring: its rows are no row a token (row r holds
+    # the newest position p with p mod rows == r), so it has leaves of
+    # its own and nothing that cuts rows by token takes it for a plane
+    "k_ring": ("kv_window", 2), "v_ring": ("kv_window", 2),
     "conv": ("conv", None), "ssm": ("scan", None),
 }
 
@@ -313,7 +509,13 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
     (the normalised latent | the roped shared key | zeros to whole
     lane tiles: `LatentConfig`), in the layout of a single-KV-head
     plane so that everything that cuts, copies or streams rows treats
-    it as one.
+    it as one. A WINDOW layer (`LMConfig.attention_layers`): `k_ring`
+    and `v_ring` [B, KV, W, D], a ring of the window's W rows a slot
+    whatever `max_len` is (`LMConfig.layer_rows`): position p is
+    written at row p mod W, over position p - W, which no later query
+    attends; the rows a slot at length n holds are its last min(n, W)
+    positions, in ring order (softmax asks no order of its keys, and a
+    key carries its rope).
 
     Layout is head-major ([B, KV, T, D], not [B, T, KV, D]): each
     head's rows are a contiguous [T, D] plane, which is what the
@@ -333,7 +535,12 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
     shape = (batch, cfg.kv_heads, max_len, cfg.head_dim)
     sshape = (batch, cfg.kv_heads, 1, max_len)
 
-    def layer(kind):
+    def layer(i, kind):
+        if cfg.attn(i).window is not None and kind is None:
+            ring = (batch, cfg.kv_heads, cfg.layer_rows(i, max_len),
+                    cfg.head_dim)
+            return {"k_ring": jnp.zeros(ring, cfg.dtype),
+                    "v_ring": jnp.zeros(ring, cfg.dtype)}
         if cfg.latent is not None:
             return {"latent": jnp.zeros(
                 (batch, 1, max_len, cfg.latent.row_stride), cfg.dtype)}
@@ -359,27 +566,36 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
             "v": jnp.zeros(shape, cfg.dtype),
         }
 
-    layers = {f"block_{i}": layer(k) for i, k in enumerate(cfg.kinds)}
+    layers = {f"block_{i}": layer(i, k) for i, k in enumerate(cfg.kinds)}
     return {name: lay for name, lay in layers.items() if lay is not None}
 
 
-def cache_rows(cache: Dict[str, Any]) -> int:
-    """Rows a slot holds (`max_len`), read off the first leaf that has
-    rows (`CACHE_LEAVES`); 0 for a cache of state alone."""
-    for lay in cache.values():
+def cache_rows(cache: Dict[str, Any], name: Optional[str] = None) -> int:
+    """Rows a slot holds in layer `name` of a cache (`max_len`, or a
+    window layer's ring), read off its first leaf that has rows
+    (`CACHE_LEAVES`). Without a name: the most any layer holds, which
+    is `max_len` wherever a layer caches a row a token (a stack of
+    window layers alone holds no more than its rings); 0 for a cache
+    of state alone."""
+    def rows(lay):
         for key, leaf in lay.items():
             axis = CACHE_LEAVES[key][1]
             if axis is not None:
                 return leaf.shape[axis]
-    return 0
+        return 0
+
+    if name is not None:
+        return rows(cache[name])
+    return max((rows(lay) for lay in cache.values()), default=0)
 
 
 def state_bytes(cache: Dict[str, Any]) -> Dict[str, int]:
     """Bytes of a slot-grid cache by kind of leaf: `kv` (grouped
-    attention's rows and scales), `latent` (latent attention's rows),
-    `conv` and `scan` (a state-space layer's convolution window and
-    recurrent state)."""
-    out = {"kv": 0, "latent": 0, "conv": 0, "scan": 0}
+    attention's rows and scales, a row a token), `kv_window` (the
+    window layers' rings, which do not grow with `max_len`), `latent`
+    (latent attention's rows), `conv` and `scan` (a state-space layer's
+    convolution window and recurrent state)."""
+    out = {"kv": 0, "kv_window": 0, "latent": 0, "conv": 0, "scan": 0}
     for lay in cache.values():
         for key, leaf in lay.items():
             out[CACHE_LEAVES[key][0]] += int(leaf.size) * leaf.dtype.itemsize
@@ -973,17 +1189,31 @@ def _latent_attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed):
         return attn @ kernel_of(blk["proj"], dt), rows
 
 
-def _attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed=False):
+def _attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed=False,
+               lay: Optional[AttentionType] = None):
     """The attention mixer on normalised `y`: (its output through
     `proj`, k, v); under latent attention (the rows to cache, None) in
     k's and v's place, by the form `absorbed` names
-    (`_latent_attention`)."""
+    (`_latent_attention`). `lay` is the layer's type (`LMConfig.attn`;
+    None = the one type of a stack without `attention_layers`): its
+    query heads over the config's
+    KV heads, its rope (from the base, or from `rope_table` where the
+    rope rotates part of a head or scales its frequencies), and its
+    gate: `sigmoid(head_gate y)`, a number a head from the same normed
+    input, on the heads' outputs before `proj`."""
     if cfg.latent is not None:
         out, rows = _latent_attention(
             blk, cfg, y, positions, attn_fn, absorbed)
         return out, rows, None
     b, t = y.shape[:2]
-    h, hd, kv, qw = cfg.n_heads, cfg.head_dim, cfg.kv_heads, cfg.q_width
+    if lay is None:
+        if cfg.attention_layers is not None:
+            raise ValueError(
+                "a layer of a stack whose attention layers go by type "
+                "comes with its type (`LMConfig.attn`)")
+        lay = cfg.attn(0)
+    hd, kv = cfg.head_dim, cfg.kv_heads
+    h, qw, ro = lay.n_heads, lay.n_heads * hd, lay.rope
     with part("attn_proj"):
         qkv = y @ kernel_of(blk["qkv"], cfg.dtype)  # [B, T, qw + 2*kv*hd]
         q = qkv[..., :qw].reshape(b, t, h, hd)
@@ -992,14 +1222,23 @@ def _attention(blk, cfg: LMConfig, y, positions, attn_fn, absorbed=False):
         if cfg.qk_norm:
             q = _rms_norm(q, blk["q_norm"]["scale"], cfg.dtype, cfg.norm_eps)
             k = _rms_norm(k, blk["k_norm"]["scale"], cfg.dtype, cfg.norm_eps)
-        if cfg.rope:
-            q = _rope_of(cfg)(q, positions, cfg.rope_theta)
-            k = _rope_of(cfg)(k, positions, cfg.rope_theta)
+        if cfg.rope and ro.plain:
+            q = _rope_of(cfg)(q, positions, ro.theta)
+            k = _rope_of(cfg)(k, positions, ro.theta)
+        elif cfg.rope:
+            table = rope_table(ro, hd)
+            q = rope_by_table(q, positions, *table)
+            k = rope_by_table(k, positions, *table)
         v = v.reshape(b, t, kv, hd)
     # k/v carry kv heads; the closure decides, and names its own parts
     # (`attn_core`, `cache_write`)
     attn = attn_fn(q, k, v)
     with part("attn_proj"):
+        if lay.gate:
+            gate = jax.nn.sigmoid(jnp.matmul(
+                y, kernel_of(blk["head_gate"], cfg.dtype),
+                preferred_element_type=jnp.float32))  # [B, T, H]
+            attn = attn.astype(jnp.float32) * gate[..., None]
         attn = attn.reshape(b, t, qw).astype(cfg.dtype)
         return attn @ kernel_of(blk["proj"], cfg.dtype), k, v
 
@@ -1044,6 +1283,7 @@ def _apply_block(
     kind: Optional[str] = None,
     ssm_fn=None,  # (ssm subtree, y [B,T,d]) -> [B,T,d]
     absorbed: bool = False,  # latent attention's form (`_latent_attention`)
+    lay: Optional[AttentionType] = None,  # the layer's type (`_attention`)
 ) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
     """ONE layer — the single copy of the layer math that decode (T=1,
     cache attention, one recurrence step) and prefill (T=Tp, flash
@@ -1075,7 +1315,7 @@ def _apply_block(
     if kind is None:
         with part("attn_proj"):
             y = _rms_norm(x, blk["ln_attn"]["scale"], cfg.dtype, cfg.norm_eps)
-        out, k, v = _attention(blk, cfg, y, positions, attn_fn, absorbed)
+        out, k, v = _attention(blk, cfg, y, positions, attn_fn, absorbed, lay)
         x = x + out
         with part(ffn):
             y = _rms_norm(x, blk["ln_mlp"]["scale"], cfg.dtype, cfg.norm_eps)
@@ -1157,6 +1397,15 @@ def _kernel_on_mesh(kernel, mesh: Optional[Mesh], in_specs, out_specs):
     )
 
 
+def _typed_on_one_device(cfg: LMConfig, mesh: Optional[Mesh]) -> None:
+    """Raise for a stack whose attention layers go by type under a
+    mesh: `heads_axis` places ONE head count."""
+    if cfg.attention_layers is not None and mesh is not None:
+        raise ValueError(
+            "attention layers by type are served on one device: no rule "
+            "divides layers of different head counts over a mesh yet")
+
+
 def uses_decode_kernel() -> bool:
     """Whether `batched_decode_step` hands cache attention to the
     Pallas kernel (ops/decode_attention.py): on a TPU, for every cache
@@ -1203,13 +1452,16 @@ def uses_decode_kernel() -> bool:
 
 
 def decode_block_rows(
-    cfg: LMConfig, max_len: int, mesh: Optional[Mesh] = None
+    cfg: LMConfig, max_len: int, mesh: Optional[Mesh] = None,
+    layer: int = 0,
 ) -> Optional[int]:
     """Cache rows per k-block that `batched_decode_step`'s kernel
-    fetches for a `max_len`-row cache of `cfg` (per device under
-    `mesh`), or None on the einsum route, which streams every row.
-    For a caller that reckons the rows a step reads from the slots'
-    lengths (LMServer's `kv_rows` accounting)."""
+    fetches in attention layer `layer` of a `max_len`-row cache of
+    `cfg` (per device under `mesh`): a window layer's plane is its
+    ring (`LMConfig.layer_rows`), so its blocks go by the ring's rows.
+    None on the einsum route, which streams every row. For a caller
+    that reckons the rows a step reads from the slots' lengths
+    (LMServer's `kv_rows` accounting)."""
     if not uses_decode_kernel():
         return None
     from ..ops.decode_attention import block_rows
@@ -1221,7 +1473,8 @@ def decode_block_rows(
         mesh, cfg.n_heads, cfg.kv_heads) else 1
     return block_rows(
         cfg.kv_heads // tp, cfg.head_dim,
-        jnp.int8 if cfg.kv_quant else cfg.dtype, max_len,
+        jnp.int8 if cfg.kv_quant else cfg.dtype,
+        cfg.layer_rows(layer, max_len),
     )
 
 
@@ -1311,10 +1564,16 @@ def batched_decode_step(
     A state-space layer advances its slot's state by the one position
     (`ssm_mixer`'s step form), whatever the slot holds: an empty
     slot's state is garbage nobody reads, overwritten whole by the
-    next placement. `experts` is `_apply_block`'s."""
+    next placement. `experts` is `_apply_block`'s.
+
+    A WINDOW layer (`LMConfig.attention_layers`) writes its row at
+    `pos mod W` of its ring of W rows and attends the ring's first
+    `min(lengths, W)` rows: the slot's last min(length, W) positions,
+    the one just written among them, and no other (`init_cache`). Same
+    kernel, same oracle, a shorter plane."""
     hd = cfg.head_dim
     b = tokens.shape[0]
-    grp = cfg.n_heads // cfg.kv_heads
+    _typed_on_one_device(cfg, mesh)
     with part("embed"):
         x = params["embed"]["embedding"][tokens].astype(cfg.dtype)[:, None, :]
     positions = pos[:, None]  # [B, 1] — rope's per-example form
@@ -1356,14 +1615,31 @@ def batched_decode_step(
             new_cache[name] = {"latent": leaf}
             return out
 
-        def attn_fn(q, k, v, name=name):
+        typ = cfg.attn(i)
+        ring = (cfg.layer_rows(i, max_len) if typ.window is not None
+                else None)
+
+        def attn_fn(q, k, v, name=name, ring=ring):
             # k/v arrive [B, 1, KV, D]; the cache is head-major
             # (`_write_rows` on how the rows are written)
+            grp = q.shape[2] // cfg.kv_heads
+            n_rows, vmask = lengths, valid
             with part("cache_write"):
                 upd = functools.partial(_write_rows, pos=pos)
                 kh = jnp.swapaxes(k, 1, 2)  # [B, KV, 1, D]
                 vh = jnp.swapaxes(v, 1, 2)
-                if cfg.kv_quant:
+                if ring is not None:
+                    # the ring's rows < min(lengths[b], W), written at
+                    # pos mod W
+                    n_rows = jnp.minimum(lengths, ring)
+                    vmask = jnp.arange(ring)[None, :] < lengths[:, None]
+                    upd = functools.partial(_write_rows, pos=pos % ring)
+                    ck = upd(cache[name]["k_ring"], kh.astype(cfg.dtype),
+                             axis=2)
+                    cv = upd(cache[name]["v_ring"], vh.astype(cfg.dtype),
+                             axis=2)
+                    new_cache[name] = {"k_ring": ck, "v_ring": cv}
+                elif cfg.kv_quant:
                     kq, ks = _kv_quantize(kh)
                     vq, vs = _kv_quantize(vh)
                     lay = {
@@ -1393,13 +1669,13 @@ def batched_decode_step(
                         lay["v_q"], jnp.swapaxes(lay["v_s"], 2, 3)
                     )
                 elif use_kernel:
-                    return kernel(q, ck, cv, lengths)
+                    return kernel(q, ck, cv, n_rows)
                 qg = q.astype(jnp.float32).reshape(
                     b, 1, cfg.kv_heads, grp, hd)
                 s = jnp.einsum(
                     "bqkgd,bktd->bkgqt", qg, ck.astype(jnp.float32)
                 ) * (hd**-0.5)
-                vmask = valid[:, None, None, None, :]
+                vmask = vmask[:, None, None, None, :]
                 s = jnp.where(vmask, s, -1e30)
                 # a live slot's p is already exactly 0 on dead rows; the
                 # select makes an EMPTY slot (all rows dead, softmax
@@ -1407,12 +1683,12 @@ def batched_decode_step(
                 p = jnp.where(vmask, jax.nn.softmax(s, axis=-1), 0.0)
                 attn = jnp.einsum(
                     "bkgqt,bktd->bqkgd", p, cv.astype(jnp.float32))
-                return attn.reshape(b, 1, cfg.n_heads, hd)
+                return attn.reshape(b, 1, q.shape[2], hd)
 
         x, _, _ = _apply_block(
             params[name], cfg, x, positions,
             attn_fn if cfg.latent is None else latent_fn, experts, mesh,
-            kind, ssm_fn, absorbed=True)
+            kind, ssm_fn, absorbed=True, lay=typ)
 
     return _head(params, cfg, x), new_cache
 
@@ -1482,6 +1758,17 @@ def batched_block_step(
         raise ValueError(
             "the multi-token cached forward cannot roll a state-space "
             "layer's state back; speculation and block diffusion need it to")
+    if cfg.attention_layers is not None:
+        # T rows written at once overwrite positions the step's first
+        # queries still attend (a ring holds the window's rows and no
+        # more), and a rejected draft's rows have overwritten what a
+        # rollback would need; nor does this step take a layer's heads,
+        # rope and gate from its type yet
+        raise ValueError(
+            "the multi-token cached forward serves no stack of attention "
+            "layers by type: a window layer's ring holds the window and "
+            "no more, so a step of several rows overwrites what its "
+            "first rows attend")
     with part("embed"):
         x = params["embed"]["embedding"][tokens].astype(cfg.dtype)  # [B,T,d]
     max_len = cache_rows(cache)
@@ -1603,6 +1890,17 @@ def batched_verify_step(
     return batched_block_step(params, cfg, cache, tokens, pos, mesh=mesh)
 
 
+def ring_positions(lengths: jax.Array, rows: int) -> jax.Array:
+    """[B, rows] int32: the position ring row r of a slot holds after
+    `lengths[b]` positions were written at p mod rows each: the newest
+    p < length with p mod rows = r; negative where the row is still
+    unwritten (r >= length: behind every mask, as `min(length, rows)`
+    rows are attended)."""
+    last = lengths[:, None].astype(jnp.int32) - 1
+    r = jnp.arange(rows, dtype=jnp.int32)[None, :]
+    return last - (last - r) % rows
+
+
 def prefill(
     params: Dict[str, Any],
     cfg: LMConfig,
@@ -1634,7 +1932,14 @@ def prefill(
     a `logits_index` every row's state is taken at its own length,
     `logits_index + 1` (`ssm_mixer`'s `lengths`): the cache a padded
     row hands back holds, beside its K/V rows, the convolution window
-    and scan state of the unpadded prompt.
+    and scan state of the unpadded prompt. A WINDOW layer
+    (`LMConfig.attention_layers`) attends under the banded mask (`0 <=
+    i - j < W`; the flash kernel visits the band's k-blocks alone) and
+    hands back its RING, filled as decode would have left it after the
+    row's own length n (`logits_index + 1`, else Tp): ring row r holds
+    the newest position p < n with p mod W = r (`ring_positions`), so
+    a row padded to a bucket caches the last W positions of the
+    unpadded prompt, and the pad tail none.
 
     The old path pushed the prompt through the decode scan one token
     at a time — O(Tp) sequential [B,1] steps that leave the MXU idle.
@@ -1649,7 +1954,7 @@ def prefill(
         x = params["embed"]["embedding"][prompt].astype(cfg.dtype)  # [B,Tp,d]
     positions = jnp.arange(tp)
     pad = max_len - tp
-    grp = cfg.n_heads // cfg.kv_heads
+    _typed_on_one_device(cfg, mesh)
 
     h_spec = P(None, None, heads_axis(mesh, cfg.n_heads), None)  # [B,T,H,D]
     mask = {"mask_block": cfg.mask_block} if cfg.mask_block > 1 else {}
@@ -1658,20 +1963,24 @@ def prefill(
         in_specs=(h_spec, h_spec, h_spec), out_specs=h_spec,
     )
 
-    def attn_fn(q, k, v):
+    def attn_fn(q, k, v, window=None):
         # flash kernel is head-symmetric: broadcast GQA kv heads to
         # full heads for the prefill pass (the cache below keeps the
         # compact layout _apply_block returned)
+        grp = q.shape[2] // cfg.kv_heads
         with part("attn_core"):
             if grp > 1:
                 k = jnp.repeat(k, grp, axis=2)
                 v = jnp.repeat(v, grp, axis=2)
+            if window is not None:  # a window layer: the band alone
+                return flash_attention(q, k, v, causal=True, window=window)
             return flash(q, k, v)
 
     # rows' own lengths, where the caller gave them: a state-space
-    # layer must not take a padded position into its state
+    # layer must not take a padded position into its state, nor a
+    # window layer's ring a padded position's row
     lengths = None
-    if cfg.has_state and logits_index is not None:
+    if (cfg.has_state or cfg.has_ring) and logits_index is not None:
         lengths = jnp.broadcast_to(
             jnp.asarray(logits_index, jnp.int32) + 1, (b,))
 
@@ -1684,13 +1993,26 @@ def prefill(
             out, cache[name] = ssm_mixer(p, cfg, y, lengths=lengths)
             return out
 
+        typ = cfg.attn(i)
+        window = typ.window
         x, k, v = _apply_block(
-            params[name], cfg, x, positions, attn_fn, experts=experts,
-            mesh=mesh, kind=kind, ssm_fn=ssm_fn,
+            params[name], cfg, x, positions,
+            attn_fn if window is None else functools.partial(
+                attn_fn, window=window),
+            experts=experts, mesh=mesh, kind=kind, ssm_fn=ssm_fn, lay=typ,
         )
         if k is None:
             continue
         with part("cache_write"):  # the call's rows in the cache's layout
+            if window is not None:
+                at = ring_positions(
+                    jnp.full((b,), tp, jnp.int32) if lengths is None
+                    else lengths, cfg.layer_rows(i, max_len))  # [B, R]
+                take = lambda u: jnp.swapaxes(jnp.take_along_axis(
+                    u, jnp.maximum(at, 0)[:, :, None, None], axis=1),
+                    1, 2).astype(cfg.dtype)  # [B, Tp, KV, D] -> [B, KV, R, D]
+                cache[name] = {"k_ring": take(k), "v_ring": take(v)}
+                continue
             if cfg.latent is not None:  # k: the rows to cache [B, Tp, W]
                 cache[name] = {
                     "latent": jnp.pad(_latent_rows(cfg, k), pad4)}

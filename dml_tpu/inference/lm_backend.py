@@ -36,7 +36,8 @@ from ..jobs.cost_model import ModelCost, lm_request_forwards
 from ..tracing import current_all_ctxs
 from .generate import (
     ACTIVATIONS, ATTENTION_KINDS, LAYER_KINDS, ROPE_PAIRINGS, ROUTER_SCORING,
-    LatentConfig, LMConfig, SSMConfig,
+    AttentionLayers, AttentionType, LatentConfig, LMConfig, RopeConfig,
+    SSMConfig, YarnConfig,
 )
 from .lm_server import REMASKING, BlockDiffusion, LMDriver, LMServer
 
@@ -110,7 +111,7 @@ _ARCH_KEYS = (
     "block_length", "param_dtype", "layer_pattern", "ssm", "rope",
     "norm_eps", "router", "expert_latent", "shared_expert_d_ff",
     "activation", "attention", "latent_attention", "rope_pairing",
-    "dense_layers",
+    "dense_layers", "attention_layers",
 )
 _SSM_KEYS = ("heads", "head_dim", "state", "groups", "conv_kernel", "chunk")
 _ROUTER_KEYS = ("scoring", "bias", "scale")
@@ -123,6 +124,67 @@ _LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
 #: `time_step_max`, `time_step_floor`, which shape the initialisation only
 _DT_INIT = (0.001, 0.1, 1e-4)
 _DTYPES = ("bfloat16", "float32")
+#: `attention_layers`: the attention layers' types as data. `layers`
+#: names each layer's type, `types` describes each name once: its query
+#: heads, its rope (`theta`; `rotary_dim`, the columns of a head it
+#: rotates; `yarn`, the frequencies' scaling), its `window` (a type that
+#: has one caches a ring of that many rows a slot), its `gate`
+_LAYER_TYPE_KEYS = ("n_heads", "rope", "window", "gate")
+_ROPE_KEYS = ("theta", "rotary_dim", "yarn")
+_YARN_KEYS = ("factor", "original_max_position", "beta_fast", "beta_slow",
+              "attention_factor")
+HEAD_GATES = ("per_head",)
+
+
+def _attention_layers(al: Any, n_layers: int) -> AttentionLayers:
+    """`lm_spec["attention_layers"]` checked and as the config holds it."""
+    if (not isinstance(al, dict) or set(al) != {"layers", "types"}
+            or not isinstance(al["types"], dict) or not al["types"]):
+        raise ValueError(
+            f"attention_layers {al!r}: `layers` (each layer's type by "
+            f"name) and `types` (each name's description)")
+    layers = tuple(al["layers"])
+    if len(layers) != n_layers or set(layers) - set(al["types"]):
+        raise ValueError(
+            f"attention_layers names {len(layers)} layers of types "
+            f"{sorted(set(layers))}: n_layers is {n_layers}, the types "
+            f"described are {sorted(al['types'])}")
+    types = []
+    for name, t in al["types"].items():
+        if not isinstance(t, dict) or set(t) - set(_LAYER_TYPE_KEYS) or (
+                "n_heads" not in t):
+            raise ValueError(
+                f"attention layer type {name!r} {t!r}: n_heads, and of "
+                f"{_LAYER_TYPE_KEYS} no other key")
+        rope = dict(t.get("rope") or {})
+        yarn = rope.get("yarn")
+        if set(rope) - set(_ROPE_KEYS) or (yarn is not None and (
+                not isinstance(yarn, dict) or set(yarn) - set(_YARN_KEYS)
+                or not {"factor", "original_max_position"} <= set(yarn))):
+            raise ValueError(
+                f"attention layer type {name!r}'s rope {rope!r}: keys of "
+                f"{_ROPE_KEYS}, a yarn of {_YARN_KEYS}")
+        if t.get("gate") not in (None,) + HEAD_GATES:
+            raise ValueError(
+                f"attention layer type {name!r}'s gate {t['gate']!r} "
+                f"({' | '.join(HEAD_GATES)})")
+        types.append((name, AttentionType(
+            n_heads=int(t["n_heads"]),
+            rope=RopeConfig(
+                theta=float(rope.get("theta", 10000.0)),
+                rotary_dim=(None if rope.get("rotary_dim") is None
+                            else int(rope["rotary_dim"])),
+                yarn=None if yarn is None else YarnConfig(
+                    factor=float(yarn["factor"]),
+                    original_max_position=int(yarn["original_max_position"]),
+                    beta_fast=float(yarn.get("beta_fast", 32.0)),
+                    beta_slow=float(yarn.get("beta_slow", 1.0)),
+                    attention_factor=(
+                        None if yarn.get("attention_factor") is None
+                        else float(yarn["attention_factor"])))),
+            window=None if t.get("window") is None else int(t["window"]),
+            gate=t.get("gate") is not None)))
+    return AttentionLayers(tuple(types), layers)
 
 
 def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
@@ -161,6 +223,35 @@ def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
     a["latent_attention"] = spec.get("latent_attention")
     a["rope_pairing"] = spec.get("rope_pairing", "half")
     a["dense_layers"] = int(spec.get("dense_layers", 0) or 0)
+    a["attention_layers"] = None
+    if spec.get("attention_layers") is not None:
+        # what a stack of typed layers cannot be, or what no code here
+        # does with one (`LMConfig` raises on the same; said here by key)
+        for key, why in (
+                ("latent_attention", "its layers are grouped attention"),
+                ("layer_pattern", "it is served in classic blocks"),
+                ("qk_norm", "no type describes a q/k norm"),
+                ("denoising_steps", "a ring cannot be rewritten by a "
+                                    "block's forwards")):
+            if spec.get(key):
+                raise ValueError(f"{key} under attention_layers: {why}")
+        if (a["attention_mask"] != "causal" or a["rope"] != "rotary"
+                or a["rope_pairing"] != "half"):
+            raise ValueError(
+                "attention_layers are served under the causal mask (a "
+                "window layer under its band), rope in halves: no "
+                "block_causal mask, rope none or interleaved pairing")
+        if spec.get("n_kv_heads") is None:
+            raise ValueError(
+                "attention_layers: n_kv_heads says the K and V heads "
+                "every type's query heads are grouped over")
+        a["attention_layers"] = _attention_layers(
+            spec["attention_layers"], int(spec.get("n_layers", 2)))
+        if spec.get("kv_quant") and any(
+                t.window is not None for _, t in a["attention_layers"].types):
+            raise ValueError(
+                "kv_quant under a window layer: its ring of rows is "
+                "cached unquantized")
     if a["attention"] not in ATTENTION_KINDS:
         raise ValueError(
             f"unknown attention {a['attention']!r} "
@@ -329,7 +420,10 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
     (nope + rope)], `kv_a` [d, kv_rank + rope], `kv_a_norm`
     [kv_rank], and the published `kv_b` as its two halves a head,
     `w_uk` [H, kv_rank, nope] and `w_uv` [H, kv_rank, v] (the absorbed
-    form multiplies them apart); `proj` is [H * v, d].
+    form multiplies them apart); `proj` is [H * v, d]. Under
+    `attention_layers` a block's `qkv` and `proj` take its TYPE's query
+    heads, and a gated type's block holds `head_gate` [d, H] beside
+    them.
 
     Under a `layer_pattern` a block holds one norm (`ln`) and its
     mixer's leaves alone: `qkv` and `proj`, or `moe`, or `ssm`
@@ -402,11 +496,22 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
             "out_proj": {"kernel": (s.d_inner, d)},
         }}
     mixers = {"*": attention, "E": ffn, "M": ssm}
+
+    def typed(i):  # layer i's attention leaves, by its type
+        if cfg.attention_layers is None:
+            return attention
+        t = cfg.attn(i)
+        return {
+            "qkv": {"kernel": (d, t.n_heads * hd + 2 * kvw)},
+            "proj": {"kernel": (t.n_heads * hd, d)},
+            **({"head_gate": {"kernel": (d, t.n_heads)}} if t.gate else {}),
+        }
+
     shapes: Dict[str, Any] = {"embed": {"embedding": (cfg.vocab_size, d)}}
     for i, kind in enumerate(cfg.kinds):
         shapes[f"block_{i}"] = (
             {"ln_attn": {"scale": (d,)}, "ln_mlp": {"scale": (d,)},
-             **attention, **(dense if i < arch["dense_layers"] else ffn)}
+             **typed(i), **(dense if i < arch["dense_layers"] else ffn)}
             if kind is None
             else {"ln": {"scale": (d,)}, **mixers[kind]})
     shapes["ln_out"] = {"scale": (d,)}
@@ -460,7 +565,7 @@ def lm_spec_parts(spec: Dict[str, Any]):
     `_ARCH_KEYS` is TransformerLM's block, initialised by the flax
     module and stored in float32 as ever; one that sets any of them
     (another head size, a rope base or pairing, q/k norms, latent
-    attention, a gated MLP, gated top-k experts under leading dense
+    attention, attention layers by type, a gated MLP, gated top-k experts under leading dense
     layers, the block-causal mask, `param_dtype`) gets
     `init_lm_params`' tree,
     its matrices stored in `param_dtype`, every layer an expert layer
@@ -515,6 +620,7 @@ def lm_spec_parts(spec: Dict[str, Any]):
             "activation": arch["activation"],
             "latent": arch["latent_attention"],
             "rope_pairing": arch["rope_pairing"],
+            "attention_layers": arch["attention_layers"],
         } if described else {}),
     )
     if described:

@@ -92,10 +92,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..observability import METRICS
+from ..ops.flash_attention import band_visits
 from ..tracing import TRACER, TraceContext
 from .generate import (
     LMConfig,
     _sample,
+    _typed_on_one_device,
     batched_block_step,
     batched_decode_step,
     batched_verify_step,
@@ -162,15 +164,20 @@ _M_PREFILL_PADDED = _M_PREFILL_TOKENS.labels(kind="padded")
 _M_KV_ROWS = METRICS.counter(
     "lm_server_decode_kv_rows_total",
     "cache rows of ONE layer over the chunk dispatches' decode steps by "
-    "kind=: live (rows the slots' lengths name), read (rows of the "
-    "k-blocks cache attention fetches for them; the whole grid on the "
-    "einsum route), grid (steps x slots x max_len, what a length-blind "
-    "step streams) and blocks (not rows: the k-blocks the kernel "
-    "visits, one grid step each; 0 on the einsum route)")
-_M_KV_LIVE = _M_KV_ROWS.labels(kind="live")
-_M_KV_READ = _M_KV_ROWS.labels(kind="read")
-_M_KV_GRID = _M_KV_ROWS.labels(kind="grid")
-_M_KV_BLOCKS = _M_KV_ROWS.labels(kind="blocks")
+    "layers= full (a layer that caches a row a token) | window (a layer "
+    "that caches a ring of its window's rows) and kind=: live (rows the "
+    "slots' lengths name; in a window layer min(length, window)), read "
+    "(rows of the k-blocks cache attention fetches for them; the whole "
+    "grid on the einsum route), grid (steps x slots x the layer's rows "
+    "a slot, what a length-blind step streams) and blocks (not rows: "
+    "the k-blocks the kernel visits, one grid step each; 0 on the "
+    "einsum route)")
+#: the counter's children a layer type, in `_kv_rows`' order
+_KV_KINDS = ("live", "read", "grid", "blocks")
+_M_KV = {
+    layers: tuple(_M_KV_ROWS.labels(kind=kind, layers=layers)
+                  for kind in _KV_KINDS)
+    for layers in ("full", "window")}
 _M_FIRST_TOKEN = METRICS.histogram(
     "lm_server_first_token_seconds",
     "slot placement -> the request's first token VALUE on the host "
@@ -200,7 +207,9 @@ _M_DELIVER = METRICS.histogram(
 _M_STATE_BYTES = METRICS.gauge(
     "lm_server_state_bytes",
     "the slot grid's bytes by kind= kv (grouped attention's K and V "
-    "rows) | latent (latent attention's one row a token) | conv | scan "
+    "rows, a row a token) | kv_window (the window layers' rings, which "
+    "do not grow with max_len) | latent (latent attention's one row a "
+    "token) | conv | scan "
     "(a state-space layer's convolution window and recurrent state, "
     "which every decode step reads and writes whole for every slot)")
 _M_WEIGHT_BYTES = METRICS.gauge(
@@ -341,7 +350,14 @@ _STATE_GROUP_TOKENS = 8192
 #: the bytes of the rows a group hands back (50 KB a token over 40 layers:
 #: 0.2 GB a 4,096-token row) beside 9.6 GB of weights and a 3.4 GB grid
 #: (ahead-of-time compile, PR 36: a 1 x 4,096 call holds 2.0 GiB of
-#: temporaries, a 4 x 4,096 call 1.6 GiB and 0.8 GiB of rows).
+#: temporaries, a 4 x 4,096 call 1.6 GiB and 0.8 GiB of rows). A stack with
+#: window layers takes the same bound for the same two reasons: it is as
+#: deep (the one that is served holds 40 layers, and a prefill's attention
+#: is 64 query heads wide), and a group hands back its full layers' rows
+#: padded to max_len beside every window layer's ring (0.23 GB a row of
+#: 4,096) next to 8 GB of weights and a 3.7 GB grid. (The bound is the deep
+#: stacks', not latent attention's alone; it keeps the name that
+#: `benchmark/tests/test_latent_cell.py` reads it by.)
 _LATENT_GROUP_TOKENS = _BUCKET_FLOOR
 
 
@@ -640,13 +656,27 @@ class LMServer:
             raise ValueError(
                 "a model with latent attention is served on one device: "
                 "the sharded forms have no placement for its shared rows")
+        _typed_on_one_device(cfg, self._mesh)
         self.cache = self._new_cache(cfg)
         for kind, n in state_bytes(self.cache).items():
             _M_STATE_BYTES.set(n, kind=kind)
+        # whether a placement round enqueues ONE prefill group at a time
+        # (`_place_group` says why): the deep models, whose groups hand
+        # back tenths of a GB of rows each
+        self._one_group = cfg.latent is not None or cfg.has_ring
         # the most padded tokens a prefill group of several rows holds
         self._group_tokens = (
             _STATE_GROUP_TOKENS if cfg.has_state
-            else _LATENT_GROUP_TOKENS if cfg.latent is not None else None)
+            else _LATENT_GROUP_TOKENS if self._one_group else None)
+        # the attention layers of each type (`_kv_rows`): one (layers,
+        # a layer of that type) a type the stack holds
+        kinds = {}
+        for i, kind in enumerate(cfg.kinds):
+            if kind in (None, "*"):
+                kinds.setdefault(
+                    "full" if cfg.attn(i).window is None else "window", i)
+        # (a stack without attention counts nothing, under "full")
+        self._kv_layers = tuple(kinds.items()) or (("full", 0),)
         # whether a prefill hands back the bucket's own rows, not rows
         # padded to max_len, and an insert writes those alone
         # (`_insert_impl` says why either kind of server may)
@@ -968,15 +998,29 @@ class LMServer:
         )
 
     def _refuse_state(self, what: str) -> None:
-        """Raise where this server's model (or its draft) carries a
-        state-space layer's state, which holds a whole sequence in one
-        array: it cannot be cut at a token or rolled back, as K/V rows
-        can, and `what` needs one of the two."""
+        """Raise where what a slot of this server's model carries is
+        not a row a token a layer under ONE attention type, and `what`
+        needs it to be: a state-space layer's state holds a whole
+        sequence in one array, and a window layer's ring its last
+        `window` positions, wrapped; neither can be cut at a token from
+        its start nor rolled back past what it overwrote, as K/V rows
+        can. (A stack of typed layers without a window could be cut;
+        what cuts and re-attends rows here reads one head count and one
+        rope for the whole stack, so it is refused with the others.)"""
         if self.cfg.has_state:
             raise ValueError(
                 f"{what} needs state that can be cut by token or rolled "
                 f"back; this model's state-space layers carry a scan "
                 f"state and a convolution window that allow neither")
+        if self.cfg.has_ring:
+            raise ValueError(
+                f"{what} needs state that can be cut by token or rolled "
+                f"back; this model's window layers cache a ring of their "
+                f"window's rows, which allows neither")
+        if self.cfg.attention_layers is not None:
+            raise ValueError(
+                f"{what} reads one head count and one rope for the whole "
+                f"stack; this model's attention layers go by type")
 
     def _new_cache(self, cfg: LMConfig):
         """An empty slot-grid cache for `cfg`. Under a mesh every
@@ -1667,6 +1711,9 @@ class LMServer:
             **({"state_rows": k} if self.cfg.has_state else {}),
             # latent attention's form here: keys and values rebuilt
             **({"attn": "expanded"} if self.cfg.latent is not None else {}),
+            # of the k-blocks the causal kernel computes over the bucket,
+            # the share a window layer's banded kernel never visits
+            **self._band_label(bucket),
         ) as span:
             padded = np.zeros((kp, bucket), np.int32)
             tps = np.ones(kp, np.int32)
@@ -1688,7 +1735,7 @@ class LMServer:
             # row's logits at its true last prompt position identical
             # to an UNPADDED prefill's, so first tokens match
             # generate() exactly despite bucket AND group padding
-            if self.cfg.latent is not None:
+            if self._one_group:
                 # ONE group's rows at a time: a call's outputs are
                 # allocated as it is enqueued, and a round of sixteen
                 # long prompts enqueued at once would hold 2-3 GB of
@@ -1784,6 +1831,20 @@ class LMServer:
             self.rid_vec[slot] = req.rid
             if req.done:  # max_new_tokens == 1
                 self._retire(slot)
+
+    def _band_label(self, bucket: int) -> Dict[str, float]:
+        """`lm_prefill_group`'s label `band_skipped` for a group of
+        `bucket` tokens a row: 1 - the k-blocks a window layer's banded
+        flash kernel visits over the ones the causal kernel computes
+        (`ops.flash_attention.band_visits`, at that kernel's own blocks);
+        nothing for a model without a window layer."""
+        if not self.cfg.has_ring:
+            return {}
+        window = min(
+            t.window for _, t in self.cfg.attention_layers.types
+            if t.window is not None)
+        banded, causal = band_visits(bucket, window)
+        return {"band_skipped": round(1.0 - banded / causal, 4)}
 
     def _retire(self, slot: int) -> None:
         req = self._slot_req[slot]
@@ -2093,34 +2154,38 @@ class LMServer:
         _M_SLOTS.set(sum(1 for r in self._slot_req if r is not None))
         step.label(tokens=delivered, firsts=first_n)
 
-    def _kv_rows(self) -> Tuple[int, int, int, int]:
-        """(live, read, grid, blocks) of one layer over the chunk
-        dispatch about to be issued: cache rows live, fetched and in
-        the whole grid, and the k-blocks the kernel visits. Host
-        arithmetic on what the device will do, no readback. A live
-        slot at step i attends prompt + emitted + i rows (its clamped
-        position + 1); cache attention walks the live (slot, k-block)
-        pairs alone, `cdiv(rows, block)` a slot, and fetches each
-        whole; an empty slot has none (a step with every slot empty
-        visits one block of no live rows: ops/decode_attention.py)."""
+    def _kv_rows(self, layer: int = 0) -> Tuple[int, int, int, int]:
+        """(live, read, grid, blocks) of ONE layer, attention layer
+        `layer`, over the chunk dispatch about to be issued: cache rows
+        live, fetched and in the whole grid, and the k-blocks the
+        kernel visits. Host arithmetic on what the device will do, no
+        readback. A live slot at step i attends prompt + emitted + i
+        rows (its clamped position + 1) of a layer that caches a row a
+        token, and min(that, window) rows of a window layer's ring,
+        whose plane is the ring (`LMConfig.layer_rows`); cache
+        attention walks the live (slot, k-block) pairs alone,
+        `cdiv(rows, block)` a slot, and fetches each whole; an empty
+        slot has none (a step with every slot empty visits one block of
+        no live rows: ops/decode_attention.py)."""
         if "*" not in (self.cfg.layer_pattern or "*"):
             return 0, 0, 0, 0  # no attention layer, no rows
-        grid = self.chunk * self.max_slots * self.max_len
+        plane = self.cfg.layer_rows(layer, self.max_len)
+        grid = self.chunk * self.max_slots * plane
         pos0 = np.asarray(
             [r.prompt.size + r.emitted - 1
              for r in self._slot_req if r is not None], np.int64
         )
-        lens = np.minimum(
+        lens = np.minimum(np.minimum(
             pos0[:, None] + np.arange(self.chunk), self.max_len - 1
-        ) + 1  # [live slots, chunk]
+        ) + 1, plane)  # [live slots, chunk]
         live = int(lens.sum())
-        bk = decode_block_rows(self.cfg, self.max_len, self._mesh)
+        bk = decode_block_rows(self.cfg, self.max_len, self._mesh, layer)
         if bk is None:  # the einsum streams every row; it has no blocks
             return live, grid, grid, 0
         nblk = -(-lens // bk)
-        rows = np.minimum(nblk * bk, self.max_len)  # T's last block is short
+        rows = np.minimum(nblk * bk, plane)  # the plane's last block is short
         blocks = np.maximum(nblk.sum(axis=0), 1)  # a step
-        read = np.maximum(rows.sum(axis=0), min(bk, self.max_len))
+        read = np.maximum(rows.sum(axis=0), min(bk, plane))
         return live, int(read.sum()), grid, int(blocks.sum())
 
     def _chunk_step(self, step: Any) -> None:
@@ -2135,13 +2200,22 @@ class LMServer:
                     jnp.asarray(self.rid_vec),
                 ))
             self._fed()
-        # reckoned while the device works, before delivery moves `emitted`
-        live, read, grid, blocks = self._kv_rows()
-        _M_KV_LIVE.inc(live)
-        _M_KV_READ.inc(read)
-        _M_KV_GRID.inc(grid)
-        _M_KV_BLOCKS.inc(blocks)
-        step.label(kv_rows_live=live, kv_rows_read=read, kv_blocks=blocks)
+        # reckoned while the device works, before delivery moves
+        # `emitted`: one layer of each type the stack holds. A layer
+        # that caches a row a token keeps the labels it had; a window
+        # layer's ring has its own
+        for layers, i in self._kv_layers:
+            rows = self._kv_rows(i)
+            for child, n in zip(_M_KV[layers], rows):
+                child.inc(n)
+            live, read, _, blocks = rows
+            if layers == "full":
+                step.label(kv_rows_live=live, kv_rows_read=read,
+                           kv_blocks=blocks)
+            else:
+                step.label(kv_window_rows_live=live,
+                           kv_window_rows_read=read,
+                           kv_window_blocks=blocks)
         if self.cfg.has_state:
             # every occupied slot's state was read and written whole by
             # each of the dispatch's steps

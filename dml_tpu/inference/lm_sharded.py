@@ -974,6 +974,12 @@ class LMPrefillBackend:
                 "state-space layer's scan state and convolution window "
                 "are not such rows (LMServer.submit_prefilled refuses "
                 "them too)")
+        if cfg.attention_layers is not None:
+            raise ValueError(
+                "a prefill worker ships a slab of K/V rows by token; a "
+                "window layer's ring is not such rows, and the slab "
+                "names one head count for the stack "
+                "(LMServer.submit_prefilled refuses them too)")
         if cfg.latent is not None:
             # `LMServer.submit_prefilled` adopts a slab of latent rows as
             # it adopts any; the chunk-streamed wire format that carries

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 
 # params keys quantized at each block level: 2-D kernels, the stacked
 # tensors of an expert layer (w_gate: gated experts only), the head
-_BLOCK_MATMULS = ("qkv", "proj", "up", "down", "gate", "q_a", "q_b", "kv_a")
+_BLOCK_MATMULS = ("qkv", "proj", "up", "down", "gate", "q_a", "q_b", "kv_a",
+                  "head_gate")
 _EXPERT_MATMULS = ("w_up", "w_down", "w_gate")
 # ... latent attention's up-projections, stacked over heads as the expert
 # tensors are over experts ([H, in, out]: one scale a head and channel)

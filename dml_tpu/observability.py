@@ -197,7 +197,7 @@ line when you add the metric.
     lm_kv_cache_hits_total           warm starts from cached prefixes
     lm_kv_cache_misses_total         lookups with no usable prefix
     lm_kv_cache_tokens_saved_total   prompt tokens not re-prefilled
-    lm_server_decode_kv_rows_total   decode cache rows by kind= live|read|grid (blocks: k-blocks visited)
+    lm_server_decode_kv_rows_total   decode cache rows by layers= full|window kind= live|read|grid (blocks: k-blocks visited)
     lm_server_decode_tokens_total    tokens decoded (all slots)
     lm_server_deliver_seconds        a dispatch's token delivery + callbacks
     lm_server_exposed_seconds        device left with nothing queued, a stretch
@@ -214,7 +214,7 @@ line when you add the metric.
     lm_server_slot_occupancy         busy decode slots per dispatched step
     lm_server_slots_active           busy decode slots
     lm_server_slots_total            configured decode slots
-    lm_server_state_bytes            slot grid bytes by kind= kv|conv|scan
+    lm_server_state_bytes            slot grid bytes by kind= kv|kv_window|latent|conv|scan
     lm_server_step_seconds           decode step wall
     lm_server_steps_total            decode steps executed
     lm_server_tokens_fixed_total     block-diffusion tokens fixed and delivered

@@ -9,6 +9,19 @@ The XLA einsum (inference/generate.py `batched_decode_step`, the CPU
 and test oracle) streams every row of every slot behind a mask; this
 kernel fetches only the k-blocks that hold live rows.
 
+What a slot's live rows are goes by the layer's type. A layer that
+caches a row a token hands its [B, KV, max_len, D] planes and the slots'
+lengths. A WINDOW layer (`generate.LMConfig.attention_layers`) caches a
+ring of its window's W rows a slot, position p at row p mod W
+(`generate.init_cache`), and hands that [B, KV, W, D] plane with
+`min(length, W)`: the ring's first min(length, W) rows ARE the slot's
+last min(length, W) positions, in the ring's order (softmax asks no
+order of its keys; a key carries its rope), so validity stays `iota <
+length` and no block has a dead lower edge: the k-blocks of a slot's
+last W positions are the ring's blocks, cdiv(min(length, W), block) of
+them, where a window cut out of a max_len plane would start a slot's
+work at a first row inside a block. One kernel, both layer types.
+
 The query may hold Q > 1 rows a slot (speculative decoding's verify,
 block diffusion's forwards: `generate.batched_block_step`): a KV
 head's Q * G rows then share every fetched block, each row masked to

@@ -70,6 +70,34 @@ def _below_diagonal(iq, ik, block_q, block_k, causal=1):
     return ik * block_k <= last
 
 
+def band_blocks(window: int, block: int) -> int:
+    """k-blocks a q-block visits under the banded mask `0 <= i - j <
+    window` at square blocks of `block` rows: its own and the ones that
+    hold the `window - 1` positions before its first row. The grid's
+    innermost extent; the blocks below the band are no grid steps."""
+    return -(-(window - 1) // block) + 1
+
+
+def band_block(window: int, block_q: int = 1024, block_k: int = 1024) -> int:
+    """The banded kernel's square block: `min(block_q, block_k)` rows
+    and no more than the window rounded up to 128 (a block wider than
+    the band computes scores the mask throws away)."""
+    return min(block_q, block_k, -(-window // 128) * 128)
+
+
+def band_visits(t: int, window: int, block: Optional[int] = None) -> tuple:
+    """(k-blocks the banded kernel computes, k-blocks the causal kernel
+    computes) over a sequence of `t` positions at square blocks (the
+    kernel's own, `band_block`, by default): what the band skips of
+    the causal triangle (a server's span labels are this arithmetic).
+    q-block i computes blocks max(0, i - nband + 1) .. i, the causal
+    kernel 0 .. i."""
+    block = band_block(window) if block is None else block
+    n = -(-t // min(block, t))
+    nband = band_blocks(window, min(block, t))
+    return (sum(min(i + 1, nband) for i in range(n)), n * (n + 1) // 2)
+
+
 def _kv_valid_mask(s, ik, block_k, t_kv):
     """Mask k positions past the true sequence length (pad columns)."""
     kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -77,11 +105,20 @@ def _kv_valid_mask(s, ik, block_k, t_kv):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, t_kv, padded_kv):
+                *, scale, causal, block_q, block_k, t_kv, padded_kv,
+                window=None):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
+    step = ik  # the grid's own innermost index
+    if window is not None:
+        # the banded mask (0 <= i - j < window, square blocks): the
+        # grid's innermost axis runs over the band's `nk` k-blocks
+        # alone, step j of q-block iq being k-block iq - (nk - 1) + j
+        # (`_flash_fwd_impl`'s index maps say the same); a block before
+        # the sequence's start is no block, and its step does nothing
+        ik = iq - (nk - 1) + step
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -97,6 +134,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         ) * scale  # [bq, bk] f32
         if causal:
             s = _causal_mask(s, iq, ik, block_q, block_k, causal)
+        if window is not None:  # the band's lower edge
+            qpos = iq * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            kpos = ik * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(qpos - kpos < window, s, NEG_INF)
         if padded_kv:
             s = _kv_valid_mask(s, ik, block_k, t_kv)
         m_prev = m_scr[:, :1]  # [bq, 1]
@@ -111,13 +154,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
+    if window is not None:
+        pl.when(ik >= 0)(_body)  # every block of the band is on or under
+    elif causal:
         # skip blocks entirely above the diagonal
         pl.when(_below_diagonal(iq, ik, block_q, block_k, causal))(_body)
     else:
         _body()
 
-    @pl.when(ik == nk - 1)
+    @pl.when(step == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
         o_ref[:] = (acc_scr[:] / l).astype(o_ref.dtype)
@@ -260,10 +305,13 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
     return out
 
 
-def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
+                    window=None):
     """q,k: [B,H,T,D], v: [B,H,T,Dv] (Dv = D as a rule; a model whose
     values are narrower than its keys hands them as they are). Returns
-    (out [B,H,T,Dv], lse [B,H,T]) f32 lse."""
+    (out [B,H,T,Dv], lse [B,H,T]) f32 lse. `window` W (causal, equal
+    lengths, square blocks): the banded mask, the grid over the band's
+    k-blocks alone (`band_blocks`)."""
     b, h, t, d = q.shape
     t_kv, dv = k.shape[2], v.shape[3]
     bq = min(block_q, t)
@@ -275,9 +323,21 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     nk = kp.shape[2] // bk
     padded_kv = kp.shape[2] != t_kv
 
+    def kblock(i, j):
+        return j
+
+    if window is not None:
+        # step j of q-block i is k-block i - (nband - 1) + j; a block
+        # before the start is named 0 (held already, so no copy) and
+        # its step is skipped in the kernel
+        nk = min(nk, band_blocks(window, bk))
+
+        def kblock(i, j, back=nk - 1):
+            return jnp.maximum(i - back + j, 0)
+
     q_spec = pl.BlockSpec((None, None, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    kv_spec = pl.BlockSpec((None, None, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0))
-    v_spec = pl.BlockSpec((None, None, bk, dv), lambda b_, h_, i, j: (b_, h_, j, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, d), lambda b_, h_, i, j: (b_, h_, kblock(i, j), 0))
+    v_spec = pl.BlockSpec((None, None, bk, dv), lambda b_, h_, i, j: (b_, h_, kblock(i, j), 0))
     o_spec = pl.BlockSpec((None, None, bq, dv), lambda b_, h_, i, j: (b_, h_, i, 0))
     # rows stored [B, H, T, 1]: trailing singleton lane dim keeps the
     # block's last-two-dims (bq, 1) legal for Mosaic (bs0 == as0)
@@ -286,6 +346,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         t_kv=t_kv, padded_kv=padded_kv,
+        **({} if window is None else {"window": window}),
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -418,6 +479,30 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_banded(q, k, v, window, scale, block, interpret):
+    """The forward kernel under the banded mask. Serving calls the
+    forward alone; the backward kernels know no lower edge and skip no
+    block below one, so differentiating this raises instead of handing
+    back the causal kernels' gradients."""
+    return _flash_fwd_impl(
+        q, k, v, True, scale, block, block, interpret, window=window)[0]
+
+
+def _flash_banded_fwd(q, k, v, window, scale, block, interpret):
+    raise NotImplementedError(
+        "flash attention's backward kernels know no window: the banded "
+        "mask is forward only (serving); train under it with another "
+        "attention, or teach _bwd_dq_kernel and _bwd_dkv_kernel the band")
+
+
+def _flash_banded_bwd(window, scale, block, interpret, res, g):
+    raise NotImplementedError("unreachable: the forward rule raises")
+
+
+_flash_banded.defvjp(_flash_banded_fwd, _flash_banded_bwd)
+
+
 # ---------------------------------------------------------------------------
 # lse-returning variant (the ring-attention building block)
 # ---------------------------------------------------------------------------
@@ -489,11 +574,21 @@ def flash_attention(
     block_k: int = 1024,
     interpret: Optional[bool] = None,
     mask_block: int = 1,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Blockwise (flash) attention. q, k, v: [B, T, H, D] (T of k/v may
     differ from q's); returns [B, Tq, H, D] in q's dtype. v may be
     narrower (or wider) than q and k, [B, T, H, Dv]: the output then is
     [B, Tq, H, Dv], and nothing is padded to the wider of the two.
+
+    `window` W (with `causal`, `mask_block` 1) is the banded mask of a
+    sliding-window layer: position i attends j iff 0 <= i - j < W, its
+    own key among the W. Blocks are square, `min(block_q, block_k)`
+    rows and no more than the window rounded up to 128 (a block wider
+    than the band computes scores the mask throws away), and the grid
+    runs over the band's k-blocks alone: the ones wholly below the band
+    are no grid steps, as the ones above the diagonal are none. Forward
+    only: differentiating it raises.
 
     `mask_block` B > 1 (with `causal`) is the block-causal rule of
     block-diffusion models: position i attends j iff j // B <= i // B.
@@ -512,6 +607,14 @@ def flash_attention(
     interpret = _interpret_default() if interpret is None else interpret
     if mask_block < 1 or (mask_block > 1 and not causal):
         raise ValueError(f"mask_block {mask_block} with causal={causal}")
+    if window is not None:
+        if window < 1 or not causal or mask_block != 1:
+            raise ValueError(
+                f"window {window} with causal={causal}, mask_block "
+                f"{mask_block}: the band is causal, by positions")
+        block = band_block(window, block_q, block_k)
+        return _bhtd(_flash_banded(
+            _bhtd(q), _bhtd(k), _bhtd(v), window, scale, block, interpret))
     out = _flash(
         _bhtd(q), _bhtd(k), _bhtd(v), mask_block if mask_block > 1 else causal,
         scale, block_q, block_k, interpret,

@@ -144,7 +144,7 @@ def test_a_padded_group_with_riders_and_a_reused_slot_equal_prompts_alone(
 def test_spans_and_counters_count_latent_rows(model):
     params, cfg = model
     live0 = METRICS.counter("lm_server_decode_kv_rows_total").value(
-        kind="live")
+        kind="live", layers="full")
     TRACER.reset()
     prompt = _tokens(10)
     srv, _ = _serve(params, cfg, [prompt], budget=9)
@@ -159,7 +159,7 @@ def test_spans_and_counters_count_latent_rows(model):
     want = sum(10 + i for i in range(1, 9))
     assert sum(s["lb"]["kv_rows_live"] for s in steps) == want
     assert METRICS.counter("lm_server_decode_kv_rows_total").value(
-        kind="live") - live0 == want
+        kind="live", layers="full") - live0 == want
     assert all(s["lb"]["kv_rows_read"] >= s["lb"]["kv_rows_live"]
                for s in steps)
     span = TRACER.loop_spans("lm_weights_resident")[-1]
@@ -177,7 +177,8 @@ def test_the_cache_holds_one_narrow_row_a_token():
     assert leaf.shape == (4, 1, 64, 128) and leaf.dtype == jnp.bfloat16
     assert g.cache_rows(cache) == 64
     assert g.state_bytes(cache) == {
-        "kv": 0, "latent": 3 * 4 * 64 * 128 * 2, "conv": 0, "scan": 0}
+        "kv": 0, "kv_window": 0, "latent": 3 * 4 * 64 * 128 * 2,
+        "conv": 0, "scan": 0}
     # the published widths: 576 values a token a layer, in 640 columns
     big = g.LatentConfig(1536, 512, 128, 64, 128)
     assert (big.row_width, big.row_stride, big.key_width) == (576, 640, 192)

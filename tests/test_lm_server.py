@@ -562,7 +562,7 @@ def _served_mixed_budgets(params):
         return {"first_token_count": sum(v[0] for _, v in hist),
                 "prompt": tok.value(kind="prompt"),
                 "padded": tok.value(kind="padded"),
-                **{"kv_" + k: rows.value(kind=k)
+                **{"kv_" + k: rows.value(kind=k, layers="full")
                    for k in ("live", "read", "grid", "blocks")}}
 
     rng = np.random.RandomState(11)

@@ -569,3 +569,81 @@ def test_latent_attention_programs_of_the_joyai_config_fit_the_chip(
         lambda p, x, i: prefill(p, cfg, x, x.shape[1], logits_index=i)
     ).lower(params, prompt, at).compile().as_text()
     assert text.count("tpu_custom_call") == 3 + 3 * 2
+
+
+def test_banded_flash_attention_compiles(topo):
+    """The window layers' prefill kernel at laguna_xs2_ep16's widths: 64
+    heads of 128 over a 4,096-token row, the band of 512 at blocks of 512
+    (the grid's innermost extent is the band's two k-blocks)."""
+    from dml_tpu.ops.flash_attention import flash_attention
+
+    text, _ = compile_on_chip(
+        topo, functools.partial(flash_attention, causal=True, window=512,
+                                interpret=False),
+        *[((1, 4096, 64, 128), jnp.bfloat16)] * 3)
+    assert "tpu_custom_call" in text
+
+
+def test_window_attention_programs_of_the_laguna_config_fit_the_chip(
+        topo, chip_routes):
+    """The fifth benchmark configuration's two programs at
+    laguna_xs2_ep16's published widths, slot grid and chunk, the depth cut
+    to one period of its pattern (full, window x 3: the dense layer and
+    three expert layers; the full depth compiles in 40 s and 50 s:
+    `benchmark/tools/aot_memory_window.py`, PERF.md section 4):
+    `LMServer._chunk_impl` holds the decode kernel a layer (6 query rows a
+    KV head in the full layer, 8 in a window layer, whose plane is its
+    512-row ring) and three grouped matmuls an expert layer, and updates
+    planes and rings in place; a 1 x 4,096 prefill holds a flash kernel a
+    layer, the window layers' banded."""
+    import json
+    import os
+
+    from dml_tpu.inference.generate import init_cache, prefill
+    from dml_tpu.inference.lm_backend import lm_spec_parts
+    from dml_tpu.inference.lm_server import LMServer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna_xs2_ep16.json")) as f:
+        spec = json.load(f)["lm_spec"]
+    al = spec["attention_layers"]
+    spec = {**spec, "n_layers": 4, "attention_layers": {
+        **al, "layers": al["layers"][:4]}}
+    made = {}
+
+    def declared():
+        params, made["cfg"] = lm_spec_parts(spec)
+        return params
+
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = functools.partial(jax.tree_util.tree_map, lambda s: (
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)))
+    params = on_chip(jax.eval_shape(declared))
+    cfg = made["cfg"]
+    slots, max_len = spec["max_slots"], spec["max_len"]
+    srv = object.__new__(LMServer)
+    srv.cfg, srv.max_len, srv.max_slots = cfg, max_len, slots
+    srv.chunk, srv.temperature, srv._mesh = spec["chunk"], 0.0, None
+    srv._routed = (3, spec["num_experts"])
+    srv._held = (0, spec["experts_held"][1])
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))
+    assert cache["block_0"]["k"].shape == (16, 8, 4096, 128)
+    assert cache["block_1"]["k_ring"].shape == (16, 8, 512, 128)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    compiled = jax.jit(srv._chunk_impl, donate_argnums=(1, 2, 3)).lower(
+        params, cache, vec, vec, vec).compile()
+    text = compiled.as_text()
+    assert text.lstrip().startswith("HloModule jit__chunk_impl")
+    assert text.count("tpu_custom_call") == 4 + 3 * 3
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2 ** 28
+    grid = sum(x.size * 2 for x in jax.tree_util.tree_leaves(cache))
+    assert m.alias_size_in_bytes >= grid  # planes and rings, in place
+    prompt = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one)
+    at = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one)
+    compiled = jax.jit(
+        lambda p, x, i: prefill(p, cfg, x, max_len, logits_index=i)
+    ).lower(params, prompt, at).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 4 + 3 * 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 31
