@@ -1964,16 +1964,19 @@ def prefill(
     )
 
     def attn_fn(q, k, v, window=None):
-        # flash kernel is head-symmetric: broadcast GQA kv heads to
-        # full heads for the prefill pass (the cache below keeps the
-        # compact layout _apply_block returned)
-        grp = q.shape[2] // cfg.kv_heads
         with part("attn_core"):
+            if cfg.attention_layers is not None:
+                # a layer that has a type: K and V by KV head, as
+                # `_apply_block` returned them, and the kernel's live
+                # blocks alone (a window layer: the band's)
+                return flash_attention(q, k, v, causal=True, window=window)
+            # the untyped call is head-symmetric: broadcast GQA kv heads
+            # to full heads for the prefill pass (the cache below keeps
+            # the compact layout _apply_block returned)
+            grp = q.shape[2] // cfg.kv_heads
             if grp > 1:
                 k = jnp.repeat(k, grp, axis=2)
                 v = jnp.repeat(v, grp, axis=2)
-            if window is not None:  # a window layer: the band alone
-                return flash_attention(q, k, v, causal=True, window=window)
             return flash(q, k, v)
 
     # rows' own lengths, where the caller gave them: a state-space

@@ -92,7 +92,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..observability import METRICS
-from ..ops.flash_attention import band_visits
+from ..ops.flash_attention import band_masked, band_visits
 from ..tracing import TRACER, TraceContext
 from .generate import (
     LMConfig,
@@ -578,6 +578,26 @@ def _hold_resident(params: Any, dtype, tree: str) -> Tuple[Any, int, int]:
         resident = quantized_bytes(params)[0]
         span.label(handed_bytes=handed, resident_bytes=resident)
     return params, handed, resident
+
+
+@functools.lru_cache(maxsize=None)
+def _band_labels(bucket: int, window: int, head_dim: int,
+                 group: int) -> Dict[str, float]:
+    """A window layer's prefill kernel over `bucket` tokens a row, in
+    that kernel's own arithmetic at its own blocks
+    (`ops.flash_attention`: `band_visits`, `band_masked`): `band_skipped`,
+    1 - the k-blocks it visits over the ones the causal rule leaves;
+    `band_masked`, the share of the visited ones that an edge crosses,
+    so that they build a mask (under 1: the band has an inside);
+    `kv_group`, the query heads that share a copy of a K and V block.
+    A few buckets a server: reckoned once each."""
+    shape = {"head_dim": head_dim, "group": group}
+    banded, causal = band_visits(bucket, window, **shape)
+    return {
+        "band_skipped": round(1.0 - banded / causal, 4),
+        "band_masked": round(band_masked(bucket, window, **shape) / banded, 4),
+        "kv_group": group,
+    }
 
 
 class LMServer:
@@ -1833,18 +1853,16 @@ class LMServer:
                 self._retire(slot)
 
     def _band_label(self, bucket: int) -> Dict[str, float]:
-        """`lm_prefill_group`'s label `band_skipped` for a group of
-        `bucket` tokens a row: 1 - the k-blocks a window layer's banded
-        flash kernel visits over the ones the causal kernel computes
-        (`ops.flash_attention.band_visits`, at that kernel's own blocks);
+        """`lm_prefill_group`'s labels of a window layer's prefill
+        kernel for a group of `bucket` tokens a row (`_band_labels`);
         nothing for a model without a window layer."""
         if not self.cfg.has_ring:
             return {}
-        window = min(
-            t.window for _, t in self.cfg.attention_layers.types
-            if t.window is not None)
-        banded, causal = band_visits(bucket, window)
-        return {"band_skipped": round(1.0 - banded / causal, 4)}
+        lay = min(
+            (t for _, t in self.cfg.attention_layers.types
+             if t.window is not None), key=lambda t: t.window)
+        return _band_labels(bucket, lay.window, self.cfg.head_dim,
+                            lay.n_heads // self.cfg.kv_heads)
 
     def _retire(self, slot: int) -> None:
         req = self._slot_req[slot]

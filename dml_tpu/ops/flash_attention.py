@@ -27,6 +27,15 @@ XLA's naive attention (8.9 ms -> 1.65 ms), which is HBM-bound on the
 materialized [B,H,T,T] score tensor. Blocks are min'd to the actual
 sequence length, so small-T callers are unaffected by the defaults.
 
+Served layers (`generate.prefill` of a stack whose attention layers
+have a type) take two forward-only kernels that do their live work and
+nothing else, K and V by KV head: `_band_fwd_kernel` (a window layer:
+the band's k-blocks handed side by side, one softmax pass a q-block, a
+mask where an edge crosses) and `_live_fwd_kernel` (a full layer: this
+kernel's grid, the mask in diagonal blocks alone, no copy above the
+diagonal). The untyped call below is the program it was: its JAXPR is
+pinned in tests/test_ops.py.
+
 The reference has no attention anywhere (SURVEY §0 — its models are
 CNNs over single images); this is part of the net-new long-context
 path, written per /opt/skills/guides/pallas_guide.md.
@@ -73,29 +82,67 @@ def _below_diagonal(iq, ik, block_q, block_k, causal=1):
 def band_blocks(window: int, block: int) -> int:
     """k-blocks a q-block visits under the banded mask `0 <= i - j <
     window` at square blocks of `block` rows: its own and the ones that
-    hold the `window - 1` positions before its first row. The grid's
-    innermost extent; the blocks below the band are no grid steps."""
+    hold the `window - 1` positions before its first row."""
     return -(-(window - 1) // block) + 1
 
 
-def band_block(window: int, block_q: int = 1024, block_k: int = 1024) -> int:
-    """The banded kernel's square block: `min(block_q, block_k)` rows
-    and no more than the window rounded up to 128 (a block wider than
-    the band computes scores the mask throws away)."""
-    return min(block_q, block_k, -(-window // 128) * 128)
+def live_blocks(window: Optional[int], t: int, head_dim: int,
+                group: int) -> tuple:
+    """(q rows, k rows) of a block of the served layers' forward kernels:
+    ONE rule of the layer's window (None: a full layer), the sequence,
+    the head size and the query heads a KV head; no knob. A full layer
+    keeps the causal kernel's 1,024 (a head a grid step). A window
+    layer's are square, HALF the window in 128s, so that the band is
+    three pieces and the middle one all inside (at W 512: 256; chip
+    readings of the kernel alone, PERF.md §6, PR 42: 1.28 ms a 4,096-token
+    row at 256 and at 512, 1.56 at 128, the parent's form 2.98), and no
+    more than keeps the group's q and output blocks, double-buffered,
+    within 4 MiB (512 rows at 8 heads of 128)."""
+    if window is None:
+        return (min(1024, t),) * 2
+    half = -(-(window // 2) // 128) * 128
+    fit = (1 << 19) // (group * head_dim) // 128 * 128
+    return (min(max(128, min(half, fit)), t),) * 2
 
 
-def band_visits(t: int, window: int, block: Optional[int] = None) -> tuple:
-    """(k-blocks the banded kernel computes, k-blocks the causal kernel
-    computes) over a sequence of `t` positions at square blocks (the
-    kernel's own, `band_block`, by default): what the band skips of
-    the causal triangle (a server's span labels are this arithmetic).
-    q-block i computes blocks max(0, i - nband + 1) .. i, the causal
-    kernel 0 .. i."""
-    block = band_block(window) if block is None else block
-    n = -(-t // min(block, t))
-    nband = band_blocks(window, min(block, t))
-    return (sum(min(i + 1, nband) for i in range(n)), n * (n + 1) // 2)
+def _band_edges(window: int, block: int, pieces: int) -> tuple:
+    """Which of a q-block's `pieces` k-blocks under the band an edge
+    crosses, oldest first: the last (the diagonal) and the ones that hold
+    a key `window` and more before some row. The others are all inside,
+    and the kernel builds no mask for them."""
+    return tuple(j == pieces - 1 or (pieces - j) * block - 1 >= window
+                 for j in range(pieces))
+
+
+def _band_walk(t, window, block, head_dim, group):
+    """(block, q-blocks, k-blocks a q-block visits at most) of the banded
+    kernel over `t` positions."""
+    block = min(block, t) if block else live_blocks(
+        window, t, head_dim, group)[0]
+    nq = -(-t // block)
+    return block, nq, min(nq, band_blocks(window, block))
+
+
+def band_visits(t: int, window: int, block: Optional[int] = None, *,
+                head_dim: int = 128, group: int = 1) -> tuple:
+    """(k-blocks the banded kernel computes, k-blocks the causal rule
+    leaves at the same blocks) over a sequence of `t` positions: what the
+    band skips of the causal triangle (a server's span labels are this
+    arithmetic). Square blocks of `block` rows, else the kernel's own
+    (`live_blocks` of the head size and the group). q-block i computes
+    blocks max(0, i - pieces + 1) .. i, the causal rule leaves 0 .. i."""
+    _, nq, pieces = _band_walk(t, window, block, head_dim, group)
+    return (sum(min(i + 1, pieces) for i in range(nq)), nq * (nq + 1) // 2)
+
+
+def band_masked(t: int, window: int, block: Optional[int] = None, *,
+                head_dim: int = 128, group: int = 1) -> int:
+    """Of the k-blocks `band_visits` counts under the band, those an edge
+    crosses, for which the kernel builds a mask (`_band_edges`); the
+    others are all inside."""
+    block, nq, pieces = _band_walk(t, window, block, head_dim, group)
+    edges = _band_edges(window, block, pieces)
+    return sum(sum(edges[pieces - min(i + 1, pieces):]) for i in range(nq))
 
 
 def _kv_valid_mask(s, ik, block_k, t_kv):
@@ -105,24 +152,11 @@ def _kv_valid_mask(s, ik, block_k, t_kv):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, t_kv, padded_kv,
-                window=None):
+                *, scale, causal, block_q, block_k, t_kv, padded_kv):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
-    step = ik  # the grid's own innermost index
-    if window is not None:
-        # the banded mask (0 <= i - j < window, square blocks): the
-        # grid's innermost axis runs over the band's `nk` k-blocks
-        # alone, step j of q-block iq being k-block iq - (nk - 1) + j
-        # (`_flash_fwd_impl`'s index maps say the same); a block before
-        # the sequence's start is no block, and its step does nothing
-        ik = iq - (nk - 1) + step
 
-    @pl.when(step == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    pl.when(ik == 0)(functools.partial(_reset, m_scr, l_scr, acc_scr))
 
     def _body():
         # MXU wants the dot inputs in their native (bf16) dtype with
@@ -134,39 +168,137 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         ) * scale  # [bq, bk] f32
         if causal:
             s = _causal_mask(s, iq, ik, block_q, block_k, causal)
-        if window is not None:  # the band's lower edge
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            kpos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(qpos - kpos < window, s, NEG_INF)
         if padded_kv:
             s = _kv_valid_mask(s, ik, block_k, t_kv)
-        m_prev = m_scr[:, :1]  # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)  # [bq, 1]
-        l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[:],
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        _online_softmax(s, v_ref, m_scr, l_scr, acc_scr)
 
-    if window is not None:
-        pl.when(ik >= 0)(_body)  # every block of the band is on or under
-    elif causal:
+    if causal:
         # skip blocks entirely above the diagonal
         pl.when(_below_diagonal(iq, ik, block_q, block_k, causal))(_body)
     else:
         _body()
 
-    @pl.when(step == nk - 1)
+    @pl.when(ik == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
         o_ref[:] = (acc_scr[:] / l).astype(o_ref.dtype)
         lse_ref[:] = m_scr[:, :1] + jnp.log(l)  # [bq, 1]
+
+
+def _reset(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+def _online_softmax(s, v_ref, m_scr, l_scr, acc_scr):
+    """One k-block of the online-softmax recurrence: scores `s` [rows,
+    bk] f32 (masked already) against the block's values, into the
+    running max, denominator and accumulator."""
+    m_prev = m_scr[:, :1]  # [rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)  # [rows, 1]
+    l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot(
+        p.astype(v_ref.dtype), v_ref[:],
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+
+def _scores(q, k_ref, scale, bias=None):
+    """[bq, bk] f32 scores of one head's rows against a K block; `bias`
+    (0 where a pair is kept, -1e30 where the mask drops it; None: every
+    pair kept) is ADDED, one plane for all the heads of a group: s + 0 is
+    s and s - 1e30 is -1e30 in float32, what a select would have left."""
+    s = jax.lax.dot_general(  # bf16 in, f32 accum (MXU)
+        q, k_ref[:], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    return s if bias is None else s + bias
+
+
+def _band_fwd_kernel(q_ref, *refs, scale, window, block, pieces):
+    """The forward kernel of a WINDOW layer that is served (the banded
+    mask `0 <= i - j < window`, equal lengths, forward only). A grid step
+    is a q-block of `block` rows of the `group` query heads of one KV
+    head; K and V come as `pieces` blocks of `block` rows each, the
+    q-block's own and the ones before it that hold the band (the index
+    maps of `_flash_live_impl`), so the softmax over the band is ONE
+    pass: no running statistics, no rescaled accumulator, nothing carried
+    from step to step. A piece an edge crosses (`_band_edges`) adds its
+    mask, a constant plane; the pieces between are all inside and add
+    nothing. The first q-blocks have fewer live pieces (a block before
+    the sequence's start is no block): each count is a body of its own
+    that reads its live pieces alone. A pad tail needs no mask of its
+    own: a key past the sequence's end is after every row that is kept."""
+    k_refs, v_refs, o_ref = refs[:pieces], refs[pieces:-1], refs[-1]
+    iq = pl.program_id(2)
+    edges = _band_edges(window, block, pieces)
+
+    def _body(live):
+        def plane(j):  # piece j's rows back from each query to each key
+            shape = (block, block)
+            back = (pieces - 1 - j) * block + (
+                jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                - jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+            return jnp.where((back >= 0) & (back < window), 0.0, NEG_INF)
+
+        bias = [plane(j) if edges[j] else None
+                for j in range(pieces - live, pieces)]
+        ks, vs = k_refs[pieces - live:], v_refs[pieces - live:]
+
+        # the group's heads one after another, unrolled: one chain a
+        # head, side by side for the scheduler (1.05 ms a 4,096-token
+        # row against 1.28 under a rolled loop: PERF.md §6, PR 42)
+        for h in range(q_ref.shape[0]):
+            s = [_scores(q_ref[h], k, scale, b) for k, b in zip(ks, bias)]
+            m = functools.reduce(jnp.maximum, [
+                jnp.max(x, axis=-1, keepdims=True) for x in s])
+            p = [jnp.exp(x - m) for x in s]
+            l = sum(jnp.sum(x, axis=-1, keepdims=True) for x in p)
+            acc = sum(jax.lax.dot(x.astype(v.dtype), v[:],
+                                  preferred_element_type=jnp.float32)
+                      for x, v in zip(p, vs))
+            o_ref[h] = (acc / l).astype(o_ref.dtype)
+
+    for live in range(1, pieces):
+        pl.when(iq == live - 1)(functools.partial(_body, live))
+    pl.when(iq >= pieces - 1)(functools.partial(_body, pieces))
+
+
+def _live_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                     scale, block_q, block_k):
+    """The forward kernel of a FULL layer that is served (causal, equal
+    lengths, forward only) with K and V by KV head: `_fwd_kernel`'s grid
+    and recurrence, a head's K and V block named by its KV head (the
+    index maps of `_flash_live_impl`), the mask built where the diagonal
+    crosses the block and nowhere else, and a k-block wholly above the
+    diagonal no block (its step names the diagonal's, already held, so
+    no copy is issued for it). A pad tail needs no mask of its own: a
+    key past the sequence's end is after every row that is kept."""
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
+
+    pl.when(ik == 0)(functools.partial(_reset, m_scr, l_scr, acc_scr))
+
+    def _body(masked):
+        s = _scores(q_ref[:], k_ref, scale)
+        if masked:  # one head a step: a select, no plane to share
+            s = _causal_mask(s, iq, ik, block_q, block_k)
+        _online_softmax(s, v_ref, m_scr, l_scr, acc_scr)
+
+    below = ik * block_k <= iq * block_q + block_q - 1
+    crossed = ik * block_k + block_k - 1 > iq * block_q
+    pl.when(below & crossed)(functools.partial(_body, True))
+    pl.when(jnp.logical_not(crossed))(functools.partial(_body, False))
+
+    @pl.when(ik == nk - 1)
+    def _finish():
+        l = jnp.maximum(l_scr[:, :1], 1e-30)
+        o_ref[:] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -305,13 +437,10 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
     return out
 
 
-def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
-                    window=None):
+def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     """q,k: [B,H,T,D], v: [B,H,T,Dv] (Dv = D as a rule; a model whose
     values are narrower than its keys hands them as they are). Returns
-    (out [B,H,T,Dv], lse [B,H,T]) f32 lse. `window` W (causal, equal
-    lengths, square blocks): the banded mask, the grid over the band's
-    k-blocks alone (`band_blocks`)."""
+    (out [B,H,T,Dv], lse [B,H,T]) f32 lse."""
     b, h, t, d = q.shape
     t_kv, dv = k.shape[2], v.shape[3]
     bq = min(block_q, t)
@@ -323,21 +452,9 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     nk = kp.shape[2] // bk
     padded_kv = kp.shape[2] != t_kv
 
-    def kblock(i, j):
-        return j
-
-    if window is not None:
-        # step j of q-block i is k-block i - (nband - 1) + j; a block
-        # before the start is named 0 (held already, so no copy) and
-        # its step is skipped in the kernel
-        nk = min(nk, band_blocks(window, bk))
-
-        def kblock(i, j, back=nk - 1):
-            return jnp.maximum(i - back + j, 0)
-
     q_spec = pl.BlockSpec((None, None, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    kv_spec = pl.BlockSpec((None, None, bk, d), lambda b_, h_, i, j: (b_, h_, kblock(i, j), 0))
-    v_spec = pl.BlockSpec((None, None, bk, dv), lambda b_, h_, i, j: (b_, h_, kblock(i, j), 0))
+    kv_spec = pl.BlockSpec((None, None, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0))
+    v_spec = pl.BlockSpec((None, None, bk, dv), lambda b_, h_, i, j: (b_, h_, j, 0))
     o_spec = pl.BlockSpec((None, None, bq, dv), lambda b_, h_, i, j: (b_, h_, i, 0))
     # rows stored [B, H, T, 1]: trailing singleton lane dim keeps the
     # block's last-two-dims (bq, 1) legal for Mosaic (bs0 == as0)
@@ -346,7 +463,6 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         t_kv=t_kv, padded_kv=padded_kv,
-        **({} if window is None else {"window": window}),
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -367,6 +483,78 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret,
         name="flash_attention",
     )(qp, kp, vp)
     return out[:, :, :t], lse[:, :, :t, 0]
+
+
+def _flash_band_impl(q, k, v, window, scale, block, interpret):
+    """q: [B,H,T,D], k: [B,KV,T,D], v: [B,KV,T,Dv], H = group x KV, the
+    heads of a group side by side: out [B,H,T,Dv] under the banded mask
+    by `_band_fwd_kernel` at square blocks of `block` rows."""
+    b, h, t, d = q.shape
+    kv, dv = k.shape[1], v.shape[3]
+    group = h // kv
+    block = min(block, t)
+    qp, kp, vp = (_pad_seq(x, block) for x in (q, k, v))
+    nq = qp.shape[2] // block
+    pieces = min(nq, band_blocks(window, block))
+    # a q-block holds a KV head's query heads, [group, block, D] (a block
+    # of the head axis: q and the output keep their [B, H, T, D], and no
+    # reshape stands between the kernel and what XLA fuses around it);
+    # piece j of q-block i is k-block i - (pieces - 1) + j, and one
+    # before the start is named 0 and not read
+    rows = lambda width: pl.BlockSpec(
+        (None, group, block, width), lambda b_, h_, i: (b_, h_, i, 0))
+    piece = lambda width, back: pl.BlockSpec(
+        (None, None, block, width),
+        lambda b_, h_, i: (b_, h_, jnp.maximum(i - back, 0), 0))
+    backs = range(pieces - 1, -1, -1)
+    out = pl.pallas_call(
+        functools.partial(_band_fwd_kernel, scale=scale, window=window,
+                          block=block, pieces=pieces),
+        grid=(b, kv, nq),
+        in_specs=[rows(d)] + [piece(d, x) for x in backs]
+        + [piece(dv, x) for x in backs],
+        out_specs=rows(dv),
+        out_shape=jax.ShapeDtypeStruct((*qp.shape[:3], dv), q.dtype),
+        interpret=interpret,
+        name="flash_attention",  # the same part in a profiler trace
+    )(qp, *[kp] * pieces, *[vp] * pieces)
+    return out[:, :, :t]
+
+
+def _flash_live_impl(q, k, v, scale, block_q, block_k, interpret):
+    """q: [B,H,T,D], k: [B,KV,T,D], v: [B,KV,T,Dv], H = group x KV, the
+    heads of a group side by side: out [B,H,T,Dv] under the causal mask
+    by `_live_fwd_kernel`."""
+    b, h, t, d = q.shape
+    group, dv = h // k.shape[1], v.shape[3]
+    bq, bk = min(block_q, t), min(block_k, t)
+    qp, kp, vp = _pad_seq(q, bq), _pad_seq(k, bk), _pad_seq(v, bk)
+    # a head's K and V are its KV head's; a step above the diagonal
+    # names the diagonal's block
+    kv_spec = lambda width: pl.BlockSpec(
+        (None, None, bk, width),
+        lambda b_, h_, i, j: (
+            b_, jax.lax.div(h_, jnp.int32(group)),
+            jnp.minimum(j, jax.lax.div(i * bq + bq - 1, jnp.int32(bk))), 0))
+    out = pl.pallas_call(
+        functools.partial(_live_fwd_kernel, scale=scale, block_q=bq,
+                          block_k=bk),
+        grid=(b, h, qp.shape[2] // bq, kp.shape[2] // bk),
+        in_specs=[
+            pl.BlockSpec((None, None, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            kv_spec(d), kv_spec(dv),
+        ],
+        out_specs=pl.BlockSpec((None, None, bq, dv), lambda b_, h_, i, j: (b_, h_, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((*qp.shape[:3], dv), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((bq, LANES), jnp.float32),  # running max
+            pltpu.VMEM((bq, LANES), jnp.float32),  # running denominator
+            pltpu.VMEM((bq, dv), jnp.float32),     # output accumulator
+        ],
+        interpret=interpret,
+        name="flash_attention",
+    )(qp, kp, vp)
+    return out[:, :, :t]
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -479,28 +667,36 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_banded(q, k, v, window, scale, block, interpret):
-    """The forward kernel under the banded mask. Serving calls the
-    forward alone; the backward kernels know no lower edge and skip no
-    block below one, so differentiating this raises instead of handing
-    back the causal kernels' gradients."""
-    return _flash_fwd_impl(
-        q, k, v, True, scale, block, block, interpret, window=window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_live_forward(q, k, v, window, scale, block_q, block_k, interpret):
+    """The served layers' forward kernels: a window layer's band
+    (`_band_fwd_kernel`), a full layer's causal mask with K and V by KV
+    head (`_live_fwd_kernel`). Serving calls the forward alone; the
+    backward kernels know no lower edge, skip no block below one and sum
+    no group's gradients into a KV head, so differentiating this raises
+    instead of handing back the causal kernels' gradients."""
+    if window is not None:
+        return _flash_band_impl(q, k, v, window, scale, block_q, interpret)
+    return _flash_live_impl(q, k, v, scale, block_q, block_k, interpret)
 
 
-def _flash_banded_fwd(q, k, v, window, scale, block, interpret):
+def _flash_live_fwd(q, k, v, window, scale, block_q, block_k, interpret):
     raise NotImplementedError(
-        "flash attention's backward kernels know no window: the banded "
-        "mask is forward only (serving); train under it with another "
-        "attention, or teach _bwd_dq_kernel and _bwd_dkv_kernel the band")
+        "flash attention's backward kernels know no window and no K and V "
+        "by KV head: the banded mask and the grouped call are forward only "
+        "(serving); train under them with another attention, or teach "
+        "_bwd_dq_kernel and _bwd_dkv_kernel the band and the group")
 
 
-def _flash_banded_bwd(window, scale, block, interpret, res, g):
+def _flash_live_bwd(window, scale, block_q, block_k, interpret, res, g):
     raise NotImplementedError("unreachable: the forward rule raises")
 
 
-_flash_banded.defvjp(_flash_banded_fwd, _flash_banded_bwd)
+_flash_live_forward.defvjp(_flash_live_fwd, _flash_live_bwd)
+# one traced function a (shapes, window, blocks): a program whose layers
+# unroll in Python (`generate.prefill`: thirty window layers, ten full)
+# traces and lowers the kernel once a layer TYPE, not once a layer
+_flash_live = jax.jit(_flash_live_forward, static_argnums=(3, 4, 5, 6, 7))
 
 
 # ---------------------------------------------------------------------------
@@ -581,22 +777,29 @@ def flash_attention(
     narrower (or wider) than q and k, [B, T, H, Dv]: the output then is
     [B, Tq, H, Dv], and nothing is padded to the wider of the two.
 
+    K and V may come BY KV HEAD, [B, T, H_kv, D] where q has H = group x
+    H_kv heads (query head h reads KV head h // group, as `jnp.repeat`
+    on the head axis would have laid them; a count that does not divide
+    raises): the served form of a grouped layer, causal and forward only
+    (differentiating it raises). Nothing is repeated in or around the
+    kernel, a block the diagonal does not cross builds no mask, and a
+    block above it is no copy and no work (`_live_fwd_kernel`).
+
     `window` W (with `causal`, `mask_block` 1) is the banded mask of a
     sliding-window layer: position i attends j iff 0 <= i - j < W, its
-    own key among the W. Blocks are square, `min(block_q, block_k)`
-    rows and no more than the window rounded up to 128 (a block wider
-    than the band computes scores the mask throws away), and the grid
-    runs over the band's k-blocks alone: the ones wholly below the band
-    are no grid steps, as the ones above the diagonal are none. Forward
-    only: differentiating it raises.
+    own key among the W; K and V by KV head or repeated. A q-block reads
+    the k-blocks that hold its band and no other, in ONE pass, and
+    builds a mask for the ones an edge crosses (`_band_fwd_kernel`);
+    blocks are square, `live_blocks`' and no more than `block_q` and
+    `block_k`. Forward only: differentiating it raises.
 
     `mask_block` B > 1 (with `causal`) is the block-causal rule of
     block-diffusion models: position i attends j iff j // B <= i // B.
     k-blocks wholly above that stepped diagonal are skipped as under
     the causal rule; `mask_block` 1 is the causal kernel unchanged.
 
-    Differentiable (custom VJP, both passes are Pallas kernels).
-    `interpret=None` auto-selects: compiled on TPU, interpreter
+    Otherwise differentiable (custom VJP, both passes are Pallas
+    kernels). `interpret=None` auto-selects: compiled on TPU, interpreter
     elsewhere (the CPU test mesh).
     """
     if q.ndim != 4:
@@ -607,14 +810,25 @@ def flash_attention(
     interpret = _interpret_default() if interpret is None else interpret
     if mask_block < 1 or (mask_block > 1 and not causal):
         raise ValueError(f"mask_block {mask_block} with causal={causal}")
-    if window is not None:
-        if window < 1 or not causal or mask_block != 1:
+    group, ragged = divmod(q.shape[2], k.shape[2])
+    if ragged or v.shape[2] != k.shape[2]:
+        raise ValueError(
+            f"{k.shape[2]} K and {v.shape[2]} V heads under {q.shape[2]} "
+            f"query heads: the KV heads divide the query heads")
+    if window is not None or group > 1:
+        if (window is not None and window < 1) or not causal or (
+                mask_block != 1):
             raise ValueError(
-                f"window {window} with causal={causal}, mask_block "
-                f"{mask_block}: the band is causal, by positions")
-        block = band_block(window, block_q, block_k)
-        return _bhtd(_flash_banded(
-            _bhtd(q), _bhtd(k), _bhtd(v), window, scale, block, interpret))
+                f"window {window}, {group} query heads a KV head with "
+                f"causal={causal}, mask_block {mask_block}: the band is "
+                f"causal, by positions, and so is the call with K and V by "
+                f"KV head")
+        bq, bk = live_blocks(window, q.shape[1], q.shape[3], group)
+        bq, bk = min(block_q, bq), min(block_k, bk)
+        if window is not None:  # the band's blocks are square
+            bq = bk = min(bq, bk)
+        return _bhtd(_flash_live(
+            _bhtd(q), _bhtd(k), _bhtd(v), window, scale, bq, bk, interpret))
     out = _flash(
         _bhtd(q), _bhtd(k), _bhtd(v), mask_block if mask_block > 1 else causal,
         scale, block_q, block_k, interpret,

@@ -573,8 +573,9 @@ def test_latent_attention_programs_of_the_joyai_config_fit_the_chip(
 
 def test_banded_flash_attention_compiles(topo):
     """The window layers' prefill kernel at laguna_xs2_ep16's widths: 64
-    heads of 128 over a 4,096-token row, the band of 512 at blocks of 512
-    (the grid's innermost extent is the band's two k-blocks)."""
+    heads of 128 over a 4,096-token row, K and V repeated to the query
+    heads as a caller without types would hand them, the band of 512 in
+    pieces of 256 rows."""
     from dml_tpu.ops.flash_attention import flash_attention
 
     text, _ = compile_on_chip(
@@ -582,6 +583,32 @@ def test_banded_flash_attention_compiles(topo):
                                 interpret=False),
         *[((1, 4096, 64, 128), jnp.bfloat16)] * 3)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)])
+def test_the_typed_layers_prefill_kernels_compile_by_kv_head(
+        topo, heads, window):
+    """laguna_xs2_ep16's two layer types as `generate.prefill` calls
+    them, a 4,096-token row: 64 query heads under the band of 512 and 48
+    under the causal mask, K and V with the 8 KV heads they have. The
+    chip's compiler takes both, nowhere in the program is a K or a V of
+    the query heads' count (no repeat, in or around the kernel), and a
+    compile stays under 20 s (1.3-3.4 s here, PR 42)."""
+    from dml_tpu.ops.flash_attention import flash_attention
+
+    bf = jnp.bfloat16
+    text, secs = compile_on_chip(
+        topo, functools.partial(flash_attention, causal=True, window=window,
+                                interpret=False),
+        ((1, 4096, heads, 128), bf), ((1, 4096, 8, 128), bf),
+        ((1, 4096, 8, 128), bf))
+    assert text.count("tpu_custom_call") == 1
+    # the repeat was a broadcast of [4096, 8, 128] to [4096, 8, g, 128]
+    # ahead of the kernel (the parent's program holds two)
+    repeated = [ln for ln in text.splitlines()
+                if " broadcast(" in ln and "bf16[" in ln]
+    assert not repeated, repeated[:2]
+    assert secs < 20.0, f"{heads} heads, window {window}: {secs:.1f}s"
 
 
 def test_window_attention_programs_of_the_laguna_config_fit_the_chip(

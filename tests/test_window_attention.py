@@ -7,6 +7,7 @@ plain reference (`benchmark/references/laguna_window_moe.py`: float32 at
 HIGHEST, one full forward, every layer holding every position's k and v,
 the window as a mask), the ONE copy that the cell's `correct` imports too."""
 
+import importlib
 import math
 
 import jax
@@ -24,6 +25,8 @@ from dml_tpu.ops.decode_attention import decode_attention
 from dml_tpu.ops.flash_attention import (band_blocks, band_visits,
                                          flash_attention)
 from dml_tpu.tracing import TRACER
+
+fa = importlib.import_module("dml_tpu.ops.flash_attention")
 
 ref = mf.load_module("references", "laguna_window_moe")
 
@@ -100,13 +103,20 @@ ONE_WINDOW = {**SPEC, "n_layers": 1, "dense_layers": 0, "attention_layers": {
     "layers": ["window"], "types": TYPES}}
 
 
+# the band's blocks: the kernel's own (one block here), and blocks that
+# put the edge and the diagonal in pieces of their own (W = 8: three
+# pieces of 4 rows with an inside one, five of 2, two of 8 over 20 rows)
+@pytest.mark.parametrize("block", [None, 4, 2, 8])
 @pytest.mark.parametrize("split", [20, 12])
-def test_the_edge_is_seen_at_w_minus_one_back_and_not_at_w(split):
+def test_the_edge_is_seen_at_w_minus_one_back_and_not_at_w(
+        split, block, monkeypatch):
     """One window layer, so that a position's logits see W keys and no
     further: the token W - 1 back moves them, the token W back does not
     (bit for bit), through the banded prefill (split 20: position 19 is a
     prompt's) and through the ring (split 12: position 19 is decoded, and
     the ring has wrapped over position 19 - W)."""
+    if block is not None:
+        monkeypatch.setattr(fa, "live_blocks", lambda *a: (block, block))
     _, cfg = lb.lm_spec_parts(ONE_WINDOW)
     params = ref.make_params(ONE_WINDOW, 3)
     toks = _tokens(20, seed=5)
@@ -286,18 +296,32 @@ def _masked_softmax(q, k, v, window):
     (96, 40, 16, (18, 21)),   # a band of four blocks
     (70, 200, 32, (6, 6)),    # a window past the sequence: causal
     (50, 8, 1024, (1, 1)),    # one block
+    (12, 16, 16, (1, 1)),     # T < W, one ragged block
+    (16, 16, 16, (1, 1)),     # T = W, one block
+    (32, 32, 16, (3, 3)),     # T = W, two blocks: the band skips nothing
+    (100, 48, 16, (22, 28)),  # four pieces, two inside, a pad tail of 12
+    (90, 24, 8, (42, 78)),    # a band of four blocks of 8, a pad tail of 6
+    (300, 64, None, (5, 6)),  # the kernel's own blocks (128), a pad tail
+    (600, 512, None, (6, 6)),  # ... of 256: pieces 0, 0-1, 0-2, one inside
 ])
 def test_banded_flash_equals_a_masked_softmax_and_skips_the_blocks_below(
         t, window, block, visited):
     ks = jax.random.split(jax.random.PRNGKey(t), 3)
     q, k, v = (jax.random.normal(kk, (2, t, 3, 16)) for kk in ks)
-    got = flash_attention(q, k, v, window=window, block_q=block,
-                          block_k=block)
+    blocks = {} if block is None else {"block_q": block, "block_k": block}
+    got = flash_attention(q, k, v, window=window, **blocks)
     np.testing.assert_allclose(got, _masked_softmax(q, k, v, window),
                                atol=2e-6)
+    # K and V by KV head (three query heads to one) are the same rows
+    np.testing.assert_allclose(
+        flash_attention(q, k[:, :, :1], v[:, :, :1], window=window,
+                        **blocks),
+        _masked_softmax(q, jnp.repeat(k[:, :, :1], 3, 2),
+                        jnp.repeat(v[:, :, :1], 3, 2), window), atol=2e-6)
     # the grid's innermost extent is the band, not the sequence
-    assert band_visits(t, window, block) == visited
-    assert band_blocks(window, block) == -(-(window - 1) // block) + 1
+    assert band_visits(t, window, block, head_dim=16) == visited
+    if block is not None:
+        assert band_blocks(window, block) == -(-(window - 1) // block) + 1
 
 
 def test_the_band_at_the_real_sizes_and_the_backward_refuses():
@@ -305,6 +329,15 @@ def test_the_band_at_the_real_sizes_and_the_backward_refuses():
     assert band_blocks(512, 512) == 2
     assert band_visits(4096, 512, 512) == (15, 36)
     assert band_visits(512, 512, 512) == (1, 1)
+    # at the kernel's own blocks (64 heads of 128 over 8): three pieces of
+    # 256 rows a q-block, the middle one all inside
+    assert fa.live_blocks(512, 4096, 128, 8) == (256, 256)
+    assert band_visits(4096, 512, head_dim=128, group=8) == (45, 136)
+    assert fa.band_masked(4096, 512, head_dim=128, group=8) == 30
+    assert band_visits(512, 512, head_dim=128, group=8) == (3, 3)
+    assert fa._band_edges(512, 256, 3) == (True, False, True)
+    assert fa._band_edges(512, 128, 5) == (True, False, False, False, True)
+    assert fa._band_edges(40, 16, 4) == (True, True, False, True)
     q = jnp.ones((1, 16, 1, 8))
     with pytest.raises(NotImplementedError, match="know no window"):
         jax.grad(lambda x: flash_attention(x, q, q, window=4).sum())(q)
@@ -624,12 +657,80 @@ def test_spans_and_counters_tell_the_layer_types_apart(model):
         vocab_size=64, d_model=32, n_heads=4, n_layers=1, d_ff=64,
         n_kv_heads=2, attention_layers=g.AttentionLayers(
             (("w", g.AttentionType(4, window=512)),), ("w",)))
-    assert srv._band_label(4096) == {"band_skipped": round(1 - 15 / 36, 4)}
-    assert srv._band_label(512) == {"band_skipped": 0.0}
+    assert srv._band_label(4096)["band_skipped"] == round(1 - 45 / 136, 4)
+    assert srv._band_label(512)["band_skipped"] == 0.0
     # the resident tree holds the gate with the other matrices
     span = TRACER.loop_spans("lm_weights_resident")[-1]
     assert span["lb"]["resident_bytes"] == sum(
         x.nbytes for x in jax.tree.leaves(srv.params))
+
+
+def test_a_prefill_groups_span_says_how_the_band_engages(model):
+    """Beside `band_skipped`: `band_masked`, the share of the k-blocks a
+    window layer's kernel visits that an edge crosses (under 1: the band
+    has an inside, which builds no mask), and `kv_group`, the query heads
+    that share a copy of a K and V block; all three the kernel's own
+    arithmetic at its own blocks, and none without a window layer."""
+    params, cfg = model
+    TRACER.reset()
+    srv, _ = _serve(params, cfg, [_tokens(10)], [3], slots=2)
+    lb_ = TRACER.loop_spans("lm_prefill_group")[-1]["lb"]
+    # one block of the bucket's rows: the diagonal crosses it
+    assert (lb_["band_skipped"], lb_["band_masked"], lb_["kv_group"]) == (
+        0.0, 1.0, 4)
+    # laguna's window layers: 64 heads over 8, W 512, pieces of 256 rows
+    srv.cfg = g.LMConfig(
+        vocab_size=64, d_model=8192, n_heads=64, n_layers=1, d_ff=64,
+        n_kv_heads=8, attention_layers=g.AttentionLayers(
+            (("w", g.AttentionType(64, window=512)),), ("w",)))
+    assert srv._band_label(4096) == {
+        "band_skipped": round(1 - 45 / 136, 4),
+        "band_masked": round(30 / 45, 4), "kv_group": 8}
+    assert srv._band_label(1024) == {
+        "band_skipped": round(1 - 9 / 10, 4),
+        "band_masked": round(6 / 9, 4), "kv_group": 8}
+    assert srv._band_label(4096)["band_masked"] < 1
+    srv.cfg = g.LMConfig(vocab_size=64, d_model=32, n_heads=4, n_layers=1,
+                         d_ff=64, n_kv_heads=2)
+    assert srv._band_label(4096) == {}
+
+
+def _flash_calls(fn, *args):
+    """(q heads, k heads) of every flash kernel call in fn's JAXPR and
+    whether the trace holds a `jnp.repeat`'s gather of K."""
+    calls = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                q, k = (v.aval.shape for v in eqn.invars[:2])
+                # [B, H, T, D], or [B, KV, group, T, D] by KV head
+                calls.append((q[1] * q[2] if len(q) == 5 else q[1], k[1]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return calls
+
+
+def test_typed_layers_hand_k_and_v_by_kv_head_and_untyped_ones_repeat(model):
+    """`generate.prefill` of a stack whose layers have a type calls the
+    flash kernel with K and V as `_apply_block` returned them (2 KV heads
+    under 6 and 8 query heads: no repeat); a stack without types still
+    repeats K and V to the query heads and calls the kernel it called."""
+    params, cfg = model
+    toks = jnp.asarray(_tokens(16)[None])
+    typed = _flash_calls(lambda p: g.prefill(p, cfg, toks, 32)[0], params)
+    assert typed == [(6, 2), (8, 2), (8, 2), (8, 2), (6, 2)]
+    plain = g.LMConfig(vocab_size=256, d_model=64, n_heads=8, n_layers=2,
+                       d_ff=96, n_kv_heads=2)
+    from dml_tpu.models.transformer import TransformerLM
+
+    lm = TransformerLM(vocab_size=256, d_model=64, n_heads=8, n_layers=2,
+                       d_ff=96, n_kv_heads=2)
+    pp = lm.init(jax.random.PRNGKey(0), toks)["params"]
+    assert _flash_calls(
+        lambda p: g.prefill(p, plain, toks, 32)[0], pp) == [(8, 8), (8, 8)]
 
 
 def test_the_gate_is_resident_in_the_compute_dtype_and_quantizes():
