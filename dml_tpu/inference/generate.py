@@ -251,29 +251,42 @@ def rope_by_table(x: jax.Array, positions: jax.Array, freqs: np.ndarray,
 
 @dataclass(frozen=True)
 class AttentionType:
-    """What the attention layers of one type share: the query heads
-    (over the config's `kv_heads` of `head_dim`), the rope, the window
-    (None: position i attends every j <= i, and a slot caches a row a
-    token; W: i attends j iff 0 <= i - j < W, and a slot caches a RING
-    of `W` rows, row p mod W holding position p: `init_cache`), and
+    """What the layers of one type share. An attention type: the query
+    heads (over the config's `kv_heads` of `head_dim`), the rope, the
+    window (None: position i attends every j <= i, and a slot caches a
+    row a token; W: i attends j iff 0 <= i - j < W, and a slot caches a
+    RING of `W` rows, row p mod W holding position p: `init_cache`), and
     whether a sigmoid gate a head, a linear map of the layer's normed
-    input (`head_gate`), scales the heads' outputs before `proj`."""
+    input (`head_gate`), scales the heads' outputs before `proj`.
 
-    n_heads: int
+    A type whose operator is no attention: `conv_kernel` K, the gated
+    short convolution (`conv_mixer`) over the last K positions, in the
+    attention's place in the classic block. It has no heads, rope,
+    window or gate; a slot caches the K - 1 rows before its next
+    position and no row a token."""
+
+    n_heads: int = 0
     rope: RopeConfig = RopeConfig()
     window: Optional[int] = None
     gate: bool = False
+    conv_kernel: Optional[int] = None
 
     def __post_init__(self):
-        if self.n_heads < 1 or (self.window is not None and self.window < 1):
+        if self.conv_kernel is not None:
+            if (self.conv_kernel < 2 or self.n_heads or self.gate
+                    or self.window is not None):
+                raise ValueError(f"convolution layer type {self}")
+        elif self.n_heads < 1 or (
+                self.window is not None and self.window < 1):
             raise ValueError(f"attention layer type {self}")
 
 
 @dataclass(frozen=True)
 class AttentionLayers:
-    """A stack whose attention layers differ by TYPE: the types by name
+    """A stack whose layers' operators differ by TYPE: the types by name
     and each layer's type, ONE description that `LMConfig.attn`
-    answers "layer i's heads, rope, window, gate" from."""
+    answers "layer i's heads, rope, window, gate" (or "a convolution
+    of K positions") from."""
 
     types: Tuple[Tuple[str, AttentionType], ...]
     layers: Tuple[str, ...]
@@ -303,7 +316,9 @@ class LMConfig:
     a full layer caches a K and a V row a token as above, `max_len`
     rows a slot; a window layer the last `window` tokens' alone, a ring
     of that many rows a slot whatever `max_len` is; heads, rope and
-    the output gate are the type's too (`attn`, `layer_rows`).
+    the output gate are the type's too (`attn`, `layer_rows`); a layer
+    whose type is a gated short convolution caches its window's K - 1
+    rows a slot (`conv`) and no row a token.
 
     `kv_quant=True` stores the KV cache as int8 with one f32 scale per
     (position, kv-head) — ~1.9x less cache HBM than bf16, i.e. ~2x the
@@ -371,18 +386,19 @@ class LMConfig:
                     f"n_layers is {self.n_layers}")
             if (self.latent is not None or self.layer_pattern is not None
                     or self.attention_mask != "causal" or not self.rope
-                    or self.qk_norm or self.rope_pairing != "half"):
+                    or self.rope_pairing != "half"):
                 raise ValueError(
                     "attention layers by type are grouped attention under "
                     "the causal mask in a stack of classic blocks, rope in "
                     "halves: no latent attention, layer_pattern, "
-                    "block_causal mask, qk_norm, interleaved pairing, and "
-                    "rope on")
+                    "block_causal mask, interleaved pairing, and rope on")
             if self.has_ring and self.kv_quant:
                 raise ValueError(
                     "a window layer's ring of rows is cached unquantized: "
                     "no kv_quant")
             for _, t in al.types:
+                if t.conv_kernel is not None:
+                    continue
                 if t.n_heads % self.kv_heads:
                     raise ValueError(
                         f"{self.kv_heads} KV heads do not divide a layer "
@@ -426,7 +442,7 @@ class LMConfig:
                 raise ValueError(
                     f"layer_pattern {pat!r} has {len(pat)} layers, "
                     f"n_layers is {self.n_layers}")
-        if self.has_state != (self.ssm is not None):
+        if ("M" in (pat or "")) != (self.ssm is not None):
             raise ValueError(
                 "state-space sizes (`ssm`) come with a layer_pattern "
                 "that holds a state-space layer, and only with one")
@@ -438,9 +454,18 @@ class LMConfig:
 
     @property
     def has_state(self) -> bool:
-        """Whether a sequence carries recurrent state beside K/V rows:
-        state that cannot be cut by token or rolled back."""
-        return "M" in (self.layer_pattern or "")
+        """Whether some layer carries state that is no row a token:
+        state that cannot be cut by token or rolled back (a state-space
+        layer's scan state and convolution window; a gated short
+        convolution's window, scan or no scan)."""
+        return "M" in (self.layer_pattern or "") or self.has_conv
+
+    @property
+    def has_conv(self) -> bool:
+        """Whether some layer's type is the gated short convolution."""
+        al = self.attention_layers
+        return al is not None and any(
+            al.of(i).conv_kernel is not None for i in range(self.n_layers))
 
     @property
     def has_ring(self) -> bool:
@@ -452,7 +477,8 @@ class LMConfig:
             al.of(i).window is not None for i in range(self.n_layers))
 
     def attn(self, i: int) -> AttentionType:
-        """Attention layer i's type: its heads, rope, window and gate."""
+        """Layer i's type: its heads, rope, window and gate (or its
+        convolution, where that is its operator)."""
         if self.attention_layers is None:
             return AttentionType(self.n_heads, RopeConfig(self.rope_theta))
         return self.attention_layers.of(i)
@@ -461,9 +487,12 @@ class LMConfig:
         """Cache rows a slot holds in attention layer i: the ring of a
         window layer (`window` rows: position p lives in row p mod
         window, so the window's 512 are there and no other; a window of
-        `max_len` and more is a full layer's plane), else `max_len`."""
-        w = self.attn(i).window
-        return max_len if w is None else min(w, max_len)
+        `max_len` and more is a full layer's plane), else `max_len`;
+        none in a layer whose operator is a convolution."""
+        t = self.attn(i)
+        if t.conv_kernel is not None:
+            return 0
+        return max_len if t.window is None else min(t.window, max_len)
 
     @property
     def head_dim(self) -> int:
@@ -482,6 +511,27 @@ class LMConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads whose rows one cached row holds side by side: heads
+        narrower than the chip's 128 lanes that fill them exactly (two of
+        64) share a row of a [B, KV / r, T, r D] plane. The chip lays a
+        64-column plane out in 128 whatever the program says (a grid
+        twice its bytes, or a copy of it a dispatch: ahead-of-time
+        compile, PR 44; `LatentConfig.row_stride` met the same), so the
+        leaf states what fills a tile (`pack_rows`, `packed_attention`).
+        Only in a stack whose layers go by type, whose rows nothing but
+        `prefill`, `batched_decode_step` and a server's inserts touch
+        (what cuts, ships or re-attends rows by head refuses such a
+        stack), and not over a ring or an int8 cache; 1 everywhere
+        else."""
+        d = self.head_dim
+        if (d >= 128 or 128 % d or self.kv_heads % (128 // d)
+                or self.attention_layers is None or self.has_ring
+                or self.kv_quant):
+            return 1
+        return 128 // d
 
 
 #: every leaf a layer of a slot-grid cache can hold: its kind
@@ -509,7 +559,9 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
     (the normalised latent | the roped shared key | zeros to whole
     lane tiles: `LatentConfig`), in the layout of a single-KV-head
     plane so that everything that cuts, copies or streams rows treats
-    it as one. A WINDOW layer (`LMConfig.attention_layers`): `k_ring`
+    it as one. Heads narrower than a lane tile, in a stack of typed
+    layers: [B, KV / r, max_len, r D], r heads' rows side by side
+    (`LMConfig.kv_pack`). A WINDOW layer (`LMConfig.attention_layers`): `k_ring`
     and `v_ring` [B, KV, W, D], a ring of the window's W rows a slot
     whatever `max_len` is (`LMConfig.layer_rows`): position p is
     written at row p mod W, over position p - W, which no later query
@@ -531,12 +583,20 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int) -> Dict[str, Any]:
     `conv_kernel - 1` rows of the convolution's input ([B, K-1, C], the
     model's dtype) and the scan state ([B, H, P, N], float32: the
     recurrence runs for as many steps as a sequence has tokens); nothing
-    in an expert layer, which has no entry."""
-    shape = (batch, cfg.kv_heads, max_len, cfg.head_dim)
+    in an expert layer, which has no entry. A layer whose TYPE is the
+    gated short convolution (`AttentionType.conv_kernel` K) holds the
+    `conv` leaf alone, the last K - 1 rows of the convolution's input
+    [B, K-1, d], beside other layers' rows."""
+    shape = (batch, cfg.kv_heads // cfg.kv_pack, max_len,
+             cfg.kv_pack * cfg.head_dim)
     sshape = (batch, cfg.kv_heads, 1, max_len)
 
     def layer(i, kind):
-        if cfg.attn(i).window is not None and kind is None:
+        typ = cfg.attn(i)
+        if typ.conv_kernel is not None and kind is None:
+            return {"conv": jnp.zeros(
+                (batch, typ.conv_kernel - 1, cfg.d_model), cfg.dtype)}
+        if typ.window is not None and kind is None:
             ring = (batch, cfg.kv_heads, cfg.layer_rows(i, max_len),
                     cfg.head_dim)
             return {"k_ring": jnp.zeros(ring, cfg.dtype),
@@ -594,7 +654,8 @@ def state_bytes(cache: Dict[str, Any]) -> Dict[str, int]:
     attention's rows and scales, a row a token), `kv_window` (the
     window layers' rings, which do not grow with `max_len`), `latent`
     (latent attention's rows), `conv` and `scan` (a state-space layer's
-    convolution window and recurrent state)."""
+    convolution window and recurrent state; a gated short convolution's
+    window is `conv` too)."""
     out = {"kv": 0, "kv_window": 0, "latent": 0, "conv": 0, "scan": 0}
     for lay in cache.values():
         for key, leaf in lay.items():
@@ -1025,6 +1086,76 @@ def ssm_scan_step(x, dt, a, bm, cm, h):
     return jnp.sum(h * ch[:, :, None, :], axis=-1), h
 
 
+def causal_conv(
+    x: jax.Array,  # [B, T, C]: the convolution's input
+    kernel: jax.Array,  # [K, C], tap K - 1 on the current position
+    state: Optional[jax.Array] = None,  # [B, K-1, C]: the rows before x
+    lengths: Optional[jax.Array] = None,  # [B] int32: rows' own lengths
+    bias: Optional[jax.Array] = None,  # [C]
+    activation=None,
+) -> Tuple[jax.Array, jax.Array]:
+    """The causal depthwise convolution both stateful mixers share:
+    `c_t = bias + sum_i kernel[i] x_{t - (K-1) + i}` a channel, in
+    float32, through `activation` where one is given; positions before
+    the sequence's start are `state` (zeros without one). Returns (c
+    [B, T, C] float32, the window [B, K-1, C] in x's dtype: the last K
+    - 1 rows of the input, what the next call is handed as `state`).
+    With `lengths` the window is taken at row b's OWN length,
+    positions lengths[b] - (K-1) .. lengths[b] - 1 (zeros or `state`
+    on the left of a row shorter than the window), so a row padded to
+    a bucket hands back the window of the unpadded row."""
+    b, t, c = x.shape
+    kk = kernel.shape[0]
+    f32 = jnp.float32
+    left = (jnp.zeros((b, kk - 1, c), x.dtype) if state is None
+            else state.astype(x.dtype))
+    full = jnp.concatenate([left, x], axis=1)  # [B, K-1+T, C]
+    w = kernel.astype(f32)
+    conv = sum(full[:, i:i + t].astype(f32) * w[i] for i in range(kk))
+    if bias is not None:
+        conv = bias.astype(f32) + conv
+    if activation is not None:
+        conv = activation(conv)
+    if lengths is None:
+        window = full[:, t:]
+    else:
+        window = jax.vmap(
+            lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, kk - 1, 0)
+        )(full, lengths.astype(jnp.int32))
+    return conv, window
+
+
+def conv_mixer(
+    p: Dict[str, Any],  # a block's "short_conv" subtree
+    cfg: LMConfig,
+    y: jax.Array,  # [B, T, d], normalised
+    state: Optional[Dict[str, jax.Array]] = None,
+    lengths: Optional[jax.Array] = None,  # [B] int32: rows' own lengths
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The gated short convolution (the LFM2 family's operator): `[B |
+    C | X] = in_proj y`, thirds of `d` columns in that order; `z = B *
+    X`; `c = causal_conv(z)` over the last K positions (K the kernel's
+    rows; depthwise, no bias, no activation); `out_proj (C * c)`. No
+    scan, no step size: what a sequence carries is the K - 1 rows of z
+    before its next position, {"conv": [B, K-1, d]}, whatever its
+    length. `state` None = a sequence's start; with `lengths` the
+    window handed back is the row's own (`causal_conv`). One position
+    (a decode step) is the same arithmetic at T = 1: the cached rows
+    and the new one."""
+    d = cfg.d_model
+    with part("conv_proj"):
+        bcx = y @ kernel_of(p["in_proj"], cfg.dtype)  # [B, T, 3d]
+        z = bcx[..., :d] * bcx[..., 2 * d:]
+    with part("conv_mix"):
+        c, window = causal_conv(
+            z, p["conv"]["kernel"],
+            None if state is None else state["conv"], lengths)
+    with part("conv_proj"):
+        out = (bcx[..., d:2 * d] * c.astype(cfg.dtype)) @ kernel_of(
+            p["out_proj"], cfg.dtype)
+    return out, {"conv": window}
+
+
 def ssm_mixer(
     p: Dict[str, Any],  # a block's "ssm" subtree
     cfg: LMConfig,
@@ -1052,30 +1183,22 @@ def ssm_mixer(
     s = cfg.ssm
     b, t, _ = y.shape
     f32 = jnp.float32
-    di, gn, kk = s.d_inner, s.groups * s.state, s.conv_kernel
+    di, gn = s.d_inner, s.groups * s.state
     with part("ssm_proj"):
         zxbcdt = y @ kernel_of(p["in_proj"], cfg.dtype)
         z = zxbcdt[..., :di]
         xbc = zxbcdt[..., di:di + s.conv_width]
         dt = zxbcdt[..., di + s.conv_width:]
-        left = (jnp.zeros((b, kk - 1, s.conv_width), xbc.dtype)
-                if state is None else state["conv"].astype(xbc.dtype))
-        full = jnp.concatenate([left, xbc], axis=1)  # [B, K-1+T, C]
-        w = p["conv"]["kernel"].astype(f32)  # [K, C]
-        conv = p["conv"]["bias"].astype(f32) + sum(
-            full[:, i:i + t].astype(f32) * w[i] for i in range(kk))
-        xbc = jax.nn.silu(conv).astype(cfg.dtype)
+        conv, window = causal_conv(
+            xbc, p["conv"]["kernel"],
+            None if state is None else state["conv"], lengths,
+            bias=p["conv"]["bias"], activation=jax.nn.silu)
+        xbc = conv.astype(cfg.dtype)
         dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
-        if lengths is None:
-            window = full[:, t:]
-        else:
+        if lengths is not None:
             dt = jnp.where(
                 jnp.arange(t)[None, :, None] < lengths[:, None, None],
                 dt, 0.0)
-            window = jax.vmap(
-                lambda row, n: jax.lax.dynamic_slice_in_dim(
-                    row, n, kk - 1, 0)
-            )(full, lengths.astype(jnp.int32))
         a = -jnp.exp(p["A_log"].astype(f32))
         x = xbc[..., :di].reshape(b, t, s.heads, s.head_dim)
         bm = xbc[..., di:di + gn].reshape(b, t, s.groups, s.state)
@@ -1281,7 +1404,7 @@ def _apply_block(
     experts: Optional[Dict[str, Any]] = None,
     mesh: Optional[Mesh] = None,
     kind: Optional[str] = None,
-    ssm_fn=None,  # (ssm subtree, y [B,T,d]) -> [B,T,d]
+    ssm_fn=None,  # (a stateful mixer's subtree, y [B,T,d]) -> [B,T,d]
     absorbed: bool = False,  # latent attention's form (`_latent_attention`)
     lay: Optional[AttentionType] = None,  # the layer's type (`_attention`)
 ) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
@@ -1295,7 +1418,11 @@ def _apply_block(
     under its own norm: it matches models/transformer.py
     layer-for-layer where `cfg` is at its defaults; `cfg.d_head`,
     `rope_theta`, `qk_norm` and the expert keys are the architectures
-    `lm_spec` describes beyond it. A kind of `LAYER_KINDS` (a
+    `lm_spec` describes beyond it; where the layer's type (`lay`) is a
+    gated short convolution, that operator stands in the attention's
+    place under the same norm, run by the caller's `ssm_fn` closure
+    (`conv_mixer` with the window it owns), and no k/v come back. A
+    kind of `LAYER_KINDS` (a
     `layer_pattern`'s layer) is `x + mixer(norm(x))` with that ONE
     mixer: attention, the expert feed-forward, or the state-space
     mixer, which the caller's `ssm_fn` closure runs (`ssm_mixer` with
@@ -1313,9 +1440,14 @@ def _apply_block(
     # a mixer's norm goes by the mixer's (first) part
     ffn = "moe_route" if "moe" in blk else "mlp"
     if kind is None:
-        with part("attn_proj"):
+        conv = lay is not None and lay.conv_kernel is not None
+        with part("conv_proj" if conv else "attn_proj"):
             y = _rms_norm(x, blk["ln_attn"]["scale"], cfg.dtype, cfg.norm_eps)
-        out, k, v = _attention(blk, cfg, y, positions, attn_fn, absorbed, lay)
+        if conv:
+            out, k, v = ssm_fn(blk["short_conv"], y), None, None
+        else:
+            out, k, v = _attention(
+                blk, cfg, y, positions, attn_fn, absorbed, lay)
         x = x + out
         with part(ffn):
             y = _rms_norm(x, blk["ln_mlp"]["scale"], cfg.dtype, cfg.norm_eps)
@@ -1335,9 +1467,18 @@ def _lm_head(params: Dict[str, Any], cfg: LMConfig, x: jax.Array) -> jax.Array:
     stored in float32 (or int8) multiplies in float32, as
     TransformerLM's does; one stored in the model's compute dtype
     (`lm_spec`'s `param_dtype`) multiplies in it and accumulates in
-    float32, so no float32 copy of it is ever made."""
+    float32, so no float32 copy of it is ever made. A tree without an
+    `lm_head` ties the head to the embedding: the logits are the normed
+    rows against the table [V, d], contracted over d in the same
+    dtypes."""
     with part("head"):
         x = _rms_norm(x, params["ln_out"]["scale"], cfg.dtype, cfg.norm_eps)
+        if "lm_head" not in params:
+            table = params["embed"]["embedding"]
+            if table.dtype != cfg.dtype:
+                x, table = x.astype(jnp.float32), table.astype(jnp.float32)
+            return jnp.einsum("...d,vd->...v", x, table,
+                              preferred_element_type=jnp.float32)
         kern = params["lm_head"]["kernel"]
         if (not isinstance(kern, dict)
                 and kern.dtype == cfg.dtype != jnp.float32):
@@ -1472,7 +1613,7 @@ def decode_block_rows(
     tp = mesh.shape["tp"] if heads_axis(
         mesh, cfg.n_heads, cfg.kv_heads) else 1
     return block_rows(
-        cfg.kv_heads // tp, cfg.head_dim,
+        cfg.kv_heads // cfg.kv_pack // tp, cfg.kv_pack * cfg.head_dim,
         jnp.int8 if cfg.kv_quant else cfg.dtype,
         cfg.layer_rows(layer, max_len),
     )
@@ -1491,6 +1632,51 @@ def _write_rows(c: jax.Array, u: jax.Array, pos: jax.Array,
             start[axis] = pos[bi]
             c = jax.lax.dynamic_update_slice(c, u[bi : bi + 1], start)
         return c
+
+
+def pack_rows(cfg: LMConfig, x: jax.Array) -> jax.Array:
+    """K or V rows [B, T, KV, D] as the cache holds them, head-major:
+    [B, KV / r, T, r D], `LMConfig.kv_pack` r neighbouring heads' rows
+    side by side (r = 1: [B, KV, T, D], a transpose and no more)."""
+    b, t, kv, d = x.shape
+    r = cfg.kv_pack
+    return jnp.swapaxes(x.reshape(b, t, kv // r, r * d), 1, 2)
+
+
+def unpack_rows(cfg: LMConfig, c: jax.Array) -> jax.Array:
+    """A cache plane [B, KV / r, T, r D] by head, [B, KV, T, D]: the
+    einsum route's view (the tests' oracle), a copy the kernel route
+    never makes."""
+    r = cfg.kv_pack
+    if r == 1:
+        return c
+    b, kvp, t, _ = c.shape
+    return jnp.swapaxes(c.reshape(b, kvp, t, r, cfg.head_dim), 2, 3).reshape(
+        b, kvp * r, t, cfg.head_dim)
+
+
+def packed_attention(cfg: LMConfig, kernel, q: jax.Array, ck, cv, n_rows):
+    """`kernel(q, ck, cv, n_rows)` over planes that hold `kv_pack` r
+    heads a row: query head h (of KV head h // G, the j-th of its row's
+    r) is widened to the row, zeros in the other heads' columns, so its
+    scores against a packed row are its scores against its own head's
+    key and no other; a row then serves r G query heads as ONE head of
+    r D columns, which is a shape the kernel has always taken. Of the r
+    D columns that come back a head keeps its own D: the others are its
+    probabilities over ANOTHER head's values. The zeros cost arithmetic
+    the memory-bound step does not miss, and no byte of cache. q
+    [B, Q, H, D] -> [B, Q, H, D] f32."""
+    r, hd = cfg.kv_pack, cfg.head_dim
+    if r == 1:
+        return kernel(q, ck, cv, n_rows)
+    b, qn, h, _ = q.shape
+    g = h // cfg.kv_heads
+    place = jnp.eye(r, dtype=q.dtype)
+    wide = jnp.einsum(
+        "bqpjgd,jk->bqpjgkd", q.reshape(b, qn, -1, r, g, hd), place)
+    out = kernel(wide.reshape(b, qn, h, r * hd), ck, cv, n_rows)
+    out = out.reshape(b, qn, -1, r, g, r, hd)
+    return jnp.einsum("bqpjgjd->bqpjgd", out).reshape(b, qn, h, hd)
 
 
 def _latent_rows(cfg: LMConfig, rows: jax.Array) -> jax.Array:
@@ -1564,7 +1750,9 @@ def batched_decode_step(
     A state-space layer advances its slot's state by the one position
     (`ssm_mixer`'s step form), whatever the slot holds: an empty
     slot's state is garbage nobody reads, overwritten whole by the
-    next placement. `experts` is `_apply_block`'s.
+    next placement. A gated short convolution does the same with its
+    window (`conv_mixer`: the cached rows and the new one). `experts`
+    is `_apply_block`'s.
 
     A WINDOW layer (`LMConfig.attention_layers`) writes its row at
     `pos mod W` of its ring of W rows and attends the ring's first
@@ -1593,7 +1781,7 @@ def batched_decode_step(
         c_spec = P(None, ax, None, None)  # [B, KV, T, D] / [B, KV, 1, T]
         kernel = _kernel_on_mesh(
             lambda q, k, v, n, ks=None, vs=None: decode_attention(
-                q, k, v, n, k_scale=ks, v_scale=vs),
+                q, k, v, n, k_scale=ks, v_scale=vs, scale=hd ** -0.5),
             mesh,
             in_specs=(q_spec, c_spec, c_spec, P())
             + ((c_spec, c_spec) if cfg.kv_quant else ()),
@@ -1604,8 +1792,11 @@ def batched_decode_step(
     for i, kind in enumerate(cfg.kinds):
         name = f"block_{i}"
 
-        def ssm_fn(p, y, name=name):
-            out, new_cache[name] = ssm_mixer(p, cfg, y, cache[name])
+        typ = cfg.attn(i)
+
+        def ssm_fn(p, y, name=name,
+                   mixer=ssm_mixer if typ.conv_kernel is None else conv_mixer):
+            out, new_cache[name] = mixer(p, cfg, y, cache[name])
             return out
 
         def latent_fn(q, rows, name=name):
@@ -1615,7 +1806,6 @@ def batched_decode_step(
             new_cache[name] = {"latent": leaf}
             return out
 
-        typ = cfg.attn(i)
         ring = (cfg.layer_rows(i, max_len) if typ.window is not None
                 else None)
 
@@ -1626,8 +1816,8 @@ def batched_decode_step(
             n_rows, vmask = lengths, valid
             with part("cache_write"):
                 upd = functools.partial(_write_rows, pos=pos)
-                kh = jnp.swapaxes(k, 1, 2)  # [B, KV, 1, D]
-                vh = jnp.swapaxes(v, 1, 2)
+                kh = pack_rows(cfg, k)  # [B, KV, 1, D]
+                vh = pack_rows(cfg, v)
                 if ring is not None:
                     # the ring's rows < min(lengths[b], W), written at
                     # pos mod W
@@ -1669,7 +1859,8 @@ def batched_decode_step(
                         lay["v_q"], jnp.swapaxes(lay["v_s"], 2, 3)
                     )
                 elif use_kernel:
-                    return kernel(q, ck, cv, n_rows)
+                    return packed_attention(cfg, kernel, q, ck, cv, n_rows)
+                ck, cv = unpack_rows(cfg, ck), unpack_rows(cfg, cv)
                 qg = q.astype(jnp.float32).reshape(
                     b, 1, cfg.kv_heads, grp, hd)
                 s = jnp.einsum(
@@ -1757,7 +1948,8 @@ def batched_block_step(
         # state that has taken them in allows neither
         raise ValueError(
             "the multi-token cached forward cannot roll a state-space "
-            "layer's state back; speculation and block diffusion need it to")
+            "layer's state or a convolution's window back; speculation "
+            "and block diffusion need it to")
     if cfg.attention_layers is not None:
         # T rows written at once overwrite positions the step's first
         # queries still attend (a ring holds the window's rows and no
@@ -1932,7 +2124,8 @@ def prefill(
     a `logits_index` every row's state is taken at its own length,
     `logits_index + 1` (`ssm_mixer`'s `lengths`): the cache a padded
     row hands back holds, beside its K/V rows, the convolution window
-    and scan state of the unpadded prompt. A WINDOW layer
+    and scan state of the unpadded prompt (a gated short convolution's
+    window the same way: `conv_mixer`). A WINDOW layer
     (`LMConfig.attention_layers`) attends under the banded mask (`0 <=
     i - j < W`; the flash kernel visits the band's k-blocks alone) and
     hands back its RING, filled as decode would have left it after the
@@ -1992,12 +2185,14 @@ def prefill(
     for i, kind in enumerate(cfg.kinds):
         name = f"block_{i}"
 
-        def ssm_fn(p, y, name=name):
-            out, cache[name] = ssm_mixer(p, cfg, y, lengths=lengths)
-            return out
-
         typ = cfg.attn(i)
         window = typ.window
+
+        def ssm_fn(p, y, name=name,
+                   mixer=ssm_mixer if typ.conv_kernel is None else conv_mixer):
+            out, cache[name] = mixer(p, cfg, y, lengths=lengths)
+            return out
+
         x, k, v = _apply_block(
             params[name], cfg, x, positions,
             attn_fn if window is None else functools.partial(
@@ -2020,8 +2215,8 @@ def prefill(
                 cache[name] = {
                     "latent": jnp.pad(_latent_rows(cfg, k), pad4)}
                 continue
-            kh = jnp.swapaxes(k, 1, 2)  # [B, KV, Tp, D] — cache layout
-            vh = jnp.swapaxes(v, 1, 2)
+            kh = pack_rows(cfg, k)  # [B, KV, Tp, D] — cache layout
+            vh = pack_rows(cfg, v)
             if cfg.kv_quant:
                 kq, ks = _kv_quantize(kh)
                 vq, vs = _kv_quantize(vh)

@@ -111,7 +111,7 @@ _ARCH_KEYS = (
     "block_length", "param_dtype", "layer_pattern", "ssm", "rope",
     "norm_eps", "router", "expert_latent", "shared_expert_d_ff",
     "activation", "attention", "latent_attention", "rope_pairing",
-    "dense_layers", "attention_layers",
+    "dense_layers", "attention_layers", "tied_head",
 )
 _SSM_KEYS = ("heads", "head_dim", "state", "groups", "conv_kernel", "chunk")
 _ROUTER_KEYS = ("scoring", "bias", "scale")
@@ -124,12 +124,14 @@ _LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
 #: `time_step_max`, `time_step_floor`, which shape the initialisation only
 _DT_INIT = (0.001, 0.1, 1e-4)
 _DTYPES = ("bfloat16", "float32")
-#: `attention_layers`: the attention layers' types as data. `layers`
-#: names each layer's type, `types` describes each name once: its query
-#: heads, its rope (`theta`; `rotary_dim`, the columns of a head it
+#: `attention_layers`: the layers' types as data. `layers` names each
+#: layer's type, `types` describes each name once. An attention type: its
+#: query heads, its rope (`theta`; `rotary_dim`, the columns of a head it
 #: rotates; `yarn`, the frequencies' scaling), its `window` (a type that
-#: has one caches a ring of that many rows a slot), its `gate`
-_LAYER_TYPE_KEYS = ("n_heads", "rope", "window", "gate")
+#: has one caches a ring of that many rows a slot), its `gate`. A type
+#: whose operator is the gated short convolution: `conv_kernel`, the
+#: positions it runs over, and no other key
+_LAYER_TYPE_KEYS = ("n_heads", "rope", "window", "gate", "conv_kernel")
 _ROPE_KEYS = ("theta", "rotary_dim", "yarn")
 _YARN_KEYS = ("factor", "original_max_position", "beta_fast", "beta_slow",
               "attention_factor")
@@ -151,6 +153,14 @@ def _attention_layers(al: Any, n_layers: int) -> AttentionLayers:
             f"described are {sorted(al['types'])}")
     types = []
     for name, t in al["types"].items():
+        if isinstance(t, dict) and t.get("conv_kernel") is not None:
+            if set(t) != {"conv_kernel"}:
+                raise ValueError(
+                    f"layer type {name!r} {t!r}: a convolution type is "
+                    f"its conv_kernel and no other key")
+            types.append((name, AttentionType(
+                conv_kernel=int(t["conv_kernel"]))))
+            continue
         if not isinstance(t, dict) or set(t) - set(_LAYER_TYPE_KEYS) or (
                 "n_heads" not in t):
             raise ValueError(
@@ -223,6 +233,7 @@ def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
     a["latent_attention"] = spec.get("latent_attention")
     a["rope_pairing"] = spec.get("rope_pairing", "half")
     a["dense_layers"] = int(spec.get("dense_layers", 0) or 0)
+    a["tied_head"] = bool(spec.get("tied_head", False))
     a["attention_layers"] = None
     if spec.get("attention_layers") is not None:
         # what a stack of typed layers cannot be, or what no code here
@@ -230,9 +241,9 @@ def lm_arch(spec: Dict[str, Any]) -> Dict[str, Any]:
         for key, why in (
                 ("latent_attention", "its layers are grouped attention"),
                 ("layer_pattern", "it is served in classic blocks"),
-                ("qk_norm", "no type describes a q/k norm"),
-                ("denoising_steps", "a ring cannot be rewritten by a "
-                                    "block's forwards")):
+                ("denoising_steps", "neither a ring nor a convolution's "
+                                    "window can be rewritten by a block's "
+                                    "forwards")):
             if spec.get(key):
                 raise ValueError(f"{key} under attention_layers: {why}")
         if (a["attention_mask"] != "causal" or a["rope"] != "rotary"
@@ -423,7 +434,11 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
     form multiplies them apart); `proj` is [H * v, d]. Under
     `attention_layers` a block's `qkv` and `proj` take its TYPE's query
     heads, and a gated type's block holds `head_gate` [d, H] beside
-    them.
+    them; a block whose type is the gated short convolution holds, in
+    their place, `short_conv` {in_proj [d, B | C | X = 3d], conv
+    {kernel [K, d]} (float32, uniform within 1 / sqrt(K)), out_proj
+    [d, d]}. Under `tied_head` the tree holds no `lm_head`: the head is
+    the embedding (`generate._lm_head`).
 
     Under a `layer_pattern` a block holds one norm (`ln`) and its
     mixer's leaves alone: `qkv` and `proj`, or `moe`, or `ssm`
@@ -501,10 +516,18 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
         if cfg.attention_layers is None:
             return attention
         t = cfg.attn(i)
+        if t.conv_kernel is not None:
+            return {"short_conv": {
+                "in_proj": {"kernel": (d, 3 * d)},
+                "conv": {"kernel": (t.conv_kernel, d)},
+                "out_proj": {"kernel": (d, d)}}}
+        norms = {k: attention[k] for k in ("q_norm", "k_norm")
+                 if k in attention}
         return {
             "qkv": {"kernel": (d, t.n_heads * hd + 2 * kvw)},
             "proj": {"kernel": (t.n_heads * hd, d)},
             **({"head_gate": {"kernel": (d, t.n_heads)}} if t.gate else {}),
+            **norms,
         }
 
     shapes: Dict[str, Any] = {"embed": {"embedding": (cfg.vocab_size, d)}}
@@ -515,7 +538,8 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
             if kind is None
             else {"ln": {"scale": (d,)}, **mixers[kind]})
     shapes["ln_out"] = {"scale": (d,)}
-    shapes["lm_head"] = {"kernel": (d, cfg.vocab_size)}
+    if not arch["tied_head"]:
+        shapes["lm_head"] = {"kernel": (d, cfg.vocab_size)}
     is_shape = lambda x: isinstance(x, tuple)
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=is_shape)
@@ -536,7 +560,10 @@ def init_lm_params(cfg: LMConfig, arch: Dict[str, Any], seed: int):
                     k, shape, jnp.float32, jnp.log(lo), jnp.log(hi))))
                 out.append(dt + jnp.log(-jnp.expm1(-dt)))
             elif "conv" in name:
-                lim = cfg.ssm.conv_kernel ** -0.5
+                # a convolution's [K, C] kernel (and a state-space
+                # layer's bias beside it)
+                lim = (shape[0] if name[-1] == "kernel"
+                       else cfg.ssm.conv_kernel) ** -0.5
                 out.append(jax.random.uniform(
                     k, shape, jnp.float32, -lim, lim))
             elif name[-1] == "bias":  # the router's selection bias
@@ -565,8 +592,9 @@ def lm_spec_parts(spec: Dict[str, Any]):
     `_ARCH_KEYS` is TransformerLM's block, initialised by the flax
     module and stored in float32 as ever; one that sets any of them
     (another head size, a rope base or pairing, q/k norms, latent
-    attention, attention layers by type, a gated MLP, gated top-k experts under leading dense
-    layers, the block-causal mask, `param_dtype`) gets
+    attention, layers by type (attention, or a gated short convolution),
+    a gated MLP, gated top-k experts under leading dense layers, the
+    block-causal mask, a tied head, `param_dtype`) gets
     `init_lm_params`' tree,
     its matrices stored in `param_dtype`, every layer an expert layer
     where `num_experts` is set, or, under `layer_pattern`, one mixer a

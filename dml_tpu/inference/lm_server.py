@@ -339,6 +339,16 @@ def _group_rows(k: int, bucket: int, max_slots: int) -> int:
 #: grow with the tokens, and beside 9.3 GB of weights and a 64-slot grid
 #: a (512, 64) group does not fit. A bound on the group, so that no slot,
 #: width or row has to give way (PERF.md section 4 has the arithmetic).
+#: A stack of gated short convolutions has no such transients, and keeps
+#: the bound for what its groups hand BACK: a row's K and V padded to
+#: max_len in every attention layer (48 MiB a row at 6 layers of 8 x 64 x
+#: 4,096), beside a 128-slot grid and the weights (10.8 GiB resident).
+#: Ahead of time for a described v5e (PR 44, `tools/aot_memory_window.py`):
+#: a (512, 16) group holds 777 MiB of temporaries and 774 MiB of rows, a
+#: (512, 32) group 1,457 + 1,549, a (512, 128) group 5.5 + 6.2 GiB, which
+#: the chip does not have; and 8,192 tokens are already bound by their
+#: matmuls (14 TFLOP against one 5 GB weight read), so more rows a call
+#: would buy nothing but a longer stall of the grid's decode.
 _STATE_GROUP_TOKENS = 8192
 
 #: ... and of a model with latent attention: no two rows of the shortest
@@ -663,13 +673,15 @@ class LMServer:
                     f"max_len {max_len} is no whole number of blocks "
                     f"of {cfg.block_length}")
         if cfg.has_state and (diffusion is not None or self._mesh is not None):
-            # a state-space layer's state is whole-sequence and per slot:
+            # a state-space layer's state is whole-sequence and per slot
+            # (a gated short convolution's window its last positions'):
             # the denoising forwards rewrite rows it has already taken in,
             # and no sharding rule places it over a mesh yet
             raise ValueError(
-                "a model with a state-space layer is served by the plain "
-                "chunked loop on one device: block diffusion and the "
-                "sharded forms cannot hold its state")
+                "a model with a state-space layer or a gated short "
+                "convolution is served by the plain chunked loop on one "
+                "device: block diffusion and the sharded forms cannot "
+                "hold its state")
         if cfg.latent is not None and self._mesh is not None:
             # no rule yet says where a latent row lives over a mesh: it
             # has no heads to divide, and every head reads all of it
@@ -682,8 +694,12 @@ class LMServer:
             _M_STATE_BYTES.set(n, kind=kind)
         # whether a placement round enqueues ONE prefill group at a time
         # (`_place_group` says why): the deep models, whose groups hand
-        # back tenths of a GB of rows each
-        self._one_group = cfg.latent is not None or cfg.has_ring
+        # back tenths of a GB of rows each, and a stack with gated short
+        # convolutions, which caches so little a token that it is served
+        # over a grid of a hundred slots and more: a round into an empty
+        # grid places as many rows, each handed back padded to max_len
+        self._one_group = (cfg.latent is not None or cfg.has_ring
+                           or cfg.has_conv)
         # the most padded tokens a prefill group of several rows holds
         self._group_tokens = (
             _STATE_GROUP_TOKENS if cfg.has_state
@@ -692,7 +708,7 @@ class LMServer:
         # a layer of that type) a type the stack holds
         kinds = {}
         for i, kind in enumerate(cfg.kinds):
-            if kind in (None, "*"):
+            if kind in (None, "*") and cfg.attn(i).conv_kernel is None:
                 kinds.setdefault(
                     "full" if cfg.attn(i).window is None else "window", i)
         # (a stack without attention counts nothing, under "full")
@@ -1021,17 +1037,22 @@ class LMServer:
         """Raise where what a slot of this server's model carries is
         not a row a token a layer under ONE attention type, and `what`
         needs it to be: a state-space layer's state holds a whole
-        sequence in one array, and a window layer's ring its last
+        sequence in one array (a gated short convolution's window its
+        last positions' products, scan or no scan), and a window
+        layer's ring its last
         `window` positions, wrapped; neither can be cut at a token from
         its start nor rolled back past what it overwrote, as K/V rows
         can. (A stack of typed layers without a window could be cut;
         what cuts and re-attends rows here reads one head count and one
         rope for the whole stack, so it is refused with the others.)"""
         if self.cfg.has_state:
+            carried = ("gated short convolutions carry a convolution window"
+                       if self.cfg.has_conv else
+                       "state-space layers carry a scan state and a "
+                       "convolution window")
             raise ValueError(
                 f"{what} needs state that can be cut by token or rolled "
-                f"back; this model's state-space layers carry a scan "
-                f"state and a convolution window that allow neither")
+                f"back; this model's {carried} that allow neither")
         if self.cfg.has_ring:
             raise ValueError(
                 f"{what} needs state that can be cut by token or rolled "
@@ -2185,9 +2206,9 @@ class LMServer:
         `cdiv(rows, block)` a slot, and fetches each whole; an empty
         slot has none (a step with every slot empty visits one block of
         no live rows: ops/decode_attention.py)."""
-        if "*" not in (self.cfg.layer_pattern or "*"):
-            return 0, 0, 0, 0  # no attention layer, no rows
         plane = self.cfg.layer_rows(layer, self.max_len)
+        if "*" not in (self.cfg.layer_pattern or "*") or not plane:
+            return 0, 0, 0, 0  # no attention layer, no rows
         grid = self.chunk * self.max_slots * plane
         pos0 = np.asarray(
             [r.prompt.size + r.emitted - 1

@@ -51,7 +51,8 @@ _EXPERT_MATMULS = ("w_up", "w_down", "w_gate")
 # tensors are over experts ([H, in, out]: one scale a head and channel)
 _HEAD_MATMULS = ("w_uk", "w_uv")
 # ... the 2-D kernels an expert layer holds beside its experts (the latent
-# projections, the shared expert) and a state-space mixer's two
+# projections, the shared expert) and a state-space mixer's two, which
+# are a gated short convolution's two as well (`short_conv`)
 _MOE_MATMULS = ("latent_down", "latent_up", "shared_up", "shared_down",
                 "shared_gate")
 _SSM_MATMULS = ("in_proj", "out_proj")
@@ -79,7 +80,8 @@ def _dequant(t: Dict[str, jax.Array], dtype) -> jax.Array:
 def _map_block_matmuls(params: Dict[str, Any], kernel_fn, expert_fn):
     """The same tree with `kernel_fn` applied to every block's 2-D
     matmul kernel (`_BLOCK_MATMULS`, an expert layer's `_MOE_MATMULS`,
-    a state-space mixer's `_SSM_MATMULS`) and `expert_fn` to every
+    a state-space mixer's or a short convolution's `_SSM_MATMULS`) and
+    `expert_fn` to every
     stacked tensor (`_EXPERT_MATMULS` over experts, `_HEAD_MATMULS`
     over heads): the leaves
     `_apply_block`, `expert_ffn` and `ssm_mixer` read through
@@ -103,7 +105,7 @@ def _map_block_matmuls(params: Dict[str, Any], kernel_fn, expert_fn):
                 }, **{
                     w: {**v[w], "kernel": kernel_fn(v[w]["kernel"])}
                     for w in _MOE_MATMULS if w in v}}
-            elif k == "ssm":
+            elif k in ("ssm", "short_conv"):
                 blk[k] = {**v, **{
                     w: {**v[w], "kernel": kernel_fn(v[w]["kernel"])}
                     for w in _SSM_MATMULS}}

@@ -215,6 +215,9 @@ line when you add the metric.
     lm_server_slots_active           busy decode slots
     lm_server_slots_total            configured decode slots
     lm_server_state_bytes            slot grid bytes by kind= kv|kv_window|latent|conv|scan
+                                     (conv: a state-space layer's or a gated short
+                                     convolution's window; its device time goes by the
+                                     parts conv_proj / conv_mix of tracing.PARTS)
     lm_server_step_seconds           decode step wall
     lm_server_steps_total            decode steps executed
     lm_server_tokens_fixed_total     block-diffusion tokens fixed and delivered

@@ -175,6 +175,8 @@ PARTS = (
     "moe_shared",        # expert layer: the shared expert
     "ssm_proj",          # state-space mixer: norm, projections, conv, gate
     "ssm_scan",          # state-space mixer: the recurrence (chunks, a step)
+    "conv_proj",         # gated short convolution: norm, in_proj, gates, out_proj
+    "conv_mix",          # ... the depthwise convolution and its window's update
     "head",              # final norm, logits, argmax / sample
     "diffuse_select",    # block diffusion: confidences, ranks, fix, commit
     "insert",            # a prefilled row into its slot; cur/pos/firsts merges

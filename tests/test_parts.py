@@ -36,6 +36,9 @@ WANTED = {
     "joyai_llm_flash_ep16": (
         ATTENTION | {"mlp", "moe_route", "moe_experts", "moe_shared"},
         {"flash_attention"}, set()),
+    "lfm2_8b_a1b_ep4": (
+        ATTENTION | {"conv_proj", "conv_mix", "mlp", "moe_route",
+                     "moe_experts"}, {"flash_attention"}, set()),
 }
 
 
@@ -102,7 +105,7 @@ def test_programs_name_their_parts_and_nothing_else_changes(
 def test_a_part_is_a_name_of_the_table():
     from dml_tpu.inference.generate import part
 
-    assert len(PARTS) == len(set(PARTS)) <= 16
+    assert len(PARTS) == len(set(PARTS)) <= 18
     with pytest.raises(ValueError, match="tracing.PARTS"):
         part("attention")
     with part("attn_core"):

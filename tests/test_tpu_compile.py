@@ -674,3 +674,110 @@ def test_window_attention_programs_of_the_laguna_config_fit_the_chip(
     ).lower(params, prompt, at).compile()
     assert compiled.as_text().count("tpu_custom_call") == 4 + 3 * 3
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 31
+
+
+def test_both_attention_kernels_compile_at_heads_of_64(topo):
+    """lfm2_8b_a1b_ep4's attention layers: 32 query heads over 8 KV heads
+    of 64, half a lane tile. The prefill kernel takes a 2,048-token row by
+    KV head as it is; the decode kernel takes the cache as the program
+    lays it out (`LMConfig.kv_pack`: two heads a row of 128, a plane
+    [128, 4, 4096, 128]) with the queries widened to the row
+    (`packed_attention`), over the 128-slot grid."""
+    from dml_tpu.inference import generate as G
+    from dml_tpu.ops.decode_attention import decode_attention
+    from dml_tpu.ops.flash_attention import flash_attention
+
+    bf = jnp.bfloat16
+    text, secs = compile_on_chip(
+        topo, functools.partial(flash_attention, causal=True,
+                                interpret=False),
+        ((1, 2048, 32, 64), bf), ((1, 2048, 8, 64), bf),
+        ((1, 2048, 8, 64), bf))
+    assert text.count("tpu_custom_call") == 1
+    assert secs < 20.0, f"flash at D 64: {secs:.1f}s"
+    al = G.AttentionLayers((("a", G.AttentionType(32)),), ("a",))
+    cfg = G.LMConfig(65536, 2048, 32, 1, 7168, dtype=bf, n_kv_heads=8,
+                     d_head=64, attention_layers=al)
+    assert cfg.kv_pack == 2
+
+    def step(q, k, v, n):
+        return G.packed_attention(
+            cfg, lambda *a: decode_attention(*a, scale=64 ** -0.5,
+                                             interpret=False), q, k, v, n)
+
+    plane = ((128, 4, 4096, 128), bf)
+    text, secs = compile_on_chip(
+        topo, step, ((128, 1, 32, 64), bf), plane, plane,
+        ((128,), jnp.int32))
+    assert text.count("tpu_custom_call") == 1
+    # no copy of a plane in or around the kernel
+    assert "bf16[128,4,4096,128]{3,2,1,0} copy(" not in text
+    assert secs < 20.0, f"decode at D 64, two heads a row: {secs:.1f}s"
+
+
+def test_short_convolution_programs_of_the_lfm2_config_fit_the_chip(
+        topo, chip_routes):
+    """The sixth benchmark configuration's two programs at
+    lfm2_8b_a1b_ep4's published widths, 128-slot grid and chunk, the depth
+    cut to its first eight layers (two dense, conv conv attention conv
+    twice over; the full depth compiles in 34 s and 25-28 s:
+    `benchmark/tools/aot_memory_window.py`, PERF.md section 4):
+    `LMServer._chunk_impl` holds the decode kernel an attention layer over
+    planes of two heads a row and three grouped matmuls an expert layer,
+    updates planes and convolution windows in place, and copies no plane
+    (a [., 64]-column plane was laid out in 128 and copied whole every
+    dispatch: 12 GiB of temporaries at full depth, PR 44); a 16 x 512
+    prefill group holds a flash kernel an attention layer."""
+    import json
+    import os
+
+    from dml_tpu.inference.generate import init_cache, prefill
+    from dml_tpu.inference.lm_backend import lm_spec_parts
+    from dml_tpu.inference.lm_server import LMServer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2_8b_a1b_ep4.json")) as f:
+        spec = json.load(f)["lm_spec"]
+    al = spec["attention_layers"]
+    assert al["layers"][:8] == ["conv", "conv", "full_attention", "conv"] * 2
+    spec = {**spec, "n_layers": 8, "attention_layers": {
+        **al, "layers": al["layers"][:8]}}
+    made = {}
+
+    def declared():
+        params, made["cfg"] = lm_spec_parts(spec)
+        return params
+
+    one = SingleDeviceSharding(topo.devices[0])
+    on_chip = functools.partial(jax.tree_util.tree_map, lambda s: (
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)))
+    params = on_chip(jax.eval_shape(declared))
+    assert "lm_head" not in params
+    cfg = made["cfg"]
+    slots, max_len = spec["max_slots"], spec["max_len"]
+    srv = object.__new__(LMServer)
+    srv.cfg, srv.max_len, srv.max_slots = cfg, max_len, slots
+    srv.chunk, srv.temperature, srv._mesh = spec["chunk"], 0.0, None
+    srv._routed = (6, spec["num_experts"])
+    srv._held = (0, spec["experts_held"][1])
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, slots, max_len)))
+    assert cache["block_0"]["conv"].shape == (128, 2, 2048)
+    assert cache["block_2"]["k"].shape == (128, 4, 4096, 128)
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+    compiled = jax.jit(srv._chunk_impl, donate_argnums=(1, 2, 3)).lower(
+        params, cache, vec, vec, vec).compile()
+    text = compiled.as_text()
+    assert text.lstrip().startswith("HloModule jit__chunk_impl")
+    assert text.count("tpu_custom_call") == 2 + 6 * 3
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2 ** 28
+    grid = sum(x.size * 2 for x in jax.tree_util.tree_leaves(cache))
+    assert m.alias_size_in_bytes >= grid  # planes and windows, in place
+    prompt = jax.ShapeDtypeStruct((16, 512), jnp.int32, sharding=one)
+    at = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one)
+    compiled = jax.jit(
+        lambda p, x, i: prefill(p, cfg, x, max_len, logits_index=i)
+    ).lower(params, prompt, at).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2 + 6 * 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30

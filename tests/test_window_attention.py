@@ -497,7 +497,8 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
       "denoising_steps": 2, "mask_token_id": 0}, "denoising_steps under"),
     ({"attention_mask": "block_causal", "block_length": 4}, "causal mask"),
     ({"layer_pattern": "*E*E*"}, "layer_pattern under"),
-    ({"qk_norm": True}, "qk_norm under"),
+    ({"attention_layers": {"layers": ["full"] * 5, "types": {
+        "full": {"conv_kernel": 3, "n_heads": 6}}}}, "no other key"),
     ({"rope_pairing": "interleaved"}, "rope in halves"),
     ({"rope": "none"}, "rope in halves"),
     ({"n_kv_heads": None}, "n_kv_heads says"),
@@ -575,7 +576,7 @@ def test_a_config_refuses_what_the_spec_refuses():
     assert g.LMConfig(**base).has_ring
     for change, match in (
             ({"kv_quant": True}, "no kv_quant"),
-            ({"qk_norm": True}, "grouped attention under"),
+            ({"rope_pairing": "interleaved"}, "grouped attention under"),
             ({"rope": False}, "grouped attention under"),
             ({"n_layers": 3}, "names 2 layers"),
             ({"n_kv_heads": 3}, "3 KV heads do not divide"),
